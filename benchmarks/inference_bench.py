@@ -46,6 +46,8 @@ def main():
     import jax
 
     import deepspeed_tpu
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if "/" in args.model or args.model.startswith("."):
         model = args.model  # HF checkpoint path

@@ -14,8 +14,7 @@ Usage::
                                        [--config gpt2|llama]
 
 Knobs are bench.py's env vars (BENCH_BATCH/SEQ/REMAT/LOSS_CHUNK/OPT...).
-This feeds the PARITY.md perf breakdown (VERDICT r3 ask 1: remat
-recompute vs loss chunking vs optimizer vs input pipeline).
+Like bench.py it runs on the chip only (device guard first).
 """
 
 from __future__ import annotations
@@ -40,15 +39,16 @@ def main():
 
     import sys
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-    from bench import (_probe_backend, build_bench_engine,
-                       build_bert_bench_engine, build_llama_bench_engine)
+    from bench import (build_bench_engine, build_bert_bench_engine,
+                       build_llama_bench_engine)
 
-    if os.environ.get("BENCH_SKIP_PROBE") != "1":
-        err = _probe_backend()
-        if err is not None:
-            print(f"profile_bench: [{err['stage']}] {err['summary']}\n"
-                  f"{err.get('error', '')}", file=sys.stderr)
-            sys.exit(1)
+    from deepspeed_tpu.accelerator import require_tpu
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+    try:
+        require_tpu()
+    except RuntimeError as e:
+        sys.exit(f"profile_bench: {e}")
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp
@@ -60,10 +60,11 @@ def main():
     BATCH, SEQ = knobs["BATCH"], knobs["SEQ"]
 
     # ---- 1. AOT cost analysis of the compiled step ----
-    float(engine.train_batch(batch()))  # compile
+    jax.block_until_ready(engine.train_batch(batch()))  # compile
     cost = None
     try:
         fn = next(iter(engine._train_batch_jit.values()))
+        fn = getattr(fn, "inner", fn)   # the jit under the compile watchdog
         # the compiled step takes the batch stacked [gas, B, ...] (gas=1)
         b = jax.tree.map(lambda x: jnp.asarray(x)[None], batch())
         cost = fn.lower(engine.state, b,
@@ -82,12 +83,12 @@ def main():
         with jax.profiler.trace(args.trace_dir):
             for _ in range(args.steps):
                 loss = engine.train_batch(batch())
-            float(loss)
+            jax.block_until_ready(loss)
         print(f"trace written to {args.trace_dir}")
     else:
         for _ in range(args.steps):
             loss = engine.train_batch(batch())
-        float(loss)
+        jax.block_until_ready(loss)
     dt = (time.perf_counter() - t0) / args.steps
     toks = BATCH * SEQ / dt
     print(json.dumps({"seconds_per_step": round(dt, 4),

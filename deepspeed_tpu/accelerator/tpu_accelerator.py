@@ -33,11 +33,7 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         return jax
 
     def _devices(self):
-        jax = self._jax
-        try:
-            return jax.devices()
-        except RuntimeError:
-            return jax.devices("cpu")
+        return self._jax.devices()
 
     def _local_devices(self):
         return self._jax.local_devices()
@@ -137,28 +133,28 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         # XLA owns the allocator; nothing to flush.
         pass
 
-    # per-chip bf16 matmul peak by device kind: the MFU denominator used
-    # by the telemetry gauge and bench.py (DS_PEAK_TFLOPS overrides for
-    # kinds not in the table)
-    _PEAK_TFLOPS = (("v5p", 459.0), ("v5e", 197.0), ("v5lite", 197.0),
-                    ("v4", 275.0), ("v3", 123.0), ("v2", 45.0))
+    # Per-chip dense bf16 peak, TFLOP/s, keyed by jax's ``device_kind`` as
+    # libtpu 0.0.34 spells it (one jax device per chip on these kinds).
+    # Source: the Google Cloud TPU documentation page of each generation
+    # ("TPU v4", "TPU v5e", "TPU v5p", "TPU v6e": peak compute per chip).
+    PEAK_BF16_TFLOPS = {
+        "TPU v4": 275.0,
+        "TPU v5 lite": 197.0,   # v5e
+        "TPU v5": 459.0,        # v5p
+        "TPU v6 lite": 918.0,   # v6e
+    }
 
     def peak_tflops(self) -> float:
-        """Per-chip bf16 peak TFLOP/s, or 0.0 when unknown (the MFU gauge
-        then reads 0 rather than fabricating a denominator)."""
-        import os
-        env = os.environ.get("DS_PEAK_TFLOPS")
-        if env:
-            return float(env)
+        """Per-chip bf16 peak TFLOP/s of device 0's kind. A kind without a
+        published entry raises ``LookupError``: a peak is a measurement's
+        denominator, so there is no default."""
+        kind = self._devices()[0].device_kind
         try:
-            kind = getattr(self._devices()[0], "device_kind", "").lower()
-        except Exception:
-            return 0.0
-        kind = kind.replace(" ", "")
-        for tag, peak in self._PEAK_TFLOPS:
-            if tag in kind:
-                return peak
-        return 0.0
+            return self.PEAK_BF16_TFLOPS[kind]
+        except KeyError:
+            raise LookupError(
+                f"no published bf16 peak for device_kind {kind!r} (known: "
+                f"{sorted(self.PEAK_BF16_TFLOPS)})") from None
 
     def memory_stats(self, device_index: Optional[int] = None) -> dict:
         return self._stats(device_index)

@@ -3,8 +3,7 @@
 
 Sweeps message sizes through the comm facade's collectives on the active
 mesh and reports latency / algorithmic BW / bus BW per op+size — the same
-table ``ds_bench`` prints. Sync is a host fetch of a reduction (the only
-reliable barrier over remote device transports).
+table ``ds_bench`` prints. Sync is a host fetch of a reduction.
 """
 
 from __future__ import annotations

@@ -38,6 +38,8 @@ def _serve(argv):
     ``"stream": true`` server-sent events). Prompts are token-id lists;
     see ``docs/api.md`` "Async serving" for a curl example."""
     from deepspeed_tpu.inference.serve import serve_main
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     return serve_main(argv)
 
 

@@ -104,12 +104,8 @@ def build_hybrid_mesh(ici_axes: Dict[str, int], dcn_axes: Dict[str, int]) -> Mes
 
 def bound_axis_size(name) -> int:
     """Size of a manual/collective axis bound in the CURRENT trace (a
-    shard_map/pmap body). ``jax.lax.axis_size`` where the installed jax has
-    it; on older versions (e.g. 0.4.x) the classic psum-of-1 idiom, which
-    jax constant-folds to the axis size."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
+    shard_map/pmap body)."""
+    return jax.lax.axis_size(name)
 
 
 def axis_size(mesh: Mesh, axis) -> int:

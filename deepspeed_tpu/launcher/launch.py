@@ -21,6 +21,7 @@ from collections import defaultdict
 from typing import Any, Dict, List
 
 from deepspeed_tpu.launcher.runner import decode_world_info
+from deepspeed_tpu.utils.compile_cache import compile_cache_dir
 from deepspeed_tpu.utils.logging import logger
 
 
@@ -90,6 +91,9 @@ def main(args=None):
         env = os.environ.copy()
         env.update(build_rank_env(world_info, args.node_rank, local_rank,
                                   args.master_addr, args.master_port))
+        # workers share one persistent compile cache; jax reads the variable
+        # itself, so this parent stays off jax
+        env.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache_dir())
         cmd = [sys.executable, "-u", args.user_script] + args.user_args
         if log_dir:
             rank = env["RANK"]

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.ops import dispatch
+
 
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
@@ -425,6 +427,7 @@ def attention(cfg: TransformerConfig, x, lp, positions, mask_bias):
         out = sp_attention(q, k, v, mesh=sp_mesh, impl=cfg.sequence_parallel,
                            causal=cfg.causal, mask_bias=mask_bias,
                            alibi_slopes=slopes, scale=cfg.attn_scale)
+        form = "sp"
     else:
         # kernel paths first — the Pallas kernel beats the XLA streaming
         # core at every length it can run
@@ -441,8 +444,10 @@ def attention(cfg: TransformerConfig, x, lp, positions, mask_bias):
                                       scale=cfg.attn_scale,
                                       block_q=cfg.attn_block_q,
                                       block_k=cfg.attn_block_k)
+                form = "flash"
             else:
                 out = _flash_sharded(cfg, q, k, v, mask_bias, slopes, fmesh)
+                form = "flash_sharded"
         if out is None and S > DENSE_STREAM_THRESHOLD:
             # long sequences off the kernel paths (pipeline stage vmap,
             # sp-less CPU, shapes outside the kernel envelope): stream the
@@ -457,7 +462,9 @@ def attention(cfg: TransformerConfig, x, lp, positions, mask_bias):
                                        jnp.int32(0), cfg.causal,
                                        DENSE_STREAM_CHUNK, q.dtype,
                                        cfg.attn_scale)
+            form = "chunked_stream"
     if out is None:
+        form = "einsum"
         # GQA kv goes in UNREPEATED — mha_attention contracts grouped query
         # heads [KV, G] against the raw kv, no H/KV× copy
         from deepspeed_tpu.ops.attention import mha_attention
@@ -465,6 +472,8 @@ def attention(cfg: TransformerConfig, x, lp, positions, mask_bias):
                             mask_bias=None if mask_bias is None else mask_bias[:, None, None, :],
                             causal=cfg.causal, alibi_slopes=slopes,
                             scale=cfg.attn_scale)
+    dispatch.record("attention", form,
+                    f"B={B} S={S} H={H} KV={k.shape[2]} Hd={Hd}")
     out = checkpoint_name(out.reshape(B, S, H * Hd), "attn_out")
     proj = out @ _w(lp["wo"], out)
     if cfg.manual_tp:
@@ -538,8 +547,7 @@ def _inside_full_manual(mesh) -> bool:
         if size > 1:
             try:
                 # probe only: axis_index raises NameError iff the axis is
-                # not bound in the current trace (works on every jax
-                # version; lax.axis_size does not exist on older ones)
+                # not bound in the current trace
                 jax.lax.axis_index(name)
             except NameError:
                 return False
@@ -568,7 +576,7 @@ def _use_flash(cfg: TransformerConfig) -> bool:
         return False
     if cfg.attention_backend == "flash":
         return True
-    return jax.default_backend() == "tpu"
+    return dispatch.on_tpu()
 
 
 def _flash_mesh(cfg: TransformerConfig):
@@ -581,7 +589,7 @@ def _flash_mesh(cfg: TransformerConfig):
     the sp paths, where a shard_map cannot be placed)."""
     if cfg.attention_backend not in ("flash", "auto"):
         return None
-    if cfg.attention_backend == "auto" and jax.default_backend() != "tpu":
+    if cfg.attention_backend == "auto" and not dispatch.on_tpu():
         return None
     import deepspeed_tpu.comm as dist
     if not dist.has_mesh():
@@ -1156,6 +1164,7 @@ def _paged_decode_attention(cfg: TransformerConfig, x, lp, positions, pos,
         o = paged_decode_attention(q[:, 0], kp, vp, block_tables, pos,
                                    pad_bias=pad_bias, alibi_slopes=slopes,
                                    scale=cfg.attn_scale)
+        form = "paged_kernel"
     else:
         # SPMD mesh (a bare pallas_call is illegal): shard_map the kernel
         # over the KV-head/tp axis — the head-sharded pool's shards each
@@ -1165,14 +1174,18 @@ def _paged_decode_attention(cfg: TransformerConfig, x, lp, positions, pos,
             o = _paged_decode_sharded(q[:, 0], kp, vp, block_tables, pos,
                                       pad_bias, slopes, pmesh,
                                       scale=cfg.attn_scale)
+            form = "paged_kernel_sharded"
     if o is not None:
         out = o.reshape(B, 1, H * cfg.head_dim)
     else:
+        form = "gather_einsum"
         # gather + grouped einsum (the dense cache path's masked-softmax
         # core with per-request qpos) — partitionable, the CPU tier default
         out = _grouped_cache_einsum(cfg, q, _paged_gather(kp, block_tables),
                                     _paged_gather(vp, block_tables),
                                     positions, pad_bias)
+    dispatch.record("paged_decode", form,
+                    f"B={B} H={H} KV={kp.shape[2]} Hd={cfg.head_dim} bs={bs}")
     out = out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
     return out, kp, vp
 
@@ -1198,10 +1211,13 @@ def _paged_prefill_attention(cfg: TransformerConfig, x, lp, positions,
         out = flash_attention(q, k, v, causal=True, alibi_slopes=slopes,
                               scale=cfg.attn_scale, block_q=cfg.attn_block_q,
                               block_k=cfg.attn_block_k)
+        form = "flash"
     if out is None:
         from deepspeed_tpu.ops.attention import mha_attention
         out = mha_attention(q, k, v, causal=True, alibi_slopes=slopes,
                             scale=cfg.attn_scale)
+        form = "einsum"
+    dispatch.record("paged_prefill", form, f"T={T}")
     out = out.reshape(B, T, H * Hd)
     out = out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
     return out, kp, vp
@@ -1576,7 +1592,7 @@ def _use_fused_ce(cfg) -> bool:
     if mode != "auto":
         raise ValueError(f"fused_cross_entropy={mode!r} (expected "
                          "'auto', 'on' or 'off')")
-    return jax.default_backend() == "tpu" and _bare_pallas_legal()
+    return dispatch.on_tpu() and _bare_pallas_legal()
 
 
 def vocab_head_ce(cfg, h, w, hb, safe_labels, valid):
@@ -1590,9 +1606,12 @@ def vocab_head_ce(cfg, h, w, hb, safe_labels, valid):
         from deepspeed_tpu.ops.pallas.fused_cross_entropy import (
             fused_cross_entropy)
         bias = None if isinstance(hb, (int, float)) else hb
+        dispatch.record("vocab_head", "fused_ce",
+                        f"D={h.shape[-1]} V={w.shape[-1]}")
         return fused_cross_entropy(h, w, safe_labels, bias=bias, valid=valid)
-    return chunked_vocab_ce(h, w, hb, safe_labels, valid,
-                            getattr(cfg, "loss_chunk", 0))
+    chunk = getattr(cfg, "loss_chunk", 0)
+    dispatch.record("vocab_head", "loss_chunk", f"chunk={chunk}")
+    return chunked_vocab_ce(h, w, hb, safe_labels, valid, chunk)
 
 
 def lm_loss(cfg: TransformerConfig, params, batch, rng=None,

@@ -188,8 +188,8 @@ class TelemetryConfig(ConfigModel):
     # compile watchdog: warn when one entry point compiles this many times
     # inside its rolling window
     compile_storm_threshold: int = 8
-    # hardware peak for the MFU gauge, per chip; 0 = auto (DS_PEAK_TFLOPS
-    # env, else the accelerator's device-kind table, else MFU reads 0)
+    # hardware peak for the MFU gauge, per chip; 0 = auto (the
+    # accelerator's device_kind table; a kind without an entry reads MFU 0)
     peak_tflops_per_chip: float = 0.0
     # health observatory sub-block (sentinels + anomaly detectors +
     # memory gauges + the `dscli health` screen); accepts a dict or a bool

@@ -271,15 +271,14 @@ class ProfileWindow:
 
 # Detection: jax emits a '/jax/core/compile/backend_compile_duration'
 # monitoring event for every REAL XLA compile. A thread-local accumulator
-# attributes those events to the watched call in flight — unlike the
+# attributes those events to the watched call in flight — unlike a
 # jit-cache-size heuristic this never miscounts C++ fastpath-cache
 # signature misses (e.g. donated-output arrays re-entering a step) as
-# compiles. When the listener can't register (older jax), the wrapper
-# falls back to cache-size growth.
+# compiles.
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _tls = threading.local()
-_listener_state = {"registered": False, "ok": False}
+_listener_registered = False
 
 
 def _compile_listener(name: str, dur: float, **kw) -> None:
@@ -290,17 +289,13 @@ def _compile_listener(name: str, dur: float, **kw) -> None:
         acc.append(dur)
 
 
-def _ensure_compile_listener() -> bool:
-    if not _listener_state["registered"]:
-        _listener_state["registered"] = True
-        try:
-            import jax.monitoring
-            jax.monitoring.register_event_duration_secs_listener(
-                _compile_listener)
-            _listener_state["ok"] = True
-        except Exception:
-            _listener_state["ok"] = False
-    return _listener_state["ok"]
+def _ensure_compile_listener() -> None:
+    global _listener_registered
+    if not _listener_registered:
+        _listener_registered = True
+        import jax.monitoring
+        jax.monitoring.register_event_duration_secs_listener(
+            _compile_listener)
 
 
 def _abstract_signature(args, kwargs, max_leaves: int = 24) -> str:
@@ -358,28 +353,17 @@ class CompileWatchdog:
         function's) and records one compile — with the summed backend
         compile wall time and the triggering abstract input shapes —
         whenever XLA actually compiled during the call."""
-        use_events = _ensure_compile_listener()
-        cache_size = getattr(jitted, "_cache_size", None)
+        _ensure_compile_listener()
 
         def wrapped(*args, **kwargs):
-            if use_events:
-                prev = getattr(_tls, "acc", None)
-                _tls.acc = acc = []
-                try:
-                    out = jitted(*args, **kwargs)
-                finally:
-                    _tls.acc = prev
-                if acc:
-                    self._record(name, sum(acc),
-                                 _abstract_signature(args, kwargs))
-                return out
-            if cache_size is None:
-                return jitted(*args, **kwargs)
-            before = cache_size()
-            t0 = time.perf_counter()
-            out = jitted(*args, **kwargs)
-            if cache_size() > before:
-                self._record(name, time.perf_counter() - t0,
+            prev = getattr(_tls, "acc", None)
+            _tls.acc = acc = []
+            try:
+                out = jitted(*args, **kwargs)
+            finally:
+                _tls.acc = prev
+            if acc:
+                self._record(name, sum(acc),
                              _abstract_signature(args, kwargs))
             return out
 

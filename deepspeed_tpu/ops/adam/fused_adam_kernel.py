@@ -36,6 +36,8 @@ import optax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops import dispatch
+
 # (rows, 128) f32 tile per grid step: 256*128*4B = 128 KiB per operand —
 # 7 operands ≈ 0.9 MiB of VMEM, far under budget, big enough to saturate
 # HBM bandwidth.
@@ -137,9 +139,9 @@ def _jnp_adam_flat(p, g, m, v, lr, bc1, bc2, *, b1, b2, eps, wd, adam_w, emit):
 def _run_adam(p, g, m, v, *, step, lr, b1, b2, eps, weight_decay, adam_w_mode,
               bias_correction, interpret, emit):
     # interpret=None: compiled kernel on TPU, jnp math elsewhere.
-    # interpret=True: kernel in interpret mode (any backend).
+    # interpret=True: kernel in interpret mode (off-TPU only).
     # interpret=False: compiled kernel (any backend — caller's risk off-TPU).
-    use_kernel = True if interpret is not None else jax.default_backend() == "tpu"
+    use_kernel = interpret is not None or dispatch.on_tpu()
     step = jnp.asarray(step, jnp.float32)
     if bias_correction:
         bc1 = 1.0 - jnp.asarray(b1, jnp.float32) ** step
@@ -151,9 +153,11 @@ def _run_adam(p, g, m, v, *, step, lr, b1, b2, eps, weight_decay, adam_w_mode,
               wd=float(weight_decay), adam_w=bool(adam_w_mode), emit=emit)
     lr = jnp.asarray(lr, jnp.float32)
     if not use_kernel:
+        dispatch.record("kernel/fused_adam", "jnp")
         return _jnp_adam_flat(p, g, m, v, lr, bc1, bc2, **kw)
-    return _fused_adam_flat(p, g, m, v, lr, bc1, bc2, interpret=bool(interpret),
-                            **kw)
+    return _fused_adam_flat(
+        p, g, m, v, lr, bc1, bc2,
+        interpret=dispatch.resolve_interpret("fused_adam", interpret), **kw)
 
 
 def fused_adam_step(p, g, m, v, *, step, lr, b1=0.9, b2=0.999, eps=1e-8,

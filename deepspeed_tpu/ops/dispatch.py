@@ -1,0 +1,76 @@
+"""Which form each kernel dispatch site selected, made observable.
+
+``auto`` settings pick the Pallas kernel on TPU and the XLA reference form
+elsewhere (the CPU tests need the reference forms), so a chip machine whose
+TPU failed to initialise would quietly run the CPU forms. Every dispatch
+site therefore reports its choice here. The Python that chooses runs while
+JAX traces, so one :func:`record` is one selection per compiled program:
+it bumps a process-wide counter and logs the first occurrence of each
+``(site, form)`` pair. ``chip_smoke.py`` asserts on :func:`selected`.
+
+Sites and their forms:
+
+====================  ====================================================
+``attention``         ``flash`` | ``flash_sharded`` | ``sp`` |
+                      ``chunked_stream`` | ``einsum``
+``vocab_head``        ``fused_ce`` | ``loss_chunk``
+``paged_decode``      ``paged_kernel`` | ``paged_kernel_sharded`` |
+                      ``gather_einsum``
+``paged_prefill``     ``flash`` | ``einsum``
+``kernel/<name>``     ``compiled`` | ``interpret`` | ``jnp`` (one per Pallas
+                      entry point; ``jnp`` = the kernel's plain-XLA twin)
+====================  ====================================================
+"""
+
+from __future__ import annotations
+
+import collections
+import threading
+from typing import Dict, Optional
+
+from deepspeed_tpu.utils.logging import logger
+
+_lock = threading.Lock()
+_counts: "collections.Counter[str]" = collections.Counter()
+
+
+def record(site: str, form: str, detail: str = "") -> None:
+    """Count one selection of ``form`` at ``site``; log it the first time."""
+    key = f"{site}={form}"
+    with _lock:
+        _counts[key] += 1
+        first = _counts[key] == 1
+    if first:
+        logger.info(f"dispatch: {key}" + (f" ({detail})" if detail else ""))
+
+
+def selected() -> Dict[str, int]:
+    """``{"site=form": times selected}`` since the last :func:`reset`."""
+    with _lock:
+        return dict(_counts)
+
+
+def reset() -> None:
+    with _lock:
+        _counts.clear()
+
+
+def on_tpu() -> bool:
+    import jax
+    return jax.default_backend() == "tpu"
+
+
+def resolve_interpret(kernel: str, interpret: Optional[bool]) -> bool:
+    """The one ``interpret`` rule of the Pallas entry points: ``None``
+    compiles on TPU and interprets elsewhere; an explicit value is honoured,
+    except that interpreting on a TPU backend is an error (it would run the
+    host emulation in place of the Mosaic kernel on the very machine the
+    kernel exists for). Records the outcome under ``kernel/<kernel>``."""
+    if interpret is None:
+        interpret = not on_tpu()
+    elif interpret and on_tpu():
+        raise RuntimeError(
+            f"{kernel}: interpret=True on a TPU backend — the Pallas kernel "
+            "must compile here; use the jnp reference for comparisons")
+    record(f"kernel/{kernel}", "interpret" if interpret else "compiled")
+    return interpret

@@ -30,6 +30,8 @@ import optax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops import dispatch
+
 _BLOCK_ROWS = 256
 _LANES = 128
 _BLOCK = _BLOCK_ROWS * _LANES
@@ -141,8 +143,8 @@ def _jnp_lamb_flat(p, g, m, v, lr, bc1, bc2, *, b1, b2, eps, wd, emit):
 def _run_lamb(p, g, m, v, *, step, lr, b1, b2, eps, weight_decay,
               bias_correction, interpret, emit="param"):
     # interpret=None: compiled kernel on TPU, jnp elsewhere; True: kernel in
-    # interpret mode; False: compiled kernel on any backend.
-    use_kernel = True if interpret is not None else jax.default_backend() == "tpu"
+    # interpret mode (off-TPU only); False: compiled kernel on any backend.
+    use_kernel = interpret is not None or dispatch.on_tpu()
     step = jnp.asarray(step, jnp.float32)
     if bias_correction:
         bc1 = 1.0 - jnp.asarray(b1, jnp.float32) ** step
@@ -154,9 +156,11 @@ def _run_lamb(p, g, m, v, *, step, lr, b1, b2, eps, weight_decay,
               emit=emit)
     lr = jnp.asarray(lr, jnp.float32)
     if not use_kernel:
+        dispatch.record("kernel/fused_lamb", "jnp")
         return _jnp_lamb_flat(p, g, m, v, lr, bc1, bc2, **kw)
-    return _fused_lamb_flat(p, g, m, v, lr, bc1, bc2,
-                            interpret=bool(interpret), **kw)
+    return _fused_lamb_flat(
+        p, g, m, v, lr, bc1, bc2,
+        interpret=dispatch.resolve_interpret("fused_lamb", interpret), **kw)
 
 
 def fused_lamb_step(p, g, m, v, *, step, lr, b1=0.9, b2=0.999, eps=1e-6,

@@ -9,7 +9,9 @@ declares its own argtypes on top of the handle returned by :func:`get_lib`.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional
@@ -28,19 +30,57 @@ def lib_path() -> str:
     return os.path.join(_repo_root(), "csrc", "build", "libdstpu.so")
 
 
-def build_library(verbose: bool = False) -> str:
-    """Run ``make -C csrc`` (idempotent; cheap when up to date)."""
+def _build_stamp() -> str:
+    """What an on-disk library must have been built from to be loaded: the
+    current ``csrc`` sources and this host's CPU. The Makefile compiles with
+    ``-march=native`` and ``csrc/build/`` is not in git, so a binary copied
+    in from another machine (or left from older sources) must not be used."""
+    h = hashlib.sha256()
     csrc = os.path.join(_repo_root(), "csrc")
-    result = subprocess.run(["make", "-C", csrc, "-j"], capture_output=True, text=True)
+    for name in sorted(os.listdir(csrc)):
+        if name.endswith(".cpp") or name == "Makefile":
+            h.update(name.encode())
+            with open(os.path.join(csrc, name), "rb") as f:
+                h.update(f.read())
+    cpu = platform.machine()
+    try:
+        with open("/proc/cpuinfo") as f:
+            cpu += next((line for line in f if line.startswith("flags")), "")
+    except OSError:
+        pass
+    h.update(cpu.encode())
+    return h.hexdigest()
+
+
+def build_library(verbose: bool = False) -> str:
+    """Rebuild ``csrc/build/libdstpu.so`` from the current sources on this
+    host and stamp it (see :func:`_build_stamp`)."""
+    csrc = os.path.join(_repo_root(), "csrc")
+    result = subprocess.run(["make", "-C", csrc, "-B", "-j"],
+                            capture_output=True, text=True)
     if result.returncode != 0:
         # -march=native can fail under qemu/exotic hosts; retry portable.
-        result = subprocess.run(["make", "-C", csrc, "-j", "ARCHFLAGS="],
+        result = subprocess.run(["make", "-C", csrc, "-B", "-j", "ARCHFLAGS="],
                                 capture_output=True, text=True)
     if result.returncode != 0:
         raise RuntimeError(f"native build failed:\n{result.stderr[-2000:]}")
+    with open(lib_path() + ".stamp", "w") as f:
+        f.write(_build_stamp())
     if verbose:
         logger.info(f"built native library at {lib_path()}")
     return lib_path()
+
+
+def ensure_library() -> str:
+    """Path of a library built from the current sources on this host,
+    rebuilding when the file or its stamp is missing or stale."""
+    path = lib_path()
+    try:
+        with open(path + ".stamp") as f:
+            fresh = os.path.exists(path) and f.read() == _build_stamp()
+    except OSError:
+        fresh = False
+    return path if fresh else build_library()
 
 
 def get_lib() -> ctypes.CDLL:
@@ -50,10 +90,7 @@ def get_lib() -> ctypes.CDLL:
         return _LIB
     with _LOCK:
         if _LIB is None:
-            path = lib_path()
-            if not os.path.exists(path):
-                build_library()
-            _LIB = ctypes.CDLL(path)
+            _LIB = ctypes.CDLL(ensure_library())
     return _LIB
 
 

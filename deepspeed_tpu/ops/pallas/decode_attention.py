@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.dispatch import resolve_interpret
+
 _NEG = -1e30
 
 
@@ -126,6 +128,7 @@ def _decode_call(q, ck, cv, pos, bias, slopes, *, bk, has_bias, has_alibi,
     out = pl.pallas_call(
         functools.partial(_kernel, bk=bk, n_blocks=n_blocks, kv=KV, group=P,
                           has_bias=has_bias, has_alibi=has_alibi),
+        name="decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,
@@ -166,8 +169,7 @@ def decode_attention(q, ck, cv, pos, *, pad_bias=None, alibi_slopes=None,
     bk = next((b for b in (512, 256, 128) if Smax % b == 0), None)
     if bk is None:
         return None
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret("decode_attention", interpret)
     P = H // KV
     scale = Hd**-0.5 if scale is None else scale
     qg = (q * scale).reshape(B, KV, P, Hd)

@@ -46,6 +46,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.dispatch import resolve_interpret
+
 _MASKED = -1e30  # large-negative for masked logits (exp2 underflows to 0)
 _LOG2E = 1.4426950408889634
 
@@ -410,6 +412,7 @@ def _build_packed(causal: bool, scale: float, bq: int, bk: int, interpret: bool,
                                    bq=bq, bk=bk, P=P, Hd=Hd)
         o, lse = pl.pallas_call(
             kernel,
+            name="flash_packed_fwd",
             grid=(B, H2, nq, nk),
             in_specs=[xq_spec(), xkv_spec(), xkv_spec(), tri_spec],
             out_specs=[xq_spec(), row_spec],
@@ -447,6 +450,7 @@ def _build_packed(causal: bool, scale: float, bq: int, bk: int, interpret: bool,
                                       bq=bq, bk=bk, P=P, Hd=Hd)
         dq = pl.pallas_call(
             dq_kernel,
+            name="flash_packed_dq",
             grid=(B, H2, nq, nk),
             in_specs=[xq_spec(), xkv_spec(), xkv_spec(), xq_spec(),
                       row_spec, row_spec, tri_spec],
@@ -465,6 +469,7 @@ def _build_packed(causal: bool, scale: float, bq: int, bk: int, interpret: bool,
                                        bq=bq, bk=bk, P=P, Hd=Hd)
         dk, dv = pl.pallas_call(
             dkv_kernel,
+            name="flash_packed_dkv",
             grid=(B, H2, nk, nq),
             in_specs=[kq_spec, kkv_spec, kkv_spec, kq_spec, krow_spec, krow_spec,
                       ktri_spec],
@@ -550,6 +555,7 @@ def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret
         kernel = functools.partial(_fwd_kernel, **statics)
         o, lse = pl.pallas_call(
             kernel,
+            name="flash_fwd",
             grid=(B, H, nq, nk),
             in_specs=[_q_spec(bq, Hd), _kv_spec(bk, Hd, G), _kv_spec(bk, Hd, G),
                       _mask_spec(bk), _slope_spec()] + maybe_tri + maybe_layout,
@@ -592,6 +598,7 @@ def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret
         dq_kernel = functools.partial(_dq_kernel, **statics)
         dq = pl.pallas_call(
             dq_kernel,
+            name="flash_dq",
             grid=(B, H, nq, nk),
             in_specs=[_q_spec(bq, Hd), _kv_spec(bk, Hd, G), _kv_spec(bk, Hd, G),
                       _q_spec(bq, Hd), _row_spec(bq), _row_spec(bq),
@@ -623,6 +630,7 @@ def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret
         dkv_kernel = functools.partial(_dkv_kernel, **statics)
         dk, dv = pl.pallas_call(
             dkv_kernel,
+            name="flash_dkv",
             grid=(B, KV, nk, G, nq),
             in_specs=[kq_spec, kk_spec, kk_spec, kq_spec, krow_spec, krow_spec,
                       kmask_spec, kslope_spec] + kmaybe_tri + kmaybe_layout,
@@ -694,8 +702,7 @@ def flash_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=N
         raise ValueError(f"q heads {H} not a multiple of kv heads {KV}")
     kv_group = H // KV
     scale = float(scale if scale is not None else Hd**-0.5)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret("flash_attention", interpret)
     # default blocks: one program per (b, h) when the whole sequence fits
     # (fewest program launches — measured fastest at S ≤ 1024); for longer
     # sequences 1024² blocks: chip-measured 8.7% faster than 512² at S=2048

@@ -40,6 +40,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.dispatch import resolve_interpret
+
 _MASKED = -1e30  # pad-column bias: exp2 underflows to exactly 0
 _LOG2E = 1.4426950408889634
 
@@ -199,6 +201,7 @@ def _build(D: int, bt: int, bv: int, bv_dw: int, interpret: bool):
         kernel = functools.partial(_fwd_kernel, bt=bt, bv=bv)
         nll, lse = pl.pallas_call(
             kernel,
+            name="fused_ce_fwd",
             grid=(Np // bt, Vp // bv),
             in_specs=[h_spec(), w_spec(), vrow_spec(), trow_spec()],
             out_specs=[trow_spec(), trow_spec()],
@@ -227,6 +230,7 @@ def _build(D: int, bt: int, bv: int, bv_dw: int, interpret: bool):
 
         dh = pl.pallas_call(
             functools.partial(_dh_kernel, bt=bt, bv=bv),
+            name="fused_ce_dh",
             grid=(Np // bt, Vp // bv),
             in_specs=[h_spec(), w_spec(), vrow_spec(), trow_spec(),
                       trow_spec(), trow_spec()],
@@ -245,6 +249,7 @@ def _build(D: int, bt: int, bv: int, bv_dw: int, interpret: bool):
         kt_spec = pl.BlockSpec((1, bt), lambda j, i: (0, i))
         dw, db = pl.pallas_call(
             functools.partial(_dw_kernel, bt=bt, bv=bv_dw),
+            name="fused_ce_dw",
             grid=(Vp // bv_dw, Np // bt),
             in_specs=[kh_spec, kw_spec, kv_spec, kt_spec, kt_spec, kt_spec],
             out_specs=[kw_spec, kv_spec],
@@ -294,8 +299,7 @@ def fused_cross_entropy(h, w, labels, bias=None, valid=None,
         N *= d
     if h.size != N * D:
         raise ValueError(f"h {h.shape} does not match labels {labels.shape}")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret("fused_cross_entropy", interpret)
 
     # token tile: whole (8-aligned) token set when it fits one block; else
     # 128-aligned so the [1, Np] row blocks tile lanes legally. Large-D

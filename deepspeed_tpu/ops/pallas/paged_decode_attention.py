@@ -40,6 +40,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops.dispatch import resolve_interpret
+from deepspeed_tpu.utils.logging import warn_once
+
 _NEG = -1e30
 
 
@@ -115,16 +118,19 @@ def _paged_call(q, kp, vp, bt, pos, bias, slopes, *, bs, has_bias, has_alibi,
         pl.BlockSpec((1, KV, P, Hd), lambda b, i, bt_s, pos_s: (b, 0, 0, 0)),
         pl.BlockSpec((1, bs, KV, Hd), kv_idx),
         pl.BlockSpec((1, bs, KV, Hd), kv_idx),
-        # bias over LOGICAL positions, [B, n_blocks, bs]: block index follows
-        # the clamped logical block (not the pool id)
+        # bias over LOGICAL positions rides [B, 1, n_blocks * bs] like the
+        # dense kernel's (a sublane-1 block over a larger dim is not a legal
+        # Mosaic block); the lane-block index follows the clamped logical
+        # block, not the pool id
         pl.BlockSpec((1, 1, bs),
                      lambda b, i, bt_s, pos_s:
-                     (b, jnp.minimum(i, pos_s[b] // bs), 0)),
+                     (b, 0, jnp.minimum(i, pos_s[b] // bs))),
         pl.BlockSpec((KV, P), lambda b, i, bt_s, pos_s: (0, 0)),
     ]
     out = pl.pallas_call(
         functools.partial(_kernel, bs=bs, n_blocks=n_blocks, kv=KV, group=P,
                           has_bias=has_bias, has_alibi=has_alibi),
+        name="paged_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
@@ -139,7 +145,7 @@ def _paged_call(q, kp, vp, bt, pos, bias, slopes, *, bs, has_bias, has_alibi,
         ),
         out_shape=jax.ShapeDtypeStruct((B, KV, P, Hd), q.dtype),
         interpret=interpret,
-    )(bt, pos, q, kp, vp, bias.reshape(B, bt.shape[1], bs), slopes)
+    )(bt, pos, q, kp, vp, bias.reshape(B, 1, n_blocks * bs), slopes)
     return out
 
 
@@ -176,9 +182,12 @@ def paged_decode_attention(q, kp, vp, block_tables, pos, *, pad_bias=None,
     B, H, Hd = q.shape
     bs, KV = kp.shape[1], kp.shape[2]
     if not paged_envelope_ok(H, KV, Hd, bs):
+        warn_once(f"paged_decode_attention: heads={H} kv_heads={KV} "
+                  f"head_dim={Hd} block_size={bs} is outside the kernel "
+                  "envelope (H % KV == 0, head_dim % 64 == 0, block_size % "
+                  "128 == 0); the caller takes its gather + einsum form")
         return None
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret("paged_decode_attention", interpret)
     P = H // KV
     scale = Hd**-0.5 if scale is None else scale
     qg = (q * scale).reshape(B, KV, P, Hd)

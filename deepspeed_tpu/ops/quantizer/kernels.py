@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops import dispatch
+
 _LANES = 128
 _ROWS = 8
 
@@ -126,8 +128,8 @@ def ds_sr_quantize(x, groups: int, bits: int = 8, seed=0,
     # the core-PRNG primitives have no interpret-mode lowering, so the
     # kernel runs only where it compiles: on TPU (interpret=False forces
     # a compile attempt for AOT checks)
-    use_kernel = (jax.default_backend() == "tpu" if interpret is None
-                  else not interpret)
+    use_kernel = dispatch.on_tpu() if interpret is None else not interpret
+    dispatch.record("kernel/sr_quantize", "compiled" if use_kernel else "jnp")
     flat, L, pad, rpad = _group_view(x, groups)
     scale, qmax = _sym_scale(flat[:groups] if rpad else flat, bits)
     if rpad:

@@ -13,8 +13,6 @@ and configs that probe ops by name port over.
 from __future__ import annotations
 
 import importlib
-import os
-import subprocess
 from typing import Dict, Optional, Type
 
 from deepspeed_tpu.utils.logging import logger
@@ -56,40 +54,28 @@ class NativeOpBuilder(OpBuilder):
     """Host-side C++ op loaded via ctypes from a shared library.
 
     The library is built from ``csrc/`` with ``make`` (no torch cpp_extension
-    involved). ``load()`` triggers a build if the .so is missing.
+    involved) by :func:`deepspeed_tpu.ops.native.ensure_library`, which only
+    accepts an on-disk file built from the current sources on this host.
     """
 
-    LIBRARY = "libdstpu.so"
-
-    def lib_path(self) -> str:
-        root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-        return os.path.join(root, "csrc", "build", self.LIBRARY)
-
     def build(self, verbose: bool = True) -> bool:
-        root = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-        csrc = os.path.join(root, "csrc")
-        if not os.path.exists(os.path.join(csrc, "Makefile")):
-            self.error_log = "csrc/Makefile not found"
-            return False
+        from deepspeed_tpu.ops import native
         try:
-            subprocess.run(["make", "-C", csrc, "-j"], check=True,
-                           capture_output=not verbose)
+            native.ensure_library()
             return True
-        except subprocess.CalledProcessError as e:  # pragma: no cover
+        except (OSError, RuntimeError) as e:
             self.error_log = f"native build failed: {e}"
+            if verbose:
+                logger.warning(self.error_log)
             return False
 
     def is_compatible(self, verbose: bool = True) -> bool:
-        if os.path.exists(self.lib_path()):
-            return True
         return self.build(verbose=verbose)
 
     def load(self, verbose: bool = True):
-        if not os.path.exists(self.lib_path()):
-            if not self.build(verbose=verbose):
-                raise RuntimeError(f"Could not build native library for {self.NAME}: {self.error_log}")
-        mod = importlib.import_module(self.MODULE)
-        return mod
+        if not self.build(verbose=verbose):
+            raise RuntimeError(f"Could not build native library for {self.NAME}: {self.error_log}")
+        return importlib.import_module(self.MODULE)
 
 
 # --------------------------------------------------------------------- #

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.ops import dispatch
 from deepspeed_tpu.ops.sparse_attention.sparsity_config import (FixedSparsityConfig,
                                                                 SparsityConfig)
 
@@ -59,7 +60,7 @@ class SparseSelfAttention:
             return True
         if self.backend == "dense":
             return False
-        return jax.default_backend() == "tpu" and self.sparsity_config.block >= 128
+        return dispatch.on_tpu() and self.sparsity_config.block >= 128
 
     def __call__(self, query, key, value, rpe=None, key_padding_mask=None, attn_mask=None):
         B, S, H, Hd = query.shape

@@ -1920,15 +1920,15 @@ class DeepSpeedEngine:
         return fpt
 
     def _tel_peak_tflops(self) -> float:
-        """MFU denominator: config > DS_PEAK_TFLOPS env / accelerator
-        device-kind table > 0 (gauge reads 0 rather than fabricating)."""
+        """MFU denominator: config > accelerator device-kind table; a kind
+        with no published peak gives 0 (no peak, no gauge)."""
         p = float(self._telemetry.peak_tflops_per_chip or 0.0)
         if p > 0:
             return p
+        from deepspeed_tpu.accelerator import get_accelerator
         try:
-            from deepspeed_tpu.accelerator import get_accelerator
-            return float(get_accelerator().peak_tflops())
-        except Exception:
+            return get_accelerator().peak_tflops()
+        except LookupError:
             return 0.0
 
     def telemetry_snapshot(self) -> Dict:

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.ops import dispatch
 from deepspeed_tpu.utils.jax_compat import shard_map
 
 
@@ -68,4 +69,4 @@ def resolve_use_flash(override) -> bool:
     wins, else kernel on TPU, XLA streaming core elsewhere."""
     if override is not None:
         return bool(override)
-    return jax.default_backend() == "tpu"
+    return dispatch.on_tpu()

@@ -1,38 +1,6 @@
-"""Version-compat shims for the jax API surface this codebase targets.
+"""The one import site of ``shard_map`` (``jax.shard_map``, with its
+``check_vma`` keyword, as the installed jax 0.9 spells it)."""
 
-The TPU toolchain ships a newer jax than some dev/CI containers; the
-symbols that moved between those versions are normalised here so call
-sites stay version-agnostic:
-
-- ``shard_map``: top-level ``jax.shard_map`` (with its ``check_vma``
-  kwarg) on newer jax; on 0.4.x the ``jax.experimental.shard_map``
-  function, whose equivalent kwarg is spelled ``check_rep`` — the shim
-  translates.
-- axis-size-in-trace lives in :func:`deepspeed_tpu.comm.bound_axis_size`
-  (``jax.lax.axis_size`` vs the classic psum-of-1 idiom).
-"""
-
-try:
-    from jax import shard_map  # noqa: F401  (jax >= 0.5)
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _experimental_shard_map
-
-    def shard_map(f, *, check_vma=None, axis_names=None, mesh=None, **kwargs):
-        if check_vma is not None:
-            kwargs["check_rep"] = check_vma
-        if axis_names is not None and frozenset(axis_names) != frozenset(
-                getattr(mesh, "axis_names", ())):
-            # new API: axis_names = the MANUAL axes, the rest stay auto.
-            # The experimental API spells that auto=complement, but this
-            # jax generation lowers partial-auto bodies that take
-            # axis_index to a PartitionId op its SPMD partitioner rejects
-            # (and some such programs hard-abort the process) — refuse
-            # cleanly instead of letting XLA crash the interpreter.
-            raise NotImplementedError(
-                "partial-auto shard_map (axis_names subset of the mesh) "
-                "needs the newer jax this codebase targets; the installed "
-                f"jax predates it (mesh axes {tuple(mesh.axis_names)}, "
-                f"manual {tuple(axis_names)})")
-        return _experimental_shard_map(f, mesh=mesh, **kwargs)
+from jax import shard_map
 
 __all__ = ["shard_map"]

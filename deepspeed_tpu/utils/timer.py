@@ -218,8 +218,8 @@ class ThroughputTimer:
         if self.steps_per_output and self.global_step_count >= self.start_step:
             # only pay the device sync when the measurement is consumed —
             # with reporting off (steps_per_print=0) a per-step synchronize
-            # would serialize host dispatch against the device (very costly
-            # over remote-device transports) for a number nobody reads
+            # would serialize host dispatch against the device for a number
+            # nobody reads
             _device_synchronize()
             self.start_time = time.perf_counter()
 

@@ -655,29 +655,3 @@ class TestStepTracerMetadata:
         assert threads and threads[0]["args"]["name"] == "MainThread"
         span = next(e for e in doc["traceEvents"] if e["ph"] == "X")
         assert span["tid"] == threads[0]["tid"]
-
-
-class TestBenchSkipRecords:
-
-    def test_skip_records_carry_stage_and_error_text(self, capsys):
-        import bench
-        err = {"stage": "backend_init_timeout",
-               "summary": "device backend did not initialize within 240s",
-               "error": "TimeoutExpired: Command '...' timed out\n"
-                        "RuntimeError: relay unreachable"}
-        bench._emit_skip_records(err)
-        lines = capsys.readouterr().out.strip().splitlines()
-        assert len(lines) == len(bench._enabled_metrics())
-        for line in lines:
-            rec = json.loads(line)
-            assert rec["skipped"] is True
-            assert rec["skip_stage"] == "backend_init_timeout"
-            assert "relay unreachable" in rec["skip_error"]
-            assert "did not initialize" in rec["unit"]
-
-    def test_legacy_string_error_still_works(self, capsys):
-        import bench
-        bench._emit_skip_records("boom\ndetail")
-        rec = json.loads(capsys.readouterr().out.strip().splitlines()[0])
-        assert rec["skip_stage"] == "backend_probe"
-        assert rec["unit"].endswith("(skipped: boom)")

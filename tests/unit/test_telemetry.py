@@ -383,9 +383,8 @@ class TestSchedulerServingMetrics:
 
 class TestEngineTelemetry:
 
-    def test_train_step_records_step_time_tokens_mfu_compiles(self, monkeypatch):
-        monkeypatch.setenv("DS_PEAK_TFLOPS", "1.0")
-        engine = make_train_engine()
+    def test_train_step_records_step_time_tokens_mfu_compiles(self):
+        engine = make_train_engine(peak_tflops_per_chip=1.0)
         engine.train_batch(train_batch(engine))
         snap = engine.telemetry_snapshot()
         validate_snapshot(snap)
@@ -393,7 +392,7 @@ class TestEngineTelemetry:
         assert snap["counters"]["train/steps"] == 1
         assert snap["counters"]["train/tokens"] == 8 * 32
         assert snap["gauges"]["train/tokens_per_sec"] > 0
-        assert snap["gauges"]["train/mfu"] > 0          # peak pinned by env
+        assert snap["gauges"]["train/mfu"] > 0          # peak pinned by config
         assert snap["gauges"]["train/achieved_tflops_per_chip"] > 0
         by_fn = snap["compile"]["by_fn"]
         assert by_fn.get("engine.train_batch[gas=1]") == 1
@@ -487,12 +486,11 @@ class TestServingTelemetrySmoke:
         assert snap["counters"]["serving/recompute_tokens"] > 0
         assert snap["histograms"]["serving/ttft_ms"]["count"] == 2
 
-    def test_full_smoke_train_plus_serve(self, monkeypatch):
+    def test_full_smoke_train_plus_serve(self):
         """The acceptance checklist in one snapshot: step-time breakdown,
         tokens/sec, MFU, compile count, TTFT/TPOT, queue depth, KV-block
         utilization, preemption counters."""
-        monkeypatch.setenv("DS_PEAK_TFLOPS", "1.0")
-        train = make_train_engine()
+        train = make_train_engine(peak_tflops_per_chip=1.0)
         train.train_batch(train_batch(train))
         dist.set_mesh(None)
         serve = deepspeed_tpu.init_inference(
