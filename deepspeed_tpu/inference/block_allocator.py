@@ -2,7 +2,7 @@
 
 The host-side twin of the device pools built by
 ``models/transformer.py::init_paged_kv_cache``: the pools are
-``[n_layer, num_blocks, block_size, KV, Hd]`` arrays, and this allocator
+``[n_layer, num_blocks, block_size, KV*Hd]`` arrays, and this allocator
 hands out pool block ids to requests and reclaims them when requests retire
 or are preempted. The analogue of vLLM's ``BlockAllocator``, including its
 automatic prefix caching: blocks are REFERENCE-COUNTED, and with

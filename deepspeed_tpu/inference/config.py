@@ -139,7 +139,7 @@ class KvHostConfig(ConfigModel):
     (``inference/kv_host_pool.py``) behind the paged block allocator:
     instead of destroying a cold prefix-cache block under allocation
     pressure, the allocator *demotes* it — an async D2H copy of the
-    block's ``[L, bs, KV, Hd]`` k/v slices keyed by its blake2b hash
+    block's ``[L, bs, KV*Hd]`` k/v slices keyed by its blake2b hash
     chain — and a later admission whose prefix walks onto a demoted
     chain re-materializes it H2D into fresh device blocks instead of
     recomputing the prefill. Host RAM is ~10x HBM, so effective cache

@@ -791,8 +791,9 @@ class InferenceEngine:
 
     def _kv_head_sharding(self):
         """NamedSharding for the KV workspaces — the dense cache
-        [L, B, S, KV, Hd] and the paged pools [L, blocks, bs, KV, Hd] share
-        the rank-5 KV-heads-at-axis-3 layout: head-sharded over ``tp``
+        [L, B, S, KV, Hd] and the paged pools [L, blocks, bs, KV*Hd] both
+        carry the KV heads at axis 3 (the pools merged with Hd, so ``tp``
+        splits their rows into whole heads): head-sharded over ``tp``
         when the model's KV heads divide the axis (per-chip KV bytes drop
         to 1/tp; block tables stay replicated because per-shard block
         indices are identical), replicated with a rate-limited warning
@@ -803,8 +804,7 @@ class InferenceEngine:
             kvh = getattr(getattr(self.module, "config", None),
                           "kv_heads", None)
             if kvh is not None and kvh % tp == 0:
-                return NamedSharding(self.mesh,
-                                     P(None, None, None, "tp", None))
+                return NamedSharding(self.mesh, P(None, None, None, "tp"))
             warn_once(f"serving tp={tp} does not divide the model's "
                       f"kv_heads={kvh}: KV caches/pools replicate over the "
                       "tp axis (params still shard, but there is no KV "
@@ -813,15 +813,15 @@ class InferenceEngine:
 
     def _kv_slice_sharding(self):
         """NamedSharding for ONE block's per-layer k/v slice
-        ``[L, bs, KV, Hd]`` — the tiered KV cache's D2H/H2D unit. Under
+        ``[L, bs, KV*Hd]`` — the tiered KV cache's D2H/H2D unit. Under
         ``serving.tp`` the slice lands head-sharded exactly like the
-        pools themselves (axis 2 here = axis 3 of the rank-5 pool), so a
+        pools themselves (axis 2 here = axis 3 of the pool), so a
         spill gathers each shard's local heads and a fetch scatters them
         back without ever gathering the pool."""
         from jax.sharding import NamedSharding, PartitionSpec as P
         pool_sh = self._kv_head_sharding()
         if any(s is not None for s in pool_sh.spec):
-            return NamedSharding(self.mesh, P(None, None, "tp", None))
+            return NamedSharding(self.mesh, P(None, None, "tp"))
         return NamedSharding(self.mesh, P())
 
     def _kv_host_pool_for(self, num_blocks: int, block_size: int,
@@ -838,7 +838,7 @@ class InferenceEngine:
             raise ValueError(
                 f"serving.kv_host.spill={kh.spill!r} (expected auto|off)")
         cfg = self.module.config
-        shape = (cfg.n_layer, block_size, cfg.kv_heads, cfg.head_dim)
+        shape = (cfg.n_layer, block_size, cfg.kv_heads * cfg.head_dim)
         dtype = self.dtype.__name__
         cap = int(kh.max_host_blocks) or 4 * max(num_blocks - 1, 1)
         pool = self._kv_host_pool
@@ -884,7 +884,7 @@ class InferenceEngine:
             return
         cfg = self.module.config
         shape = (cfg.n_layer, int(self._config.serving.block_size),
-                 cfg.kv_heads, cfg.head_dim)
+                 cfg.kv_heads * cfg.head_dim)
         if not pool.matches_geometry(shape, self.dtype.__name__):
             raise ValueError(
                 f"host KV pool geometry {pool.block_shape}/{pool.dtype} "
@@ -1764,7 +1764,7 @@ class _ServeSession:
 
     def _run_fetches(self, req, pools):
         """Land the admission's host-tier hits H2D: device_put each
-        demoted ``[L, bs, KV, Hd]`` slice (head-sharded under tp, like
+        demoted ``[L, bs, KV*Hd]`` slice (head-sharded under tp, like
         the pools) and scatter it into the request's freshly allocated
         block via the jitted per-block program. Runs BEFORE any of the
         request's prefill compute reads the blocks. Each promoted block

@@ -5,7 +5,7 @@ allocation pressure reclaims them — reclaiming DESTROYS content a
 returning user would re-hit, so at scale cache capacity (not compute)
 bounds hit rate and TTFT. Host RAM is ~10x HBM: instead of destroying a
 cold block, the allocator *demotes* it here — an async D2H copy of the
-block's per-layer ``[L, bs, KV, Hd]`` k/v slices, keyed by the block's
+block's per-layer ``[L, bs, KV*Hd]`` k/v slices, keyed by the block's
 blake2b hash chain (the same content address the device table uses) —
 and a later admission whose prefix walks onto a demoted chain
 *re-materializes* the block H2D into a freshly allocated device block
@@ -57,7 +57,7 @@ from deepspeed_tpu.utils.logging import warn_once
 
 
 class _HostBlock:
-    """One demoted block: k/v slices ``[L, bs, KV, Hd]``. Until
+    """One demoted block: k/v slices ``[L, bs, KV*Hd]``. Until
     :meth:`materialize` runs they are the gather program's device arrays
     with an async host copy in flight; after, plain numpy."""
 
@@ -87,8 +87,8 @@ class KvHostPool:
                  dtype: str, pending_limit: int = 4, telemetry=None):
         if max_blocks < 1:
             raise ValueError("max_blocks must be >= 1")
-        if len(block_shape) != 4:
-            raise ValueError("block_shape must be [L, bs, KV, Hd], got "
+        if len(block_shape) != 3:
+            raise ValueError("block_shape must be [L, bs, KV*Hd], got "
                              f"{block_shape}")
         self.max_blocks = int(max_blocks)
         self.block_shape = tuple(int(s) for s in block_shape)
@@ -129,7 +129,7 @@ class KvHostPool:
             return list(self._entries)
 
     def matches_geometry(self, block_shape, dtype) -> bool:
-        """Entries are only valid for one ``[L, bs, KV, Hd]`` + dtype —
+        """Entries are only valid for one ``[L, bs, KV*Hd]`` + dtype —
         the engine rebuilds the pool when serving geometry changes."""
         return (self.block_shape == tuple(int(s) for s in block_shape)
                 and self.dtype == str(dtype))

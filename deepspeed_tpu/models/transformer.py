@@ -752,8 +752,9 @@ def _paged_shard_ok(mesh, H: int, KV: int, Hd: int, bs: int) -> bool:
 def _paged_decode_sharded(q1, kp, vp, block_tables, pos, pad_bias, slopes,
                           mesh, scale=None):
     """Paged decode-attention kernel under an SPMD mesh: shard_map over the
-    KV-HEAD axis — q and the block pools split over ``tp``, while block
-    tables, positions and the logical-position bias stay REPLICATED
+    KV-HEAD axis — q splits over ``tp`` by heads and the block pools' merged
+    ``KV*Hd`` rows by whole kv heads, while block tables, positions and the
+    logical-position bias stay REPLICATED
     (per-shard block indices are identical; the head split is the only
     partition, so shards need no communication). dp/fsdp/ep axes replicate
     the whole fused step: continuous batching is ONE program over all
@@ -764,7 +765,7 @@ def _paged_decode_sharded(q1, kp, vp, block_tables, pos, pad_bias, slopes,
     from deepspeed_tpu.utils.jax_compat import shard_map
 
     B, H, Hd = q1.shape
-    bs, KV = kp.shape[1], kp.shape[2]
+    bs, KV = kp.shape[1], kp.shape[2] // Hd
     if not _paged_shard_ok(mesh, H, KV, Hd, bs):
         return None
     head_axis = "tp" if mesh.shape.get("tp", 1) > 1 else None
@@ -773,7 +774,7 @@ def _paged_decode_sharded(q1, kp, vp, block_tables, pos, pad_bias, slopes,
         paged_decode_attention
 
     qspec = P(None, head_axis, None)
-    pspec = P(None, None, head_axis, None)
+    pspec = P(None, None, head_axis)
     operands = [q1, kp, vp, jnp.asarray(block_tables, jnp.int32),
                 jnp.asarray(pos, jnp.int32)]
     specs = [qspec, pspec, pspec, P(), P()]
@@ -1106,47 +1107,84 @@ def forward_cached(cfg: TransformerConfig, params, tokens, cache, pos, pad_bias=
 
 # --------------------------------------------------------------------- #
 # Paged KV cache (vLLM PagedAttention / Orca continuous batching, TPU form):
-# KV lives in fixed-size block POOLS [n_layer, num_blocks, block_size, KV, Hd]
+# KV lives in fixed-size block POOLS [n_layer, num_blocks, block_size, KV*Hd]
 # shared by every in-flight request; each request owns a block table mapping
 # its logical blocks to pool blocks. Memory is bounded by tokens in flight
 # (not B × Smax), requests at different depths decode in one fused step, and
 # retiring a request frees its blocks for the next admission.
+#
+# The pool has ONE representation from the engine's workspace to the
+# kernel's DMA. A token's kv heads are merged into one row of KV*Hd lanes:
+# with Hd 64 as its own minor dimension (half a lane tile) the device would
+# store the pool transposed and convert every layer's slice on the way into
+# and out of the row-major kernel. And the four forward_paged_* programs
+# thread the pools as the layer scan's CARRY, viewed as
+# [n_layer*num_blocks, block_size, KV*Hd] (:func:`_scan_paged_layers`), so a
+# step touches the rows it reads and writes and nothing else of the pool.
 
 def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
                         block_size: int, dtype=jnp.bfloat16) -> Dict[str, Any]:
-    """Paged KV pools: k/v [n_layer, num_blocks, block_size, kv_heads, Hd].
-    Block 0 is conventionally the allocator's dummy block (padding tokens
-    and inactive decode rows write there; nothing ever reads it)."""
-    shape = (cfg.n_layer, num_blocks, block_size, cfg.kv_heads, cfg.head_dim)
+    """Paged KV pools: k/v [n_layer, num_blocks, block_size, kv_heads * Hd]
+    (kv head ``g`` of a token at ``[g*Hd, (g+1)*Hd)`` of its row). This is
+    the one home of the shape. Block 0 is conventionally the allocator's
+    dummy block (padding tokens and inactive decode rows write there;
+    nothing ever reads it)."""
+    shape = (cfg.n_layer, num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def _pool_scatter(pool, kv_new, slots):
-    """Write per-token k or v [N, KV, Hd] into one layer's pool
-    [num_blocks, bs, KV, Hd] at flat slots [N] (block_id * bs + offset)."""
-    Nb, bs = pool.shape[0], pool.shape[1]
-    flat = pool.reshape(Nb * bs, *pool.shape[2:])
-    return flat.at[slots].set(kv_new.astype(pool.dtype)).reshape(pool.shape)
+    """Write per-token k or v [N, KV, Hd] into a pool [blocks, bs, KV*Hd]
+    at flat slots [N] (block_id * bs + offset)."""
+    Nb, bs, F = pool.shape
+    flat = pool.reshape(Nb * bs, F)
+    rows = kv_new.reshape(-1, F).astype(pool.dtype)
+    return flat.at[slots].set(rows).reshape(pool.shape)
 
 
-def _paged_gather(pool, block_tables):
+def _paged_gather(pool, block_tables, kv_heads: int):
     """Dense [B, max_blocks*bs, KV, Hd] gather of each request's cache via
     its block table — the einsum fallback when the paged kernel is
-    off-envelope or the mesh/SPMD context forbids a bare pallas_call."""
-    Nb, bs = pool.shape[0], pool.shape[1]
-    flat = pool.reshape(Nb * bs, *pool.shape[2:])
-    B = block_tables.shape[0]
-    idx = (block_tables[:, :, None] * bs
-           + jnp.arange(bs, dtype=jnp.int32)[None, None, :])
-    return flat[idx.reshape(B, -1)]
+    off-envelope or the mesh/SPMD context forbids a bare pallas_call. Only
+    the GATHERED rows are split back into heads."""
+    B, F = block_tables.shape[0], pool.shape[2]
+    return pool[block_tables].reshape(B, -1, kv_heads, F // kv_heads)
+
+
+def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
+                       mlp_fn=None):
+    """The ONE way the paged programs thread the pools through the layer
+    stack: as the scan's carry, viewed as [n_layer*num_blocks, bs, KV*Hd] (a
+    bitcast), with the layer index among the scanned inputs. Layer ``l``
+    lives at blocks ``[l*num_blocks, (l+1)*num_blocks)`` of that view, so
+    ``attn_fn(x_normed, lp_attn, kp, vp, block0, slot0)`` reads and writes
+    it through ``block_tables + block0`` / ``slots + slot0``: no per-layer
+    slice of a pool exists and nothing is stacked. Returns (x, new pools)."""
+    L, Nb, bs = pools["k"].shape[:3]
+
+    def run_block(carry, xs):
+        h, kp, vp = carry
+        lp, l = xs
+        h, kp, vp = _decode_block(
+            cfg, h, lp,
+            lambda xn: attn_fn(xn, lp["attn"], kp, vp, l * Nb, l * (Nb * bs)),
+            mlp_fn)
+        return (h, kp, vp), None
+
+    flat = {n: a.reshape(L * Nb, *a.shape[2:]) for n, a in pools.items()}
+    (x, kp, vp), _ = jax.lax.scan(
+        run_block, (x, flat["k"], flat["v"]),
+        (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+    return x, {"k": kp.reshape(pools["k"].shape),
+               "v": vp.reshape(pools["v"].shape)}
 
 
 def _paged_decode_attention(cfg: TransformerConfig, x, lp, positions, pos,
                             kp, vp, block_tables, pad_bias):
     """One fused decode step over all running requests against the paged
     pools: x [B, 1, D] (one new token per request), pos [B] per-request
-    cache depths, kp/vp [num_blocks, bs, KV, Hd], block_tables
-    [B, max_blocks]. Returns (out [B, 1, D], new kp, vp)."""
+    cache depths, kp/vp [blocks, bs, KV*Hd], block_tables [B, max_blocks].
+    Returns (out [B, 1, D], new kp, vp)."""
     B, T, D = x.shape
     H = cfg.n_head
     bs = kp.shape[1]
@@ -1184,11 +1222,11 @@ def _paged_decode_attention(cfg: TransformerConfig, x, lp, positions, pos,
         form = "gather_einsum"
         # gather + grouped einsum (the dense cache path's masked-softmax
         # core with per-request qpos) — partitionable, the CPU tier default
-        out = _grouped_cache_einsum(cfg, q, _paged_gather(kp, block_tables),
-                                    _paged_gather(vp, block_tables),
-                                    positions, pad_bias)
+        out = _grouped_cache_einsum(
+            cfg, q, _paged_gather(kp, block_tables, cfg.kv_heads),
+            _paged_gather(vp, block_tables, cfg.kv_heads), positions, pad_bias)
     dispatch.record("paged_decode", form,
-                    f"B={B} H={H} KV={kp.shape[2]} Hd={cfg.head_dim} bs={bs}")
+                    f"B={B} H={H} KV={cfg.kv_heads} Hd={cfg.head_dim} bs={bs}")
     out = out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
     return out, kp, vp
 
@@ -1200,12 +1238,12 @@ def _paged_prefill_attention(cfg: TransformerConfig, x, lp, positions,
     prompt's k/v scattered into the request's pool blocks. x [1, T, D];
     slots [T] flat pool slots (pad positions routed to the dummy block)."""
     B, T, D = x.shape
-    H, KV, Hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
+    H, Hd = cfg.n_head, cfg.head_dim
 
     q, k, v = _qkv_project(cfg, x, lp, positions)
 
-    kp = _pool_scatter(kp, k.reshape(T, KV, Hd), slots)
-    vp = _pool_scatter(vp, v.reshape(T, KV, Hd), slots)
+    kp = _pool_scatter(kp, k, slots)
+    vp = _pool_scatter(vp, v, slots)
 
     slopes = _alibi_slopes(H) if cfg.pos_embedding == "alibi" else None
     out = None
@@ -1237,20 +1275,18 @@ def _paged_chunk_attention(cfg: TransformerConfig, x, lp, positions,
     machinery the off-kernel paged decode uses, so numerics match it).
     x [1, T, D] (T the chunk bucket, pads routed to the dummy block);
     positions [1, T] global positions ``start + arange(T)``."""
-    B, T, D = x.shape
-    H, KV, Hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
-
+    KV = cfg.kv_heads
     q, k, v = _qkv_project(cfg, x, lp, positions)
 
-    kp = _pool_scatter(kp, k.reshape(T, KV, Hd), slots)
-    vp = _pool_scatter(vp, v.reshape(T, KV, Hd), slots)
+    kp = _pool_scatter(kp, k, slots)
+    vp = _pool_scatter(vp, v, slots)
 
     # gather the request's whole block table (static width) and let the
     # causal mask (kpos <= qpos) hide everything beyond the chunk's last
     # real token — unwritten tail blocks and dummy-mapped table slots all
     # sit at higher logical positions than any live query
-    out = _grouped_cache_einsum(cfg, q, _paged_gather(kp, block_tables),
-                                _paged_gather(vp, block_tables),
+    out = _grouped_cache_einsum(cfg, q, _paged_gather(kp, block_tables, KV),
+                                _paged_gather(vp, block_tables, KV),
                                 positions, None)
     out = out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
     return out, kp, vp
@@ -1278,21 +1314,15 @@ def forward_paged_prefill(cfg: TransformerConfig, params, tokens, pools,
     x, positions = cached_embed(cfg, params, tokens, jnp.int32(0),
                                 pools["k"].dtype)
 
-    def run_block(h, xs):
-        lp, kp, vp = xs
-        h, nkp, nvp = _decode_block(
-            cfg, h, lp,
-            lambda xn: _paged_prefill_attention(cfg, xn, lp["attn"], positions,
-                                                kp, vp, slots),
-            mlp_fn)
-        return h, (nkp, nvp)
-
-    x, (nk, nv) = jax.lax.scan(run_block, x,
-                               (params["layers"], pools["k"], pools["v"]))
+    x, pools = _scan_paged_layers(
+        cfg, params, pools, x,
+        lambda xn, lp, kp, vp, block0, slot0: _paged_prefill_attention(
+            cfg, xn, lp, positions, kp, vp, slots + slot0),
+        mlp_fn)
     # head on the sampled position only: the [1, vocab] projection, not
     # the whole bucket's [T, vocab]
     xl = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
-    return cached_head(cfg, params, xl)[:, 0, :], {"k": nk, "v": nv}
+    return cached_head(cfg, params, xl)[:, 0, :], pools
 
 
 def forward_paged_prefill_chunk(cfg: TransformerConfig, params, tokens,
@@ -1314,19 +1344,14 @@ def forward_paged_prefill_chunk(cfg: TransformerConfig, params, tokens,
     x, positions = cached_embed(cfg, params, tokens, start_pos,
                                 pools["k"].dtype)
 
-    def run_block(h, xs):
-        lp, kp, vp = xs
-        h, nkp, nvp = _decode_block(
-            cfg, h, lp,
-            lambda xn: _paged_chunk_attention(cfg, xn, lp["attn"], positions,
-                                              kp, vp, block_tables, slots),
-            mlp_fn)
-        return h, (nkp, nvp)
-
-    x, (nk, nv) = jax.lax.scan(run_block, x,
-                               (params["layers"], pools["k"], pools["v"]))
+    x, pools = _scan_paged_layers(
+        cfg, params, pools, x,
+        lambda xn, lp, kp, vp, block0, slot0: _paged_chunk_attention(
+            cfg, xn, lp, positions, kp, vp, block_tables + block0,
+            slots + slot0),
+        mlp_fn)
     xl = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
-    return cached_head(cfg, params, xl)[:, 0, :], {"k": nk, "v": nv}
+    return cached_head(cfg, params, xl)[:, 0, :], pools
 
 
 def copy_paged_block(pools, src, dst):
@@ -1398,14 +1423,14 @@ def _paged_verify_attention(cfg: TransformerConfig, x, lp, positions,
 
     # re-scattering already-written positions is idempotent (same values
     # to the same slots), so the off-envelope break above lands here clean
-    kp = _pool_scatter(kp, k.reshape(B * W, KV, Hd), slots.reshape(-1))
-    vp = _pool_scatter(vp, v.reshape(B * W, KV, Hd), slots.reshape(-1))
+    kp = _pool_scatter(kp, k, slots.reshape(-1))
+    vp = _pool_scatter(vp, v, slots.reshape(-1))
 
     # causal mask (kpos <= qpos) bounds each window query at its own
     # position: candidate t sees the cached context plus window tokens
     # <= t, junk pad queries see junk but nothing reads their logits
-    out = _grouped_cache_einsum(cfg, q, _paged_gather(kp, block_tables),
-                                _paged_gather(vp, block_tables),
+    out = _grouped_cache_einsum(cfg, q, _paged_gather(kp, block_tables, KV),
+                                _paged_gather(vp, block_tables, KV),
                                 positions, None)
     out = out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
     return out, kp, vp
@@ -1434,18 +1459,13 @@ def forward_paged_verify(cfg: TransformerConfig, params, tokens, pools,
     _check_paged_config(cfg)
     x, positions = cached_embed(cfg, params, tokens, pos, pools["k"].dtype)
 
-    def run_block(h, xs):
-        lp, kp, vp = xs
-        h, nkp, nvp = _decode_block(
-            cfg, h, lp,
-            lambda xn: _paged_verify_attention(cfg, xn, lp["attn"], positions,
-                                               kp, vp, block_tables, slots),
-            mlp_fn)
-        return h, (nkp, nvp)
-
-    x, (nk, nv) = jax.lax.scan(run_block, x,
-                               (params["layers"], pools["k"], pools["v"]))
-    return cached_head(cfg, params, x), {"k": nk, "v": nv}
+    x, pools = _scan_paged_layers(
+        cfg, params, pools, x,
+        lambda xn, lp, kp, vp, block0, slot0: _paged_verify_attention(
+            cfg, xn, lp, positions, kp, vp, block_tables + block0,
+            slots + slot0),
+        mlp_fn)
+    return cached_head(cfg, params, x), pools
 
 
 def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
@@ -1456,19 +1476,14 @@ def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
     _check_paged_config(cfg)
     x, positions = cached_embed(cfg, params, tokens, pos, pools["k"].dtype)
 
-    def run_block(h, xs):
-        lp, kp, vp = xs
-        h, nkp, nvp = _decode_block(
-            cfg, h, lp,
-            lambda xn: _paged_decode_attention(cfg, xn, lp["attn"], positions,
-                                               pos, kp, vp, block_tables,
-                                               pad_bias),
-            mlp_fn)
-        return h, (nkp, nvp)
-
-    x, (nk, nv) = jax.lax.scan(run_block, x,
-                               (params["layers"], pools["k"], pools["v"]))
-    return cached_head(cfg, params, x)[:, 0, :], {"k": nk, "v": nv}
+    # the decode step derives its write slots from the (layer-offset) table
+    x, pools = _scan_paged_layers(
+        cfg, params, pools, x,
+        lambda xn, lp, kp, vp, block0, slot0: _paged_decode_attention(
+            cfg, xn, lp, positions, pos, kp, vp, block_tables + block0,
+            pad_bias),
+        mlp_fn)
+    return cached_head(cfg, params, x)[:, 0, :], pools
 
 
 def run_layers(cfg: TransformerConfig, x, layer_params, positions, mask_bias,

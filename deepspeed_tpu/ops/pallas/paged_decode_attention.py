@@ -2,7 +2,9 @@
 
 The serving-side companion of :mod:`decode_attention` (vLLM PagedAttention
 re-expressed for TPU): the KV cache is not one contiguous ``[B, Smax, ...]``
-workspace but a POOL of fixed-size blocks ``[num_blocks, block_size, KV, Hd]``
+workspace but a POOL of fixed-size blocks ``[num_blocks, block_size, KV*Hd]``
+(a token's kv heads merged into one lane-dense row, which is the device's
+own row-major layout: the pool reaches the kernel's DMA without a copy)
 shared by every in-flight request, and each request owns a *block table* —
 the list of pool blocks holding its logical token positions. Continuous
 batching retires/admits requests per step, so physical KV placement is
@@ -17,6 +19,8 @@ Design (mirrors ``decode_attention``, which documents the TPU reasoning):
   turn the logical block index ``i`` into a pool block id — the gather
   happens in the DMA engine, never materialising a contiguous per-request
   cache copy;
+* a pool block lands in VMEM as ``[block_size, KV*Hd]``; kv head ``g`` is
+  the static lane slice ``[:, g*Hd:(g+1)*Hd]`` of it;
 * per-request positions: ``pos[b]`` is the 0-based position of request
   ``b``'s new token (attends ``kpos <= pos[b]``) — requests at different
   depths decode in the same fused step (iteration-level batching);
@@ -52,6 +56,7 @@ def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, bias_ref, slope_ref, o_ref,
     b = pl.program_id(0)
     i = pl.program_id(1)
     pos = pos_ref[b]
+    hd = q_ref.shape[3]
 
     @pl.when(i == 0)
     def _():
@@ -72,8 +77,9 @@ def _kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, bias_ref, slope_ref, o_ref,
         for g in range(kv):
             rows = pl.ds(g * group, group)
             q = q_ref[0, g].astype(jnp.float32)          # [P, Hd] (pre-scaled)
-            k = k_ref[0, :, g].astype(jnp.float32)       # [bs, Hd]
-            v = v_ref[0, :, g].astype(jnp.float32)       # [bs, Hd]
+            lanes = pl.ds(g * hd, hd)                    # kv head g's lanes
+            k = k_ref[0, :, lanes].astype(jnp.float32)   # [bs, Hd]
+            v = v_ref[0, :, lanes].astype(jnp.float32)   # [bs, Hd]
             s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32)
             kpos = jnp.broadcast_to(kpos1, s.shape)      # [P, bs]
@@ -112,12 +118,12 @@ def _paged_call(q, kp, vp, bt, pos, bias, slopes, *, bs, has_bias, has_alibi,
     # tail iterations revisit that pool block (no re-fetch — same index)
     # and the pl.when guard skips their FLOPs
     def kv_idx(b, i, bt_s, pos_s):
-        return (bt_s[b, jnp.minimum(i, pos_s[b] // bs)], 0, 0, 0)
+        return (bt_s[b, jnp.minimum(i, pos_s[b] // bs)], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, KV, P, Hd), lambda b, i, bt_s, pos_s: (b, 0, 0, 0)),
-        pl.BlockSpec((1, bs, KV, Hd), kv_idx),
-        pl.BlockSpec((1, bs, KV, Hd), kv_idx),
+        pl.BlockSpec((1, bs, KV * Hd), kv_idx),
+        pl.BlockSpec((1, bs, KV * Hd), kv_idx),
         # bias over LOGICAL positions rides [B, 1, n_blocks * bs] like the
         # dense kernel's (a sublane-1 block over a larger dim is not a legal
         # Mosaic block); the lane-block index follows the clamped logical
@@ -155,7 +161,8 @@ def paged_envelope_ok(H: int, KV: int, Hd: int, bs: int) -> bool:
     shard_map dispatch checks it against PER-SHARD shapes before entering a
     manual region (a shard_map body cannot fall back per-shard), and
     :func:`paged_decode_attention` checks it to decide None-vs-kernel."""
-    return H % KV == 0 and Hd % 64 == 0 and bs % 128 == 0
+    return (H % KV == 0 and Hd % 64 == 0 and (KV * Hd) % 128 == 0
+            and bs % 128 == 0)
 
 
 def paged_decode_attention(q, kp, vp, block_tables, pos, *, pad_bias=None,
@@ -164,8 +171,10 @@ def paged_decode_attention(q, kp, vp, block_tables, pos, *, pad_bias=None,
     """Attention of one new token per request against a PAGED KV cache.
 
     q ``[B, H, Hd]`` (one new token per running request, rope applied);
-    kp/vp ``[num_blocks, block_size, KV, Hd]`` — the shared block pools,
-    with each request's new k/v already written at its slot;
+    kp/vp ``[num_blocks, block_size, KV*Hd]`` — the shared block pools (a
+    token's kv heads merged, head ``g`` at lanes ``[g*Hd, (g+1)*Hd)``; KV
+    is read off ``kp.shape[2] // Hd``), with each request's new k/v already
+    written at its slot;
     ``block_tables`` ``[B, max_blocks]`` int32 pool block ids (logical block
     ``j`` of request ``b`` lives in pool block ``block_tables[b, j]``; dead
     tail entries may be anything — they are clamped away);
@@ -177,15 +186,17 @@ def paged_decode_attention(q, kp, vp, block_tables, pos, *, pad_bias=None,
 
     Returns None when the shape is outside the kernel's envelope (caller
     falls back to a gather + einsum path): block_size not a multiple of
-    128, head_dim not lane-aligned, or H % KV != 0.
+    128, head_dim not lane-aligned, a pool row ``KV*Hd`` that is not a
+    multiple of 128 lanes (MQA at Hd 64), or H % KV != 0.
     """
     B, H, Hd = q.shape
-    bs, KV = kp.shape[1], kp.shape[2]
+    bs, KV = kp.shape[1], kp.shape[2] // Hd
     if not paged_envelope_ok(H, KV, Hd, bs):
         warn_once(f"paged_decode_attention: heads={H} kv_heads={KV} "
                   f"head_dim={Hd} block_size={bs} is outside the kernel "
-                  "envelope (H % KV == 0, head_dim % 64 == 0, block_size % "
-                  "128 == 0); the caller takes its gather + einsum form")
+                  "envelope (H % KV == 0, head_dim % 64 == 0, kv_heads * "
+                  "head_dim % 128 == 0, block_size % 128 == 0); the caller "
+                  "takes its gather + einsum form")
         return None
     interpret = resolve_interpret("paged_decode_attention", interpret)
     P = H // KV

@@ -12,12 +12,14 @@ from deepspeed_tpu.ops.pallas.paged_decode_attention import \
     paged_decode_attention
 
 
-def random_paged_case(r, B, KV, Hd, bs, n_max, dtype=jnp.float32):
-    """Pools + per-request non-overlapping random block tables + positions."""
-    H = KV * int(r.choice([1, 2, 4]))
+def random_paged_case(r, B, KV, Hd, bs, n_max, dtype=jnp.float32, group=None):
+    """Pools ``[num_blocks, bs, KV*Hd]`` (the layout
+    ``init_paged_kv_cache`` gives one layer) + per-request non-overlapping
+    random block tables + positions."""
+    H = KV * (int(r.choice([1, 2, 4])) if group is None else group)
     num_blocks = B * n_max + 1
-    kp = jnp.asarray(r.normal(size=(num_blocks, bs, KV, Hd)), dtype)
-    vp = jnp.asarray(r.normal(size=(num_blocks, bs, KV, Hd)), dtype)
+    kp = jnp.asarray(r.normal(size=(num_blocks, bs, KV * Hd)), dtype)
+    vp = jnp.asarray(r.normal(size=(num_blocks, bs, KV * Hd)), dtype)
     q = jnp.asarray(r.normal(size=(B, H, Hd)), dtype)
     perm = r.permutation(num_blocks - 1) + 1  # dummy block 0 never mapped
     bt = jnp.asarray(perm[:B * n_max].reshape(B, n_max), jnp.int32)
@@ -25,13 +27,10 @@ def random_paged_case(r, B, KV, Hd, bs, n_max, dtype=jnp.float32):
     return q, kp, vp, bt, pos
 
 
-def gather_dense(pool, bt):
-    """Dense per-request cache via the block table (the reference layout
-    decode_attention expects)."""
-    Nb, bs = pool.shape[0], pool.shape[1]
-    flat = pool.reshape(Nb * bs, *pool.shape[2:])
-    idx = (bt[:, :, None] * bs + jnp.arange(bs)[None, None, :])
-    return flat[idx.reshape(bt.shape[0], -1)]
+def gather_dense(pool, bt, Hd):
+    """Dense per-request cache ``[B, S, KV, Hd]`` via the block table (the
+    reference layout decode_attention expects)."""
+    return pool[bt].reshape(bt.shape[0], -1, pool.shape[2] // Hd, Hd)
 
 
 @pytest.mark.parametrize("seed", range(4))
@@ -53,7 +52,7 @@ def test_paged_matches_dense_kernel(seed):
 
     out = paged_decode_attention(q, kp, vp, bt, pos, pad_bias=bias,
                                  alibi_slopes=slopes)
-    ck, cv = gather_dense(kp, bt), gather_dense(vp, bt)
+    ck, cv = gather_dense(kp, bt, q.shape[2]), gather_dense(vp, bt, q.shape[2])
     for b in range(B):
         want = decode_attention(
             q[b:b + 1], ck[b:b + 1], cv[b:b + 1], int(pos[b]),
@@ -63,13 +62,38 @@ def test_paged_matches_dense_kernel(seed):
         assert err < 1e-5, (seed, b, err)
 
 
+@pytest.mark.parametrize("KV,group,Hd", [
+    (2, 4, 128),    # KV < H at Hd 128: each kv head is one whole lane tile
+    (4, 1, 64),     # Hd 64: odd kv heads are the upper HALF of a lane tile
+])
+def test_paged_lane_slice_per_head(KV, group, Hd):
+    """The pool row holds a token's kv heads side by side; kv head ``g`` is
+    the lane slice ``[g*Hd, (g+1)*Hd)``. Every head's output is checked on
+    its own against the dense kernel fed that head's slice alone, so a
+    slice that read a neighbour's lanes could not hide in a max over heads
+    (at Hd 64 heads 1 and 3 start mid-tile)."""
+    r = np.random.default_rng(31 + Hd)
+    B, n_max = 2, 3
+    q, kp, vp, bt, pos = random_paged_case(r, B, KV, Hd, 128, n_max,
+                                           group=group)
+    out = paged_decode_attention(q, kp, vp, bt, pos)
+    ck, cv = gather_dense(kp, bt, Hd), gather_dense(vp, bt, Hd)
+    for b in range(B):
+        for g in range(KV):
+            heads = slice(g * group, (g + 1) * group)
+            want = decode_attention(q[b:b + 1, heads], ck[b:b + 1, :, g:g + 1],
+                                    cv[b:b + 1, :, g:g + 1], int(pos[b]))
+            err = float(jnp.abs(out[b, heads] - want[0]).max())
+            assert err < 1e-5, (b, g, err)
+
+
 def test_paged_bf16_pools():
     r = np.random.default_rng(9)
     q, kp, vp, bt, pos = random_paged_case(r, 2, 2, 64, 128, 3,
                                            dtype=jnp.bfloat16)
     out = paged_decode_attention(q, kp, vp, bt, pos)
     assert out.dtype == jnp.bfloat16
-    ck, cv = gather_dense(kp, bt), gather_dense(vp, bt)
+    ck, cv = gather_dense(kp, bt, q.shape[2]), gather_dense(vp, bt, q.shape[2])
     for b in range(2):
         want = decode_attention(q[b:b + 1].astype(jnp.float32),
                                 ck[b:b + 1].astype(jnp.float32),
@@ -85,7 +109,7 @@ def test_paged_per_request_positions_differ():
     q, kp, vp, bt, _ = random_paged_case(r, 3, 2, 64, 128, 4)
     pos = jnp.asarray([0, 200, 511], jnp.int32)
     out = paged_decode_attention(q, kp, vp, bt, pos)
-    ck, cv = gather_dense(kp, bt), gather_dense(vp, bt)
+    ck, cv = gather_dense(kp, bt, q.shape[2]), gather_dense(vp, bt, q.shape[2])
     for b in range(3):
         want = decode_attention(q[b:b + 1], ck[b:b + 1], cv[b:b + 1],
                                 int(pos[b]))
@@ -111,12 +135,16 @@ def test_paged_envelope_fallback():
     """Each envelope rejection independently returns None."""
     # block size not 128-aligned
     q = jnp.zeros((1, 4, 64), jnp.float32)
-    kp = jnp.zeros((3, 64, 4, 64), jnp.float32)
+    kp = jnp.zeros((3, 64, 4 * 64), jnp.float32)
     bt = jnp.zeros((1, 2), jnp.int32)
     assert paged_decode_attention(q, kp, kp, bt, jnp.zeros(1, jnp.int32)) is None
     # head dim not lane-aligned
     q = jnp.zeros((1, 4, 48), jnp.float32)
-    kp = jnp.zeros((3, 128, 4, 48), jnp.float32)
+    kp = jnp.zeros((3, 128, 4 * 48), jnp.float32)
+    assert paged_decode_attention(q, kp, kp, bt, jnp.zeros(1, jnp.int32)) is None
+    # a pool row that is not whole lane tiles (MQA at Hd 64)
+    q = jnp.zeros((1, 4, 64), jnp.float32)
+    kp = jnp.zeros((3, 128, 64), jnp.float32)
     assert paged_decode_attention(q, kp, kp, bt, jnp.zeros(1, jnp.int32)) is None
 
 
@@ -131,7 +159,7 @@ def test_paged_traced_pos_and_tables():
         return paged_decode_attention(q, kp, vp, bt, pos)
 
     out = f(bt, pos)
-    ck, cv = gather_dense(kp, bt), gather_dense(vp, bt)
+    ck, cv = gather_dense(kp, bt, q.shape[2]), gather_dense(vp, bt, q.shape[2])
     for b in range(2):
         want = decode_attention(q[b:b + 1], ck[b:b + 1], cv[b:b + 1],
                                 int(pos[b]))

@@ -1,0 +1,173 @@
+"""Guards that the KV pool keeps ONE buffer and ONE layout through a serving
+step (``models/transformer.py`` ``_scan_paged_layers``).
+
+(a) Structural, on the CPU: in the jaxpr of each ``forward_paged_*`` the
+pools are the layer scan's CARRY, viewed as ``[n_layer*num_blocks, bs,
+KV*Hd]``, and the scan has no input or output of a layer's pool size — so
+no per-layer slice is taken and nothing is stacked.
+
+(b) Compile fact, where XLA:TPU can be described without a device (as
+``perfbench/tools/aot_size.py`` does; skipped otherwise): decode and prefill
+of a two-layer model with head_dim 64 compiled for a described v5e keep
+their temporaries under a quarter of the pool and hold no ``copy``,
+``dynamic-slice`` or ``dynamic-update-slice`` of a layer's pool size. With
+the pool stored ``[.., KV, Hd=64]`` and scanned as inputs/outputs (before PR
+24) the same programs had six such copies and two pool-sized temporaries.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.models.causal_lm import CausalLM
+from deepspeed_tpu.models.transformer import TransformerConfig
+
+I32 = jnp.int32
+BS = 128
+
+
+def paged_programs(model, rows, width, tokens):
+    """name -> (fn(params, pools, *rest), shapes of rest): the four paged
+    programs with the argument shapes the serving engine gives them."""
+    n_max = -(-model.config.max_seq // BS)
+    return {
+        "prefill": (
+            lambda p, po, t, s, li: model.forward_paged_prefill(
+                p, t, po, s, li),
+            [((1, tokens), I32), ((tokens,), I32), ((), I32)]),
+        "prefill_chunk": (
+            lambda p, po, t, bt, s, sp, li: model.forward_paged_prefill_chunk(
+                p, t, po, bt, s, sp, li),
+            [((1, tokens), I32), ((1, n_max), I32), ((tokens,), I32),
+             ((), I32), ((), I32)]),
+        "verify": (
+            lambda p, po, t, bt, s, pos: model.forward_paged_verify(
+                p, t, po, bt, s, pos),
+            [((rows, width), I32), ((rows, n_max), I32),
+             ((rows, width), I32), ((rows,), I32)]),
+        "decode": (
+            lambda p, po, t, bt, pos: model.forward_paged_decode(
+                p, t, po, bt, pos),
+            [((rows, 1), I32), ((rows, n_max), I32), ((rows,), I32)]),
+    }
+
+
+# --------------------------------------------------------------------- #
+# (a) the jaxpr: pools in the carry, nothing pool-sized scanned
+
+TINY = dict(vocab_size=64, max_seq=256, n_layer=3, n_head=2, n_kv_head=2,
+            d_model=32, d_ff=64, remat=False)
+NUM_BLOCKS = 9
+
+
+def _scans(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+@pytest.mark.parametrize("name", ["prefill", "prefill_chunk", "verify",
+                                  "decode"])
+def test_pools_are_the_layer_scans_carry(name):
+    import deepspeed_tpu.comm as dist
+    dist.set_mesh(None)
+    model = CausalLM(TransformerConfig(**TINY))
+    cfg = model.config
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    pools = jax.eval_shape(
+        lambda: model.init_paged_cache(NUM_BLOCKS, BS, dtype=jnp.float32))
+    row = cfg.kv_heads * cfg.head_dim
+    assert pools["k"].shape == (cfg.n_layer, NUM_BLOCKS, BS, row)
+    fn, rest = paged_programs(model, rows=2, width=2, tokens=BS)[name]
+    jaxpr = jax.make_jaxpr(fn)(
+        params, pools, *[jax.ShapeDtypeStruct(s, d) for s, d in rest])
+
+    layer_scans = [e for e in _scans(jaxpr.jaxpr)
+                   if e.params["length"] == cfg.n_layer]
+    assert len(layer_scans) == 1, [str(e.primitive) for e in layer_scans]
+    eqn = layer_scans[0]
+    n_const, n_carry = eqn.params["num_consts"], eqn.params["num_carry"]
+    carry = [v.aval.shape for v in eqn.invars[n_const:n_const + n_carry]]
+    view = (cfg.n_layer * NUM_BLOCKS, BS, row)
+    assert carry.count(view) == 2, (name, carry)
+    # everything else the scan takes in or gives out is smaller than ONE
+    # layer's pool: no per-layer slice goes in, nothing is stacked
+    layer_pool = NUM_BLOCKS * BS * row
+    others = ([v.aval for v in eqn.invars[:n_const]]
+              + [v.aval for v in eqn.invars[n_const + n_carry:]]
+              + [v.aval for v in eqn.outvars[n_carry:]])
+    big = [a.shape for a in others if int(np.prod(a.shape)) >= layer_pool]
+    assert not big, (name, big)
+    out_pools = jax.eval_shape(fn, params, pools, *[
+        jax.ShapeDtypeStruct(s, d) for s, d in rest])[1]
+    assert out_pools["k"].shape == pools["k"].shape
+
+
+# --------------------------------------------------------------------- #
+# (b) the compile fact on a described v5e
+
+@pytest.fixture(scope="module")
+def one_v5e():
+    """A described (not attached) v5e chip's sharding. Only inside a test:
+    one process at a time may load libtpu, so nothing here may run while a
+    worker merely imports this file."""
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+    try:
+        from jax.experimental import topologies
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:1x1",
+            chips_per_host_bounds=(1, 1, 1), num_slices=1)
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: nothing to check
+        pytest.skip(f"no v5e can be described here: {e!r:.200}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("name", ["decode", "prefill"])
+def test_no_pool_sized_copy_compiled_for_v5e(name, one_v5e, monkeypatch):
+    import deepspeed_tpu.comm as dist
+    from deepspeed_tpu.ops import dispatch
+    dist.set_mesh(None)
+    sh = one_v5e
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+    # head_dim 64: half a lane tile, the case the device once stored
+    # transposed; the pool is larger than any weight or activation here
+    model = CausalLM(TransformerConfig(
+        vocab_size=512, max_seq=512, n_layer=2, n_head=4, n_kv_head=4,
+        d_model=256, d_ff=512, remat=False))
+    cfg = model.config
+    assert cfg.head_dim == 64
+    num_blocks = 16
+    params = jax.tree.map(lambda a: sds(a.shape, jnp.bfloat16),
+                          jax.eval_shape(model.init_params, jax.random.key(0)))
+    pools = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda: model.init_paged_cache(num_blocks, BS, dtype=jnp.bfloat16)))
+    fn, rest = paged_programs(model, rows=8, width=2, tokens=256)[name]
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, pools, *[sds(s, d) for s, d in rest]).compile()
+
+    pool_bytes = sum(int(np.prod(a.shape)) * 2 for a in jax.tree.leaves(pools))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, "the pools are not aliased"
+    assert mem.temp_size_in_bytes < pool_bytes / 4, (
+        mem.temp_size_in_bytes, pool_bytes)
+    layer_pool = num_blocks * BS * cfg.kv_heads * cfg.head_dim
+    moved = []
+    for m in re.finditer(
+            r"= \w+\[([\d,]+)\]\S* "
+            r"(copy|dynamic-slice|dynamic-update-slice)\(", compiled.as_text()):
+        if int(np.prod([int(d) for d in m.group(1).split(",")])) >= layer_pool:
+            moved.append(m.group(0))
+    assert not moved, moved

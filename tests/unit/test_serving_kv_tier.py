@@ -77,7 +77,7 @@ def keys_for(alloc, tokens):
     return keys
 
 
-SHAPE = (1, 4, 1, 1)        # [L, bs, KV, Hd] for the host-level tests
+SHAPE = (1, 4, 1)           # [L, bs, KV*Hd] for the host-level tests
 
 
 def slab(fill):
@@ -122,9 +122,9 @@ class TestKvHostPool:
         assert hp.remove(b"a") and not hp.remove(b"a")
         assert hp.nbytes == 0
         with pytest.raises(ValueError, match="geometry"):
-            hp.put(b"x", np.zeros((1, 8, 1, 1), np.float32),
-                   np.zeros((1, 8, 1, 1), np.float32))
-        assert not hp.matches_geometry((2, 4, 1, 1), "float32")
+            hp.put(b"x", np.zeros((1, 8, 1), np.float32),
+                   np.zeros((1, 8, 1), np.float32))
+        assert not hp.matches_geometry((2, 4, 1), "float32")
         assert hp.matches_geometry(SHAPE, "float32")
 
     def test_spill_fault_degrades_to_noop(self):

@@ -294,7 +294,7 @@ def _einsum_reference(q, kp, vp, bt, pos, scale):
     """Replicated numpy softmax-attention reference through the block
     tables — independent of both the kernel and the jax einsum core."""
     B, H, Hd = q.shape
-    bs, KV = kp.shape[1], kp.shape[2]
+    KV = kp.shape[2] // Hd          # pools are [blocks, bs, KV*Hd]
     G = H // KV
     out = np.zeros_like(q, dtype=np.float32)
     for b in range(B):
@@ -324,10 +324,11 @@ class TestShardedKernelPath:
 
         mesh = Mesh(np.array(devices[:8]).reshape(4, 2), ("dp", "tp"))
         rng = np.random.default_rng(0)
-        B, H, KV, Hd, bs, NB, nmax = 3, 4, 2, 64, 128, 7, 3
+        # per shard: 2 kv heads of 64 = one whole 128-lane pool row
+        B, H, KV, Hd, bs, NB, nmax = 3, 8, 4, 64, 128, 7, 3
         q = rng.standard_normal((B, H, Hd)).astype(np.float32)
-        kp = rng.standard_normal((NB, bs, KV, Hd)).astype(np.float32)
-        vp = rng.standard_normal((NB, bs, KV, Hd)).astype(np.float32)
+        kp = rng.standard_normal((NB, bs, KV * Hd)).astype(np.float32)
+        vp = rng.standard_normal((NB, bs, KV * Hd)).astype(np.float32)
         bt = np.stack([rng.permutation(np.arange(1, NB))[:nmax]
                        for _ in range(B)]).astype(np.int32)
         pos = np.asarray([37, 200, 129], np.int32)
@@ -347,7 +348,9 @@ class TestShardedKernelPath:
         from deepspeed_tpu.models.transformer import _paged_shard_ok
 
         mesh = Mesh(np.array(devices[:8]).reshape(4, 2), ("dp", "tp"))
-        assert _paged_shard_ok(mesh, 4, 2, 64, 128)
+        assert _paged_shard_ok(mesh, 8, 4, 64, 128)
+        assert _paged_shard_ok(mesh, 4, 2, 128, 128)
+        assert not _paged_shard_ok(mesh, 4, 2, 64, 128)   # shard row 64 lanes
         assert not _paged_shard_ok(mesh, 4, 3, 64, 128)   # KV % tp
         assert not _paged_shard_ok(mesh, 5, 2, 64, 128)   # H % tp
         assert not _paged_shard_ok(mesh, 4, 2, 32, 128)   # Hd % 64
@@ -370,8 +373,9 @@ class TestShardedKernelPath:
 
         monkeypatch.setattr(pda, "paged_decode_attention", counting)
 
-        kw = dict(vocab_size=64, n_layer=1, n_head=4, n_kv_head=2,
-                  d_model=256, d_ff=128, max_seq=256, remat=False)
+        # each tp shard holds 2 kv heads of 64: a whole 128-lane pool row
+        kw = dict(vocab_size=64, n_layer=1, n_head=8, n_kv_head=4,
+                  d_model=512, d_ff=128, max_seq=256, remat=False)
         m_ref = CausalLM(TransformerConfig(**kw, attention_backend="auto"))
         params = m_ref.init_params(jax.random.key(0))
         prompts = _prompts((9, 14), seed=1)

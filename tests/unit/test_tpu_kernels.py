@@ -323,7 +323,7 @@ def _paged_reference(q, kp, vp, bt, pos, pad_bias=None, slopes=None):
     import jax
     import jax.numpy as jnp
     B, H, Hd = q.shape
-    bs, KV = kp.shape[1], kp.shape[2]
+    bs, KV = kp.shape[1], kp.shape[2] // Hd     # pools [blocks, bs, KV*Hd]
     S = bt.shape[1] * bs
     k = kp[bt].reshape(B, S, KV, Hd).astype(jnp.float32)
     v = vp[bt].reshape(B, S, KV, Hd).astype(jnp.float32)
@@ -342,6 +342,7 @@ def _paged_reference(q, kp, vp, bt, pos, pad_bias=None, slopes=None):
 
 @tpu_tier
 @pytest.mark.parametrize("H,KV,Hd", [(12, 12, 64),     # GPT-2: MHA, group 1
+                                      (32, 32, 64),     # OPT-1.3B: 2,048-lane rows
                                       (12, 4, 128)])    # GQA, group 3
 @pytest.mark.parametrize("with_bias,with_alibi", [(False, False), (True, False),
                                                   (False, True), (True, True)])
@@ -366,8 +367,8 @@ def test_paged_decode_attention_compiles_and_matches(tpu, H, KV, Hd,
     for b in range(B):      # dead tail entries may be anything in range
         bt[b, pos[b] // bs + 1:] = rng.integers(0, num_blocks)
     q = jnp.asarray(rng.normal(size=(B, H, Hd)), jnp.bfloat16)
-    kp = jnp.asarray(rng.normal(size=(num_blocks, bs, KV, Hd)), jnp.bfloat16)
-    vp = jnp.asarray(rng.normal(size=(num_blocks, bs, KV, Hd)), jnp.bfloat16)
+    kp = jnp.asarray(rng.normal(size=(num_blocks, bs, KV * Hd)), jnp.bfloat16)
+    vp = jnp.asarray(rng.normal(size=(num_blocks, bs, KV * Hd)), jnp.bfloat16)
     pad = None
     if with_bias:
         pad = np.zeros((B, n_max * bs), np.float32)
@@ -404,10 +405,10 @@ def test_paged_matches_dense_decode_kernel(tpu):
     cv = jnp.asarray(rng.normal(size=(B, n_max * bs, KV, Hd)), jnp.bfloat16)
     q = jnp.asarray(rng.normal(size=(B, H, Hd)), jnp.bfloat16)
     bt = rng.permutation(np.arange(1, 1 + B * n_max)).reshape(B, n_max)
-    kp = jnp.zeros((1 + B * n_max, bs, KV, Hd), jnp.bfloat16)
+    kp = jnp.zeros((1 + B * n_max, bs, KV * Hd), jnp.bfloat16)
     vp = jnp.zeros_like(kp)
-    kp = kp.at[bt.reshape(-1)].set(ck.reshape(B * n_max, bs, KV, Hd))
-    vp = vp.at[bt.reshape(-1)].set(cv.reshape(B * n_max, bs, KV, Hd))
+    kp = kp.at[bt.reshape(-1)].set(ck.reshape(B * n_max, bs, KV * Hd))
+    vp = vp.at[bt.reshape(-1)].set(cv.reshape(B * n_max, bs, KV * Hd))
 
     dense = decode_attention(q, ck, cv, pos, interpret=False)
     paged = paged_decode_attention(q, kp, vp, jnp.asarray(bt, jnp.int32),
