@@ -1,5 +1,22 @@
 """The comparison that decides ``correct``: the system against the plain
-reference (``reference/dense_decoder.py``) on the program's own weights.
+reference of its configuration on the program's own weights.
+
+The reference is a module under ``reference/``, named by the configuration's
+map (``reference/maps/<config>.json`` ``"reference"``; ``dense_decoder``
+where the map names none). What is asked of it, and all that is:
+
+* ``final_hidden(cfg, weights, tokens)`` — tokens [B, S] -> the hidden state
+  the head reads, [B, S, D];
+* ``logits_rows(cfg, weights, h_rows)`` — [N, D] of those rows -> logits
+  [N, V]: the head is the reference's business, tied or not;
+* ``next_token_loss(cfg, weights, tokens)`` — the loss the model trains on as
+  a float, every auxiliary term included and named in the module's docstring;
+* optionally ``Weights``, its own view of the program's parameter tree, for
+  a stack whose layers are not one leading axis (``Weights`` below serves
+  every stack that is).
+
+``cfg`` is ``reference_config`` of the configuration file and the map. One
+set of tolerances holds for every reference.
 
 Tolerances, with their reasons:
 
@@ -21,6 +38,7 @@ Tolerances, with their reasons:
 
 from __future__ import annotations
 
+import importlib
 import json
 import math
 import os
@@ -28,8 +46,6 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from reference import dense_decoder as ref
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 TRAIN_LOSS_TOL = 2e-3
@@ -39,6 +55,12 @@ SERVE_ULPS = 4
 def load_map(config_name: str) -> dict:
     with open(os.path.join(HERE, "reference", "maps", f"{config_name}.json")) as f:
         return json.load(f)
+
+
+def load_reference(name_map: dict):
+    """The map's reference module, imported when a check first needs it."""
+    return importlib.import_module(
+        "reference." + name_map.get("reference", "dense_decoder"))
 
 
 def reference_config(config: dict, name_map: dict) -> dict:
@@ -78,8 +100,19 @@ class Weights:
                 for k, p in self.map["layer"].items()}
 
 
+def _reference_of(weights: Weights):
+    """The reference the weights' map names, and the weights in that
+    reference's own view where it has one."""
+    ref = load_reference(weights.map)
+    view = getattr(ref, "Weights", Weights)
+    if type(weights) is not view:
+        weights = view(weights.params, weights.map, weights.device)
+    return ref, weights
+
+
 def check_train(engine_loss: float, cfg: dict, weights: Weights,
                 tokens: np.ndarray) -> dict:
+    ref, weights = _reference_of(weights)
     want = ref.next_token_loss(cfg, weights, jnp.asarray(tokens))
     diff = abs(engine_loss - want)
     return {"ok": bool(math.isfinite(want) and diff <= TRAIN_LOSS_TOL),
@@ -92,10 +125,11 @@ def check_served(cfg: dict, weights: Weights, prompt: np.ndarray,
     """Teacher-forced: the reference runs prompt + served tokens once; at
     each served position the served token's logit is compared with the
     reference's largest."""
+    ref, weights = _reference_of(weights)
     seq = np.concatenate([prompt, np.asarray(served, np.int32)])[None, :]
     h = ref.final_hidden(cfg, weights, jnp.asarray(seq))
     rows = h[0, len(prompt) - 1: len(prompt) - 1 + len(served)]
-    logits = np.asarray(ref.logits_rows(weights, rows), np.float32)
+    logits = np.asarray(ref.logits_rows(cfg, weights, rows), np.float32)
     top = logits.max(axis=-1)
     got = logits[np.arange(len(served)), np.asarray(served)]
     step = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(top), 1e-30))) - 7)

@@ -1,7 +1,10 @@
 """A ratio of sums of the PROGRAM's counters (the metrics registry), each
 taken as its growth over the window. ``num`` and ``den`` map counter names to
 weights; ``den_times`` multiplies the denominator by a fact of the run
-(``rows``: the fused decode width); ``percent`` scales by 100."""
+(``rows``: the fused decode width); ``percent`` scales by 100; ``require``
+lists counters that have to exist for there to be a reading at all."""
+
+from ._common import window_counters
 
 
 def _sum(weights, start, end):
@@ -10,10 +13,10 @@ def _sum(weights, start, end):
 
 
 def read(params, facts):
-    marks = facts["window"].get("marks")
-    if not marks or "start" not in marks:
+    marks = window_counters(facts, params.get("require", ()))
+    if marks is None:
         return None
-    start, end = marks["start"]["counters"], marks["end"]["counters"]
+    start, end = marks
     den = _sum(params["den"], start, end)
     if params.get("den_times"):
         den *= facts["window"][params["den_times"]]

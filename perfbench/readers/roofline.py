@@ -1,13 +1,14 @@
 """A kernel family's share of its roofline, in %: the least time the chip
 could take for the calls that were made over the device time they took.
-``kernels`` maps a regex over op names to a cost function of ``costs.py``;
-each call is taken to cover one chip's micro-batch (``facts["shapes"]``).
-Which roof bounds each kernel goes on a detail line."""
+``kernels`` maps a regex over op names to a cost function, ``<function>`` of
+``costs.py`` or ``<module>:<function>`` of a module beside it; each call is
+taken to cover one chip's micro-batch (``facts["shapes"]``). Which roof
+bounds each kernel goes on a detail line."""
 
 import costs
 import trace_reduce
 
-from ._common import device_of
+from ._common import cost_function, device_of
 
 
 def read(params, facts):
@@ -19,8 +20,8 @@ def read(params, facts):
         secs, calls = trace_reduce.matching(dev["ops"], pattern)
         if not calls:
             continue
-        t, roof = costs.roofline_seconds(getattr(costs, fn)(facts["shapes"]),
-                                         facts["peak"])
+        t, roof = costs.roofline_seconds(
+            cost_function(fn)(facts["shapes"]), facts["peak"])
         print(f"[perfbench] roofline {fn}: {calls} calls, "
               f"{secs / calls * 1e3:.3f} ms a call against {t * 1e3:.3f} ms "
               f"({roof}-bound)", flush=True)

@@ -113,7 +113,7 @@ def final_hidden(cfg, weights, tokens):
             x, top["lnf_g"], top["lnf_b"])
 
 
-def logits_rows(weights, h_rows):
+def logits_rows(cfg, weights, h_rows):
     """h_rows [N, D] -> logits [N, V] through the tied head."""
     with jax.default_matmul_precision("highest"):
         return jax.jit(lambda h, e: h @ e.T)(h_rows, weights.top()["wte"])

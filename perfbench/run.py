@@ -8,9 +8,10 @@ per-layer metrics (``perfbench/layer_metrics/``) and their readers
 (``perfbench/readers/``) by name, runs the cell through the train or the
 serve runner, and prints detail lines followed by the contract's one JSON
 object as the LAST line of stdout. No TPU (or fewer chips than the cell asks
-for): non-zero exit and no result line. ``--rehearse`` swaps in the toy
-configuration and the CPU backend for debugging; it prints ``platform=cpu``
-and never a device metric.
+for): non-zero exit and no result line. ``--rehearse`` swaps in a toy
+configuration (the one the cell's configuration names, else the dense toy)
+and the CPU backend for debugging; it prints ``platform=cpu`` and never a
+device metric.
 
 A run that cannot give a result prints none and says where it died through
 its exit code, because the code may be all of a refusal that reaches the next
@@ -95,14 +96,21 @@ def layer_metrics_for(manifest, cell_name):
 
 
 def model_dims(config, name_map):
+    """The sizes the cost functions and the readers see: the reference's
+    configuration under its own names. The three an architecture may leave
+    to convention default from the others; every other size a map declares
+    (``n_experts``, ``experts_per_token``, ``d_expert``, ``window``... any
+    whole number under ``from_config`` or ``fixed``) passes through."""
     import correctness
     ref = correctness.reference_config(config, name_map)
-    return {"d_model": ref["d_model"], "n_layer": ref["n_layer"],
-            "n_head": ref["n_head"], "n_kv_head": ref["n_head"],
-            "head_dim": ref["d_model"] // ref["n_head"], "d_ff": ref["d_ff"],
-            "vocab": config["vocab_size"], "positions": ref["positions"],
+    dims = {"n_kv_head": ref["n_head"],
+            "head_dim": ref["d_model"] // ref["n_head"],
+            "vocab": config["vocab_size"], "positions": ref.get("positions"),
             "max_seq": config[name_map["max_seq_key"]],
             "embed_layernorm": bool(ref.get("embed_layernorm"))}
+    dims.update({k: v for k, v in ref.items()
+                 if isinstance(v, int) and not isinstance(v, bool)})
+    return dims
 
 
 class CompileCounter:
@@ -140,10 +148,12 @@ def main(argv=None):
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--manifest", default=os.path.join(ROOT, "BENCHMARK.json"))
-    ap.add_argument("--rehearse", nargs="?", const="rehearsal-tiny",
-                    default=None, metavar="CONFIG",
-                    help="CPU backend, toy configuration: proves nothing "
-                         "about the chip")
+    ap.add_argument("--rehearse", nargs="?", const="", default=None,
+                    metavar="CONFIG",
+                    help="CPU backend, toy configuration (given, else the "
+                         "one the cell's configuration names as its "
+                         "``rehearsal``, else rehearsal-tiny): proves "
+                         "nothing about the chip")
     ap.add_argument("--keep-trace", action="store_true",
                     help="leave the .xplane.pb and its reduction under "
                          ".perfbench_out/ for inspection")
@@ -154,16 +164,19 @@ def main(argv=None):
     manifest = load_json(args.manifest)
     cell, conf = find_cell(manifest, args.workload)
     chips = int(cell["chips"])
-    if args.rehearse:
+    config_name = conf["name"]
+    config = load_json(ROOT, conf["file"])
+    rehearsing = args.rehearse is not None
+    if rehearsing:
         os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={chips}")
-        config_name = args.rehearse
+        # a toy of the cell's own architecture, where its configuration
+        # names one
+        config_name = args.rehearse or config.get("rehearsal",
+                                                  "rehearsal-tiny")
         config = load_json(HERE, "configs", config_name + ".json")
-    else:
-        config_name = conf["name"]
-        config = load_json(ROOT, conf["file"])
 
     import jax
 
@@ -171,7 +184,7 @@ def main(argv=None):
     from deepspeed_tpu.ops import dispatch
     from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
-    if args.rehearse:
+    if rehearsing:
         d0 = jax.devices()[0]
         dev = {"platform": d0.platform, "kind": d0.device_kind,
                "count": len(jax.devices())}
@@ -187,15 +200,15 @@ def main(argv=None):
                             f"{dev['count']}")
     say(f"{args.workload}: config {config_name}, traffic {cell['traffic']}, "
         f"{chips} of {dev['count']} x {dev['kind']} (platform="
-        f"{dev['platform']})" + ("  *** REHEARSAL ***" if args.rehearse else ""))
+        f"{dev['platform']})" + ("  *** REHEARSAL ***" if rehearsing else ""))
 
     peaks = load_json(HERE, "peaks.json")["device_kinds"]
-    if not args.rehearse and dev["kind"] not in peaks:
+    if not rehearsing and dev["kind"] not in peaks:
         die(EXIT_NO_DEVICE, f"no peaks for device kind {dev['kind']!r}")
     peak = peaks.get(dev["kind"])
 
     compiles = CompileCounter()
-    if not args.rehearse:
+    if not rehearsing:
         cache_dir = enable_compile_cache()
         # every program, however quick to compile, comes from the cache in
         # the second run of a cell
@@ -234,6 +247,7 @@ def main(argv=None):
     dims = model_dims(config, name_map)
     facts = {
         "cell": cell, "chips": chips, "peak": peak, "dims": dims,
+        "map": name_map,
         "shapes": runner.shapes(dims), "window": window,
         "bench": {"setup_s": setup_s,
                   "compile_cache_misses": at_setup["cache_misses"],
@@ -268,6 +282,10 @@ def main(argv=None):
                 device["busy_s"] = sum(busy) / len(busy)
                 device["window_s"] = hi - lo
                 breakdown = trace_reduce.breakdown(loaded)
+                busiest = loaded["devices"][trace_reduce.busiest_device(loaded)]
+                say("largest device ops [name, self s, calls, scope]: "
+                    + json.dumps(trace_reduce.largest_ops(
+                        busiest["ops"] or busiest["programs"])))
         if args.keep_trace:
             with open(os.path.join(out_dir, "trace_reduced.json"), "w") as f:
                 json.dump({k: loaded[k] for k in ("devices", "host")}, f)
@@ -311,7 +329,7 @@ def main(argv=None):
         traceback.print_exc()
         say("closing the runner raised; the result stands")
 
-    if args.rehearse:
+    if rehearsing:
         print(json.dumps({"rehearsal": True, "platform": dev["platform"],
                           "correct": correct,
                           "attempted": window["attempted"],

@@ -33,6 +33,7 @@ GENERATOR_LATE_LIMIT_MS = 25.0
 LADDER = np.geomspace(0.01, 1e7, 600)
 
 
+
 class _Rec:
     __slots__ = ("index", "due", "sent", "stamps", "counts", "status",
                  "max_new", "n_prompt", "error")
@@ -198,11 +199,15 @@ class ServeRunner:
             th.start()
 
         t0 = time.perf_counter() + ramp
+        self._stall = (0.0, 0.0)         # (longest hold-up s, from s)
+        self._ticked = time.perf_counter()
         if spec["loop"] == "open":
             late = self._open_loop(t0, seconds, ramp, on_clock)
         else:
             late = self._closed_loop(t0, seconds, on_clock)
         marks["end"] = self._snapshot()
+        self.say(f"load thread: longest hold-up {self._stall[0] * 1e3:.1f} ms "
+                 f"from {self._stall[1]:.2f} s")
         t_close = t0 + seconds
         with self._lock:
             self._closing = True         # each request cuts itself from now
@@ -227,6 +232,19 @@ class ServeRunner:
         facts["trace_host_window"] = (trace.started_at, trace.stopped_at)
         return facts
 
+    def _tick(self, t0, dt, on_clock):
+        """Sleep ``dt`` on the thread that drives the load and keep its
+        longest hold-up since the tick before (an oversleep, or a thread
+        that would not start): that thread only sleeps and starts threads,
+        so what holds it up held the whole process up."""
+        time.sleep(dt)
+        now = time.perf_counter()
+        over = now - self._ticked - dt
+        if over > self._stall[0]:
+            self._stall = (over, now - over - t0)
+        on_clock(t0)
+        self._ticked = time.perf_counter()
+
     def _open_loop(self, t0, seconds, ramp, on_clock):
         """Each request gets a thread of its own when it is due, which sends
         it and reads its stream: a send the program holds up delays no other
@@ -240,16 +258,14 @@ class ServeRunner:
                 wait = t0 + a - time.perf_counter()
                 if wait <= 0:
                     break
-                time.sleep(min(wait, 0.02))
-                on_clock(t0)
+                self._tick(t0, min(wait, 0.02), on_clock)
             late.append((a, -wait * 1e3))
             th = threading.Thread(target=self._request, args=(req, t0 + a),
                                   daemon=True)
             th.start()
             self._threads.append(th)
         while time.perf_counter() < t0 + seconds:
-            time.sleep(0.005)
-            on_clock(t0)
+            self._tick(t0, 0.005, on_clock)
         on_clock(t0)
         self.say(f"generator: {len(plan)} requests; latest (ms late, due s): "
                  f"{[(round(l, 2), round(a, 2)) for a, l in sorted(late, key=lambda x: -x[1])[:3]]}")
@@ -271,8 +287,7 @@ class ServeRunner:
         for c in clients:
             c.start()
         while time.perf_counter() < t0 + seconds:
-            time.sleep(0.005)
-            on_clock(t0)
+            self._tick(t0, 0.005, on_clock)
         on_clock(t0)
         stop.set()
         self._threads = clients
@@ -400,9 +415,9 @@ class ServeRunner:
                                "error": h.error or raised})
                 continue
             served.append(correctness.check_served(cfg, weights, prompt, toks))
-        ok = (not faults and w["failed"] == 0 and w["attempted"] > 0
-              and late_p90 <= GENERATOR_LATE_LIMIT_MS
-              and code == 200 and all(s["ok"] for s in served))
+        ok = bool(not faults and w["failed"] == 0 and w["attempted"] > 0
+                  and late_p90 <= GENERATOR_LATE_LIMIT_MS
+                  and code == 200 and all(s["ok"] for s in served))
         return {"ok": ok, "faults": faults, "failed": w["failed"],
                 "generator_late_p90_ms": float(late_p90),
                 "generator_late_p99_ms": float(late_p99),

@@ -94,7 +94,7 @@ def test_reference_against_the_hand_written_case(style):
     w = Weights(rng, style["positions"])
     tokens = rng.integers(0, V, size=(2, S))
     h = ref.final_hidden(cfg, w, jnp.asarray(tokens))
-    got = np.asarray(ref.logits_rows(w, h.reshape(-1, D))).reshape(2, S, V)
+    got = np.asarray(ref.logits_rows(cfg, w, h.reshape(-1, D))).reshape(2, S, V)
     want = np.stack([by_hand(cfg, w, t) for t in tokens])
     np.testing.assert_allclose(got, want, atol=2e-4, rtol=2e-4)
     # the loss in vocabulary blocks (a block of 4 does not divide 11)
@@ -109,3 +109,24 @@ def test_alibi_slopes_for_sixteen_heads():
     assert s[0] == pytest.approx(2 ** -0.5) and s[-1] == pytest.approx(2 ** -8)
     with pytest.raises(ValueError):
         ref.alibi_slopes(12)
+
+
+def test_the_map_names_the_reference_and_its_view_of_the_weights(monkeypatch):
+    import correctness
+    from reference import parallel_rope_decoder
+
+    assert correctness.load_reference({}) is ref
+    named = {"reference": "parallel_rope_decoder"}
+    assert correctness.load_reference(named) is parallel_rope_decoder
+    plain = correctness.Weights({}, {}, device="cpu:0")
+    assert correctness._reference_of(plain) == (ref, plain)
+
+    class Own(correctness.Weights):
+        """a stack whose layers are not one leading axis brings its own"""
+
+    monkeypatch.setattr(parallel_rope_decoder, "Weights", Own, raising=False)
+    params = {"layers": {}}
+    module, view = correctness._reference_of(
+        correctness.Weights(params, named, device="cpu:0"))
+    assert module is parallel_rope_decoder and type(view) is Own
+    assert (view.params, view.map, view.device) == (params, named, "cpu:0")

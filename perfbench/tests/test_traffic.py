@@ -7,8 +7,14 @@ import pytest
 import traffic
 
 
-def mixed(seed, scale=1.0):
-    return traffic.ServeTraffic(traffic.load("open_mixed"), 50272, seed, scale)
+def mixed(seed, scale=1.0, plan_seed=None):
+    """The mixed cell's traffic, its own sample path by the seed unless a
+    ``plan_seed`` is given (the file names one; ``test_plan_seed...``)."""
+    spec = dict(traffic.load("open_mixed"))
+    spec["arrivals"] = {"dist": "poisson", "rate_per_s": 1.6}
+    if plan_seed is not None:
+        spec["arrivals"]["plan_seed"] = plan_seed
+    return traffic.ServeTraffic(spec, 50272, seed, scale)
 
 
 def test_requests_are_a_function_of_seed_and_index():
@@ -59,6 +65,27 @@ def test_open_plan_is_fixed_work_arranged_by_the_seed():
     assert [r["due"] for r in a] != [r["due"] for r in c]
     assert all(32 <= len(r["prompt"]) <= 1792 and 16 <= r["max_new"] <= 256
                for r in a)
+
+
+def test_plan_seed_fixes_times_and_lengths_and_leaves_tokens_to_the_seed():
+    shape = lambda plan: [(r["due"], r["cls"], len(r["prompt"]), r["max_new"])
+                          for r in plan]
+    own = mixed(2500000501).open_plan(3.0, 51.0)
+    a = mixed(9, plan_seed=2500000501).open_plan(3.0, 51.0)
+    b = mixed(2500000777, plan_seed=2500000501).open_plan(3.0, 51.0)
+    # the plan is that seed's own sample path, whatever --seed is
+    assert shape(a) == shape(b) == shape(own)
+    assert sum(r["due"] >= 0 for r in a) == 82
+    # the seed still draws the tokens, and the same seed the same tokens
+    assert not any(np.array_equal(x["prompt"], y["prompt"]) for x, y in zip(a, b))
+    again = mixed(9, plan_seed=2500000501).open_plan(3.0, 51.0)
+    assert all(np.array_equal(x["prompt"], y["prompt"]) for x, y in zip(a, again))
+    # the cell's file names one: two seeds offer the same requests
+    spec = traffic.load("open_mixed")
+    assert spec["arrivals"]["plan_seed"] == 2500000501
+    c, d = (traffic.ServeTraffic(spec, 50272, s).open_plan(3.0, 51.0)
+            for s in (1, 2))
+    assert shape(c) == shape(d) == shape(own)
 
 
 def test_arrivals_are_a_poisson_process_given_its_count():

@@ -109,7 +109,10 @@ def main():
         os.makedirs(args.out, exist_ok=True)
 
     def one(rate, seed, seconds, tag):
-        trial = {**spec, "arrivals": {**spec["arrivals"], "rate_per_s": rate}}
+        # a sweep is over the seeds' own sample paths: a cell's fixed plan
+        # (``plan_seed``) is set aside here
+        trial = {**spec, "arrivals": {"dist": spec["arrivals"]["dist"],
+                                      "rate_per_s": rate}}
         runner.spec, runner.seed = trial, seed
         runner.traffic = traffic_mod.ServeTraffic(trial, vocab, seed, scale)
         w = runner.window(seconds, MidWindowTrace(False, None))

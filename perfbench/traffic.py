@@ -7,7 +7,8 @@ Train files (``"kind": "train"``): ``seq``, ``micro_batch_per_chip``,
 ``sync_every``, ``warmup_steps``, ``trace_steps``, ``eval_sequences``.
 
 Serve files (``"kind": "serve"``): ``loop`` (``open`` | ``closed``),
-``arrivals`` (open: ``{"dist": "poisson", "rate_per_s"}``), ``clients_per_row``
+``arrivals`` (open: ``{"dist": "poisson", "rate_per_s"}`` and optionally
+``"plan_seed"``: see ``ServeTraffic.open_plan``), ``clients_per_row``
 (closed: clients = that x ``max_running``), ``ramp_s`` (load offered before
 the window opens, not measured), ``classes`` (each ``share``, ``prompt`` and
 ``answer`` length distributions), ``trace_seconds``, ``drain_s`` (the most the
@@ -153,13 +154,23 @@ class ServeTraffic:
         bursts and lulls fall where the seed puts them. Each class has its
         share of the requests and its lengths at evenly spread quantiles of
         their distributions, dealt out in an order of the seed. Two seeds
-        thus offer the same work under another sample path."""
+        thus offer the same work under another sample path.
+
+        Where a window holds too few requests for two sample paths to read
+        alike (some eighty: a tail then reads where the seed bunched them),
+        ``arrivals`` names a ``plan_seed``: times, classes and lengths are
+        drawn from it, the same for every ``--seed``, and the seed draws only
+        the prompts' tokens."""
         rate = float(self.spec["arrivals"]["rate_per_s"])
         if self.spec["arrivals"]["dist"] != "poisson":
             raise ValueError("arrivals: only poisson is known")
+        plan_seed = self.spec["arrivals"].get("plan_seed")
         out = []
         for part, (t0, span) in enumerate(((-ramp_s, ramp_s), (0.0, seconds))):
-            rng = np.random.default_rng([self.seed, 4, part])
+            rng = np.random.default_rng(
+                [self.seed if plan_seed is None else int(plan_seed), 4, part])
+            tokens = rng if plan_seed is None else \
+                np.random.default_rng([self.seed, 5, part])
             n = int(round(rate * span))
             quota = np.floor(self.shares * n).astype(int)
             for i in np.argsort(-(self.shares * n - quota))[: n - quota.sum()]:
@@ -176,6 +187,6 @@ class ServeTraffic:
                 j = taken[ci]
                 taken[ci] += 1
                 out.append({**self._request(len(out), ci, lengths[ci][0][j],
-                                            lengths[ci][1][j], rng),
+                                            lengths[ci][1][j], tokens),
                             "due": float(due[k])})
         return out
