@@ -1025,7 +1025,10 @@ class InferenceEngine:
     # flight (not B × Smax) and a slow request never convoys the batch.
 
     def _paged_supported(self) -> bool:
-        return (not self._stream_weights and not self._is_moe
+        # an MoE model pages like any other on one replica of its experts;
+        # expert parallelism (ep > 1) and weight streaming stay on the
+        # static path
+        return (not self._stream_weights and self._ep_size == 1
                 and hasattr(self.module, "forward_paged_decode")
                 and hasattr(self.module, "forward_paged_prefill")
                 and hasattr(self.module, "init_paged_cache")
@@ -1097,8 +1100,10 @@ class InferenceEngine:
 
             def _pinned(fn):
                 def run(*args):
-                    logits, pools = fn(*args)
-                    return logits, _pin(pools)
+                    # an MoE model's decode step returns its [L, E + 1]
+                    # assignment counts third
+                    logits, pools, *aux = fn(*args)
+                    return (logits, _pin(pools), *aux)
                 return run
 
             # named functions, not lambdas: the name is what a device trace
@@ -1225,7 +1230,8 @@ class InferenceEngine:
             raise ValueError(
                 "serving.paged='on' but this engine cannot page: the model "
                 "must be a zoo causal LM (forward_paged_decode) and the "
-                "engine must not be weight-streaming or MoE")
+                "engine must not be weight-streaming or expert-parallel "
+                "(moe.ep_size > 1)")
         max_new = (max_new_tokens if max_new_tokens is not None
                    else self._config.max_out_tokens)
         if mode == "off" or not supported:
@@ -1313,8 +1319,9 @@ class InferenceEngine:
         if str(srv.paged) == "off" or not self._paged_supported():
             raise ValueError(
                 "a serving session needs the paged engine (zoo causal LM, "
-                "not weight-streaming/MoE, serving.paged != 'off') — the "
-                "serving loop has no static fallback")
+                "dense or MoE, not weight-streaming, moe.ep_size == 1, "
+                "serving.paged != 'off') — the serving loop has no static "
+                "fallback")
         if max_new <= 0:
             raise ValueError("a serving session needs max_new >= 1")
 
@@ -2045,8 +2052,13 @@ class _ServeSession:
                         engine.params, jnp.asarray(toks), pools,
                         jnp.asarray(bt), jnp.asarray(pos))
                     _step_fault("decode", "post")
-                    logits, pools = out
+                    logits, pools, *moe_counts = out
+                    if moe_counts and tel is not None:
+                        # on its way beside the tokens: no wait of its own
+                        moe_counts[0].copy_to_host_async()
                 tok = self._sample_fetch(logits)
+                if moe_counts and tel is not None:
+                    tel.count_moe(np.asarray(moe_counts[0]))
                 if ev is not None:
                     # emitted BEFORE record_decode so a retirement this
                     # tick triggers lands after its final decode slice

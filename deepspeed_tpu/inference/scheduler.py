@@ -294,6 +294,35 @@ class ServingTelemetry:
             "per fused decode step, the sum of its rows' positions: over "
             "decode_steps, the KV tokens a decode step really reads")
 
+    def count_moe(self, counts) -> None:
+        """One fused decode step of an MoE model. ``counts`` [L, E + 1], the
+        program's own: the assignments each expert of each layer computed
+        (padding rows excluded) and, in the last column, those the layer
+        owed (real rows x k). The ``serving/moe_*`` counters exist only once
+        an MoE model has decoded (they are not pre-created: a reader that
+        requires them finds nothing under a dense model); resolved per
+        access like every family here (0.4 us each), so a registry reset
+        cannot orphan them."""
+        c = self.registry.counter
+        computed, owed = counts[:, :-1], counts[:, -1]
+        c("serving/moe_layer_steps",
+          "MoE layers run by fused decode steps (steps x layers)"
+          ).inc(counts.shape[0])
+        c("serving/moe_assignments",
+          "(row, expert) pairs the decode steps' MoE layers computed"
+          ).inc(int(computed.sum()))
+        c("serving/moe_experts_touched",
+          "experts with at least one row, summed over layers and decode "
+          "steps: over moe_layer_steps, the expert weights a layer reads"
+          ).inc(int((computed > 0).sum()))
+        c("serving/moe_max_expert_load",
+          "rows of the busiest expert, summed over layers and decode steps"
+          ).inc(int(computed.max(axis=1).sum()))
+        c("serving/moe_dropped_assignments",
+          "assignments of real rows that no expert computed (0 on the "
+          "no-drop dispatch; capacity overflow on the capacity dispatch)"
+          ).inc(int(owed.sum() - computed.sum()))
+
     @property
     def prefix_cache_lookups(self):
         return self.registry.counter(

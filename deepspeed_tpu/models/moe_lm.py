@@ -21,7 +21,27 @@ from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.utils.init_on_device import honors_on_device
-from deepspeed_tpu.moe.sharded_moe import dispatch_combine, top1gating, top2gating
+from deepspeed_tpu.moe.sharded_moe import (dense_dispatch, dispatch_combine,
+                                           sorted_dispatch, top1gating,
+                                           top2gating, topk_balance_loss,
+                                           topk_routing)
+
+# The no-drop dispatch computes every expert over every row
+# (sharded_moe.dense_dispatch) for a call of fewer rows than this, and sorts
+# rows into ragged groups (sorted_dispatch over jax.lax.ragged_dot) from here
+# on. Measured on a v5e at OLMoE's sizes (64 experts of 2,048 x 1,024, top-8)
+# with the layer's weights a scan's slices, as every path of this model has
+# them (benchmarks/moe_dispatch_bench.py, device times; PERF.md section 6,
+# PR 26, call 7), ms a layer, dense | sorted: 1 row 1.07 | 2.60, 16 rows
+# 1.19 | 3.59, 64 rows 1.12 | 5.01, 512 rows 2.21 | 5.44, 1,024 rows 4.36 |
+# 5.92, 1,536 rows 6.53 | 6.44, 2,048 rows 8.66 | 6.90. The dense form costs
+# a pass over the weights or rows x E expert-rows of arithmetic, whichever is
+# longer; the ragged form rows x k of them plus ~5 ms that no row count
+# changes (XLA's ragged matmul takes a scan's slice as a copy and reads it at
+# a fifth of the memory's rate). Where they cross is the chip's balance of
+# arithmetic to memory and moves little with E and k (E / (E - 1.8 k)), so
+# one number and no option; no cell runs the ragged side yet (ROADMAP S13).
+_SORTED_DISPATCH_MIN_ROWS = 1536
 
 
 @dataclasses.dataclass(frozen=True)
@@ -36,6 +56,18 @@ class MoEConfig:
     drop_tokens: bool = True
     use_rts: bool = True
     expert_ff_mult: int = 4
+    # an expert's width in its own right (OLMoE: 1,024 under d_model 2,048);
+    # None = expert_ff_mult * d_model
+    expert_d_ff: Optional[int] = None
+    # "gelu": gelu(x W_up + b) W_down + b; "swiglu": the gated SiLU expert
+    # W_down(silu(x W_gate) * (x W_up)), no bias anywhere
+    expert_activation: str = "gelu"
+    # "capacity": the GShard one-hot dispatch above (k 1 or 2, tokens over an
+    # expert's capacity dropped, ep-sharded); "nodrop": softmax, then the k
+    # largest of ANY k, every assignment computed (moe/sharded_moe.py
+    # sorted_dispatch / dense_dispatch)
+    dispatch: str = "capacity"
+    norm_topk_prob: bool = False         # nodrop: divide the k weights by their sum
     # Residual (PR-)MoE, arXiv:2201.05596: each MoE MLP is blended with a
     # dense MLP through a learned 2-way softmax coefficient (reference
     # moe/layer.py use_residual + inference moe_type='residual')
@@ -52,6 +84,26 @@ class MoECausalLM:
         self.param_dtype = param_dtype
         self.mesh = mesh
         self.num_experts = moe_config.num_experts
+        if moe_config.dispatch not in ("capacity", "nodrop"):
+            raise ValueError(f"MoEConfig.dispatch={moe_config.dispatch!r} "
+                             "(expected capacity|nodrop)")
+        if moe_config.expert_activation not in ("gelu", "swiglu"):
+            raise ValueError("MoEConfig.expert_activation is gelu or swiglu")
+        if moe_config.dispatch == "capacity" and moe_config.k not in (1, 2):
+            raise ValueError("the capacity dispatch routes top-1 or top-2; "
+                             "k > 2 needs dispatch='nodrop'")
+        if moe_config.dispatch == "nodrop" and (
+                moe_config.use_residual or moe_config.noisy_gate_policy):
+            raise ValueError("dispatch='nodrop' has no residual MLP and no "
+                             "noisy gate")
+
+    @property
+    def expert_ff(self) -> int:
+        return self.moe.expert_d_ff or self.moe.expert_ff_mult * self.config.d_model
+
+    @property
+    def _gated(self) -> bool:
+        return self.moe.expert_activation == "swiglu"
 
     # -------------------- params -------------------- #
 
@@ -61,16 +113,22 @@ class MoECausalLM:
         base = T.init_params(cfg, rng, dtype=self.param_dtype)
         L, D = cfg.n_layer, cfg.d_model
         E = moe.num_experts
-        F = moe.expert_ff_mult * D
+        F = self.expert_ff
         k1, k2, k3 = jax.random.split(jax.random.fold_in(rng, 999), 3)
         s_in, s_out = 0.02, 0.02 / math.sqrt(2 * L)
         base["layers"]["mlp"] = {
             "gate_w": (jax.random.normal(k1, (L, D, E)) / math.sqrt(D)).astype(self.param_dtype),
             "w_up": (jax.random.normal(k2, (L, E, D, F)) * s_in).astype(self.param_dtype),
-            "b_up": jnp.zeros((L, E, F), self.param_dtype),
             "w_down": (jax.random.normal(k3, (L, E, F, D)) * s_out).astype(self.param_dtype),
-            "b_down": jnp.zeros((L, E, D), self.param_dtype),
         }
+        if self._gated:
+            k7 = jax.random.fold_in(rng, 1003)
+            base["layers"]["mlp"]["w_gate"] = (
+                jax.random.normal(k7, (L, E, D, F)) * s_in).astype(self.param_dtype)
+        else:
+            base["layers"]["mlp"].update({
+                "b_up": jnp.zeros((L, E, F), self.param_dtype),
+                "b_down": jnp.zeros((L, E, D), self.param_dtype)})
         if moe.use_residual:
             k4, k5, k6 = jax.random.split(jax.random.fold_in(rng, 1001), 3)
             base["layers"]["mlp"].update({
@@ -88,9 +146,9 @@ class MoECausalLM:
         specs["layers"]["mlp"] = {
             "gate_w": P(None, None, None),
             "w_up": P(None, "ep", None, "tp"),
-            "b_up": P(None, "ep", "tp"),
             "w_down": P(None, "ep", "tp", None),
-            "b_down": P(None, "ep", None),
+            **({"w_gate": P(None, "ep", None, "tp")} if self._gated else
+               {"b_up": P(None, "ep", "tp"), "b_down": P(None, "ep", None)}),
         }
         if self.moe.use_residual:
             specs["layers"]["mlp"].update({
@@ -102,10 +160,82 @@ class MoECausalLM:
 
     # -------------------- forward -------------------- #
 
+    def _expert_keys(self):
+        return ("w_gate", "w_up", "w_down") if self._gated else \
+            ("w_up", "b_up", "w_down", "b_down")
+
+    def _act(self, up, gate=None):
+        """``up`` with its bias already on; ``gate`` for the gated expert."""
+        if self._gated:
+            return jax.nn.silu(gate) * up
+        return jax.nn.gelu(up, approximate=True)
+
     def _moe_mlp(self, lp, x, rng, train: bool, used_token=None):
-        """x [B,S,D] → ([B,S,D], l_aux) via top-k expert routing.
-        ``used_token`` [B*S] 1/0 keeps masked tokens out of capacity (top-1
-        only; the reference's top-2 gate has no mask either)."""
+        """x [B,S,D] → ([B,S,D], l_aux, counts) via top-k expert routing.
+        ``used_token`` [B*S] 1/0 keeps masked tokens away from the experts
+        (nodrop: any k; capacity: top-1 only, the reference's top-2 gate has
+        no mask either). ``counts`` [E] int32: the assignments each expert
+        computed for rows that are ``used_token`` (what the decode program
+        hands the engine's ``serving/moe_*`` counters)."""
+        if self.moe.dispatch == "nodrop":
+            return self._nodrop_mlp(lp, x, used_token)
+        return self._capacity_mlp(lp, x, rng, train, used_token)
+
+    def _nodrop_mlp(self, lp, x, valid=None):
+        """Softmax in float32, the k largest as they are, every assignment
+        computed: every expert over every row for a call of fewer than
+        ``_SORTED_DISPATCH_MIN_ROWS`` rows, rows sorted into ragged groups
+        (``jax.lax.ragged_dot``) from there on. Scopes ``router`` /
+        ``moe_dispatch`` / ``experts`` name the three parts in a device
+        trace."""
+        moe = self.moe
+        B, S, D = x.shape
+        E = moe.num_experts
+        tokens = x.reshape(-1, D)
+        with jax.named_scope("router"):
+            # float32 for real: on the chip a default-precision float32
+            # matmul rounds its operands to bf16
+            logits = jnp.dot(tokens.astype(jnp.float32),
+                             lp["gate_w"].astype(jnp.float32),
+                             precision=jax.lax.Precision.HIGHEST)
+            weights, experts, probs = topk_routing(logits, moe.k,
+                                                   moe.norm_topk_prob)
+        p = {k: T._w(lp[k], tokens) for k in self._expert_keys()}
+
+        def grouped(xs, sizes):
+            """xs [T*k, D] sorted by expert, ``sizes`` [E] rows a group."""
+            with jax.named_scope("experts"):
+                dot = lambda a, w: jax.lax.ragged_dot(a, w, sizes)  # noqa: E731
+                if self._gated:
+                    h = self._act(dot(xs, p["w_up"]), dot(xs, p["w_gate"]))
+                    return dot(h.astype(xs.dtype), p["w_down"])
+                row = jnp.repeat(jnp.arange(E), sizes,
+                                 total_repeat_length=xs.shape[0])
+                h = self._act(dot(xs, p["w_up"]) + p["b_up"][row])
+                return dot(h.astype(xs.dtype), p["w_down"]) + p["b_down"][row]
+
+        def dense(xs, combine):
+            """Every expert over every row of xs [T, D]; combine [T, E]."""
+            with jax.named_scope("experts"):
+                ein = lambda w: jnp.einsum(  # noqa: E731
+                    "td,edf->tef", xs, w, preferred_element_type=jnp.float32)
+                if self._gated:
+                    h = self._act(ein(p["w_up"]), ein(p["w_gate"]))
+                else:
+                    h = self._act(ein(p["w_up"]) + p["b_up"][None])
+                out = jnp.einsum(
+                    "tef,efd->td", (h * combine[:, :, None]).astype(xs.dtype),
+                    p["w_down"], preferred_element_type=jnp.float32)
+                return out if self._gated else out + combine @ p["b_down"]
+
+        if tokens.shape[0] < _SORTED_DISPATCH_MIN_ROWS:
+            out, counts = dense_dispatch(tokens, weights, experts, E, dense, valid)
+        else:
+            out, counts = sorted_dispatch(tokens, weights, experts, E, grouped, valid)
+        l_aux = topk_balance_loss(probs, counts, moe.k)
+        return out.reshape(B, S, D), l_aux, counts
+
+    def _capacity_mlp(self, lp, x, rng, train: bool, used_token=None):
         moe = self.moe
         B, S, D = x.shape
         tokens = x.reshape(-1, D)
@@ -125,13 +255,19 @@ class MoECausalLM:
         else:
             l_aux, combine, dispatch, _ = top2gating(logits, cf, moe.min_capacity,
                                                      moe.drop_tokens, rng=rng)
+        # assignments that kept a capacity slot, rows that are real only
+        kept = dispatch if used_token is None else \
+            dispatch & (used_token > 0)[:, None, None]
+        counts = jnp.sum(kept, axis=(0, 2), dtype=jnp.int32)
 
         def expert(p, xe):
             # T._w dequantises int8 Quantized8 expert weights transparently
-            h = xe @ T._w(p["w_up"], xe) + p["b_up"]
-            return jax.nn.gelu(h, approximate=True) @ T._w(p["w_down"], xe) + p["b_down"]
+            up = xe @ T._w(p["w_up"], xe)
+            if self._gated:
+                return self._act(up, xe @ T._w(p["w_gate"], xe)) @ T._w(p["w_down"], xe)
+            return self._act(up + p["b_up"]) @ T._w(p["w_down"], xe) + p["b_down"]
 
-        eps = {k: lp[k] for k in ("w_up", "b_up", "w_down", "b_down")}
+        eps = {k: lp[k] for k in self._expert_keys()}
         combined = dispatch_combine(tokens, combine, dispatch, expert, eps, mesh=self.mesh)
         if moe.use_residual:
             # PR-MoE blend (reference moe/layer.py:115-123): dense MLP +
@@ -141,7 +277,7 @@ class MoECausalLM:
             res = h @ T._w(lp["res_w_down"], tokens) + lp["res_b_down"]
             coef = jax.nn.softmax(tokens @ lp["coef_w"] + lp["coef_b"], axis=-1)
             combined = combined * coef[..., 0:1] + res * coef[..., 1:2]
-        return combined.reshape(B, S, D), l_aux
+        return combined.reshape(B, S, D), l_aux, counts
 
     def _block(self, x, lp, positions, mask_bias, rng, train: bool):
         cfg = self.config
@@ -153,7 +289,7 @@ class MoECausalLM:
                 k_route = rng
         a = T.attention(cfg, T._norm(cfg, x, lp["ln_attn"]), lp["attn"], positions, mask_bias)
         x = x + T._dropout(cfg, a, ka)
-        m, l_aux = self._moe_mlp(lp["mlp"], T._norm(cfg, x, lp["ln_mlp"]), k_route, train)
+        m, l_aux, _ = self._moe_mlp(lp["mlp"], T._norm(cfg, x, lp["ln_mlp"]), k_route, train)
         return x + T._dropout(cfg, m, km), l_aux
 
     def forward(self, params, tokens, attn_mask=None, rng=None, train: bool = True):
@@ -208,12 +344,67 @@ class MoECausalLM:
         used = None if valid is None else valid.reshape(-1)
 
         def moe_mlp_fn(cfg, x_normed, lp):
-            out, _ = self._moe_mlp(lp["mlp"], x_normed, None, train=False,
-                                   used_token=used)
-            return out
+            return self._moe_mlp(lp["mlp"], x_normed, None, train=False,
+                                 used_token=used)[0]
 
         return T.forward_cached(self.config, params, tokens, cache, pos,
                                 pad_bias, mlp_fn=moe_mlp_fn)
+
+    # ---- paged KV serving: transformer.forward_paged_* with the MoE MLP ----
+    # A position the engine routes to the dummy block (a prompt bucket's
+    # padding, an empty decode or verify row) reaches no expert and is
+    # counted nowhere (T.paged_real_rows).
+
+    def init_paged_cache(self, num_blocks: int, block_size: int,
+                         dtype=jnp.bfloat16) -> Dict[str, Any]:
+        return T.init_paged_kv_cache(self.config, num_blocks, block_size, dtype)
+
+    def _paged(self, pools, slots, counts: bool = False):
+        """The ``mlp_fn`` of a ``transformer.forward_paged_*`` call whose
+        positions write to ``slots``. ``counts``: it also returns [E + 1]
+        int32, the assignments each expert computed and, last, those the
+        layer owed (real rows x k)."""
+        used = T.paged_real_rows(pools, slots).reshape(-1)
+
+        def mlp_fn(cfg, x_normed, lp):
+            with jax.named_scope("mlp"):
+                out, _, n = self._moe_mlp(lp["mlp"], x_normed, None,
+                                          train=False, used_token=used)
+            if not counts:
+                return out
+            owed = jnp.sum(used, dtype=jnp.int32) * self.moe.k
+            return out, jnp.append(n, owed)
+        return mlp_fn
+
+    def forward_paged_prefill(self, params, tokens, pools, slots, last_idx):
+        mlp_fn = self._paged(pools, slots)
+        return T.forward_paged_prefill(self.config, params, tokens, pools,
+                                       slots, last_idx, mlp_fn=mlp_fn)
+
+    def forward_paged_prefill_chunk(self, params, tokens, pools,
+                                    block_tables, slots, start_pos, last_idx):
+        mlp_fn = self._paged(pools, slots)
+        return T.forward_paged_prefill_chunk(
+            self.config, params, tokens, pools, block_tables, slots,
+            start_pos, last_idx, mlp_fn=mlp_fn)
+
+    def forward_paged_verify(self, params, tokens, pools, block_tables,
+                             slots, pos):
+        mlp_fn = self._paged(pools, slots)
+        return T.forward_paged_verify(self.config, params, tokens, pools,
+                                      block_tables, slots, pos, mlp_fn=mlp_fn)
+
+    def forward_paged_decode(self, params, tokens, pools, block_tables, pos,
+                             pad_bias=None):
+        """(logits [B, vocab], new pools, counts [L, E + 1]): third, the
+        assignments each expert of each layer computed in this step and, in
+        the last column, those the layer owed (real rows x k): what the
+        engine's ``serving/moe_*`` counters are fed."""
+        bs = pools["k"].shape[2]
+        slots = block_tables[jnp.arange(pos.shape[0]), pos // bs] * bs + pos % bs
+        mlp_fn = self._paged(pools, slots, counts=True)
+        return T.forward_paged_decode(self.config, params, tokens, pools,
+                                      block_tables, pos, pad_bias, mlp_fn=mlp_fn)
 
     def loss(self, params, batch, rng=None):
         logits, aux = self.forward(params, batch["input_ids"], batch.get("attention_mask"),
@@ -234,10 +425,12 @@ class MoECausalLM:
     def num_parameters(self) -> int:
         cfg, moe = self.config, self.moe
         D, E = cfg.d_model, moe.num_experts
-        F = moe.expert_ff_mult * D
+        F = self.expert_ff
         embed = cfg.vocab_size * D + (cfg.max_seq * D if cfg.pos_embedding == "learned" else 0)
         attn = D * cfg.head_dim * (cfg.n_head + 2 * cfg.kv_heads) + cfg.n_head * cfg.head_dim * D
-        moe_mlp = D * E + E * (2 * D * F + F + D)
+        moe_mlp = D * E + E * (3 * D * F if self._gated else 2 * D * F + F + D)
+        if cfg.qk_norm:
+            attn += cfg.head_dim * (cfg.n_head + cfg.kv_heads)
         norms = (4 if cfg.norm == "layernorm" else 2) * D
         final_norm = (2 if cfg.norm == "layernorm" else 1) * D
         head = 0 if cfg.tie_embeddings else D * cfg.vocab_size

@@ -97,12 +97,42 @@ def gpt_neox(size: str = "20b", **over) -> CausalLM:
     return CausalLM(cfg)
 
 
+def olmoe(size: str = "1b-7b", **over):
+    """OLMoE-1B-7B (Muennighoff et al. 2024; ``allenai/OLMoE-1B-7B-0125-
+    Instruct`` config.json): pre-RMSNorm, no bias, query/key RMSNorm over the
+    whole projection, rope in the half-split pairing, an untied head, and in
+    every layer 64 gated-SiLU experts of width 1,024 of which a token takes
+    the 8 of largest softmax probability, weights not renormalised, nothing
+    dropped. ``1b-7b-8l`` is the same model at half depth (what one 16 GB
+    chip holds beside a KV pool: perfbench's ``olmoe1b7b_serve_decode``);
+    ``tiny`` keeps every kind of part at toy widths (8 experts, top-2)."""
+    from deepspeed_tpu.models.moe_lm import MoECausalLM, MoEConfig
+    published = dict(n_head=16, d_model=2048, vocab_size=50304, max_seq=4096)
+    dims, moe = {
+        "tiny": (dict(n_layer=2, n_head=4, d_model=128, vocab_size=50304, max_seq=2048),
+                 dict(num_experts=8, k=2, expert_d_ff=64)),
+        "1b-7b": (dict(n_layer=16, **published),
+                  dict(num_experts=64, k=8, expert_d_ff=1024)),
+        "1b-7b-8l": (dict(n_layer=8, **published),
+                     dict(num_experts=64, k=8, expert_d_ff=1024)),
+    }[size]
+    param_dtype = over.pop("param_dtype", jnp.float32)
+    cfg = TransformerConfig(pos_embedding="rope", norm="rmsnorm", norm_eps=1e-5,
+                            rope_theta=10000.0, qk_norm=True, tie_embeddings=False,
+                            attn_bias=False, **{**dims, **over})
+    return MoECausalLM(cfg, MoEConfig(
+        dispatch="nodrop", expert_activation="swiglu", norm_topk_prob=False,
+        aux_loss_coef=0.01, **moe),
+        param_dtype=param_dtype)
+
+
 MODEL_PRESETS: Dict[str, Callable] = {
     "gpt2": gpt2,
     "llama": llama,
     "bloom": bloom,
     "opt": opt,
     "gpt_neox": gpt_neox,
+    "olmoe": olmoe,
 }
 
 

@@ -48,6 +48,10 @@ class TransformerConfig:
     tie_embeddings: bool = True
     embed_layernorm: bool = False        # BLOOM word_embeddings_layernorm
     attn_bias: bool = False              # qkv/out biases (gpt2/opt/bloom/neox)
+    # RMSNorm of the query and key PROJECTIONS, each over its whole width
+    # (all heads together, one learned scale of H*Hd / KV*Hd), before the
+    # split into heads and before rope (OLMoE, OLMo-2)
+    qk_norm: bool = False
     # numerics
     rope_theta: float = 10000.0
     rope_dim: int = 0                    # 0 = full head dim; else partial
@@ -172,6 +176,9 @@ def init_params(cfg: TransformerConfig, rng, dtype=jnp.float32) -> Dict[str, Any
                     "bk": jnp.zeros((L, KV * Hd), dtype),
                     "bv": jnp.zeros((L, KV * Hd), dtype),
                     "bo": jnp.zeros((L, D), dtype)} if cfg.attn_bias else {}),
+                **({"q_norm": {"scale": jnp.ones((L, H * Hd), dtype)},
+                    "k_norm": {"scale": jnp.ones((L, KV * Hd), dtype)}}
+                   if cfg.qk_norm else {}),
             },
             "ln_mlp": norm_params(),
             "mlp": ({
@@ -216,6 +223,8 @@ def tp_specs(cfg: TransformerConfig) -> Dict[str, Any]:
                 "wo": P(None, "tp", None),
                 **({"bq": P(None, "tp"), "bk": P(None, "tp"),
                     "bv": P(None, "tp"), "bo": P(None, None)} if cfg.attn_bias else {}),
+                **({"q_norm": {"scale": P(None, None)},
+                    "k_norm": {"scale": P(None, None)}} if cfg.qk_norm else {}),
             },
             "ln_mlp": ln,
             "mlp": ({
@@ -265,6 +274,26 @@ def _norm(cfg: TransformerConfig, x, p):
         out = (x32 - mean) * jax.lax.rsqrt(var + cfg.norm_eps)
         out = out * p["scale"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
     return out.astype(x.dtype)
+
+
+def _qk_norm(cfg: TransformerConfig, q, k, lp):
+    """``cfg.qk_norm``: RMSNorm of the flat query and key projections
+    [..., H*Hd] / [..., KV*Hd] over their whole width. The ONE place every
+    attention path (``attention`` and ``_qkv_project``: cached, paged
+    prefill, chunk, verify, decode) takes it from."""
+    if not cfg.qk_norm:
+        return q, k
+    if cfg.manual_tp:
+        raise NotImplementedError(
+            "qk_norm reduces over all heads; manual-tp stages hold a slice")
+
+    def rms(x, p):
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        return (x32 * jax.lax.rsqrt(var + cfg.norm_eps)
+                * p["scale"].astype(jnp.float32)).astype(x.dtype)
+
+    return rms(q, lp["q_norm"]), rms(k, lp["k_norm"])
 
 
 def _rope(x, positions, theta: float, rope_dim: int = 0,
@@ -384,8 +413,9 @@ def attention(cfg: TransformerConfig, x, lp, positions, mask_bias):
     bq = lp["bq"] if cfg.attn_bias else 0
     bk = lp["bk"] if cfg.attn_bias else 0
     bv = lp["bv"] if cfg.attn_bias else 0
-    q = checkpoint_name((x @ _w(lp["wq"], x) + bq).reshape(B, S, H, Hd), "q_proj")
-    k = checkpoint_name((x @ _w(lp["wk"], x) + bk).reshape(B, S, KV, Hd), "k_proj")
+    q, k = _qk_norm(cfg, x @ _w(lp["wq"], x) + bq, x @ _w(lp["wk"], x) + bk, lp)
+    q = checkpoint_name(q.reshape(B, S, H, Hd), "q_proj")
+    k = checkpoint_name(k.reshape(B, S, KV, Hd), "k_proj")
     v = checkpoint_name((x @ _w(lp["wv"], x) + bv).reshape(B, S, KV, Hd), "v_proj")
 
     if cfg.pos_embedding == "rope":
@@ -927,8 +957,9 @@ def _qkv_project(cfg: TransformerConfig, x, lp, positions):
     bq = lp["bq"] if cfg.attn_bias else 0
     bk = lp["bk"] if cfg.attn_bias else 0
     bv = lp["bv"] if cfg.attn_bias else 0
-    q = (x @ _w(lp["wq"], x) + bq).reshape(B, T, H, Hd)
-    k = (x @ _w(lp["wk"], x) + bk).reshape(B, T, KV, Hd)
+    q, k = _qk_norm(cfg, x @ _w(lp["wq"], x) + bq, x @ _w(lp["wk"], x) + bk, lp)
+    q = q.reshape(B, T, H, Hd)
+    k = k.reshape(B, T, KV, Hd)
     v = (x @ _w(lp["wv"], x) + bv).reshape(B, T, KV, Hd)
     if cfg.pos_embedding == "rope":
         q = _rope(q, positions, cfg.rope_theta, cfg.rope_dim, cfg.rope_interleaved)
@@ -1046,7 +1077,8 @@ def _decode_block(cfg: TransformerConfig, h, lp, attn_fn, mlp_fn=None):
     ``mlp_fn(cfg, x_normed, lp)`` overrides the dense MLP (MoE)."""
     mfn = mlp_fn if mlp_fn is not None else (
         lambda c, xx, lpp: mlp(c, xx, lpp["mlp"]))
-    a, nkp, nvp = attn_fn(_norm(cfg, h, lp["ln_attn"]))
+    with jax.named_scope("attention"):
+        a, nkp, nvp = attn_fn(_norm(cfg, h, lp["ln_attn"]))
     if cfg.parallel_residual:
         m = mfn(cfg, _norm(cfg, h, lp["ln_mlp"]), lp)
         return h + a + m, nkp, nvp
@@ -1159,24 +1191,43 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
     lives at blocks ``[l*num_blocks, (l+1)*num_blocks)`` of that view, so
     ``attn_fn(x_normed, lp_attn, kp, vp, block0, slot0)`` reads and writes
     it through ``block_tables + block0`` / ``slots + slot0``: no per-layer
-    slice of a pool exists and nothing is stacked. Returns (x, new pools)."""
+    slice of a pool exists and nothing is stacked. An ``mlp_fn`` may return
+    ``(out, aux)``: ``aux`` (an MoE layer's per-expert counts) comes back
+    stacked over the layers, else None. Returns (x, new pools, aux)."""
     L, Nb, bs = pools["k"].shape[:3]
 
     def run_block(carry, xs):
         h, kp, vp = carry
         lp, l = xs
+        aux = []
+        mfn = mlp_fn
+        if mlp_fn is not None:
+            def mfn(c, xx, lpp):
+                out = mlp_fn(c, xx, lpp)
+                if isinstance(out, tuple):
+                    out, a = out
+                    aux.append(a)
+                return out
         h, kp, vp = _decode_block(
             cfg, h, lp,
             lambda xn: attn_fn(xn, lp["attn"], kp, vp, l * Nb, l * (Nb * bs)),
-            mlp_fn)
-        return (h, kp, vp), None
+            mfn)
+        return (h, kp, vp), (aux[0] if aux else None)
 
     flat = {n: a.reshape(L * Nb, *a.shape[2:]) for n, a in pools.items()}
-    (x, kp, vp), _ = jax.lax.scan(
+    (x, kp, vp), aux = jax.lax.scan(
         run_block, (x, flat["k"], flat["v"]),
         (params["layers"], jnp.arange(L, dtype=jnp.int32)))
     return x, {"k": kp.reshape(pools["k"].shape),
-               "v": vp.reshape(pools["v"].shape)}
+               "v": vp.reshape(pools["v"].shape)}, aux
+
+
+def paged_real_rows(pools, slots):
+    """Which positions of a paged step are real: padding (a prompt bucket's
+    tail, an inactive decode or verify row) is what the engine routes to the
+    dummy block 0, so a flat slot under ``block_size`` is padding. What an
+    MoE MLP keeps away from its experts."""
+    return slots >= pools["k"].shape[2]
 
 
 def _paged_decode_attention(cfg: TransformerConfig, x, lp, positions, pos,
@@ -1314,7 +1365,7 @@ def forward_paged_prefill(cfg: TransformerConfig, params, tokens, pools,
     x, positions = cached_embed(cfg, params, tokens, jnp.int32(0),
                                 pools["k"].dtype)
 
-    x, pools = _scan_paged_layers(
+    x, pools, _ = _scan_paged_layers(
         cfg, params, pools, x,
         lambda xn, lp, kp, vp, block0, slot0: _paged_prefill_attention(
             cfg, xn, lp, positions, kp, vp, slots + slot0),
@@ -1344,7 +1395,7 @@ def forward_paged_prefill_chunk(cfg: TransformerConfig, params, tokens,
     x, positions = cached_embed(cfg, params, tokens, start_pos,
                                 pools["k"].dtype)
 
-    x, pools = _scan_paged_layers(
+    x, pools, _ = _scan_paged_layers(
         cfg, params, pools, x,
         lambda xn, lp, kp, vp, block0, slot0: _paged_chunk_attention(
             cfg, xn, lp, positions, kp, vp, block_tables + block0,
@@ -1459,7 +1510,7 @@ def forward_paged_verify(cfg: TransformerConfig, params, tokens, pools,
     _check_paged_config(cfg)
     x, positions = cached_embed(cfg, params, tokens, pos, pools["k"].dtype)
 
-    x, pools = _scan_paged_layers(
+    x, pools, _ = _scan_paged_layers(
         cfg, params, pools, x,
         lambda xn, lp, kp, vp, block0, slot0: _paged_verify_attention(
             cfg, xn, lp, positions, kp, vp, block_tables + block0,
@@ -1472,18 +1523,21 @@ def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
                          block_tables, pos, pad_bias=None, mlp_fn=None):
     """One fused decode step over ALL running requests: tokens [B, 1] (each
     request's last sampled token), block_tables [B, max_blocks], pos [B]
-    per-request cache depths. Returns (logits [B, vocab], new pools)."""
+    per-request cache depths. Returns (logits [B, vocab], new pools), and
+    third what an ``mlp_fn`` that returns ``(out, aux)`` gave, stacked over
+    the layers (an MoE model's [L, E + 1] assignment counts)."""
     _check_paged_config(cfg)
     x, positions = cached_embed(cfg, params, tokens, pos, pools["k"].dtype)
 
     # the decode step derives its write slots from the (layer-offset) table
-    x, pools = _scan_paged_layers(
+    x, pools, aux = _scan_paged_layers(
         cfg, params, pools, x,
         lambda xn, lp, kp, vp, block0, slot0: _paged_decode_attention(
             cfg, xn, lp, positions, pos, kp, vp, block_tables + block0,
             pad_bias),
         mlp_fn)
-    return cached_head(cfg, params, x)[:, 0, :], pools
+    logits = cached_head(cfg, params, x)[:, 0, :]
+    return (logits, pools) if aux is None else (logits, pools, aux)
 
 
 def run_layers(cfg: TransformerConfig, x, layer_params, positions, mask_bias,

@@ -13,6 +13,13 @@ expert-stacked weights are sharded over the ``ep`` mesh axis and the
 dispatched token tensor ``[E, C, D]`` is constrained to ``P("ep")`` on the
 expert dim — the SPMD partitioner inserts the all-to-all pair the reference
 issues by hand (``sharded_moe.py:467-499``).
+
+Beside the capacity path there is a routing for any ``k`` and two dispatches
+that drop nothing (:func:`topk_routing`, :func:`sorted_dispatch`,
+:func:`dense_dispatch`): what a model with many small experts and a large
+``k`` needs (OLMoE: top-8 of 64), where a ``[T, E, C]`` one-hot pair is
+almost all zeros and a full capacity bucket would drop tokens the model
+never drops.
 """
 
 from __future__ import annotations
@@ -168,6 +175,96 @@ def top2gating(logits: jnp.ndarray,
     combine = combine1 + combine2
     dispatch_mask = combine > 0
     return l_aux, combine, dispatch_mask, exp_counts
+
+
+# --------------------------------------------------------------------- #
+# Top-k of any size, no capacity, no dropped token.
+
+
+def topk_routing(logits: jnp.ndarray, k: int, norm_topk_prob: bool = False
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Softmax first, then the ``k`` largest probabilities as they are
+    (``norm_topk_prob``: divided by their sum). logits [T, E] ->
+    (weights [T, k] float32, experts [T, k] int32, probs [T, E] float32).
+    All in float32 whatever the logits came in."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    weights, experts = jax.lax.top_k(probs, k)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts.astype(jnp.int32), probs
+
+
+def topk_balance_loss(probs: jnp.ndarray, counts: jnp.ndarray,
+                      k: int) -> jnp.ndarray:
+    """Load-balancing loss of one layer for top-k routing: ``E * sum_e f_e *
+    P_e`` with ``f_e`` expert e's share of the ``T * k`` assignments and
+    ``P_e`` its mean routing probability (Switch / megablocks convention,
+    1.0 when both are uniform). probs [T, E], counts [E]."""
+    T, E = probs.shape
+    f = counts.astype(jnp.float32) / (T * k)
+    return E * jnp.sum(f * jnp.mean(probs, axis=0))
+
+
+def _masked_experts(experts, num_experts: int, valid):
+    """Flat [T*k] expert index per assignment; a row that is not ``valid``
+    (a padded prompt position, an empty decode row) goes to the sentinel
+    ``num_experts``: sorted last, in no group, counted nowhere."""
+    flat = experts.reshape(-1)
+    if valid is None:
+        return flat
+    return jnp.where(jnp.repeat(valid.astype(bool), experts.shape[1]),
+                     flat, num_experts)
+
+
+def _group_sizes(flat, num_experts: int):
+    return jnp.sum(flat[:, None] == jnp.arange(num_experts)[None, :],
+                   axis=0, dtype=jnp.int32)
+
+
+def sorted_dispatch(tokens: jnp.ndarray, weights: jnp.ndarray,
+                    experts: jnp.ndarray, num_experts: int,
+                    grouped_fn: Callable, valid=None):
+    """No-drop dispatch over ragged groups: the ``T * k`` assignments sorted
+    by expert, ``grouped_fn(xs [T*k, D], group_sizes [E]) -> [T*k, D]`` (the
+    experts as grouped matmuls, ``jax.lax.ragged_dot``) run over them, and
+    the rows weighted and summed back per token. Differentiable; work and
+    memory are ``T * k`` rows whatever ``E`` is. ``valid`` [T] keeps padding
+    out (see :func:`_masked_experts`). Returns (out [T, D], counts [E])."""
+    T, k = experts.shape
+    with jax.named_scope("moe_dispatch"):
+        flat = _masked_experts(experts, num_experts, valid)
+        order = jnp.argsort(flat)
+        counts = _group_sizes(flat, num_experts)
+        xs = tokens[order // k]
+    ys = grouped_fn(xs, counts)
+    with jax.named_scope("moe_dispatch"):
+        # rows past the last group belong to no expert: whatever the grouped
+        # matmul left there must not reach a token
+        ys = jnp.where((flat[order] < num_experts)[:, None], ys, 0)
+        back = jnp.argsort(order)
+        y = ys[back].reshape(T, k, -1)
+        out = jnp.einsum("tk,tkd->td", weights.astype(jnp.float32),
+                         y.astype(jnp.float32))
+    return out.astype(tokens.dtype), counts
+
+
+def dense_dispatch(tokens: jnp.ndarray, weights: jnp.ndarray,
+                   experts: jnp.ndarray, num_experts: int,
+                   dense_fn: Callable, valid=None):
+    """No-drop dispatch for FEW rows: every expert computes every row and
+    ``combine`` [T, E] (a row's weight for each expert it chose, else 0)
+    picks. Exact like :func:`sorted_dispatch`; ``E / k`` times its
+    arithmetic, but no sort, no gather and no ragged group, so it is the
+    form for a decode batch that touches every expert anyway and is bound by
+    reading their weights. ``dense_fn(tokens [T, D], combine [T, E]) ->
+    [T, D]``. Returns (out [T, D], counts [E])."""
+    with jax.named_scope("moe_dispatch"):
+        chosen = experts[:, :, None] == jnp.arange(num_experts)[None, None, :]
+        if valid is not None:
+            chosen = chosen & valid.astype(bool)[:, None, None]
+        combine = jnp.sum(jnp.where(chosen, weights[:, :, None], 0.0), axis=1)
+        counts = jnp.sum(chosen, axis=(0, 1), dtype=jnp.int32)
+    return dense_fn(tokens, combine).astype(tokens.dtype), counts
 
 
 class TopKGate:
