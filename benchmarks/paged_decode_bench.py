@@ -1,0 +1,171 @@
+"""The paged decode attention kernel alone on a TPU, in the states the
+serving cells put it in.
+
+    python benchmarks/paged_decode_bench.py [--groups 1 2 4] [--parent FILE]
+
+One call of ``paged_decode_attention`` is a decode step's attention of one
+layer over every row. Three states shaped as PERF.md section 5 reads them
+off the cells, and one no cell has: ``opt_decode`` (40 rows of 32 heads x 64, 16 table entries,
+every row ~330 tokens deep), ``opt_mixed`` (the same table with 3 rows
+decoding and 37 idle rows on the dummy block) and ``olmoe_decode`` (64 rows
+of 16 heads x 128, 32 table entries, ~510 tokens a row); ``narrow_decode``
+(``opt_decode`` with 12 heads x 64: a block is 0.19 MB a pool, not 0.5, so
+an iteration's fixed cost weighs more). The time is the
+device's: the kernel's own events in a profiler trace, a call. ``--groups``
+times the kernel at those blocks a loop iteration (``_group_blocks`` is what
+the program takes); ``--parent`` names another version of the kernel's
+module (a file) to time beside it. Each line also checks the output against
+a float32 gather + softmax. The numbers behind ``_STREAM_VMEM_BYTES``
+(PERF.md section 6, PR 27). TPU only: the script refuses to print a time
+from another backend.
+"""
+
+import argparse
+import importlib
+import importlib.util
+import json
+import os
+import sys
+import tempfile
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path[:0] = [ROOT, os.path.join(ROOT, "perfbench")]
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+import trace_reduce
+
+BS = 128
+LAYERS = 8          # calls a program, each with its own query
+STATES = {          # rows, heads (= kv heads), head size, table width, pool
+    "opt_decode": (40, 32, 64, 16, 224),
+    "opt_mixed": (40, 32, 64, 16, 224),
+    "olmoe_decode": (64, 16, 128, 32, 528),
+    "narrow_decode": (40, 12, 64, 16, 224),     # gpt2:125m's 768-lane row
+}
+
+
+def draw_state(name, seed):
+    """Block tables and positions of ``name``: live blocks drawn without
+    replacement from the pool, the dead tail zero (the dummy block)."""
+    B, H, Hd, width, blocks = STATES[name]
+    r = np.random.default_rng(seed)
+    if name == "opt_mixed":
+        pos = np.zeros(B, np.int32)
+        pos[r.choice(B, 3, replace=False)] = r.integers(300, 700, 3)
+    else:
+        pos = r.integers(128, 900 if name == "olmoe_decode" else 540, B)
+    live = pos // BS + 1
+    ids = iter(r.permutation(np.arange(1, blocks)))
+    bt = np.zeros((B, width), np.int32)
+    for b in range(B):
+        if pos[b]:
+            bt[b, :live[b]] = [next(ids) for _ in range(live[b])]
+    return bt, pos.astype(np.int32), int(live.sum())
+
+
+def reference(q, kp, vp, bt, pos):
+    B, H, Hd = q.shape
+    k = kp[bt].reshape(B, -1, H, Hd).astype(jnp.float32)
+    v = vp[bt].reshape(B, -1, H, Hd).astype(jnp.float32)
+    s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32) * Hd**-0.5, k,
+                   precision="highest")
+    kpos = jnp.arange(k.shape[1])[None, None, :]
+    s = jnp.where(kpos <= pos[:, None, None], s, -1e30)
+    return jnp.einsum("bhs,bshd->bhd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest")
+
+
+def load_module(path):
+    spec = importlib.util.spec_from_file_location("paged_parent", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--groups", type=int, nargs="*", default=[])
+    ap.add_argument("--parent", default=None)
+    ap.add_argument("--states", nargs="+", default=list(STATES))
+    ap.add_argument("--reps", type=int, default=6)
+    ap.add_argument("--seed", type=int, default=2700000001)
+    args = ap.parse_args()
+    platform = jax.devices()[0].platform
+    if platform != "tpu":
+        sys.exit(f"paged_decode_bench: the default device is {platform!r}, "
+                 "not a TPU: no time is taken")
+    here = importlib.import_module(
+        "deepspeed_tpu.ops.pallas.paged_decode_attention")
+    group_blocks = here._group_blocks
+    variants = [("program", here, None)]
+    variants += [(f"G{g}", here, g) for g in args.groups]
+    if args.parent:
+        variants.append(("parent", load_module(args.parent), None))
+
+    runs = {}
+    for name in args.states:
+        B, H, Hd, width, blocks = STATES[name]
+        bt, pos, live = draw_state(name, args.seed)
+        key = jax.random.key(args.seed % (1 << 31))
+        kq, kk, kv = jax.random.split(key, 3)
+        q = jax.random.normal(kq, (LAYERS, B, H, Hd), jnp.bfloat16)
+        kp = jax.random.normal(kk, (blocks, BS, H * Hd), jnp.bfloat16)
+        vp = jax.random.normal(kv, (blocks, BS, H * Hd), jnp.bfloat16)
+        bt, pos = jnp.asarray(bt), jnp.asarray(pos)
+        want = jax.jit(reference)(q[0], kp, vp, bt, pos)
+        for label, mod, g in variants:
+            def stack(q, kp, vp, bt, pos, mod=mod):
+                return jax.lax.map(
+                    lambda ql: mod.paged_decode_attention(ql, kp, vp, bt, pos),
+                    q)
+            stack.__name__ = f"paged_{name}_{label}"
+            here._group_blocks = (lambda *a, g=g: g) if g else group_blocks
+            run = jax.jit(stack)
+            out = jax.block_until_ready(run(q, kp, vp, bt, pos))
+            err = float(jnp.abs(out[0].astype(jnp.float32) - want).max())
+            runs[name, label] = (run, (q, kp, vp, bt, pos), live, err)
+    here._group_blocks = group_blocks
+
+    trace_dir = tempfile.mkdtemp(prefix="paged_decode_bench_")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    for run, operands, _, _ in runs.values():
+        for _ in range(args.reps):
+            out = run(*operands)
+        jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    trace = trace_reduce.load_xplane(trace_reduce.find_xplane(trace_dir))
+    dev = trace["devices"][trace_reduce.busiest_device(trace)]
+
+    # two variants of one state can be ONE program to XLA (the same HLO under
+    # the first one's name), so executions are told apart by their order
+    execs = sorted((p for p in dev["programs"] if "jit_paged_" in p[0]),
+                   key=lambda p: p[1])
+    if len(execs) != len(runs) * args.reps:
+        sys.exit(f"{len(execs)} executions in the trace, {len(runs)} x "
+                 f"{args.reps} were run: {sorted({p[0] for p in execs})}")
+    for i, ((name, label), (_, _, live, err)) in enumerate(runs.items()):
+        B, H, Hd, width, _ = STATES[name]
+        took, calls = 0.0, 0
+        for _, start, dur in execs[i * args.reps:(i + 1) * args.reps]:
+            inside = [op for op in dev["ops"] if start <= op[1] < start + dur]
+            t, n = trace_reduce.matching(inside, "paged_decode_attention")
+            took, calls = took + t, calls + n
+        events, calls = calls, args.reps * LAYERS
+        ms = took / calls * 1e3
+        block_bytes = 2 * BS * H * Hd * 2
+        print(json.dumps({
+            "state": name, "kernel": label, "device_ms_per_call": round(ms, 4),
+            "trace_events_per_call": events / calls,
+            "rows": B, "table_entries": B * width, "live_blocks": live,
+            "us_per_live_block": round(ms * 1e3 / live, 3),
+            "live_block_gb_per_s": round(live * block_bytes / ms / 1e6, 1),
+            "max_abs_err_from_float32": round(err, 5)}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

@@ -2046,6 +2046,8 @@ class _ServeSession:
                         toks[i, 0] = r.last_token
                     if tel is not None:
                         tel.decode_live_kv_tokens.inc(int(pos.sum()))
+                        # an idle row reads the dummy block: one copy too
+                        tel.decode_live_kv_blocks.inc(int((pos // bs + 1).sum()))
                 with span("serve.dispatch"):
                     t0 = time.monotonic_ns() if ev is not None else 0
                     out = self._decode_jit(

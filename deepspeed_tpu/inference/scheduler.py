@@ -142,6 +142,7 @@ class ServingTelemetry:
                "cold_blocks", "prefill_steps", "prefill_chunks",
                "prefill_tokens", "prefill_padded_tokens",
                "decode_steps", "decode_live_kv_tokens",
+               "decode_live_kv_blocks",
                "prefix_cache_lookups", "prefix_cache_hits",
                "prefix_cache_hit_tokens",
                "kv_host_blocks", "kv_host_bytes", "kv_spills",
@@ -293,6 +294,14 @@ class ServingTelemetry:
             "serving/decode_live_kv_tokens",
             "per fused decode step, the sum of its rows' positions: over "
             "decode_steps, the KV tokens a decode step really reads")
+
+    @property
+    def decode_live_kv_blocks(self):
+        return self.registry.counter(
+            "serving/decode_live_kv_blocks",
+            "per fused decode step, the sum over ALL its rows (idle ones "
+            "read the dummy block) of pos // block_size + 1: the block "
+            "copies the paged kernel issues a layer and pool")
 
     def count_moe(self, counts) -> None:
         """One fused decode step of an MoE model. ``counts`` [L, E + 1], the

@@ -166,6 +166,168 @@ def test_paged_traced_pos_and_tables():
         assert float(jnp.abs(out[b] - want[0]).max()) < 1e-5
 
 
+# --------------------------------------------------------------------- #
+# The streaming form: grid over rows, a row's LIVE blocks copied by the
+# kernel itself in groups of G, all heads of a group in one product.
+
+def paged_reference(q, kp, vp, bt, pos, bias=None, slopes=None):
+    """float32 gather + softmax over each row's live blocks. Dead table
+    entries are taken off before the gather: what they name is not read."""
+    B, H, Hd = q.shape
+    bs, KV = kp.shape[1], kp.shape[2] // Hd
+    live = jnp.arange(bt.shape[1])[None, :] <= (pos // bs)[:, None]
+    bt = jnp.where(live, bt, 0)
+    k, v = (jnp.repeat(pool[bt].reshape(B, -1, KV, Hd).astype(jnp.float32),
+                       H // KV, axis=2) for pool in (kp, vp))
+    s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32) * Hd**-0.5, k,
+                   precision="highest")
+    kpos = jnp.arange(k.shape[1])[None, None, :]
+    qpos = pos[:, None, None]
+    if slopes is not None:
+        s = s + slopes[None, :, None] * (kpos - qpos)
+    if bias is not None:
+        s = s + bias[:, None, :]
+    s = jnp.where(kpos <= qpos, s, -1e30)
+    return jnp.einsum("bhs,bshd->bhd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest")
+
+
+def force_group(monkeypatch, G):
+    """Blocks a loop iteration (the program takes them from the shapes: at
+    these toy widths a whole table would be one group)."""
+    import importlib
+    mod = importlib.import_module(
+        "deepspeed_tpu.ops.pallas.paged_decode_attention")
+    monkeypatch.setattr(mod, "_group_blocks", lambda *a: G)
+
+
+def max_err(out, want):
+    err = jnp.abs(out.astype(jnp.float32) - want).max()
+    return float(jnp.where(jnp.isnan(err), jnp.inf, err))
+
+
+@pytest.mark.parametrize("G", [1, 2, 3])
+def test_live_blocks_one_a_multiple_of_the_group_and_full_width(G, monkeypatch):
+    """One batch holds rows of 1 live block, exactly G, G + 1, 2 G and the
+    table's whole width: the loop's trip count is each row's own, the last
+    group is full, partly filled, or the only one."""
+    force_group(monkeypatch, G)
+    r = np.random.default_rng(40 + G)
+    n_max, bs = 6, 128
+    lives = [1, G, G + 1, 2 * G, n_max]
+    q, kp, vp, bt, _ = random_paged_case(r, len(lives), 2, 64, bs, n_max,
+                                         group=2)
+    pos = jnp.asarray([n * bs - 1 - int(r.integers(0, bs)) for n in lives],
+                      jnp.int32)
+    assert (np.asarray(pos) // bs + 1).tolist() == lives
+    out = paged_decode_attention(q, kp, vp, bt, pos)
+    assert max_err(out, paged_reference(q, kp, vp, bt, pos)) < 1e-5
+
+
+@pytest.mark.parametrize("G", [1, 2])
+def test_dead_tail_is_never_copied(G, monkeypatch):
+    """Dead table entries hold ids beyond the pool and the id of a block of
+    NaNs (where an index clamped by the interpreter would land too): a copy
+    of either would reach the output through 0 x NaN."""
+    force_group(monkeypatch, G)
+    r = np.random.default_rng(50 + G)
+    n_max, bs = 5, 128
+    q, kp, vp, bt, _ = random_paged_case(r, 4, 2, 64, bs, n_max, group=1)
+    last = kp.shape[0] - 1
+    kp, vp = kp.at[last].set(jnp.nan), vp.at[last].set(jnp.nan)
+    pos = np.asarray([5, 130, 300, 511], np.int32)
+    bt = np.array(bt)
+    bt[bt == last] = 1                     # no LIVE entry names the NaNs
+    for b in range(4):
+        tail = slice(int(pos[b]) // bs + 1, None)
+        bt[b, tail] = [last, 10**6, -7, 2**31 - 1][b]
+    bt, pos = jnp.asarray(bt), jnp.asarray(pos)
+    out = paged_decode_attention(q, kp, vp, bt, pos)
+    assert max_err(out, paged_reference(q, kp, vp, bt, pos)) < 1e-5
+
+
+@pytest.mark.parametrize("G", [1, 2])
+def test_pos_at_a_blocks_first_and_last_slot(G, monkeypatch):
+    """The mask bites in a row's last block only, and there exactly at
+    ``pos``: first slot of the table, last slot of a block, first slot of
+    the next, the table's very last slot."""
+    force_group(monkeypatch, G)
+    r = np.random.default_rng(60 + G)
+    n_max, bs = 4, 128
+    edges = [0, bs - 1, bs, 2 * bs - 1, 2 * bs, n_max * bs - 1]
+    q, kp, vp, bt, _ = random_paged_case(r, len(edges), 2, 64, bs, n_max,
+                                         group=2)
+    pos = jnp.asarray(edges, jnp.int32)
+    out = paged_decode_attention(q, kp, vp, bt, pos)
+    assert max_err(out, paged_reference(q, kp, vp, bt, pos)) < 1e-5
+
+
+def test_shared_prefix_blocks_and_idle_rows_on_the_dummy_block(monkeypatch):
+    """Rows 0 and 1 share their first two pool blocks (a cached prefix) and
+    part; rows 2 and 4 are idle: position 0 under a zeroed table, so they
+    read the dummy block 0, with a decoding row between them."""
+    force_group(monkeypatch, 2)
+    r = np.random.default_rng(70)
+    n_max, bs = 4, 128
+    q, kp, vp, bt, _ = random_paged_case(r, 5, 2, 64, bs, n_max, group=2)
+    bt = np.array(bt)
+    bt[1, :2] = bt[0, :2]
+    bt[2] = bt[4] = 0
+    pos = jnp.asarray([300, 450, 0, 200, 0], jnp.int32)
+    bt = jnp.asarray(bt)
+    out = paged_decode_attention(q, kp, vp, bt, pos)
+    assert max_err(out, paged_reference(q, kp, vp, bt, pos)) < 1e-5
+    # an idle row attends one token: its output is that token's value
+    np.testing.assert_allclose(
+        np.asarray(out[4]), np.asarray(vp[0, 0]).reshape(2, 1, 64).repeat(2, 1)
+        .reshape(4, 64), atol=1e-6)
+
+
+CELL_HEADS = [(32, 1, 64),      # OPT-1.3B: 32 heads of 64, a 2,048-lane row
+              (16, 1, 128),     # OLMoE: 16 heads of 128
+              (2, 4, 64),       # a GQA group of 4, kv heads half a lane tile
+              (2, 4, 128)]
+
+
+@pytest.mark.parametrize("terms", ["alibi", "pad_bias"])
+@pytest.mark.parametrize("KV,group,Hd", CELL_HEADS)
+def test_cell_head_shapes_with_alibi_and_pad_bias(KV, group, Hd, terms):
+    """Both head shapes of the serving cells and a GQA group of 4, each
+    with ALiBi slopes and with a key-side bias over logical positions, at
+    the group size the program takes from the shapes."""
+    r = np.random.default_rng(80 + KV + Hd)
+    B, n_max, bs = 3, 3, 128
+    q, kp, vp, bt, _ = random_paged_case(r, B, KV, Hd, bs, n_max, group=group)
+    pos = jnp.asarray([100, 129, n_max * bs - 1], jnp.int32)
+    H = KV * group
+    slopes = bias = None
+    if terms == "alibi":
+        slopes = jnp.asarray(2.0 ** (-8.0 * np.arange(1, H + 1) / H),
+                             jnp.float32)
+    else:
+        bias = np.zeros((B, n_max * bs), np.float32)
+        bias[:, 1:40] = -1e9               # a left-padded batch's dead slots
+        bias[:, 40:] = r.normal(size=(B, n_max * bs - 40)) * 0.2
+        bias = jnp.asarray(bias)
+    out = paged_decode_attention(q, kp, vp, bt, pos, pad_bias=bias,
+                                 alibi_slopes=slopes)
+    want = paged_reference(q, kp, vp, bt, pos, bias, slopes)
+    assert max_err(out, want) < 1e-5
+
+
+@pytest.mark.parametrize("KV,group,Hd", CELL_HEADS[:3])
+def test_bf16_pools_against_float32_reference(KV, group, Hd):
+    """The stored operands go to the MXU as bf16; probabilities, sums and
+    the numerator stay float32. Against the float32 reference of the same
+    bf16 values only the output's own rounding is left."""
+    r = np.random.default_rng(90 + KV + Hd)
+    q, kp, vp, bt, pos = random_paged_case(r, 3, KV, Hd, 128, 3,
+                                           dtype=jnp.bfloat16, group=group)
+    out = paged_decode_attention(q, kp, vp, bt, pos)
+    assert out.dtype == jnp.bfloat16
+    assert max_err(out, paged_reference(q, kp, vp, bt, pos)) < 2e-2
+
+
 def test_forward_paged_matches_forward_cached():
     """Model-level parity: paged prefill + decode reproduces the dense
     cached path's logits (GQA + rope) with attention_backend='flash', so

@@ -171,3 +171,41 @@ def test_no_pool_sized_copy_compiled_for_v5e(name, one_v5e, monkeypatch):
         if int(np.prod([int(d) for d in m.group(1).split(",")])) >= layer_pool:
             moved.append(m.group(0))
     assert not moved, moved
+
+
+@pytest.mark.parametrize("B,H,KV,Hd,width,terms", [
+    (40, 32, 32, 64, 16, False),    # opt1b3_serve_*: one block a loop step
+    (64, 16, 16, 128, 32, False),   # olmoe1b7b_serve_decode
+    (8, 32, 8, 64, 6, True),        # GQA group of 4 with ALiBi and pad bias
+    (8, 12, 12, 64, 6, True),       # a 768-lane row: four blocks a loop step
+])
+def test_paged_kernel_compiles_for_v5e_at_real_widths(B, H, KV, Hd, width,
+                                                      terms, one_v5e,
+                                                      monkeypatch):
+    """Mosaic takes the streaming kernel at the cells' widths (what interpret
+    mode cannot see: the block-diagonal query's half-tile lane offsets at
+    head size 64, the stacked bf16 product, the VMEM the buffers take), and
+    the pools reach it where they lie: the program holds no copy of one."""
+    from deepspeed_tpu.ops import dispatch
+    from deepspeed_tpu.ops.pallas.paged_decode_attention import \
+        paged_decode_attention
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    blocks = 64
+    pool = sds((blocks, BS, KV * Hd), jnp.bfloat16)
+
+    def call(q, kp, vp, bt, pos, bias, slopes):
+        return paged_decode_attention(
+            q, kp, vp, bt, pos, pad_bias=bias if terms else None,
+            alibi_slopes=slopes if terms else None, interpret=False)
+
+    compiled = jax.jit(call).lower(
+        sds((B, H, Hd), jnp.bfloat16), pool, pool, sds((B, width), jnp.int32),
+        sds((B,), jnp.int32), sds((B, width * BS), jnp.float32),
+        sds((H,), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "paged_decode_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < blocks * BS * KV * Hd

@@ -212,6 +212,24 @@ def test_counters_count_what_the_steps_did():
     assert c["serving/decode_steps"] >= steps
 
 
+def test_live_kv_blocks_counts_every_rows_block_copies():
+    """``serving/decode_live_kv_blocks``: what the paged kernel copies a
+    layer and pool in a fused step, ``pos // block_size + 1`` blocks a
+    decoding row and the dummy block for each idle row of the step."""
+    get_registry().reset()
+    rows, bs = 4, 8
+    engine = tiny_engine(max_running=rows, block_size=bs,
+                         prefix_caching="off")
+    lens, max_new = (5, 11, 100), 7
+    prompts = [np.arange(n, dtype=np.int32) % 64 for n in lens]
+    engine.generate_batch(prompts, max_new_tokens=max_new)
+    c = engine.telemetry_snapshot()["counters"]
+    steps = max_new - 1
+    decoding = sum((n + i) // bs + 1 for n in lens for i in range(steps))
+    idle = c["serving/decode_steps"] * rows - len(lens) * steps
+    assert c["serving/decode_live_kv_blocks"] == decoding + idle
+
+
 def test_range_push_pop_keep_to_their_thread(monkeypatch):
     import jax
 
