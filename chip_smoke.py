@@ -6,15 +6,13 @@ One process, jax touched once, no arguments::
 
 drives the two main paths through the entry points a user calls, at the
 published width of GPT-2 125M (12L, d 768, 12 heads, hd 64, vocab 50,257 —
-the default of both ``bench.py`` and ``dscli serve``), weights random from a
-seed:
+the default of ``dscli serve``), weights random from a seed:
 
 1. *device guard* — jax is on a TPU whose ``device_kind`` has a published
    peak and the selected accelerator agrees; anything else exits non-zero
    within seconds and prints no result;
-2. *train leg* — ``deepspeed_tpu.initialize`` with the configuration of
-   ``bench.py``'s ``build_bench_engine`` (bf16, ZeRO-1, AdamW, remat=dots,
-   unrolled layers) at global batch 32 x seq 1024: one warm-up step (its
+2. *train leg* — ``deepspeed_tpu.initialize`` in bf16 with ZeRO-1, AdamW,
+   remat=dots and unrolled layers at global batch 32 x seq 1024: one warm-up step (its
    wall time is the compile seconds) and five more on one fixed batch; the
    loss is finite and falls; on one device the lowered step holds the flash
    forward/backward and the three fused-CE kernels as Mosaic
@@ -158,7 +156,6 @@ def train_leg(name, dry_run, n_dev, zero_stage, mesh_axes):
     say(f"{name}: ZeRO-{zero_stage} mesh={mesh_axes} global batch {B} x "
         f"seq {S}")
     fresh_leg()
-    # bench.py build_bench_engine's model and engine configuration
     model = gpt2_model(dry_run, remat="dots", loss_chunk=sz["loss_chunk"],
                        scan_layers=False)
     params = model.init_params(jax.random.key(0))

@@ -46,8 +46,7 @@ def set_accelerator(accel: DeepSpeedAccelerator) -> None:
 
 def require_tpu() -> dict:
     """Device guard of the measurement entry points (``chip_smoke.py``,
-    ``bench.py``, ``perfbench/run.py``): they exist to say
-    something about the chip, so anything else is an error — no retry, no
+    ``perfbench/run.py``): they exist to say something about the chip, so anything else is an error — no retry, no
     child probe, no CPU leg. Returns ``{"platform", "kind", "count"}`` as
     jax reports them when the default backend is a TPU whose ``device_kind``
     has a published peak and the selected accelerator agrees; raises

@@ -22,7 +22,8 @@ from __future__ import annotations
 
 import os
 import time
-from typing import Any, Callable, Dict, Optional
+from contextlib import nullcontext
+from typing import Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1439,6 +1440,12 @@ class InferenceEngine:
         return self._config
 
 
+#: the dispatch sites of a serving step (the four action kinds' and the
+#: sub-dispatches), in the order of ``_ensure_paged_jits``' programs
+_DISPATCH_SITES = ("prefill", "decode", "prefill_chunk", "cow", "verify",
+                   "spill", "fetch")
+
+
 class _ServeSession:
     """One paged serving session: scheduler + pools + jit context behind a
     step API. ``generate_batch`` (closed loop) and ``AsyncServingEngine``
@@ -1457,9 +1464,7 @@ class _ServeSession:
         self.engine = engine
         self.sched = sched
         self.pools = pools
-        (self._prefill_jit, self._decode_jit, self._chunk_jit,
-         self._cow_jit, self._verify_jit, self._spill_jit,
-         self._fetch_jit) = jits
+        self._programs = dict(zip(_DISPATCH_SITES, jits))
         # fault containment (serving.fault): the action a fault can be
         # attributed to, the finer-grained dispatch site for the
         # step_faults{kind=} label (an action may run cow/fetch sub-steps
@@ -1551,48 +1556,22 @@ class _ServeSession:
             self._flush_finished()   # admission-time error retirements
             return False
         self.last_action = action
-        with span("serve.exec", **self._span_args(action)):
-            self._exec(action)
+        name, payload = action
+        kind = _ACTION_KINDS.get(name)
+        if kind is None:
+            raise ValueError(f"scheduler action of unknown kind {name!r}")
+        reqs = kind.rows(payload)
+        with span("serve.exec", kind=name, **kind.says(self, reqs)):
+            self._exec(name, kind, reqs)
             with span("serve.commit"):
                 self._flush_finished()
         return True
-
-    def _span_args(self, action) -> Dict[str, Any]:
-        """What ``serve.exec`` says of its action in a kept trace: the
-        identifier that lets a request's prefill and the decode steps that
-        carry it be followed."""
-        kind, payload = action
-        if kind in ("decode", "verify"):
-            return {"kind": kind, "rows": len(payload)}
-        if kind == "prefill":
-            return {"kind": kind, "rid": payload.rid,
-                    "tokens": payload.prefill_target}
-        if kind == "prefill_chunk":
-            return {"kind": kind, "rid": payload.rid,
-                    "tokens": self._chunk_len(payload)}
-        return {"kind": kind}
 
     def _chunk_len(self, req) -> int:
         """Tokens the next prefill chunk of ``req`` computes."""
         remaining = req.prefill_target - req.pos
         return min(self.chunk_tokens, remaining) \
             if self.chunk_tokens else remaining
-
-    def _sample_fetch(self, logits) -> np.ndarray:
-        """One token per row of ``logits``, on the host. The sampler is
-        dispatch only (argmax/categorical run on the device), so the
-        ``np.asarray`` is the step's one wait for the device."""
-        with span("serve.sample"):
-            self.rng, sub = jax.random.split(self.rng)
-            tok = self.engine._sample_host(
-                logits.astype(jnp.float32), self.temperature, self.top_k,
-                sub)
-        with span("serve.fetch"):
-            return np.asarray(tok)
-
-    def _emit_tokens(self, req, tokens) -> None:
-        if self.on_tokens is not None:
-            self.on_tokens(req, [int(t) for t in tokens])
 
     def _flush_finished(self) -> None:
         fin = self.sched.finished
@@ -1632,8 +1611,7 @@ class _ServeSession:
         ``serving/step_faults{kind=}``). The closed loop never calls
         this: ``generate_batch`` propagates, exactly like its
         :class:`PoolExhausted` contract."""
-        kind, payload = self.last_action if self.last_action is not None \
-            else ("unknown", None)
+        kind, payload = self.last_action or ("unknown", None)
         # the LABEL is the finer dispatch site (a cow/fetch sub-step of a
         # prefill action attributes to cow/fetch); request attribution
         # below still follows the enclosing action's payload
@@ -1648,17 +1626,14 @@ class _ServeSession:
         logger.warning(f"serving step fault ({site}): {msg}")
         if not self.pools_alive():
             return "fatal"
-        if kind in ("prefill", "prefill_chunk"):
-            reqs = [payload]
-        elif kind in ("decode", "verify"):
-            # a fused step has no single culprit: every row re-queues
-            # (recompute keeps each greedy-identical), so whichever
-            # request is poison accrues retries until quarantine while
-            # the innocent ones recompute (their retry counts reset as
-            # soon as they emit a token again)
-            reqs = [r for r in payload if r.state == "running"]
-        else:
-            reqs = []
+        known = _ACTION_KINDS.get(kind)
+        # a fused step has no single culprit: every row that still runs
+        # re-queues (recompute keeps each greedy-identical), so whichever
+        # request is poison accrues retries until quarantine while the
+        # innocent ones recompute (their retry counts reset as soon as
+        # they emit a token again)
+        reqs = [r for r in known.rows(payload) if r.state == "running"] \
+            if known is not None else []
         if not reqs:
             return "unattributed"
         # REVERSED: each requeue appendlefts, so walking the batch
@@ -1700,9 +1675,8 @@ class _ServeSession:
                                         sched.prefix_caching, False)
         alloc.attach_host_pool(host_pool)
         sched.reset_pool(alloc)
-        (self._prefill_jit, self._decode_jit, self._chunk_jit,
-         self._cow_jit, self._verify_jit, self._spill_jit,
-         self._fetch_jit) = engine._ensure_paged_jits()
+        self._programs = dict(zip(_DISPATCH_SITES,
+                                  engine._ensure_paged_jits()))
         self.pools = pools
         if self._kv_spill:
             alloc.set_spill(self._spill_block)
@@ -1718,17 +1692,16 @@ class _ServeSession:
         the way weight streaming overlaps layer copies. Never raises:
         any failure degrades to today's destroy-on-reclaim (the host
         pool counts and warns)."""
-        sched, ev = self.sched, self.ev
-        hp = sched.allocator.host_pool
+        hp = self.sched.allocator.host_pool
         if hp is None:
             return False
         prev_site = self.fault_site
         self.fault_site = "spill"    # degraded internally below, but a
         # non-Exception escape (SimulatedCrash) should still read "spill"
         try:
-            _step_fault("spill", "pre")
-            t0 = time.monotonic_ns() if ev is not None else 0
-            sl = self._spill_jit(self.pools, jnp.int32(block))
+            # the gather does not donate (the pools live on): no "post"
+            sl, t0 = self._dispatch("spill", self.pools, np.int32(block),
+                                    post=False, spanned=False)
             ok = hp.put(key, sl["k"], sl["v"])
         except Exception as e:          # SimulatedCrash (BaseException)
             # and record_* invariants still propagate; everything else
@@ -1738,20 +1711,15 @@ class _ServeSession:
             return False
         self.fault_site = prev_site
         if ok:
-            if ev is not None:
-                # dur DELIBERATELY brackets only the gather dispatch +
-                # async-copy kick-off: the D2H itself overlaps the next
-                # fused steps (that overlap is the whole point), so a
-                # sync here would serialize what the tier exists to hide
-                dur = time.monotonic_ns() - t0  # dslint: disable=DS005
-                ev.emit("kv.spill", t_ns=t0, dur_ns=dur,
-                        blocks=1,
-                        bytes=int(sl["k"].nbytes) + int(sl["v"].nbytes),
-                        block=block)
-                if sched.telemetry is not None:
-                    sched.telemetry.phase("spill", dur / 1e6)
-            if sched.telemetry is not None:
-                sched.telemetry.kv_spills.inc()
+            # no sync: the slice DELIBERATELY brackets only the gather
+            # dispatch + async-copy kick-off. The D2H itself overlaps the
+            # next fused steps (that overlap is the whole point), so a wait
+            # here would serialize what the tier exists to hide
+            self._book("spill", t0, [("kv.spill", dict(
+                blocks=1, bytes=int(sl["k"].nbytes) + int(sl["v"].nbytes),
+                block=block))])
+            if self.sched.telemetry is not None:
+                self.sched.telemetry.kv_spills.inc()
         return ok
 
     def demote_prompt(self, tokens) -> int:
@@ -1789,291 +1757,138 @@ class _ServeSession:
     def _land_fetches(self, req, pools, fetches):
         """The H2D copies and scatters of :meth:`_run_fetches`."""
         prev_site = self.fault_site
-        self.fault_site = "fetch"    # a fault in here labels as "fetch";
+        self.fault_site = "fetch"    # the copies label as "fetch" too;
         # restored only on the success path so containment sees the site
-        _step_fault("fetch", "pre")
-        engine, sched, ev = self.engine, self.sched, self.ev
-        alloc = sched.allocator
-        sh = engine._kv_slice_sharding()
-        t0 = time.monotonic_ns() if ev is not None else 0
+        alloc, tel = self.sched.allocator, self.sched.telemetry
+        sh = self.engine._kv_slice_sharding()
+        t0 = time.monotonic_ns() if self.ev is not None else 0
         nbytes = 0
         ntokens = 0
         for dst, key, k_np, v_np, tokens in fetches:
             ks = jax.device_put(jnp.asarray(k_np), sh)
             vs = jax.device_put(jnp.asarray(v_np), sh)
-            out = self._fetch_jit(pools, jnp.int32(dst), ks, vs)
-            _step_fault("fetch", "post")
-            pools = out
+            # no serve.dispatch: the landing lies under serve.kv_fetch
+            pools, _ = self._dispatch("fetch", pools, np.int32(dst), ks, vs,
+                                      spanned=False)
             nbytes += int(k_np.nbytes) + int(v_np.nbytes)
             ntokens += int(tokens)
             if key is not None:
                 alloc.register(dst, key)
                 if alloc.host_pool is not None:
                     alloc.host_pool.remove(key)
-        if sched.telemetry is not None:
+        if tel is not None:
             # observed at LANDING, not admission: a preempt-before-fetch
             # re-admission must not double-count an H2D that never ran
-            sched.telemetry.kv_fetch_hits.inc(len(fetches))
+            tel.kv_fetch_hits.inc(len(fetches))
             if ntokens:
-                sched.telemetry.kv_fetch_tokens.inc(ntokens)
-        if ev is not None:
-            # the scatters are async dispatches: sync so the slice covers
-            # device work, not µs of dispatch (the DS005 rule)
-            jax.block_until_ready(pools)
-            dur = time.monotonic_ns() - t0
-            ev.emit("kv.fetch", rid=req.rid, t_ns=t0, dur_ns=dur,
-                    blocks=len(fetches), bytes=nbytes)
-            if sched.telemetry is not None:
-                sched.telemetry.phase("fetch", dur / 1e6, rid=req.rid)
+                tel.kv_fetch_tokens.inc(ntokens)
+        self._book("fetch", t0, [("kv.fetch", dict(
+            blocks=len(fetches), bytes=nbytes))], rid=req.rid, sync=pools)
         self.fault_site = prev_site
         return pools
 
-    def _exec(self, action) -> None:
-        engine, sched, ev = self.engine, self.sched, self.ev
-        cfg = engine.module.config
-        bs, W, n_max, spec_wb = self.bs, self.W, self.n_max, self.spec_wb
+    def _cow_split(self, req, pools):
+        """Copy-on-write split ahead of a chunk: the request restarts
+        mid-block inside a SHARED cached block — give it a private device
+        copy before any of its writes land."""
+        if req.cow_pending is None:
+            return pools
+        src, dst = req.cow_pending
+        pools, t0 = self._dispatch("cow", pools, np.int32(src), np.int32(dst))
+        self._book("cow", t0, [("req.cow_copy", dict(src=src, dst=dst))],
+                   rid=req.rid, sync=pools)
+        req.cow_pending = None
+        return pools
+
+    # ---- the executor: one pipeline for every kind of action ---- #
+
+    def _dispatch(self, site, *operands, pre=True, post=True, spanned=True):
+        """Call ``site``'s program: the ONE place where a fault, the host
+        clock and ``serve.dispatch`` attach, for the four action kinds and
+        the sub-dispatches ``cow``, ``fetch`` and ``spill``. Returns
+        ``(outputs, t0)``; numpy operands go to the device here. Fault
+        injection (utils/fault_injection.fail_step) costs one None check a
+        consult. ``"pre"`` fires before the dispatch: the pools are intact,
+        the fault is containable per request. ``"post"`` fires between the
+        donating dispatch and the caller's adoption of the outputs:
+        ``self.pools`` still names the consumed buffers, which leaves the
+        session as a mid-step device death would, engine-fatal. ``fault_site``
+        reads the finer site for ``step_faults{kind=}`` meanwhile, and on if
+        the dispatch raises."""
+        prev_site = self.fault_site
+        self.fault_site = site
+        if pre:
+            _step_fault(site, "pre")
+        with span("serve.dispatch") if spanned else nullcontext():
+            t0 = time.monotonic_ns() if self.ev is not None else 0
+            out = self._programs[site](*[
+                jnp.asarray(a) if isinstance(a, (np.ndarray, np.generic))
+                else a for a in operands])
+            if post:
+                _step_fault(site, "post")
+        self.fault_site = prev_site
+        return out, t0
+
+    def _book(self, site, t0, events, rid=None, sync=None) -> None:
+        """Book one timed phase when the recorder is on (off: no clock is
+        read, nothing waits for the device): a slice from ``t0`` to now for
+        each of ``events`` ((kind, fields); ``rid`` unless they name their
+        own) and the phase ledger's sample for ``site``. ``sync`` is what to
+        wait for where the site fetched nothing of its own: dispatch is
+        async, the slice would clock microseconds of it (the DS005 rule)."""
+        if self.ev is None:
+            return
+        if sync is not None:
+            jax.block_until_ready(sync)
+        dur = time.monotonic_ns() - t0
+        for kind, fields in events:
+            self.ev.emit(kind, t_ns=t0, dur_ns=dur, **{"rid": rid, **fields})
+        if self.sched.telemetry is not None:
+            self.sched.telemetry.phase(site, dur / 1e6, rid=rid)
+
+    def _exec(self, name, kind, reqs) -> None:
+        """Run one action through the phases every kind shares; what differs
+        is in ``kind`` (:class:`_ActionKind`). The data between the phases is
+        explicit (operands into the dispatch, ``logits, pools, aux, t0`` out
+        of it, tokens into the commit): reordering them touches no kind."""
+        tel = self.sched.telemetry
         pools = self.pools
-        kind, payload = action
-        # serving fault injection (utils/fault_injection.fail_step): ONE
-        # None check per consult; "pre" fires before any device dispatch
-        # (per-request containable — the pools are intact), "post" fires
-        # between the donating dispatch and the adoption of its outputs
-        # (the local `pools` still names the consumed buffers, so the
-        # exception leaves the session exactly as a mid-step device death
-        # would: engine-fatal). The top consult ticks the injector's
-        # deterministic step counter. fault_site tracks the finer dispatch
-        # site (cow/fetch sub-steps update it) for step_faults{kind=}.
-        self.fault_site = kind
-        _step_fault(kind, "pre", tick=True)
+        logits = None
+        self.fault_site = name       # the action's own "pre" ticks the
+        # injector's step counter, ahead of the sub-dispatches' consults
+        _step_fault(name, "pre", tick=True)
         try:
-            if kind == "wait":
+            if kind.inputs is None:
                 # retry-backoff idle tick: no device work, clock advanced
                 return
-            tel = sched.telemetry
-            if kind == "prefill":
-                req = payload
-                pools = self._run_fetches(req, pools)
-                with span("serve.inputs"):
-                    prefix = req.prefix()
-                    L = prefix.size
-                    Tb = engine._bucket(L, cfg.max_seq)
-                    toks = np.zeros((1, Tb), np.int32)
-                    toks[0, :L] = prefix
-                    table = np.asarray(req.blocks, np.int32)
-                    slots = engine._flat_slots(table, 0, L, Tb, bs)
-                    if tel is not None:
-                        tel.count_prefill(L, Tb)
-                with span("serve.dispatch"):
-                    t0 = time.monotonic_ns() if ev is not None else 0
-                    out = self._prefill_jit(
-                        engine.params, jnp.asarray(toks), pools,
-                        jnp.asarray(slots, jnp.int32), jnp.int32(L - 1))
-                    _step_fault("prefill", "post")
-                    logits, pools = out
-                # fetch the sampled token BEFORE emitting: _sample_host
-                # is device-only (argmax/categorical), so the np.asarray
-                # in _sample_fetch is the sync — emitting first would clock
-                # async dispatch while the device work lands later (DS005)
-                tok = self._sample_fetch(logits)
-                if ev is not None:
-                    # dslint: disable=DS005 (_sample_fetch synced)
-                    dur = time.monotonic_ns() - t0
-                    ev.emit("req.prefill", rid=req.rid, t_ns=t0,
-                            dur_ns=dur, tokens=L)
-                    if sched.telemetry is not None:
-                        sched.telemetry.phase("prefill", dur / 1e6,
-                                              rid=req.rid)
-                with span("serve.commit"):
-                    sched.record_prefill(req, int(tok[0]))
-                    self._emit_tokens(req, [int(tok[0])])
-            elif kind == "prefill_chunk":
-                req = payload
-                pools = self._run_fetches(req, pools)
-                if req.cow_pending is not None:
-                    # copy-on-write split: the request restarts mid-block
-                    # inside a SHARED cached block — give it a private
-                    # device copy before any of its writes land
-                    src, dst = req.cow_pending
-                    self.fault_site = "cow"
-                    _step_fault("cow", "pre")
-                    with span("serve.dispatch"):
-                        t0 = time.monotonic_ns() if ev is not None else 0
-                        out = self._cow_jit(pools, jnp.int32(src),
-                                            jnp.int32(dst))
-                        _step_fault("cow", "post")
-                        pools = out
-                    self.fault_site = kind
-                    if ev is not None:
-                        # dispatch is async: wait for the copy so the
-                        # span covers device work, not µs of dispatch
-                        jax.block_until_ready(pools)
-                        dur = time.monotonic_ns() - t0
-                        ev.emit("req.cow_copy", rid=req.rid, t_ns=t0,
-                                dur_ns=dur, src=src, dst=dst)
-                        if sched.telemetry is not None:
-                            sched.telemetry.phase("cow", dur / 1e6,
-                                                  rid=req.rid)
-                    req.cow_pending = None
-                with span("serve.inputs"):
-                    start = req.pos
-                    step = self._chunk_len(req)
-                    Tb = engine._bucket(step, cfg.max_seq)
-                    prefix = req.prefix()
-                    toks = np.zeros((1, Tb), np.int32)
-                    toks[0, :step] = prefix[start:start + step]
-                    table = np.asarray(req.blocks, np.int32)
-                    slots = engine._flat_slots(table, start, step, Tb, bs)
-                    # the chunk attends over the gathered table, so its cost
-                    # is O(table width × block_size) per layer — bucket the
-                    # width to the next power of two of the request's OWN
-                    # block count (≤ log2(n_max) compiles) instead of paying
-                    # n_max (= max_seq worth of KV) for every short
-                    # cache-hit tail
-                    nb = min(n_max,
-                             1 << max(int(table.size) - 1, 0).bit_length())
-                    bt = np.zeros((1, nb), np.int32)
-                    bt[0, :table.size] = table
-                    if tel is not None:
-                        tel.count_prefill(step, Tb)
-                with span("serve.dispatch"):
-                    t0 = time.monotonic_ns() if ev is not None else 0
-                    out = self._chunk_jit(
-                        engine.params, jnp.asarray(toks), pools,
-                        jnp.asarray(bt), jnp.asarray(slots, jnp.int32),
-                        jnp.int32(start), jnp.int32(step - 1))
-                    _step_fault("prefill_chunk", "post")
-                    logits, pools = out
-                if ev is not None:
-                    # non-final chunks never fetch a result, so the
-                    # dispatch alone would clock near-zero: sync first
-                    # (tracing-only cost) so the slice is device time
-                    jax.block_until_ready(logits)
-                    dur = time.monotonic_ns() - t0
-                    ev.emit("req.prefill_chunk", rid=req.rid, t_ns=t0,
-                            dur_ns=dur, start=start, tokens=step)
-                    if sched.telemetry is not None:
-                        sched.telemetry.phase("prefill_chunk", dur / 1e6,
-                                              rid=req.rid)
-                last = start + step == req.prefill_target
-                if last:
-                    tok = self._sample_fetch(logits)
-                with span("serve.commit"):
-                    if last:
-                        sched.record_prefill_chunk(req, step, int(tok[0]))
-                        self._emit_tokens(req, [int(tok[0])])
-                    else:
-                        sched.record_prefill_chunk(req, step)
-            elif kind == "verify":
-                # speculative multi-token step: the fused decode math
-                # over each request's window (pending token + proposed
-                # candidates) at once, then greedy argmax acceptance —
-                # the accepted candidate prefix plus the first-mismatch
-                # token is exactly what token-by-token decode would emit
-                reqs = payload
-                with span("serve.inputs"):
-                    bt = np.zeros((W, n_max), np.int32)       # zeros → dummy
-                    pos = np.zeros((W,), np.int32)
-                    toks = np.zeros((W, spec_wb), np.int32)
-                    slotm = np.zeros((W, spec_wb), np.int32)
-                    zt = np.zeros((1,), np.int32)
-                    for i in range(W):
-                        if i >= len(reqs):
-                            # inactive rows: junk routed to the dummy block
-                            slotm[i] = engine._flat_slots(zt, 0, 0, spec_wb,
-                                                          bs)
-                            continue
-                        r = reqs[i]
-                        nv = 1 + len(r.spec_tokens)
-                        toks[i, 0] = r.last_token
-                        toks[i, 1:nv] = r.spec_tokens
-                        table = np.asarray(r.blocks, np.int32)
-                        bt[i, :table.size] = table
-                        pos[i] = r.pos
-                        slotm[i] = engine._flat_slots(table, r.pos, nv,
-                                                      spec_wb, bs)
-                with span("serve.dispatch"):
-                    t0 = time.monotonic_ns() if ev is not None else 0
-                    out = self._verify_jit(
-                        engine.params, jnp.asarray(toks), pools,
-                        jnp.asarray(bt), jnp.asarray(slotm),
-                        jnp.asarray(pos))
-                    _step_fault("verify", "post")
-                    logits, pools = out
-                # same argmax the decode path's _sample_host runs, at
-                # every window position; the fetch is the sync point,
-                # so the spec_verify slices below clock device time
-                with span("serve.sample"):
-                    greedy = jnp.argmax(logits.astype(jnp.float32), axis=-1)
-                with span("serve.fetch"):
-                    greedy = np.asarray(greedy)
-                dur = time.monotonic_ns() - t0 if ev is not None else 0
-                if ev is not None and sched.telemetry is not None:
-                    # one ledger sample per fused verify step (the
-                    # per-rid spec_verify events below carry identity)
-                    sched.telemetry.phase("verify", dur / 1e6)
-                with span("serve.commit"):
-                    for i, r in enumerate(reqs):
-                        cands = r.spec_tokens
-                        n_acc = 0
-                        while n_acc < len(cands) \
-                                and int(greedy[i, n_acc]) == cands[n_acc]:
-                            n_acc += 1
-                        emitted = list(cands[:n_acc]) \
-                            + [int(greedy[i, n_acc])]
-                        # truncate at eos HERE so the event's accepted=
-                        # matches what record_verify will commit (its own
-                        # truncation stays as the invariant check)
-                        eos_r = r.eos
-                        if eos_r is not None and int(eos_r) in emitted:
-                            emitted = emitted[:emitted.index(int(eos_r)) + 1]
-                        if ev is not None:
-                            # emitted BEFORE record_verify so a retirement
-                            # this step triggers lands after its slice
-                            ev.emit("req.spec_verify", rid=r.rid, t_ns=t0,
-                                    dur_ns=dur, window=1 + len(cands),
-                                    accepted=len(emitted) - 1)
-                        sched.record_verify(r, emitted)
-                        self._emit_tokens(r, emitted)
-            else:
-                reqs = payload
-                with span("serve.inputs"):
-                    bt = np.zeros((W, n_max), np.int32)       # zeros → dummy
-                    pos = np.zeros((W,), np.int32)
-                    toks = np.zeros((W, 1), np.int32)
-                    for i, r in enumerate(reqs):
-                        bt[i, :len(r.blocks)] = r.blocks
-                        pos[i] = r.pos
-                        toks[i, 0] = r.last_token
-                    if tel is not None:
-                        tel.decode_live_kv_tokens.inc(int(pos.sum()))
-                        # an idle row reads the dummy block: one copy too
-                        tel.decode_live_kv_blocks.inc(int((pos // bs + 1).sum()))
-                with span("serve.dispatch"):
-                    t0 = time.monotonic_ns() if ev is not None else 0
-                    out = self._decode_jit(
-                        engine.params, jnp.asarray(toks), pools,
-                        jnp.asarray(bt), jnp.asarray(pos))
-                    _step_fault("decode", "post")
-                    logits, pools, *moe_counts = out
-                    if moe_counts and tel is not None:
-                        # on its way beside the tokens: no wait of its own
-                        moe_counts[0].copy_to_host_async()
-                tok = self._sample_fetch(logits)
-                if moe_counts and tel is not None:
-                    tel.count_moe(np.asarray(moe_counts[0]))
-                if ev is not None:
-                    # emitted BEFORE record_decode so a retirement this
-                    # tick triggers lands after its final decode slice
-                    # dslint: disable=DS005 (_sample_fetch synced)
-                    dur = time.monotonic_ns() - t0
-                    ev.emit("decode.tick", t_ns=t0, dur_ns=dur,
-                            rids=[r.rid for r in reqs], n=len(reqs))
-                    if sched.telemetry is not None:
-                        sched.telemetry.phase("decode", dur / 1e6)
-                with span("serve.commit"):
-                    for i, r in enumerate(reqs):
-                        sched.record_decode(r, int(tok[i]))
-                        self._emit_tokens(r, [int(tok[i])])
+            for sub in kind.before:
+                # each adopted as it lands: a fault at a later site leaves
+                # the pools the earlier ones handed back
+                pools = sub(self, reqs[0], pools)
+            with span("serve.inputs"):
+                (toks, *rest), part = kind.inputs(self, reqs)
+            (logits, pools, *aux), t0 = self._dispatch(
+                name, self.engine.params, toks, pools, *rest, pre=False)
+            if aux and tel is not None:
+                # an MoE model's assignment counts, on their way beside
+                # the tokens: no wait of their own
+                aux[0].copy_to_host_async()
+            out = kind.tokens(self, logits, reqs, part)
+            if aux and tel is not None:
+                tel.count_moe(np.asarray(aux[0]))
+            if self.ev is not None:
+                # AFTER the tokens' fetch (it is the sync: emitting first
+                # would clock async dispatch; a step that fetched none
+                # waits in _book) and BEFORE the commit, so a retirement
+                # this step triggers lands after its last slice
+                rid, events = kind.events(reqs, part, out)
+                self._book(name, t0, events, rid=rid,
+                           sync=None if any(out) else logits)
+            with span("serve.commit"):
+                for r, tokens in zip(reqs, out):
+                    kind.record(self.sched, r, part, tokens)
+                    if tokens and self.on_tokens is not None:
+                        self.on_tokens(r, tokens)
         finally:
             with span("serve.release"):
                 # rebind even when a record_* invariant raised: the donated
@@ -2083,7 +1898,123 @@ class _ServeSession:
                 # while the device waits (PERF.md §5); the step's logits die
                 # under it too, and not at the return
                 self.pools = pools
-                out = logits = None  # noqa: F841
+                logits = None  # noqa: F841
+
+    # ---- what differs by kind: the hooks _ACTION_KINDS names ---- #
+
+    def _piece_inputs(self, req, prefix, start, n):
+        """Tokens ``start .. start + n`` of ``prefix`` in their compile
+        bucket, the request's block table, and the pool slots they write."""
+        engine = self.engine
+        Tb = engine._bucket(n, engine.module.config.max_seq)
+        toks = np.zeros((1, Tb), np.int32)
+        toks[0, :n] = prefix[start:start + n]
+        table = np.asarray(req.blocks, np.int32)
+        slots = engine._flat_slots(table, start, n, Tb, self.bs)
+        if self.sched.telemetry is not None:
+            self.sched.telemetry.count_prefill(n, Tb)
+        return toks, table, slots.astype(np.int32), np.int32(n - 1)
+
+    def _prefill_inputs(self, reqs):
+        prefix = reqs[0].prefix()
+        toks, _, slots, last = self._piece_inputs(reqs[0], prefix, 0,
+                                                  prefix.size)
+        return (toks, slots, last), (0, prefix.size)
+
+    def _chunk_inputs(self, reqs):
+        req = reqs[0]
+        start, n = req.pos, self._chunk_len(req)
+        toks, table, slots, last = self._piece_inputs(req, req.prefix(),
+                                                      start, n)
+        # the chunk attends over the gathered table, so its cost is
+        # O(table width × block_size) per layer — bucket the width to the
+        # next power of two of the request's OWN block count (≤ log2(n_max)
+        # compiles) instead of paying n_max (= max_seq worth of KV) for
+        # every short cache-hit tail
+        nb = min(self.n_max, 1 << max(int(table.size) - 1, 0).bit_length())
+        bt = np.zeros((1, nb), np.int32)
+        bt[0, :table.size] = table
+        return (toks, bt, slots, np.int32(start), last), (start, n)
+
+    def _decode_inputs(self, reqs):
+        tel = self.sched.telemetry
+        bt = np.zeros((self.W, self.n_max), np.int32)       # zeros → dummy
+        pos = np.zeros((self.W,), np.int32)
+        toks = np.zeros((self.W, 1), np.int32)
+        for i, r in enumerate(reqs):
+            bt[i, :len(r.blocks)] = r.blocks
+            pos[i] = r.pos
+            toks[i, 0] = r.last_token
+        if tel is not None:
+            tel.decode_live_kv_tokens.inc(int(pos.sum()))
+            # an idle row reads the dummy block: one copy too
+            tel.decode_live_kv_blocks.inc(int((pos // self.bs + 1).sum()))
+        return (toks, bt, pos), None
+
+    def _verify_inputs(self, reqs):
+        # speculative multi-token step: the fused decode math over each
+        # request's window (pending token + proposed candidates) at once
+        engine, W, spec_wb, bs = self.engine, self.W, self.spec_wb, self.bs
+        bt = np.zeros((W, self.n_max), np.int32)       # zeros → dummy
+        pos = np.zeros((W,), np.int32)
+        toks = np.zeros((W, spec_wb), np.int32)
+        slotm = np.zeros((W, spec_wb), np.int32)
+        zt = np.zeros((1,), np.int32)
+        for i in range(W):
+            if i >= len(reqs):
+                # inactive rows: junk routed to the dummy block
+                slotm[i] = engine._flat_slots(zt, 0, 0, spec_wb, bs)
+                continue
+            r = reqs[i]
+            nv = 1 + len(r.spec_tokens)
+            toks[i, 0] = r.last_token
+            toks[i, 1:nv] = r.spec_tokens
+            table = np.asarray(r.blocks, np.int32)
+            bt[i, :table.size] = table
+            pos[i] = r.pos
+            slotm[i] = engine._flat_slots(table, r.pos, nv, spec_wb, bs)
+        return (toks, bt, slotm, pos), None
+
+    def _sampled(self, logits, reqs, part):
+        """One sampled token a request; none from a chunk that is not its
+        prefill's last. The sampler is dispatch only (argmax/categorical run
+        on the device), so ``np.asarray`` is the step's one wait for it."""
+        if part is not None and sum(part) < reqs[0].prefill_target:
+            return [[]]
+        with span("serve.sample"):
+            self.rng, sub = jax.random.split(self.rng)
+            tok = self.engine._sample_host(
+                logits.astype(jnp.float32), self.temperature, self.top_k,
+                sub)
+        with span("serve.fetch"):
+            tok = np.asarray(tok)
+        return [[int(t)] for t in tok[:len(reqs)]]
+
+    def _accepted(self, logits, reqs, part):
+        """Greedy acceptance over each row's verify window: the accepted
+        candidate prefix plus the first-mismatch token is exactly what
+        token-by-token decode would emit. The same argmax the decode path's
+        sampler runs, at every window position; the fetch is the sync
+        point, so the spec_verify slices clock device time."""
+        with span("serve.sample"):
+            greedy = jnp.argmax(logits.astype(jnp.float32), axis=-1)
+        with span("serve.fetch"):
+            greedy = np.asarray(greedy)
+        out = []
+        for i, r in enumerate(reqs):
+            cands = r.spec_tokens
+            n_acc = 0
+            while n_acc < len(cands) \
+                    and int(greedy[i, n_acc]) == cands[n_acc]:
+                n_acc += 1
+            emitted = list(cands[:n_acc]) + [int(greedy[i, n_acc])]
+            # truncate at eos HERE so the event's accepted= matches what
+            # record_verify will commit (its own truncation stays as the
+            # invariant check)
+            if r.eos is not None and int(r.eos) in emitted:
+                emitted = emitted[:emitted.index(int(r.eos)) + 1]
+            out.append(emitted)
+        return out
 
     # ---- lifecycle ---- #
 
@@ -2121,3 +2052,70 @@ class _ServeSession:
             from deepspeed_tpu.monitor.health import sample_memory_gauges
             sample_memory_gauges(engine._tel_reg)
         engine._paged_workspace = (self.num_blocks, self.bs, self.pools)
+
+
+class _ActionKind(NamedTuple):
+    """What differs between the kinds of action the scheduler hands over;
+    ``_ServeSession._exec`` runs every kind through the same phases and
+    ``_ACTION_KINDS`` is the one place a kind is looked up. ``s``: the
+    session; ``part``: the ``(start, tokens)`` of its prefix a prefill step
+    computes (None for a fused step); ``out``: a token list for each of
+    ``reqs``."""
+    #: ``(payload) -> reqs``: the requests it carries, in row order
+    rows: Callable
+    #: ``(s, reqs)``: what ``serve.exec`` says of it besides its kind, the
+    #: identifiers a kept trace is followed by
+    says: Callable
+    #: ``(s, req, pools) -> pools`` each: sub-dispatches ahead of its own
+    before: Tuple[Callable, ...] = ()
+    #: ``(s, reqs) -> (operands, part)``: its program's operands after the
+    #: parameters, less the pools (always third), built in numpy under
+    #: ``serve.inputs`` with the counters taken there; None runs nothing
+    inputs: Optional[Callable] = None
+    #: ``(s, logits, reqs, part) -> out``: how tokens come out
+    tokens: Optional[Callable] = None
+    #: ``(reqs, part, out) -> (rid, [(event kind, fields), ...])``: its
+    #: flight-recorder events, and whose exemplar its ledger sample is
+    events: Optional[Callable] = None
+    #: ``(sched, req, part, tokens)``: the scheduler's ``record_*``
+    record: Optional[Callable] = None
+
+
+# an action carries one request (an admission's prefill, whole or a chunk
+# at a time) or the running rows of a fused step
+_ONE = dict(rows=lambda req: [req], says=lambda s, reqs: {
+    "rid": reqs[0].rid, "tokens": s._chunk_len(reqs[0])})
+_FUSED = dict(rows=lambda reqs: reqs,
+              says=lambda s, reqs: {"rows": len(reqs)})
+_ACTION_KINDS: Dict[str, _ActionKind] = {
+    "wait": _ActionKind(rows=lambda _: [], says=lambda s, reqs: {}),
+    "prefill": _ActionKind(
+        **_ONE, before=(_ServeSession._run_fetches,),
+        inputs=_ServeSession._prefill_inputs, tokens=_ServeSession._sampled,
+        events=lambda reqs, part, out: (
+            reqs[0].rid, [("req.prefill", dict(tokens=part[1]))]),
+        record=lambda sched, r, part, t: sched.record_prefill(r, t[0])),
+    "prefill_chunk": _ActionKind(
+        **_ONE, before=(_ServeSession._run_fetches, _ServeSession._cow_split),
+        inputs=_ServeSession._chunk_inputs, tokens=_ServeSession._sampled,
+        events=lambda reqs, part, out: (reqs[0].rid, [
+            ("req.prefill_chunk", dict(start=part[0], tokens=part[1]))]),
+        # the sampled token rides the last chunk alone
+        record=lambda sched, r, part, t:
+            sched.record_prefill_chunk(r, part[1], *t)),
+    "verify": _ActionKind(
+        **_FUSED, inputs=_ServeSession._verify_inputs,
+        tokens=_ServeSession._accepted,
+        # an event a row (they carry identity), one ledger sample a step
+        events=lambda reqs, part, out: (None, [
+            ("req.spec_verify", dict(rid=r.rid, window=1 + len(r.spec_tokens),
+                                     accepted=len(t) - 1))
+            for r, t in zip(reqs, out)]),
+        record=lambda sched, r, part, t: sched.record_verify(r, t)),
+    "decode": _ActionKind(
+        **_FUSED, inputs=_ServeSession._decode_inputs,
+        tokens=_ServeSession._sampled,
+        events=lambda reqs, part, out: (None, [
+            ("decode.tick", dict(rids=[r.rid for r in reqs], n=len(reqs)))]),
+        record=lambda sched, r, part, t: sched.record_decode(r, t[0])),
+}

@@ -598,7 +598,7 @@ def read_last_snapshots(path: str, n: int = 2,
 
 def labeled_series(section: Dict, name: str) -> Dict[str, float]:
     """``{label_value: value}`` for every ``name{k="v"}`` series in a
-    snapshot section (shared by the CLI renderer and bench.py's blob)."""
+    snapshot section (the CLI renderer reads it)."""
     out = {}
     prefix = name + "{"
     for k, v in section.items():

@@ -1,6 +1,6 @@
 """Placement of jax's persistent compilation cache.
 
-Called by the process entry points (``chip_smoke.py``, ``bench.py``,
+Called by the process entry points (``chip_smoke.py``, ``perfbench/run.py``,
 ``dscli serve``, ``benchmarks/*.py``, the launcher for its workers) before
 their first compile, never at ``import deepspeed_tpu``. The directory is part
 of nothing but the lookup, so it must not move between runs: either the

@@ -23,10 +23,13 @@ failure modes TPU fleets actually deliver:
   catch.
 - **serving step faults** (:meth:`FaultInjector.fail_step` +
   ``delay_per_step_s``): the serving-plane mirror of ``guarded_write`` —
-  the paged engine's action executor (``_ServeSession._exec``) consults
-  :func:`step_fault` at every dispatch site (``prefill`` / ``prefill_chunk``
-  / ``decode`` / ``verify`` / ``cow`` / ``spill`` / ``fetch``), one ``None``
-  check when no injector is installed. A scheduled fault raises at a pinned
+  the paged engine consults :func:`step_fault` at every dispatch site
+  (``prefill`` / ``prefill_chunk`` / ``decode`` / ``verify`` / ``cow`` /
+  ``spill`` / ``fetch``) from two places: the top of its action executor
+  (``_ServeSession._exec``: the action's ``pre``, which ticks the step
+  counter) and the one helper every site calls its program through
+  (``_ServeSession._dispatch``: a sub-site's ``pre``, then ``post``). One
+  ``None`` check each when no injector is installed. A scheduled fault raises at a pinned
   logical step: ``phase="pre"`` fires BEFORE the jit dispatch (the donated
   pools are intact — the fault is contained per-request), ``phase="post"``
   fires after the pools were donated but before the step's outputs were

@@ -250,3 +250,43 @@ class TestGenerateBatch:
             tiny_model(), dtype="fp32", serving={"block_size": 8})
         with pytest.raises(ValueError, match="max_seq"):
             engine.generate_batch([np.ones(60, np.int32)], max_new_tokens=10)
+
+
+# --------------------------------------------------------------------- #
+# the executor's table of action kinds
+
+# what ContinuousBatchingScheduler.next_action can return
+ACTION_KINDS = ("wait", "prefill", "prefill_chunk", "verify", "decode")
+
+
+@pytest.mark.parametrize("kind", ACTION_KINDS + ("defragment",))
+def test_executor_table_holds_the_schedulers_action_kinds(kind, monkeypatch):
+    """The session's executor has an entry for every kind the scheduler
+    can hand over and for no other; handed another kind it raises (it
+    used to run anything it did not know as a decode step)."""
+    import inspect
+    import re
+
+    from deepspeed_tpu.inference import engine as engine_mod
+    returned = set(re.findall(
+        r'return \("(\w+)",', inspect.getsource(ContinuousBatchingScheduler)))
+    assert returned == set(ACTION_KINDS)    # the list above is the scheduler's
+    assert set(engine_mod._ACTION_KINDS) == returned
+    if kind in returned:
+        assert isinstance(engine_mod._ACTION_KINDS[kind],
+                          engine_mod._ActionKind)
+        return
+    engine = deepspeed_tpu.init_inference(
+        tiny_model(), dtype="fp32",
+        serving={"block_size": 8, "max_running": 2})
+    with engine._mesh_scope():
+        session = engine.open_serve_session(max_new=4)
+        try:
+            monkeypatch.setattr(session.sched, "next_action",
+                                lambda: (kind, []))
+            with pytest.raises(ValueError, match=f"unknown kind '{kind}'"):
+                session.step()
+            assert session.pools_alive()    # nothing was dispatched
+            assert session.contain_fault(ValueError(kind)) == "unattributed"
+        finally:
+            session.close()
