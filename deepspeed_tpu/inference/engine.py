@@ -765,12 +765,18 @@ class InferenceEngine:
     @staticmethod
     def _sample_host(logits, temperature, top_k, rng):
         if temperature > 0.0:
-            logits = logits / temperature
-            if top_k > 0:
-                kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
-                logits = jnp.where(logits < kth, -jnp.inf, logits)
-            return jax.random.categorical(rng, logits, axis=-1)
+            return InferenceEngine._draw(logits, temperature, top_k, rng)
         return jnp.argmax(logits, axis=-1)
+
+    @staticmethod
+    def _draw(logits, temperature, top_k, rng):
+        """One draw a row at ``temperature`` (positive; may be traced)
+        from the ``top_k`` largest logits (0: all)."""
+        logits = logits / temperature
+        if top_k > 0:
+            kth = jax.lax.top_k(logits, top_k)[0][..., -1:]
+            logits = jnp.where(logits < kth, -jnp.inf, logits)
+        return jax.random.categorical(rng, logits, axis=-1)
 
     # ------------------------------------------------------------------ #
     # KV-cache generation: prefill + fixed-shape decode, no per-token
@@ -1115,6 +1121,19 @@ class InferenceEngine:
                     p, t, pools, slots, li)
 
             def paged_decode(p, t, pools, bt, pos):
+                # the session hands ``t`` over as the step's token feed
+                # ``(prev, idx, toks)``: row i takes the token at
+                # ``prev[idx[i]]``, still on the device as the sampler left
+                # it, where idx[i] >= 0, and the host's ``toks[i]``
+                # otherwise. ONE form whatever step came before (the sampler
+                # leaves its tokens at the decode width), so the first
+                # prefill -> decode of a warm-up compiles all there is; and
+                # no program of its own: the gather is an op of this one
+                if isinstance(t, tuple):
+                    prev, idx, toks = t
+                    t = jnp.where(idx[:, None] >= 0,
+                                  prev[jnp.maximum(idx, 0)][:, None]
+                                  .astype(toks.dtype), toks)
                 return _pinned(mod.forward_paged_decode)(p, t, pools, bt, pos)
 
             def paged_prefill_chunk(p, t, pools, bt, slots, sp, li):
@@ -1157,9 +1176,21 @@ class InferenceEngine:
                     "v": jax.lax.dynamic_update_index_in_dim(
                         pools["v"], vs.astype(pools["v"].dtype), b, axis=1)})
 
-            def watched(fn, donate=()):
-                return self._watched(jax.jit(fn, donate_argnums=donate),
-                                     "inference." + fn.__name__)
+            def paged_sample(logits, key, temperature, top_k, width):
+                # the sampler's whole dispatch as ONE program: the cast,
+                # the draw (``key`` and ``temperature`` None: greedy) and
+                # the widening of a prefill's one token to the decode
+                # width, where the next decode step's feed gathers from
+                logits = logits.astype(jnp.float32)
+                tok = jnp.argmax(logits, axis=-1) if key is None \
+                    else self._draw(logits, temperature, top_k, key)
+                return tok if tok.shape[0] == width \
+                    else jnp.broadcast_to(tok, (width,))
+
+            def watched(fn, donate=(), static=()):
+                return self._watched(
+                    jax.jit(fn, donate_argnums=donate, static_argnums=static),
+                    "inference." + fn.__name__)
 
             self._paged_jits = (
                 watched(paged_prefill, (2,)),
@@ -1171,6 +1202,7 @@ class InferenceEngine:
                 if hasattr(mod, "forward_paged_verify") else None,
                 watched(paged_spill_gather),
                 watched(paged_fetch_scatter, (0,)),
+                watched(paged_sample, static=(3, 4)),
             )
         return self._paged_jits
 
@@ -1443,7 +1475,35 @@ class InferenceEngine:
 #: the dispatch sites of a serving step (the four action kinds' and the
 #: sub-dispatches), in the order of ``_ensure_paged_jits``' programs
 _DISPATCH_SITES = ("prefill", "decode", "prefill_chunk", "cow", "verify",
-                   "spill", "fetch")
+                   "spill", "fetch", "sample")
+
+
+def _on_device(a):
+    """A numpy operand as a device array, a tuple of operands (the decode
+    step's token feed) one by one; anything else as it is."""
+    if isinstance(a, tuple):
+        return tuple(map(_on_device, a))
+    return jnp.asarray(a) if isinstance(a, (np.ndarray, np.generic)) else a
+
+
+class _Launched:
+    """A step on the device's queue whose tokens the host has not fetched:
+    what :meth:`_ServeSession._launch` leaves for :meth:`_ServeSession.land`."""
+    __slots__ = ("name", "kind", "reqs", "part", "tok", "aux", "t0",
+                 "logits", "spent", "live")
+
+    def __init__(self, name, kind, reqs, part, tok, aux, t0, logits, spent):
+        self.name, self.kind, self.reqs, self.part = name, kind, reqs, part
+        #: the sampler's output, its copy to the host under way (None: a
+        #: prefill chunk that is not the last samples nothing)
+        self.tok = tok
+        self.aux, self.t0 = aux, t0
+        #: dropped under ``serve.release`` when the step lands: its logits
+        #: and the handles of the pools it consumed
+        self.logits, self.spent = logits, spent
+        #: by row, whether its token counts; False where the token of the
+        #: step before turned out to be the request's EOS (the overshoot)
+        self.live = [True] * len(reqs)
 
 
 class _ServeSession:
@@ -1455,7 +1515,35 @@ class _ServeSession:
     its test. Single-threaded by contract: every method must run on the
     thread that owns the engine's jit dispatch (the caller's thread for
     generate_batch, the serving loop thread for the async engine), under
-    the engine's ``_mesh_scope``."""
+    the engine's ``_mesh_scope``.
+
+    **One step ahead.** Between two :meth:`step` calls at most ONE launched
+    step is in flight (``_flight``): its program and its sampler are on the
+    device's queue, its positional half is in the scheduler
+    (``advance_*``), its tokens are not on the host. The next call launches
+    the following action BEHIND it, from its tokens on the device (the
+    decode program's feed operand), and only then fetches, commits and releases it, under
+    the new step's device time. The overlap is the device's queue: there is
+    no second thread. Nobody but :meth:`step` and :meth:`land` may touch a
+    step in flight, and everything that needs its tokens on the host or
+    would undo its rows lands it first — :meth:`cancel`,
+    :meth:`demote_prompt`, :meth:`contain_fault`, :meth:`restart_engine`,
+    :meth:`close`, and inside :meth:`step` the cases ``plans_ahead`` and
+    ``_ActionKind.ahead`` name. A front-end that touches ``sched`` itself
+    (a knob, load shedding, a look-up by rid) calls :meth:`land` first; a
+    ``submit`` needs none. Served tokens are those of the serial order (the
+    pipe at depth zero); the one difference is a request whose EOS lands
+    while its next step is already queued: that step's token for it is
+    dropped (``_Launched.live``), its write went beyond ``pos`` into the
+    request's own block, never read and never registered. (Under
+    ``temperature > 0`` such a step still takes its ``random.split`` and
+    holds the row for one step more, so the OTHER rows' draws after an EOS
+    are another sample of the same distribution, not the serial order's.)"""
+
+    #: private: False pins the pipe at depth zero (every step lands in the
+    #: call that launched it), the serial order the exactness tests compare
+    #: with. Nothing a user can set.
+    _run_ahead = True
 
     def __init__(self, engine, sched, pools, jits, *, max_new, temperature,
                  top_k, rng, eos_token_id, spec_wb, W, n_max, bs, num_blocks,
@@ -1503,6 +1591,10 @@ class _ServeSession:
         self.retain_finished = retain_finished
         self._finished_seen = 0
         self._closed = False
+        self._flight: Optional[_Launched] = None
+        # the newest sampled tokens at the decode width, on the device:
+        # what a decode step's feed gathers from (the step in flight's, if any)
+        self._tok_dev = None
 
     # ---- request front-end ---- #
 
@@ -1535,6 +1627,7 @@ class _ServeSession:
     def cancel(self, req) -> bool:
         """Cancel between engine steps; fires ``on_finish`` for the
         retired request."""
+        self.land()
         ok = self.sched.cancel_request(req)
         self._flush_finished()
         return ok
@@ -1542,29 +1635,64 @@ class _ServeSession:
     # ---- stepping ---- #
 
     def step(self) -> bool:
-        """Execute ONE scheduler action (admission prefill, prefill
-        chunk, fused decode or fused verify). Returns False when nothing
-        is runnable — queue and running batch both empty."""
+        """Launch ONE scheduler action (admission prefill, prefill chunk,
+        fused decode or fused verify) and land the one launched before it,
+        in that order when the new action can be chosen and fed without the
+        old one's tokens on the host, else the other way round. Returns
+        False when nothing is runnable — queue and running batch both empty
+        — and nothing is in flight."""
         if self._closed:
             raise RuntimeError("serving session is closed")
         self.last_action = None      # a fault in next_action itself must
         self.fault_site = None       # not be attributed to the PREVIOUS
         # step's action or dispatch site
-        with span("serve.schedule"):
-            action = self.sched.next_action()
+        if self._flight is not None \
+                and not self.sched.plans_ahead(self._flight.reqs):
+            self.land()
+        try:
+            with span("serve.schedule"):
+                action = self.sched.next_action()
+        except BaseException:
+            self.land()              # its tokens are sound
+            raise
         if action is None:
+            if self._flight is not None:
+                self.land()          # an EOS may yet free what a queued
+                return True          # request waits for: choose again
             self._flush_finished()   # admission-time error retirements
             return False
         self.last_action = action
         name, payload = action
         kind = _ACTION_KINDS.get(name)
         if kind is None:
+            self.land()
             raise ValueError(f"scheduler action of unknown kind {name!r}")
         reqs = kind.rows(payload)
+        if not kind.ahead or reqs[0].cow_pending is not None \
+                or reqs[0].fetch_pending:
+            # the action reads tokens on the host (verify), is a tick of
+            # the clock alone (wait), or copies blocks a row in flight may
+            # share: the serial order
+            self.land()
         with span("serve.exec", kind=name, **kind.says(self, reqs)):
-            self._exec(name, kind, reqs)
-            with span("serve.commit"):
-                self._flush_finished()
+            try:
+                launched = self._launch(name, kind, reqs)
+            except BaseException:
+                # a fault at this launch is contained as ever, AFTER the
+                # step before it landed: no token lost, none given twice
+                self.land()
+                raise
+            self.land()              # the step before, under this one
+            if launched is not None:
+                self._advance(launched)
+                self._flight = launched
+                if launched.tok is None or not kind.ahead \
+                        or not self._run_ahead:
+                    self.land()      # nothing to wait for, or depth zero
+            if self._finished_seen < len(self.sched.finished):
+                # retirements the launch itself made (an admission's error)
+                with span("serve.commit"):
+                    self._flush_finished()
         return True
 
     def _chunk_len(self, req) -> int:
@@ -1611,6 +1739,7 @@ class _ServeSession:
         ``serving/step_faults{kind=}``). The closed loop never calls
         this: ``generate_batch`` propagates, exactly like its
         :class:`PoolExhausted` contract."""
+        self.land()                  # step() has, before it raised
         kind, payload = self.last_action or ("unknown", None)
         # the LABEL is the finer dispatch site (a cow/fetch sub-step of a
         # prefill action attributes to cow/fetch); request attribution
@@ -1664,6 +1793,7 @@ class _ServeSession:
         recovery recompute-preemption already proves greedy-identical.
         The content-addressed host KV tier survives (its bytes live in
         host RAM); the device prefix cache starts cold."""
+        self.land()
         engine, sched = self.engine, self.sched
         sched.allocator.set_spill(None)      # hook captured the dead pools
         host_pool = sched.allocator.host_pool
@@ -1735,6 +1865,7 @@ class _ServeSession:
             raise RuntimeError("serving session is closed")
         if not self._kv_spill:
             return 0
+        self.land()
         return self.sched.allocator.demote_chain(tokens)
 
     def _run_fetches(self, req, pools):
@@ -1804,9 +1935,10 @@ class _ServeSession:
 
     def _dispatch(self, site, *operands, pre=True, post=True, spanned=True):
         """Call ``site``'s program: the ONE place where a fault, the host
-        clock and ``serve.dispatch`` attach, for the four action kinds and
-        the sub-dispatches ``cow``, ``fetch`` and ``spill``. Returns
-        ``(outputs, t0)``; numpy operands go to the device here. Fault
+        clock and ``serve.dispatch`` attach, for the four action kinds, the
+        sub-dispatches ``cow``, ``fetch`` and ``spill`` and the sampler
+        (``sample``: under ``serve.sample``, no fault consult of its own).
+        Returns ``(outputs, t0)``; numpy operands go to the device here. Fault
         injection (utils/fault_injection.fail_step) costs one None check a
         consult. ``"pre"`` fires before the dispatch: the pools are intact,
         the fault is containable per request. ``"post"`` fires between the
@@ -1821,9 +1953,7 @@ class _ServeSession:
             _step_fault(site, "pre")
         with span("serve.dispatch") if spanned else nullcontext():
             t0 = time.monotonic_ns() if self.ev is not None else 0
-            out = self._programs[site](*[
-                jnp.asarray(a) if isinstance(a, (np.ndarray, np.generic))
-                else a for a in operands])
+            out = self._programs[site](*map(_on_device, operands))
             if post:
                 _step_fault(site, "post")
         self.fault_site = prev_site
@@ -1846,59 +1976,97 @@ class _ServeSession:
         if self.sched.telemetry is not None:
             self.sched.telemetry.phase(site, dur / 1e6, rid=rid)
 
-    def _exec(self, name, kind, reqs) -> None:
-        """Run one action through the phases every kind shares; what differs
-        is in ``kind`` (:class:`_ActionKind`). The data between the phases is
-        explicit (operands into the dispatch, ``logits, pools, aux, t0`` out
-        of it, tokens into the commit): reordering them touches no kind."""
+    def _launch(self, name, kind, reqs) -> Optional[_Launched]:
+        """Put one action on the device's queue: its sub-dispatches, its
+        operands, its program and its sampler, then the tokens' copy to the
+        host — and wait for none of it. What differs by kind is in ``kind``
+        (:class:`_ActionKind`); the data between the phases is explicit.
+        The pools a dispatch hands back are adopted at once, so that a
+        fault between a donating dispatch and its adoption (``"post"``)
+        leaves ``self.pools`` naming consumed buffers, as a device death
+        would. Between this and :meth:`land` the step belongs to the
+        session alone (see the class docstring)."""
         tel = self.sched.telemetry
-        pools = self.pools
-        logits = None
         self.fault_site = name       # the action's own "pre" ticks the
         # injector's step counter, ahead of the sub-dispatches' consults
         _step_fault(name, "pre", tick=True)
+        if kind.inputs is None:
+            # retry-backoff idle tick: no device work, clock advanced
+            return None
+        for sub in kind.before:
+            # each adopted as it lands: a fault at a later site leaves
+            # the pools the earlier ones handed back
+            self.pools = sub(self, reqs[0], self.pools)
+        with span("serve.inputs"):
+            (toks, *rest), part = kind.inputs(self, reqs)
+        if kind.fed and self._flight is not None:
+            self.sched.stats["decode_steps_ahead"] += 1
+            if tel is not None:
+                tel.decode_steps_ahead.inc()
+        spent = self.pools
+        (logits, pools, *aux), t0 = self._dispatch(
+            name, self.engine.params, toks, spent, *rest, pre=False)
+        self.pools = pools
+        if aux and tel is not None:
+            # an MoE model's assignment counts, on their way beside
+            # the tokens: no wait of their own
+            aux[0].copy_to_host_async()
+        tok = kind.sample(self, logits, reqs, part)
+        return _Launched(name, kind, reqs, part, tok, aux, t0, logits, spent)
+
+    def _advance(self, step: _Launched) -> None:
+        """The positional half of a launched step, once the step before it
+        has landed: every token the blocks it fills are keyed by is on the
+        host then, and so is an EOS that makes a row of it an overshoot."""
+        step.live = [r.state == "running" for r in step.reqs]
+        if step.kind.advance is not None:
+            for r, live in zip(step.reqs, step.live):
+                if live:
+                    step.kind.advance(self.sched, r, step.part)
+
+    def land(self) -> None:
+        """Fetch, commit and release the step in flight, if there is one:
+        the tokens' fetch (the one wait for the device), the MoE counts, the
+        booked phase, ``record``/``on_tokens`` row by row, the retirements,
+        and the drop of what the step held. A front-end calls this before
+        it touches the scheduler's running rows itself."""
+        step, self._flight = self._flight, None
+        if step is None:
+            return
+        tel = self.sched.telemetry
+        reqs, part = step.reqs, step.part
         try:
-            if kind.inputs is None:
-                # retry-backoff idle tick: no device work, clock advanced
-                return
-            for sub in kind.before:
-                # each adopted as it lands: a fault at a later site leaves
-                # the pools the earlier ones handed back
-                pools = sub(self, reqs[0], pools)
-            with span("serve.inputs"):
-                (toks, *rest), part = kind.inputs(self, reqs)
-            (logits, pools, *aux), t0 = self._dispatch(
-                name, self.engine.params, toks, pools, *rest, pre=False)
-            if aux and tel is not None:
-                # an MoE model's assignment counts, on their way beside
-                # the tokens: no wait of their own
-                aux[0].copy_to_host_async()
-            out = kind.tokens(self, logits, reqs, part)
-            if aux and tel is not None:
-                tel.count_moe(np.asarray(aux[0]))
-            if self.ev is not None:
-                # AFTER the tokens' fetch (it is the sync: emitting first
-                # would clock async dispatch; a step that fetched none
-                # waits in _book) and BEFORE the commit, so a retirement
-                # this step triggers lands after its last slice
-                rid, events = kind.events(reqs, part, out)
-                self._book(name, t0, events, rid=rid,
-                           sync=None if any(out) else logits)
-            with span("serve.commit"):
-                for r, tokens in zip(reqs, out):
-                    kind.record(self.sched, r, part, tokens)
-                    if tokens and self.on_tokens is not None:
-                        self.on_tokens(r, tokens)
-        finally:
-            with span("serve.release"):
-                # rebind even when a record_* invariant raised: the donated
-                # input buffers are gone either way, and close()/end() must
-                # see the live pools. The span is there because dropping
-                # the consumed pool handles costs the host a millisecond
-                # while the device waits (PERF.md §5); the step's logits die
-                # under it too, and not at the return
-                self.pools = pools
-                logits = None  # noqa: F841
+            with span("serve.fetch"):
+                got = None if step.tok is None else np.asarray(step.tok)
+        except BaseException:
+            self.sched.abandon(reqs)     # recomputed, like a preemption
+            raise
+        # a row whose EOS landed meanwhile is left out: its token is dropped
+        rows = [(r, t) for r, t, live in zip(
+            reqs, step.kind.tokens(got, reqs), step.live) if live]
+        if step.aux and tel is not None:
+            tel.count_moe(np.asarray(step.aux[0]))
+        if self.ev is not None:
+            # AFTER the tokens' fetch (it is the sync: emitting first
+            # would clock async dispatch; a step that fetched none
+            # waits in _book) and BEFORE the commit, so a retirement
+            # this step triggers lands after its last slice
+            rid, events = step.kind.events(
+                [r for r, _ in rows], part, [t for _, t in rows])
+            self._book(step.name, step.t0, events, rid=rid,
+                       sync=None if any(t for _, t in rows)
+                       else step.logits)
+        with span("serve.commit"):
+            for r, tokens in rows:
+                step.kind.record(self.sched, r, part, tokens)
+                if tokens and self.on_tokens is not None:
+                    self.on_tokens(r, tokens)
+            self._flush_finished()
+        with span("serve.release"):
+            # the span is there because dropping the consumed pool handles
+            # costs the host a millisecond (PERF.md §5); the step's logits
+            # die under it too, and not at the return
+            step.spent = step.logits = step.tok = None
 
     # ---- what differs by kind: the hooks _ACTION_KINDS names ---- #
 
@@ -1941,15 +2109,28 @@ class _ServeSession:
         bt = np.zeros((self.W, self.n_max), np.int32)       # zeros → dummy
         pos = np.zeros((self.W,), np.int32)
         toks = np.zeros((self.W, 1), np.int32)
+        # a row of the step in flight takes its token from that step's
+        # sampler on the device, at the row it had there (rows move as
+        # requests retire and are admitted); the host holds the others'
+        idx = np.full((self.W,), -1, np.int32)
+        ahead = self._flight
+        src = {} if ahead is None else {
+            id(r): j for j, r in enumerate(ahead.reqs)}
         for i, r in enumerate(reqs):
             bt[i, :len(r.blocks)] = r.blocks
             pos[i] = r.pos
-            toks[i, 0] = r.last_token
+            j = src.get(id(r))
+            if j is None:
+                toks[i, 0] = r.last_token
+            else:
+                idx[i] = j
         if tel is not None:
             tel.decode_live_kv_tokens.inc(int(pos.sum()))
             # an idle row reads the dummy block: one copy too
             tel.decode_live_kv_blocks.inc(int((pos // self.bs + 1).sum()))
-        return (toks, bt, pos), None
+        # _tok_dev: a request decodes after its own prefill, so the sampler
+        # has left tokens there by the first decode step
+        return ((self._tok_dev, idx, toks), bt, pos), None
 
     def _verify_inputs(self, reqs):
         # speculative multi-token step: the fused decode math over each
@@ -1975,31 +2156,45 @@ class _ServeSession:
             slotm[i] = engine._flat_slots(table, r.pos, nv, spec_wb, bs)
         return (toks, bt, slotm, pos), None
 
-    def _sampled(self, logits, reqs, part):
-        """One sampled token a request; none from a chunk that is not its
-        prefill's last. The sampler is dispatch only (argmax/categorical run
-        on the device), so ``np.asarray`` is the step's one wait for it."""
+    def _sample(self, logits, reqs, part):
+        """Launch side of a sampled step: one token a request, none from a
+        chunk that is not its prefill's last. The sampler is dispatch only
+        (argmax/categorical run on the device); the tokens stay there, at
+        the decode width for the next step's feed, while their copy to the
+        host starts."""
         if part is not None and sum(part) < reqs[0].prefill_target:
-            return [[]]
+            return None
         with span("serve.sample"):
-            self.rng, sub = jax.random.split(self.rng)
-            tok = self.engine._sample_host(
-                logits.astype(jnp.float32), self.temperature, self.top_k,
-                sub)
-        with span("serve.fetch"):
-            tok = np.asarray(tok)
+            key = temperature = None     # greedy draws nothing
+            if self.temperature > 0.0:
+                self.rng, key = jax.random.split(self.rng)
+                temperature = np.float32(self.temperature)
+            tok, _ = self._dispatch(
+                "sample", logits, key, temperature, self.top_k, self.W,
+                pre=False, post=False, spanned=False)
+            self._tok_dev = tok
+            tok.copy_to_host_async()
+        return tok
+
+    @staticmethod
+    def _sampled(tok, reqs):
+        """Landing side: the fetched tokens, row by row."""
+        if tok is None:
+            return [[] for _ in reqs]
         return [[int(t)] for t in tok[:len(reqs)]]
 
-    def _accepted(self, logits, reqs, part):
+    def _greedy(self, logits, reqs, part):
+        """Launch side of a verify step: the same argmax the decode path's
+        sampler runs, at every window position."""
+        with span("serve.sample"):
+            return jnp.argmax(logits.astype(jnp.float32), axis=-1)
+
+    @staticmethod
+    def _accepted(greedy, reqs):
         """Greedy acceptance over each row's verify window: the accepted
         candidate prefix plus the first-mismatch token is exactly what
-        token-by-token decode would emit. The same argmax the decode path's
-        sampler runs, at every window position; the fetch is the sync
+        token-by-token decode would emit. The fetch before it is the sync
         point, so the spec_verify slices clock device time."""
-        with span("serve.sample"):
-            greedy = jnp.argmax(logits.astype(jnp.float32), axis=-1)
-        with span("serve.fetch"):
-            greedy = np.asarray(greedy)
         out = []
         for i, r in enumerate(reqs):
             cands = r.spec_tokens
@@ -2026,6 +2221,12 @@ class _ServeSession:
         active-session slot. Idempotent."""
         if self._closed:
             return
+        try:
+            self.land()              # nothing stays in flight
+        finally:
+            self._close()
+
+    def _close(self) -> None:
         self._closed = True
         engine = self.engine
         # the demotion hook captures THIS session's live pools: a stale
@@ -2056,11 +2257,11 @@ class _ServeSession:
 
 class _ActionKind(NamedTuple):
     """What differs between the kinds of action the scheduler hands over;
-    ``_ServeSession._exec`` runs every kind through the same phases and
-    ``_ACTION_KINDS`` is the one place a kind is looked up. ``s``: the
-    session; ``part``: the ``(start, tokens)`` of its prefix a prefill step
-    computes (None for a fused step); ``out``: a token list for each of
-    ``reqs``."""
+    ``_ServeSession._launch`` and ``land`` run every kind through the same
+    phases and ``_ACTION_KINDS`` is the one place a kind is looked up.
+    ``s``: the session; ``part``: the ``(start, tokens)`` of its prefix a
+    prefill step computes (None for a fused step); ``out``: a token list for
+    each of ``reqs``."""
     #: ``(payload) -> reqs``: the requests it carries, in row order
     rows: Callable
     #: ``(s, reqs)``: what ``serve.exec`` says of it besides its kind, the
@@ -2072,40 +2273,64 @@ class _ActionKind(NamedTuple):
     #: parameters, less the pools (always third), built in numpy under
     #: ``serve.inputs`` with the counters taken there; None runs nothing
     inputs: Optional[Callable] = None
-    #: ``(s, logits, reqs, part) -> out``: how tokens come out
+    #: its first operand is the feed ``(prev, idx, toks)``: the newest
+    #: sampled tokens on the device, the rows that take theirs from there
+    #: (the step in flight's), and the host's tokens for the others
+    fed: bool = False
+    #: may be launched while the step before it is unfetched, and stay in
+    #: flight itself: it reads no token on the host. (A prefill of a request
+    #: that is a row of the step in flight cannot come up: the scheduler
+    #: has those rows past their prefill.)
+    ahead: bool = False
+    #: ``(s, logits, reqs, part) -> array or None``: the sampler's dispatch,
+    #: at the launch
+    sample: Optional[Callable] = None
+    #: ``(fetched, reqs) -> out``: how tokens come out of what it left, at
+    #: the landing
     tokens: Optional[Callable] = None
     #: ``(reqs, part, out) -> (rid, [(event kind, fields), ...])``: its
     #: flight-recorder events, and whose exemplar its ledger sample is
     events: Optional[Callable] = None
-    #: ``(sched, req, part, tokens)``: the scheduler's ``record_*``
+    #: ``(sched, req, part)``: the positional half of the scheduler's
+    #: ``record_*``, known at the launch (None: ``record`` does it all)
+    advance: Optional[Callable] = None
+    #: ``(sched, req, part, tokens)``: the rest of it, at the landing
     record: Optional[Callable] = None
+
+
+def _commit_one(sched, r, part, t):
+    """A prefill's token, if this piece of it sampled one."""
+    if t:
+        sched.commit_token(r, t[0])
 
 
 # an action carries one request (an admission's prefill, whole or a chunk
 # at a time) or the running rows of a fused step
 _ONE = dict(rows=lambda req: [req], says=lambda s, reqs: {
-    "rid": reqs[0].rid, "tokens": s._chunk_len(reqs[0])})
+    "rid": reqs[0].rid, "tokens": s._chunk_len(reqs[0])}, ahead=True,
+    sample=_ServeSession._sample, tokens=_ServeSession._sampled,
+    record=_commit_one)
 _FUSED = dict(rows=lambda reqs: reqs,
               says=lambda s, reqs: {"rows": len(reqs)})
 _ACTION_KINDS: Dict[str, _ActionKind] = {
     "wait": _ActionKind(rows=lambda _: [], says=lambda s, reqs: {}),
     "prefill": _ActionKind(
         **_ONE, before=(_ServeSession._run_fetches,),
-        inputs=_ServeSession._prefill_inputs, tokens=_ServeSession._sampled,
+        inputs=_ServeSession._prefill_inputs,
         events=lambda reqs, part, out: (
             reqs[0].rid, [("req.prefill", dict(tokens=part[1]))]),
-        record=lambda sched, r, part, t: sched.record_prefill(r, t[0])),
+        advance=lambda sched, r, part: sched.advance_prefill(r)),
     "prefill_chunk": _ActionKind(
         **_ONE, before=(_ServeSession._run_fetches, _ServeSession._cow_split),
-        inputs=_ServeSession._chunk_inputs, tokens=_ServeSession._sampled,
+        inputs=_ServeSession._chunk_inputs,
         events=lambda reqs, part, out: (reqs[0].rid, [
             ("req.prefill_chunk", dict(start=part[0], tokens=part[1]))]),
         # the sampled token rides the last chunk alone
-        record=lambda sched, r, part, t:
-            sched.record_prefill_chunk(r, part[1], *t)),
+        advance=lambda sched, r, part: sched.advance_prefill_chunk(
+            r, part[1], last=sum(part) >= r.prefill_target)),
     "verify": _ActionKind(
         **_FUSED, inputs=_ServeSession._verify_inputs,
-        tokens=_ServeSession._accepted,
+        sample=_ServeSession._greedy, tokens=_ServeSession._accepted,
         # an event a row (they carry identity), one ledger sample a step
         events=lambda reqs, part, out: (None, [
             ("req.spec_verify", dict(rid=r.rid, window=1 + len(r.spec_tokens),
@@ -2113,9 +2338,11 @@ _ACTION_KINDS: Dict[str, _ActionKind] = {
             for r, t in zip(reqs, out)]),
         record=lambda sched, r, part, t: sched.record_verify(r, t)),
     "decode": _ActionKind(
-        **_FUSED, inputs=_ServeSession._decode_inputs,
-        tokens=_ServeSession._sampled,
+        **_FUSED, inputs=_ServeSession._decode_inputs, fed=True, ahead=True,
+        sample=_ServeSession._sample, tokens=_ServeSession._sampled,
         events=lambda reqs, part, out: (None, [
             ("decode.tick", dict(rids=[r.rid for r in reqs], n=len(reqs)))]),
-        record=lambda sched, r, part, t: sched.record_decode(r, t[0])),
+        advance=lambda sched, r, part: sched.advance_decode(r),
+        record=lambda sched, r, part, t:
+            sched.commit_token(r, t[0], fused=True)),
 }

@@ -92,6 +92,20 @@ that step. Hence cached = prompt + generated[:-1], pos = len(prompt) +
 len(generated) - 1 whenever the request is running (and past prefill).
 While prefilling, ``pos < prefill_target == len(prefix())`` counts the
 chunked/cache-hit progress.
+
+**A launched step** (the engine's loop runs one step ahead): a step's
+result has a positional half that is fixed before its token is known —
+``pos`` moves on, the filled blocks register, a request that reaches
+``max_new`` BY COUNT gives its row and blocks back — and a token half.
+``record_*`` does both at once; a loop that holds a step's tokens on the
+device calls ``advance_*`` when the step is launched and
+:meth:`commit_token` when it lands. In between, ``pos`` is one ahead of the
+invariant above for the step's rows (``generated`` lacks the token in
+flight) and a request retired by count waits in ``retiring`` for its last
+token: :meth:`next_action` plans on exactly what the serial order would see,
+because none of it depends on a token's value — save an EOS, which the
+engine handles as an overshoot — and everything else (cancel, time-out,
+preemption, fault containment) first lands the step.
 """
 
 from __future__ import annotations
@@ -141,8 +155,8 @@ class ServingTelemetry:
                "kv_blocks_free", "kv_block_utilization", "kv_fragmentation",
                "cold_blocks", "prefill_steps", "prefill_chunks",
                "prefill_tokens", "prefill_padded_tokens",
-               "decode_steps", "decode_live_kv_tokens",
-               "decode_live_kv_blocks",
+               "decode_steps", "decode_steps_ahead",
+               "decode_live_kv_tokens", "decode_live_kv_blocks",
                "prefix_cache_lookups", "prefix_cache_hits",
                "prefix_cache_hit_tokens",
                "kv_host_blocks", "kv_host_bytes", "kv_spills",
@@ -287,6 +301,14 @@ class ServingTelemetry:
     def decode_steps(self):
         return self.registry.counter(
             "serving/decode_steps", "fused decode steps (all rows at once)")
+
+    @property
+    def decode_steps_ahead(self):
+        return self.registry.counter(
+            "serving/decode_steps_ahead",
+            "fused decode steps dispatched while the step before them was "
+            "unfetched (their tokens fed on the device): over decode_steps, "
+            "how often the loop ran a step ahead")
 
     @property
     def decode_live_kv_tokens(self):
@@ -638,7 +660,7 @@ class ContinuousBatchingScheduler:
         self.stats = {"decode_steps": 0, "verify_steps": 0,
                       "emitted_tokens": 0, "spec_proposed": 0,
                       "spec_accepted": 0, "spec_rollbacks": 0,
-                      "preemptions": 0}
+                      "preemptions": 0, "decode_steps_ahead": 0}
         self.telemetry = telemetry
         # flight recorder (monitor/events.py): None when disabled, so
         # every emit site below gates at one None check
@@ -658,6 +680,9 @@ class ContinuousBatchingScheduler:
         self.step_seq = 0
         self.waiting: deque = deque()
         self.running: List[Request] = []   # admission-ordered
+        # retired BY COUNT when their last step was launched (row and
+        # blocks handed on), waiting for that step's token to land
+        self.retiring: List[Request] = []
         self.finished: List[Request] = []
         self._admit_counter = 0
         # rid_base: the engine threads a per-engine offset through so rids
@@ -784,7 +809,7 @@ class ContinuousBatchingScheduler:
         return req
 
     def all_done(self) -> bool:
-        return not self.waiting and not self.running
+        return not self.waiting and not self.running and not self.retiring
 
     def _deadline_retired(self, req: Request) -> None:
         """Called at every permanent retirement: the deadline sweep's
@@ -1221,8 +1246,11 @@ class ContinuousBatchingScheduler:
             self.step_seq += 1
         return action
 
-    def _sweep_deadlines(self) -> None:
+    def _expired(self) -> List[Tuple[Request, str]]:
+        """The waiting and running requests whose deadline has passed, each
+        with what to say of it."""
         now = None
+        out = []
         for req in list(self.waiting) + list(self.running):
             expired = None
             if req.deadline_steps is not None and \
@@ -1238,7 +1266,45 @@ class ContinuousBatchingScheduler:
                                f"exceeded ({waited_ms:.0f} ms since "
                                "submission)")
             if expired is not None:
-                self.timeout_request(req, expired)
+                out.append((req, expired))
+        return out
+
+    def _sweep_deadlines(self) -> None:
+        for req, expired in self._expired():
+            self.timeout_request(req, expired)
+
+    def plans_ahead(self, rows: List[Request]) -> bool:
+        """Whether :meth:`next_action` can choose while a launched step over
+        ``rows`` still holds its tokens on the device, i.e. whether the
+        choice reads none of them and undoes none of the rows: not when the
+        n-gram proposer would read the tokens, when a deadline sweep would
+        retire one of ``rows``, or when growing the decode rows' blocks
+        would have to preempt (a victim re-queues prompt + generated).
+        Reads only; conservative about the last (an admission that takes
+        the turn grows nothing)."""
+        if self.spec_k > 0:
+            return False
+        if self._deadline_live and any(
+                any(req is r for r in rows) for req, _ in self._expired()):
+            return False
+        bs = self.allocator.block_size
+        grow = sum(1 for r in self.running
+                   if not r.prefilling and r.pos >= len(r.blocks) * bs)
+        return grow <= self.allocator.num_free
+
+    def abandon(self, rows: List[Request]) -> None:
+        """The tokens of a launched step over ``rows`` were lost (its fetch
+        raised): every row it advanced goes back to the queue, those retired
+        by count included, to be recomputed from prompt + generated like a
+        preempted request. Earliest-admitted ends at the queue's head."""
+        for req in reversed(rows):
+            if req.state != RUNNING:
+                continue
+            if any(req is r for r in self.retiring):
+                self.retiring.remove(req)
+                self.running.append(req)      # its blocks went at the launch
+            self._demote_to_queue(req)
+        self._tel_gauges()
 
     def _next_action(self) -> Optional[Tuple[str, object]]:
         action = self._try_admit()
@@ -1443,12 +1509,8 @@ class ContinuousBatchingScheduler:
     def record_prefill(self, req: Request, token: int) -> None:
         """The engine prefilled ``req.prefix()`` whole and sampled
         ``token`` from the last position."""
-        req.pos = len(req.prefix())
-        req.prefilling = False
-        self._register_full_blocks(req)
-        req.generated.append(int(token))
-        self._record_token_time(req)
-        self._maybe_finish(req)
+        self.advance_prefill(req)
+        self.commit_token(req, token)
 
     def record_prefill_chunk(self, req: Request, n_tokens: int,
                              token: Optional[int] = None) -> None:
@@ -1456,30 +1518,68 @@ class ContinuousBatchingScheduler:
         the engine passes the ``token`` it sampled from the prefix's last
         position, completing the prefill exactly like
         :meth:`record_prefill`."""
+        self.advance_prefill_chunk(req, n_tokens, last=token is not None)
+        if token is not None:
+            self.commit_token(req, token)
+
+    def record_decode(self, req: Request, token: int) -> None:
+        """One decode step: the previous ``last_token``'s k/v was written at
+        slot ``pos`` and ``token`` sampled from the resulting logits."""
+        self.advance_decode(req)
+        self.commit_token(req, token, fused=True)
+
+    # the two halves of the records above, for a loop that launches a step
+    # before it holds the tokens of the one before (see the module docstring)
+
+    def advance_prefill(self, req: Request) -> None:
+        """A whole prefill of ``req.prefix()`` was launched."""
+        req.pos = len(req.prefix())
+        req.prefilling = False
+        self._register_full_blocks(req)
+        self._retire_by_count(req)
+
+    def advance_prefill_chunk(self, req: Request, n_tokens: int,
+                              last: bool) -> None:
+        """A prefill chunk of ``n_tokens`` was launched; ``last`` when it
+        samples the request's next token."""
         req.pos += int(n_tokens)
         if req.pos > req.prefill_target:
             raise ValueError(
                 f"prefill chunk overran request {req.rid}: pos {req.pos} > "
                 f"target {req.prefill_target}")
         self._register_full_blocks(req)
-        if token is None:
+        if not last:
             return
         if req.pos != req.prefill_target:
             raise ValueError(
                 f"request {req.rid} sampled a token at pos {req.pos} before "
                 f"reaching its prefill target {req.prefill_target}")
         req.prefilling = False
-        req.generated.append(int(token))
-        self._record_token_time(req)
-        self._maybe_finish(req)
+        self._retire_by_count(req)
 
-    def record_decode(self, req: Request, token: int) -> None:
-        """One decode step: the previous ``last_token``'s k/v was written at
-        slot ``pos`` and ``token`` sampled from the resulting logits."""
+    def advance_decode(self, req: Request) -> None:
+        """A decode step over ``req`` was launched: ``last_token``'s k/v goes
+        to slot ``pos``."""
         req.pos += 1
         self._register_full_blocks(req)
+        self._retire_by_count(req)
+
+    def _retire_by_count(self, req: Request) -> None:
+        """The step just launched samples ``req``'s ``max_new``-th token:
+        whatever that token is, the request needs no further step, so its
+        row and its blocks are handed on now (later programs run after this
+        one on the device) and :meth:`commit_token` finishes it."""
+        if len(req.generated) + 1 >= req.max_new:
+            self.running.remove(req)
+            self._free_blocks(req)
+            self.retiring.append(req)
+
+    def commit_token(self, req: Request, token: int,
+                     fused: bool = False) -> None:
+        """The token of ``req``'s launched step landed."""
         req.generated.append(int(token))
-        self.stats["emitted_tokens"] += 1
+        if fused:
+            self.stats["emitted_tokens"] += 1
         self._record_token_time(req)
         self._maybe_finish(req)
 
@@ -1608,8 +1708,11 @@ class ContinuousBatchingScheduler:
         if done:
             req.state = FINISHED
             self._deadline_retired(req)
-            self.running.remove(req)
-            self._free_blocks(req)
+            if any(req is r for r in self.retiring):
+                self.retiring.remove(req)   # row and blocks went at launch
+            else:
+                self.running.remove(req)
+                self._free_blocks(req)
             self.finished.append(req)
             if self.events is not None:
                 self.events.emit("req.retire", rid=req.rid,

@@ -56,6 +56,7 @@ import queue
 import signal as _signal
 import threading
 import time
+from contextlib import nullcontext
 from collections import deque
 from typing import Any, Callable, Dict, List, Optional
 
@@ -484,11 +485,18 @@ class AsyncServingEngine:
             with self._cv:
                 cmds = list(self._intake)
                 self._intake.clear()
-            with span("serve.intake", n=len(cmds)):
+            # an empty intake is no span: a trace holds what happened
+            with span("serve.intake", n=len(cmds)) if cmds \
+                    else nullcontext():
                 for kind, h in cmds:
                     if kind == "submit":
                         self._process_submit(h)
-                    elif kind == "demote":
+                        continue
+                    # the session runs a step ahead: anything but a
+                    # submission reads or undoes the running rows, so the
+                    # step in flight lands first
+                    self._session.land()
+                    if kind == "demote":
                         self._process_demote(h)
                     elif kind == "knobs":
                         self._process_knobs(h)
@@ -613,6 +621,8 @@ class AsyncServingEngine:
         if bound <= 0:
             return
         sched = self._session.sched
+        if len(sched.waiting) > bound:
+            self._session.land()     # retirements in their order
         while len(sched.waiting) > bound:
             idx = self.policy.select_shed_victim(sched)
             if idx is None or not 0 <= idx < len(sched.waiting):
@@ -862,6 +872,10 @@ class AsyncServingEngine:
             # allocator stays leak-free for the next session (on_finish
             # terminates each handle as "cancelled")
             sched = self._session.sched
+            try:
+                self._session.land()   # a step in flight: its tokens count
+            except Exception:  # noqa: BLE001 — best-effort teardown
+                pass
             for r in list(sched.waiting) + list(sched.running):
                 try:
                     self._session.cancel(r)

@@ -813,8 +813,9 @@ class TestDs009:
         rule, and the serving_metrics_steady budgets exist."""
         from dslint.contracts import budgets_for
         table = budgets_for("serving_metrics_steady")
-        assert {"inference.paged_decode", "inference.paged_verify",
-                "inference.paged_prefill", "inference.paged_prefill_chunk",
+        assert {"inference.paged_decode", "inference.paged_sample",
+                "inference.paged_verify", "inference.paged_prefill",
+                "inference.paged_prefill_chunk",
                 "inference.paged_cow"} == set(table)
         import deepspeed_tpu.monitor as mon
         root = os.path.dirname(mon.__file__)

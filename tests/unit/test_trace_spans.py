@@ -111,8 +111,13 @@ def serve(engine, prompts, max_new, rec=None):
 
 
 STEP_PHASES = ("serve.intake", "serve.schedule", "serve.exec")
-EXEC_PHASES = ("serve.inputs", "serve.dispatch", "serve.sample",
-               "serve.fetch", "serve.commit", "serve.release")
+# a step is launched inside its serve.exec; it lands (PR 29: the loop runs
+# one step ahead) inside the NEXT step's serve.exec, after that launch, or,
+# where the next action needs its tokens on the host, ahead of the choice,
+# under serve.step itself
+LAUNCH_PHASES = ("serve.inputs", "serve.dispatch", "serve.sample")
+LAND_PHASES = ("serve.fetch", "serve.commit", "serve.release")
+EXEC_PHASES = LAUNCH_PHASES + LAND_PHASES
 
 # serving config, prompts and what serve.exec must say, per action kind
 MOTIF = np.tile(np.arange(1, 9, dtype=np.int32), 3)         # 24 tokens
@@ -146,9 +151,26 @@ def test_serving_loop_spans(kind, recorder):
             assert s["parent"] is None
         elif name in STEP_PHASES:
             assert s["parent"] == pb("serve.step")
-        else:
-            assert name in EXEC_PHASES, name
+        elif name in LAUNCH_PHASES:
             assert s["parent"] == pb("serve.exec")
+        else:
+            assert name in LAND_PHASES, name
+            assert s["parent"] in (pb("serve.exec"), pb("serve.step"))
+    fetched_under = {s["parent"] for s in recorder.named(pb("serve.fetch"))}
+    # under serve.exec: a verify step lands at once (the proposer reads its
+    # tokens on the host), any other under the launch that followed it;
+    # under serve.step: ahead of a choice that needs the tokens, and the
+    # last step of all, with nothing left to launch
+    assert fetched_under == {pb("serve.exec"), pb("serve.step")}
+    # a span is something that happened: an intake with no command and a
+    # trailing flush with nothing to flush leave none, and a decode step's
+    # token feed is an operand of its program, not a dispatch of its own
+    assert all(s["args"]["n"] > 0 for s in recorder.named(pb("serve.intake")))
+    assert len(recorder.named(pb("serve.commit"))) \
+        < 2 * len(recorder.named(pb("serve.exec")))
+    if kind == "decode":
+        assert len(recorder.named(pb("serve.dispatch"))) \
+            == len(recorder.named(pb("serve.exec")))
 
     execs = [s["args"] for s in recorder.named(pb("serve.exec"))
              if s["args"]["kind"] == kind]
@@ -277,7 +299,7 @@ def test_serving_programs_carry_their_names():
         jits = engine._ensure_paged_jits()
     names = ["paged_prefill", "paged_decode", "paged_prefill_chunk",
              "paged_cow", "paged_verify", "paged_spill_gather",
-             "paged_fetch_scatter"]
+             "paged_fetch_scatter", "paged_sample"]
     assert [j.inner.__name__ for j in jits] == names
     assert [j.__name__ for j in jits] \
         == [f"watched[inference.{n}]" for n in names]

@@ -153,6 +153,11 @@ BUDGETS: List[CompileBudget] = [
         "per-request positions are traced vectors — one program no "
         "matter how many requests/tokens flow through"),
     CompileBudget(
+        "inference.paged_sample", "serving_steady", 2,
+        "the sampler as one program at two widths (a prefill's one row "
+        "of logits, the fused step's rows): see "
+        "serving_async_steady"),
+    CompileBudget(
         "inference.paged_prefill", "serving_steady", 2,
         "whole-prompt prefill compiles once per 128-token prompt-length "
         "bucket; the steady scenario stays within two buckets"),
@@ -162,6 +167,11 @@ BUDGETS: List[CompileBudget] = [
     CompileBudget(
         "inference.paged_decode", "serving_chunked", 1,
         "chunked prefill interleaves with the SAME fused decode program"),
+    CompileBudget(
+        "inference.paged_sample", "serving_chunked", 2,
+        "the sampler as one program at two widths (a prefill's one row "
+        "of logits, the fused step's rows): see "
+        "serving_async_steady"),
     CompileBudget(
         "inference.paged_prefill_chunk", "serving_chunked", 4,
         "one program per (chunk bucket, table-width power-of-two) pair; "
@@ -180,6 +190,11 @@ BUDGETS: List[CompileBudget] = [
         "no-match fallback steps ride the SAME fused decode program "
         "speculation-off serving uses"),
     CompileBudget(
+        "inference.paged_sample", "serving_speculative", 2,
+        "the sampler as one program at two widths (a prefill's one row "
+        "of logits, the fused step's rows): see "
+        "serving_async_steady"),
+    CompileBudget(
         "inference.paged_prefill", "serving_speculative", 2,
         "admission prefill is untouched by speculation: one compile per "
         "128-token prompt bucket, the scenario stays within two"),
@@ -196,6 +211,15 @@ BUDGETS: List[CompileBudget] = [
         "executes through the same _ServeSession as generate_batch, the "
         "batch stays fixed-width over max_running slots, positions stay "
         "traced vectors — arrivals mid-flight must not retrace"),
+    CompileBudget(
+        "inference.paged_sample", "serving_async_steady", 2,
+        "the sampler's whole dispatch (cast, draw, and the widening of a "
+        "prefill's one token to the decode width, where the next decode "
+        "step's feed operand gathers from) as ONE program at two widths: "
+        "a prefill's one row of logits and the fused step's rows — the "
+        "first prefill -> decode compiles both, and a step launched "
+        "behind an unfetched decode step adds none (its feed is an "
+        "operand of paged_decode, one form whatever step came before)"),
     CompileBudget(
         "inference.paged_verify", "serving_async_steady", 1,
         "fused verify under the open loop: one program per k window "
@@ -220,6 +244,11 @@ BUDGETS: List[CompileBudget] = [
         "lock — they never touch the jit cache, donate a buffer, or "
         "perturb an input signature"),
     CompileBudget(
+        "inference.paged_sample", "serving_metrics_steady", 2,
+        "the sampler as one program at two widths (a prefill's one row "
+        "of logits, the fused step's rows): see "
+        "serving_async_steady"),
+    CompileBudget(
         "inference.paged_verify", "serving_metrics_steady", 1,
         "fused verify under scrape load: one program per k window "
         "bucket, same as the unobserved loop"),
@@ -238,6 +267,11 @@ BUDGETS: List[CompileBudget] = [
         "inference.paged_decode", "serving_tiered_steady", 1,
         "THE fused decode step is tier-independent: demotion/fetch are "
         "separate copy programs, the decode signature never changes"),
+    CompileBudget(
+        "inference.paged_sample", "serving_tiered_steady", 2,
+        "the sampler as one program at two widths (a prefill's one row "
+        "of logits, the fused step's rows): see "
+        "serving_async_steady"),
     CompileBudget(
         "inference.paged_verify", "serving_tiered_steady", 1,
         "THE fused verify step under tiering: one program per k window "
@@ -268,6 +302,11 @@ BUDGETS: List[CompileBudget] = [
         "scenario injects exactly one engine-fatal fault, and recovery "
         "rebuilds the jit wrappers once (same shapes, one compile)"),
     CompileBudget(
+        "inference.paged_sample", "serving_faulted_steady", 4,
+        "the sampler as one program at two widths (a prefill's one row "
+        "of logits, the fused step's rows), per engine built: see "
+        "serving_async_steady"),
+    CompileBudget(
         "inference.paged_prefill", "serving_faulted_steady", 4,
         "two 128-token prompt buckets, each at most twice (steady + one "
         "post-restart recompile)"),
@@ -295,6 +334,11 @@ BUDGETS: List[CompileBudget] = [
         "traced shard_map, per-request positions stay traced vectors — "
         "one program, same as tp=1 (sharding must not multiply programs)"),
     CompileBudget(
+        "inference.paged_sample", "serving_sharded_steady", 2,
+        "the sampler as one program at two widths (a prefill's one row "
+        "of logits, the fused step's rows): see "
+        "serving_async_steady"),
+    CompileBudget(
         "inference.paged_prefill", "serving_sharded_steady", 2,
         "whole-prompt prefill under tp>1: one compile per 128-token "
         "prompt bucket exactly as at tp=1; the scenario spans two"),
@@ -315,6 +359,11 @@ BUDGETS: List[CompileBudget] = [
         "one fused decode program PER REPLICA (N=2): each engine owns "
         "its jit wrappers; the router's host-side dispatch must add "
         "zero — a third compile means routed traffic retraced a step"),
+    CompileBudget(
+        "inference.paged_sample", "serving_replicated_steady", 4,
+        "the sampler as one program at two widths (a prefill's one row "
+        "of logits, the fused step's rows), per engine built: see "
+        "serving_async_steady"),
     CompileBudget(
         "inference.paged_verify", "serving_replicated_steady", 2,
         "one k-window-bucket verify program per replica (N=2); routed "
@@ -347,6 +396,11 @@ BUDGETS: List[CompileBudget] = [
         "often as untraced — a second compile means instrumentation "
         "leaked into the traced program"),
     CompileBudget(
+        "inference.paged_sample", "serving_traced_steady", 2,
+        "the sampler as one program at two widths (a prefill's one row "
+        "of logits, the fused step's rows): see "
+        "serving_async_steady"),
+    CompileBudget(
         "inference.paged_verify", "serving_traced_steady", 1,
         "one k-window-bucket verify program, same as untraced: the "
         "verify phase observe reuses the step's existing host sync"),
@@ -369,6 +423,11 @@ BUDGETS: List[CompileBudget] = [
         "spill actions are host-side scheduler state, spec k=0 rides "
         "this same program — a second compile means a knob action "
         "perturbed the decode signature"),
+    CompileBudget(
+        "inference.paged_sample", "serving_adaptive_steady", 2,
+        "the sampler as one program at two widths (a prefill's one row "
+        "of logits, the fused step's rows): see "
+        "serving_async_steady"),
     CompileBudget(
         "inference.paged_verify", "serving_adaptive_steady", 1,
         "the verify window is bucketed to the power of two of the "
