@@ -61,10 +61,17 @@ class BlockAllocator:
     ``block_size`` tokens; block 0 (``DUMMY_BLOCK``) is never handed out.
     With ``prefix_cache=True``, full blocks are content-addressed and
     freed-but-cached blocks are kept COLD for reuse until allocation
-    pressure reclaims them LRU-first."""
+    pressure reclaims them LRU-first.
+
+    The second kind of state it manages (a model with recurrent layers):
+    ``state_slots`` rows of the state pools, slot 0 the dummy like block 0,
+    one handed to a request at its admission and back at its release. A
+    slot holds no content worth keeping once freed, so there is nothing to
+    reference-count: the next holder's first prefill piece starts it from
+    zero."""
 
     def __init__(self, num_blocks: int, block_size: int,
-                 prefix_cache: bool = False):
+                 prefix_cache: bool = False, state_slots: int = 0):
         if num_blocks < 2:
             raise ValueError(f"num_blocks={num_blocks}: need at least one "
                              "allocatable block besides the reserved dummy")
@@ -90,6 +97,13 @@ class BlockAllocator:
         # demoted chains for re-materialization on admission
         self.host_pool = None
         self._spill_fn = None       # (block, key) -> bool; session-scoped
+        if state_slots and prefix_cache:
+            raise ValueError(
+                "a cached block says nothing of the recurrent state at its "
+                "end: prefix caching needs state snapshots at block "
+                "boundaries, which are not built")
+        self.state_slots = int(state_slots)
+        self._free_slots = list(range(self.state_slots - 1, 0, -1))
 
     # ------------------------------------------------------------------ #
     # capacity accounting
@@ -134,6 +148,25 @@ class BlockAllocator:
         """Blocks still referenced — empty once every request retired
         (the test-suite teardown assertion; cold blocks are NOT leaks)."""
         return {b: r for b, r in self._ref.items() if r > 0}
+
+    @property
+    def slots_held(self) -> int:
+        """State slots requests hold right now."""
+        return max(self.state_slots - 1, 0) - len(self._free_slots)
+
+    def allocate_slot(self) -> int:
+        """A free state slot (never 0), the last one freed first; None when
+        all are held. 0 where the model keeps no state."""
+        if not self.state_slots:
+            return 0
+        return self._free_slots.pop() if self._free_slots else None
+
+    def free_slot(self, slot: int) -> None:
+        if not slot:
+            return
+        if slot in self._free_slots or not 0 < slot < self.state_slots:
+            raise ValueError(f"state slot {slot} is not held")
+        self._free_slots.append(slot)
 
     # ------------------------------------------------------------------ #
     # allocate / free / acquire

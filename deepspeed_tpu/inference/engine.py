@@ -831,6 +831,15 @@ class InferenceEngine:
             return NamedSharding(self.mesh, P(None, None, "tp"))
         return NamedSharding(self.mesh, P())
 
+    def _state_slots(self) -> int:
+        """Rows of the state pools a model with recurrent layers needs (one
+        a running request and the dummy), 0 for a model without: read from
+        the model's cache spec, the one place what it keeps is declared."""
+        spec = getattr(getattr(self.module, "config", None), "cache_spec",
+                       None) or {}
+        return int(self._config.serving.max_running) + 1 \
+            if spec.get("state") else 0
+
     def _kv_host_pool_for(self, num_blocks: int, block_size: int,
                           caching: bool):
         """The persistent host-RAM KV tier for the current serving
@@ -839,6 +848,11 @@ class InferenceEngine:
         Content addressing makes entries valid across serves and even
         fresh pool workspaces; only a geometry/dtype change rebuilds."""
         kh = getattr(self._config.serving, "kv_host", None)
+        if kh is not None and kh.enabled and self._state_slots():
+            raise ValueError(
+                "serving.kv_host is on but the model keeps a recurrent "
+                "state beside its KV: a block on the host says nothing of "
+                "the state at its end (no state snapshot is built)")
         if kh is None or not kh.enabled or not caching:
             return None
         if str(kh.spill) not in ("auto", "off"):
@@ -1049,12 +1063,15 @@ class InferenceEngine:
         workspace has no valid cached content, so the caller must drop any
         persisted prefix-cache state alongside it."""
         pw = getattr(self, "_paged_workspace", None)
-        if pw is not None and pw[0] == num_blocks and pw[1] == block_size:
+        slots = self._state_slots()
+        if pw is not None and pw[0] == num_blocks and pw[1] == block_size \
+                and (not slots or pw[2]["state"][0].shape[1] == slots):
             leaves = jax.tree.leaves(pw[2])
             if not any(getattr(a, "is_deleted", lambda: False)() for a in leaves):
                 return pw[2], True
-        pools = self.module.init_paged_cache(num_blocks, block_size,
-                                             dtype=self.dtype)
+        pools = self.module.init_paged_cache(
+            num_blocks, block_size, dtype=self.dtype,
+            **({"state_slots": slots} if slots else {}))
         kv_sh = self._kv_head_sharding()
         pools = jax.tree.map(lambda a: jax.device_put(a, kv_sh), pools)
         self._paged_workspace = (num_blocks, block_size, pools)
@@ -1074,7 +1091,8 @@ class InferenceEngine:
 
         if not caching:
             self._paged_alloc = None
-            return BlockAllocator(num_blocks, block_size)
+            return BlockAllocator(num_blocks, block_size,
+                                  state_slots=self._state_slots())
         pa = self._paged_alloc
         if (pools_reused and pa is not None
                 and pa.num_blocks == num_blocks
@@ -1106,21 +1124,26 @@ class InferenceEngine:
                     pools)
 
             def _pinned(fn):
-                def run(*args):
+                def run(*args, **state):
                     # an MoE model's decode step returns its [L, E + 1]
                     # assignment counts third
-                    logits, pools, *aux = fn(*args)
+                    logits, pools, *aux = fn(*args, **state)
                     return (logits, _pin(pools), *aux)
                 return run
 
             # named functions, not lambdas: the name is what a device trace
             # calls the program (``jit_paged_decode``) and what the
             # persistent compile cache keys it by
-            def paged_prefill(p, t, pools, slots, li):
-                return _pinned(mod.forward_paged_prefill)(
-                    p, t, pools, slots, li)
+            # ``ss``: the state slot(s) of a model that keeps a recurrent
+            # state (its cache spec), the last operand; None for the others
+            def _state(ss, name):
+                return {} if ss is None else {name: ss}
 
-            def paged_decode(p, t, pools, bt, pos):
+            def paged_prefill(p, t, pools, slots, li, ss=None):
+                return _pinned(mod.forward_paged_prefill)(
+                    p, t, pools, slots, li, **_state(ss, "state_slot"))
+
+            def paged_decode(p, t, pools, bt, pos, ss=None):
                 # the session hands ``t`` over as the step's token feed
                 # ``(prev, idx, toks)``: row i takes the token at
                 # ``prev[idx[i]]``, still on the device as the sampler left
@@ -1134,11 +1157,12 @@ class InferenceEngine:
                     t = jnp.where(idx[:, None] >= 0,
                                   prev[jnp.maximum(idx, 0)][:, None]
                                   .astype(toks.dtype), toks)
-                return _pinned(mod.forward_paged_decode)(p, t, pools, bt, pos)
+                return _pinned(mod.forward_paged_decode)(
+                    p, t, pools, bt, pos, **_state(ss, "state_slots"))
 
-            def paged_prefill_chunk(p, t, pools, bt, slots, sp, li):
+            def paged_prefill_chunk(p, t, pools, bt, slots, sp, li, ss=None):
                 return _pinned(mod.forward_paged_prefill_chunk)(
-                    p, t, pools, bt, slots, sp, li)
+                    p, t, pools, bt, slots, sp, li, **_state(ss, "state_slot"))
 
             def paged_verify(p, t, pools, bt, slots, pos):
                 return _pinned(mod.forward_paged_verify)(
@@ -1376,6 +1400,24 @@ class InferenceEngine:
         if chunk_tokens < 0:
             raise ValueError("serving.prefill_chunk_tokens must be >= 0")
         chunk_ok = hasattr(self.module, "forward_paged_prefill_chunk")
+        # what cannot hold beside a recurrent state yet, each refused from
+        # the model's cache spec
+        stateful = bool(self._state_slots())
+        if stateful and pc_mode == "on":
+            raise ValueError(
+                "serving.prefix_caching='on' but the model keeps a "
+                "recurrent state beside its KV: a cached block says nothing "
+                "of the state at its end (snapshots at block boundaries "
+                "are not built)")
+        if stateful and str(srv.speculative.mode) != "off":
+            raise ValueError(
+                f"serving.speculative.mode={str(srv.speculative.mode)!r} "
+                "but the model keeps a recurrent state: a verify window "
+                "rewinds to the last accepted position and a state cannot "
+                "be rewound (no snapshot is kept)")
+        if stateful and self.mesh.shape.get("tp", 1) > 1:
+            raise ValueError("serving.tp > 1 but the model keeps a recurrent "
+                             "state: its state pools are not sharded")
         if not chunk_ok:
             if pc_mode == "on":
                 raise ValueError(
@@ -1386,7 +1428,7 @@ class InferenceEngine:
                 raise ValueError(
                     "serving.prefill_chunk_tokens set but the model has no "
                     "forward_paged_prefill_chunk")
-        caching = chunk_ok and pc_mode != "off"
+        caching = chunk_ok and pc_mode != "off" and not stateful
 
         # ---- speculative decoding (n-gram self-speculation) ----
         spec = srv.speculative
@@ -1591,6 +1633,9 @@ class _ServeSession:
         self.retain_finished = retain_finished
         self._finished_seen = 0
         self._closed = False
+        # a model that keeps a recurrent state takes the requests' state
+        # slots as each program's last operand
+        self._stateful = "state" in pools
         self._flight: Optional[_Launched] = None
         # the newest sampled tokens at the decode width, on the device:
         # what a decode step's feed gathers from (the step in flight's, if any)
@@ -1863,6 +1908,11 @@ class _ServeSession:
         the session has no spill hook / host tier)."""
         if self._closed:
             raise RuntimeError("serving session is closed")
+        if self._stateful:
+            raise NotImplementedError(
+                "a prefill->decode handoff moves KV blocks through the host "
+                "tier, and this model keeps a recurrent state beside them "
+                "that no block holds")
         if not self._kv_spill:
             return 0
         self.land()
@@ -2081,13 +2131,19 @@ class _ServeSession:
         slots = engine._flat_slots(table, start, n, Tb, self.bs)
         if self.sched.telemetry is not None:
             self.sched.telemetry.count_prefill(n, Tb)
+            if self._stateful and start == 0:
+                self.sched.telemetry.count_state_reset()
         return toks, table, slots.astype(np.int32), np.int32(n - 1)
+
+    def _state_of(self, req):
+        """The trailing operand of a stateful model's prefill programs."""
+        return (np.int32(req.state_slot),) if self._stateful else ()
 
     def _prefill_inputs(self, reqs):
         prefix = reqs[0].prefix()
         toks, _, slots, last = self._piece_inputs(reqs[0], prefix, 0,
                                                   prefix.size)
-        return (toks, slots, last), (0, prefix.size)
+        return (toks, slots, last, *self._state_of(reqs[0])), (0, prefix.size)
 
     def _chunk_inputs(self, reqs):
         req = reqs[0]
@@ -2102,7 +2158,8 @@ class _ServeSession:
         nb = min(self.n_max, 1 << max(int(table.size) - 1, 0).bit_length())
         bt = np.zeros((1, nb), np.int32)
         bt[0, :table.size] = table
-        return (toks, bt, slots, np.int32(start), last), (start, n)
+        return (toks, bt, slots, np.int32(start), last,
+                *self._state_of(req)), (start, n)
 
     def _decode_inputs(self, reqs):
         tel = self.sched.telemetry
@@ -2128,9 +2185,16 @@ class _ServeSession:
             tel.decode_live_kv_tokens.inc(int(pos.sum()))
             # an idle row reads the dummy block: one copy too
             tel.decode_live_kv_blocks.inc(int((pos // self.bs + 1).sum()))
+            if self._stateful:
+                tel.count_state(len(reqs))
+        state = ()
+        if self._stateful:
+            slots = np.zeros((self.W,), np.int32)           # zeros → dummy
+            slots[:len(reqs)] = [r.state_slot for r in reqs]
+            state = (slots,)
         # _tok_dev: a request decodes after its own prefill, so the sampler
         # has left tokens there by the first decode step
-        return ((self._tok_dev, idx, toks), bt, pos), None
+        return ((self._tok_dev, idx, toks), bt, pos, *state), None
 
     def _verify_inputs(self, reqs):
         # speculative multi-token step: the fused decode math over each

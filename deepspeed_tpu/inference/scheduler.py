@@ -325,6 +325,23 @@ class ServingTelemetry:
             "read the dummy block) of pos // block_size + 1: the block "
             "copies the paged kernel issues a layer and pool")
 
+    def count_state(self, rows: int) -> None:
+        """One fused decode step of a model with recurrent state over
+        ``rows`` live rows. Not pre-created: a model without state has
+        neither this nor ``state_slot_resets``."""
+        self.registry.counter(
+            "serving/decode_state_rows",
+            "per fused decode step, the live rows whose recurrent state it "
+            "updates: over decode_steps, the states a step reads and writes"
+        ).inc(rows)
+
+    def count_state_reset(self) -> None:
+        self.registry.counter(
+            "serving/state_slot_resets",
+            "prefill pieces that started a state slot from zero: a "
+            "request's first, and its first again after each recompute"
+        ).inc()
+
     def count_moe(self, counts) -> None:
         """One fused decode step of an MoE model. ``counts`` [L, E + 1], the
         program's own: the assignments each expert of each layer computed
@@ -550,6 +567,8 @@ class Request:
     eos: Optional[int] = None
     state: str = QUEUED
     blocks: List[int] = dataclasses.field(default_factory=list)
+    state_slot: int = 0             # its row of the state pools, held like
+    # its blocks from admission to release (0: none, or a model without)
     pos: int = 0                    # tokens currently cached in the pools
     generated: List[int] = dataclasses.field(default_factory=list)
     admit_seq: int = -1             # admission stamp (eviction order)
@@ -1173,6 +1192,10 @@ class ContinuousBatchingScheduler:
             self.telemetry.phase(
                 "queue", max(now - req.t_arrival, 0.0) * 1e3, rid=req.rid)
         req.blocks = blocks
+        # max_running + 1 slots and at most max_running rows: one is free
+        req.state_slot = self.allocator.allocate_slot()
+        if req.state_slot is None:
+            raise RuntimeError("no state slot left for an admitted request")
         req.keys = list(keys)
         req.pos = cached
         req.prefill_target = target
@@ -1475,6 +1498,8 @@ class ContinuousBatchingScheduler:
         if self.prefix_caching:
             blocks = list(reversed(blocks))
         self.allocator.free(blocks)
+        self.allocator.free_slot(req.state_slot)
+        req.state_slot = 0
         req.blocks = []
         req.keys = []
         req.cow_pending = None

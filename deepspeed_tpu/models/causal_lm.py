@@ -61,23 +61,28 @@ class CausalLM:
     # ---- paged KV serving (see transformer.forward_paged_*) ----
 
     def init_paged_cache(self, num_blocks: int, block_size: int,
-                         dtype=jnp.bfloat16) -> Dict[str, Any]:
-        return T.init_paged_kv_cache(self.config, num_blocks, block_size, dtype)
+                         dtype=jnp.bfloat16, state_slots: int = 0) -> Dict[str, Any]:
+        return T.init_paged_kv_cache(self.config, num_blocks, block_size, dtype,
+                                     state_slots=state_slots)
 
-    def forward_paged_prefill(self, params, tokens, pools, slots, last_idx):
+    def forward_paged_prefill(self, params, tokens, pools, slots, last_idx,
+                              state_slot=None):
         return T.forward_paged_prefill(self.config, params, tokens, pools,
-                                       slots, last_idx)
+                                       slots, last_idx, state_slot=state_slot)
 
     def forward_paged_prefill_chunk(self, params, tokens, pools,
-                                    block_tables, slots, start_pos, last_idx):
+                                    block_tables, slots, start_pos, last_idx,
+                                    state_slot=None):
         return T.forward_paged_prefill_chunk(self.config, params, tokens,
                                              pools, block_tables, slots,
-                                             start_pos, last_idx)
+                                             start_pos, last_idx,
+                                             state_slot=state_slot)
 
     def forward_paged_decode(self, params, tokens, pools, block_tables, pos,
-                             pad_bias=None):
+                             pad_bias=None, state_slots=None):
         return T.forward_paged_decode(self.config, params, tokens, pools,
-                                      block_tables, pos, pad_bias)
+                                      block_tables, pos, pad_bias,
+                                      state_slots=state_slots)
 
     def forward_paged_verify(self, params, tokens, pools, block_tables,
                              slots, pos):

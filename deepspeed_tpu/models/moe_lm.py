@@ -68,6 +68,21 @@ class MoEConfig:
     # sorted_dispatch / dense_dispatch)
     dispatch: str = "capacity"
     norm_topk_prob: bool = False         # nodrop: divide the k weights by their sum
+    # nodrop: how a token scores the experts before its k largest are taken:
+    # "softmax" over them, or "sigmoid" of each logit with a learned bias
+    # ``b_select`` added for the choice alone (DeepSeek-V3 / GLM-4-MoE)
+    scoring: str = "softmax"
+    # THE CHIP'S SHARE of an expert-parallel layer (nodrop): the router
+    # scores ``router_experts`` experts (None = num_experts: all are here),
+    # of which this model HOLDS the ``num_experts`` from ``expert_offset``
+    # on; an assignment to an expert held elsewhere is left out of the
+    # result, as that chip's part of the sum. No exchange, nothing stands
+    # in for the absent chips.
+    router_experts: Optional[int] = None
+    expert_offset: int = 0
+    # a gated-SiLU expert every token takes beside its routed ones, of this
+    # width (n_shared_experts x their width); 0 = none
+    shared_expert_d_ff: int = 0
     # Residual (PR-)MoE, arXiv:2201.05596: each MoE MLP is blended with a
     # dense MLP through a learned 2-way softmax coefficient (reference
     # moe/layer.py use_residual + inference moe_type='residual')
@@ -96,6 +111,17 @@ class MoECausalLM:
                 moe_config.use_residual or moe_config.noisy_gate_policy):
             raise ValueError("dispatch='nodrop' has no residual MLP and no "
                              "noisy gate")
+        share = (moe_config.router_experts is not None
+                 or moe_config.expert_offset or moe_config.shared_expert_d_ff
+                 or moe_config.scoring != "softmax")
+        if share and moe_config.dispatch != "nodrop":
+            raise ValueError("a share of the experts, a shared expert and "
+                             "sigmoid scoring need dispatch='nodrop'")
+        if moe_config.expert_offset + moe_config.num_experts > self.router_width:
+            raise ValueError(
+                f"experts {moe_config.expert_offset}.."
+                f"{moe_config.expert_offset + moe_config.num_experts} held "
+                f"here lie outside the router's {self.router_width}")
 
     @property
     def expert_ff(self) -> int:
@@ -105,43 +131,71 @@ class MoECausalLM:
     def _gated(self) -> bool:
         return self.moe.expert_activation == "swiglu"
 
+    @property
+    def router_width(self) -> int:
+        return self.moe.router_experts or self.moe.num_experts
+
     # -------------------- params -------------------- #
 
     @honors_on_device
     def init_params(self, rng) -> Dict[str, Any]:
-        cfg, moe = self.config, self.moe
+        cfg = self.config
         base = T.init_params(cfg, rng, dtype=self.param_dtype)
+        if cfg.layer_kinds is None:
+            base["layers"]["mlp"] = self._mlp_params(rng, cfg.n_layer)
+        else:
+            for j, group in enumerate(base["layers"]):
+                group["mlp"] = self._mlp_params(
+                    jax.random.fold_in(rng, 2000 + j), cfg.n_periods)
+        return base
+
+    def _mlp_params(self, rng, n: int) -> Dict[str, Any]:
+        """The MoE MLP of ``n`` stacked layers."""
+        cfg, moe = self.config, self.moe
         L, D = cfg.n_layer, cfg.d_model
         E = moe.num_experts
         F = self.expert_ff
+        dt = self.param_dtype
         k1, k2, k3 = jax.random.split(jax.random.fold_in(rng, 999), 3)
-        s_in, s_out = 0.02, 0.02 / math.sqrt(2 * L)
-        base["layers"]["mlp"] = {
-            "gate_w": (jax.random.normal(k1, (L, D, E)) / math.sqrt(D)).astype(self.param_dtype),
-            "w_up": (jax.random.normal(k2, (L, E, D, F)) * s_in).astype(self.param_dtype),
-            "w_down": (jax.random.normal(k3, (L, E, F, D)) * s_out).astype(self.param_dtype),
+        s_in, s_out = cfg.init_std, cfg.init_std / math.sqrt(2 * L)
+        mlp = {
+            "gate_w": (jax.random.normal(k1, (n, D, self.router_width)) / math.sqrt(D)).astype(dt),
+            "w_up": (jax.random.normal(k2, (n, E, D, F)) * s_in).astype(dt),
+            "w_down": (jax.random.normal(k3, (n, E, F, D)) * s_out).astype(dt),
         }
         if self._gated:
             k7 = jax.random.fold_in(rng, 1003)
-            base["layers"]["mlp"]["w_gate"] = (
-                jax.random.normal(k7, (L, E, D, F)) * s_in).astype(self.param_dtype)
+            mlp["w_gate"] = (jax.random.normal(k7, (n, E, D, F)) * s_in).astype(dt)
         else:
-            base["layers"]["mlp"].update({
-                "b_up": jnp.zeros((L, E, F), self.param_dtype),
-                "b_down": jnp.zeros((L, E, D), self.param_dtype)})
+            mlp.update({"b_up": jnp.zeros((n, E, F), dt),
+                        "b_down": jnp.zeros((n, E, D), dt)})
+        if moe.scoring == "sigmoid":
+            mlp["b_select"] = jnp.zeros((n, self.router_width), dt)
+        if moe.shared_expert_d_ff:
+            Fs = moe.shared_expert_d_ff
+            k8, k9, k10 = jax.random.split(jax.random.fold_in(rng, 1005), 3)
+            mlp["shared"] = {
+                "w_gate": (jax.random.normal(k8, (n, D, Fs)) * s_in).astype(dt),
+                "w_up": (jax.random.normal(k9, (n, D, Fs)) * s_in).astype(dt),
+                "w_down": (jax.random.normal(k10, (n, Fs, D)) * s_out).astype(dt)}
         if moe.use_residual:
             k4, k5, k6 = jax.random.split(jax.random.fold_in(rng, 1001), 3)
-            base["layers"]["mlp"].update({
-                "res_w_up": (jax.random.normal(k4, (L, D, F)) * s_in).astype(self.param_dtype),
-                "res_b_up": jnp.zeros((L, F), self.param_dtype),
-                "res_w_down": (jax.random.normal(k5, (L, F, D)) * s_out).astype(self.param_dtype),
-                "res_b_down": jnp.zeros((L, D), self.param_dtype),
-                "coef_w": (jax.random.normal(k6, (L, D, 2)) * 0.02).astype(self.param_dtype),
-                "coef_b": jnp.zeros((L, 2), self.param_dtype),
+            mlp.update({
+                "res_w_up": (jax.random.normal(k4, (n, D, F)) * s_in).astype(dt),
+                "res_b_up": jnp.zeros((n, F), dt),
+                "res_w_down": (jax.random.normal(k5, (n, F, D)) * s_out).astype(dt),
+                "res_b_down": jnp.zeros((n, D), dt),
+                "coef_w": (jax.random.normal(k6, (n, D, 2)) * 0.02).astype(dt),
+                "coef_b": jnp.zeros((n, 2), dt),
             })
-        return base
+        return mlp
 
     def tp_specs(self) -> Dict[str, Any]:
+        if self.config.layer_kinds is not None or self.moe.shared_expert_d_ff \
+                or self.moe.scoring != "softmax":
+            # replicated: no sharded form of these stacks is built
+            return T.replicated_specs(
+                lambda: self.init_params(jax.random.key(0)))
         specs = T.tp_specs(self.config)
         specs["layers"]["mlp"] = {
             "gate_w": P(None, None, None),
@@ -170,36 +224,71 @@ class MoECausalLM:
             return jax.nn.silu(gate) * up
         return jax.nn.gelu(up, approximate=True)
 
-    def _moe_mlp(self, lp, x, rng, train: bool, used_token=None):
+    def _moe_mlp(self, lp, x, rng, train: bool, used_token=None,
+                 with_owed: bool = False):
         """x [B,S,D] → ([B,S,D], l_aux, counts) via top-k expert routing.
         ``used_token`` [B*S] 1/0 keeps masked tokens away from the experts
         (nodrop: any k; capacity: top-1 only, the reference's top-2 gate has
         no mask either). ``counts`` [E] int32: the assignments each expert
-        computed for rows that are ``used_token`` (what the decode program
-        hands the engine's ``serving/moe_*`` counters)."""
+        HELD HERE computed for rows that are ``used_token`` (what the decode
+        program hands the engine's ``serving/moe_*`` counters).
+        ``with_owed``: fourth, the assignments the layer owed (those of
+        ``used_token`` rows to experts held here)."""
         if self.moe.dispatch == "nodrop":
-            return self._nodrop_mlp(lp, x, used_token)
-        return self._capacity_mlp(lp, x, rng, train, used_token)
+            out = self._nodrop_mlp(lp, x, used_token)
+        else:
+            used = x.shape[0] * x.shape[1] if used_token is None \
+                else jnp.sum(used_token > 0, dtype=jnp.int32)
+            out = (*self._capacity_mlp(lp, x, rng, train, used_token),
+                   used * self.moe.k)
+        return out if with_owed else out[:3]
+
+    def _route(self, lp, tokens):
+        """tokens [T, D] -> (weights [T, k] float32, experts [T, k] int32 as
+        indices into the experts HELD HERE, ``num_experts`` for one held
+        elsewhere, scores [T, router width])."""
+        moe = self.moe
+        # float32 for real: on the chip a default-precision float32
+        # matmul rounds its operands to bf16
+        logits = jnp.dot(tokens.astype(jnp.float32),
+                         lp["gate_w"].astype(jnp.float32),
+                         precision=jax.lax.Precision.HIGHEST)
+        if moe.scoring == "softmax":
+            weights, experts, probs = topk_routing(logits, moe.k,
+                                                   moe.norm_topk_prob)
+        else:
+            weights, experts, probs = topk_routing(
+                logits, moe.k, moe.norm_topk_prob, scoring=moe.scoring,
+                select_bias=lp["b_select"])
+        if self.router_width != moe.num_experts:
+            local = experts - moe.expert_offset
+            experts = jnp.where((local >= 0) & (local < moe.num_experts),
+                                local, moe.num_experts)
+        return weights, experts, probs
 
     def _nodrop_mlp(self, lp, x, valid=None):
-        """Softmax in float32, the k largest as they are, every assignment
-        computed: every expert over every row for a call of fewer than
-        ``_SORTED_DISPATCH_MIN_ROWS`` rows, rows sorted into ragged groups
-        (``jax.lax.ragged_dot``) from there on. Scopes ``router`` /
-        ``moe_dispatch`` / ``experts`` name the three parts in a device
-        trace."""
+        """A score an expert in float32 (softmax, or sigmoid with a
+        selection bias), the k largest as they are, every assignment to an
+        expert held here computed: every held expert over every row for a
+        call of fewer than ``_SORTED_DISPATCH_MIN_ROWS`` rows, rows sorted
+        into ragged groups (``jax.lax.ragged_dot``) from there on; then the
+        shared expert, if the model has one. Scopes ``router`` /
+        ``moe_dispatch`` / ``experts`` / ``shared_expert`` name the parts in
+        a device trace. Returns (out, l_aux, counts [E], owed)."""
         moe = self.moe
         B, S, D = x.shape
         E = moe.num_experts
         tokens = x.reshape(-1, D)
         with jax.named_scope("router"):
-            # float32 for real: on the chip a default-precision float32
-            # matmul rounds its operands to bf16
-            logits = jnp.dot(tokens.astype(jnp.float32),
-                             lp["gate_w"].astype(jnp.float32),
-                             precision=jax.lax.Precision.HIGHEST)
-            weights, experts, probs = topk_routing(logits, moe.k,
-                                                   moe.norm_topk_prob)
+            weights, experts, probs = self._route(lp, tokens)
+        if self.router_width == E:
+            owed = (tokens.shape[0] if valid is None
+                    else jnp.sum(valid, dtype=jnp.int32)) * moe.k
+        else:
+            held = experts < E
+            if valid is not None:
+                held = held & valid.astype(bool)[:, None]
+            owed = jnp.sum(held, dtype=jnp.int32)
         p = {k: T._w(lp[k], tokens) for k in self._expert_keys()}
 
         def grouped(xs, sizes):
@@ -232,8 +321,19 @@ class MoECausalLM:
             out, counts = dense_dispatch(tokens, weights, experts, E, dense, valid)
         else:
             out, counts = sorted_dispatch(tokens, weights, experts, E, grouped, valid)
-        l_aux = topk_balance_loss(probs, counts, moe.k)
-        return out.reshape(B, S, D), l_aux, counts
+        if self.router_width == E:
+            l_aux = topk_balance_loss(probs, counts, moe.k)
+        else:
+            # a share sees its own experts' loads only: the loss is the
+            # whole layer's, taken where all shares meet (training across
+            # the ep axis is not built)
+            l_aux = jnp.zeros((), jnp.float32)
+        if moe.shared_expert_d_ff:
+            with jax.named_scope("shared_expert"):
+                sp = {k: T._w(w, tokens) for k, w in lp["shared"].items()}
+                out = out + (jax.nn.silu(tokens @ sp["w_gate"])
+                             * (tokens @ sp["w_up"])) @ sp["w_down"]
+        return out.reshape(B, S, D), l_aux, counts, owed
 
     def _capacity_mlp(self, lp, x, rng, train: bool, used_token=None):
         moe = self.moe
@@ -294,6 +394,7 @@ class MoECausalLM:
 
     def forward(self, params, tokens, attn_mask=None, rng=None, train: bool = True):
         cfg = self.config
+        T._no_layer_pattern(cfg, "the training forward (and its scan's backward)")
         B, S = tokens.shape
         x = params["embed"]["tokens"][tokens]
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))
@@ -356,8 +457,9 @@ class MoECausalLM:
     # counted nowhere (T.paged_real_rows).
 
     def init_paged_cache(self, num_blocks: int, block_size: int,
-                         dtype=jnp.bfloat16) -> Dict[str, Any]:
-        return T.init_paged_kv_cache(self.config, num_blocks, block_size, dtype)
+                         dtype=jnp.bfloat16, state_slots: int = 0) -> Dict[str, Any]:
+        return T.init_paged_kv_cache(self.config, num_blocks, block_size, dtype,
+                                     state_slots=state_slots)
 
     def _paged(self, pools, slots, counts: bool = False):
         """The ``mlp_fn`` of a ``transformer.forward_paged_*`` call whose
@@ -368,25 +470,28 @@ class MoECausalLM:
 
         def mlp_fn(cfg, x_normed, lp):
             with jax.named_scope("mlp"):
-                out, _, n = self._moe_mlp(lp["mlp"], x_normed, None,
-                                          train=False, used_token=used)
+                out, _, n, owed = self._moe_mlp(
+                    lp["mlp"], x_normed, None, train=False, used_token=used,
+                    with_owed=True)
             if not counts:
                 return out
-            owed = jnp.sum(used, dtype=jnp.int32) * self.moe.k
             return out, jnp.append(n, owed)
         return mlp_fn
 
-    def forward_paged_prefill(self, params, tokens, pools, slots, last_idx):
+    def forward_paged_prefill(self, params, tokens, pools, slots, last_idx,
+                              state_slot=None):
         mlp_fn = self._paged(pools, slots)
         return T.forward_paged_prefill(self.config, params, tokens, pools,
-                                       slots, last_idx, mlp_fn=mlp_fn)
+                                       slots, last_idx, mlp_fn=mlp_fn,
+                                       state_slot=state_slot)
 
     def forward_paged_prefill_chunk(self, params, tokens, pools,
-                                    block_tables, slots, start_pos, last_idx):
+                                    block_tables, slots, start_pos, last_idx,
+                                    state_slot=None):
         mlp_fn = self._paged(pools, slots)
         return T.forward_paged_prefill_chunk(
             self.config, params, tokens, pools, block_tables, slots,
-            start_pos, last_idx, mlp_fn=mlp_fn)
+            start_pos, last_idx, mlp_fn=mlp_fn, state_slot=state_slot)
 
     def forward_paged_verify(self, params, tokens, pools, block_tables,
                              slots, pos):
@@ -395,7 +500,7 @@ class MoECausalLM:
                                       block_tables, slots, pos, mlp_fn=mlp_fn)
 
     def forward_paged_decode(self, params, tokens, pools, block_tables, pos,
-                             pad_bias=None):
+                             pad_bias=None, state_slots=None):
         """(logits [B, vocab], new pools, counts [L, E + 1]): third, the
         assignments each expert of each layer computed in this step and, in
         the last column, those the layer owed (real rows x k): what the
@@ -404,7 +509,8 @@ class MoECausalLM:
         slots = block_tables[jnp.arange(pos.shape[0]), pos // bs] * bs + pos % bs
         mlp_fn = self._paged(pools, slots, counts=True)
         return T.forward_paged_decode(self.config, params, tokens, pools,
-                                      block_tables, pos, pad_bias, mlp_fn=mlp_fn)
+                                      block_tables, pos, pad_bias, mlp_fn=mlp_fn,
+                                      state_slots=state_slots)
 
     def loss(self, params, batch, rng=None):
         logits, aux = self.forward(params, batch["input_ids"], batch.get("attention_mask"),

@@ -126,6 +126,65 @@ def olmoe(size: str = "1b-7b", **over):
         param_dtype=param_dtype)
 
 
+def solar_open2(size: str = "250b-4l-ep8", share: int = 0, **over):
+    """Solar-Open2-250B (``upstage/Solar-Open2-250B`` config.json): 48
+    pre-RMSNorm layers of d 4,096 without positions, in periods of four: a
+    softmax GQA layer (64 query and 8 key/value heads of 128, a sigmoid
+    output gate) and three KDA layers (the channel-wise gated delta rule:
+    64 heads of 128 x 128 float32 state, a causal conv of 4 taps on q, k
+    and v); in every layer 320 gated-SiLU experts of width 1,280, 8 a token
+    by sigmoid score plus a selection bias, weights normalised, and one
+    shared expert; an untied head over 196,608 rows. ``250b-4l-ep8`` is ONE
+    CHIP OF THE EIGHT that share each layer, at one period of depth: the
+    router keeps its 320 outputs, the 40 experts of ``share`` (0-7) are
+    held here, attention, KDA and the shared expert are whole, the
+    vocabulary is this chip's eighth (perfbench's
+    ``solaropen2_serve_decode``). ``max_seq`` is what the deployment serves
+    (it sizes the block tables; the model has no positions to run out of).
+    Its token embedding is drawn at std 2.0, the RMS of the residual stream
+    at the middle of the 48-layer stack under this init (a layer adds a
+    mixer's 0.31 and an MoE's 0.26: 0.41 sqrt(24)), so that the four layers
+    see what a stage of the deployment sees: over an untrained embedding's
+    0.02 each layer's output is fifteen times its input, a router's flipped
+    boundary choice (weights 1/8 each under sigmoid scores) moves the hidden
+    state by 4%, bf16 rounding flips 7-30% of the choices a layer and the
+    flips feed the next layer's. What a served-token check can and cannot
+    see at this scale is in PERF.md section 6, PR 31.
+    ``tiny`` keeps every kind of part at toy widths, a head size that is
+    not d_model / heads, and 2 of 16 experts held, IN THE SAME REGIME: its
+    matrices at 0.16 = 0.02 sqrt(4096 / 64), which puts its branches where
+    the cut's are (mixers 0.17-0.35, MoE 0.31 against 0.15-0.32, 0.26), and
+    its embedding at the same 2.0."""
+    from deepspeed_tpu.models.moe_lm import MoECausalLM, MoEConfig
+    dims, moe = {
+        "tiny": (dict(n_layer=4, n_head=4, n_kv_head=2, head_size=32,
+                      d_model=64, d_ff=64, vocab_size=512, max_seq=1024,
+                      lin_heads=4, lin_head_dim=32,
+                      init_std=0.16, embed_init_std=2.0),
+                 dict(num_experts=2, router_experts=16, k=4, expert_d_ff=32,
+                      shared_expert_d_ff=32)),
+        "250b-4l-ep8": (dict(n_layer=4, n_head=64, n_kv_head=8, head_size=128,
+                             d_model=4096, d_ff=1280, vocab_size=24576,
+                             max_seq=2048, lin_heads=64, lin_head_dim=128,
+                             embed_init_std=2.0),
+                        dict(num_experts=40, router_experts=320, k=8,
+                             expert_d_ff=1280, shared_expert_d_ff=1280)),
+    }[size]
+    param_dtype = over.pop("param_dtype", jnp.float32)
+    moe = {**moe, **over.pop("moe", {})}
+    cfg = TransformerConfig(
+        pos_embedding="none", norm="rmsnorm", norm_eps=1e-5,
+        activation="swiglu", tie_embeddings=False, attn_bias=False,
+        attn_out_gate=True,
+        layer_kinds=("attention",) + ("linear_attention",) * 3,
+        **{**dims, **over})
+    return MoECausalLM(cfg, MoEConfig(
+        dispatch="nodrop", expert_activation="swiglu", scoring="sigmoid",
+        norm_topk_prob=True, aux_loss_coef=0.0,
+        expert_offset=share * moe["num_experts"], **moe),
+        param_dtype=param_dtype)
+
+
 MODEL_PRESETS: Dict[str, Callable] = {
     "gpt2": gpt2,
     "llama": llama,
@@ -133,6 +192,7 @@ MODEL_PRESETS: Dict[str, Callable] = {
     "opt": opt,
     "gpt_neox": gpt_neox,
     "olmoe": olmoe,
+    "solar_open2": solar_open2,
 }
 
 

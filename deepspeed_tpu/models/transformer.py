@@ -29,6 +29,9 @@ from jax.sharding import PartitionSpec as P
 from deepspeed_tpu.ops import dispatch
 
 
+ATTENTION, LINEAR_ATTENTION = "attention", "linear_attention"
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 50257
@@ -52,6 +55,23 @@ class TransformerConfig:
     # (all heads together, one learned scale of H*Hd / KV*Hd), before the
     # split into heads and before rope (OLMoE, OLMo-2)
     qk_norm: bool = False
+    # a head's width where it is not d_model // n_head (64 heads of 128
+    # under d_model 4,096): ``head_dim`` reads it
+    head_size: Optional[int] = None
+    # sigmoid output gate on attention: y = (a * sigmoid(x Wg)) Wo, Wg
+    # [d_model, n_head * head_dim], no bias
+    attn_out_gate: bool = False
+    # ONE period of the stack's layer pattern, the kinds of its mixers in
+    # order ("attention": softmax attention over the KV cache;
+    # "linear_attention": the gated delta rule with a recurrent state, the
+    # lin_* sizes below); the stack is n_layer / len(layer_kinds) periods.
+    # None: every layer is "attention". A kind says what cache it keeps
+    # (``cache_spec``): KV blocks, or a state slot a request.
+    layer_kinds: Optional[Tuple[str, ...]] = None
+    # the linear-attention (KDA) mixer: heads and their key = value width
+    # (its two factored gate projections have rank lin_head_dim)
+    lin_heads: int = 0
+    lin_head_dim: int = 0
     # numerics
     rope_theta: float = 10000.0
     rope_dim: int = 0                    # 0 = full head dim; else partial
@@ -120,10 +140,35 @@ class TransformerConfig:
     manual_tp: Optional[str] = None
     # init
     init_std: float = 0.02
+    # std of the token embedding's draw where it is not init_std: a cut of a
+    # deep stack takes the residual stream its layers see in the stack
+    embed_init_std: Optional[float] = None
 
     @property
     def head_dim(self) -> int:
-        return self.d_model // self.n_head
+        return self.head_size or self.d_model // self.n_head
+
+    @property
+    def period(self) -> Tuple[str, ...]:
+        """The kinds of one period of the layer pattern."""
+        return tuple(self.layer_kinds) if self.layer_kinds else (ATTENTION,)
+
+    @property
+    def n_periods(self) -> int:
+        return self.n_layer // len(self.period)
+
+    def layers_of(self, kind: str) -> int:
+        return self.n_periods * self.period.count(kind)
+
+    @property
+    def cache_spec(self) -> Dict[str, int]:
+        """What the stack keeps between steps, by cache: ``kv`` the layers
+        that hold KV blocks, ``state`` those that hold a recurrent state
+        and a conv state in a slot a request. What cannot hold with a
+        state yet (a prefix hit, a verify window's rewind, a block moved
+        to the host) is refused from here, not by an option."""
+        return {"kv": self.layers_of(ATTENTION),
+                "state": self.layers_of(LINEAR_ATTENTION)}
 
     @property
     def kv_heads(self) -> int:
@@ -143,55 +188,99 @@ class TransformerConfig:
 # --------------------------------------------------------------------- #
 # parameter init
 
-def init_params(cfg: TransformerConfig, rng, dtype=jnp.float32) -> Dict[str, Any]:
-    """Stacked-layer parameter pytree. Layer weights carry a leading
-    ``n_layer`` dim so ``lax.scan`` runs one compiled block for all layers."""
-    k_emb, k_pos, k_layers, k_head = jax.random.split(rng, 4)
+def _check_pattern(cfg: TransformerConfig):
+    kinds = cfg.period
+    bad = [k for k in kinds if k not in (ATTENTION, LINEAR_ATTENTION)]
+    if bad:
+        raise ValueError(f"layer_kinds {kinds}: unknown kind {bad[0]!r} "
+                         f"(expected {ATTENTION}|{LINEAR_ATTENTION})")
+    if cfg.n_layer % len(kinds):
+        raise ValueError(f"n_layer {cfg.n_layer} is not whole periods of "
+                         f"the layer pattern {kinds}")
+    if LINEAR_ATTENTION in kinds and not (cfg.lin_heads and cfg.lin_head_dim):
+        raise ValueError("a linear_attention layer needs lin_heads and "
+                         "lin_head_dim")
+
+
+def _layer_group(cfg: TransformerConfig, kind: str, n: int, ks, dtype):
+    """The stacked parameters of ``n`` like layers of ``kind`` (leading dim
+    ``n``, what ``lax.scan`` runs one compiled block over) from the keys
+    ``ks``: the mixer under ``attn`` or ``lin``, its norm, the dense MLP and
+    its norm."""
     std = cfg.init_std
-    L, D, F = cfg.n_layer, cfg.d_model, cfg.ff_dim
+    D, F = cfg.d_model, cfg.ff_dim
     H, KV, Hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
+    # attention out & mlp down get depth-scaled init (gpt-2 style)
+    out_std = std / math.sqrt(2 * cfg.n_layer)
 
     def norm_params():
-        scale = jnp.ones((L, D), dtype)
+        scale = jnp.ones((n, D), dtype)
         if cfg.norm == "layernorm":
-            return {"scale": scale, "bias": jnp.zeros((L, D), dtype)}
+            return {"scale": scale, "bias": jnp.zeros((n, D), dtype)}
         return {"scale": scale}
 
     def dense(key, shape, scale=std):
         return (jax.random.normal(key, shape) * scale).astype(dtype)
 
-    ks = jax.random.split(k_layers, 8)
-    # attention out & mlp down get depth-scaled init (gpt-2 style)
-    out_std = std / math.sqrt(2 * L)
+    group = {"ln_attn": norm_params()}
+    if kind == ATTENTION:
+        group["attn"] = {
+            "wq": dense(ks[0], (n, D, H * Hd)),
+            "wk": dense(ks[1], (n, D, KV * Hd)),
+            "wv": dense(ks[2], (n, D, KV * Hd)),
+            "wo": dense(ks[3], (n, H * Hd, D), out_std),
+            **({"bq": jnp.zeros((n, H * Hd), dtype),
+                "bk": jnp.zeros((n, KV * Hd), dtype),
+                "bv": jnp.zeros((n, KV * Hd), dtype),
+                "bo": jnp.zeros((n, D), dtype)} if cfg.attn_bias else {}),
+            **({"q_norm": {"scale": jnp.ones((n, H * Hd), dtype)},
+                "k_norm": {"scale": jnp.ones((n, KV * Hd), dtype)}}
+               if cfg.qk_norm else {}),
+            **({"wg": dense(ks[7], (n, D, H * Hd))}
+               if cfg.attn_out_gate else {}),
+        }
+    else:
+        group["lin"] = _init_linear_attention(cfg, n, ks[8], dtype, out_std)
+    group["ln_mlp"] = norm_params()
+    group["mlp"] = ({
+        "w_gate": dense(ks[4], (n, D, F)),
+        "w_up": dense(ks[5], (n, D, F)),
+        "w_down": dense(ks[6], (n, F, D), out_std),
+    } if cfg.activation == "swiglu" else {
+        "w_up": dense(ks[5], (n, D, F)),
+        "b_up": jnp.zeros((n, F), dtype),
+        "w_down": dense(ks[6], (n, F, D), out_std),
+        "b_down": jnp.zeros((n, D), dtype),
+    })
+    return group
+
+
+def init_params(cfg: TransformerConfig, rng, dtype=jnp.float32) -> Dict[str, Any]:
+    """Stacked-layer parameter pytree. Layer weights carry a leading
+    ``n_layer`` dim so ``lax.scan`` runs one compiled block for all layers.
+    Under a layer pattern (``cfg.layer_kinds``) ``layers`` is a tuple, one
+    group a position of the period, each stacked over the periods."""
+    _check_pattern(cfg)
+    k_emb, k_pos, k_layers, k_head = jax.random.split(rng, 4)
+    std = cfg.init_std
+    D = cfg.d_model
+
+    def dense(key, shape, scale=std):
+        return (jax.random.normal(key, shape) * scale).astype(dtype)
+
+    if cfg.layer_kinds is None:
+        layers = _layer_group(cfg, ATTENTION, cfg.n_layer,
+                              jax.random.split(k_layers, 8), dtype)
+    else:
+        layers = tuple(
+            _layer_group(cfg, kind, cfg.n_periods,
+                         jax.random.split(jax.random.fold_in(k_layers, j), 9),
+                         dtype)
+            for j, kind in enumerate(cfg.period))
     params: Dict[str, Any] = {
-        "embed": {"tokens": dense(k_emb, (cfg.vocab_size, D))},
-        "layers": {
-            "ln_attn": norm_params(),
-            "attn": {
-                "wq": dense(ks[0], (L, D, H * Hd)),
-                "wk": dense(ks[1], (L, D, KV * Hd)),
-                "wv": dense(ks[2], (L, D, KV * Hd)),
-                "wo": dense(ks[3], (L, H * Hd, D), out_std),
-                **({"bq": jnp.zeros((L, H * Hd), dtype),
-                    "bk": jnp.zeros((L, KV * Hd), dtype),
-                    "bv": jnp.zeros((L, KV * Hd), dtype),
-                    "bo": jnp.zeros((L, D), dtype)} if cfg.attn_bias else {}),
-                **({"q_norm": {"scale": jnp.ones((L, H * Hd), dtype)},
-                    "k_norm": {"scale": jnp.ones((L, KV * Hd), dtype)}}
-                   if cfg.qk_norm else {}),
-            },
-            "ln_mlp": norm_params(),
-            "mlp": ({
-                "w_gate": dense(ks[4], (L, D, F)),
-                "w_up": dense(ks[5], (L, D, F)),
-                "w_down": dense(ks[6], (L, F, D), out_std),
-            } if cfg.activation == "swiglu" else {
-                "w_up": dense(ks[5], (L, D, F)),
-                "b_up": jnp.zeros((L, F), dtype),
-                "w_down": dense(ks[6], (L, F, D), out_std),
-                "b_down": jnp.zeros((L, D), dtype),
-            }),
-        },
+        "embed": {"tokens": dense(k_emb, (cfg.vocab_size, D),
+                                  cfg.embed_init_std or std)},
+        "layers": layers,
         "ln_f": ({"scale": jnp.ones((D,), dtype), "bias": jnp.zeros((D,), dtype)}
                  if cfg.norm == "layernorm" else {"scale": jnp.ones((D,), dtype)}),
     }
@@ -207,10 +296,19 @@ def init_params(cfg: TransformerConfig, rng, dtype=jnp.float32) -> Dict[str, Any
     return params
 
 
+def replicated_specs(init) -> Dict[str, Any]:
+    """All-None PartitionSpecs in the shape of ``init()``'s tree: what a
+    stack with no tensor-parallel form gives ``tp_specs``."""
+    return jax.tree.map(lambda a: P(*([None] * a.ndim)), jax.eval_shape(init))
+
+
 def tp_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """Tensor-parallel PartitionSpecs: column-shard qkv/up, row-shard out/down
     (Megatron layout over the ``tp`` mesh axis); vocab-shard embeddings.
-    ZeRO sharding composes on the remaining free dims."""
+    ZeRO sharding composes on the remaining free dims. A stack with a layer
+    pattern is replicated: no tensor-parallel form of its mixers is built."""
+    if cfg.layer_kinds is not None:
+        return replicated_specs(lambda: init_params(cfg, jax.random.key(0)))
     ln = {"scale": P(None, None), "bias": P(None, None)} if cfg.norm == "layernorm" else {"scale": P(None, None)}
     specs = {
         "embed": {"tokens": P("tp", None)},
@@ -225,6 +323,7 @@ def tp_specs(cfg: TransformerConfig) -> Dict[str, Any]:
                     "bv": P(None, "tp"), "bo": P(None, None)} if cfg.attn_bias else {}),
                 **({"q_norm": {"scale": P(None, None)},
                     "k_norm": {"scale": P(None, None)}} if cfg.qk_norm else {}),
+                **({"wg": P(None, None, "tp")} if cfg.attn_out_gate else {}),
             },
             "ln_mlp": ln,
             "mlp": ({
@@ -506,6 +605,8 @@ def attention(cfg: TransformerConfig, x, lp, positions, mask_bias):
     dispatch.record("attention", form,
                     f"B={B} S={S} H={H} KV={k.shape[2]} Hd={Hd}")
     out = checkpoint_name(out.reshape(B, S, H * Hd), "attn_out")
+    if cfg.attn_out_gate:
+        out = out * jax.nn.sigmoid(x @ _w(lp["wg"], x))
     proj = out @ _w(lp["wo"], out)
     if cfg.manual_tp:
         # row-parallel wo: each shard contracted its local heads only —
@@ -945,6 +1046,15 @@ def init_kv_cache(cfg: TransformerConfig, batch_size: int, max_len: Optional[int
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+def _attn_out(cfg: TransformerConfig, out, x, lp):
+    """The output projection of every cache path: ``out`` [B, T, H*Hd] the
+    heads' outputs, ``x`` the block input they were computed from. With
+    ``cfg.attn_out_gate``: (out * sigmoid(x Wg)) Wo."""
+    if cfg.attn_out_gate:
+        out = out * jax.nn.sigmoid(x @ _w(lp["wg"], x))
+    return out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
+
+
 def _qkv_project(cfg: TransformerConfig, x, lp, positions):
     """Shared decode-side q/k/v projection: act-quant (QAT parity with the
     training path — or prefill/decode logits diverge from forward()),
@@ -1029,7 +1139,7 @@ def _cached_attention(cfg: TransformerConfig, x, lp, positions, pos, ck, cv, pad
                                     slopes, dmesh, scale=cfg.attn_scale)
         if o is not None:
             out = o.reshape(B, 1, H * Hd)
-            out = out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
+            out = _attn_out(cfg, out, x, lp)
             return out, ck, cv
 
     if Smax > DENSE_STREAM_THRESHOLD:
@@ -1047,11 +1157,11 @@ def _cached_attention(cfg: TransformerConfig, x, lp, positions, pos, ck, cv, pad
                                  True, DENSE_STREAM_CHUNK, q.dtype,
                                  cfg.attn_scale)
         out = o.reshape(B, T, H * Hd)
-        out = out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
+        out = _attn_out(cfg, out, x, lp)
         return out, ck, cv
 
     out = _grouped_cache_einsum(cfg, q, ck, cv, positions, pad_bias)
-    out = out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
+    out = _attn_out(cfg, out, x, lp)
     return out, ck, cv
 
 
@@ -1070,21 +1180,24 @@ def cached_embed(cfg: TransformerConfig, params, tokens, pos, dtype):
     return x, positions
 
 
-def _decode_block(cfg: TransformerConfig, h, lp, attn_fn, mlp_fn=None):
+def _decode_block(cfg: TransformerConfig, h, lp, mix_fn, mlp_fn=None,
+                  scope: str = ATTENTION):
     """The ONE pre-LN residual wiring of every cache-decode block (dense
     workspace via :func:`cached_block`, paged prefill and paged decode):
-    ``attn_fn(x_normed)`` returns (attn_out, new cache k, new cache v);
-    ``mlp_fn(cfg, x_normed, lp)`` overrides the dense MLP (MoE)."""
+    ``mix_fn(x_normed)`` returns (mixer_out, *the mixer's new caches): k and
+    v of an attention layer, state and conv state of a linear-attention
+    one, under ``scope``; ``mlp_fn(cfg, x_normed, lp)`` overrides the dense
+    MLP (MoE). Returns (h, *new caches)."""
     mfn = mlp_fn if mlp_fn is not None else (
         lambda c, xx, lpp: mlp(c, xx, lpp["mlp"]))
-    with jax.named_scope("attention"):
-        a, nkp, nvp = attn_fn(_norm(cfg, h, lp["ln_attn"]))
+    with jax.named_scope(scope):
+        a, *caches = mix_fn(_norm(cfg, h, lp["ln_attn"]))
     if cfg.parallel_residual:
         m = mfn(cfg, _norm(cfg, h, lp["ln_mlp"]), lp)
-        return h + a + m, nkp, nvp
+        return (h + a + m, *caches)
     h = h + a
     m = mfn(cfg, _norm(cfg, h, lp["ln_mlp"]), lp)
-    return h + m, nkp, nvp
+    return (h + m, *caches)
 
 
 def cached_block(cfg: TransformerConfig, h, lp, ck, cv, positions, pos,
@@ -1114,6 +1227,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, cache, pos, pad_bias=
     to ``cache`` at offset ``pos`` ([] int32). Returns (logits [B, T, vocab],
     new cache). ``pad_bias`` [B, Smax] additive f32 masks cache slots of
     left-padded prompts; ``mlp_fn`` see :func:`cached_block`."""
+    _no_layer_pattern(cfg, "the dense-workspace KV cache")
     if cfg.norm_position == "post":
         raise ValueError("norm_position='post' is not supported by the "
                          "KV-cache decode path (pre-LN only)")
@@ -1138,6 +1252,233 @@ def forward_cached(cfg: TransformerConfig, params, tokens, cache, pos, pad_bias=
 
 
 # --------------------------------------------------------------------- #
+# Linear attention: the channel-wise gated delta rule (Kimi Delta Attention)
+#
+#   q, k, v = silu(conv(x Wq)), silu(conv(x Wk)), silu(conv(x Wv))
+#   qh = q / |q| * dk^-0.5,  kh = k / |k|                 (per head)
+#   g  = -exp(A_log) * softplus(x Wf1 Wf2 + dt_bias) <= 0 (per channel)
+#   beta = KDA_BETA_SCALE * sigmoid(x Wb)                 (per head)
+#   S' = diag(exp(g)) S;  S = S' + beta kh (v - S'^T kh)^T;  o = S^T qh
+#   y  = (RMSNorm_head(o) * sigmoid(x Wg1 Wg2 + bg)) Wo
+#
+# A request's state (S, float32 [H, dk, dv] a layer) and conv state (the
+# last K-1 conv inputs) live in a SLOT of two pools beside the KV pools:
+# ``state`` [periods, slots, H, dk, dv] and ``conv`` [periods, slots, K-1,
+# 3*H*dk], one array of each a linear-attention position of the period
+# (init_paged_kv_cache says why). Slot 0 is the dummy (inactive decode rows and nothing else).
+# Decode runs the one-token update over the WHOLE pool slice of a layer,
+# slot-major (a slot no row of the step holds is left as it is: decay 1,
+# beta 0), so the state is read and written where it lives and never
+# gathered; prefill runs a chunked form.
+
+_HI = jax.lax.Precision.HIGHEST
+#: tokens a chunk of the chunked form; every exponent inside is a decay
+#: between two positions of one chunk, so <= 0
+KDA_CHUNK = 64
+#: taps of the causal depthwise conv over q, k and v
+KDA_CONV_KERNEL = 4
+#: beta = 2 sigmoid(.): eigenvalues of the state's transition in (-1, 1)
+KDA_BETA_SCALE = 2.0
+
+
+def _init_linear_attention(cfg: TransformerConfig, n: int, key, dtype, out_std):
+    D, H, dk, K = cfg.d_model, cfg.lin_heads, cfg.lin_head_dim, KDA_CONV_KERNEL
+    r = dk                      # the rank of the two factored gates
+    std = cfg.init_std
+    ks = jax.random.split(key, 12)
+
+    def dense(k, shape, scale=std):
+        return (jax.random.normal(k, shape) * scale).astype(dtype)
+
+    # the family's own draws (fla KimiDeltaAttention): a step's decay
+    # exp(-A * dt) lies where a trained model's does
+    a = jax.random.uniform(ks[9], (n, H), minval=1.0, maxval=16.0)
+    dt = jax.random.uniform(ks[10], (n, H * dk), minval=1e-3, maxval=0.1)
+    return {
+        "wq": dense(ks[0], (n, D, H * dk)),
+        "wk": dense(ks[1], (n, D, H * dk)),
+        "wv": dense(ks[2], (n, D, H * dk)),
+        "wo": dense(ks[3], (n, H * dk, D), out_std),
+        # depthwise, causal: tap K-1 multiplies the current input; q | k | v
+        "conv_w": dense(ks[4], (n, K, 3 * H * dk), K ** -0.5),
+        "wf1": dense(ks[5], (n, D, r)), "wf2": dense(ks[6], (n, r, H * dk)),
+        "A_log": jnp.log(a).astype(dtype),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+        "wb": dense(ks[7], (n, D, H)),
+        "wg1": dense(ks[8], (n, D, r)), "wg2": dense(ks[11], (n, r, H * dk)),
+        "bg": jnp.zeros((n, H * dk), dtype),
+        "o_norm": {"scale": jnp.ones((n, dk), dtype)},
+    }
+
+
+def _kda_project(cfg: TransformerConfig, x, lp, conv_ctx):
+    """x [N, T, D], conv_ctx [N, K-1, 3*H*dk] the conv inputs before x ->
+    (qh, kh, v [N, T, H, dk], g [N, T, H, dk] <= 0, beta [N, T, H], all
+    float32, and the conv window [N, T+K-1, 3*H*dk] the next conv state is
+    cut from)."""
+    N, T, _ = x.shape
+    H, dk, K = cfg.lin_heads, cfg.lin_head_dim, KDA_CONV_KERNEL
+    f32 = jnp.float32
+    u = jnp.concatenate([x @ _w(lp["wq"], x), x @ _w(lp["wk"], x),
+                         x @ _w(lp["wv"], x)], axis=-1)
+    win = jnp.concatenate([conv_ctx.astype(u.dtype), u], axis=1)
+    with jax.named_scope("short_conv"):
+        w = lp["conv_w"].astype(f32)
+        y = sum(win[:, j:j + T].astype(f32) * w[j] for j in range(K))
+        y = jax.nn.silu(y).reshape(N, T, 3, H, dk)
+    q, k, v = y[:, :, 0], y[:, :, 1], y[:, :, 2]
+    qh = q * jax.lax.rsqrt(jnp.sum(q * q, -1, keepdims=True) + 1e-6) * dk ** -0.5
+    kh = k * jax.lax.rsqrt(jnp.sum(k * k, -1, keepdims=True) + 1e-6)
+    f = ((x @ _w(lp["wf1"], x)) @ _w(lp["wf2"], x)).astype(f32)
+    g = -jnp.exp(lp["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+        f.reshape(N, T, H, dk) + lp["dt_bias"].astype(f32).reshape(H, dk))
+    beta = KDA_BETA_SCALE * jax.nn.sigmoid(
+        (x @ _w(lp["wb"], x)).astype(f32))
+    return qh, kh, v, g, beta, win
+
+
+def _kda_output(cfg: TransformerConfig, o, x, lp):
+    """o [N, T, H, dv] float32 -> (RMSNorm_head(o) * sigmoid(gate)) Wo."""
+    N, T, H, dv = o.shape
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + cfg.norm_eps) \
+        * lp["o_norm"]["scale"].astype(jnp.float32)
+    gate = ((x @ _w(lp["wg1"], x)) @ _w(lp["wg2"], x) + lp["bg"]).astype(jnp.float32)
+    y = (o.reshape(N, T, H * dv) * jax.nn.sigmoid(gate)).astype(x.dtype)
+    return y @ _w(lp["wo"], y)
+
+
+def kda_recurrent_step(S, qh, kh, v, g, beta):
+    """The one-token update, any leading dims: S [..., dk, dv] float32,
+    qh, kh, g [..., dk], v [..., dv], beta [...] -> (o [..., dv], new S).
+    S is read twice and written once: o = S'^T qh + (kh . qh) u comes out
+    of the same pass as S'^T kh."""
+    S1 = S * jnp.exp(g)[..., None]
+    r = jnp.sum(S1 * kh[..., None], axis=-2)
+    p = jnp.sum(S1 * qh[..., None], axis=-2)
+    u = beta[..., None] * (v - r)
+    o = p + jnp.sum(qh * kh, axis=-1, keepdims=True) * u
+    return o, S1 + kh[..., None] * u[..., None, :]
+
+
+def _inv_unit_lower(A):
+    """(I + A)^-1 for A [..., C, C] strictly lower triangular, row by row
+    (forward substitution on the identity), all leading dims at once."""
+    C = A.shape[-1]
+    eye = jnp.eye(C, dtype=A.dtype)
+
+    def row(t, inv):
+        a_t = jax.lax.dynamic_index_in_dim(A, t, axis=-2, keepdims=False)
+        new = eye[t] - jnp.einsum("...s,...sj->...j", a_t, inv, precision=_HI)
+        return jax.lax.dynamic_update_index_in_dim(inv, new, t, axis=-2)
+
+    return jax.lax.fori_loop(0, C, row, jnp.zeros_like(A))
+
+
+def kda_chunked(S0, qh, kh, v, g, beta, chunk: int = KDA_CHUNK):
+    """The recurrence over T tokens of one sequence in chunks: S0 [H, dk,
+    dv], qh, kh, g [T, H, dk], v [T, H, dv], beta [T, H] (T whole chunks)
+    -> (o [T, H, dv], S_T). Inside a chunk, with G_t the cumulated log
+    decay, u_t = beta_t (v_t - S'_t^T kh_t) solves (I + A) U = beta (V -
+    (K e^G) S0), A_ts = beta_t sum_c kh_tc kh_sc e^(G_tc - G_sc) (s < t);
+    o_t = S0^T (qh_t e^G_t) + sum_{s<=t} (qh_t . kh_s e^(G_t - G_s)) u_s;
+    S_C = e^G_C S0 + sum_s (kh_s e^(G_C - G_s)) u_s^T. Decays are only ever
+    taken between two positions (s <= t), never as e^-G: that overflows
+    float32 within a chunk at this family's decay range."""
+    T, H, dk = qh.shape
+    C, n = chunk, T // chunk
+    cut = lambda a: jnp.moveaxis(a.reshape(n, C, *a.shape[1:]), 2, 1)  # noqa: E731
+    q, k, vv, gg = cut(qh), cut(kh), cut(v), cut(g)        # [n, H, C, d]
+    b = cut(beta[..., None])                               # [n, H, C, 1]
+    G = jnp.cumsum(gg, axis=2)
+    t_idx = jnp.arange(C)
+    incl = t_idx[:, None] >= t_idx[None, :]                # s <= t
+    decay = jnp.exp(jnp.where(incl[:, :, None],
+                              G[:, :, :, None, :] - G[:, :, None, :, :],
+                              -jnp.inf))                   # [n, H, t, s, dk]
+    a_qk = jnp.sum(q[:, :, :, None, :] * k[:, :, None, :, :] * decay, -1)
+    a_kk = jnp.sum(k[:, :, :, None, :] * k[:, :, None, :, :] * decay, -1)
+    a_kk = jnp.where(t_idx[:, None] > t_idx[None, :], a_kk, 0.0) * b
+    inv = _inv_unit_lower(a_kk)                            # [n, H, C, C]
+    eG = jnp.exp(G)
+    g_end = G[:, :, -1:, :]
+    w = jnp.einsum("nhts,nhsd->nhtd", inv, b * k * eG, precision=_HI)
+    vb = jnp.einsum("nhts,nhsd->nhtd", inv, b * vv, precision=_HI)
+    qd, kr = q * eG, k * jnp.exp(g_end - G)
+    d_end = jnp.exp(g_end[:, :, 0, :])                     # [n, H, dk]
+
+    def step(S, xs):
+        w_i, vb_i, qd_i, aqk_i, kr_i, d_i = xs
+        u = vb_i - jnp.einsum("htk,hkv->htv", w_i, S, precision=_HI)
+        o = jnp.einsum("htk,hkv->htv", qd_i, S, precision=_HI) \
+            + jnp.einsum("hts,hsv->htv", aqk_i, u, precision=_HI)
+        S = d_i[..., None] * S \
+            + jnp.einsum("htk,htv->hkv", kr_i, u, precision=_HI)
+        return S, o
+
+    S, o = jax.lax.scan(step, S0, (w, vb, qd, a_qk, kr, d_end))
+    return jnp.moveaxis(o, 1, 2).reshape(T, H, -1), S
+
+
+def _kda_prefill(cfg: TransformerConfig, x, lp, state, conv, slot, n_valid,
+                 fresh):
+    """The mixer over a prompt, or a chunk of one, of ONE request: x [1, T,
+    D] (T a compile bucket, the first ``n_valid`` positions real), ``slot``
+    the request's row of the layer in ``state`` [rows, H, dk, dv] and
+    ``conv`` [rows, K-1, 3*H*dk]. ``fresh`` (a bool, traced or not): the
+    request's first piece starts from zero, whatever the slot's last holder
+    left there. Padding has decay 1 and beta 0: the state after the bucket
+    is the state after position ``n_valid``."""
+    T = x.shape[1]
+    K = KDA_CONV_KERNEL
+    keep = jnp.logical_not(fresh)
+    ctx = jax.lax.dynamic_slice_in_dim(conv, slot, 1, axis=0)
+    ctx = jnp.where(keep, ctx, jnp.zeros_like(ctx))
+    qh, kh, v, g, beta, win = _kda_project(cfg, x, lp, ctx)
+    real = (jnp.arange(T) < n_valid)[None, :, None]
+    g = jnp.where(real[..., None], g, 0.0)
+    beta = jnp.where(real, beta, 0.0)
+    S0 = jax.lax.dynamic_slice_in_dim(state, slot, 1, axis=0)[0]
+    S0 = jnp.where(keep, S0, jnp.zeros_like(S0))
+    with jax.named_scope("kda_state_update"):
+        o, S = kda_chunked(S0, qh[0], kh[0], v[0], g[0], beta[0])
+    state = jax.lax.dynamic_update_slice_in_dim(state, S[None], slot, axis=0)
+    tail = jax.lax.dynamic_slice_in_dim(win, n_valid, K - 1, axis=1)
+    conv = jax.lax.dynamic_update_slice_in_dim(
+        conv, tail.astype(conv.dtype), slot, axis=0)
+    return _kda_output(cfg, o[None], x, lp), state, conv
+
+
+def _kda_decode(cfg: TransformerConfig, x, lp, state, conv, base, slots,
+                n_slots: int):
+    """One token a row: x [B, 1, D], ``slots`` [B] each row's state slot
+    (0, the dummy, for an inactive row), the layer's slots at rows ``base ..
+    base + n_slots`` of ``state`` and ``conv``. The update runs over the
+    layer's whole slice of the pool in slot order, the rows' vectors
+    scattered to their slots: a slot no row holds keeps its state (decay 1,
+    beta 0), and the pool is updated where it lives. So a step's state
+    traffic is that of ALL the layer's slots (``max_running + 1``), however
+    few rows are live: right for a batch that is kept full, a waste at low
+    occupancy, where a kernel over the live rows' slots would move less
+    (PERF.md section 7)."""
+    rows = base + slots
+    ctx = conv[rows]
+    qh, kh, v, g, beta, win = _kda_project(cfg, x, lp, ctx)
+    conv = conv.at[rows].set(win[:, 1:].astype(conv.dtype))
+
+    def by_slot(a):
+        return jnp.zeros((n_slots, *a.shape[1:]), a.dtype).at[slots].set(a)
+
+    with jax.named_scope("kda_state_update"):
+        S = jax.lax.dynamic_slice_in_dim(state, base, n_slots, axis=0)
+        o, S = kda_recurrent_step(S, by_slot(qh[:, 0]), by_slot(kh[:, 0]),
+                                  by_slot(v[:, 0]), by_slot(g[:, 0]),
+                                  by_slot(beta[:, 0]))
+        state = jax.lax.dynamic_update_slice_in_dim(state, S, base, axis=0)
+        o = o[slots]
+    return _kda_output(cfg, o[:, None], x, lp), state, conv
+
+
+# --------------------------------------------------------------------- #
 # Paged KV cache (vLLM PagedAttention / Orca continuous batching, TPU form):
 # KV lives in fixed-size block POOLS [n_layer, num_blocks, block_size, KV*Hd]
 # shared by every in-flight request; each request owns a block table mapping
@@ -1155,14 +1496,39 @@ def forward_cached(cfg: TransformerConfig, params, tokens, cache, pos, pad_bias=
 # step touches the rows it reads and writes and nothing else of the pool.
 
 def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
-                        block_size: int, dtype=jnp.bfloat16) -> Dict[str, Any]:
-    """Paged KV pools: k/v [n_layer, num_blocks, block_size, kv_heads * Hd]
-    (kv head ``g`` of a token at ``[g*Hd, (g+1)*Hd)`` of its row). This is
-    the one home of the shape. Block 0 is conventionally the allocator's
-    dummy block (padding tokens and inactive decode rows write there;
-    nothing ever reads it)."""
-    shape = (cfg.n_layer, num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
-    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+                        block_size: int, dtype=jnp.bfloat16,
+                        state_slots: int = 0) -> Dict[str, Any]:
+    """Paged pools, a dict that each kind of layer adds to. Attention
+    layers: k/v [kv layers, num_blocks, block_size, kv_heads * Hd] (kv head
+    ``g`` of a token at ``[g*Hd, (g+1)*Hd)`` of its row). Block 0 is
+    conventionally the allocator's dummy block (padding tokens and inactive
+    decode rows write there; nothing ever reads it). Linear-attention
+    layers: ``state`` and ``conv``, each a tuple of one array a
+    linear-attention POSITION of the period, [periods, state_slots, H, dk,
+    dv] float32 and [periods, state_slots, K-1, 3*H*dk]: a slot a running
+    request and slot 0 the dummy. (One array a position, not one for all:
+    the period's layers are unrolled, so their offsets into a shared array
+    would be constants, XLA would fold a later layer's read through the
+    earlier layer's update back to the buffer as it came in, and a buffer
+    read after it is written cannot be updated in place: the whole pool
+    was copied in and out, twice its size a step.) This is the one home of
+    the shapes."""
+    spec = cfg.cache_spec
+    shape = (spec["kv"], num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
+    pools = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    if spec["state"]:
+        if state_slots < 2:
+            raise ValueError("a stack with recurrent state needs state_slots "
+                             ">= 2 (the dummy and one a running request)")
+        H, dk, K = cfg.lin_heads, cfg.lin_head_dim, KDA_CONV_KERNEL
+        n_lin, P_ = cfg.period.count(LINEAR_ATTENTION), cfg.n_periods
+        pools["state"] = tuple(
+            jnp.zeros((P_, state_slots, H, dk, dk), jnp.float32)
+            for _ in range(n_lin))
+        pools["conv"] = tuple(
+            jnp.zeros((P_, state_slots, K - 1, 3 * H * dk), dtype)
+            for _ in range(n_lin))
+    return pools
 
 
 def _pool_scatter(pool, kv_new, slots):
@@ -1184,21 +1550,33 @@ def _paged_gather(pool, block_tables, kv_heads: int):
 
 
 def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
-                       mlp_fn=None):
+                       mlp_fn=None, lin_fn=None):
     """The ONE way the paged programs thread the pools through the layer
-    stack: as the scan's carry, viewed as [n_layer*num_blocks, bs, KV*Hd] (a
-    bitcast), with the layer index among the scanned inputs. Layer ``l``
-    lives at blocks ``[l*num_blocks, (l+1)*num_blocks)`` of that view, so
-    ``attn_fn(x_normed, lp_attn, kp, vp, block0, slot0)`` reads and writes
-    it through ``block_tables + block0`` / ``slots + slot0``: no per-layer
-    slice of a pool exists and nothing is stacked. An ``mlp_fn`` may return
-    ``(out, aux)``: ``aux`` (an MoE layer's per-expert counts) comes back
-    stacked over the layers, else None. Returns (x, new pools, aux)."""
-    L, Nb, bs = pools["k"].shape[:3]
+    stack: as the carry of a scan over the PERIODS of the layer pattern (a
+    period of one attention layer where the stack has no pattern), a
+    period's layers unrolled inside it, each pool viewed with its layer
+    axis merged into the next (a bitcast): k and v as
+    [layers*num_blocks, bs, KV*Hd], state and conv as [layers*slots, ...].
+    Attention layer ``l`` lives at blocks ``[l*num_blocks, (l+1)*num_blocks)``
+    of that view, so ``attn_fn(x_normed, lp_attn, kp, vp, block0, slot0)``
+    reads and writes it through ``block_tables + block0`` / ``slots +
+    slot0``; linear-attention layer ``l`` at rows ``[l*slots, (l+1)*slots)``,
+    ``lin_fn(x_normed, lp_lin, state, conv, row0)``. No per-layer slice of
+    a pool exists and nothing is stacked. An ``mlp_fn`` may return ``(out,
+    aux)``: ``aux`` (an MoE layer's per-expert counts) comes back stacked
+    over the layers, else None. Returns (x, new pools, aux)."""
+    period = cfg.period
+    n_att, n_lin = period.count(ATTENTION), period.count(LINEAR_ATTENTION)
+    Nb, bs = pools["k"].shape[1:3]
+    n_slots = pools["state"][0].shape[1] if n_lin else 0
+    groups = params["layers"] if cfg.layer_kinds is not None \
+        else (params["layers"],)
 
-    def run_block(carry, xs):
-        h, kp, vp = carry
-        lp, l = xs
+    def run_period(carry, xs):
+        h, flat = carry
+        flat = {n: list(a) if isinstance(a, tuple) else a
+                for n, a in flat.items()}
+        lps, p = xs
         aux = []
         mfn = mlp_fn
         if mlp_fn is not None:
@@ -1208,18 +1586,37 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
                     out, a = out
                     aux.append(a)
                 return out
-        h, kp, vp = _decode_block(
-            cfg, h, lp,
-            lambda xn: attn_fn(xn, lp["attn"], kp, vp, l * Nb, l * (Nb * bs)),
-            mfn)
-        return (h, kp, vp), (aux[0] if aux else None)
+        i_att = i_lin = 0
+        for kind, lp in zip(period, lps):
+            if kind == ATTENTION:
+                l = p * n_att + i_att
+                i_att += 1
+                h, flat["k"], flat["v"] = _decode_block(
+                    cfg, h, lp,
+                    lambda xn: attn_fn(xn, lp["attn"], flat["k"], flat["v"],
+                                       l * Nb, l * (Nb * bs)),
+                    mfn)
+            else:
+                i = i_lin
+                i_lin += 1
+                h, flat["state"][i], flat["conv"][i] = _decode_block(
+                    cfg, h, lp,
+                    lambda xn: lin_fn(xn, lp["lin"], flat["state"][i],
+                                      flat["conv"][i], p * n_slots),
+                    mfn, scope=LINEAR_ATTENTION)
+        flat = {n: tuple(a) if isinstance(a, list) else a
+                for n, a in flat.items()}
+        out = None if not aux else aux[0] if len(aux) == 1 else jnp.stack(aux)
+        return (h, flat), out
 
-    flat = {n: a.reshape(L * Nb, *a.shape[2:]) for n, a in pools.items()}
-    (x, kp, vp), aux = jax.lax.scan(
-        run_block, (x, flat["k"], flat["v"]),
-        (params["layers"], jnp.arange(L, dtype=jnp.int32)))
-    return x, {"k": kp.reshape(pools["k"].shape),
-               "v": vp.reshape(pools["v"].shape)}, aux
+    flat = jax.tree.map(
+        lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), pools)
+    (x, flat), aux = jax.lax.scan(
+        run_period, (x, flat),
+        (groups, jnp.arange(cfg.n_periods, dtype=jnp.int32)))
+    if aux is not None and len(period) > 1:
+        aux = aux.reshape(cfg.n_layer, *aux.shape[2:])
+    return x, jax.tree.map(lambda a, b: a.reshape(b.shape), flat, pools), aux
 
 
 def paged_real_rows(pools, slots):
@@ -1278,7 +1675,7 @@ def _paged_decode_attention(cfg: TransformerConfig, x, lp, positions, pos,
             _paged_gather(vp, block_tables, cfg.kv_heads), positions, pad_bias)
     dispatch.record("paged_decode", form,
                     f"B={B} H={H} KV={cfg.kv_heads} Hd={cfg.head_dim} bs={bs}")
-    out = out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
+    out = _attn_out(cfg, out, x, lp)
     return out, kp, vp
 
 
@@ -1311,7 +1708,7 @@ def _paged_prefill_attention(cfg: TransformerConfig, x, lp, positions,
         form = "einsum"
     dispatch.record("paged_prefill", form, f"T={T}")
     out = out.reshape(B, T, H * Hd)
-    out = out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
+    out = _attn_out(cfg, out, x, lp)
     return out, kp, vp
 
 
@@ -1339,8 +1736,15 @@ def _paged_chunk_attention(cfg: TransformerConfig, x, lp, positions,
     out = _grouped_cache_einsum(cfg, q, _paged_gather(kp, block_tables, KV),
                                 _paged_gather(vp, block_tables, KV),
                                 positions, None)
-    out = out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
+    out = _attn_out(cfg, out, x, lp)
     return out, kp, vp
+
+
+def _no_layer_pattern(cfg: TransformerConfig, what: str):
+    if cfg.layer_kinds is not None:
+        raise NotImplementedError(
+            f"{what} is not built for a stack with a layer pattern "
+            f"({cfg.period}): it runs on the paged serving path only")
 
 
 def _check_paged_config(cfg: TransformerConfig):
@@ -1351,16 +1755,30 @@ def _check_paged_config(cfg: TransformerConfig):
             "sparse_attention is not supported by the paged KV decode path")
 
 
+def _lin_prefill_fn(cfg: TransformerConfig, pools, state_slot, n_valid, fresh):
+    """The ``lin_fn`` of a prefill or chunk program (None for a stack
+    without state): the request's slot in each linear-attention layer."""
+    if "state" not in pools:
+        return None
+    if state_slot is None:
+        raise ValueError("a stack with recurrent state needs the request's "
+                         "state slot")
+    return lambda xn, lp, st, cv, row0: _kda_prefill(
+        cfg, xn, lp, st, cv, row0 + state_slot, n_valid, fresh)
+
+
 
 def forward_paged_prefill(cfg: TransformerConfig, params, tokens, pools,
-                          slots, last_idx, mlp_fn=None):
+                          slots, last_idx, mlp_fn=None, state_slot=None):
     """Prefill ONE admitted request into its allocated blocks.
 
     tokens [1, T] right-padded prompt (T the compile bucket); slots [T]
     flat pool slots per prompt position (block_table[t // bs] * bs + t % bs,
     pads routed to the dummy block); last_idx [] int32 index of the last
     real prompt token. Returns (logits [1, vocab] at last_idx, new pools) —
-    junk pad positions are causally invisible to the sampled position."""
+    junk pad positions are causally invisible to the sampled position.
+    ``state_slot`` [] int32 (a stack with recurrent state): the request's
+    slot, which a whole prefill starts from zero."""
     _check_paged_config(cfg)
     x, positions = cached_embed(cfg, params, tokens, jnp.int32(0),
                                 pools["k"].dtype)
@@ -1369,7 +1787,7 @@ def forward_paged_prefill(cfg: TransformerConfig, params, tokens, pools,
         cfg, params, pools, x,
         lambda xn, lp, kp, vp, block0, slot0: _paged_prefill_attention(
             cfg, xn, lp, positions, kp, vp, slots + slot0),
-        mlp_fn)
+        mlp_fn, _lin_prefill_fn(cfg, pools, state_slot, last_idx + 1, True))
     # head on the sampled position only: the [1, vocab] projection, not
     # the whole bucket's [T, vocab]
     xl = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
@@ -1378,7 +1796,7 @@ def forward_paged_prefill(cfg: TransformerConfig, params, tokens, pools,
 
 def forward_paged_prefill_chunk(cfg: TransformerConfig, params, tokens,
                                 pools, block_tables, slots, start_pos,
-                                last_idx, mlp_fn=None):
+                                last_idx, mlp_fn=None, state_slot=None):
     """Prefill ONE CHUNK of a request that already has ``start_pos`` tokens
     cached in its blocks (a prefix-cache hit, or earlier chunks of a
     Sarathi-style chunked prefill).
@@ -1390,7 +1808,9 @@ def forward_paged_prefill_chunk(cfg: TransformerConfig, params, tokens,
     dummy block); start_pos [] int32 tokens already cached; last_idx []
     int32 index WITHIN the chunk of its last real token. Returns
     (logits [1, vocab] at last_idx, new pools) — intermediate chunks
-    discard the logits, the final chunk samples from them."""
+    discard the logits, the final chunk samples from them. ``state_slot``
+    [] int32 (a stack with recurrent state): the request's slot, zeroed by
+    the chunk that starts at position 0 and carried over the later ones."""
     _check_paged_config(cfg)
     x, positions = cached_embed(cfg, params, tokens, start_pos,
                                 pools["k"].dtype)
@@ -1400,7 +1820,8 @@ def forward_paged_prefill_chunk(cfg: TransformerConfig, params, tokens,
         lambda xn, lp, kp, vp, block0, slot0: _paged_chunk_attention(
             cfg, xn, lp, positions, kp, vp, block_tables + block0,
             slots + slot0),
-        mlp_fn)
+        mlp_fn, _lin_prefill_fn(cfg, pools, state_slot, last_idx + 1,
+                                start_pos == 0))
     xl = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
     return cached_head(cfg, params, xl)[:, 0, :], pools
 
@@ -1409,7 +1830,8 @@ def copy_paged_block(pools, src, dst):
     """Device copy of one pool block across every layer (the scheduler's
     copy-on-write split: a request restarting mid-block inside a SHARED
     block gets a private copy before it writes). src/dst [] int32."""
-    return {"k": pools["k"].at[:, dst].set(pools["k"][:, src]),
+    return {**pools,
+            "k": pools["k"].at[:, dst].set(pools["k"][:, src]),
             "v": pools["v"].at[:, dst].set(pools["v"][:, src])}
 
 
@@ -1469,7 +1891,7 @@ def _paged_verify_attention(cfg: TransformerConfig, x, lp, positions,
             outs.append(o)
         if len(outs) == W:
             out = jnp.stack(outs, axis=1).reshape(B, W, H * Hd)
-            out = out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
+            out = _attn_out(cfg, out, x, lp)
             return out, kp, vp
 
     # re-scattering already-written positions is idempotent (same values
@@ -1483,7 +1905,7 @@ def _paged_verify_attention(cfg: TransformerConfig, x, lp, positions,
     out = _grouped_cache_einsum(cfg, q, _paged_gather(kp, block_tables, KV),
                                 _paged_gather(vp, block_tables, KV),
                                 positions, None)
-    out = out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
+    out = _attn_out(cfg, out, x, lp)
     return out, kp, vp
 
 
@@ -1508,6 +1930,10 @@ def forward_paged_verify(cfg: TransformerConfig, params, tokens, pools,
     (attention masks at each row's pos) and overwritten as decode
     advances; the scheduler handles pos rewind + prefix-cache rollback."""
     _check_paged_config(cfg)
+    if cfg.cache_spec["state"]:
+        raise NotImplementedError(
+            "a verify window rewinds to the last accepted position, and a "
+            "recurrent state cannot be rewound (no snapshot is kept)")
     x, positions = cached_embed(cfg, params, tokens, pos, pools["k"].dtype)
 
     x, pools, _ = _scan_paged_layers(
@@ -1520,14 +1946,25 @@ def forward_paged_verify(cfg: TransformerConfig, params, tokens, pools,
 
 
 def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
-                         block_tables, pos, pad_bias=None, mlp_fn=None):
+                         block_tables, pos, pad_bias=None, mlp_fn=None,
+                         state_slots=None):
     """One fused decode step over ALL running requests: tokens [B, 1] (each
     request's last sampled token), block_tables [B, max_blocks], pos [B]
     per-request cache depths. Returns (logits [B, vocab], new pools), and
     third what an ``mlp_fn`` that returns ``(out, aux)`` gave, stacked over
-    the layers (an MoE model's [L, E + 1] assignment counts)."""
+    the layers (an MoE model's [L, E + 1] assignment counts).
+    ``state_slots`` [B] int32 (a stack with recurrent state): each row's
+    state slot, 0 (the dummy) for an inactive row."""
     _check_paged_config(cfg)
     x, positions = cached_embed(cfg, params, tokens, pos, pools["k"].dtype)
+    lin_fn = None
+    if "state" in pools:
+        if state_slots is None:
+            raise ValueError("a stack with recurrent state needs the rows' "
+                             "state slots")
+        n_slots = pools["state"][0].shape[1]
+        lin_fn = lambda xn, lp, st, cv, row0: _kda_decode(  # noqa: E731
+            cfg, xn, lp, st, cv, row0, state_slots, n_slots)
 
     # the decode step derives its write slots from the (layer-offset) table
     x, pools, aux = _scan_paged_layers(
@@ -1535,7 +1972,7 @@ def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
         lambda xn, lp, kp, vp, block0, slot0: _paged_decode_attention(
             cfg, xn, lp, positions, pos, kp, vp, block_tables + block0,
             pad_bias),
-        mlp_fn)
+        mlp_fn, lin_fn)
     logits = cached_head(cfg, params, x)[:, 0, :]
     return (logits, pools) if aux is None else (logits, pools, aux)
 
@@ -1547,6 +1984,7 @@ def run_layers(cfg: TransformerConfig, x, layer_params, positions, mask_bias,
     encoders (e.g. the CLIP vision tower). ``rng`` (training loss paths
     only) seeds per-layer dropout keys; None keeps every path deterministic
     and the traced program identical to the dropout-free form."""
+    _no_layer_pattern(cfg, "the training forward (and its scan's backward)")
     with_keys = rng is not None and bool(cfg.dropout)
     n_layer = jax.tree.leaves(layer_params)[0].shape[0]
 

@@ -181,16 +181,31 @@ def top2gating(logits: jnp.ndarray,
 # Top-k of any size, no capacity, no dropped token.
 
 
-def topk_routing(logits: jnp.ndarray, k: int, norm_topk_prob: bool = False
+def topk_routing(logits: jnp.ndarray, k: int, norm_topk_prob: bool = False,
+                 scoring: str = "softmax", select_bias=None
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Softmax first, then the ``k`` largest probabilities as they are
-    (``norm_topk_prob``: divided by their sum). logits [T, E] ->
-    (weights [T, k] float32, experts [T, k] int32, probs [T, E] float32).
+    """A score for every expert first (``scoring``: ``softmax`` over the
+    experts, or ``sigmoid`` of each logit), then the ``k`` largest as they
+    are (``norm_topk_prob``: divided by their sum). ``select_bias`` [E]
+    (the sigmoid router's load-balancing bias) is added to the scores for
+    the CHOICE only: the weights are the scores without it. logits [T, E] ->
+    (weights [T, k] float32, experts [T, k] int32, scores [T, E] float32).
     All in float32 whatever the logits came in."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    weights, experts = jax.lax.top_k(probs, k)
+    logits = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"scoring={scoring!r} (expected softmax|sigmoid)")
+    if select_bias is None:
+        weights, experts = jax.lax.top_k(probs, k)
+    else:
+        _, experts = jax.lax.top_k(probs + select_bias.astype(jnp.float32), k)
+        weights = jnp.take_along_axis(probs, experts, axis=-1)
     if norm_topk_prob:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + (1e-20 if scoring == "sigmoid" else 0.0))
     return weights, experts.astype(jnp.int32), probs
 
 
