@@ -24,7 +24,12 @@ the default of ``dscli serve``), weights random from a seed:
    ``/healthz`` says ``restarts: 0``, the fault counters are zero, the
    decode program ran the paged Pallas kernel, and every served token is the
    argmax of an ``attention_backend="xla"`` reference up to bf16 rounding;
-4. *four-chip legs* (only with >= 4 devices visible, else ``not run``) —
+4. *state leg* — a stack with a recurrent state beside its KV pool (the
+   ``solar_open2`` toy at a state width the KDA decode kernel tiles) through
+   ``init_inference`` and ``generate_batch``: the decode program ran the
+   Pallas state kernel (``kda_decode=kda_kernel``), and its tokens are those
+   of the same weights on the plain-XLA forms;
+5. *four-chip legs* (only with >= 4 devices visible, else ``not run``) —
    the train leg under ZeRO-3 on ``mesh {"fsdp": 4}`` and the serve leg with
    ``serving.tp = 4``, asserting from ``addressable_shards`` that params,
    optimizer state and KV pools are split four ways.
@@ -418,6 +423,67 @@ def serve_leg(name, dry_run, n_dev, tp):
     return out
 
 
+def state_leg(name, dry_run, n_dev):
+    """A stack with a recurrent state beside its KV pool: the ``solar_open2``
+    toy at a width the KDA decode kernel tiles (two heads of a 128 x 128
+    float32 state; GQA heads of 64, which the paged and flash kernels take),
+    six requests over four rows through ``generate_batch``, so rows idle on
+    the dummy slot and slots are handed on. The decode step must have taken
+    the Pallas state kernel, and its tokens are those of the same weights on
+    the plain-XLA forms (float32 weights, matmuls in full float32: greedy
+    picks decided by the last bit aside, the two forms are one result)."""
+    import jax
+    import numpy as np
+
+    import deepspeed_tpu
+    from deepspeed_tpu.models.presets import get_model
+    from deepspeed_tpu.ops import dispatch
+
+    say(f"{name}: solar_open2 toy (1 GQA + 3 KDA layers, 2 heads of a "
+        "128 x 128 state) fp32 block_size 128 max_running 4")
+    fresh_leg()
+
+    def toy(backend):
+        return get_model("solar_open2", "tiny", head_size=64, lin_heads=2,
+                         lin_head_dim=128, attention_backend=backend)
+
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, 512, size=n) for n in (5, 40, 130, 70, 9, 200)]
+    serving = {"block_size": 128, "max_running": 4}
+    n_new = sizes(dry_run)["max_tokens"]
+    t0 = time.perf_counter()
+    with jax.default_matmul_precision("highest"):
+        engine = deepspeed_tpu.init_inference(
+            toy("flash" if dry_run else "auto"), dtype="fp32", serving=serving)
+        served = engine.generate_batch(prompts, max_new_tokens=n_new)
+        forms = dispatch.selected()
+        ref = deepspeed_tpu.init_inference(toy("xla"), params=engine.params,
+                                           dtype="fp32", serving=serving)
+        want = ref.generate_batch(prompts, max_new_tokens=n_new)
+    assert dry_run or not any(k.endswith("=interpret") for k in forms), forms
+    if n_dev == 1:
+        # (over several devices the engine builds a mesh, where a bare
+        # pallas_call is illegal: the step takes its plain-XLA form there)
+        how = "interpret" if dry_run else "compiled"
+        for need in ("kda_decode=kda_kernel", "paged_decode=paged_kernel",
+                     f"kernel/kda_decode_update={how}"):
+            assert need in forms, (need, forms)
+        assert "kda_decode=slot_update" not in forms, forms
+    assert "kda_decode=slot_update" in dispatch.selected()
+    same = sum(np.array_equal(a, b) for a, b in zip(served, want))
+    assert same == len(prompts), (
+        f"{name}: {len(prompts) - same} of {len(prompts)} completions differ "
+        "from the plain-XLA forms'")
+    out = dict(ok=True, forms=sorted(forms), requests=len(prompts),
+               tokens=len(prompts) * n_new, same_as_xla=f"{same}/{len(prompts)}",
+               wall_s=round(time.perf_counter() - t0, 2))
+    say(f"{name}: ok, {out['requests']} requests, tokens as the plain-XLA "
+        f"forms' in {out['same_as_xla']}, forms {sorted(forms)}")
+    del engine, ref
+    gc.collect()
+    return out
+
+
 # ----------------------------------------------------------------------- #
 
 
@@ -472,6 +538,7 @@ def main(argv=None):
     legs = summary["legs"] = {}
     legs["train"] = train_leg("train", dry_run, n, 1, {"dp": -1})
     legs["serve"] = serve_leg("serve", dry_run, n, 0)
+    legs["serve_state"] = state_leg("serve_state", dry_run, n)
     if n >= 4:
         legs["train_zero3_fsdp4"] = train_leg("train_zero3_fsdp4", dry_run, 4,
                                               3, {"fsdp": 4})

@@ -1266,10 +1266,10 @@ def forward_cached(cfg: TransformerConfig, params, tokens, cache, pos, pad_bias=
 # ``state`` [periods, slots, H, dk, dv] and ``conv`` [periods, slots, K-1,
 # 3*H*dk], one array of each a linear-attention position of the period
 # (init_paged_kv_cache says why). Slot 0 is the dummy (inactive decode rows and nothing else).
-# Decode runs the one-token update over the WHOLE pool slice of a layer,
-# slot-major (a slot no row of the step holds is left as it is: decay 1,
-# beta 0), so the state is read and written where it lives and never
-# gathered; prefill runs a chunked form.
+# Decode updates the state where it lives and never gathers it: on TPU a
+# Pallas kernel over the live rows' slots, elsewhere the one-token update
+# over the WHOLE pool slice of a layer, slot-major (_kda_decode); prefill
+# runs a chunked form.
 
 _HI = jax.lax.Precision.HIGHEST
 #: tokens a chunk of the chunked form; every exponent inside is a decay
@@ -1448,33 +1448,62 @@ def _kda_prefill(cfg: TransformerConfig, x, lp, state, conv, slot, n_valid,
     return _kda_output(cfg, o[None], x, lp), state, conv
 
 
+def _kda_slot_update(state, qh, kh, v, g, beta, slots, base, n_slots: int):
+    """The plain-XLA form of a decode step's state update, and what the
+    tests compare the kernel against: ``kda_recurrent_step`` over the
+    layer's WHOLE slice of the pool (rows ``base .. base + n_slots``) in
+    slot order, the rows' vectors ([B, H, d], by row) scattered to their
+    slots; a slot no row holds keeps its state (decay 1, beta 0). Returns
+    (o [B, H, dv] by row, the pool)."""
+    def by_slot(a):
+        return jnp.zeros((n_slots, *a.shape[1:]), a.dtype).at[slots].set(a)
+
+    S = jax.lax.dynamic_slice_in_dim(state, base, n_slots, axis=0)
+    o, S = kda_recurrent_step(S, by_slot(qh), by_slot(kh), by_slot(v),
+                              by_slot(g), by_slot(beta))
+    return o[slots], jax.lax.dynamic_update_slice_in_dim(state, S, base, axis=0)
+
+
+def _kda_state_update(cfg: TransformerConfig, state, qh, kh, v, g, beta,
+                      slots, base, n_slots: int):
+    """A decode step's state update in one of two forms of the same float32
+    arithmetic, chosen as the paged kernel is (``_use_flash``: the backend
+    and the shape, never the model):
+
+    * ``kda_kernel`` (TPU): ``ops/pallas/kda_decode_update.py`` reads each
+      LIVE row's state once and writes it once, addressed row -> slot, the
+      rows' vectors taken by row. A step's state traffic is the live rows';
+      the dummy and every slot no live row holds are not touched.
+    * ``slot_update`` (elsewhere, and shapes the kernel cannot tile):
+      ``_kda_slot_update``. Its traffic is that of ALL the layer's slots,
+      read twice and written once."""
+    step = (qh, kh, v, g, beta, slots, base)
+    out = None
+    if _use_flash(cfg):
+        from deepspeed_tpu.ops.pallas.kda_decode_update import \
+            kda_decode_update
+        out = kda_decode_update(state, *step)
+    dispatch.record("kda_decode", "slot_update" if out is None else "kda_kernel",
+                    f"B={qh.shape[0]} H={qh.shape[1]} dk={qh.shape[2]} "
+                    f"dv={v.shape[2]} slots={n_slots}")
+    return out or _kda_slot_update(state, *step, n_slots)
+
+
 def _kda_decode(cfg: TransformerConfig, x, lp, state, conv, base, slots,
                 n_slots: int):
     """One token a row: x [B, 1, D], ``slots`` [B] each row's state slot
     (0, the dummy, for an inactive row), the layer's slots at rows ``base ..
-    base + n_slots`` of ``state`` and ``conv``. The update runs over the
-    layer's whole slice of the pool in slot order, the rows' vectors
-    scattered to their slots: a slot no row holds keeps its state (decay 1,
-    beta 0), and the pool is updated where it lives. So a step's state
-    traffic is that of ALL the layer's slots (``max_running + 1``), however
-    few rows are live: right for a batch that is kept full, a waste at low
-    occupancy, where a kernel over the live rows' slots would move less
-    (PERF.md section 7)."""
+    base + n_slots`` of ``state`` and ``conv``. The state is updated where
+    it lives (``_kda_state_update``): on TPU by a kernel over the live rows'
+    slots, so a step's state traffic goes with the live rows; elsewhere over
+    the layer's whole slice, in slot order."""
     rows = base + slots
     ctx = conv[rows]
     qh, kh, v, g, beta, win = _kda_project(cfg, x, lp, ctx)
     conv = conv.at[rows].set(win[:, 1:].astype(conv.dtype))
-
-    def by_slot(a):
-        return jnp.zeros((n_slots, *a.shape[1:]), a.dtype).at[slots].set(a)
-
     with jax.named_scope("kda_state_update"):
-        S = jax.lax.dynamic_slice_in_dim(state, base, n_slots, axis=0)
-        o, S = kda_recurrent_step(S, by_slot(qh[:, 0]), by_slot(kh[:, 0]),
-                                  by_slot(v[:, 0]), by_slot(g[:, 0]),
-                                  by_slot(beta[:, 0]))
-        state = jax.lax.dynamic_update_slice_in_dim(state, S, base, axis=0)
-        o = o[slots]
+        o, state = _kda_state_update(cfg, state, qh[:, 0], kh[:, 0], v[:, 0],
+                                     g[:, 0], beta[:, 0], slots, base, n_slots)
     return _kda_output(cfg, o[:, None], x, lp), state, conv
 
 

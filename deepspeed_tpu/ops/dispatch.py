@@ -17,6 +17,10 @@ Sites and their forms:
 ``paged_decode``      ``paged_kernel`` | ``paged_kernel_sharded`` |
                       ``gather_einsum``
 ``paged_prefill``     ``flash`` | ``einsum``
+``kda_decode``        ``kda_kernel`` | ``slot_update`` (a linear-attention
+                      layer's one-token state update: the Pallas kernel
+                      over the live rows' slots | ``kda_recurrent_step``
+                      over the layer's whole slice of the pool)
 ``kernel/<name>``     ``compiled`` | ``interpret`` | ``jnp`` (one per Pallas
                       entry point; ``jnp`` = the kernel's plain-XLA twin)
 ====================  ====================================================

@@ -209,3 +209,88 @@ def test_paged_kernel_compiles_for_v5e_at_real_widths(B, H, KV, Hd, width,
     text = compiled.as_text()
     assert "paged_decode_attention" in text
     assert compiled.memory_analysis().temp_size_in_bytes < blocks * BS * KV * Hd
+
+
+def test_state_pool_stays_in_one_buffer_for_v5e(one_v5e, monkeypatch):
+    """The recurrent state beside the KV pool: the decode program of a
+    two-period stateful toy (a KDA state the kernel tiles: 2 heads of 128 x
+    128) compiled for a described v5e runs ``kda_decode_update`` on the
+    pool where it lies. Every pool is aliased, the temporaries stay under a
+    quarter of the state, and no ``copy``, ``dynamic-slice`` or
+    ``dynamic-update-slice`` of a layer's state size exists: XLA copies a
+    state pool in and out when it cannot prove an update in place (PERF.md
+    section 6, PR 31: 1.73 GB of temporaries against 0.21)."""
+    import deepspeed_tpu.comm as dist
+    from deepspeed_tpu.models.presets import get_model
+    from deepspeed_tpu.ops import dispatch
+    dist.set_mesh(None)
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    rows, num_blocks = 8, 16
+    model = get_model("solar_open2", "tiny", n_layer=8, head_size=64,
+                      lin_heads=2, lin_head_dim=128)
+    assert model.config.n_periods == 2
+    params = jax.tree.map(lambda a: sds(a.shape, jnp.bfloat16),
+                          jax.eval_shape(model.init_params, jax.random.key(0)))
+    pools = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda: model.init_paged_cache(num_blocks, BS, dtype=jnp.bfloat16,
+                                       state_slots=rows + 1)))
+    n_max = -(-model.config.max_seq // BS)
+    dispatch.reset()
+    compiled = jax.jit(
+        lambda p, po, t, bt, pos, ss: model.forward_paged_decode(
+            p, t, po, bt, pos, state_slots=ss), donate_argnums=(1,)).lower(
+        params, pools, sds((rows, 1), I32), sds((rows, n_max), I32),
+        sds((rows,), I32), sds((rows,), I32)).compile()
+    assert dispatch.selected()["kda_decode=kda_kernel"] == 3
+    assert dispatch.selected()["kernel/kda_decode_update=compiled"] == 3
+
+    nbytes = lambda tree: sum(  # noqa: E731
+        int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+        for a in jax.tree.leaves(tree))
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes(pools), "the pools are not aliased"
+    assert mem.temp_size_in_bytes < nbytes(pools["state"]) / 4, (
+        mem.temp_size_in_bytes, nbytes(pools["state"]))
+    text = compiled.as_text()
+    # the period's body: the paged kernel and three of the KDA kernel
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    layer_state = int(np.prod(pools["state"][0].shape[1:]))
+    moved = []
+    for m in re.finditer(
+            r"= \w+\[([\d,]+)\]\S* "
+            r"(copy|dynamic-slice|dynamic-update-slice)\(", text):
+        if int(np.prod([int(d) for d in m.group(1).split(",")])) >= layer_state:
+            moved.append(m.group(0))
+    assert not moved, moved
+
+
+def test_kda_kernel_compiles_for_v5e_at_real_widths(one_v5e, monkeypatch):
+    """Mosaic takes the KDA decode kernel at ``solaropen2_serve_decode``'s
+    widths (128 rows, 64 heads of a 128 x 128 float32 state, 129 slots of
+    one period: what interpret mode cannot see is the turned vectors' lane
+    slices, the scalars in SMEM and the VMEM two 16 MB phase buffers take),
+    and the pool is its input and its output in one buffer."""
+    from deepspeed_tpu.ops import dispatch
+    from deepspeed_tpu.ops.pallas.kda_decode_update import kda_decode_update
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    B, H, dk, dv, slots = 128, 64, 128, 128, 129
+    f32 = jnp.float32
+
+    def sds(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    compiled = jax.jit(
+        lambda S, q, k, v, g, b, ss: kda_decode_update(
+            S, q, k, v, g, b, ss, 0, interpret=False),
+        donate_argnums=(0,)).lower(
+        sds((slots, H, dk, dv)), sds((B, H, dk)), sds((B, H, dk)),
+        sds((B, H, dv)), sds((B, H, dk)), sds((B, H)), sds((B,), I32)).compile()
+    assert "kda_decode_update" in compiled.as_text()
+    pool_bytes = slots * H * dk * dv * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not aliased"
+    assert mem.temp_size_in_bytes < pool_bytes / 64, mem.temp_size_in_bytes

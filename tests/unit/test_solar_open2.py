@@ -52,15 +52,27 @@ def _clean_mesh():
     dist.set_mesh(None)
 
 
-@pytest.fixture(scope="module")
-def toy():
-    """(model, float32 params, the reference's cfg, the name map)."""
+def load_toy(backend="auto", **over):
+    """(model, float32 params, the reference's cfg, the name map) of the toy
+    configuration, ``over`` laid over its preset and the reference's cfg
+    alike (``head_size`` is the reference's ``head_dim``)."""
     with open(os.path.join(BENCH, "configs", TOY + ".json")) as f:
         config = json.load(f)
     name_map = correctness.load_map(TOY)
-    model = get_model(**config["preset"])
+    model = get_model(**config["preset"], **over, attention_backend=backend)
     params = make_params(model, 3100000031, jnp.float32, jax.devices()[:1])
-    return model, params, correctness.reference_config(config, name_map), name_map
+    cfg = correctness.reference_config(config, name_map)
+    cfg.update({"head_dim" if k == "head_size" else k: v for k, v in over.items()})
+    return model, params, cfg, name_map
+
+
+#: a width the KDA decode kernel tiles: two heads of a 128 x 128 state
+WIDE = dict(lin_heads=2, lin_head_dim=128)
+
+
+@pytest.fixture(scope="module")
+def toy():
+    return load_toy()
 
 
 def tokens_of(seed, n, vocab=512):
@@ -75,12 +87,13 @@ def reference_logits(toy, tokens):
 
 
 def paged_logits(model, params, tokens, n_prompt, chunk=0, slot=2, rows=3,
-                 decode_slot=None):
+                 decode_slot=None, decode_model=None):
     """The logits of positions ``n_prompt - 1 ..`` through the paged
     programs: the prompt prefilled (whole, or ``chunk`` tokens a piece into
     the request's blocks and state slot), then the rest decoded one token a
     step, teacher-forced, in row 1 of ``rows`` (the others idle), from the
-    request's state slot (``decode_slot``: a planted fault's)."""
+    request's state slot (``decode_slot``: a planted fault's) by
+    ``decode_model`` (the same weights under another backend), if given."""
     n_blocks = -(-len(tokens) // BS)
     pools = model.init_paged_cache(n_blocks + 2, BS, jnp.float32,
                                    state_slots=rows + 1)
@@ -108,7 +121,7 @@ def paged_logits(model, params, tokens, n_prompt, chunk=0, slot=2, rows=3,
                 params, toks, pools, slots, np.int32(n - 1), np.int32(slot))
         start += n
     out.append(np.asarray(lg)[0])
-    decode = jax.jit(model.forward_paged_decode)
+    decode = jax.jit((decode_model or model).forward_paged_decode)
     for pos in range(n_prompt, len(tokens)):
         bt = np.zeros((rows, n_blocks), np.int32)
         bt[1] = table
@@ -237,6 +250,27 @@ def test_prefill_then_decode_against_the_full_forward(toy, n_prompt, chunk):
     for st in pools["state"]:
         assert float(jnp.abs(st[0, 1] - 3.0).max()) == 0
         assert float(jnp.abs(st[0, 3] - 3.0).max()) == 0
+
+
+def test_the_decode_kernel_against_the_full_forward():
+    """The same run at a width the KDA decode kernel tiles (``WIDE``) with
+    the paged programs on their Pallas forms, which the CPU interprets: the
+    prompt prefilled, 12 tokens decoded through
+    ``ops/pallas/kda_decode_update.py``, logits against the reference's full
+    forward within the same LOGIT_TOL; the idle rows' dummy slot and the
+    slots of no row are as they were."""
+    from deepspeed_tpu.ops import dispatch
+    wide = load_toy("flash", **WIDE)
+    dispatch.reset()
+    tokens = tokens_of(32, 70 + 12)
+    got, pools = paged_logits(*wide[:2], tokens, 70)
+    assert dispatch.selected().get("kda_decode=kda_kernel") == 3
+    assert "kernel/kda_decode_update=interpret" in dispatch.selected()
+    want = reference_logits(wide, tokens)[69:]
+    assert np.abs(got - want).max() <= LOGIT_TOL, np.abs(got - want).max()
+    for st in pools["state"]:
+        for slot in (0, 1, 3):
+            assert float(jnp.abs(st[0, slot] - 3.0).max()) == 0
 
 
 def test_a_bf16_state_fails_the_tolerance(toy, monkeypatch):

@@ -419,6 +419,83 @@ def test_paged_matches_dense_decode_kernel(tpu):
     assert err < 1e-2, err
 
 
+@tpu_tier
+@pytest.mark.parametrize("live", [128, 8])
+def test_kda_decode_update_compiles_and_matches(tpu, live):
+    """The KDA decode kernel compiled at ``solaropen2_serve_decode``'s widths
+    (128 rows, 64 heads of a 128 x 128 float32 state, 129 slots), every row
+    live and at 8 live rows of 128, against the plain-XLA form on the same
+    chip: the live rows' ``o`` and every pool row past the dummy (the twin
+    also steps the dummy) to 1e-5 of their largest value, the dummy and the
+    slots of no row bit-identical to what they were."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.transformer import _kda_slot_update
+    from deepspeed_tpu.ops.pallas.kda_decode_update import kda_decode_update
+    from tests.unit.ops.test_kda_decode_update import draw_step
+
+    r = np.random.default_rng(live)
+    B, H, dk, dv, n_slots = 128, 64, 128, 128, 129
+    step = draw_step(r, B, H, dk, dv)
+    slots = np.zeros(B, np.int32)
+    slots[r.choice(B, live, replace=False)] = \
+        r.permutation(np.arange(1, n_slots))[:live]
+    pool = jax.random.normal(jax.random.key(live), (n_slots, H, dk, dv),
+                             jnp.float32)
+    before = np.asarray(pool)
+    want_o, want = jax.jit(_kda_slot_update, static_argnums=(8,))(
+        pool, *step, jnp.asarray(slots), 0, n_slots)
+    o, new = jax.jit(lambda S, *a: kda_decode_update(S, *a, interpret=False))(
+        pool, *step, jnp.asarray(slots), 0)
+    o, new, want_o, want = (np.asarray(a) for a in (o, new, want_o, want))
+    on = slots != 0
+    assert np.abs(o[on] - want_o[on]).max() <= 1e-5 * np.abs(want_o[on]).max()
+    assert np.abs(new[1:] - want[1:]).max() <= 1e-5 * np.abs(want).max()
+    idle = np.ones(n_slots, bool)
+    idle[slots[on]] = False
+    np.testing.assert_array_equal(new[idle], before[idle])
+    assert not o[~on].any()
+
+
+@tpu_tier
+def test_solar_toy_logits_through_the_compiled_kda_kernel(tpu):
+    """PERF.md section 7(a): the served check of ``solaropen2_serve_decode``
+    sees only a wrong or lost state, so the kernel owes the unit tests'
+    logits on the chip. The Solar toy at a width the kernel tiles (two KDA
+    heads of 128 x 128; GQA heads of 64, which the paged and flash kernels
+    take), float32 weights and matmuls in full float32: a prompt prefilled
+    and 12 tokens decoded through the COMPILED kernel, logits against the
+    plain reference's full forward. The same run on the plain-XLA form
+    bounds what the chip's own float32 arithmetic leaves: the kernel may
+    not be further from the reference than twice that, and both lie far
+    under the 3e-3 a bf16 state moves them by."""
+    import jax
+
+    from deepspeed_tpu.ops import dispatch
+    from tests.unit import test_solar_open2 as solar
+
+    tokens = solar.tokens_of(32, 70 + 12)
+    err = {}
+    with jax.default_matmul_precision("highest"):
+        # the prompt through the plain-XLA prefill in both runs: the decode
+        # step is what differs
+        toy = solar.load_toy("xla", head_size=64, **solar.WIDE)
+        want = solar.reference_logits(toy, tokens)[69:]
+        for backend, form in (("auto", "kda_kernel"), ("xla", "slot_update")):
+            decoder = solar.load_toy(backend, head_size=64, **solar.WIDE)[0]
+            dispatch.reset()
+            got, _ = solar.paged_logits(*toy[:2], tokens, 70,
+                                        decode_model=decoder)
+            assert dispatch.selected().get(f"kda_decode={form}") == 3
+            assert ("kernel/kda_decode_update=compiled" in dispatch.selected()) \
+                == (backend == "auto")
+            err[form] = float(np.abs(got - want).max())
+    print("logit error on the chip:", err)
+    assert err["slot_update"] <= 2e-4, err
+    assert err["kda_kernel"] <= max(2 * err["slot_update"], 2e-5), err
+
+
 # --------------------------------------------------------------------- #
 # Fused logits-free cross-entropy: numerics run in the DEFAULT CPU tier
 # (interpret mode); the class is deliberately NOT tpu-marked.
