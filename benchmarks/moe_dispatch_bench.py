@@ -1,6 +1,7 @@
 """One OLMoE-sized MoE layer stack on a TPU, by dispatch form and rows.
 
     python benchmarks/moe_dispatch_bench.py [--rows 1 16 64 512 1024 1536 2048]
+    python benchmarks/moe_dispatch_bench.py --preset sdar 30b-a3b-ep8 --rows 64 256 1024
 
 Times ``MoECausalLM._nodrop_mlp`` scanned over the 8 layers of the ``olmoe``
 ``1b-7b-8l`` preset (64 experts of 2,048 x 1,024, top-8; 805 MB of expert
@@ -11,7 +12,11 @@ and ``dense`` (every expert over every row). The time is the device's: the
 median duration of the program's executions in a profiler trace, over the
 layers. The numbers behind ``moe_lm._SORTED_DISPATCH_MIN_ROWS`` (PERF.md
 section 6, PR 26). TPU only: a time from another backend says nothing about
-the threshold, so the script refuses to print one.
+the threshold, so the script refuses to print one. ``--preset FAMILY SIZE``
+times another preset's layer in the same way at 8 layers of depth (PR 33:
+``sdar 30b-a3b-ep8``, 16 held experts of 2,048 x 768 of a router's 128,
+top-8, so one choice in eight is held: the dense form does 16 times the
+products the routing needs, the sorted form a sixteenth of the rows).
 """
 
 import argparse
@@ -39,16 +44,18 @@ def main():
     ap.add_argument("--rows", type=int, nargs="+",
                     default=[1, 16, 64, 512, 1024, 1536, 2048])
     ap.add_argument("--reps", type=int, default=8)
+    ap.add_argument("--preset", nargs=2, default=["olmoe", "1b-7b-8l"],
+                    metavar=("FAMILY", "SIZE"))
     args = ap.parse_args()
     platform = jax.devices()[0].platform
     if platform != "tpu":
         sys.exit(f"moe_dispatch_bench: the default device is {platform!r}, "
                  "not a TPU: no time is taken")
-    model = get_model("olmoe", "1b-7b-8l", param_dtype=jnp.bfloat16)
+    model = get_model(*args.preset, n_layer=8, param_dtype=jnp.bfloat16)
     cfg, moe = model.config, model.moe
     mlp = jax.jit(lambda k: model.init_params(k)["layers"]["mlp"])(jax.random.key(0))
     jax.block_until_ready(mlp)
-    layer_bytes = 3 * moe.num_experts * cfg.d_model * moe.expert_d_ff * 2
+    layer_bytes = 3 * moe.num_experts * cfg.d_model * model.expert_ff * 2
     print(f"device {jax.devices()[0].device_kind}; a layer's experts "
           f"{layer_bytes / 1e6:.0f} MB", flush=True)
 
@@ -59,7 +66,7 @@ def main():
         for form, max_rows in FORMS.items():
             def stack(mlp, x):
                 def body(h, lp):
-                    out, _, counts = model._nodrop_mlp(lp, h)
+                    out, _, counts, _ = model._nodrop_mlp(lp, h)
                     return h + out, counts
                 return jax.lax.scan(body, x, mlp)
             # the name is what the trace files the program's executions under

@@ -10,7 +10,13 @@ every row ~330 tokens deep), ``opt_mixed`` (the same table with 3 rows
 decoding and 37 idle rows on the dummy block) and ``olmoe_decode`` (64 rows
 of 16 heads x 128, 32 table entries, ~510 tokens a row); ``narrow_decode``
 (``opt_decode`` with 12 heads x 64: a block is 0.19 MB a pool, not 0.5, so
-an iteration's fixed cost weighs more). The time is the
+an iteration's fixed cost weighs more); ``sdar_block`` (PR 33: a pass of
+generation by blocks as ``_paged_block_attention`` hands it over, 64 rows
+of 4 positions x 32 heads = 128 QUERY ROWS over 4 kv heads x 128 = 512
+lanes, 8 table entries, 130-640 tokens a row: the block-diagonal product
+does 4 times the arithmetic its scores need over 32 times the query rows
+of a decode step, so here the arithmetic and not the copy sets the time).
+The time is the
 device's: the kernel's own events in a profiler trace, a call. ``--groups``
 times the kernel at those blocks a loop iteration (``_group_blocks`` is what
 the program takes); ``--parent`` names another version of the kernel's
@@ -39,24 +45,32 @@ import trace_reduce
 
 BS = 128
 LAYERS = 8          # calls a program, each with its own query
-STATES = {          # rows, heads (= kv heads), head size, table width, pool
+STATES = {          # rows, heads, head size, table width, pool[, kv heads]
     "opt_decode": (40, 32, 64, 16, 224),
     "opt_mixed": (40, 32, 64, 16, 224),
     "olmoe_decode": (64, 16, 128, 32, 528),
     "narrow_decode": (40, 12, 64, 16, 224),     # gpt2:125m's 768-lane row
+    "sdar_block": (64, 128, 128, 8, 328, 4),    # 4 positions x 32 heads, GQA
 }
+
+
+def shape_of(name):
+    """(rows, query heads, head size, table width, pool blocks, kv heads)."""
+    B, H, Hd, width, blocks, *kv = STATES[name]
+    return B, H, Hd, width, blocks, (kv[0] if kv else H)
 
 
 def draw_state(name, seed):
     """Block tables and positions of ``name``: live blocks drawn without
     replacement from the pool, the dead tail zero (the dummy block)."""
-    B, H, Hd, width, blocks = STATES[name]
+    B, H, Hd, width, blocks, _ = shape_of(name)
     r = np.random.default_rng(seed)
     if name == "opt_mixed":
         pos = np.zeros(B, np.int32)
         pos[r.choice(B, 3, replace=False)] = r.integers(300, 700, 3)
     else:
-        pos = r.integers(128, 900 if name == "olmoe_decode" else 540, B)
+        pos = r.integers(128, {"olmoe_decode": 900, "sdar_block": 640}
+                         .get(name, 540), B)
     live = pos // BS + 1
     ids = iter(r.permutation(np.arange(1, blocks)))
     bt = np.zeros((B, width), np.int32)
@@ -67,15 +81,17 @@ def draw_state(name, seed):
 
 
 def reference(q, kp, vp, bt, pos):
+    """Query head h reads kv head h // (H / KV), KV off the pool's row."""
     B, H, Hd = q.shape
-    k = kp[bt].reshape(B, -1, H, Hd).astype(jnp.float32)
-    v = vp[bt].reshape(B, -1, H, Hd).astype(jnp.float32)
-    s = jnp.einsum("bhd,bshd->bhs", q.astype(jnp.float32) * Hd**-0.5, k,
-                   precision="highest")
-    kpos = jnp.arange(k.shape[1])[None, None, :]
-    s = jnp.where(kpos <= pos[:, None, None], s, -1e30)
-    return jnp.einsum("bhs,bshd->bhd", jax.nn.softmax(s, axis=-1), v,
-                      precision="highest")
+    KV = kp.shape[2] // Hd
+    k = kp[bt].reshape(B, -1, KV, Hd).astype(jnp.float32)
+    v = vp[bt].reshape(B, -1, KV, Hd).astype(jnp.float32)
+    q5 = (q.astype(jnp.float32) * Hd**-0.5).reshape(B, KV, H // KV, Hd)
+    s = jnp.einsum("bcgd,bscd->bcgs", q5, k, precision="highest")
+    kpos = jnp.arange(k.shape[1])[None, None, None, :]
+    s = jnp.where(kpos <= pos[:, None, None, None], s, -1e30)
+    return jnp.einsum("bcgs,bscd->bcgd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest").reshape(B, H, Hd)
 
 
 def load_module(path):
@@ -107,13 +123,13 @@ def main():
 
     runs = {}
     for name in args.states:
-        B, H, Hd, width, blocks = STATES[name]
+        B, H, Hd, width, blocks, KV = shape_of(name)
         bt, pos, live = draw_state(name, args.seed)
         key = jax.random.key(args.seed % (1 << 31))
         kq, kk, kv = jax.random.split(key, 3)
         q = jax.random.normal(kq, (LAYERS, B, H, Hd), jnp.bfloat16)
-        kp = jax.random.normal(kk, (blocks, BS, H * Hd), jnp.bfloat16)
-        vp = jax.random.normal(kv, (blocks, BS, H * Hd), jnp.bfloat16)
+        kp = jax.random.normal(kk, (blocks, BS, KV * Hd), jnp.bfloat16)
+        vp = jax.random.normal(kv, (blocks, BS, KV * Hd), jnp.bfloat16)
         bt, pos = jnp.asarray(bt), jnp.asarray(pos)
         want = jax.jit(reference)(q[0], kp, vp, bt, pos)
         for label, mod, g in variants:
@@ -149,7 +165,7 @@ def main():
         sys.exit(f"{len(execs)} executions in the trace, {len(runs)} x "
                  f"{args.reps} were run: {sorted({p[0] for p in execs})}")
     for i, ((name, label), (_, _, live, err)) in enumerate(runs.items()):
-        B, H, Hd, width, _ = STATES[name]
+        B, H, Hd, width, _, KV = shape_of(name)
         took, calls = 0.0, 0
         for _, start, dur in execs[i * args.reps:(i + 1) * args.reps]:
             inside = [op for op in dev["ops"] if start <= op[1] < start + dur]
@@ -157,7 +173,7 @@ def main():
             took, calls = took + t, calls + n
         events, calls = calls, args.reps * LAYERS
         ms = took / calls * 1e3
-        block_bytes = 2 * BS * H * Hd * 2
+        block_bytes = 2 * BS * KV * Hd * 2
         print(json.dumps({
             "state": name, "kernel": label, "device_ms_per_call": round(ms, 4),
             "trace_events_per_call": events / calls,

@@ -484,6 +484,66 @@ def state_leg(name, dry_run, n_dev):
     return out
 
 
+def blockgen_leg(name, dry_run, n_dev):
+    """A model that generates by diffusion over blocks: the ``sdar`` toy at
+    a head size the paged and flash kernels take (64), six requests over
+    four rows through ``generate_batch``, so rows sit at different passes of
+    different blocks in one fused step. The block step must have taken the
+    paged kernel (a row's 4 positions as 8 query rows a kv head) and the
+    prefill the flash kernel's staircase, and the tokens are those of the
+    same weights on the plain-XLA forms."""
+    import jax
+    import numpy as np
+
+    import deepspeed_tpu
+    from deepspeed_tpu.models.presets import get_model
+    from deepspeed_tpu.ops import dispatch
+
+    say(f"{name}: sdar toy (GQA heads of 64, 4 of 8 experts held, blocks of "
+        "4) fp32 block_size 128 max_running 4")
+    fresh_leg()
+
+    def toy(backend):
+        # matrices at 0.3 and the embedding at 1.0: at the default 0.02 a
+        # toy of d 64 answers the same few tokens whatever it is asked
+        return get_model("sdar", "tiny", head_size=64, init_std=0.3,
+                         embed_init_std=1.0, attention_backend=backend)
+
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 512, size=n) for n in (5, 41, 130, 70, 9, 203)]
+    serving = {"block_size": 128, "max_running": 4}
+    n_new = sizes(dry_run)["max_tokens"]
+    t0 = time.perf_counter()
+    with jax.default_matmul_precision("highest"):
+        engine = deepspeed_tpu.init_inference(
+            toy("flash" if dry_run else "auto"), dtype="fp32", serving=serving)
+        served = engine.generate_batch(prompts, max_new_tokens=n_new)
+        forms = dispatch.selected()
+        ref = deepspeed_tpu.init_inference(toy("xla"), params=engine.params,
+                                           dtype="fp32", serving=serving)
+        want = ref.generate_batch(prompts, max_new_tokens=n_new)
+    assert dry_run or not any(k.endswith("=interpret") for k in forms), forms
+    if n_dev == 1:
+        how = "interpret" if dry_run else "compiled"
+        for need in ("paged_block=paged_kernel", "paged_prefill=flash",
+                     f"kernel/paged_decode_attention={how}",
+                     f"kernel/flash_attention={how}"):
+            assert need in forms, (need, forms)
+    assert "paged_block=gather_einsum" in dispatch.selected()
+    same = sum(np.array_equal(a, b) for a, b in zip(served, want))
+    assert same == len(prompts), (
+        f"{name}: {len(prompts) - same} of {len(prompts)} completions differ "
+        "from the plain-XLA forms'")
+    out = dict(ok=True, forms=sorted(forms), requests=len(prompts),
+               tokens=len(prompts) * n_new, same_as_xla=f"{same}/{len(prompts)}",
+               wall_s=round(time.perf_counter() - t0, 2))
+    say(f"{name}: ok, {out['requests']} requests, tokens as the plain-XLA "
+        f"forms' in {out['same_as_xla']}, forms {sorted(forms)}")
+    del engine, ref
+    gc.collect()
+    return out
+
+
 # ----------------------------------------------------------------------- #
 
 
@@ -539,6 +599,7 @@ def main(argv=None):
     legs["train"] = train_leg("train", dry_run, n, 1, {"dp": -1})
     legs["serve"] = serve_leg("serve", dry_run, n, 0)
     legs["serve_state"] = state_leg("serve_state", dry_run, n)
+    legs["serve_blockgen"] = blockgen_leg("serve_blockgen", dry_run, n)
     if n >= 4:
         legs["train_zero3_fsdp4"] = train_leg("train_zero3_fsdp4", dry_run, 4,
                                               3, {"fsdp": 4})

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import deepspeed_tpu.comm as dist
+from deepspeed_tpu.inference import blockgen
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 from deepspeed_tpu.monitor.trace import span
 from deepspeed_tpu.utils.fault_injection import step_fault as _step_fault
@@ -733,6 +734,12 @@ class InferenceEngine:
         if input_ids.ndim == 1:
             input_ids = input_ids[None, :]
         self._reject_encoders("generate()")
+        if getattr(getattr(self.module, "config", None), "generation",
+                   None) is not None:
+            raise ValueError(
+                "generate() decodes a token a step; this model generates by "
+                "diffusion over blocks (config.generation): use "
+                "generate_batch() or the serving engine")
         max_new = max_new_tokens if max_new_tokens is not None else self._config.max_out_tokens
         max_len = input_ids.shape[1] + max_new
         cfg = getattr(self.module, "config", None)
@@ -1168,6 +1175,24 @@ class InferenceEngine:
                 return _pinned(mod.forward_paged_verify)(
                     p, t, pools, bt, slots, pos)
 
+            gen = getattr(mod.config, "generation", None)
+
+            def paged_block(p, t, pools, bt, pos, n_decide, commit,
+                            key=None, temperature=None, top_k=0):
+                # one pass of generation by blocks over all running rows,
+                # the decision included: ``t`` is the block feed ``(prev,
+                # idx, host)`` (row i's open block as the pass in flight
+                # left it on the device at row idx[i], or the host's), the
+                # first output the rows' blocks as this pass leaves them,
+                # which stay on the device for the next pass's feed
+                state = blockgen.feed(*t)
+                logits, pools, *aux = _pinned(mod.forward_paged_block)(
+                    p, blockgen.tokens_of(gen, state), pools, bt, pos)
+                draw = None if key is None else (
+                    lambda lg: self._draw(lg, temperature, top_k, key))
+                return (blockgen.unmask(gen, logits, state, n_decide, commit,
+                                        draw), pools, *aux)
+
             def paged_cow(pools, src, dst):
                 return _pin(copy_paged_block(pools, src, dst))
 
@@ -1227,6 +1252,9 @@ class InferenceEngine:
                 watched(paged_spill_gather),
                 watched(paged_fetch_scatter, (0,)),
                 watched(paged_sample, static=(3, 4)),
+                # last, and only for a model that generates by blocks
+                *([watched(paged_block, (2,), static=(9,))]
+                  if gen is not None else []),
             )
         return self._paged_jits
 
@@ -1415,6 +1443,18 @@ class InferenceEngine:
                 "but the model keeps a recurrent state: a verify window "
                 "rewinds to the last accepted position and a state cannot "
                 "be rewound (no snapshot is kept)")
+        # how the model generates: its config's record, read as the cache
+        # spec is (None: a token a step)
+        gen = getattr(cfg, "generation", None)
+        if gen is not None and not hasattr(self.module, "forward_paged_block"):
+            raise ValueError("the model generates by blocks (config."
+                             "generation) but has no forward_paged_block")
+        if gen is not None and str(srv.speculative.mode) != "off":
+            raise ValueError(
+                f"serving.speculative.mode={str(srv.speculative.mode)!r} "
+                "but the model generates by diffusion over blocks: a pass "
+                "already carries a block of positions a row, and a draft's "
+                "next-token window has no meaning under its mask")
         if stateful and self.mesh.shape.get("tp", 1) > 1:
             raise ValueError("serving.tp > 1 but the model keeps a recurrent "
                              "state: its state pools are not sharded")
@@ -1487,7 +1527,7 @@ class InferenceEngine:
                                             rid_base=self._serve_rid_base,
                                             spec_k=spec_k if spec_on else 0,
                                             spec_proposer=proposer,
-                                            policy=policy)
+                                            policy=policy, generation=gen)
         session = _ServeSession(
             self, sched, pools, self._ensure_paged_jits(),
             max_new=max_new, temperature=temperature, top_k=top_k,
@@ -1517,7 +1557,7 @@ class InferenceEngine:
 #: the dispatch sites of a serving step (the four action kinds' and the
 #: sub-dispatches), in the order of ``_ensure_paged_jits``' programs
 _DISPATCH_SITES = ("prefill", "decode", "prefill_chunk", "cow", "verify",
-                   "spill", "fetch", "sample")
+                   "spill", "fetch", "sample", "block")
 
 
 def _on_device(a):
@@ -1640,6 +1680,10 @@ class _ServeSession:
         # the newest sampled tokens at the decode width, on the device:
         # what a decode step's feed gathers from (the step in flight's, if any)
         self._tok_dev = None
+        # generation by blocks (the model's record; None: a token a step):
+        # the rows' open blocks as the newest pass left them, on the device
+        self._gen = sched.gen
+        self._blk_dev = None
 
     # ---- request front-end ---- #
 
@@ -1731,9 +1775,13 @@ class _ServeSession:
             if launched is not None:
                 self._advance(launched)
                 self._flight = launched
-                if launched.tok is None or not kind.ahead \
-                        or not self._run_ahead:
-                    self.land()      # nothing to wait for, or depth zero
+                if (launched.tok is None and self._gen is None) \
+                        or not kind.ahead or not self._run_ahead:
+                    # nothing to wait for, or depth zero. (A model that
+                    # generates by blocks samples nothing at ANY prefill:
+                    # its prefill stays in flight as a sampling one does,
+                    # the next pass is launched behind it and counts so.)
+                    self.land()
             if self._finished_seen < len(self.sched.finished):
                 # retirements the launch itself made (an admission's error)
                 with span("serve.commit"):
@@ -1743,8 +1791,11 @@ class _ServeSession:
     def _chunk_len(self, req) -> int:
         """Tokens the next prefill chunk of ``req`` computes."""
         remaining = req.prefill_target - req.pos
-        return min(self.chunk_tokens, remaining) \
-            if self.chunk_tokens else remaining
+        chunk = self.chunk_tokens
+        if chunk and self._gen is not None:
+            # pieces start and end on whole generation blocks
+            chunk = max(chunk // self._gen.block, 1) * self._gen.block
+        return min(chunk, remaining) if chunk else remaining
 
     def _flush_finished(self) -> None:
         fin = self.sched.finished
@@ -2108,7 +2159,9 @@ class _ServeSession:
                        else step.logits)
         with span("serve.commit"):
             for r, tokens in rows:
-                step.kind.record(self.sched, r, part, tokens)
+                out = step.kind.record(self.sched, r, part, tokens)
+                if out is not None:
+                    tokens = out     # what the row streams of what landed
                 if tokens and self.on_tokens is not None:
                     self.on_tokens(r, tokens)
             self._flush_finished()
@@ -2120,11 +2173,12 @@ class _ServeSession:
 
     # ---- what differs by kind: the hooks _ACTION_KINDS names ---- #
 
-    def _piece_inputs(self, req, prefix, start, n):
+    def _piece_inputs(self, req, prefix, start, n, bucket_of=None):
         """Tokens ``start .. start + n`` of ``prefix`` in their compile
-        bucket, the request's block table, and the pool slots they write."""
+        bucket (the bucket of ``bucket_of`` tokens where that is given), the
+        request's block table, and the pool slots they write."""
         engine = self.engine
-        Tb = engine._bucket(n, engine.module.config.max_seq)
+        Tb = engine._bucket(bucket_of or n, engine.module.config.max_seq)
         toks = np.zeros((1, Tb), np.int32)
         toks[0, :n] = prefix[start:start + n]
         table = np.asarray(req.blocks, np.int32)
@@ -2140,9 +2194,14 @@ class _ServeSession:
         return (np.int32(req.state_slot),) if self._stateful else ()
 
     def _prefill_inputs(self, reqs):
-        prefix = reqs[0].prefix()
-        toks, _, slots, last = self._piece_inputs(reqs[0], prefix, 0,
-                                                  prefix.size)
+        # generation by blocks prefills the prefix's whole generation
+        # blocks only, in the bucket of the whole prefix: a front-end that
+        # warms one prompt a bucket (``_bucket`` of its length) has warmed
+        # the program this one takes
+        whole = reqs[0].prefix()
+        prefix = whole[:reqs[0].prefill_target]
+        toks, _, slots, last = self._piece_inputs(
+            reqs[0], prefix, 0, prefix.size, bucket_of=whole.size)
         return (toks, slots, last, *self._state_of(reqs[0])), (0, prefix.size)
 
     def _chunk_inputs(self, reqs):
@@ -2196,6 +2255,60 @@ class _ServeSession:
         # has left tokens there by the first decode step
         return ((self._tok_dev, idx, toks), bt, pos, *state), None
 
+    def _block_inputs(self, reqs):
+        """A fused pass of generation by blocks: each row's table and
+        committed depth, its block feed (from the pass in flight on the
+        device where the row rode it, else the host's state), and the
+        scheduler's plan for it (commit or denoise, how many to decide),
+        which is also the step's ``part``, by rid."""
+        tel, sched, Bg = self.sched.telemetry, self.sched, self._gen.block
+        W = self.W
+        bt = np.zeros((W, self.n_max), np.int32)            # zeros → dummy
+        pos = np.zeros((W,), np.int32)
+        host = np.full((W, Bg), -1, np.int32)
+        idx = np.full((W,), -1, np.int32)
+        n_decide = np.zeros((W,), np.int32)
+        commit = np.ones((W,), bool)     # an idle row's block stays undecided
+        ahead = self._flight
+        src = {} if ahead is None or ahead.name != "block" else {
+            id(r): j for j, r in enumerate(ahead.reqs)}
+        plan = {}
+        for i, r in enumerate(reqs):
+            bt[i, :len(r.blocks)] = r.blocks
+            pos[i] = r.pos
+            plan[r.rid] = sched.plan_block(r)
+            commit[i], n_decide[i], _ = plan[r.rid]
+            j = src.get(id(r))
+            if j is None:
+                host[i] = sched.block_state(r)
+            else:
+                idx[i] = j
+        if tel is not None:
+            # a row's pass reads its committed tokens and its open block
+            tel.decode_live_kv_tokens.inc(int(pos.sum()) + Bg * len(reqs))
+            tel.decode_live_kv_blocks.inc(int(((pos + Bg - 1) // self.bs + 1).sum()))
+            tel.count_block(len(reqs), int(commit[:len(reqs)].sum()))
+        prev = self._blk_dev if self._blk_dev is not None \
+            else np.full((W, Bg), -1, np.int32)
+        draw = ()
+        if self.temperature > 0.0:
+            self.rng, key = jax.random.split(self.rng)
+            draw = (key, np.float32(self.temperature), self.top_k)
+        return ((prev, idx, host), bt, pos, n_decide, commit, *draw), plan
+
+    def _block_kept(self, state, reqs, part):
+        """Launch side of a block step: its program decided already (the
+        ``unmask`` ops); the rows' blocks stay on the device for the next
+        pass's feed while their copy to the host starts."""
+        self._blk_dev = state
+        state.copy_to_host_async()
+        return state
+
+    @staticmethod
+    def _block_states(got, reqs):
+        """Landing side: each row's block as the pass left it."""
+        return got[:len(reqs)].tolist()
+
     def _verify_inputs(self, reqs):
         # speculative multi-token step: the fused decode math over each
         # request's window (pending token + proposed candidates) at once
@@ -2226,7 +2339,9 @@ class _ServeSession:
         (argmax/categorical run on the device); the tokens stay there, at
         the decode width for the next step's feed, while their copy to the
         host starts."""
-        if part is not None and sum(part) < reqs[0].prefill_target:
+        if self._gen is not None \
+                or (part is not None and sum(part) < reqs[0].prefill_target):
+            # a model that generates by blocks samples nothing at a prefill
             return None
         with span("serve.sample"):
             key = temperature = None     # greedy draws nothing
@@ -2358,7 +2473,9 @@ class _ActionKind(NamedTuple):
     #: ``(sched, req, part)``: the positional half of the scheduler's
     #: ``record_*``, known at the launch (None: ``record`` does it all)
     advance: Optional[Callable] = None
-    #: ``(sched, req, part, tokens)``: the rest of it, at the landing
+    #: ``(sched, req, part, tokens)``: the rest of it, at the landing. A
+    #: kind whose landed output is not yet tokens (a block step lands the
+    #: rows' blocks) returns the tokens the row streams of it
     record: Optional[Callable] = None
 
 
@@ -2409,4 +2526,23 @@ _ACTION_KINDS: Dict[str, _ActionKind] = {
         advance=lambda sched, r, part: sched.advance_decode(r),
         record=lambda sched, r, part, t:
             sched.commit_token(r, t[0], fused=True)),
+    # generation by diffusion over blocks: a fused pass over every running
+    # row's open block, denoise and commit rows together (``part``: the
+    # scheduler's plan by rid). Ahead like decode: under the static rules
+    # the plan reads no token (``plans_ahead`` lands the data-dependent one)
+    "block": _ActionKind(
+        rows=lambda reqs: reqs,
+        says=lambda s, reqs: {
+            "rows": len(reqs),
+            "commits": sum(s.sched.plan_block(r)[0] for r in reqs)},
+        inputs=_ServeSession._block_inputs, fed=True, ahead=True,
+        sample=_ServeSession._block_kept, tokens=_ServeSession._block_states,
+        # the fused step's event, as a decode step's: what the recorder's
+        # request tracks and the latency anatomy read a row's step from
+        events=lambda reqs, part, out: (None, [
+            ("decode.tick", dict(rids=[r.rid for r in reqs], n=len(reqs),
+                                 commits=sum(part[r.rid][0] for r in reqs)))]),
+        advance=lambda sched, r, part: sched.advance_block(r, *part[r.rid]),
+        record=lambda sched, r, part, t:
+            sched.record_block(r, *part[r.rid], t)),
 }

@@ -21,6 +21,11 @@ The engine drives one *step* at a time: :meth:`next_action` returns one of
   empty window (single-token decode inside the same program), and a
   step where NO request found a match degrades to plain ``decode``.
 
+- ``("block", running)`` — in place of ``decode`` for a model that
+  generates by diffusion over blocks (a ``generation`` record on its
+  config, see below): one fused pass over every running request's open
+  block.
+
 Finished requests retire between steps (their blocks return to the pool)
 and queued requests take their slots, so a convoying long request never
 stalls the batch the way the static ``generate`` loop does.
@@ -93,6 +98,28 @@ len(generated) - 1 whenever the request is running (and past prefill).
 While prefilling, ``pos < prefill_target == len(prefix())`` counts the
 chunked/cache-hit progress.
 
+**Generation by blocks** (``generation``: the model config's
+``BlockGeneration`` record; None = one token a step). A step no longer
+yields one token a row. ``pos`` is the tokens whose FINAL k/v sit in the
+pools, a multiple of the generation block ``Bg``; a (re)admission prefills
+the whole generation blocks of ``prefix()`` and samples nothing; what is
+left of the prefix (``len % Bg`` tokens) is decided from the start in the
+request's first open block, ``[pos, pos + Bg)``. Each ``block`` step is,
+for a row, a DENOISE pass (``plan_block``: it decides ``n`` of the block's
+undecided positions; their k/v, written to the block's slots, is
+overwritten by the next pass) or, once the block is whole, a COMMIT pass
+(its k/v stays; ``pos`` moves on by ``Bg``). A pool block holds whole
+generation blocks, so a row's next pass needs the same one block ahead a
+decode step needs (``_ensure_decode_capacity`` as it is). A token is
+streamed (``generated``) once it and every token before it is decided;
+``max_new`` cuts inside a block. Under the two static rules the host
+knows every row's phase without reading a token (``blk_decided``,
+``blk_pass`` move at the launch), so the loop runs a pass ahead; under the
+confidence-threshold rule the count a pass decides is data and
+``plans_ahead`` says no. A preempted request re-prefills whole blocks of
+prompt + streamed tokens and re-enters its open block with what that
+block had decided (``blk_landed``).
+
 **A launched step** (the engine's loop runs one step ahead): a step's
 result has a positional half that is fixed before its token is known —
 ``pos`` moves on, the filled blocks register, a request that reaches
@@ -118,6 +145,7 @@ from typing import List, Optional, Tuple
 import numpy as np
 
 from deepspeed_tpu.inference.block_allocator import ROOT_KEY, BlockAllocator
+from deepspeed_tpu.inference.blockgen import open_block
 from deepspeed_tpu.utils.logging import logger
 
 QUEUED, RUNNING, FINISHED = "queued", "running", "finished"
@@ -341,6 +369,29 @@ class ServingTelemetry:
             "prefill pieces that started a state slot from zero: a "
             "request's first, and its first again after each recompute"
         ).inc()
+
+    def count_block(self, rows: int, commits: int) -> None:
+        """One fused block step (generation by diffusion over blocks) over
+        ``rows`` live rows, ``commits`` of them on their commit pass. Not
+        pre-created: a model that generates a token a step has none of the
+        ``serving/block_*`` counters."""
+        c = self.registry.counter
+        c("serving/block_passes",
+          "fused block steps: one pass over every running row's open block"
+          ).inc()
+        c("serving/block_row_passes",
+          "live rows summed over the block steps: the passes rows took"
+          ).inc(rows)
+        c("serving/block_commit_row_passes",
+          "of block_row_passes, the commit passes (a whole block's final "
+          "KV written, the row moved on, nothing decided)").inc(commits)
+
+    def count_block_decided(self, n: int) -> None:
+        self.registry.counter(
+            "serving/block_decided_tokens",
+            "positions the denoise passes decided (a prompt's remainder in "
+            "its first block is not one): over block_row_passes, the tokens "
+            "a row's pass yields").inc(n)
 
     def count_moe(self, counts) -> None:
         """One fused decode step of an MoE model. ``counts`` [L, E + 1], the
@@ -618,6 +669,14 @@ class Request:
     error: Optional[str] = None     # set when retired without completing
     # ---- speculative decoding state ----
     spec_tokens: Tuple[int, ...] = ()  # candidates for the pending verify
+    # ---- generation by blocks (a model with a generation record) ----
+    blk_start: int = -1             # absolute position of the open block
+    blk_decided: int = 0            # its decided positions, launched passes
+    # counted in (under the data-dependent rule: as of the last landing)
+    blk_pass: int = 0               # denoise passes launched on it
+    blk_landed: Optional[Tuple] = None  # (start, state [Bg] int32, passes):
+    # the open block as the newest landed denoise pass left it, what a
+    # re-admission re-enters it with
 
     def prefix(self) -> np.ndarray:
         """The token prefix a (re)admission must have cached before decode
@@ -651,16 +710,25 @@ class ContinuousBatchingScheduler:
                  telemetry: Optional[ServingTelemetry] = None,
                  prefix_caching: bool = False, chunk_tokens: int = 0,
                  events=None, rid_base: int = 0,
-                 spec_k: int = 0, spec_proposer=None, policy=None):
+                 spec_k: int = 0, spec_proposer=None, policy=None,
+                 generation=None):
         if max_running < 1:
             raise ValueError("max_running must be >= 1")
         if chunk_tokens < 0:
             raise ValueError("chunk_tokens must be >= 0 (0 = whole-prompt)")
         if spec_k < 0:
             raise ValueError("spec_k must be >= 0 (0 = speculation off)")
+        if generation is not None and (
+                spec_k or allocator.block_size % generation.block):
+            raise ValueError(
+                "generation by blocks takes no speculation (a pass already "
+                "carries a block), and a pool block holds whole generation "
+                "blocks")
         self.allocator = allocator
         self.max_running = max_running
         self.max_blocks_per_seq = max_blocks_per_seq
+        #: the model's BlockGeneration record; None: a token a step
+        self.gen = generation
         self.prefix_caching = prefix_caching and allocator.prefix_cache
         # chunk_tokens and spec_k are runtime-mutable by contract: the
         # adaptive controller (monitor/controller.py) lowers them under
@@ -1024,6 +1092,9 @@ class ContinuousBatchingScheduler:
             else:
                 return None
         prefix = req.prefix()
+        if self.gen is not None:
+            # whole generation blocks; the rest enters the open block
+            prefix = prefix[:prefix.size // self.gen.block * self.gen.block]
         target = int(prefix.size)
         bs = self.allocator.block_size
         need_total = self.allocator.blocks_for_tokens(target)
@@ -1077,7 +1148,7 @@ class ContinuousBatchingScheduler:
                 if resolved:
                     self.telemetry.prefix_cache_hits.inc()
             cached = len(resolved) * bs
-            if cached >= target:
+            if cached >= target and self.gen is None:
                 # full prefix cached: cap the hit at target-1 (the last
                 # token's logits must still be computed to sample the
                 # continuation), which restarts mid-block inside the last
@@ -1233,11 +1304,21 @@ class ContinuousBatchingScheduler:
                     "req.phase", rid=req.rid, t_ns=now_ns, phase="queue",
                     dur_ns=int(max(time.perf_counter() - req.t_arrival, 0.0)
                                * 1e9))
+        # generation by blocks samples nothing at a prefill, so a prefix
+        # that is cached whole (or shorter than a generation block) has
+        # none to run: straight into its first open block
+        no_prefill = self.gen is not None and cached >= target and not fetches
         if self.telemetry is not None:
-            self.telemetry.prefill_steps.inc()
+            if not no_prefill:
+                self.telemetry.prefill_steps.inc()
             if cached:
                 self.telemetry.prefix_cache_hit_tokens.inc(cached)
+        if no_prefill:
+            req.prefilling = False
+            self._open_block(req)
         self._tel_gauges()
+        if no_prefill:
+            return self._try_admit()
         if req.pos > 0 or self.chunk_tokens > 0:
             if self.telemetry is not None:
                 self.telemetry.prefill_chunks.inc()
@@ -1305,7 +1386,8 @@ class ContinuousBatchingScheduler:
         would have to preempt (a victim re-queues prompt + generated).
         Reads only; conservative about the last (an admission that takes
         the turn grows nothing)."""
-        if self.spec_k > 0:
+        if self.spec_k > 0 or (self.gen is not None
+                               and self.gen.data_dependent):
             return False
         if self._deadline_live and any(
                 any(req is r for r in rows) for req, _ in self._expired()):
@@ -1359,6 +1441,8 @@ class ContinuousBatchingScheduler:
             if self.telemetry is not None:
                 self.telemetry.decode_steps.inc()
             self._tel_gauges()       # capacity growth/evictions moved blocks
+            if self.gen is not None:
+                return ("block", decodable)
             return ("decode", decodable)
         if self.waiting:
             if all(r.retry_at_step > self.step_seq for r in self.waiting):
@@ -1558,10 +1642,10 @@ class ContinuousBatchingScheduler:
 
     def advance_prefill(self, req: Request) -> None:
         """A whole prefill of ``req.prefix()`` was launched."""
-        req.pos = len(req.prefix())
+        req.pos = req.prefill_target
         req.prefilling = False
         self._register_full_blocks(req)
-        self._retire_by_count(req)
+        self._prefilled(req)
 
     def advance_prefill_chunk(self, req: Request, n_tokens: int,
                               last: bool) -> None:
@@ -1580,7 +1664,7 @@ class ContinuousBatchingScheduler:
                 f"request {req.rid} sampled a token at pos {req.pos} before "
                 f"reaching its prefill target {req.prefill_target}")
         req.prefilling = False
-        self._retire_by_count(req)
+        self._prefilled(req)
 
     def advance_decode(self, req: Request) -> None:
         """A decode step over ``req`` was launched: ``last_token``'s k/v goes
@@ -1589,15 +1673,114 @@ class ContinuousBatchingScheduler:
         self._register_full_blocks(req)
         self._retire_by_count(req)
 
+    def _prefilled(self, req: Request) -> None:
+        """The last piece of ``req``'s prefill was launched: it samples the
+        request's next token, or (generation by blocks) none, and the
+        request enters its first open block."""
+        if self.gen is None:
+            self._retire_by_count(req)
+        else:
+            self._open_block(req)
+
+    # ---- generation by blocks ---- #
+
+    def block_state(self, req: Request) -> np.ndarray:
+        """``req``'s open block as the host knows it, [Bg] int32 (-1:
+        undecided): the prefix's tokens that lie inside it, and what the
+        newest landed pass over this same block had decided beside them."""
+        state = open_block(self.gen, req.prefix(), req.blk_start)
+        if req.blk_landed is not None and req.blk_landed[0] == req.blk_start:
+            state = np.where(state >= 0, state, req.blk_landed[1])
+        return state
+
+    def _open_block(self, req: Request) -> None:
+        """``req`` enters the block at ``pos``: fresh, or (a re-admission
+        whose recompute ends where its open block began) as it was."""
+        req.blk_start = req.pos
+        again = req.blk_landed is not None and req.blk_landed[0] == req.pos
+        req.blk_pass = req.blk_landed[2] if again else 0
+        req.blk_decided = int((self.block_state(req) >= 0).sum())
+
+    def plan_block(self, req: Request) -> Tuple[bool, int, int]:
+        """What the next block step is for ``req``: (commit, the positions
+        it decides at least, the index of the pass in its block)."""
+        left = self.gen.block - req.blk_decided
+        if left == 0:
+            return True, 0, req.blk_pass
+        return (False, min(self.gen.transfers(req.blk_pass), left),
+                req.blk_pass)
+
+    def advance_block(self, req: Request, commit: bool, n: int,
+                      i: int) -> None:
+        """A block step over ``req`` was launched, for it a commit pass
+        (the block's final k/v is in the pools: ``pos`` moves on and the
+        next block opens, all undecided) or denoise pass ``i`` deciding
+        ``n`` positions (the data-dependent rule: at least ``n``; the
+        count comes with the landing)."""
+        g = self.gen
+        if commit:
+            req.pos += g.block
+            req.blk_start, req.blk_decided, req.blk_pass = req.pos, 0, 0
+            self._register_full_blocks(req)
+            return
+        req.blk_pass = i + 1
+        if g.data_dependent:
+            return
+        req.blk_decided += n
+        done = req.blk_start + req.blk_decided - req.prompt.size
+        # the pass decides the request's max_new-th token: under the
+        # sequential rule by count; under a confidence order only once
+        # the block is whole (which positions a pass takes is data)
+        if done >= req.max_new and (g.rule == "sequential"
+                                    or req.blk_decided == g.block):
+            self._hand_on(req)
+
+    def record_block(self, req: Request, commit: bool, n: int, i: int,
+                     state) -> List[int]:
+        """``req``'s launched block step landed with ``state`` [Bg] (-1:
+        undecided), its block as the pass left it (a commit pass: the next
+        block, all undecided). Streams what is newly decided with every
+        token before it: returns those tokens."""
+        if commit:
+            return []
+        state = np.asarray(state, np.int32)
+        start = req.blk_start
+        req.blk_landed = (start, state, i + 1)
+        if self.gen.data_dependent:
+            now = int((state >= 0).sum())
+            n, req.blk_decided = now - req.blk_decided, now
+        if self.telemetry is not None:
+            self.telemetry.count_block_decided(n)
+        out: List[int] = []
+        at = req.prompt.size + len(req.generated) - start
+        while at < state.size and state[at] >= 0 \
+                and len(req.generated) < req.max_new:
+            tok = int(state[at])
+            req.generated.append(tok)
+            out.append(tok)
+            self.stats["emitted_tokens"] += 1
+            self._record_token_time(req)
+            at += 1
+            if req.eos is not None and tok == req.eos:
+                break
+        if out:
+            self._maybe_finish(req)
+        return out
+
     def _retire_by_count(self, req: Request) -> None:
         """The step just launched samples ``req``'s ``max_new``-th token:
         whatever that token is, the request needs no further step, so its
         row and its blocks are handed on now (later programs run after this
         one on the device) and :meth:`commit_token` finishes it."""
         if len(req.generated) + 1 >= req.max_new:
-            self.running.remove(req)
-            self._free_blocks(req)
-            self.retiring.append(req)
+            self._hand_on(req)
+
+    def _hand_on(self, req: Request) -> None:
+        """``req`` needs no step after the one just launched: its row and
+        blocks go now, and it waits in ``retiring`` for that step to land."""
+        self.running.remove(req)
+        self._free_blocks(req)
+        self.retiring.append(req)
 
     def commit_token(self, req: Request, token: int,
                      fused: bool = False) -> None:

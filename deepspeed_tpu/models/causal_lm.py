@@ -89,6 +89,10 @@ class CausalLM:
         return T.forward_paged_verify(self.config, params, tokens, pools,
                                       block_tables, slots, pos)
 
+    def forward_paged_block(self, params, tokens, pools, block_tables, pos):
+        return T.forward_paged_block(self.config, params, tokens, pools,
+                                     block_tables, pos)
+
     @property
     def num_parameters(self) -> int:
         cfg = self.config

@@ -512,6 +512,17 @@ class MoECausalLM:
                                       block_tables, pos, pad_bias, mlp_fn=mlp_fn,
                                       state_slots=state_slots)
 
+    def forward_paged_block(self, params, tokens, pools, block_tables, pos):
+        """(logits [W, Bg, vocab], new pools, counts [L, E + 1]) of one
+        pass of block generation: as ``forward_paged_decode``, with the
+        rows' Bg positions each a row of the experts' work."""
+        bs = pools["k"].shape[2]
+        slots = (block_tables[jnp.arange(pos.shape[0]), pos // bs] * bs
+                 + pos % bs)[:, None] + jnp.zeros_like(tokens)
+        mlp_fn = self._paged(pools, slots, counts=True)
+        return T.forward_paged_block(self.config, params, tokens, pools,
+                                     block_tables, pos, mlp_fn=mlp_fn)
+
     def loss(self, params, batch, rng=None):
         logits, aux = self.forward(params, batch["input_ids"], batch.get("attention_mask"),
                                    rng=rng, train=True)
@@ -534,9 +545,11 @@ class MoECausalLM:
         F = self.expert_ff
         embed = cfg.vocab_size * D + (cfg.max_seq * D if cfg.pos_embedding == "learned" else 0)
         attn = D * cfg.head_dim * (cfg.n_head + 2 * cfg.kv_heads) + cfg.n_head * cfg.head_dim * D
-        moe_mlp = D * E + E * (3 * D * F if self._gated else 2 * D * F + F + D)
+        moe_mlp = D * self.router_width \
+            + E * (3 * D * F if self._gated else 2 * D * F + F + D)
         if cfg.qk_norm:
-            attn += cfg.head_dim * (cfg.n_head + cfg.kv_heads)
+            attn += cfg.head_dim * (2 if cfg.qk_norm == "head"
+                                    else cfg.n_head + cfg.kv_heads)
         norms = (4 if cfg.norm == "layernorm" else 2) * D
         final_norm = (2 if cfg.norm == "layernorm" else 1) * D
         head = 0 if cfg.tie_embeddings else D * cfg.vocab_size

@@ -185,6 +185,59 @@ def solar_open2(size: str = "250b-4l-ep8", share: int = 0, **over):
         param_dtype=param_dtype)
 
 
+def sdar(size: str = "30b-a3b-ep8", share: int = 0, rule: str = "sequential",
+         steps: int = 4, threshold: float = 0.9, **over):
+    """SDAR-30B-A3B-Chat (``JetLM/SDAR-30B-A3B-Chat`` config.json,
+    ``model_type`` ``sdar_moe``): 48 pre-RMSNorm layers (eps 1e-6) of d
+    2,048, each GQA (32 query and 4 key/value heads of 128, q and k
+    RMS-normed head by head, rope theta 1,000,000 in the half-split pairing,
+    no bias) and an MoE of 128 gated-SiLU experts of width 768, 8 a token by
+    softmax probability, the 8 weights normalised, no shared expert; an
+    untied head over 151,936 rows. It GENERATES BY DIFFUSION OVER BLOCKS:
+    its config carries a ``BlockGeneration`` record (blocks of 4 positions,
+    bidirectional inside a block and causal between blocks; ``steps``
+    denoise passes and a commit pass a block), which the scheduler and the
+    serving session read. ``30b-a3b-ep8`` is ONE CHIP OF THE EIGHT of a
+    v5e-8 that share each layer, AT FULL DEPTH: the router keeps its 128
+    outputs, the 16 experts of ``share`` (0-7) are held here, attention,
+    router and norms are whole, the vocabulary is this chip's eighth, and
+    ``[MASK]`` is the last id of the slice (the published 151,669 lies
+    outside it). ``max_seq`` is what the deployment serves (it sizes the
+    block tables only; rope has no table). ``rule`` defaults to
+    ``sequential`` at 4 steps: the family's ``generate`` defaults to the
+    confidence-threshold rule (0.9), whose order the served check cannot
+    replay from the tokens alone; the device work of a pass is the same
+    but for the order (perfbench's ``sdar30b_serve_blockgen``; PERF.md
+    section 7). ``tiny`` keeps every kind of part at toy widths: 4 of 8
+    experts held, top-2."""
+    from deepspeed_tpu.models.moe_lm import MoECausalLM, MoEConfig
+    from deepspeed_tpu.models.transformer import BlockGeneration
+    dims, moe = {
+        "tiny": (dict(n_layer=2, n_head=4, n_kv_head=2, head_size=32,
+                      d_model=64, d_ff=32, vocab_size=512, max_seq=1024),
+                 dict(num_experts=4, router_experts=8, k=2, expert_d_ff=32)),
+        "30b-a3b-ep8": (dict(n_layer=48, n_head=32, n_kv_head=4,
+                             head_size=128, d_model=2048, d_ff=768,
+                             vocab_size=18992, max_seq=1024),
+                        dict(num_experts=16, router_experts=128, k=8,
+                             expert_d_ff=768)),
+    }[size]
+    param_dtype = over.pop("param_dtype", jnp.float32)
+    moe = {**moe, **over.pop("moe", {})}
+    dims = {**dims, **over}
+    cfg = TransformerConfig(
+        pos_embedding="rope", rope_theta=1e6, norm="rmsnorm", norm_eps=1e-6,
+        activation="swiglu", tie_embeddings=False, attn_bias=False,
+        qk_norm="head", generation=BlockGeneration(
+            block=4, steps=steps, rule=rule, threshold=threshold,
+            mask_id=dims["vocab_size"] - 1), **dims)
+    return MoECausalLM(cfg, MoEConfig(
+        dispatch="nodrop", expert_activation="swiglu", scoring="softmax",
+        norm_topk_prob=True, aux_loss_coef=0.0,
+        expert_offset=share * moe["num_experts"], **moe),
+        param_dtype=param_dtype)
+
+
 MODEL_PRESETS: Dict[str, Callable] = {
     "gpt2": gpt2,
     "llama": llama,
@@ -193,6 +246,7 @@ MODEL_PRESETS: Dict[str, Callable] = {
     "gpt_neox": gpt_neox,
     "olmoe": olmoe,
     "solar_open2": solar_open2,
+    "sdar": sdar,
 }
 
 

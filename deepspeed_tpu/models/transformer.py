@@ -32,6 +32,54 @@ from deepspeed_tpu.ops import dispatch
 ATTENTION, LINEAR_ATTENTION = "attention", "linear_attention"
 
 
+#: the unmasking rules of a block-diffusion model's generation
+UNMASK_RULES = ("sequential", "low_confidence_static", "low_confidence_dynamic")
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockGeneration:
+    """How a model generates where it is not one token a step: by diffusion
+    over blocks (SDAR's ``block_diffusion_generate``). The sequence is cut
+    into blocks of ``block`` positions by absolute position; attention is
+    bidirectional inside a block and causal between blocks; a block is
+    generated by denoise passes over its ``block`` positions (undecided
+    ones fed as ``mask_id``), each deciding some of them AT THEIR OWN
+    positions by ``rule``, then committed by one more pass that keeps its
+    KV. Pass ``i`` of a block decides ``transfers(i)`` of its masked
+    positions (fewer if fewer are left): ``sequential`` the leftmost,
+    ``low_confidence_static`` those of largest confidence (the largest
+    softmax probability), ``low_confidence_dynamic`` every one whose
+    confidence passes ``threshold``, or the static rule's if fewer pass.
+    The scheduler and the serving session read this record as they read
+    ``cache_spec``: no serving option names a way to generate."""
+    block: int = 4
+    steps: int = 4
+    rule: str = "low_confidence_dynamic"
+    threshold: float = 0.9
+    mask_id: int = 0
+
+    def __post_init__(self):
+        if self.rule not in UNMASK_RULES:
+            raise ValueError(f"BlockGeneration.rule={self.rule!r} (expected "
+                             + "|".join(UNMASK_RULES) + ")")
+        if not 1 <= self.steps <= self.block:
+            raise ValueError("BlockGeneration needs 1 <= steps <= block")
+        if 8 % self.block:
+            raise ValueError("BlockGeneration.block is 1, 2, 4 or 8 (whole "
+                             "groups inside the attention kernels' 8-row tiles)")
+
+    def transfers(self, i: int) -> int:
+        """Positions pass ``i`` (from 0) of a block decides at least:
+        ``block // steps``, the remainder on the first passes."""
+        return self.block // self.steps + (i < self.block % self.steps)
+
+    @property
+    def data_dependent(self) -> bool:
+        """Whether the count a pass decides is data (the host must read a
+        pass's result before it can plan the next)."""
+        return self.rule == "low_confidence_dynamic"
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 50257
@@ -51,10 +99,11 @@ class TransformerConfig:
     tie_embeddings: bool = True
     embed_layernorm: bool = False        # BLOOM word_embeddings_layernorm
     attn_bias: bool = False              # qkv/out biases (gpt2/opt/bloom/neox)
-    # RMSNorm of the query and key PROJECTIONS, each over its whole width
-    # (all heads together, one learned scale of H*Hd / KV*Hd), before the
-    # split into heads and before rope (OLMoE, OLMo-2)
-    qk_norm: bool = False
+    # RMSNorm of the query and key PROJECTIONS before rope. True: each over
+    # its whole width (all heads together, one learned scale of H*Hd /
+    # KV*Hd: OLMoE, OLMo-2). "head": each head over its own head_dim, one
+    # learned scale of Hd shared by the heads (Qwen3, SDAR)
+    qk_norm: Any = False
     # a head's width where it is not d_model // n_head (64 heads of 128
     # under d_model 4,096): ``head_dim`` reads it
     head_size: Optional[int] = None
@@ -143,10 +192,21 @@ class TransformerConfig:
     # std of the token embedding's draw where it is not init_std: a cut of a
     # deep stack takes the residual stream its layers see in the stack
     embed_init_std: Optional[float] = None
+    # how the model generates: None = one token a step from the last
+    # position's logits (every autoregressive model); a BlockGeneration
+    # record = diffusion over blocks, which also makes every attention mask
+    # of the model block-causal (``causal_block``)
+    generation: Optional[BlockGeneration] = None
 
     @property
     def head_dim(self) -> int:
         return self.head_size or self.d_model // self.n_head
+
+    @property
+    def causal_block(self) -> int:
+        """The step of the causal mask's staircase: position i sees j iff
+        j // causal_block <= i // causal_block (1: the plain triangle)."""
+        return self.generation.block if self.generation is not None else 1
 
     @property
     def period(self) -> Tuple[str, ...]:
@@ -233,7 +293,10 @@ def _layer_group(cfg: TransformerConfig, kind: str, n: int, ks, dtype):
                 "bk": jnp.zeros((n, KV * Hd), dtype),
                 "bv": jnp.zeros((n, KV * Hd), dtype),
                 "bo": jnp.zeros((n, D), dtype)} if cfg.attn_bias else {}),
-            **({"q_norm": {"scale": jnp.ones((n, H * Hd), dtype)},
+            **({"q_norm": {"scale": jnp.ones((n, Hd), dtype)},
+                "k_norm": {"scale": jnp.ones((n, Hd), dtype)}}
+               if cfg.qk_norm == "head" else
+               {"q_norm": {"scale": jnp.ones((n, H * Hd), dtype)},
                 "k_norm": {"scale": jnp.ones((n, KV * Hd), dtype)}}
                if cfg.qk_norm else {}),
             **({"wg": dense(ks[7], (n, D, H * Hd))}
@@ -377,11 +440,14 @@ def _norm(cfg: TransformerConfig, x, p):
 
 def _qk_norm(cfg: TransformerConfig, q, k, lp):
     """``cfg.qk_norm``: RMSNorm of the flat query and key projections
-    [..., H*Hd] / [..., KV*Hd] over their whole width. The ONE place every
-    attention path (``attention`` and ``_qkv_project``: cached, paged
-    prefill, chunk, verify, decode) takes it from."""
+    [..., H*Hd] / [..., KV*Hd], over their whole width (True) or head by
+    head over ``head_dim`` ("head"). The ONE place every attention path
+    (``attention`` and ``_qkv_project``: cached, paged prefill, chunk,
+    verify, decode, block) takes it from."""
     if not cfg.qk_norm:
         return q, k
+    if cfg.qk_norm not in (True, "head"):
+        raise ValueError(f"qk_norm={cfg.qk_norm!r} (expected True|'head')")
     if cfg.manual_tp:
         raise NotImplementedError(
             "qk_norm reduces over all heads; manual-tp stages hold a slice")
@@ -392,6 +458,11 @@ def _qk_norm(cfg: TransformerConfig, q, k, lp):
         return (x32 * jax.lax.rsqrt(var + cfg.norm_eps)
                 * p["scale"].astype(jnp.float32)).astype(x.dtype)
 
+    if cfg.qk_norm == "head":
+        def heads(x, p):
+            return rms(x.reshape(*x.shape[:-1], -1, cfg.head_dim),
+                       p).reshape(x.shape)
+        return heads(q, lp["q_norm"]), heads(k, lp["k_norm"])
     return rms(q, lp["q_norm"]), rms(k, lp["k_norm"])
 
 
@@ -544,6 +615,14 @@ def attention(cfg: TransformerConfig, x, lp, positions, mask_bias):
 
     sp_mesh = _sp_mesh(cfg)
     out = None
+    # a block-causal model's staircase: the direct kernel and the einsum
+    # take it as a shape of the call; the sharded and streamed forms do not
+    stair = {} if cfg.causal_block == 1 else {"causal_block": cfg.causal_block}
+    if stair and (sp_mesh is not None or S > DENSE_STREAM_THRESHOLD
+                  or (not _use_flash(cfg) and _flash_mesh(cfg) is not None)):
+        raise NotImplementedError(
+            "a block-causal mask (cfg.generation) is built for the direct "
+            "flash kernel and the einsum form only")
     if sp_mesh is not None:
         # GQA kv stays UNREPEATED through the sp collectives (ring ppermute /
         # ulysses all-to-all move H/KV-times less data); the shard bodies
@@ -573,7 +652,7 @@ def attention(cfg: TransformerConfig, x, lp, positions, mask_bias):
                                       causal=cfg.causal, alibi_slopes=slopes,
                                       scale=cfg.attn_scale,
                                       block_q=cfg.attn_block_q,
-                                      block_k=cfg.attn_block_k)
+                                      block_k=cfg.attn_block_k, **stair)
                 form = "flash"
             else:
                 out = _flash_sharded(cfg, q, k, v, mask_bias, slopes, fmesh)
@@ -601,7 +680,7 @@ def attention(cfg: TransformerConfig, x, lp, positions, mask_bias):
         out = mha_attention(q, k, v,
                             mask_bias=None if mask_bias is None else mask_bias[:, None, None, :],
                             causal=cfg.causal, alibi_slopes=slopes,
-                            scale=cfg.attn_scale)
+                            scale=cfg.attn_scale, **stair)
     dispatch.record("attention", form,
                     f"B={B} S={S} H={H} KV={k.shape[2]} Hd={Hd}")
     out = checkpoint_name(out.reshape(B, S, H * Hd), "attn_out")
@@ -1095,6 +1174,9 @@ def _grouped_cache_einsum(cfg: TransformerConfig, q, ck, cv, positions,
     kpos = jnp.arange(S, dtype=jnp.int32)[None, None, None, None, :]  # [1,1,1,1,S]
     qpos = positions[:, None, None, :, None]                          # [B,1,1,T,1]
     valid = kpos <= qpos                                              # causal + cache bound
+    if cfg.causal_block > 1:
+        # block-causal: everything of the query's own block, and before it
+        valid = kpos // cfg.causal_block <= qpos // cfg.causal_block
     if cfg.pos_embedding == "alibi":
         slopes5 = _alibi_slopes(H).reshape(KV, G)
         scores = scores + slopes5[None, :, :, None, None] * (kpos - qpos).astype(jnp.float32)
@@ -1723,17 +1805,18 @@ def _paged_prefill_attention(cfg: TransformerConfig, x, lp, positions,
     vp = _pool_scatter(vp, v, slots)
 
     slopes = _alibi_slopes(H) if cfg.pos_embedding == "alibi" else None
+    stair = {} if cfg.causal_block == 1 else {"causal_block": cfg.causal_block}
     out = None
     if _use_flash(cfg):
         from deepspeed_tpu.ops.pallas import flash_attention
         out = flash_attention(q, k, v, causal=True, alibi_slopes=slopes,
                               scale=cfg.attn_scale, block_q=cfg.attn_block_q,
-                              block_k=cfg.attn_block_k)
+                              block_k=cfg.attn_block_k, **stair)
         form = "flash"
     if out is None:
         from deepspeed_tpu.ops.attention import mha_attention
         out = mha_attention(q, k, v, causal=True, alibi_slopes=slopes,
-                            scale=cfg.attn_scale)
+                            scale=cfg.attn_scale, **stair)
         form = "einsum"
     dispatch.record("paged_prefill", form, f"T={T}")
     out = out.reshape(B, T, H * Hd)
@@ -2003,6 +2086,86 @@ def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
             pad_bias),
         mlp_fn, lin_fn)
     logits = cached_head(cfg, params, x)[:, 0, :]
+    return (logits, pools) if aux is None else (logits, pools, aux)
+
+
+def _paged_block_attention(cfg: TransformerConfig, x, lp, positions, pos,
+                           kp, vp, block_tables):
+    """Attention of one block-generation pass over all running rows: x
+    [W, Bg, D], the ``Bg = cfg.causal_block`` positions ``pos .. pos + Bg -
+    1`` of each row's open block (``pos`` a multiple of Bg, so a block never
+    straddles a pool block). The block's k/v go to the row's pool slots
+    FIRST (a later pass over the same block, or its commit pass, overwrites
+    them: a denoise pass's KV is not kept by being written over), then all
+    Bg queries read positions ``<= pos + Bg - 1``: everything before the
+    block and the block itself, both directions. The Bg queries of a row
+    see the SAME keys, so they ride the paged kernel as Bg x (H / KV) query
+    rows a kv head, in ONE read of the row's KV. Returns (out [W, Bg, D],
+    kp, vp)."""
+    W, Bg, D = x.shape
+    H, KV, Hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
+    bs = kp.shape[1]
+
+    q, k, v = _qkv_project(cfg, x, lp, positions)
+
+    slots = (block_tables[jnp.arange(W), pos // bs] * bs + pos % bs)[:, None] \
+        + jnp.arange(Bg, dtype=jnp.int32)
+    kp = _pool_scatter(kp, k, slots.reshape(-1))
+    vp = _pool_scatter(vp, v, slots.reshape(-1))
+
+    o = None
+    if _use_flash(cfg):
+        from deepspeed_tpu.ops.pallas.paged_decode_attention import \
+            paged_decode_attention
+        P_ = H // KV
+        # the kernel's query row r reads kv head r // (rows / KV): a kv
+        # head's rows are its P_ heads at each of the Bg positions
+        qr = q.reshape(W, Bg, KV, P_, Hd).transpose(0, 2, 1, 3, 4)
+        o = paged_decode_attention(qr.reshape(W, KV * Bg * P_, Hd), kp, vp,
+                                   block_tables, pos + (Bg - 1),
+                                   scale=cfg.attn_scale)
+    if o is not None:
+        form = "paged_kernel"
+        out = o.reshape(W, KV, Bg, P_, Hd).transpose(0, 2, 1, 3, 4) \
+            .reshape(W, Bg, H * Hd)
+    else:
+        form = "gather_einsum"
+        # the shared masked-softmax core under the model's block mask: a
+        # query's own block ends at pos + Bg - 1
+        out = _grouped_cache_einsum(
+            cfg, q, _paged_gather(kp, block_tables, KV),
+            _paged_gather(vp, block_tables, KV), positions, None)
+    dispatch.record("paged_block", form,
+                    f"W={W} Bg={Bg} H={H} KV={KV} Hd={Hd} bs={bs}")
+    out = _attn_out(cfg, out, x, lp)
+    return out, kp, vp
+
+
+def forward_paged_block(cfg: TransformerConfig, params, tokens, pools,
+                        block_tables, pos, mlp_fn=None):
+    """One pass of block generation over ALL running rows (a model with a
+    ``generation`` record): tokens [W, Bg], each row's open block with its
+    undecided positions as the mask id; block_tables [W, max_blocks]; pos
+    [W] the rows' committed depths (multiples of Bg; an inactive row has a
+    zeroed table and writes the dummy block). Returns (logits [W, Bg,
+    vocab] at EVERY position of the block, each position's own; new pools)
+    and third what an ``mlp_fn`` that returns ``(out, aux)`` gave. Denoise
+    and commit passes are this same program: what differs is what the
+    caller does with the logits and whether ``pos`` moves on."""
+    _check_paged_config(cfg)
+    if cfg.generation is None or cfg.cache_spec["state"]:
+        raise NotImplementedError(
+            "a block pass needs a model that generates by blocks "
+            "(cfg.generation) and keeps no recurrent state")
+    if pools["k"].shape[2] % cfg.causal_block:
+        raise ValueError("a pool block must hold whole generation blocks")
+    x, positions = cached_embed(cfg, params, tokens, pos, pools["k"].dtype)
+    x, pools, aux = _scan_paged_layers(
+        cfg, params, pools, x,
+        lambda xn, lp, kp, vp, block0, slot0: _paged_block_attention(
+            cfg, xn, lp, positions, pos, kp, vp, block_tables + block0),
+        mlp_fn)
+    logits = cached_head(cfg, params, x)
     return (logits, pools) if aux is None else (logits, pools, aux)
 
 

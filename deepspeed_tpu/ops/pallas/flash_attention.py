@@ -52,14 +52,19 @@ _MASKED = -1e30  # large-negative for masked logits (exp2 underflows to 0)
 _LOG2E = 1.4426950408889634
 
 
-def _block_bias(qoff, koff, bq, bk, seq_len, causal, slope, mask_blk):
+def _block_bias(qoff, koff, bq, bk, seq_len, causal, slope, mask_blk,
+                stair=1):
     """Additive log2-domain bias for a (bq, bk) score block from GLOBAL
-    positions: alibi + causal/pad masking + user key mask."""
+    positions: alibi + causal/pad masking + user key mask. ``stair`` > 1:
+    the causal triangle is a staircase of that step (a query sees all of
+    its own group of ``stair`` positions)."""
     qpos = qoff + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     kpos = koff + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     bias = (slope * _LOG2E) * (kpos - qpos).astype(jnp.float32)  # slope==0 → no-op
     valid = kpos < seq_len
-    if causal:
+    if causal and stair > 1:
+        valid = valid & (qpos // stair >= kpos // stair)
+    elif causal:
         valid = valid & (qpos >= kpos)
     bias = jnp.where(valid, bias, _MASKED)
     return bias + mask_blk[None, :] * _LOG2E
@@ -88,10 +93,13 @@ def _dispatch(run, i, j, plain, causal, update, logits, tri_ref, bias):
             update(logits() + bias())
 
 
-def _make_tri(bq, bk):
-    """Precomputed (bq, bk) diagonal-block causal bias: 0 keep / -1e30 drop."""
+def _make_tri(bq, bk, stair=1):
+    """Precomputed (bq, bk) diagonal-block causal bias: 0 keep / -1e30 drop;
+    a staircase of step ``stair`` in place of the triangle where > 1."""
     r = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     c = jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    if stair > 1:
+        r, c = r // stair, c // stair
     return jnp.where(r >= c, 0.0, _MASKED).astype(jnp.float32)
 
 
@@ -127,7 +135,7 @@ def _parse_rest(rest, plain, has_layout):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, slope_ref, *rest,
-                scale, causal, seq_len, bq, bk, plain, has_layout):
+                scale, causal, seq_len, bq, bk, plain, has_layout, stair=1):
     tri_ref, layout_ref, (o_ref, lse_ref, m_scr, l_scr, acc_scr) = \
         _parse_rest(rest, plain, has_layout)
     # refs (leading dims squeezed): q/o (bq, Hd); k/v (bk, Hd); mask (bk,);
@@ -169,7 +177,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, slope_ref, *rest,
 
     _dispatch(run, i, j, plain, causal, update, logits, tri_ref,
               lambda: _block_bias(qoff, koff, bq, bk, seq_len, causal,
-                                  slope_ref[0, 0], mask_ref[0].astype(jnp.float32)))
+                                  slope_ref[0, 0], mask_ref[0].astype(jnp.float32),
+                                  stair))
 
     @pl.when(j == nk - 1)
     def _():
@@ -182,7 +191,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, slope_ref, *rest,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, slope_ref,
-               *rest, scale, causal, seq_len, bq, bk, plain, has_layout):
+               *rest, scale, causal, seq_len, bq, bk, plain, has_layout, stair=1):
     tri_ref, layout_ref, (dq_ref, dq_scr) = _parse_rest(rest, plain, has_layout)
     j = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -210,7 +219,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, slope_
 
     _dispatch(run, i, j, plain, causal, update, logits, tri_ref,
               lambda: _block_bias(qoff, koff, bq, bk, seq_len, causal,
-                                  slope_ref[0, 0], mask_ref[0].astype(jnp.float32)))
+                                  slope_ref[0, 0], mask_ref[0].astype(jnp.float32),
+                                  stair))
 
     @pl.when(j == nk - 1)
     def _():
@@ -218,7 +228,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, slope_
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, slope_ref,
-                *rest, scale, causal, seq_len, bq, bk, plain, has_layout):
+                *rest, scale, causal, seq_len, bq, bk, plain, has_layout, stair=1):
     tri_ref, layout_ref, (dk_ref, dv_ref, dk_scr, dv_scr) = \
         _parse_rest(rest, plain, has_layout)
     # grid (B, KV, nk, G, nq): q blocks innermost, then the G query heads of
@@ -256,7 +266,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, slope
 
     _dispatch(run, i, j, plain, causal, update, logits, tri_ref,
               lambda: _block_bias(qoff, koff, bq, bk, seq_len, causal,
-                                  slope_ref[0, 0], mask_ref[0].astype(jnp.float32)))
+                                  slope_ref[0, 0], mask_ref[0].astype(jnp.float32),
+                                  stair))
 
     @pl.when(jnp.logical_and(i == nq - 1, g == ng - 1))
     def _():
@@ -533,7 +544,8 @@ def _layout_spec():
 
 @functools.lru_cache(maxsize=32)
 def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret: bool,
-           has_layout: bool = False, plain: bool = False, kv_group: int = 1):
+           has_layout: bool = False, plain: bool = False, kv_group: int = 1,
+           stair: int = 1):
     """Build the custom-VJP flash function for one static configuration.
 
     Operates on padded [B, H, Sp, Hd] q / [B, KV, Sp, Hd] k,v
@@ -547,7 +559,7 @@ def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret
     maybe_tri = [_tri_spec(bq, bk)] if plain else []
     maybe_layout = [_layout_spec()] if has_layout else []
     statics = dict(scale=scale, causal=causal, seq_len=seq_len, bq=bq, bk=bk,
-                   plain=plain, has_layout=has_layout)
+                   plain=plain, has_layout=has_layout, stair=stair)
 
     def fwd_call(q, k, v, mask, slopes, *extra):
         B, H, Sp, Hd = q.shape
@@ -676,7 +688,8 @@ def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret
 def flash_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=None,
                     scale: Optional[float] = None, block_q: Optional[int] = None,
                     block_k: Optional[int] = None, block_layout=None,
-                    interpret: Optional[bool] = None, return_lse: bool = False):
+                    interpret: Optional[bool] = None, return_lse: bool = False,
+                    causal_block: int = 1):
     """Flash attention on [B, S, H, Hd] q/k/v (same contract as
     :func:`deepspeed_tpu.ops.attention.mha_attention`; mask_bias is the
     additive key-side [B, S] bias). Pads S up to the block size internally.
@@ -695,8 +708,22 @@ def flash_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=N
     logsumexp [B, H, S] (fully-masked rows carry +1e30); both outputs are
     differentiable — ring attention combines partial blocks through it.
     Uses the general kernel (no packed-heads fast path).
+
+    ``causal_block`` > 1 (with ``causal``): the causal triangle becomes a
+    staircase of that step. Position ``i`` sees position ``j`` iff
+    ``j // causal_block <= i // causal_block``: all of its own group of
+    ``causal_block`` positions, both directions, and every earlier one (a
+    block-diffusion model's prefill). The kernel blocks are whole groups
+    (multiples of 8: a step of 2, 4 or 8), so which blocks are skipped does
+    not change and the diagonal block's bias is the one thing that does,
+    in the forward and in both backward kernels alike.
     """
     B, S, H, Hd = q.shape
+    if causal_block > 1 and (not causal or 8 % causal_block
+                             or block_layout is not None):
+        raise ValueError(
+            f"causal_block={causal_block} needs causal=True, a step that "
+            "divides the kernel's 8-row tiles (2, 4 or 8) and no block_layout")
     KV = k.shape[2]
     if H % KV:
         raise ValueError(f"q heads {H} not a multiple of kv heads {KV}")
@@ -763,7 +790,7 @@ def flash_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=N
             and 128 % Hd == 0 and H % (128 // Hd) == 0):
         P128 = 128 // Hd
         fn = _build_packed(causal, scale, bq, bk, interpret, P128, Hd)
-        tri = _make_tri(bq, bk)
+        tri = _make_tri(bq, bk, causal_block)
         out = fn(q.reshape(B, S, H * Hd), k.reshape(B, S, H * Hd),
                  v.reshape(B, S, H * Hd), tri)
         return out.reshape(B, S, H, Hd)
@@ -787,7 +814,7 @@ def flash_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=N
 
     extra = ()
     if plain:
-        extra = (_make_tri(bq, bk),)
+        extra = (_make_tri(bq, bk, causal_block),)
     if block_layout is not None:
         nq, nk = Sp // bq, Sp // bk
         layout = jnp.asarray(block_layout, jnp.float32)
@@ -800,7 +827,7 @@ def flash_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=N
         extra = extra + (layout,)
 
     fn, fn_lse = _build(causal, scale, bq, bk, S, interpret, block_layout is not None,
-                        plain, kv_group)
+                        plain, kv_group, causal_block)
     if return_lse:
         out, lse = fn_lse(qt, kt, vt, mask, slopes, *extra)
         return (jnp.transpose(out[:, :, :S, :], (0, 2, 1, 3)),

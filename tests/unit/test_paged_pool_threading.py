@@ -294,3 +294,77 @@ def test_kda_kernel_compiles_for_v5e_at_real_widths(one_v5e, monkeypatch):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not aliased"
     assert mem.temp_size_in_bytes < pool_bytes / 64, mem.temp_size_in_bytes
+
+
+def test_block_step_keeps_the_pools_in_place_for_v5e(one_v5e, monkeypatch):
+    """The fused step of generation by blocks at ``sdar30b_serve_blockgen``'s
+    real widths (64 rows of 4 positions, 32 query and 4 key/value heads of
+    128, d 2,048, 16 held experts of 768 of a router's 128; two layers of
+    the 48), the decision included, compiled for a described v5e: Mosaic
+    takes the paged kernel at 128 QUERY ROWS a row over 512 lanes (one call
+    a layer, not one a position), the pools are aliased, the temporaries
+    stay under a quarter of them and no ``copy``, ``dynamic-slice`` or
+    ``dynamic-update-slice`` of a layer's pool size exists. So does the
+    block-causal prefill of 256 tokens through the flash kernel."""
+    import deepspeed_tpu.comm as dist
+    from deepspeed_tpu.inference import blockgen
+    from deepspeed_tpu.models.presets import get_model
+    from deepspeed_tpu.ops import dispatch
+    dist.set_mesh(None)
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    rows, num_blocks = 64, 160
+    model = get_model("sdar", "30b-a3b-ep8", n_layer=2)
+    cfg, gen = model.config, model.config.generation
+    params = jax.tree.map(lambda a: sds(a.shape, jnp.bfloat16),
+                          jax.eval_shape(model.init_params, jax.random.key(0)))
+    pools = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda: model.init_paged_cache(num_blocks, BS, dtype=jnp.bfloat16)))
+    n_max = -(-cfg.max_seq // BS)
+
+    def step(p, po, prev, idx, host, bt, pos, n_decide, commit):
+        state = blockgen.feed(prev, idx, host)
+        logits, po, aux = model.forward_paged_block(
+            p, blockgen.tokens_of(gen, state), po, bt, pos)
+        return blockgen.unmask(gen, logits, state, n_decide, commit), po, aux
+
+    blk = sds((rows, gen.block), I32)
+    dispatch.reset()
+    programs = {
+        "block": jax.jit(step, donate_argnums=(1,)).lower(
+            params, pools, blk, sds((rows,), I32), blk,
+            sds((rows, n_max), I32), sds((rows,), I32), sds((rows,), I32),
+            sds((rows,), jnp.bool_)).compile(),
+        "prefill": jax.jit(
+            lambda p, po, t, s, li: model.forward_paged_prefill(p, t, po, s, li),
+            donate_argnums=(1,)).lower(
+            params, pools, sds((1, 256), I32), sds((256,), I32),
+            sds((), I32)).compile()}
+    forms = dispatch.selected()
+    assert forms["paged_block=paged_kernel"] == 1
+    assert forms["paged_prefill=flash"] == 1
+    pool_bytes = sum(int(np.prod(a.shape)) * 2 for a in jax.tree.leaves(pools))
+    layer_pool = num_blocks * BS * cfg.kv_heads * cfg.head_dim
+    for name, compiled in programs.items():
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= pool_bytes, name
+        assert mem.temp_size_in_bytes < pool_bytes / 4, (
+            name, mem.temp_size_in_bytes, pool_bytes)
+        text = compiled.as_text()
+        # one kernel call in the layer scan's body
+        assert text.count('custom_call_target="tpu_custom_call"') == 1, name
+        moved = []
+        for m in re.finditer(
+                r"= \w+\[([\d,]+)\]\S* "
+                r"(copy|dynamic-slice|dynamic-update-slice)\(", text):
+            dims = [int(d) for d in m.group(1).split(",")]
+            # of a pool's own shape (the scan's slices of the 16 stacked
+            # experts, 25 M elements a matrix, are weights and fuse into
+            # their products)
+            if int(np.prod(dims)) >= layer_pool and \
+                    dims[-2:] == [BS, cfg.kv_heads * cfg.head_dim]:
+                moved.append(m.group(0))
+        assert not moved, (name, moved)

@@ -256,7 +256,8 @@ class TestGenerateBatch:
 # the executor's table of action kinds
 
 # what ContinuousBatchingScheduler.next_action can return
-ACTION_KINDS = ("wait", "prefill", "prefill_chunk", "verify", "decode")
+ACTION_KINDS = ("wait", "prefill", "prefill_chunk", "verify", "decode",
+                "block")
 
 
 @pytest.mark.parametrize("kind", ACTION_KINDS + ("defragment",))

@@ -496,6 +496,43 @@ def test_solar_toy_logits_through_the_compiled_kda_kernel(tpu):
     assert err["kda_kernel"] <= max(2 * err["slot_update"], 2e-5), err
 
 
+@tpu_tier
+def test_sdar_toy_logits_through_the_compiled_kernels(tpu):
+    """Generation by blocks through the COMPILED kernels: the SDAR toy at a
+    head size both kernels take (64; 2 kv heads x 64 = 128 lanes), float32
+    weights and matmuls in full float32, a 138-token prompt (remainder 2)
+    prefilled through ``flash_attention`` with the staircase of 4 and 14
+    tokens generated by denoise and commit passes whose 4 positions ride
+    ``paged_decode_attention`` as 8 query rows a kv head, every deciding
+    pass's logits against the plain reference's. The same replay on the
+    plain-XLA forms bounds what the chip's own float32 arithmetic leaves."""
+    import jax
+
+    from deepspeed_tpu.ops import dispatch
+    from tests.unit import test_sdar as sdar
+
+    err = {}
+    with jax.default_matmul_precision("highest"):
+        toy = sdar.load_toy(head_size=64, attention_backend="xla")
+        prompt = sdar.prompts_of([138], seed=33)[0]
+        rec = []
+        new = sdar.reference_tokens(toy, prompt, 14, record=rec)
+        for backend in ("auto", "xla"):
+            run = sdar.load_toy(head_size=64, attention_backend=backend)
+            dispatch.reset()
+            pairs = sdar.replay((run[0],) + toy[1:], prompt, rec, new,
+                                bs=128, n_blocks=4)
+            forms = dispatch.selected()
+            kernels = {"paged_block=paged_kernel", "paged_prefill=flash",
+                       "kernel/paged_decode_attention=compiled",
+                       "kernel/flash_attention=compiled"}
+            assert (kernels <= set(forms)) == (backend == "auto"), forms
+            err[backend] = sdar.worst(pairs)
+    print("logit error on the chip:", err)
+    assert err["xla"] <= 2e-4, err
+    assert err["auto"] <= max(2 * err["xla"], 1e-4), err
+
+
 # --------------------------------------------------------------------- #
 # Fused logits-free cross-entropy: numerics run in the DEFAULT CPU tier
 # (interpret mode); the class is deliberately NOT tpu-marked.
