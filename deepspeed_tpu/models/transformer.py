@@ -136,8 +136,8 @@ class TransformerConfig:
     dropout: float = 0.0
     # memory: activation checkpointing per layer. False/"none" = save all
     # activations; True/"full" = save only layer inputs (reference
-    # CheckpointFunction semantics); "dots" = save matmul outputs, recompute
-    # the cheap elementwise/attention parts (best MFU when it fits HBM);
+    # CheckpointFunction semantics); "dots" = save matmul outputs + the flash
+    # kernel's (o, lse), recompute the cheap elementwise parts (best MFU);
     # "offload_dots" = save matmul outputs to pinned host memory.
     remat: Any = True
     scan_layers: bool = True
@@ -1025,12 +1025,26 @@ def _sp_mesh(cfg: TransformerConfig):
 
 def _remat_policy(remat):
     """Map the config's remat setting to a jax.checkpoint policy (None =
-    full remat, the reference's save-only-inputs CheckpointFunction)."""
+    full remat, the reference's save-only-inputs CheckpointFunction).
+
+    ``"dots"`` keeps every matmul result of size tokens x width (the
+    projections, the MLP's products; not an einsum attention's batched
+    S x S scores) AND the flash kernel's named residuals: its output is the
+    attention's matmul result at ``[B, S, D]``, which the dots policy cannot
+    see inside a ``pallas_call``. Kept, they cost tokens x d_model x 2 B +
+    heads x tokens x 4 B a layer (a tenth more than the nine projection-sized
+    units ``dots`` keeps at ``d_ff = 4 d``) and the backward runs the ``dq`` /
+    ``dkv`` kernels on them instead of first re-running the forward kernel.
+    A block without a flash kernel has no such names and traces as before."""
     if remat is True or remat == "full":
         return None
+    from deepspeed_tpu.ops.pallas.flash_attention import \
+        RESIDUAL_NAMES as _FLASH_RESIDUALS
     pols = jax.checkpoint_policies
     if remat == "dots":
-        return pols.dots_with_no_batch_dims_saveable
+        return pols.save_from_both_policies(
+            pols.dots_with_no_batch_dims_saveable,
+            pols.save_only_these_names(*_FLASH_RESIDUALS))
     if remat == "selective":
         # save only the [tokens, D]-sized projections (cheap to store) plus
         # the flash kernel's (o, lse) residuals — so backward runs the flash
@@ -1038,7 +1052,7 @@ def _remat_policy(remat):
         # recompute the d_ff-sized up/gate activations in backward
         return pols.save_only_these_names(
             "q_proj", "k_proj", "v_proj", "attn_out", "wo_out", "ff_down",
-            "flash_o", "flash_lse")
+            *_FLASH_RESIDUALS)
     if remat == "offload_dots":
         return pols.offload_dot_with_no_batch_dims("device", "pinned_host")
     raise ValueError(f"unknown remat policy {remat!r} (expected True/'full', "

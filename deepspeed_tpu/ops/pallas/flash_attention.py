@@ -27,9 +27,13 @@ ride the MXU):
   instead of running per-element iota/compare/select.
 
 The kernel's forward outputs (o, lse) carry ``checkpoint_name`` tags
-("flash_o"/"flash_lse") so activation-checkpoint policies (e.g. the model
-zoo's ``remat="selective"``) can save the attention residuals and run the
-backward kernels without re-running the forward kernel.
+("flash_o"/"flash_lse") so activation-checkpoint policies can save the
+attention residuals and run the backward kernels without re-running the
+forward kernel: the model zoo's ``remat="dots"`` and ``remat="selective"``
+both name them (a policy that goes by primitive cannot see a matmul result
+inside a ``pallas_call``). Kept, they cost tokens x H x Hd x 2 B (bf16; twice
+that where Hd = 64 pads to 128 lanes in the general kernel's [B, H, S, Hd]
+layout) plus H x tokens x 4 B a layer.
 
 Supports causal masking, an additive key-side mask bias [B, S], and ALiBi
 slopes. Runs compiled on TPU, interpreted elsewhere (CPU unit tests).
@@ -50,6 +54,14 @@ from deepspeed_tpu.ops.dispatch import resolve_interpret
 
 _MASKED = -1e30  # large-negative for masked logits (exp2 underflows to 0)
 _LOG2E = 1.4426950408889634
+#: the ``checkpoint_name`` tags of the forward outputs (o, lse): what a remat
+#: policy lists to keep them
+RESIDUAL_NAMES = ("flash_o", "flash_lse")
+
+
+def _named(o, lse):
+    return (checkpoint_name(o, RESIDUAL_NAMES[0]),
+            checkpoint_name(lse, RESIDUAL_NAMES[1]))
 
 
 def _block_bias(qoff, koff, bq, bk, seq_len, causal, slope, mask_blk,
@@ -438,7 +450,7 @@ def _build_packed(causal: bool, scale: float, bq: int, bk: int, interpret: bool,
             ],
             interpret=interpret,
         )(q, k, v, tri)
-        return checkpoint_name(o, "flash_o"), checkpoint_name(lse, "flash_lse")
+        return _named(o, lse)
 
     @jax.custom_vjp
     def flash(q, k, v, tri):
@@ -585,7 +597,7 @@ def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret
         )(q, k, v, mask, slopes, *extra)
         # named so remat policies can save the attention residuals and skip
         # re-running the forward kernel inside the backward pass
-        return checkpoint_name(o, "flash_o"), checkpoint_name(lse, "flash_lse")
+        return _named(o, lse)
 
     @jax.custom_vjp
     def flash(q, k, v, mask, slopes, *extra):

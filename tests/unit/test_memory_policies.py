@@ -7,6 +7,8 @@ softmax-xent kernels (``csrc/transformer/softmax_kernels.cu``), ZeRO
 round-robin state partitioning (``deepspeed/runtime/zero/stage_1_and_2.py``).
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,6 +32,14 @@ def tiny(remat, loss_chunk=0, **over):
 def batch(B=2, S=64, vocab=256, seed=0):
     rng = np.random.default_rng(seed)
     return {"input_ids": jnp.asarray(rng.integers(0, vocab, size=(B, S)).astype(np.int32))}
+
+
+def peak(remat, b, **over):
+    """Temporaries of the compiled gradient program of ``tiny(remat)``."""
+    m = tiny(remat, **over)
+    p = m.init_params(jax.random.key(0))
+    c = jax.jit(jax.grad(lambda p: m.loss(p, b))).lower(p).compile()
+    return c.memory_analysis().temp_size_in_bytes
 
 
 class TestRematPolicies:
@@ -66,27 +76,145 @@ class TestRematPolicies:
         """Compiled-memory assertion: 'selective' must keep fewer live
         activation bytes than remat=False (save everything)."""
         b = batch(B=4, S=64)
-
-        def peak(remat):
-            m = tiny(remat=remat)
-            p = m.init_params(jax.random.key(0))
-            c = jax.jit(jax.grad(lambda p: m.loss(p, b))).lower(p).compile()
-            ma = c.memory_analysis()
-            return ma.temp_size_in_bytes
-
-        assert peak("selective") < peak(False)
+        assert peak("selective", b) < peak(False, b)
 
     @pytest.mark.slow
     def test_full_remat_saves_least(self):
         b = batch(B=4, S=64)
+        assert peak(True, b) <= peak("selective", b)
 
-        def peak(remat):
-            m = tiny(remat=remat)
-            p = m.init_params(jax.random.key(0))
-            c = jax.jit(jax.grad(lambda p: m.loss(p, b))).lower(p).compile()
-            return c.memory_analysis().temp_size_in_bytes
 
-        assert peak(True) <= peak("selective")
+def _grad_jaxpr(m, B=4, S=64):
+    """The jaxpr of the loss's gradient, traced from shapes alone."""
+    p = jax.eval_shape(m.init_params, jax.random.key(0))
+    b = {"input_ids": jax.ShapeDtypeStruct((B, S), jnp.int32)}
+    return jax.make_jaxpr(jax.grad(lambda p, b: m.loss(p, b)))(p, b)
+
+
+def _pallas_calls(jaxpr, scans=0, out=None):
+    """``(kernel name, number of scans around it)`` of every ``pallas_call``
+    in ``jaxpr``, sub-jaxprs (scan bodies, custom-vjp and shard_map calls)
+    included."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append((eqn.params["name"], scans))
+        inner = scans + (eqn.primitive.name == "scan")
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    _pallas_calls(sub, inner, out)
+    return out
+
+
+def _bare_dots(monkeypatch):
+    """``remat="dots"`` as it was before it named the flash kernel's
+    residuals: the policy that goes by primitive alone."""
+    from deepspeed_tpu.models import transformer
+    monkeypatch.setattr(
+        transformer, "_remat_policy",
+        lambda remat: jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+
+
+# d_model 256 = 4 heads of 64, the head size of BLOOM-560m and OPT-1.3b
+FLASH = dict(d_model=256, attention_backend="flash")
+# the three flash forms the train cells and their neighbours take
+FLASH_FORMS = {
+    "general_alibi": dict(over=dict(pos_embedding="alibi"), mesh=None,
+                          prefix="flash"),
+    "packed": dict(over={}, mesh=None, prefix="flash_packed"),
+    "packed_shard_map": dict(over={}, mesh=("fsdp", 4),
+                             prefix="flash_packed"),
+}
+
+
+class TestDotsKeepsFlashResiduals:
+    """``remat="dots"`` keeps the flash kernel's (o, lse): the backward of a
+    layer runs dq and dkv on them and no second forward kernel."""
+
+    @pytest.fixture
+    def form(self, request):
+        spec = FLASH_FORMS[request.param]
+        if spec["mesh"] is None:
+            dist.set_mesh(None)
+        else:
+            axis, n = spec["mesh"]
+            dist.set_mesh(Mesh(np.array(jax.devices()[:n]), (axis,)))
+        yield spec
+        dist.set_mesh(None)
+
+    @staticmethod
+    def kernels(spec, remat):
+        m = tiny(remat, **FLASH, **spec["over"])
+        assert m.config.scan_layers
+        calls = _pallas_calls(_grad_jaxpr(m).jaxpr)
+        # every kernel sits in a layer scan's body (forward or backward)
+        assert calls and all(scans == 1 for _, scans in calls), calls
+        return sorted(name for name, _ in calls)
+
+    @pytest.mark.parametrize("form", FLASH_FORMS, indirect=True)
+    def test_one_forward_kernel_a_layer(self, form):
+        pre = form["prefix"]
+        assert self.kernels(form, "dots") == [
+            f"{pre}_dkv", f"{pre}_dq", f"{pre}_fwd"]
+
+    @pytest.mark.parametrize("form", FLASH_FORMS, indirect=True)
+    def test_two_without_the_names(self, form, monkeypatch):
+        """The control: the count sees the re-run forward kernel under the
+        bare dots policy, which cannot look inside a pallas_call."""
+        _bare_dots(monkeypatch)
+        pre = form["prefix"]
+        assert self.kernels(form, "dots") == [
+            f"{pre}_dkv", f"{pre}_dq", f"{pre}_fwd", f"{pre}_fwd"]
+
+    @pytest.mark.parametrize("form", FLASH_FORMS, indirect=True)
+    def test_loss_and_grads_equal_no_remat(self, form):
+        over = dict(FLASH, **form["over"])
+        ref_m, m = tiny(False, **over), tiny("dots", **over)
+        p = ref_m.init_params(jax.random.key(0))
+        b = batch(B=4)
+        ref_loss, ref_grads = jax.jit(jax.value_and_grad(
+            lambda p: ref_m.loss(p, b)))(p)
+        loss, grads = jax.jit(jax.value_and_grad(lambda p: m.loss(p, b)))(p)
+        assert np.allclose(float(loss), float(ref_loss), rtol=1e-5)
+        jax.tree.map(lambda a, r: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(r), rtol=2e-4, atol=2e-5), grads, ref_grads)
+
+    @pytest.mark.parametrize("scan_layers", [True, False])
+    def test_einsum_attention_traces_as_under_bare_dots(self, scan_layers,
+                                                        monkeypatch):
+        """A block with no flash kernel has no such names: the joined policy
+        traces to the jaxpr the bare dots policy traces to."""
+        dist.set_mesh(None)
+
+        def text():
+            m = tiny("dots", attention_backend="xla", scan_layers=scan_layers)
+            # the policy function's own repr (its address) is no part of
+            # what was traced
+            return re.sub(r"policy=<function .*? at 0x[0-9a-f]+>", "policy=_",
+                          str(_grad_jaxpr(m)))
+
+        joined = text()
+        _bare_dots(monkeypatch)
+        assert joined == text()
+
+    def test_full_remat_keeps_no_more_than_selective(self):
+        dist.set_mesh(None)
+        b = batch(B=4)
+        assert peak(True, b, **FLASH) <= peak("selective", b, **FLASH)
+
+    def test_what_keeping_the_residuals_costs(self, monkeypatch):
+        """Compiled temporaries: no more than layers x (o + lse) bytes over
+        the bare dots policy."""
+        dist.set_mesh(None)
+        B, S, D, H, L = 4, 64, FLASH["d_model"], 4, 2
+        b = batch(B=B, S=S)
+        kept = peak("dots", b, **FLASH)
+        _bare_dots(monkeypatch)
+        bare = peak("dots", b, **FLASH)
+        o_and_lse = B * S * D * 4 + B * H * S * 4   # float32 params: o is f32
+        assert kept <= bare + L * o_and_lse, (bare, kept)
 
 
 class TestLossChunk:

@@ -533,6 +533,43 @@ def test_sdar_toy_logits_through_the_compiled_kernels(tpu):
     assert err["auto"] <= max(2 * err["xla"], 1e-4), err
 
 
+@tpu_tier
+@pytest.mark.parametrize("remat,forwards", [("dots", 1), (True, 2)])
+def test_dots_runs_the_forward_flash_kernel_once_a_layer(tpu, remat, forwards):
+    """``remat="dots"`` keeps the flash kernel's (o, lse), so the OPTIMISED
+    program of a BLOOM-560m-shaped stack's gradient (two scanned layers, d
+    1024, 16 heads of 64, ALiBi, 2,048 positions) holds one ``flash_fwd``
+    beside one ``flash_dq`` and one ``flash_dkv``: an XLA that made the
+    forward kernel again behind the policy's back would show here. Full
+    remat is the control that the count sees a second forward."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    import deepspeed_tpu.comm as dist
+    from deepspeed_tpu.models import CausalLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    dist.set_mesh(None)
+    m = CausalLM(TransformerConfig(
+        vocab_size=1024, max_seq=2048, n_layer=2, n_head=16, d_model=1024,
+        pos_embedding="alibi", norm="layernorm", activation="gelu",
+        tie_embeddings=True, embed_layernorm=True, attn_bias=True,
+        remat=remat))
+    assert m.config.scan_layers
+    p = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+                     jax.eval_shape(m.init_params, jax.random.key(0)))
+    b = {"input_ids": jax.ShapeDtypeStruct((2, 2048), jnp.int32)}
+    text = jax.jit(jax.grad(lambda p, b: m.loss(p, b))).lower(p, b) \
+        .compile().as_text()
+    kernels = [re.match(r"\s*%?([A-Za-z_]+)", line).group(1)
+               for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    flash = sorted(k for k in kernels if k.startswith("flash"))
+    assert flash == ["flash_dkv", "flash_dq"] + ["flash_fwd"] * forwards, kernels
+
+
 # --------------------------------------------------------------------- #
 # Fused logits-free cross-entropy: numerics run in the DEFAULT CPU tier
 # (interpret mode); the class is deliberately NOT tpu-marked.
