@@ -1606,8 +1606,12 @@ class _ServeSession:
     the following action BEHIND it, from its tokens on the device (the
     decode program's feed operand), and only then fetches, commits and releases it, under
     the new step's device time. The overlap is the device's queue: there is
-    no second thread. Nobody but :meth:`step` and :meth:`land` may touch a
-    step in flight, and everything that needs its tokens on the host or
+    no second thread. The queue is one deep, so a decode step launched when
+    the step in flight has ALREADY finished found the device dry: the launch
+    counts those (``serving/decode_steps_late``, one non-blocking
+    ``is_ready()``), the stalls of the host that outlasted a device step.
+    Nobody but :meth:`step` and :meth:`land` may touch a step in flight, and
+    everything that needs its tokens on the host or
     would undo its rows lands it first — :meth:`cancel`,
     :meth:`demote_prompt`, :meth:`contain_fault`, :meth:`restart_engine`,
     :meth:`close`, and inside :meth:`step` the cases ``plans_ahead`` and
@@ -2104,6 +2108,13 @@ class _ServeSession:
             self.sched.stats["decode_steps_ahead"] += 1
             if tel is not None:
                 tel.decode_steps_ahead.inc()
+            tok = self._flight.tok
+            if tok is not None and tok.is_ready():
+                # the queue is one deep: the step in flight has finished,
+                # so the device has nothing to run until this dispatch
+                self.sched.stats["decode_steps_late"] += 1
+                if tel is not None:
+                    tel.decode_steps_late.inc()
         spent = self.pools
         (logits, pools, *aux), t0 = self._dispatch(
             name, self.engine.params, toks, spent, *rest, pre=False)

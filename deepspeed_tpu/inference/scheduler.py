@@ -183,7 +183,7 @@ class ServingTelemetry:
                "kv_blocks_free", "kv_block_utilization", "kv_fragmentation",
                "cold_blocks", "prefill_steps", "prefill_chunks",
                "prefill_tokens", "prefill_padded_tokens",
-               "decode_steps", "decode_steps_ahead",
+               "decode_steps", "decode_steps_ahead", "decode_steps_late",
                "decode_live_kv_tokens", "decode_live_kv_blocks",
                "prefix_cache_lookups", "prefix_cache_hits",
                "prefix_cache_hit_tokens",
@@ -337,6 +337,32 @@ class ServingTelemetry:
             "fused decode steps dispatched while the step before them was "
             "unfetched (their tokens fed on the device): over decode_steps, "
             "how often the loop ran a step ahead")
+
+    @property
+    def decode_steps_late(self):
+        return self.registry.counter(
+            "serving/decode_steps_late",
+            "of decode_steps_ahead, those dispatched after the step in "
+            "flight had already finished on the device: the device ran dry, "
+            "a stall of the host that outlasted a device step")
+
+    def count_gc(self, pause_ms: float, full_pause_ms: float,
+                 full: int) -> None:
+        """What the interpreter's garbage collector took since the serving
+        loop last said (``monitor.trace.gc_totals``' growth); with zeros,
+        where the loop starts, so that a window without a collection reads 0.
+        Not pre-created: only an always-on loop publishes them."""
+        c = self.registry.counter
+        c("host/gc_pause_ms",
+          "wall clock inside garbage collections of any generation, on any "
+          "thread (the interpreter lock is held throughout): over the same "
+          "wall clock, the share of time in which no Python thread ran"
+          ).inc(pause_ms)
+        c("host/gc_full_pause_ms",
+          "of gc_pause_ms, inside full (generation 2) collections: over "
+          "gc_full_collections, the mean full pause").inc(full_pause_ms)
+        c("host/gc_full_collections",
+          "full (generation 2) garbage collections").inc(full)
 
     @property
     def decode_live_kv_tokens(self):
@@ -747,7 +773,8 @@ class ContinuousBatchingScheduler:
         self.stats = {"decode_steps": 0, "verify_steps": 0,
                       "emitted_tokens": 0, "spec_proposed": 0,
                       "spec_accepted": 0, "spec_rollbacks": 0,
-                      "preemptions": 0, "decode_steps_ahead": 0}
+                      "preemptions": 0, "decode_steps_ahead": 0,
+                      "decode_steps_late": 0}
         self.telemetry = telemetry
         # flight recorder (monitor/events.py): None when disabled, so
         # every emit site below gates at one None check

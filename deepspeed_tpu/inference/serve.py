@@ -62,7 +62,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from deepspeed_tpu.monitor.trace import span
+from deepspeed_tpu.monitor.trace import gc_totals, span, watch_gc
 
 #: terminal handle statuses
 FINISHED, CANCELLED, ERROR, REJECTED, TIMEOUT = (
@@ -241,6 +241,13 @@ class AsyncServingEngine:
         ev = engine._events
         if ev is not None:
             ev.emit("serve.begin", t_ns=self._t0, requests=0)
+        # a garbage collection on ANY thread holds the interpreter lock and
+        # so stalls this loop: each is a ``gc`` span on the profiler's clock
+        # from here on, and its pause is published once a loop step
+        watch_gc()
+        self._gc_seen = gc_totals()
+        if self._session.sched.telemetry is not None:
+            self._session.sched.telemetry.count_gc(0.0, 0.0, 0)
         self._thread: Optional[threading.Thread] = None
         if start:
             self._thread = threading.Thread(target=self._run,
@@ -478,9 +485,24 @@ class AsyncServingEngine:
             raise
 
     def _step_once(self) -> bool:
-        """One loop iteration: commands, load shedding, exit checks, one
-        engine step with fault containment. Returns False when the loop
-        should exit."""
+        """One loop iteration (``serve.step``), then what the garbage
+        collector took meanwhile, on any thread, into the ``host/gc_*``
+        counters: one comparison where nothing was collected. Returns False
+        when the loop should exit."""
+        alive = self._commands_and_step()
+        totals = gc_totals()
+        if totals != self._gc_seen:
+            tel = self._session.sched.telemetry
+            if tel is not None:
+                pause, full_pause, full = (
+                    a - b for a, b in zip(totals, self._gc_seen))
+                tel.count_gc(pause / 1e6, full_pause / 1e6, full)
+            self._gc_seen = totals
+        return alive
+
+    def _commands_and_step(self) -> bool:
+        """Commands, load shedding, exit checks, one engine step with fault
+        containment."""
         with span("serve.step"):
             with self._cv:
                 cmds = list(self._intake)

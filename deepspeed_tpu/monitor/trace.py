@@ -1,10 +1,14 @@
 """Structured per-step tracing + the compile watchdog.
 
-Three instruments living next to the metrics registry:
+Four instruments living next to the metrics registry:
 
 - :func:`span` / :func:`step_span` — the ONE way the program puts a host
   span on the profiler's clock (``jax.profiler.TraceAnnotation``): inert
   unless somebody is taking a trace, whoever started it.
+
+- :func:`watch_gc` — the interpreter's garbage collections as ``gc`` spans
+  on that clock (generation 1 and 2) and as pause totals
+  (:func:`gc_totals`) that the serving loop publishes as counters.
 
 - :class:`StepTracer` — host-side spans (``with tracer.span("fwd")``)
   that ALSO go through :func:`span` (so the same names show up in an
@@ -25,6 +29,7 @@ Three instruments living next to the metrics registry:
 
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from contextlib import contextmanager, nullcontext
@@ -60,6 +65,69 @@ def span(name: str, **args):
 def step_span(name: str, step: int):
     """A ``StepTraceAnnotation``: the span xprof's step views group by."""
     return step_span_factory(SPAN_PREFIX + name, step_num=step)
+
+
+# ------------------------------------------------------------------ #
+# garbage collections: spans on the profiler's clock, totals for counters
+
+# One collection runs at a time, under the interpreter lock, so plain
+# integers need no lock of their own.
+_gc_started_ns = 0
+_gc_open = None            # the ``gc`` span of the collection under way
+_gc_pause_ns = 0           # every generation
+_gc_full_pause_ns = 0      # generation 2
+_gc_full_collections = 0
+
+
+def _on_gc(phase: str, info: Dict[str, Any]) -> None:
+    global _gc_started_ns, _gc_open, _gc_pause_ns, _gc_full_pause_ns, \
+        _gc_full_collections
+    if phase == "start":
+        _gc_started_ns = time.perf_counter_ns()
+        if info["generation"] >= 1:
+            _gc_open = span("gc", generation=info["generation"])
+            _gc_open.__enter__()
+        return
+    if not _gc_started_ns:
+        return                       # installed in the middle of this one
+    if _gc_open is not None:
+        _gc_open.__exit__(None, None, None)
+        _gc_open = None
+    pause = time.perf_counter_ns() - _gc_started_ns
+    _gc_started_ns = 0
+    _gc_pause_ns += pause
+    if info["generation"] == 2:
+        _gc_full_pause_ns += pause
+        _gc_full_collections += 1
+
+
+def watch_gc() -> None:
+    """Put the interpreter's garbage collections on the profiler's clock and
+    into :func:`gc_totals`; calling it again changes nothing. ONE handler on
+    ``gc.callbacks``: a collection of generation 1 or 2 is a ``gc`` span
+    (``generation=``) from its start to its end, every collection adds its
+    pause to the totals. Generation 0 gets no span: it is frequent and
+    short, and a traced run pays for every span it holds.
+
+    The handler runs on whichever thread's allocation set the collector
+    off, at ANY allocation, also one made under the metrics registry's
+    lock: it takes no lock and touches no registry object. So ``gc`` is the
+    one span of the program NOT on the thread that feeds the chip. That is
+    its use: the interpreter lock is held for the whole collection, so the
+    serving loop's thread does not run under a ``gc`` span of any thread,
+    and device idle time under it belongs to the collector and not to the
+    phase of the loop that happened to be open (``perfbench`` gives a gap to
+    the latest-starting span over it, whatever its thread). Nothing is
+    triggered, deferred or tuned here."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+def gc_totals():
+    """``(pause ns of all collections, pause ns of generation 2, count of
+    generation 2)`` since :func:`watch_gc`, for a caller that keeps what it
+    saw last and publishes the growth."""
+    return _gc_pause_ns, _gc_full_pause_ns, _gc_full_collections
 
 
 # ------------------------------------------------------------------ #

@@ -3,8 +3,11 @@ the span factory swapped for a recorder, the serving loop emits every phase
 of a step, on its own thread only, properly nested, with the identifiers a
 kept trace is followed by; the spans change no token; the counters that
 ride along count what they say; ``range_push``/``range_pop`` keep to their
-thread; the serving programs carry names."""
+thread; the serving programs carry names; garbage collections are ``gc``
+spans on whichever thread collects and counters the loop publishes; a
+decode step launched behind a finished one is counted late."""
 
+import gc
 import threading
 import time
 
@@ -14,6 +17,7 @@ import pytest
 import deepspeed_tpu
 import deepspeed_tpu.comm as dist
 from deepspeed_tpu.accelerator import get_accelerator
+from deepspeed_tpu.inference import engine as engine_mod
 from deepspeed_tpu.inference.serve import AsyncServingEngine
 from deepspeed_tpu.models import CausalLM
 from deepspeed_tpu.models.transformer import TransformerConfig
@@ -35,7 +39,9 @@ class Recorder:
     def __init__(self):
         self.spans = []
         self._tls = threading.local()
-        self._lock = threading.Lock()
+        # re-entrant: an allocation under it may set the collector off, whose
+        # ``gc`` span is recorded on the same thread
+        self._lock = threading.RLock()
 
     def __call__(self, name, **args):
         return _Recorded(self, name, args)
@@ -137,13 +143,15 @@ def test_serving_loop_spans(kind, recorder):
     engine = tiny_engine(**serving_cfg)
     _, loop_tid = serve(engine, prompts, max_new=6, rec=recorder)
 
-    spans = recorder.spans
+    # a collection is a span of whichever thread it ran on, in whatever
+    # phase was open (its own cases are further down)
+    spans = [s for s in recorder.spans if s["name"] != pb("gc")]
     assert {s["name"] for s in spans} >= {
         pb(n) for n in ("serve.idle", "serve.step") + STEP_PHASES
         + EXEC_PHASES}
     assert all(s["name"].startswith(trace_mod.SPAN_PREFIX) for s in spans)
-    # only the loop's thread annotates: a client thread's span would claim
-    # idle gaps it has nothing to do with
+    # only the loop's thread annotates its phases: a client thread's span
+    # would claim idle gaps it has nothing to do with
     assert {s["thread"] for s in spans} == {loop_tid}
     for s in spans:
         name = s["name"][len(trace_mod.SPAN_PREFIX):]
@@ -250,6 +258,153 @@ def test_live_kv_blocks_counts_every_rows_block_copies():
     decoding = sum((n + i) // bs + 1 for n in lens for i in range(steps))
     idle = c["serving/decode_steps"] * rows - len(lens) * steps
     assert c["serving/decode_live_kv_blocks"] == decoding + idle
+
+
+@pytest.fixture
+def explicit_collections_only():
+    """The collector runs only where a test calls it: what the ``gc`` cases
+    count is then theirs alone."""
+    was = gc.isenabled()
+    gc.disable()
+    trace_mod.watch_gc()
+    yield
+    if was:
+        gc.enable()
+
+
+def host_gc_counters():
+    c = get_registry().snapshot()["counters"]
+    return {k: c.get("host/gc_" + k) for k in (
+        "pause_ms", "full_pause_ms", "full_collections")}
+
+
+def test_a_full_collection_is_one_gc_span_on_its_thread(
+        recorder, explicit_collections_only):
+    seen = trace_mod.gc_totals()
+    gc.collect(0)
+    # generation 0: counted, no span (frequent, short, and a traced run pays
+    # for every span it holds)
+    assert not recorder.named(pb("gc"))
+    young = trace_mod.gc_totals()
+    assert young[0] > seen[0] and young[1:] == seen[1:]
+    collector = threading.Thread(target=gc.collect)
+    collector.start()
+    collector.join(60)
+    assert not collector.is_alive()
+    (span,) = recorder.named(pb("gc"))
+    assert span["args"] == {"generation": 2}
+    assert span["thread"] == collector.ident != threading.get_ident()
+    pause, full_pause, full = (
+        a - b for a, b in zip(trace_mod.gc_totals(), young))
+    assert full == 1 and pause == full_pause > 0
+    gc.collect(1)
+    assert [s["args"]["generation"] for s in recorder.named(pb("gc"))] \
+        == [2, 1]
+    assert trace_mod.gc_totals()[2] == young[2] + 1
+
+
+def test_watch_gc_twice_leaves_one_handler():
+    trace_mod.watch_gc()
+    trace_mod.watch_gc()
+    AsyncServingEngine(tiny_engine(), max_new_tokens=2,
+                       start=False).shutdown()
+    assert gc.callbacks.count(trace_mod._on_gc) == 1
+
+
+def test_the_loop_publishes_collections_once_a_step(
+        explicit_collections_only):
+    get_registry().reset()
+    serving = AsyncServingEngine(tiny_engine(), max_new_tokens=2,
+                                 start=False)
+    # there from the loop's start: a window without a collection reads 0
+    zeros = dict(pause_ms=0.0, full_pause_ms=0.0, full_collections=0.0)
+    assert host_gc_counters() == zeros
+    gc.collect()
+    gc.collect(0)
+    assert host_gc_counters() == zeros          # the handler's own totals
+    serving._step_once()
+    c = host_gc_counters()
+    assert c["full_collections"] == 1
+    assert c["pause_ms"] > c["full_pause_ms"] > 0
+    serving._step_once()                        # nothing collected since
+    assert host_gc_counters() == c
+    serving.shutdown()
+
+
+def test_a_collection_under_the_registrys_lock_returns(
+        explicit_collections_only):
+    """The handler runs at any allocation of any thread, also one made
+    under the registry's lock (every counter's too): it may take no lock.
+    The lock is re-entrant, so the case that would hang is a collection on
+    one thread while ANOTHER holds the lock."""
+    reg = get_registry()
+    AsyncServingEngine(tiny_engine(), max_new_tokens=2,
+                       start=False).shutdown()  # the counters exist
+
+    def collect_holding_it():
+        with reg._lock:
+            gc.collect()
+
+    def joined(body):
+        t = threading.Thread(target=body, daemon=True)
+        t.start()
+        t.join(30)
+        return not t.is_alive()
+
+    seen = trace_mod.gc_totals()[2]
+    with reg._lock:
+        assert joined(gc.collect), "the handler waits for the registry"
+    assert joined(collect_holding_it)
+    assert trace_mod.gc_totals()[2] == seen + 2
+
+
+class _Tok:
+    """A sampler's output that says what it is told about being ready."""
+
+    def __init__(self, arr, ready):
+        self.arr, self.ready = arr, ready
+
+    def is_ready(self):
+        return self.ready
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.arr, dtype)
+
+
+@pytest.mark.parametrize("ready", [True, False])
+def test_late_steps_count_launches_behind_a_finished_step(ready, monkeypatch):
+    class Launched(engine_mod._Launched):
+        __slots__ = ()
+
+        def __init__(self, *args):
+            super().__init__(*args)
+            if self.tok is not None:
+                self.tok = _Tok(self.tok, ready)
+
+    monkeypatch.setattr(engine_mod, "_Launched", Launched)
+    get_registry().reset()
+    engine = tiny_engine(prefill_chunk_tokens=8)
+    start = engine.telemetry_snapshot()["counters"]
+    prompts = [np.arange(3, 23, dtype=np.int32),
+               np.arange(20, 25, dtype=np.int32)]
+    out = engine.generate_batch(prompts, max_new_tokens=6)
+    end = engine.telemetry_snapshot()["counters"]
+    stats = engine._last_serve_stats
+    # a window without a late step reads 0, not nothing
+    assert start["serving/decode_steps_late"] == 0
+    assert end["serving/decode_steps_late"] == stats["decode_steps_late"]
+    assert stats["decode_steps_ahead"] > 0
+    if ready:
+        # every decode step behind a step that sampled; the one behind a
+        # prefill chunk that sampled nothing has nothing to ask
+        assert 0 < stats["decode_steps_late"] <= stats["decode_steps_ahead"]
+    else:
+        assert stats["decode_steps_late"] == 0
+    monkeypatch.undo()
+    plain = tiny_engine(prefill_chunk_tokens=8).generate_batch(
+        prompts, max_new_tokens=6)
+    for a, b in zip(out, plain):                # the query lands nothing
+        np.testing.assert_array_equal(a, b)
 
 
 def test_range_push_pop_keep_to_their_thread(monkeypatch):
