@@ -489,7 +489,7 @@ def blockgen_leg(name, dry_run, n_dev):
     a head size the paged and flash kernels take (64), six requests over
     four rows through ``generate_batch``, so rows sit at different passes of
     different blocks in one fused step. The block step must have taken the
-    paged kernel (a row's 4 positions as 8 query rows a kv head) and the
+    paged kernel (a row's 4 positions on its position axis) and the
     prefill the flash kernel's staircase, and the tokens are those of the
     same weights on the plain-XLA forms."""
     import jax

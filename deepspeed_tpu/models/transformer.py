@@ -2113,9 +2113,9 @@ def _paged_block_attention(cfg: TransformerConfig, x, lp, positions, pos,
     them: a denoise pass's KV is not kept by being written over), then all
     Bg queries read positions ``<= pos + Bg - 1``: everything before the
     block and the block itself, both directions. The Bg queries of a row
-    see the SAME keys, so they ride the paged kernel as Bg x (H / KV) query
-    rows a kv head, in ONE read of the row's KV. Returns (out [W, Bg, D],
-    kp, vp)."""
+    see the SAME keys, so they ride the paged kernel on its position axis
+    (Bg x H / KV query rows a kv head), in ONE read of the row's KV.
+    Returns (out [W, Bg, D], kp, vp)."""
     W, Bg, D = x.shape
     H, KV, Hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
     bs = kp.shape[1]
@@ -2131,17 +2131,13 @@ def _paged_block_attention(cfg: TransformerConfig, x, lp, positions, pos,
     if _use_flash(cfg):
         from deepspeed_tpu.ops.pallas.paged_decode_attention import \
             paged_decode_attention
-        P_ = H // KV
-        # the kernel's query row r reads kv head r // (rows / KV): a kv
-        # head's rows are its P_ heads at each of the Bg positions
-        qr = q.reshape(W, Bg, KV, P_, Hd).transpose(0, 2, 1, 3, 4)
-        o = paged_decode_attention(qr.reshape(W, KV * Bg * P_, Hd), kp, vp,
-                                   block_tables, pos + (Bg - 1),
+        # the block's positions are the kernel's own axis: one read of the
+        # row's KV for all Bg of them
+        o = paged_decode_attention(q, kp, vp, block_tables, pos + (Bg - 1),
                                    scale=cfg.attn_scale)
     if o is not None:
         form = "paged_kernel"
-        out = o.reshape(W, KV, Bg, P_, Hd).transpose(0, 2, 1, 3, 4) \
-            .reshape(W, Bg, H * Hd)
+        out = o.reshape(W, Bg, H * Hd)
     else:
         form = "gather_einsum"
         # the shared masked-softmax core under the model's block mask: a

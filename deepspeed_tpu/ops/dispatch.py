@@ -17,6 +17,10 @@ Sites and their forms:
 ``paged_decode``      ``paged_kernel`` | ``paged_kernel_sharded`` |
                       ``gather_einsum``
 ``paged_prefill``     ``flash`` | ``einsum``
+``paged_block``       ``paged_kernel`` | ``gather_einsum``
+``paged_decode_attention``  ``per_kv_head`` | ``block_diagonal`` (how the
+                      paged kernel takes its products, from the static
+                      shapes of a call: query rows a kv head, head size)
 ``kda_decode``        ``kda_kernel`` | ``slot_update`` (a linear-attention
                       layer's one-token state update: the Pallas kernel
                       over the live rows' slots | ``kda_recurrent_step``

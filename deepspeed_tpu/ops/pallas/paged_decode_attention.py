@@ -11,7 +11,8 @@ batching retires/admits requests per step, so physical KV placement is
 arbitrary; the kernel follows the table instead of a dense stride.
 
 Design: the kernel's work is (rows) x (a small fixed cost) + (live blocks)
-x (about a block's copy time); the table's width costs nothing.
+x (about a block's copy time); the table's width costs nothing, and the
+arithmetic follows the work it is given.
 
 * grid ``(num_requests,)``, rows in order. The pools enter whole, where
   they lie in HBM (``pl.ANY``: no BlockSpec moves them); the block table and
@@ -24,28 +25,47 @@ x (about a block's copy time); the table's width costs nothing.
   group's copies are in flight under this group's arithmetic, and the NEXT
   ROW's first group under this row's last, so only the call's first copy
   is waited for in the open. G comes from the shapes (``_group_blocks``):
-  groups of about 0.75 MB a pool, one block at 2,048 bf16 lanes, four at 768;
-* all heads of a group in one product. The row's query is laid
-  block-diagonally once a row (``[H, KV*Hd]``: head ``h``'s values at the
-  lanes of its kv head ``h // P``, zeros elsewhere), so scores are
-  ``q_bd [H, KV*Hd] . k [G*bs, KV*Hd]^T`` and the numerator accumulates
-  ``p [H, G*bs] @ v [G*bs, KV*Hd]`` whole in float32; each head's own
-  ``Hd`` lanes (the diagonal) are taken once, at the end of the row. The
-  MXU does KV times the arithmetic needed, still under the copy's time;
+  groups of about 0.75 MB a pool, one block at 2,048 bf16 lanes, four at
+  768. A group is what is COPIED together; the arithmetic walks a group's
+  live blocks one at a time (a step of the running softmax over a block's
+  ``bs`` keys), so a row pays for the blocks it holds, not for G;
+* a query is ``[B, H, Hd]`` or ``[B, Q, H, Hd]``: Q positions of a row that
+  read the SAME keys (a generation block's), in one read of the row's KV.
+  A kv head then has ``Q x P`` query rows (P = H / KV), and the products
+  take one of two forms, chosen from those static shapes alone
+  (``_per_kv_head``; recorded at ``ops.dispatch`` site
+  ``paged_decode_attention``):
+
+  - ``per_kv_head``, where a kv head's rows fill whole sublane tiles (from
+    ``_PER_KV_HEAD_MIN_ROWS`` of them) and its ``Hd`` lanes a whole lane
+    tile: scores ``q_g [Q*P, Hd] . k[:, g's lanes]^T`` and the numerator
+    ``p_g @ v[:, g's lanes]`` a kv head, accumulated in that head's own
+    rows: the arithmetic the scores need and no more;
+  - ``block_diagonal``, below that (one position of MHA is ONE query row a
+    kv head, a matrix-vector product the MXU does not want): the row's
+    query laid block-diagonally once a row (``[Q*H, KV*Hd]``: head ``h``'s
+    values at the lanes of its kv head ``h // P``, zeros elsewhere), so
+    scores are ``q_bd . k [bs, KV*Hd]^T`` and the numerator accumulates
+    ``p @ v [bs, KV*Hd]`` whole; each head's own ``Hd`` lanes (the
+    diagonal) are taken once, at the end of the row. The MXU does KV times
+    the arithmetic needed, which stays under the copy's time at a decode
+    step's few query rows and does not at a block's 128 (PERF.md section
+    6, PR 36);
 * no precision is given up for that: bf16 operands meet on the MXU's bf16
   path with float32 sums (a bf16 x bf16 product is exact in float32), and
   the float32 probabilities go there as three bf16 terms whose sum is
   exact (``_split3``), stacked as rows of one product. m, l, p and the
   numerator are float32. float32 pools take float32 products in full;
 * per-request positions: ``pos[b]`` is the 0-based position of request
-  ``b``'s new token (attends ``kpos <= pos[b]``) — requests at different
-  depths decode in the same fused step (iteration-level batching);
+  ``b``'s new token, the last of them where there are Q (attends ``kpos <=
+  pos[b]``) — requests at different depths decode in the same fused step
+  (iteration-level batching);
 * ALiBi slopes and an additive key-side ``pad_bias`` over LOGICAL positions
   keep parity with the dense kernel; GQA head ``h`` reads kv head ``h // P``.
 
 Interpret mode on CPU — the unit tier pins parity vs ``decode_attention``
 and a float32 reference on randomized block tables; the kernel's times by
-state and G are ``benchmarks/paged_decode_bench.py``'s.
+state, G and form are ``benchmarks/paged_decode_bench.py``'s.
 """
 
 from __future__ import annotations
@@ -58,6 +78,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.ops import dispatch
 from deepspeed_tpu.ops.dispatch import resolve_interpret
 from deepspeed_tpu.utils.logging import warn_once
 
@@ -70,6 +91,16 @@ _NEG = -1e30
 # four (an iteration's fixed cost against 0.19 MB). This budget gives both,
 # and leaves most of a kernel's default 16 MiB to everything else.
 _STREAM_VMEM_BYTES = 3 * 1024 * 1024
+# Query rows a kv head (positions x heads of its group) from which the
+# products are taken a kv head. Measured on a v5e (paged_decode_bench.py
+# --forms, PERF.md section 6, PR 36; ms a call, per_kv_head | block_diagonal
+# under the same copies and steps): ``sdar_block`` (32 rows a kv head, 4 kv
+# heads) 0.1009 | 0.1392; ``solar_gqa`` (8 rows a kv head, 8 kv heads) 0.803 |
+# 0.881: already at ONE sublane tile of rows the kv head's own product wins
+# (the numerator is [rows, Hd] a kv head and not [rows, KV*Hd]). Under 8 rows
+# a tile is partly empty and a kv head's rows cannot be stacked on tile
+# borders: one position of MHA (OPT, OLMoE) or a group of 4 stays whole.
+_PER_KV_HEAD_MIN_ROWS = 8
 
 
 def _group_blocks(bs: int, row: int, itemsize: int, n_blocks: int) -> int:
@@ -77,6 +108,25 @@ def _group_blocks(bs: int, row: int, itemsize: int, n_blocks: int) -> int:
     buffers hold, never more than a table is wide."""
     fit = _STREAM_VMEM_BYTES // (4 * bs * row * itemsize)
     return int(max(1, min(fit, n_blocks)))
+
+
+def _step_blocks(G: int):
+    """How many of a group's live blocks a step of the running softmax takes
+    at once, largest first: the powers of two up to G, so every count of
+    live blocks is walked in full steps with none computed dead. A step is a
+    chain (scores, row maximum, ``exp``, values) the next cannot start
+    under, ~0.2 us each whatever its size, so the fewer the better:
+    ``sdar_block`` 0.1307 ms a call one block a step, 0.1092 in pairs + one,
+    0.1009 so (its copies alone take 0.0841); ``narrow_decode`` 0.0842,
+    0.0748, 0.0729 (paged_decode_bench.py --steps 1 21, PR 36)."""
+    return tuple(1 << e for e in reversed(range(G.bit_length())))
+
+
+def _per_kv_head(per: int, hd: int) -> bool:
+    """Whether the products are taken a kv head (``per`` query rows of a kv
+    head against that head's ``hd`` lanes) or once over everything against
+    the block-diagonal query. From static shapes only."""
+    return per % 8 == 0 and per >= _PER_KV_HEAD_MIN_ROWS and hd % 128 == 0
 
 
 def _split3(p):
@@ -89,17 +139,19 @@ def _split3(p):
     return hi, mid, r - mid
 
 
-def _kernel(bt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, *rest, bs, G, group,
-            has_bias, has_alibi):
+def _kernel(bt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, *rest, bs, G, steps,
+            group, per_head, has_bias, has_alibi):
     rest = list(rest)
     bias_ref = rest.pop(0) if has_bias else None
     slope_ref = rest.pop(0) if has_alibi else None
-    o_ref, kbuf, vbuf, sem, qbd_ref, acc_ref, slot_ref = rest
+    o_ref, kbuf, vbuf, sem, qs_ref, acc_ref, slot_ref = rest
     b = pl.program_id(0)
     nb = pl.num_programs(0)
     n_blocks = bt_ref.shape[1]
-    hp, hd = q_ref.shape[1:]
-    kv = kbuf.shape[2] // hd
+    nq, hp, hd = q_ref.shape[1:]
+    kv = kbuf.shape[3] // hd
+    per = nq * group            # query rows a kv head
+    rows = acc_ref.shape[0]
     bf16 = kbuf.dtype == jnp.bfloat16 and q_ref.dtype == jnp.bfloat16
     mxu = jnp.bfloat16 if bf16 else jnp.float32
     # operands of another precision than bf16 meet in float32, in full
@@ -121,40 +173,96 @@ def _kernel(bt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, *rest, bs, G, group,
                 for s, (pool, buf) in enumerate(((kp_hbm, kbuf),
                                                  (vp_hbm, vbuf))):
                     cp = pltpu.make_async_copy(
-                        pool.at[src], buf.at[slot, pl.ds(i * bs, bs)],
-                        sem.at[s, slot])
+                        pool.at[src], buf.at[slot, i], sem.at[s, slot])
                     cp.wait() if wait else cp.start()
+
+    def place(g, t):
+        """Where kv head ``g``'s ``group`` heads at position ``t`` sit in the
+        query scratch and the numerator: (rows, lanes). A kv head: its own
+        ``per`` rows over ``Hd`` lanes. Block-diagonal: the heads' natural
+        rows, at the lanes of their kv head in the pool's row."""
+        if per_head:
+            r0 = g * per + t * group
+            return slice(r0, r0 + group), slice(0, hd)
+        r0 = t * hp + g * group
+        return slice(r0, r0 + group), slice(g * hd, (g + 1) * hd)
 
     @pl.when(b == 0)
     def _():
-        if G > 1:  # dslint: disable=DS004 (G is a static Python int)
-            # a group's unfilled blocks are masked out of the scores, but
-            # their probability 0 still multiplies what the value buffer
-            # holds there: zeros, or an earlier block's finite values,
-            # never stale VMEM
-            vbuf[:] = jnp.zeros_like(vbuf)
-        qbd_ref[:] = jnp.zeros_like(qbd_ref)
+        if not per_head:
+            # the zeros off the diagonal are written once a call
+            qs_ref[:] = jnp.zeros_like(qs_ref)
         slot_ref[0] = 0
         copies(0, 0, 0)
 
     pos = pos_ref[b]
-    n_groups = pl.cdiv(live_blocks(b), G)
+    live = live_blocks(b)
+    n_groups = pl.cdiv(live, G)
     slot0 = slot_ref[0]
 
-    # the row's query laid block-diagonally: head h's Hd values at the
-    # lanes of ITS kv head, zeros elsewhere, so ONE product with a block's
-    # [tokens, KV*Hd] rows gives every head's scores
-    q32 = q_ref[0].astype(jnp.float32)
+    q32 = q_ref[0].astype(jnp.float32)                         # [Q, Hp, Hd]
     for g in range(kv):
-        qbd_ref[g * group:(g + 1) * group, g * hd:(g + 1) * hd] = \
-            q32[g * group:(g + 1) * group, :]
-    qbd = qbd_ref[:].astype(mxu)
+        for t in range(nq):
+            r, c = place(g, t)
+            qs_ref[r, c] = q32[t, g * group:(g + 1) * group, :]
+    qs = qs_ref[:].astype(mxu)
     acc_ref[:] = jnp.zeros_like(acc_ref)
     if has_alibi:
-        slope = slope_ref[:]                                   # [Hp, 1]
+        slope = slope_ref[:]                                   # [rows, 1]
+
+    # the products' parts, (rows of the scratch, lanes of the pool's row):
+    # a kv head each, or ONE over everything against the block-diagonal query
+    parts = [(slice(g * per, (g + 1) * per), slice(g * hd, (g + 1) * hd))
+             for g in range(kv)] if per_head else [(slice(None), slice(None))]
+
+    def scores(k):
+        return jnp.concatenate([
+            jax.lax.dot_general(qs[r], k[:, c], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32,
+                                precision=exact)
+            for r, c in parts], axis=0)                        # [rows, bs]
+
+    def values(p, v):
+        """``p @ v`` a part. Against bf16 values the float32 p goes as three
+        bf16 terms stacked as rows: one pass of v through the MXU, float32
+        sums, p exact."""
+        terms = _split3(p) if bf16 else (p,)
+        out = []
+        for r, c in parts:
+            pv = jnp.dot(jnp.concatenate([t[r] for t in terms], axis=0)
+                         .astype(mxu), v[:, c],
+                         preferred_element_type=jnp.float32, precision=exact)
+            n = pv.shape[0] // len(terms)
+            out.append(functools.reduce(
+                jnp.add, [pv[i * n:(i + 1) * n] for i in range(len(terms))]))
+        return jnp.concatenate(out, axis=0)                    # [rows, lanes]
+
+    def step(slot, blk, i, n, carry):
+        """One step of the running softmax over ``n`` blocks of the row from
+        block ``blk`` on, the ``i``-th and following of the group in
+        ``slot``: ``n * bs`` keys."""
+        m_prev, l_prev = carry
+        k = kbuf[slot, pl.ds(i, n)].reshape(n * bs, -1).astype(mxu)
+        v = vbuf[slot, pl.ds(i, n)].reshape(n * bs, -1).astype(mxu)
+        s = scores(k)                                          # [rows, n*bs]
+        # LOGICAL key positions: the table only moved the storage
+        kpos = blk * bs + jax.lax.broadcasted_iota(jnp.int32,
+                                                   (1, n * bs), 1)
+        if has_alibi:
+            s = s + slope * (kpos - pos).astype(jnp.float32)
+        if has_bias:
+            s = s + jnp.concatenate(
+                [bias_ref[0, pl.ds(blk + t, 1), :] for t in range(n)], axis=1)
+        s = jnp.where(kpos <= pos, s, _NEG)
+
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)                                 # float32
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_ref[:] = acc_ref[:] * alpha + values(p, v)
+        return m_new, l_new
 
     def body(j, carry):
-        m_prev, l_prev = carry
         slot = (slot0 + j) % 2
 
         # the next group's blocks fly under this group's arithmetic: this
@@ -168,57 +276,47 @@ def _kernel(bt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, *rest, bs, G, group,
             copies(b + 1, 0, 1 - slot)
 
         copies(b, j, slot, wait=True)
-        k = kbuf[slot].astype(mxu)                             # [G*bs, KV*Hd]
-        v = vbuf[slot].astype(mxu)
-        s = jax.lax.dot_general(qbd, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32,
-                                precision=exact)               # [Hp, G*bs]
-        # LOGICAL key positions: the table only moved the storage
-        kpos = j * (G * bs) + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if has_alibi:
-            s = s + slope * (kpos - pos).astype(jnp.float32)
-        if has_bias:
-            s = s + bias_ref[0, pl.ds(j, 1), :]
-        s = jnp.where(kpos <= pos, s, _NEG)
-
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                                 # float32
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        if bf16:
-            # three bf16 terms of p stacked as rows: one pass of v through
-            # the MXU, float32 sums, p exact
-            pv = jnp.dot(jnp.concatenate(_split3(p), axis=0)
-                         .astype(jnp.bfloat16), v,
-                         preferred_element_type=jnp.float32)
-            pv = pv[:hp] + pv[hp:2 * hp] + pv[2 * hp:]
-        else:
-            pv = jnp.dot(p, v, preferred_element_type=jnp.float32,
-                         precision=exact)
-        acc_ref[:] = acc_ref[:] * alpha + pv                   # [Hp, KV*Hd]
-        return m_new, l_new
+        if G == 1:  # dslint: disable=DS004 (G is a static Python int)
+            return step(slot, j, 0, 1, carry)
+        # the group's LIVE blocks only, the most at a time first: the
+        # arithmetic follows the row, in as few steps as its blocks allow
+        done, left = 0, jnp.minimum(G, live - j * G)
+        for n in steps:
+            carry = jax.lax.fori_loop(
+                0, left // n,
+                lambda t, c, n=n, done=done: step(
+                    slot, j * G + done + t * n, done + t * n, n, c), carry)
+            done, left = done + left // n * n, left % n
+        return carry
 
     _, l = jax.lax.fori_loop(
         0, n_groups, body,
-        (jnp.full((hp, 1), _NEG, jnp.float32), jnp.zeros((hp, 1), jnp.float32)))
+        (jnp.full((rows, 1), _NEG, jnp.float32),
+         jnp.zeros((rows, 1), jnp.float32)))
     slot_ref[0] = (slot0 + n_groups) % 2
 
-    # the product gave every head the values of every kv head: keep each
-    # head's own (the diagonal), once a row
     for g in range(kv):
-        rows = slice(g * group, (g + 1) * group)
-        o_ref[0, rows, :] = (acc_ref[rows, g * hd:(g + 1) * hd]
-                             / l[rows]).astype(o_ref.dtype)
+        for t in range(nq):
+            r, c = place(g, t)
+            o_ref[0, t, g * group:(g + 1) * group, :] = \
+                (acc_ref[r, c] / l[r]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("group", "G", "interpret"))
-def _paged_call(q, kp, vp, bt, pos, bias, slopes, *, group, G, interpret):
-    """q ``[B, Hp, Hd]`` (pre-scaled, heads padded to a multiple of 8);
-    ``bias`` ``[B, n_groups, G*bs]`` or None; ``slopes`` ``[Hp, 1]`` or None."""
-    B, hp, hd = q.shape
+@functools.partial(jax.jit,
+                   static_argnames=("group", "G", "steps", "per_head",
+                                    "interpret"))
+def _paged_call(q, kp, vp, bt, pos, bias, slopes, *, group, G, steps,
+                per_head, interpret):
+    """q ``[B, Q, Hp, Hd]`` (pre-scaled, heads padded to a multiple of 8);
+    ``bias`` ``[B, n_blocks, bs]`` or None; ``slopes`` ``[rows, 1]`` in the
+    kernel's row order or None."""
+    B, nq, hp, hd = q.shape
     bs, row = kp.shape[1:]
+    # a kv head's rows over its own Hd lanes | every head over the pool's row
+    rows, lanes = ((row // hd) * nq * group, hd) if per_head \
+        else (nq * hp, row)
     in_specs = [
-        pl.BlockSpec((1, hp, hd), lambda b, *_: (b, 0, 0)),
+        pl.BlockSpec((1, nq, hp, hd), lambda b, *_: (b, 0, 0, 0)),
         pl.BlockSpec(memory_space=pl.ANY),      # the pools stay in HBM: the
         pl.BlockSpec(memory_space=pl.ANY),      # kernel copies live blocks
     ]
@@ -228,31 +326,32 @@ def _paged_call(q, kp, vp, bt, pos, bias, slopes, *, group, G, interpret):
                                      lambda b, *_: (b, 0, 0)))
         args.append(bias)
     if slopes is not None:
-        in_specs.append(pl.BlockSpec((hp, 1), lambda b, *_: (0, 0)))
+        in_specs.append(pl.BlockSpec((rows, 1), lambda b, *_: (0, 0)))
         args.append(slopes)
     return pl.pallas_call(
-        functools.partial(_kernel, bs=bs, G=G, group=group,
-                          has_bias=bias is not None,
+        functools.partial(_kernel, bs=bs, G=G, steps=steps, group=group,
+                          per_head=per_head, has_bias=bias is not None,
                           has_alibi=slopes is not None),
         name="paged_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, hp, hd), lambda b, *_: (b, 0, 0)),
+            out_specs=pl.BlockSpec((1, nq, hp, hd),
+                                   lambda b, *_: (b, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, G * bs, row), kp.dtype),   # k blocks, 2 slots
-                pltpu.VMEM((2, G * bs, row), vp.dtype),   # v blocks, 2 slots
+                pltpu.VMEM((2, G, bs, row), kp.dtype),    # k blocks, 2 slots
+                pltpu.VMEM((2, G, bs, row), vp.dtype),    # v blocks, 2 slots
                 pltpu.SemaphoreType.DMA((2, 2)),          # [pool, slot]
-                pltpu.VMEM((hp, row), jnp.float32),       # block-diagonal q
-                pltpu.VMEM((hp, row), jnp.float32),       # running numerator
+                pltpu.VMEM((rows, lanes), jnp.float32),   # the row's query
+                pltpu.VMEM((rows, lanes), jnp.float32),   # running numerator
                 pltpu.SMEM((1,), jnp.int32),              # slot of next group
             ],
         ),
         # rows in order: a row starts the next row's first copies
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        out_shape=jax.ShapeDtypeStruct((B, hp, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, nq, hp, hd), q.dtype),
         interpret=interpret,
     )(bt, pos, *args)
 
@@ -270,28 +369,37 @@ def paged_envelope_ok(H: int, KV: int, Hd: int, bs: int) -> bool:
 def paged_decode_attention(q, kp, vp, block_tables, pos, *, pad_bias=None,
                            alibi_slopes=None, scale: Optional[float] = None,
                            interpret: Optional[bool] = None):
-    """Attention of one new token per request against a PAGED KV cache.
+    """Attention of each request's new positions against a PAGED KV cache.
 
-    q ``[B, H, Hd]`` (one new token per running request, rope applied);
+    q ``[B, H, Hd]`` (one new token per running request, rope applied) or
+    ``[B, Q, H, Hd]``: Q positions of a request that read the SAME keys
+    (a generation block's: all of ``kpos <= pos[b]``, in ONE read of the
+    request's KV);
     kp/vp ``[num_blocks, block_size, KV*Hd]`` — the shared block pools (a
     token's kv heads merged, head ``g`` at lanes ``[g*Hd, (g+1)*Hd)``; KV
     is read off ``kp.shape[2] // Hd``), with each request's new k/v already
-    written at its slot;
+    written at its slots;
     ``block_tables`` ``[B, max_blocks]`` int32 pool block ids (logical block
     ``j`` of request ``b`` lives in pool block ``block_tables[b, j]``; dead
     tail entries may be anything — they are never read);
-    ``pos`` ``[B]`` int32 per-request 0-based position of the new token
-    (request ``b`` attends logical positions ``<= pos[b]``).
+    ``pos`` ``[B]`` int32 per-request 0-based position of the new token, the
+    LAST of them where there are Q (request ``b`` attends logical positions
+    ``<= pos[b]``; ALiBi distances are taken from it).
     ``pad_bias`` ``[B, max_blocks * block_size]`` additive f32 bias over
     logical positions. GQA head h reads kv head ``h // (H // KV)``.
-    Returns ``[B, H, Hd]``.
+    Returns q's shape. Which form the products took (module docstring) is
+    static a shape and recorded: ``ops.dispatch`` site
+    ``paged_decode_attention``, ``per_kv_head`` | ``block_diagonal``.
 
     Returns None when the shape is outside the kernel's envelope (caller
     falls back to a gather + einsum path): block_size not a multiple of
     128, head_dim not lane-aligned, a pool row ``KV*Hd`` that is not a
     multiple of 128 lanes (MQA at Hd 64), or H % KV != 0.
     """
-    B, H, Hd = q.shape
+    one = q.ndim == 3
+    if one:
+        q = q[:, None]
+    B, Q, H, Hd = q.shape
     bs, KV = kp.shape[1], kp.shape[2] // Hd
     if not paged_envelope_ok(H, KV, Hd, bs):
         warn_once(f"paged_decode_attention: heads={H} kv_heads={KV} "
@@ -305,21 +413,29 @@ def paged_decode_attention(q, kp, vp, block_tables, pos, *, pad_bias=None,
     scale = Hd**-0.5 if scale is None else scale
     n_blocks = block_tables.shape[1]
     G = _group_blocks(bs, KV * Hd, kp.dtype.itemsize, n_blocks)
+    per_head = _per_kv_head(Q * P, Hd)
+    dispatch.record("paged_decode_attention",
+                    "per_kv_head" if per_head else "block_diagonal",
+                    f"Q={Q} P={P} Hd={Hd} G={G}")
     pad_h = -H % 8          # whole float32 sublane tiles of heads
-    qs = jnp.pad(q * scale, ((0, 0), (0, pad_h), (0, 0)))
+    qs = jnp.pad(q * scale, ((0, 0), (0, 0), (0, pad_h), (0, 0)))
     bias = slopes = None
     if pad_bias is not None:
-        # one group's logical positions a row: [B, groups, G*bs]
-        n_groups = -(-n_blocks // G)
-        bias = jnp.pad(pad_bias.astype(jnp.float32),
-                       ((0, 0), (0, (n_groups * G - n_blocks) * bs))
-                       ).reshape(B, n_groups, G * bs)
+        # one block's logical positions a row: [B, blocks, bs]
+        bias = pad_bias.astype(jnp.float32).reshape(B, n_blocks, bs)
     if alibi_slopes is not None:
-        slopes = jnp.pad(jnp.asarray(alibi_slopes, jnp.float32).reshape(H),
-                         (0, pad_h)).reshape(H + pad_h, 1)
+        # a slope a query row, in the kernel's order of rows (``place``)
+        slopes = jnp.asarray(alibi_slopes, jnp.float32).reshape(H)
+        if per_head:
+            slopes = jnp.broadcast_to(slopes.reshape(KV, 1, P), (KV, Q, P))
+        else:
+            slopes = jnp.broadcast_to(jnp.pad(slopes, (0, pad_h)),
+                                      (Q, H + pad_h))
+        slopes = slopes.reshape(-1, 1)
     out = _paged_call(qs, kp, vp,
                       jnp.asarray(block_tables, jnp.int32),
                       jnp.asarray(pos, jnp.int32).reshape(B),
-                      bias, slopes, group=P, G=G,
-                      interpret=bool(interpret))
-    return out[:, :H]
+                      bias, slopes, group=P, G=G, steps=_step_blocks(G),
+                      per_head=per_head,
+                      interpret=bool(interpret))[:, :, :H]
+    return out[:, 0] if one else out

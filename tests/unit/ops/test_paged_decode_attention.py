@@ -7,6 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.ops import dispatch
 from deepspeed_tpu.ops.pallas.decode_attention import decode_attention
 from deepspeed_tpu.ops.pallas.paged_decode_attention import \
     paged_decode_attention
@@ -172,7 +173,12 @@ def test_paged_traced_pos_and_tables():
 
 def paged_reference(q, kp, vp, bt, pos, bias=None, slopes=None):
     """float32 gather + softmax over each row's live blocks. Dead table
-    entries are taken off before the gather: what they name is not read."""
+    entries are taken off before the gather: what they name is not read.
+    q [B, Q, H, Hd]: a row's Q positions read the same keys."""
+    if q.ndim == 4:
+        return jax.vmap(lambda one: paged_reference(one, kp, vp, bt, pos,
+                                                    bias, slopes),
+                        in_axes=1, out_axes=1)(q)
     B, H, Hd = q.shape
     bs, KV = kp.shape[1], kp.shape[2] // Hd
     live = jnp.arange(bt.shape[1])[None, :] <= (pos // bs)[:, None]
@@ -326,6 +332,103 @@ def test_bf16_pools_against_float32_reference(KV, group, Hd):
     out = paged_decode_attention(q, kp, vp, bt, pos)
     assert out.dtype == jnp.bfloat16
     assert max_err(out, paged_reference(q, kp, vp, bt, pos)) < 2e-2
+
+
+# --------------------------------------------------------------------- #
+# The position axis: q [B, Q, H, Hd], a row's Q positions over the same
+# keys. Q x P query rows a kv head: from _PER_KV_HEAD_MIN_ROWS of them (in
+# whole sublane tiles, over a whole lane tile) the products are taken a kv
+# head, below against the block-diagonal query.
+
+POSITION_SHAPES = {              # (Q, KV, P, Hd): the form it takes
+    (4, 4, 8, 128): "per_kv_head",       # SDAR: 32 rows a kv head
+    (4, 2, 2, 128): "per_kv_head",       # 8 rows a kv head
+    (4, 2, 1, 64): "block_diagonal",     # many positions, half a lane tile
+    (1, 2, 8, 128): "per_kv_head",       # Solar-Open2's group at one position
+}
+
+
+def position_case(r, Q, KV, P, Hd, dtype, n_max=5, bs=128):
+    """Rows of 1 live block, 2 and 4 (multiples of a group of 2), the
+    table's whole width (an odd count: its last group is half filled) and
+    an idle row at position 0 on the dummy block."""
+    lives = [1, 2, 4, n_max, 1]
+    q, kp, vp, bt, _ = random_paged_case(r, len(lives), KV, Hd, bs, n_max,
+                                         dtype=dtype, group=P)
+    q = jnp.asarray(r.normal(size=(len(lives), Q, KV * P, Hd)), dtype)
+    pos = np.asarray([n * bs - 1 - int(r.integers(0, bs - Q)) for n in lives],
+                     np.int32)
+    pos[-1] = 0
+    bt = np.array(bt)
+    bt[-1] = 0
+    return q, kp, vp, jnp.asarray(bt), jnp.asarray(pos)
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("Q,KV,P,Hd", sorted(POSITION_SHAPES))
+def test_positions_of_a_row_read_the_same_keys(Q, KV, P, Hd, dtype, tol,
+                                               monkeypatch):
+    """Both forms of the products, float32 and bf16 pools, against the
+    float32 gather + softmax, at a group of 2 blocks an iteration."""
+    force_group(monkeypatch, 2)
+    r = np.random.default_rng(100 + Q + KV + P + Hd)
+    q, kp, vp, bt, pos = position_case(r, Q, KV, P, Hd, dtype)
+    out = paged_decode_attention(q, kp, vp, bt, pos)
+    assert out.shape == q.shape and out.dtype == dtype
+    assert max_err(out, paged_reference(q, kp, vp, bt, pos)) < tol
+
+
+@pytest.mark.parametrize("terms", ["alibi", "pad_bias"])
+@pytest.mark.parametrize("Q,KV,P,Hd", [(4, 2, 2, 128), (4, 2, 1, 64)])
+def test_positions_with_alibi_and_pad_bias(Q, KV, P, Hd, terms, monkeypatch):
+    """A slope goes with a query row wherever the form put it; distances
+    and the bias are the last position's, as the mask is."""
+    force_group(monkeypatch, 2)
+    r = np.random.default_rng(110 + P + Hd)
+    q, kp, vp, bt, pos = position_case(r, Q, KV, P, Hd, jnp.float32)
+    H = KV * P
+    slopes = bias = None
+    if terms == "alibi":
+        slopes = jnp.asarray(2.0 ** (-8.0 * np.arange(1, H + 1) / H),
+                             jnp.float32)
+    else:
+        bias = jnp.asarray(r.normal(size=(q.shape[0], bt.shape[1] * 128))
+                           * 0.2, jnp.float32)
+    out = paged_decode_attention(q, kp, vp, bt, pos, pad_bias=bias,
+                                 alibi_slopes=slopes)
+    want = paged_reference(q, kp, vp, bt, pos, bias, slopes)
+    assert max_err(out, want) < 1e-5
+
+
+@pytest.mark.parametrize("KV,P,Hd", [(2, 8, 128), (2, 1, 64)])
+def test_one_position_on_the_axis_is_the_call_without_it(KV, P, Hd):
+    """q [B, 1, H, Hd] and q [B, H, Hd] are one program: bit for bit."""
+    r = np.random.default_rng(120 + Hd)
+    q, kp, vp, bt, pos = random_paged_case(r, 3, KV, Hd, 128, 3, group=P)
+    flat = paged_decode_attention(q, kp, vp, bt, pos)
+    axis = paged_decode_attention(q[:, None], kp, vp, bt, pos)
+    assert axis.shape == (3, 1, KV * P, Hd)
+    np.testing.assert_array_equal(np.asarray(axis[:, 0]), np.asarray(flat))
+
+
+@pytest.mark.parametrize("Q,KV,P,Hd", sorted(POSITION_SHAPES) + [
+    (1, kv, group, hd) for kv, group, hd in CELL_HEADS])
+def test_the_form_a_shape_took_is_recorded(Q, KV, P, Hd):
+    """Static a shape, so a trace is all it takes: ``dispatch.selected()``
+    names the form. One position of MHA (OPT, OLMoE) and a group of 4 stay
+    on the block-diagonal query."""
+    want = POSITION_SHAPES.get((Q, KV, P, Hd), "block_diagonal")
+    sds = jax.ShapeDtypeStruct
+    pool = sds((9, 128, KV * Hd), jnp.bfloat16)
+    dispatch.reset()
+    out = jax.eval_shape(
+        paged_decode_attention, sds((2, Q, KV * P, Hd), jnp.bfloat16), pool,
+        pool, sds((2, 4), jnp.int32), sds((2,), jnp.int32))
+    assert out.shape == (2, Q, KV * P, Hd)
+    forms = {k: n for k, n in dispatch.selected().items()
+             if k.startswith("paged_decode_attention=")}
+    assert forms == {f"paged_decode_attention={want}": 1}, forms
 
 
 def test_forward_paged_matches_forward_cached():

@@ -173,19 +173,32 @@ def test_no_pool_sized_copy_compiled_for_v5e(name, one_v5e, monkeypatch):
     assert not moved, moved
 
 
-@pytest.mark.parametrize("B,H,KV,Hd,width,terms", [
-    (40, 32, 32, 64, 16, False),    # opt1b3_serve_*: one block a loop step
-    (64, 16, 16, 128, 32, False),   # olmoe1b7b_serve_decode
-    (8, 32, 8, 64, 6, True),        # GQA group of 4 with ALiBi and pad bias
-    (8, 12, 12, 64, 6, True),       # a 768-lane row: four blocks a loop step
+@pytest.mark.parametrize("B,Q,H,KV,Hd,width,terms,form", [
+    # opt1b3_serve_*: one block a loop step
+    (40, 1, 32, 32, 64, 16, False, "block_diagonal"),
+    # olmoe1b7b_serve_decode
+    (64, 1, 16, 16, 128, 32, False, "block_diagonal"),
+    # GQA group of 4 with ALiBi and pad bias
+    (8, 1, 32, 8, 64, 6, True, "block_diagonal"),
+    # a 768-lane row: four blocks a loop step
+    (8, 1, 12, 12, 64, 6, True, "block_diagonal"),
+    # sdar30b_serve_blockgen: a block's 4 positions, 32 query rows a kv head
+    (64, 4, 32, 4, 128, 8, False, "per_kv_head"),
+    # solaropen2_serve_decode's softmax layer: 8 query rows a kv head
+    (128, 1, 64, 8, 128, 16, False, "per_kv_head"),
+    # positions x a group of 2 with ALiBi and pad bias, either form
+    (8, 4, 8, 4, 128, 6, True, "per_kv_head"),
+    (8, 4, 4, 4, 64, 6, True, "block_diagonal"),
 ])
-def test_paged_kernel_compiles_for_v5e_at_real_widths(B, H, KV, Hd, width,
-                                                      terms, one_v5e,
+def test_paged_kernel_compiles_for_v5e_at_real_widths(B, Q, H, KV, Hd, width,
+                                                      terms, form, one_v5e,
                                                       monkeypatch):
     """Mosaic takes the streaming kernel at the cells' widths (what interpret
     mode cannot see: the block-diagonal query's half-tile lane offsets at
-    head size 64, the stacked bf16 product, the VMEM the buffers take), and
-    the pools reach it where they lie: the program holds no copy of one."""
+    head size 64, a kv head's lane slice of a block and its rows of the
+    stacked bf16 product, the VMEM the buffers take) in the form its shape
+    takes, and the pools reach it where they lie: the program holds no copy
+    of one."""
     from deepspeed_tpu.ops import dispatch
     from deepspeed_tpu.ops.pallas.paged_decode_attention import \
         paged_decode_attention
@@ -202,10 +215,12 @@ def test_paged_kernel_compiles_for_v5e_at_real_widths(B, H, KV, Hd, width,
             q, kp, vp, bt, pos, pad_bias=bias if terms else None,
             alibi_slopes=slopes if terms else None, interpret=False)
 
+    dispatch.reset()
     compiled = jax.jit(call).lower(
-        sds((B, H, Hd), jnp.bfloat16), pool, pool, sds((B, width), jnp.int32),
-        sds((B,), jnp.int32), sds((B, width * BS), jnp.float32),
-        sds((H,), jnp.float32)).compile()
+        sds((B, H, Hd) if Q == 1 else (B, Q, H, Hd), jnp.bfloat16), pool,
+        pool, sds((B, width), jnp.int32), sds((B,), jnp.int32),
+        sds((B, width * BS), jnp.float32), sds((H,), jnp.float32)).compile()
+    assert dispatch.selected()[f"paged_decode_attention={form}"] == 1
     text = compiled.as_text()
     assert "paged_decode_attention" in text
     assert compiled.memory_analysis().temp_size_in_bytes < blocks * BS * KV * Hd
@@ -301,8 +316,9 @@ def test_block_step_keeps_the_pools_in_place_for_v5e(one_v5e, monkeypatch):
     real widths (64 rows of 4 positions, 32 query and 4 key/value heads of
     128, d 2,048, 16 held experts of 768 of a router's 128; two layers of
     the 48), the decision included, compiled for a described v5e: Mosaic
-    takes the paged kernel at 128 QUERY ROWS a row over 512 lanes (one call
-    a layer, not one a position), the pools are aliased, the temporaries
+    takes the paged kernel with the block's 4 positions on its own axis, 32
+    query rows a kv head and the products a kv head (one call a layer, not
+    one a position), the pools are aliased, the temporaries
     stay under a quarter of them and no ``copy``, ``dynamic-slice`` or
     ``dynamic-update-slice`` of a layer's pool size exists. So does the
     block-causal prefill of 256 tokens through the flash kernel."""
@@ -345,6 +361,7 @@ def test_block_step_keeps_the_pools_in_place_for_v5e(one_v5e, monkeypatch):
             sds((), I32)).compile()}
     forms = dispatch.selected()
     assert forms["paged_block=paged_kernel"] == 1
+    assert forms["paged_decode_attention=per_kv_head"] == 1
     assert forms["paged_prefill=flash"] == 1
     pool_bytes = sum(int(np.prod(a.shape)) * 2 for a in jax.tree.leaves(pools))
     layer_pool = num_blocks * BS * cfg.kv_heads * cfg.head_dim

@@ -2,9 +2,9 @@
 under its shapes, in interpret mode on the CPU: ``flash_attention`` with the
 causal triangle a STAIRCASE of the generation block (the prefill of whole
 blocks) against its XLA twin ``mha_attention``, and the block step's
-attention, a row's B positions riding ``paged_decode_attention`` as B x (H /
-KV) query rows a kv head in one read of the row's KV, against the gather +
-einsum form on random block tables. The compiled forms are the ``-m tpu``
+attention, a row's B positions on ``paged_decode_attention``'s position axis
+(B x H / KV query rows a kv head) in one read of the row's KV, against the
+gather + einsum form on random block tables. The compiled forms are the ``-m tpu``
 tier's (``test_tpu_kernels.py``) and ``test_paged_pool_threading.py``'s.
 """
 
@@ -125,11 +125,13 @@ def _block_step(backend, seed=5, rows=5, blocks=24):
 
 
 def test_block_attention_rides_the_paged_kernel():
-    """4 positions x 2 heads a kv head as 8 query rows a kv head of ONE
-    kernel call a layer: the logits, the pools and the experts' counts of
-    the gather + einsum form."""
+    """4 positions x 2 heads a kv head on the position axis of ONE kernel
+    call a layer (8 query rows a kv head over HALF a lane tile at head size
+    64, so against the block-diagonal query): the logits, the pools and the
+    experts' counts of the gather + einsum form."""
     got, pools_k, counts_k, forms = _block_step("flash")
     assert forms.get("paged_block=paged_kernel") == 1, forms
+    assert forms.get("paged_decode_attention=block_diagonal") == 1, forms
     assert "kernel/paged_decode_attention=interpret" in forms
     want, pools_x, counts_x, forms_x = _block_step("xla")
     assert forms_x.get("paged_block=gather_einsum") == 1, forms_x

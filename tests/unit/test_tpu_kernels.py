@@ -389,6 +389,54 @@ def test_paged_decode_attention_compiles_and_matches(tpu, H, KV, Hd,
 
 
 @tpu_tier
+@pytest.mark.parametrize("Q,H,KV,Hd,rows,width", [
+    (4, 32, 4, 128, 64, 8),     # sdar30b_serve_blockgen: 32 rows a kv head
+    (1, 64, 8, 128, 16, 16),    # Solar-Open2's softmax layer: 8 rows
+    (4, 4, 2, 64, 8, 6),        # positions against the block-diagonal query
+])
+def test_paged_position_axis_compiles_and_matches(tpu, Q, H, KV, Hd, rows,
+                                                  width):
+    """The paged kernel's position axis COMPILED: the block-generation
+    cell's shape (64 rows of a block of 4 positions x 32 heads over 4 kv
+    heads of 128, 8 table entries) and both sides of the line between the
+    forms, bf16 pools, rows from one live block to the whole table and an
+    idle row, against the float32 reference. Mosaic sees what interpret
+    mode cannot: a kv head's lane slice of a block, the stacked terms'
+    tiles, the position-by-position rows of the output."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops import dispatch
+    from deepspeed_tpu.ops.pallas.paged_decode_attention import (
+        _per_kv_head, paged_decode_attention)
+
+    rng = np.random.default_rng(36)
+    bs, num_blocks = 128, rows * width + 1
+    pos = rng.integers(0, width * bs - Q, rows).astype(np.int32)
+    pos[:4] = [Q - 1, bs - 1, bs, width * bs - 1]
+    pos[-1] = 0
+    bt = (rng.permutation(num_blocks - 1) + 1).reshape(rows, width) \
+        .astype(np.int32)
+    for b in range(rows):       # dead tail entries may be anything in range
+        bt[b, pos[b] // bs + 1:] = rng.integers(0, num_blocks)
+    bt[-1] = 0                  # an idle row on the dummy block
+    q = jnp.asarray(rng.normal(size=(rows, Q, H, Hd)), jnp.bfloat16)
+    kp = jnp.asarray(rng.normal(size=(num_blocks, bs, KV * Hd)), jnp.bfloat16)
+    vp = jnp.asarray(rng.normal(size=(num_blocks, bs, KV * Hd)), jnp.bfloat16)
+
+    dispatch.reset()
+    out = paged_decode_attention(q, kp, vp, jnp.asarray(bt), jnp.asarray(pos),
+                                 interpret=False)
+    form = "per_kv_head" if _per_kv_head(Q * H // KV, Hd) else "block_diagonal"
+    assert dispatch.selected().get(f"paged_decode_attention={form}") == 1
+    ref = jax.vmap(lambda one: _paged_reference(
+        one, kp, vp, jnp.asarray(bt), jnp.asarray(pos)), 1, 1)(q)
+    assert out.shape == (rows, Q, H, Hd)
+    err = float(jnp.abs(out.astype(jnp.float32) - ref).max())
+    assert np.isfinite(err) and err < 0.05, err
+
+
+@tpu_tier
 def test_paged_matches_dense_decode_kernel(tpu):
     """Same cache content through both decode kernels: the paged kernel on a
     shuffled pool and ``decode_attention`` on the contiguous workspace give
@@ -503,9 +551,10 @@ def test_sdar_toy_logits_through_the_compiled_kernels(tpu):
     weights and matmuls in full float32, a 138-token prompt (remainder 2)
     prefilled through ``flash_attention`` with the staircase of 4 and 14
     tokens generated by denoise and commit passes whose 4 positions ride
-    ``paged_decode_attention`` as 8 query rows a kv head, every deciding
-    pass's logits against the plain reference's. The same replay on the
-    plain-XLA forms bounds what the chip's own float32 arithmetic leaves."""
+    ``paged_decode_attention``'s position axis (8 query rows a kv head),
+    every deciding pass's logits against the plain reference's. The same
+    replay on the plain-XLA forms bounds what the chip's own float32
+    arithmetic leaves."""
     import jax
 
     from deepspeed_tpu.ops import dispatch
