@@ -839,13 +839,21 @@ class InferenceEngine:
         return NamedSharding(self.mesh, P())
 
     def _state_slots(self) -> int:
-        """Rows of the state pools a model with recurrent layers needs (one
-        a running request and the dummy), 0 for a model without: read from
-        the model's cache spec, the one place what it keeps is declared."""
+        """Slots a model needs whose layers keep something a REQUEST beside
+        the shared KV pool, a recurrent state or a window layer's ring of
+        blocks (one a running request and the dummy), 0 for a model without:
+        read from the model's cache spec, the one place what it keeps is
+        declared."""
         spec = getattr(getattr(self.module, "config", None), "cache_spec",
                        None) or {}
         return int(self._config.serving.max_running) + 1 \
-            if spec.get("state") else 0
+            if spec.get("state") or spec.get("window") else 0
+
+    def _slot_kept(self) -> str:
+        """What the model keeps a slot, for a refusal's message."""
+        spec = self.module.config.cache_spec
+        return "a recurrent state" if spec.get("state") \
+            else "a window layer's ring of blocks"
 
     def _kv_host_pool_for(self, num_blocks: int, block_size: int,
                           caching: bool):
@@ -857,9 +865,10 @@ class InferenceEngine:
         kh = getattr(self._config.serving, "kv_host", None)
         if kh is not None and kh.enabled and self._state_slots():
             raise ValueError(
-                "serving.kv_host is on but the model keeps a recurrent "
-                "state beside its KV: a block on the host says nothing of "
-                "the state at its end (no state snapshot is built)")
+                f"serving.kv_host is on but the model keeps {self._slot_kept()} "
+                "beside its KV: a block on the host says nothing of the "
+                "state at its end or of what the ring held there (no state "
+                "snapshot is built)")
         if kh is None or not kh.enabled or not caching:
             return None
         if str(kh.spill) not in ("auto", "off"):
@@ -951,8 +960,12 @@ class InferenceEngine:
     @staticmethod
     def _bucket(n: int, cap: int) -> int:
         """Pad prompt lengths up to multiples of 128 (one compile per bucket,
-        MXU-aligned), clamped to the model's max."""
-        return min(-(-max(n, 1) // 128) * 128, cap)
+        MXU-aligned) as far as 2,048 and to multiples of 1,024 beyond (a
+        long prompt's padding is a small share of it, and a program a 128
+        tokens would be 128 programs for a 16k context), clamped to the
+        model's max."""
+        step = 128 if n <= 2048 else 1024
+        return min(-(-max(n, 1) // step) * step, cap)
 
     def _generate_cached(self, input_ids, max_new, temperature, top_k, rng, eos_token_id):
         if max_new <= 0:
@@ -1072,7 +1085,7 @@ class InferenceEngine:
         pw = getattr(self, "_paged_workspace", None)
         slots = self._state_slots()
         if pw is not None and pw[0] == num_blocks and pw[1] == block_size \
-                and (not slots or pw[2]["state"][0].shape[1] == slots):
+                and pw[3] == slots:
             leaves = jax.tree.leaves(pw[2])
             if not any(getattr(a, "is_deleted", lambda: False)() for a in leaves):
                 return pw[2], True
@@ -1081,7 +1094,7 @@ class InferenceEngine:
             **({"state_slots": slots} if slots else {}))
         kv_sh = self._kv_head_sharding()
         pools = jax.tree.map(lambda a: jax.device_put(a, kv_sh), pools)
-        self._paged_workspace = (num_blocks, block_size, pools)
+        self._paged_workspace = (num_blocks, block_size, pools, slots)
         return pools, False
 
     def _paged_allocator(self, num_blocks: int, block_size: int,
@@ -1428,21 +1441,22 @@ class InferenceEngine:
         if chunk_tokens < 0:
             raise ValueError("serving.prefill_chunk_tokens must be >= 0")
         chunk_ok = hasattr(self.module, "forward_paged_prefill_chunk")
-        # what cannot hold beside a recurrent state yet, each refused from
-        # the model's cache spec
+        # what cannot hold beside a recurrent state or a window layer's
+        # ring yet, each refused from the model's cache spec
         stateful = bool(self._state_slots())
         if stateful and pc_mode == "on":
             raise ValueError(
-                "serving.prefix_caching='on' but the model keeps a "
-                "recurrent state beside its KV: a cached block says nothing "
-                "of the state at its end (snapshots at block boundaries "
-                "are not built)")
+                f"serving.prefix_caching='on' but the model keeps "
+                f"{self._slot_kept()} beside its KV: a cached block says "
+                "nothing of the state at its end, or of the window before "
+                "it (snapshots at block boundaries are not built)")
         if stateful and str(srv.speculative.mode) != "off":
             raise ValueError(
                 f"serving.speculative.mode={str(srv.speculative.mode)!r} "
-                "but the model keeps a recurrent state: a verify window "
-                "rewinds to the last accepted position and a state cannot "
-                "be rewound (no snapshot is kept)")
+                f"but the model keeps {self._slot_kept()}: a verify window "
+                "rewinds to the last accepted position, and a state or a "
+                "ring that has been written over cannot be rewound (no "
+                "snapshot is kept)")
         # how the model generates: its config's record, read as the cache
         # spec is (None: a token a step)
         gen = getattr(cfg, "generation", None)
@@ -1455,9 +1469,16 @@ class InferenceEngine:
                 "but the model generates by diffusion over blocks: a pass "
                 "already carries a block of positions a row, and a draft's "
                 "next-token window has no meaning under its mask")
+        if chunk_tokens and stateful and self.module.config.cache_spec.get("window"):
+            raise ValueError(
+                "serving.prefill_chunk_tokens set but the model keeps a "
+                "window layer's ring of blocks: a chunk longer than a block "
+                "writes over ring positions its first queries still read "
+                "(no form reads the ring beside the chunk's own keys)")
         if stateful and self.mesh.shape.get("tp", 1) > 1:
-            raise ValueError("serving.tp > 1 but the model keeps a recurrent "
-                             "state: its state pools are not sharded")
+            raise ValueError(f"serving.tp > 1 but the model keeps "
+                             f"{self._slot_kept()}: the pools of its slots "
+                             "are not sharded")
         if not chunk_ok:
             if pc_mode == "on":
                 raise ValueError(
@@ -1677,9 +1698,13 @@ class _ServeSession:
         self.retain_finished = retain_finished
         self._finished_seen = 0
         self._closed = False
-        # a model that keeps a recurrent state takes the requests' state
-        # slots as each program's last operand
+        # a model that keeps a recurrent state or a window layer's ring
+        # takes the requests' slots as each program's last operand
         self._stateful = "state" in pools
+        self._slotted = self._stateful or "wk" in pools
+        # a window layer's reach (positions); 0: none
+        self._window = int(engine.module.config.attn_window) \
+            if "wk" in pools else 0
         self._flight: Optional[_Launched] = None
         # the newest sampled tokens at the decode width, on the device:
         # what a decode step's feed gathers from (the step in flight's, if any)
@@ -1963,11 +1988,11 @@ class _ServeSession:
         the session has no spill hook / host tier)."""
         if self._closed:
             raise RuntimeError("serving session is closed")
-        if self._stateful:
+        if self._slotted:
             raise NotImplementedError(
                 "a prefill->decode handoff moves KV blocks through the host "
-                "tier, and this model keeps a recurrent state beside them "
-                "that no block holds")
+                "tier, and this model keeps a recurrent state or a window "
+                "layer's ring beside them that no block holds")
         if not self._kv_spill:
             return 0
         self.land()
@@ -2202,7 +2227,7 @@ class _ServeSession:
 
     def _state_of(self, req):
         """The trailing operand of a stateful model's prefill programs."""
-        return (np.int32(req.state_slot),) if self._stateful else ()
+        return (np.int32(req.state_slot),) if self._slotted else ()
 
     def _prefill_inputs(self, reqs):
         # generation by blocks prefills the prefix's whole generation
@@ -2257,8 +2282,10 @@ class _ServeSession:
             tel.decode_live_kv_blocks.inc(int((pos // self.bs + 1).sum()))
             if self._stateful:
                 tel.count_state(len(reqs))
+            if self._window:
+                tel.count_window(pos, len(reqs), self._window, self.bs)
         state = ()
-        if self._stateful:
+        if self._slotted:
             slots = np.zeros((self.W,), np.int32)           # zeros → dummy
             slots[:len(reqs)] = [r.state_slot for r in reqs]
             state = (slots,)
@@ -2442,7 +2469,8 @@ class _ServeSession:
             # decode workspace are the serving memory story)
             from deepspeed_tpu.monitor.health import sample_memory_gauges
             sample_memory_gauges(engine._tel_reg)
-        engine._paged_workspace = (self.num_blocks, self.bs, self.pools)
+        engine._paged_workspace = (self.num_blocks, self.bs, self.pools,
+                                   engine._state_slots())
 
 
 class _ActionKind(NamedTuple):

@@ -389,6 +389,23 @@ class ServingTelemetry:
             "updates: over decode_steps, the states a step reads and writes"
         ).inc(rows)
 
+    def count_window(self, pos, rows: int, window: int, bs: int) -> None:
+        """One fused decode step of a model with window layers: ``pos``
+        [W] the step's rows' depths (idle rows 0), the first ``rows`` live.
+        Not pre-created: a model without a window has none of these."""
+        c = self.registry.counter
+        first = np.maximum(pos - (window - 1), 0) // bs
+        c("serving/decode_live_window_kv_tokens",
+          "per fused decode step, the sum over its live rows of min(pos + 1, "
+          "window): the KV tokens a WINDOW layer's decode step really reads "
+          "(decode_live_kv_tokens is what a full layer reads)"
+          ).inc(int(np.minimum(pos[:rows] + 1, window).sum()))
+        c("serving/decode_live_window_kv_blocks",
+          "per fused decode step, the sum over ALL its rows (idle ones read "
+          "the dummy block) of the blocks from the window's first to the "
+          "row's newest: the block copies the paged kernel issues a window "
+          "layer and pool").inc(int((pos // bs + 1 - first).sum()))
+
     def count_state_reset(self) -> None:
         self.registry.counter(
             "serving/state_slot_resets",

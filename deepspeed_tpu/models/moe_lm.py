@@ -60,7 +60,8 @@ class MoEConfig:
     # None = expert_ff_mult * d_model
     expert_d_ff: Optional[int] = None
     # "gelu": gelu(x W_up + b) W_down + b; "swiglu": the gated SiLU expert
-    # W_down(silu(x W_gate) * (x W_up)), no bias anywhere
+    # W_down(silu(x W_gate) * (x W_up)), no bias anywhere; "reglu": the same
+    # with a ReLU gate, W_down(relu(x W_gate) * (x W_up))
     expert_activation: str = "gelu"
     # "capacity": the GShard one-hot dispatch above (k 1 or 2, tokens over an
     # expert's capacity dropped, ep-sharded); "nodrop": softmax, then the k
@@ -72,6 +73,11 @@ class MoEConfig:
     # "softmax" over them, or "sigmoid" of each logit with a learned bias
     # ``b_select`` added for the choice alone (DeepSeek-V3 / GLM-4-MoE)
     scoring: str = "softmax"
+    # nodrop: what the router reads. "mlp_input": what the experts read (the
+    # post-attention norm's output); "mixer_input": what the block's mixer
+    # read (the first norm's output: a router placed before attention, whose
+    # choice does not wait for the attention)
+    router_input: str = "mlp_input"
     # THE CHIP'S SHARE of an expert-parallel layer (nodrop): the router
     # scores ``router_experts`` experts (None = num_experts: all are here),
     # of which this model HOLDS the ``num_experts`` from ``expert_offset``
@@ -102,8 +108,12 @@ class MoECausalLM:
         if moe_config.dispatch not in ("capacity", "nodrop"):
             raise ValueError(f"MoEConfig.dispatch={moe_config.dispatch!r} "
                              "(expected capacity|nodrop)")
-        if moe_config.expert_activation not in ("gelu", "swiglu"):
-            raise ValueError("MoEConfig.expert_activation is gelu or swiglu")
+        if moe_config.expert_activation not in ("gelu", "swiglu", "reglu"):
+            raise ValueError("MoEConfig.expert_activation is gelu, swiglu "
+                             "or reglu")
+        if moe_config.router_input not in ("mlp_input", "mixer_input"):
+            raise ValueError("MoEConfig.router_input is mlp_input or "
+                             "mixer_input")
         if moe_config.dispatch == "capacity" and moe_config.k not in (1, 2):
             raise ValueError("the capacity dispatch routes top-1 or top-2; "
                              "k > 2 needs dispatch='nodrop'")
@@ -113,10 +123,12 @@ class MoECausalLM:
                              "noisy gate")
         share = (moe_config.router_experts is not None
                  or moe_config.expert_offset or moe_config.shared_expert_d_ff
-                 or moe_config.scoring != "softmax")
+                 or moe_config.scoring != "softmax"
+                 or moe_config.router_input != "mlp_input")
         if share and moe_config.dispatch != "nodrop":
-            raise ValueError("a share of the experts, a shared expert and "
-                             "sigmoid scoring need dispatch='nodrop'")
+            raise ValueError("a share of the experts, a shared expert, "
+                             "sigmoid scoring and a router that reads the "
+                             "mixer's input need dispatch='nodrop'")
         if moe_config.expert_offset + moe_config.num_experts > self.router_width:
             raise ValueError(
                 f"experts {moe_config.expert_offset}.."
@@ -129,7 +141,7 @@ class MoECausalLM:
 
     @property
     def _gated(self) -> bool:
-        return self.moe.expert_activation == "swiglu"
+        return self.moe.expert_activation in ("swiglu", "reglu")
 
     @property
     def router_width(self) -> int:
@@ -220,12 +232,14 @@ class MoECausalLM:
 
     def _act(self, up, gate=None):
         """``up`` with its bias already on; ``gate`` for the gated expert."""
+        if self.moe.expert_activation == "reglu":
+            return jax.nn.relu(gate) * up
         if self._gated:
             return jax.nn.silu(gate) * up
         return jax.nn.gelu(up, approximate=True)
 
     def _moe_mlp(self, lp, x, rng, train: bool, used_token=None,
-                 with_owed: bool = False):
+                 with_owed: bool = False, mixer_in=None):
         """x [B,S,D] → ([B,S,D], l_aux, counts) via top-k expert routing.
         ``used_token`` [B*S] 1/0 keeps masked tokens away from the experts
         (nodrop: any k; capacity: top-1 only, the reference's top-2 gate has
@@ -233,9 +247,13 @@ class MoECausalLM:
         HELD HERE computed for rows that are ``used_token`` (what the decode
         program hands the engine's ``serving/moe_*`` counters).
         ``with_owed``: fourth, the assignments the layer owed (those of
-        ``used_token`` rows to experts held here)."""
+        ``used_token`` rows to experts held here). ``mixer_in`` [B,S,D]:
+        what the block's mixer read, which the router reads in x's place
+        under ``router_input="mixer_input"``."""
         if self.moe.dispatch == "nodrop":
-            out = self._nodrop_mlp(lp, x, used_token)
+            out = self._nodrop_mlp(
+                lp, x, used_token,
+                mixer_in if self.moe.router_input == "mixer_input" else None)
         else:
             used = x.shape[0] * x.shape[1] if used_token is None \
                 else jnp.sum(used_token > 0, dtype=jnp.int32)
@@ -266,7 +284,7 @@ class MoECausalLM:
                                 local, moe.num_experts)
         return weights, experts, probs
 
-    def _nodrop_mlp(self, lp, x, valid=None):
+    def _nodrop_mlp(self, lp, x, valid=None, route_x=None):
         """A score an expert in float32 (softmax, or sigmoid with a
         selection bias), the k largest as they are, every assignment to an
         expert held here computed: every held expert over every row for a
@@ -274,13 +292,15 @@ class MoECausalLM:
         into ragged groups (``jax.lax.ragged_dot``) from there on; then the
         shared expert, if the model has one. Scopes ``router`` /
         ``moe_dispatch`` / ``experts`` / ``shared_expert`` name the parts in
-        a device trace. Returns (out, l_aux, counts [E], owed)."""
+        a device trace. ``route_x``: what the router reads where that is
+        not ``x``. Returns (out, l_aux, counts [E], owed)."""
         moe = self.moe
         B, S, D = x.shape
         E = moe.num_experts
         tokens = x.reshape(-1, D)
         with jax.named_scope("router"):
-            weights, experts, probs = self._route(lp, tokens)
+            weights, experts, probs = self._route(
+                lp, tokens if route_x is None else route_x.reshape(-1, D))
         if self.router_width == E:
             owed = (tokens.shape[0] if valid is None
                     else jnp.sum(valid, dtype=jnp.int32)) * moe.k
@@ -387,9 +407,11 @@ class MoECausalLM:
                 k_route, ka, km = jax.random.split(rng, 3)
             else:
                 k_route = rng
-        a = T.attention(cfg, T._norm(cfg, x, lp["ln_attn"]), lp["attn"], positions, mask_bias)
+        xa = T._norm(cfg, x, lp["ln_attn"])
+        a = T.attention(cfg, xa, lp["attn"], positions, mask_bias)
         x = x + T._dropout(cfg, a, ka)
-        m, l_aux, _ = self._moe_mlp(lp["mlp"], T._norm(cfg, x, lp["ln_mlp"]), k_route, train)
+        m, l_aux, _ = self._moe_mlp(lp["mlp"], T._norm(cfg, x, lp["ln_mlp"]),
+                                    k_route, train, mixer_in=xa)
         return x + T._dropout(cfg, m, km), l_aux
 
     def forward(self, params, tokens, attn_mask=None, rng=None, train: bool = True):
@@ -444,9 +466,9 @@ class MoECausalLM:
         per-forward capacity semantics."""
         used = None if valid is None else valid.reshape(-1)
 
-        def moe_mlp_fn(cfg, x_normed, lp):
+        def moe_mlp_fn(cfg, x_normed, lp, mixer_in):
             return self._moe_mlp(lp["mlp"], x_normed, None, train=False,
-                                 used_token=used)[0]
+                                 used_token=used, mixer_in=mixer_in)[0]
 
         return T.forward_cached(self.config, params, tokens, cache, pos,
                                 pad_bias, mlp_fn=moe_mlp_fn)
@@ -468,11 +490,11 @@ class MoECausalLM:
         layer owed (real rows x k)."""
         used = T.paged_real_rows(pools, slots).reshape(-1)
 
-        def mlp_fn(cfg, x_normed, lp):
+        def mlp_fn(cfg, x_normed, lp, mixer_in):
             with jax.named_scope("mlp"):
                 out, _, n, owed = self._moe_mlp(
                     lp["mlp"], x_normed, None, train=False, used_token=used,
-                    with_owed=True)
+                    with_owed=True, mixer_in=mixer_in)
             if not counts:
                 return out
             return out, jnp.append(n, owed)

@@ -238,6 +238,68 @@ def sdar(size: str = "30b-a3b-ep8", share: int = 0, rule: str = "sequential",
         param_dtype=param_dtype)
 
 
+def smallthinker(size: str = "21b-a3b-12l", **over):
+    """SmallThinker-21BA3B-Instruct (``PowerInfer/SmallThinker-21BA3B-
+    Instruct`` config.json): 52 pre-RMSNorm layers (eps 1e-6) of d 2,560 in
+    periods of four (``sliding_window_layout`` and ``rope_layout`` both
+    0,1,1,1): a FULL attention layer WITHOUT positions, then three layers
+    with a WINDOW of 4,096 and rope (theta 1,500,000, the half-split
+    pairing); GQA of 28 query and 4 key/value heads of 128, no bias, no q/k
+    norm. In every layer 64 ReLU-gated experts of width 768, 6 a token by
+    a softmax over the 6 chosen logits, no shared expert, and the ROUTER
+    READS THE ATTENTION'S INPUT (the first norm's output), the experts the
+    post-attention norm's; an untied head over 151,936 rows. The config
+    says what the serving path keeps: ``cache_spec`` counts the window
+    layers apart, whose KV lives in a ring a running request.
+    ``21b-a3b-12l`` is ONE PIPELINE STAGE of four over a v5e-4, cut from
+    its 13 layers to three whole periods, with every expert, every head and
+    the whole vocabulary (perfbench's ``smallthinker21b_serve_longctx``).
+    Its seeded init makes the attention PEAKED, as a trained model's is:
+    every matrix at std 0.045 (output projections depth-scaled), so that a
+    head's scores have a standard deviation of ~5 and, of the 4,096-6,146
+    keys a query sees, about 4 hold the mass (the largest weight ~0.4). At
+    the default 0.02 the scores' deviation is 1.0, a head averages over
+    ~1,400-2,100 keys, its output is 0.007 beside the MoE's 0.037, and a
+    served token says nothing of the window, the ring or the positions.
+    The token embedding is drawn at 8.0, a little over the RMS of the
+    residual stream at the end of the 52-layer stack under this init (a
+    layer adds a mixer's 0.73 and an MoE's 0.55: 0.92 sqrt(52) = 6.6): a
+    peaked softmax multiplies what bf16 rounding its input carries, and
+    over a small embedding the stage's layers feed that to one another
+    (sound served tokens then fail the benchmark's check); 8.0 is where
+    they read lowest. Chosen by measurement: PERF.md section 6, PR 37.
+    ``tiny`` keeps every kind of part at toy widths with a window of 256
+    (two blocks of 128) under a ``max_seq`` of 1,024, IN THE SAME REGIME a
+    little lower: its matrices at 0.25 (q and k components of deviation
+    2.0, its scores' 4) and its embedding at 4.0, because with 2 of 8
+    experts a token one boundary choice of its router weighs five times
+    the cut's and at the cut's numbers its own rehearsal fails sound."""
+    from deepspeed_tpu.models.moe_lm import MoECausalLM, MoEConfig
+    dims, moe = {
+        "tiny": (dict(n_layer=4, n_head=4, n_kv_head=2, head_size=32,
+                      d_model=64, d_ff=32, vocab_size=512, max_seq=1024,
+                      attn_window=256, init_std=0.25, embed_init_std=4.0),
+                 dict(num_experts=8, k=2, expert_d_ff=32)),
+        "21b-a3b-12l": (dict(n_layer=12, n_head=28, n_kv_head=4,
+                             head_size=128, d_model=2560, d_ff=768,
+                             vocab_size=151936, max_seq=16384,
+                             attn_window=4096, init_std=0.045,
+                             embed_init_std=8.0),
+                        dict(num_experts=64, k=6, expert_d_ff=768)),
+    }[size]
+    param_dtype = over.pop("param_dtype", jnp.float32)
+    moe = {**moe, **over.pop("moe", {})}
+    cfg = TransformerConfig(
+        pos_embedding="rope", rope_theta=1.5e6, norm="rmsnorm", norm_eps=1e-6,
+        activation="swiglu", tie_embeddings=False, attn_bias=False,
+        layer_kinds=("attention",) + ("window_attention",) * 3,
+        rope_kinds=("window_attention",), **{**dims, **over})
+    return MoECausalLM(cfg, MoEConfig(**{**dict(
+        dispatch="nodrop", expert_activation="reglu", scoring="softmax",
+        norm_topk_prob=True, aux_loss_coef=0.0,
+        router_input="mixer_input"), **moe}), param_dtype=param_dtype)
+
+
 MODEL_PRESETS: Dict[str, Callable] = {
     "gpt2": gpt2,
     "llama": llama,
@@ -247,6 +309,7 @@ MODEL_PRESETS: Dict[str, Callable] = {
     "olmoe": olmoe,
     "solar_open2": solar_open2,
     "sdar": sdar,
+    "smallthinker": smallthinker,
 }
 
 

@@ -30,6 +30,8 @@ from deepspeed_tpu.ops import dispatch
 
 
 ATTENTION, LINEAR_ATTENTION = "attention", "linear_attention"
+#: softmax attention over the last ``attn_window`` positions only
+WINDOW_ATTENTION = "window_attention"
 
 
 #: the unmasking rules of a block-diffusion model's generation
@@ -113,10 +115,19 @@ class TransformerConfig:
     # ONE period of the stack's layer pattern, the kinds of its mixers in
     # order ("attention": softmax attention over the KV cache;
     # "linear_attention": the gated delta rule with a recurrent state, the
-    # lin_* sizes below); the stack is n_layer / len(layer_kinds) periods.
+    # lin_* sizes below; "window_attention": softmax attention whose query
+    # at i sees keys i - attn_window < j <= i, the attention layer's
+    # parameters); the stack is n_layer / len(layer_kinds) periods.
     # None: every layer is "attention". A kind says what cache it keeps
-    # (``cache_spec``): KV blocks, or a state slot a request.
+    # (``cache_spec``): KV blocks, a state slot a request, or a ring of KV
+    # blocks a request that holds the window and no more.
     layer_kinds: Optional[Tuple[str, ...]] = None
+    # keys a window_attention layer's query sees, its own among them
+    attn_window: int = 0
+    # the kinds whose q and k take ``pos_embedding`` where not every
+    # attention kind does (rope on the window layers, none on the full
+    # ones); None: all of them
+    rope_kinds: Optional[Tuple[str, ...]] = None
     # the linear-attention (KDA) mixer: heads and their key = value width
     # (its two factored gate projections have rank lin_head_dim)
     lin_heads: int = 0
@@ -224,11 +235,23 @@ class TransformerConfig:
     def cache_spec(self) -> Dict[str, int]:
         """What the stack keeps between steps, by cache: ``kv`` the layers
         that hold KV blocks, ``state`` those that hold a recurrent state
-        and a conv state in a slot a request. What cannot hold with a
-        state yet (a prefix hit, a verify window's rewind, a block moved
-        to the host) is refused from here, not by an option."""
+        and a conv state in a slot a request, ``window`` those that hold
+        the last ``attn_window`` positions' KV in a ring of blocks a
+        request. What cannot hold with a state or a ring yet (a prefix
+        hit, a verify window's rewind, a block moved to the host) is
+        refused from here, not by an option."""
         return {"kv": self.layers_of(ATTENTION),
-                "state": self.layers_of(LINEAR_ATTENTION)}
+                "state": self.layers_of(LINEAR_ATTENTION),
+                "window": self.layers_of(WINDOW_ATTENTION)}
+
+    def ring_blocks(self, block_size: int) -> int:
+        """Blocks of a request's ring in a window layer: the window's, and
+        one more for the block being written."""
+        return -(-self.attn_window // block_size) + 1
+
+    def takes_rope(self, kind: str) -> bool:
+        return self.pos_embedding == "rope" and (
+            self.rope_kinds is None or kind in self.rope_kinds)
 
     @property
     def kv_heads(self) -> int:
@@ -250,10 +273,13 @@ class TransformerConfig:
 
 def _check_pattern(cfg: TransformerConfig):
     kinds = cfg.period
-    bad = [k for k in kinds if k not in (ATTENTION, LINEAR_ATTENTION)]
+    known = (ATTENTION, LINEAR_ATTENTION, WINDOW_ATTENTION)
+    bad = [k for k in kinds if k not in known]
     if bad:
         raise ValueError(f"layer_kinds {kinds}: unknown kind {bad[0]!r} "
-                         f"(expected {ATTENTION}|{LINEAR_ATTENTION})")
+                         f"(expected {'|'.join(known)})")
+    if WINDOW_ATTENTION in kinds and cfg.attn_window < 1:
+        raise ValueError("a window_attention layer needs attn_window")
     if cfg.n_layer % len(kinds):
         raise ValueError(f"n_layer {cfg.n_layer} is not whole periods of "
                          f"the layer pattern {kinds}")
@@ -266,7 +292,7 @@ def _layer_group(cfg: TransformerConfig, kind: str, n: int, ks, dtype):
     """The stacked parameters of ``n`` like layers of ``kind`` (leading dim
     ``n``, what ``lax.scan`` runs one compiled block over) from the keys
     ``ks``: the mixer under ``attn`` or ``lin``, its norm, the dense MLP and
-    its norm."""
+    its norm (a window layer's are an attention layer's)."""
     std = cfg.init_std
     D, F = cfg.d_model, cfg.ff_dim
     H, KV, Hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
@@ -283,7 +309,7 @@ def _layer_group(cfg: TransformerConfig, kind: str, n: int, ks, dtype):
         return (jax.random.normal(key, shape) * scale).astype(dtype)
 
     group = {"ln_attn": norm_params()}
-    if kind == ATTENTION:
+    if kind != LINEAR_ATTENTION:
         group["attn"] = {
             "wq": dense(ks[0], (n, D, H * Hd)),
             "wk": dense(ks[1], (n, D, KV * Hd)),
@@ -1148,11 +1174,13 @@ def _attn_out(cfg: TransformerConfig, out, x, lp):
     return out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
 
 
-def _qkv_project(cfg: TransformerConfig, x, lp, positions):
+def _qkv_project(cfg: TransformerConfig, x, lp, positions,
+                 kind: str = ATTENTION):
     """Shared decode-side q/k/v projection: act-quant (QAT parity with the
     training path — or prefill/decode logits diverge from forward()),
     optional attn biases (attn_bias=True REQUIRES all four bias tensors —
-    loud KeyError on a params tree saved without them), head reshape, rope.
+    loud KeyError on a params tree saved without them), head reshape, rope
+    where the layer's ``kind`` takes it.
     Returns (q [B,T,H,Hd], k [B,T,KV,Hd], v [B,T,KV,Hd])."""
     B, T, D = x.shape
     H, KV, Hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
@@ -1164,20 +1192,23 @@ def _qkv_project(cfg: TransformerConfig, x, lp, positions):
     q = q.reshape(B, T, H, Hd)
     k = k.reshape(B, T, KV, Hd)
     v = (x @ _w(lp["wv"], x) + bv).reshape(B, T, KV, Hd)
-    if cfg.pos_embedding == "rope":
+    if cfg.takes_rope(kind):
         q = _rope(q, positions, cfg.rope_theta, cfg.rope_dim, cfg.rope_interleaved)
         k = _rope(k, positions, cfg.rope_theta, cfg.rope_dim, cfg.rope_interleaved)
     return q, k, v
 
 
 def _grouped_cache_einsum(cfg: TransformerConfig, q, ck, cv, positions,
-                          pad_bias):
+                          pad_bias, kpos=None, window: int = 0):
     """Grouped-head einsum of q [B,T,H,Hd] against an UNREPEATED cache
     ck/cv [B,S,KV,Hd] with per-row causal masking at ``positions`` (query
     heads reshaped [KV, G]: head h reads kv head h // G, matching the
     kernels' index maps — off-kernel decode skips the H/KV× cache copy).
     The single masked-softmax core shared by the dense-workspace and paged
-    fallback paths. Returns [B, T, H*Hd]."""
+    fallback paths. ``kpos`` [B, S]: the keys' positions where they are not
+    ``arange(S)`` (a window layer's ring; negative = holds nothing);
+    ``window`` > 0: a query at i sees i - window < j <= i.
+    Returns [B, T, H*Hd]."""
     B, T, H, Hd = q.shape
     S, KV = ck.shape[1], ck.shape[2]
     G = H // KV
@@ -1185,12 +1216,18 @@ def _grouped_cache_einsum(cfg: TransformerConfig, q, ck, cv, positions,
     q5 = q.reshape(B, T, KV, G, Hd)
     scores = jnp.einsum("btcgd,bscd->bcgts", q5, ck,
                         preferred_element_type=jnp.float32) * scale
-    kpos = jnp.arange(S, dtype=jnp.int32)[None, None, None, None, :]  # [1,1,1,1,S]
+    ring = kpos is not None
+    kpos = kpos[:, None, None, None, :] if ring else \
+        jnp.arange(S, dtype=jnp.int32)[None, None, None, None, :]     # [1,1,1,1,S]
     qpos = positions[:, None, None, :, None]                          # [B,1,1,T,1]
     valid = kpos <= qpos                                              # causal + cache bound
     if cfg.causal_block > 1:
         # block-causal: everything of the query's own block, and before it
         valid = kpos // cfg.causal_block <= qpos // cfg.causal_block
+    if ring:
+        valid = valid & (kpos >= 0)
+    if window:
+        valid = valid & (kpos > qpos - window)
     if cfg.pos_embedding == "alibi":
         slopes5 = _alibi_slopes(H).reshape(KV, G)
         scores = scores + slopes5[None, :, :, None, None] * (kpos - qpos).astype(jnp.float32)
@@ -1282,17 +1319,19 @@ def _decode_block(cfg: TransformerConfig, h, lp, mix_fn, mlp_fn=None,
     workspace via :func:`cached_block`, paged prefill and paged decode):
     ``mix_fn(x_normed)`` returns (mixer_out, *the mixer's new caches): k and
     v of an attention layer, state and conv state of a linear-attention
-    one, under ``scope``; ``mlp_fn(cfg, x_normed, lp)`` overrides the dense
-    MLP (MoE). Returns (h, *new caches)."""
+    one, under ``scope``; ``mlp_fn(cfg, x_normed, lp, mixer_in)`` overrides
+    the dense MLP (MoE; ``mixer_in`` is what the mixer read, for a router
+    placed before attention). Returns (h, *new caches)."""
     mfn = mlp_fn if mlp_fn is not None else (
-        lambda c, xx, lpp: mlp(c, xx, lpp["mlp"]))
+        lambda c, xx, lpp, _: mlp(c, xx, lpp["mlp"]))
+    xa = _norm(cfg, h, lp["ln_attn"])
     with jax.named_scope(scope):
-        a, *caches = mix_fn(_norm(cfg, h, lp["ln_attn"]))
+        a, *caches = mix_fn(xa)
     if cfg.parallel_residual:
-        m = mfn(cfg, _norm(cfg, h, lp["ln_mlp"]), lp)
+        m = mfn(cfg, _norm(cfg, h, lp["ln_mlp"]), lp, xa)
         return (h + a + m, *caches)
     h = h + a
-    m = mfn(cfg, _norm(cfg, h, lp["ln_mlp"]), lp)
+    m = mfn(cfg, _norm(cfg, h, lp["ln_mlp"]), lp, xa)
     return (h + m, *caches)
 
 
@@ -1301,8 +1340,8 @@ def cached_block(cfg: TransformerConfig, h, lp, ck, cv, positions, pos,
     """ONE layer of the KV-cache path: pre-LN attention against + append to
     the layer's cache. Shared by the compiled scan in :func:`forward_cached`
     and ZeRO-Inference weight streaming (per-layer host→device loop,
-    ``inference/engine.py``). ``mlp_fn(cfg, x_normed, lp)`` overrides the
-    dense MLP (the MoE zoo passes its routed experts)."""
+    ``inference/engine.py``). ``mlp_fn(cfg, x_normed, lp, mixer_in)``
+    overrides the dense MLP (the MoE zoo passes its routed experts)."""
     return _decode_block(
         cfg, h, lp,
         lambda xn: _cached_attention(cfg, xn, lp["attn"], positions, pos,
@@ -1636,15 +1675,30 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
     would be constants, XLA would fold a later layer's read through the
     earlier layer's update back to the buffer as it came in, and a buffer
     read after it is written cannot be updated in place: the whole pool
-    was copied in and out, twice its size a step.) This is the one home of
-    the shapes."""
+    was copied in and out, twice its size a step.) Window layers: ``wk``
+    and ``wv`` [window layers, (state_slots - 1) * R + 1, block_size,
+    kv_heads * Hd], R = ``cfg.ring_blocks(block_size)``: the request in
+    slot ``s`` holds blocks ``1 + (s - 1) R .. s R`` of every window layer
+    as a RING (position ``p`` in its block ``(p // block_size) % R``), and
+    block 0 is their dummy. The ring comes and goes with the slot: no
+    table changes while a request runs, and the window pools cannot run
+    dry (admission reads the full layers' pool alone). This is the one
+    home of the shapes."""
     spec = cfg.cache_spec
-    shape = (spec["kv"], num_blocks, block_size, cfg.kv_heads * cfg.head_dim)
+    row = cfg.kv_heads * cfg.head_dim
+    shape = (spec["kv"], num_blocks, block_size, row)
     pools = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    if (spec["state"] or spec["window"]) and state_slots < 2:
+        raise ValueError("a stack with recurrent state or window layers "
+                         "needs state_slots >= 2 (the dummy and one a "
+                         "running request)")
+    if spec["window"]:
+        wshape = (spec["window"],
+                  (state_slots - 1) * cfg.ring_blocks(block_size) + 1,
+                  block_size, row)
+        pools["wk"] = jnp.zeros(wshape, dtype)
+        pools["wv"] = jnp.zeros(wshape, dtype)
     if spec["state"]:
-        if state_slots < 2:
-            raise ValueError("a stack with recurrent state needs state_slots "
-                             ">= 2 (the dummy and one a running request)")
         H, dk, K = cfg.lin_heads, cfg.lin_head_dim, KDA_CONV_KERNEL
         n_lin, P_ = cfg.period.count(LINEAR_ATTENTION), cfg.n_periods
         pools["state"] = tuple(
@@ -1674,8 +1728,43 @@ def _paged_gather(pool, block_tables, kv_heads: int):
     return pool[block_tables].reshape(B, -1, kv_heads, F // kv_heads)
 
 
+def ring_tables(slots, R: int):
+    """The window layers' block tables [B, R] of the requests in ``slots``
+    [B]: slot ``s`` holds blocks ``1 + (s - 1) R .. s R``, slot 0 (an idle
+    row) the dummy block in every entry."""
+    slots = jnp.asarray(slots, jnp.int32).reshape(-1, 1)
+    own = 1 + (slots - 1) * R + jnp.arange(R, dtype=jnp.int32)[None, :]
+    return jnp.where(slots > 0, own, 0)
+
+
+def _ring_kpos(last, R: int, bs: int):
+    """The positions a ring's slots hold when its request's newest position
+    is ``last`` [B] (-1: nothing yet): [B, R * bs], ring block ``r`` the
+    newest logical block ``j <= last // bs`` with ``j % R == r``; negative
+    where a slot holds nothing of the request's: no such block, or a slot
+    of the newest block past ``last`` (what the block R before it left)."""
+    last = jnp.asarray(last, jnp.int32).reshape(-1, 1)               # [B, 1]
+    pb = last // bs
+    j = pb - (pb - jnp.arange(R, dtype=jnp.int32)[None, :]) % R      # [B, R]
+    kpos = (j[:, :, None] * bs
+            + jnp.arange(bs, dtype=jnp.int32)).reshape(-1, R * bs)
+    return jnp.where(kpos <= last, kpos, -1)
+
+
+def _ring_slots(table, positions, last, bs: int):
+    """Flat slots of a window layer's pool view for ``positions`` [T] of ONE
+    request whose ring is ``table`` [R] and whose newest real position is
+    ``last``: the last R logical blocks up to ``last`` go to the ring (no
+    two of their positions share a slot), everything else (older blocks,
+    which no later query can see, and a bucket's padding) to the dummy."""
+    R = table.shape[0]
+    blk = positions // bs
+    keep = (positions <= last) & (blk > last // bs - R)
+    return jnp.where(keep, table[blk % R] * bs, 0) + positions % bs
+
+
 def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
-                       mlp_fn=None, lin_fn=None):
+                       mlp_fn=None, lin_fn=None, win_fn=None):
     """The ONE way the paged programs thread the pools through the layer
     stack: as the carry of a scan over the PERIODS of the layer pattern (a
     period of one attention layer where the stack has no pattern), a
@@ -1686,14 +1775,18 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
     of that view, so ``attn_fn(x_normed, lp_attn, kp, vp, block0, slot0)``
     reads and writes it through ``block_tables + block0`` / ``slots +
     slot0``; linear-attention layer ``l`` at rows ``[l*slots, (l+1)*slots)``,
-    ``lin_fn(x_normed, lp_lin, state, conv, row0)``. No per-layer slice of
+    ``lin_fn(x_normed, lp_lin, state, conv, row0)``; window layer ``l`` at
+    blocks ``[l*ring blocks, ...)`` of ``wk`` / ``wv``, ``win_fn(x_normed,
+    lp_attn, wk, wv, block0)``. No per-layer slice of
     a pool exists and nothing is stacked. An ``mlp_fn`` may return ``(out,
     aux)``: ``aux`` (an MoE layer's per-expert counts) comes back stacked
     over the layers, else None. Returns (x, new pools, aux)."""
     period = cfg.period
     n_att, n_lin = period.count(ATTENTION), period.count(LINEAR_ATTENTION)
+    n_win = period.count(WINDOW_ATTENTION)
     Nb, bs = pools["k"].shape[1:3]
     n_slots = pools["state"][0].shape[1] if n_lin else 0
+    Nwb = pools["wk"].shape[1] if n_win else 0
     groups = params["layers"] if cfg.layer_kinds is not None \
         else (params["layers"],)
 
@@ -1705,13 +1798,13 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
         aux = []
         mfn = mlp_fn
         if mlp_fn is not None:
-            def mfn(c, xx, lpp):
-                out = mlp_fn(c, xx, lpp)
+            def mfn(c, xx, lpp, xa):
+                out = mlp_fn(c, xx, lpp, xa)
                 if isinstance(out, tuple):
                     out, a = out
                     aux.append(a)
                 return out
-        i_att = i_lin = 0
+        i_att = i_lin = i_win = 0
         for kind, lp in zip(period, lps):
             if kind == ATTENTION:
                 l = p * n_att + i_att
@@ -1721,6 +1814,14 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
                     lambda xn: attn_fn(xn, lp["attn"], flat["k"], flat["v"],
                                        l * Nb, l * (Nb * bs)),
                     mfn)
+            elif kind == WINDOW_ATTENTION:
+                l = p * n_win + i_win
+                i_win += 1
+                h, flat["wk"], flat["wv"] = _decode_block(
+                    cfg, h, lp,
+                    lambda xn: win_fn(xn, lp["attn"], flat["wk"], flat["wv"],
+                                      l * Nwb),
+                    mfn, scope=WINDOW_ATTENTION)
             else:
                 i = i_lin
                 i_lin += 1
@@ -1753,33 +1854,42 @@ def paged_real_rows(pools, slots):
 
 
 def _paged_decode_attention(cfg: TransformerConfig, x, lp, positions, pos,
-                            kp, vp, block_tables, pad_bias):
+                            kp, vp, block_tables, pad_bias, window: int = 0):
     """One fused decode step over all running requests against the paged
     pools: x [B, 1, D] (one new token per request), pos [B] per-request
     cache depths, kp/vp [blocks, bs, KV*Hd], block_tables [B, max_blocks].
+    ``window`` > 0 (a window layer): the tables are the requests' RINGS
+    (logical block ``j`` at entry ``j % width``) and a row reads positions
+    ``pos - window < j <= pos`` only.
     Returns (out [B, 1, D], new kp, vp)."""
     B, T, D = x.shape
     H = cfg.n_head
     bs = kp.shape[1]
 
-    q, k, v = _qkv_project(cfg, x, lp, positions)
+    q, k, v = _qkv_project(cfg, x, lp, positions,
+                           WINDOW_ATTENTION if window else ATTENTION)
 
     # each request's new k/v lands at its block-table slot; inactive rows
     # carry a zeroed table and write into the dummy block
-    slots = block_tables[jnp.arange(B), pos // bs] * bs + pos % bs
+    rows = jnp.arange(B)
+    blk = pos // bs
+    if window:
+        blk = blk % block_tables.shape[1]
+    slots = block_tables[rows, blk] * bs + pos % bs
     kp = _pool_scatter(kp, k[:, 0], slots)
     vp = _pool_scatter(vp, v[:, 0], slots)
 
     slopes = _alibi_slopes(H) if cfg.pos_embedding == "alibi" else None
+    band = {"window": window} if window else {}
     o = None
     if _use_flash(cfg):
         from deepspeed_tpu.ops.pallas.paged_decode_attention import \
             paged_decode_attention
         o = paged_decode_attention(q[:, 0], kp, vp, block_tables, pos,
                                    pad_bias=pad_bias, alibi_slopes=slopes,
-                                   scale=cfg.attn_scale)
+                                   scale=cfg.attn_scale, **band)
         form = "paged_kernel"
-    else:
+    elif not window:
         # SPMD mesh (a bare pallas_call is illegal): shard_map the kernel
         # over the KV-head/tp axis — the head-sharded pool's shards each
         # stream their local heads, tables stay replicated
@@ -1795,44 +1905,53 @@ def _paged_decode_attention(cfg: TransformerConfig, x, lp, positions, pos,
         form = "gather_einsum"
         # gather + grouped einsum (the dense cache path's masked-softmax
         # core with per-request qpos) — partitionable, the CPU tier default
+        if window:
+            band["kpos"] = _ring_kpos(pos, block_tables.shape[1], bs)
         out = _grouped_cache_einsum(
             cfg, q, _paged_gather(kp, block_tables, cfg.kv_heads),
-            _paged_gather(vp, block_tables, cfg.kv_heads), positions, pad_bias)
-    dispatch.record("paged_decode", form,
+            _paged_gather(vp, block_tables, cfg.kv_heads), positions, pad_bias,
+            **band)
+    dispatch.record("paged_window_decode" if window else "paged_decode", form,
                     f"B={B} H={H} KV={cfg.kv_heads} Hd={cfg.head_dim} bs={bs}")
     out = _attn_out(cfg, out, x, lp)
     return out, kp, vp
 
 
 def _paged_prefill_attention(cfg: TransformerConfig, x, lp, positions,
-                             kp, vp, slots):
+                             kp, vp, slots, window: int = 0):
     """Prefill attention of ONE fresh request: causal self-attention over
     its own prompt (a fresh request has no prior context to read), with the
     prompt's k/v scattered into the request's pool blocks. x [1, T, D];
-    slots [T] flat pool slots (pad positions routed to the dummy block)."""
+    slots [T] flat pool slots (pad positions routed to the dummy block).
+    ``window`` > 0 (a window layer): the attention is the band over the
+    fresh k/v, and ``slots`` keep what the request's ring keeps."""
     B, T, D = x.shape
     H, Hd = cfg.n_head, cfg.head_dim
 
-    q, k, v = _qkv_project(cfg, x, lp, positions)
+    q, k, v = _qkv_project(cfg, x, lp, positions,
+                           WINDOW_ATTENTION if window else ATTENTION)
 
     kp = _pool_scatter(kp, k, slots)
     vp = _pool_scatter(vp, v, slots)
 
     slopes = _alibi_slopes(H) if cfg.pos_embedding == "alibi" else None
-    stair = {} if cfg.causal_block == 1 else {"causal_block": cfg.causal_block}
+    mask = {} if cfg.causal_block == 1 else {"causal_block": cfg.causal_block}
+    if window:
+        mask["window"] = window
     out = None
     if _use_flash(cfg):
         from deepspeed_tpu.ops.pallas import flash_attention
         out = flash_attention(q, k, v, causal=True, alibi_slopes=slopes,
                               scale=cfg.attn_scale, block_q=cfg.attn_block_q,
-                              block_k=cfg.attn_block_k, **stair)
+                              block_k=cfg.attn_block_k, **mask)
         form = "flash"
     if out is None:
         from deepspeed_tpu.ops.attention import mha_attention
         out = mha_attention(q, k, v, causal=True, alibi_slopes=slopes,
-                            scale=cfg.attn_scale, **stair)
+                            scale=cfg.attn_scale, **mask)
         form = "einsum"
-    dispatch.record("paged_prefill", form, f"T={T}")
+    dispatch.record("paged_window_prefill" if window else "paged_prefill",
+                    form, f"T={T}")
     out = out.reshape(B, T, H * Hd)
     out = _attn_out(cfg, out, x, lp)
     return out, kp, vp
@@ -1893,6 +2012,23 @@ def _lin_prefill_fn(cfg: TransformerConfig, pools, state_slot, n_valid, fresh):
         cfg, xn, lp, st, cv, row0 + state_slot, n_valid, fresh)
 
 
+def _win_prefill_fn(cfg: TransformerConfig, pools, state_slot, positions,
+                    last_idx):
+    """The ``win_fn`` of a prefill program (None for a stack without window
+    layers): the request's ring, and the slots of it that the prompt's
+    positions keep."""
+    if "wk" not in pools:
+        return None
+    if state_slot is None:
+        raise ValueError("a stack with window layers needs the request's "
+                         "slot (its ring)")
+    bs = pools["wk"].shape[2]
+    table = ring_tables(state_slot, cfg.ring_blocks(bs))[0]
+    slots = _ring_slots(table, positions[0], positions[0, last_idx], bs)
+    return lambda xn, lp, kp, vp, block0: _paged_prefill_attention(
+        cfg, xn, lp, positions, kp, vp, slots + block0 * bs,
+        window=cfg.attn_window)
+
 
 def forward_paged_prefill(cfg: TransformerConfig, params, tokens, pools,
                           slots, last_idx, mlp_fn=None, state_slot=None):
@@ -1903,8 +2039,9 @@ def forward_paged_prefill(cfg: TransformerConfig, params, tokens, pools,
     pads routed to the dummy block); last_idx [] int32 index of the last
     real prompt token. Returns (logits [1, vocab] at last_idx, new pools) —
     junk pad positions are causally invisible to the sampled position.
-    ``state_slot`` [] int32 (a stack with recurrent state): the request's
-    slot, which a whole prefill starts from zero."""
+    ``state_slot`` [] int32 (a stack with recurrent state or window
+    layers): the request's slot, whose state a whole prefill starts from
+    zero and whose ring it fills with the prompt's last blocks."""
     _check_paged_config(cfg)
     x, positions = cached_embed(cfg, params, tokens, jnp.int32(0),
                                 pools["k"].dtype)
@@ -1913,7 +2050,8 @@ def forward_paged_prefill(cfg: TransformerConfig, params, tokens, pools,
         cfg, params, pools, x,
         lambda xn, lp, kp, vp, block0, slot0: _paged_prefill_attention(
             cfg, xn, lp, positions, kp, vp, slots + slot0),
-        mlp_fn, _lin_prefill_fn(cfg, pools, state_slot, last_idx + 1, True))
+        mlp_fn, _lin_prefill_fn(cfg, pools, state_slot, last_idx + 1, True),
+        _win_prefill_fn(cfg, pools, state_slot, positions, last_idx))
     # head on the sampled position only: the [1, vocab] projection, not
     # the whole bucket's [T, vocab]
     xl = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
@@ -1936,8 +2074,16 @@ def forward_paged_prefill_chunk(cfg: TransformerConfig, params, tokens,
     (logits [1, vocab] at last_idx, new pools) — intermediate chunks
     discard the logits, the final chunk samples from them. ``state_slot``
     [] int32 (a stack with recurrent state): the request's slot, zeroed by
-    the chunk that starts at position 0 and carried over the later ones."""
+    the chunk that starts at position 0 and carried over the later ones.
+    A stack with window layers is refused: a chunk longer than a block
+    would write over ring positions its first queries still read, and no
+    form reads the ring beside the chunk's own k/v (the engine refuses
+    ``prefill_chunk_tokens`` from ``cache_spec``)."""
     _check_paged_config(cfg)
+    if "wk" in pools:
+        raise NotImplementedError(
+            "a prefill chunk is not built for a stack with window layers: "
+            "their rings take whole prompts only")
     x, positions = cached_embed(cfg, params, tokens, start_pos,
                                 pools["k"].dtype)
 
@@ -2056,10 +2202,11 @@ def forward_paged_verify(cfg: TransformerConfig, params, tokens, pools,
     (attention masks at each row's pos) and overwritten as decode
     advances; the scheduler handles pos rewind + prefix-cache rollback."""
     _check_paged_config(cfg)
-    if cfg.cache_spec["state"]:
+    if cfg.cache_spec["state"] or cfg.cache_spec["window"]:
         raise NotImplementedError(
-            "a verify window rewinds to the last accepted position, and a "
-            "recurrent state cannot be rewound (no snapshot is kept)")
+            "a verify window rewinds to the last accepted position: a "
+            "recurrent state cannot be rewound (no snapshot is kept), and a "
+            "window layer's ring has written over what the rewind would read")
     x, positions = cached_embed(cfg, params, tokens, pos, pools["k"].dtype)
 
     x, pools, _ = _scan_paged_layers(
@@ -2079,18 +2226,24 @@ def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
     per-request cache depths. Returns (logits [B, vocab], new pools), and
     third what an ``mlp_fn`` that returns ``(out, aux)`` gave, stacked over
     the layers (an MoE model's [L, E + 1] assignment counts).
-    ``state_slots`` [B] int32 (a stack with recurrent state): each row's
-    state slot, 0 (the dummy) for an inactive row."""
+    ``state_slots`` [B] int32 (a stack with recurrent state or window
+    layers): each row's slot, 0 (the dummy) for an inactive row."""
     _check_paged_config(cfg)
     x, positions = cached_embed(cfg, params, tokens, pos, pools["k"].dtype)
-    lin_fn = None
+    lin_fn = win_fn = None
+    if ("state" in pools or "wk" in pools) and state_slots is None:
+        raise ValueError("a stack with recurrent state or window layers "
+                         "needs the rows' slots")
     if "state" in pools:
-        if state_slots is None:
-            raise ValueError("a stack with recurrent state needs the rows' "
-                             "state slots")
         n_slots = pools["state"][0].shape[1]
         lin_fn = lambda xn, lp, st, cv, row0: _kda_decode(  # noqa: E731
             cfg, xn, lp, st, cv, row0, state_slots, n_slots)
+    if "wk" in pools:
+        rings = ring_tables(state_slots,
+                            cfg.ring_blocks(pools["wk"].shape[2]))
+        win_fn = lambda xn, lp, kp, vp, block0: _paged_decode_attention(  # noqa: E731
+            cfg, xn, lp, positions, pos, kp, vp, rings + block0, None,
+            window=cfg.attn_window)
 
     # the decode step derives its write slots from the (layer-offset) table
     x, pools, aux = _scan_paged_layers(
@@ -2098,7 +2251,7 @@ def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
         lambda xn, lp, kp, vp, block0, slot0: _paged_decode_attention(
             cfg, xn, lp, positions, pos, kp, vp, block_tables + block0,
             pad_bias),
-        mlp_fn, lin_fn)
+        mlp_fn, lin_fn, win_fn)
     logits = cached_head(cfg, params, x)[:, 0, :]
     return (logits, pools) if aux is None else (logits, pools, aux)
 
@@ -2163,10 +2316,11 @@ def forward_paged_block(cfg: TransformerConfig, params, tokens, pools,
     and commit passes are this same program: what differs is what the
     caller does with the logits and whether ``pos`` moves on."""
     _check_paged_config(cfg)
-    if cfg.generation is None or cfg.cache_spec["state"]:
+    if cfg.generation is None or cfg.cache_spec["state"] \
+            or cfg.cache_spec["window"]:
         raise NotImplementedError(
             "a block pass needs a model that generates by blocks "
-            "(cfg.generation) and keeps no recurrent state")
+            "(cfg.generation) and keeps no recurrent state and no window")
     if pools["k"].shape[2] % cfg.causal_block:
         raise ValueError("a pool block must hold whole generation blocks")
     x, positions = cached_embed(cfg, params, tokens, pos, pools["k"].dtype)

@@ -16,7 +16,7 @@ import jax.numpy as jnp
 
 
 def mha_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=None, scale: Optional[float] = None,
-                  causal_block: int = 1):
+                  causal_block: int = 1, window: int = 0):
     """q: [B, S, H, Hd]; k,v: [B, S, KV, Hd] with KV | H → [B, S, H, Hd].
 
     GQA-native: when KV < H the query heads are reshaped into [KV, G] groups
@@ -28,6 +28,8 @@ def mha_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=Non
     dtype; XLA fuses scale+bias+mask+softmax into the attention matmuls.
     ``causal_block`` > 1: position i sees j iff j // causal_block <=
     i // causal_block (the flash kernel's staircase; its XLA twin).
+    ``window`` > 0 (with ``causal``): position i sees i - window < j <= i
+    (the flash kernel's band; its XLA twin).
     """
     B, S, H, Hd = q.shape
     KV = k.shape[2]
@@ -52,6 +54,9 @@ def mha_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=Non
         if causal_block > 1:  # dslint: disable=DS004 (a static Python int)
             grp = jnp.arange(S) // causal_block
             causal_mask = grp[:, None] >= grp[None, :]
+        if window:  # dslint: disable=DS004 (a static Python int)
+            causal_mask = causal_mask & ~jnp.tril(
+                jnp.ones((S, S), bool), -window)
         logits = jnp.where(causal_mask[None, None, None, :, :], logits, -1e9)
     if mask_bias is not None:
         logits = logits + mask_bias[:, None]  # [B,1,1,S] -> [B,1,1,1,S] broadcast

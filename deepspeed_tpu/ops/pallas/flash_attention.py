@@ -65,11 +65,12 @@ def _named(o, lse):
 
 
 def _block_bias(qoff, koff, bq, bk, seq_len, causal, slope, mask_blk,
-                stair=1):
+                stair=1, window=0):
     """Additive log2-domain bias for a (bq, bk) score block from GLOBAL
     positions: alibi + causal/pad masking + user key mask. ``stair`` > 1:
     the causal triangle is a staircase of that step (a query sees all of
-    its own group of ``stair`` positions)."""
+    its own group of ``stair`` positions). ``window`` > 0: a query sees the
+    ``window`` keys up to its own and none before them."""
     qpos = qoff + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
     kpos = koff + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     bias = (slope * _LOG2E) * (kpos - qpos).astype(jnp.float32)  # slope==0 → no-op
@@ -78,16 +79,39 @@ def _block_bias(qoff, koff, bq, bk, seq_len, causal, slope, mask_blk,
         valid = valid & (qpos // stair >= kpos // stair)
     elif causal:
         valid = valid & (qpos >= kpos)
+    if window:
+        valid = valid & (kpos > qpos - window)
     bias = jnp.where(valid, bias, _MASKED)
     return bias + mask_blk[None, :] * _LOG2E
 
 
-def _dispatch(run, i, j, plain, causal, update, logits, tri_ref, bias):
+def _dispatch(run, i, j, plain, causal, update, logits, tri_ref, bias,
+              low=None):
     """Apply ``update`` to the block's log2-domain logits with the cheapest
     masking that is correct: nothing for fully-visible plain blocks, one
     precomputed triangular block on the plain diagonal (i == j), or the
-    general computed bias. Shared by the forward and both backward kernels."""
-    if plain and causal:
+    general computed bias. Shared by the forward and both backward kernels.
+    ``low`` = n: a plain band whose lower edge crosses the blocks ``n`` below
+    the diagonal. Key c of such a block is inside query r's window iff c >
+    r: the complement of the diagonal block's triangle, so its bias is read
+    off ``tri_ref`` (a second precomputed block would be 4 MB more of VMEM
+    at 1,024 x 1,024, twice with its double buffer)."""
+    if plain and causal and low is not None:
+        diag, edge = i == j, i - j == low
+
+        @pl.when(jnp.logical_and(run, diag))
+        def _():
+            update(logits() + tri_ref[:])
+
+        @pl.when(jnp.logical_and(run, edge))
+        def _():
+            update(logits() + jnp.where(tri_ref[:] < 0.0, 0.0, _MASKED))
+
+        @pl.when(jnp.logical_and(run, jnp.logical_not(
+            jnp.logical_or(diag, edge))))
+        def _():
+            update(logits())
+    elif plain and causal:
         @pl.when(jnp.logical_and(run, i == j))
         def _():
             update(logits() + tri_ref[:])
@@ -147,7 +171,10 @@ def _parse_rest(rest, plain, has_layout):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, slope_ref, *rest,
-                scale, causal, seq_len, bq, bk, plain, has_layout, stair=1):
+                scale, causal, seq_len, bq, bk, plain, has_layout, stair=1,
+                window=0):
+    # a plain band (``_build``: bq == bk, a whole number of blocks wide)
+    low = window // bk if window and plain else None
     tri_ref, layout_ref, (o_ref, lse_ref, m_scr, l_scr, acc_scr) = \
         _parse_rest(rest, plain, has_layout)
     # refs (leading dims squeezed): q/o (bq, Hd); k/v (bk, Hd); mask (bk,);
@@ -166,6 +193,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, slope_ref, *rest,
     # skip blocks above the causal diagonal AND blocks the sparsity layout
     # zeroes out (block-sparse attention, reference ops/sparse_attention/)
     needed = True if not causal else (koff <= qoff + bq - 1)
+    if window:
+        # ... and blocks wholly below the band: their last key lies before
+        # the first query's window
+        needed = jnp.logical_and(needed, koff + bk - 1 > qoff - window)
     run = needed if layout_ref is None else jnp.logical_and(needed, layout_ref[0, 0] > 0)
 
     def logits():
@@ -190,7 +221,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, slope_ref, *rest,
     _dispatch(run, i, j, plain, causal, update, logits, tri_ref,
               lambda: _block_bias(qoff, koff, bq, bk, seq_len, causal,
                                   slope_ref[0, 0], mask_ref[0].astype(jnp.float32),
-                                  stair))
+                                  stair, window), low)
 
     @pl.when(j == nk - 1)
     def _():
@@ -527,6 +558,15 @@ def _kv_spec(bk, Hd, G=1):
     return pl.BlockSpec((None, None, bk, Hd), lambda b, h, i, j: (b, h // G, j, 0))
 
 
+def _band_kv_spec(bk, Hd, G, n):
+    # a plain band n blocks wide below the diagonal: a step outside it names
+    # the band's nearest block, which the pipeline then has already (a
+    # skipped block is not copied in)
+    return pl.BlockSpec(
+        (None, None, bk, Hd),
+        lambda b, h, i, j: (b, h // G, jnp.clip(j, jnp.maximum(i - n, 0), i), 0))
+
+
 def _row_spec(bq):
     # rows ride as [B, H, 1, Sp] so the trailing block dims (1, bq) tile
     return pl.BlockSpec((None, None, 1, bq), lambda b, h, i, j: (b, h, 0, i))
@@ -557,7 +597,7 @@ def _layout_spec():
 @functools.lru_cache(maxsize=32)
 def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret: bool,
            has_layout: bool = False, plain: bool = False, kv_group: int = 1,
-           stair: int = 1):
+           stair: int = 1, window: int = 0):
     """Build the custom-VJP flash function for one static configuration.
 
     Operates on padded [B, H, Sp, Hd] q / [B, KV, Sp, Hd] k,v
@@ -565,6 +605,9 @@ def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret
     h // kv_group via the BlockSpec index map), mask [B, Sp] additive f32,
     slopes [H, 1] f32 (zeros ⇒ no alibi). ``plain`` is the no-mask/no-alibi/
     no-padding fast path (tri = precomputed diagonal-block causal bias).
+    ``window`` > 0: the forward is the band ``i - window < j <= i`` (a plain
+    one reads its lower edge's bias off the diagonal's: the caller sees to
+    ``bq == bk`` and a band of whole blocks); its backward raises.
     """
 
     G = kv_group
@@ -576,12 +619,18 @@ def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret
     def fwd_call(q, k, v, mask, slopes, *extra):
         B, H, Sp, Hd = q.shape
         nq, nk = Sp // bq, Sp // bk
-        kernel = functools.partial(_fwd_kernel, **statics)
+        kv_spec = _kv_spec(bk, Hd, G)
+        fwd_statics = statics
+        if window:
+            fwd_statics = {**statics, "window": window}
+            if plain:
+                kv_spec = _band_kv_spec(bk, Hd, G, window // bk)
+        kernel = functools.partial(_fwd_kernel, **fwd_statics)
         o, lse = pl.pallas_call(
             kernel,
-            name="flash_fwd",
+            name="flash_fwd_band" if window else "flash_fwd",
             grid=(B, H, nq, nk),
-            in_specs=[_q_spec(bq, Hd), _kv_spec(bk, Hd, G), _kv_spec(bk, Hd, G),
+            in_specs=[_q_spec(bq, Hd), kv_spec, kv_spec,
                       _mask_spec(bk), _slope_spec()] + maybe_tri + maybe_layout,
             out_specs=[_q_spec(bq, Hd), _row_spec(bq)],
             out_shape=[
@@ -612,6 +661,10 @@ def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret
         [B, H, 1, Sp], or None) folds into delta — d s_k gains
         p_k * d lse_nat and lse2 = log2(e) * lse_nat, so
         delta' = delta - log2(e) * glse reuses the dq/dkv kernels unchanged."""
+        if window:
+            raise NotImplementedError(
+                "flash_attention(window=...) has no backward: the dq and "
+                "dk/dv kernels do not know the band")
         q, k, v, mask, slopes, extra, o, lse = res
         B, H, Sp, Hd = q.shape
         nq, nk = Sp // bq, Sp // bk
@@ -701,7 +754,7 @@ def flash_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=N
                     scale: Optional[float] = None, block_q: Optional[int] = None,
                     block_k: Optional[int] = None, block_layout=None,
                     interpret: Optional[bool] = None, return_lse: bool = False,
-                    causal_block: int = 1):
+                    causal_block: int = 1, window: int = 0):
     """Flash attention on [B, S, H, Hd] q/k/v (same contract as
     :func:`deepspeed_tpu.ops.attention.mha_attention`; mask_bias is the
     additive key-side [B, S] bias). Pads S up to the block size internally.
@@ -729,8 +782,19 @@ def flash_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=N
     (multiples of 8: a step of 2, 4 or 8), so which blocks are skipped does
     not change and the diagonal block's bias is the one thing that does,
     in the forward and in both backward kernels alike.
+
+    ``window`` > 0 (with ``causal``, FORWARD ONLY: the backward raises):
+    position ``i`` sees ``i - window < j <= i``, its own key and the
+    ``window - 1`` before it. Key blocks wholly below the band are skipped
+    like those above the diagonal, and where the band is whole blocks wide
+    they are not copied in either; the blocks its lower edge crosses take
+    the complement of the diagonal's precomputed bias.
     """
     B, S, H, Hd = q.shape
+    if window and (not causal or causal_block > 1 or block_layout is not None
+                   or window < 0):
+        raise ValueError(f"window={window} needs causal=True, causal_block 1 "
+                         "and no block_layout")
     if causal_block > 1 and (not causal or 8 % causal_block
                              or block_layout is not None):
         raise ValueError(
@@ -792,6 +856,9 @@ def flash_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=N
     # masking reduces to one precomputed triangular bias on diagonal blocks
     plain = (mask_bias is None and alibi_slopes is None and block_layout is None
              and Sp == S and (not causal or bq == bk))
+    if window:  # dslint: disable=DS004 (a static Python int)
+        # a band that is not whole blocks wide takes the computed bias
+        plain = plain and window % bk == 0
 
     # packed-heads fastest path: small head_dim packs P heads into one full
     # 128-lane tile and q/k/v stay in their natural [B, S, H*Hd] layout —
@@ -799,7 +866,7 @@ def flash_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=N
     # shared kv heads break the per-head lane-group pairing, and GQA models
     # are Hd=128-class anyway (general kernel, zero lane padding)
     if (plain and kv_group == 1 and not return_lse and Hd < 128
-            and 128 % Hd == 0 and H % (128 // Hd) == 0):
+            and 128 % Hd == 0 and H % (128 // Hd) == 0 and not window):
         P128 = 128 // Hd
         fn = _build_packed(causal, scale, bq, bk, interpret, P128, Hd)
         tri = _make_tri(bq, bk, causal_block)
@@ -839,7 +906,7 @@ def flash_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=N
         extra = extra + (layout,)
 
     fn, fn_lse = _build(causal, scale, bq, bk, S, interpret, block_layout is not None,
-                        plain, kv_group, causal_block)
+                        plain, kv_group, causal_block, window)
     if return_lse:
         out, lse = fn_lse(qt, kt, vt, mask, slopes, *extra)
         return (jnp.transpose(out[:, :, :S, :], (0, 2, 1, 3)),

@@ -61,7 +61,13 @@ arithmetic follows the work it is given.
   pos[b]``) — requests at different depths decode in the same fused step
   (iteration-level batching);
 * ALiBi slopes and an additive key-side ``pad_bias`` over LOGICAL positions
-  keep parity with the dense kernel; GQA head ``h`` reads kv head ``h // P``.
+  keep parity with the dense kernel; GQA head ``h`` reads kv head ``h // P``;
+* ``window`` (a static W; 0 compiles the kernel above and nothing else): a
+  row attends ``pos - W < kpos <= pos`` and its table is a RING, logical
+  block ``j`` at entry ``j % width``. Its loop starts at its FIRST LIVE block
+  ``max(0, pos - W + 1) // bs``, the first copy and the next row's prefetch
+  likewise, so a row costs ``W / bs + 1`` copies at most however long it is;
+  the keys of the first block that lie before the window are masked.
 
 Interpret mode on CPU — the unit tier pins parity vs ``decode_attention``
 and a float32 reference on randomized block tables; the kernel's times by
@@ -140,7 +146,7 @@ def _split3(p):
 
 
 def _kernel(bt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, *rest, bs, G, steps,
-            group, per_head, has_bias, has_alibi):
+            group, per_head, has_bias, has_alibi, window=0):
     rest = list(rest)
     bias_ref = rest.pop(0) if has_bias else None
     slope_ref = rest.pop(0) if has_alibi else None
@@ -157,19 +163,28 @@ def _kernel(bt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, *rest, bs, G, steps,
     # operands of another precision than bf16 meet in float32, in full
     exact = None if bf16 else jax.lax.Precision.HIGHEST
 
+    def first_block(row):
+        """The first logical block a row with a window reads."""
+        return jnp.maximum(pos_ref[row] - (window - 1), 0) // bs
+
     def live_blocks(row):
-        return jnp.minimum(pos_ref[row] // bs + 1, n_blocks)
+        n = pos_ref[row] // bs + 1
+        if window:
+            n = n - first_block(row)
+        return jnp.minimum(n, n_blocks)
 
     def copies(row, j, slot, wait=False):
         """Start, or wait for, the copies of group ``j`` of ``row``: one per
         LIVE block and pool. A dead table entry is never read."""
         live = live_blocks(row)
+        first = first_block(row) if window else None
         for i in range(G):
             blk = j * G + i
 
             @pl.when(blk < live)
             def _():
-                src = bt_ref[row, blk]
+                src = bt_ref[row, (first + blk) % n_blocks] if window \
+                    else bt_ref[row, blk]
                 for s, (pool, buf) in enumerate(((kp_hbm, kbuf),
                                                  (vp_hbm, vbuf))):
                     cp = pltpu.make_async_copy(
@@ -197,6 +212,9 @@ def _kernel(bt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, *rest, bs, G, steps,
 
     pos = pos_ref[b]
     live = live_blocks(b)
+    # a row's logical block from its index among the row's live blocks
+    logical = (lambda blk, first=first_block(b): first + blk) if window \
+        else (lambda blk: blk)
     n_groups = pl.cdiv(live, G)
     slot0 = slot_ref[0]
 
@@ -246,14 +264,17 @@ def _kernel(bt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, *rest, bs, G, steps,
         v = vbuf[slot, pl.ds(i, n)].reshape(n * bs, -1).astype(mxu)
         s = scores(k)                                          # [rows, n*bs]
         # LOGICAL key positions: the table only moved the storage
-        kpos = blk * bs + jax.lax.broadcasted_iota(jnp.int32,
-                                                   (1, n * bs), 1)
+        kpos = logical(blk) * bs + jax.lax.broadcasted_iota(
+            jnp.int32, (1, n * bs), 1)
         if has_alibi:
             s = s + slope * (kpos - pos).astype(jnp.float32)
         if has_bias:
             s = s + jnp.concatenate(
                 [bias_ref[0, pl.ds(blk + t, 1), :] for t in range(n)], axis=1)
-        s = jnp.where(kpos <= pos, s, _NEG)
+        keep = kpos <= pos
+        if window:
+            keep = jnp.logical_and(keep, kpos > pos - window)
+        s = jnp.where(keep, s, _NEG)
 
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -304,9 +325,9 @@ def _kernel(bt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, *rest, bs, G, steps,
 
 @functools.partial(jax.jit,
                    static_argnames=("group", "G", "steps", "per_head",
-                                    "interpret"))
+                                    "interpret", "window"))
 def _paged_call(q, kp, vp, bt, pos, bias, slopes, *, group, G, steps,
-                per_head, interpret):
+                per_head, interpret, window=0):
     """q ``[B, Q, Hp, Hd]`` (pre-scaled, heads padded to a multiple of 8);
     ``bias`` ``[B, n_blocks, bs]`` or None; ``slopes`` ``[rows, 1]`` in the
     kernel's row order or None."""
@@ -331,7 +352,7 @@ def _paged_call(q, kp, vp, bt, pos, bias, slopes, *, group, G, steps,
     return pl.pallas_call(
         functools.partial(_kernel, bs=bs, G=G, steps=steps, group=group,
                           per_head=per_head, has_bias=bias is not None,
-                          has_alibi=slopes is not None),
+                          has_alibi=slopes is not None, window=window),
         name="paged_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -368,7 +389,8 @@ def paged_envelope_ok(H: int, KV: int, Hd: int, bs: int) -> bool:
 
 def paged_decode_attention(q, kp, vp, block_tables, pos, *, pad_bias=None,
                            alibi_slopes=None, scale: Optional[float] = None,
-                           interpret: Optional[bool] = None):
+                           interpret: Optional[bool] = None,
+                           window: int = 0):
     """Attention of each request's new positions against a PAGED KV cache.
 
     q ``[B, H, Hd]`` (one new token per running request, rope applied) or
@@ -387,6 +409,10 @@ def paged_decode_attention(q, kp, vp, block_tables, pos, *, pad_bias=None,
     ``<= pos[b]``; ALiBi distances are taken from it).
     ``pad_bias`` ``[B, max_blocks * block_size]`` additive f32 bias over
     logical positions. GQA head h reads kv head ``h // (H // KV)``.
+    ``window`` W: request ``b`` attends ``pos[b] - W < kpos <= pos[b]`` and
+    its table is a ring of at least ``W / block_size + 1`` entries (logical
+    block ``j`` at ``block_tables[b, j % max_blocks]``; a table as long as
+    the request is one too); one query position a request, no ``pad_bias``.
     Returns q's shape. Which form the products took (module docstring) is
     static a shape and recorded: ``ops.dispatch`` site
     ``paged_decode_attention``, ``per_kv_head`` | ``block_diagonal``.
@@ -401,6 +427,13 @@ def paged_decode_attention(q, kp, vp, block_tables, pos, *, pad_bias=None,
         q = q[:, None]
     B, Q, H, Hd = q.shape
     bs, KV = kp.shape[1], kp.shape[2] // Hd
+    if window < 0 or window and (
+            Q > 1 or pad_bias is not None
+            or block_tables.shape[1] < -(-window // bs) + 1):
+        raise ValueError(
+            f"window={window}: needs one query position a request, no "
+            "pad_bias, and a table of at least window / block_size + 1 "
+            f"entries (got {block_tables.shape[1]})")
     if not paged_envelope_ok(H, KV, Hd, bs):
         warn_once(f"paged_decode_attention: heads={H} kv_heads={KV} "
                   f"head_dim={Hd} block_size={bs} is outside the kernel "
@@ -436,6 +469,6 @@ def paged_decode_attention(q, kp, vp, block_tables, pos, *, pad_bias=None,
                       jnp.asarray(block_tables, jnp.int32),
                       jnp.asarray(pos, jnp.int32).reshape(B),
                       bias, slopes, group=P, G=G, steps=_step_blocks(G),
-                      per_head=per_head,
-                      interpret=bool(interpret))[:, :, :H]
+                      per_head=per_head, interpret=bool(interpret),
+                      window=window)[:, :, :H]
     return out[:, 0] if one else out

@@ -385,3 +385,39 @@ def test_block_step_keeps_the_pools_in_place_for_v5e(one_v5e, monkeypatch):
                     dims[-2:] == [BS, cfg.kv_heads * cfg.head_dim]:
                 moved.append(m.group(0))
         assert not moved, (name, moved)
+
+
+def test_window_kernels_compile_for_v5e_at_real_widths(one_v5e, monkeypatch):
+    """Mosaic takes both kernels under a WINDOW at the widths of
+    ``smallthinker21b_serve_longctx`` (28 query and 4 key/value heads of
+    128, a window of 4,096 over blocks of 128): the paged kernel over a ring
+    table of 33 entries (a row's loop from its first live block, the table
+    index a remainder), and the banded forward flash kernel at a prompt
+    bucket of 8,192 on its plain path (two precomputed biases, the key
+    blocks' index clamped into the band), under its own name."""
+    from deepspeed_tpu.ops import dispatch
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    from deepspeed_tpu.ops.pallas.paged_decode_attention import \
+        paged_decode_attention
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    B, H, KV, Hd, W = 16, 28, 4, 128, 4096
+    pool = sds((16 * 33 + 1, BS, KV * Hd), jnp.bfloat16)
+    dispatch.reset()
+    compiled = jax.jit(lambda q, kp, vp, bt, pos: paged_decode_attention(
+        q, kp, vp, bt, pos, window=W, interpret=False)).lower(
+        sds((B, H, Hd), jnp.bfloat16), pool, pool, sds((B, 33), jnp.int32),
+        sds((B,), jnp.int32)).compile()
+    assert dispatch.selected()["paged_decode_attention=block_diagonal"] == 1
+    assert "paged_decode_attention" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20
+
+    S = 8192
+    q, kv = sds((1, S, H, Hd), jnp.bfloat16), sds((1, S, KV, Hd), jnp.bfloat16)
+    compiled = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, window=W, interpret=False)).lower(
+        q, kv, kv).compile()
+    assert "flash_fwd_band" in compiled.as_text()

@@ -437,6 +437,67 @@ def test_paged_position_axis_compiles_and_matches(tpu, Q, H, KV, Hd, rows,
 
 
 @tpu_tier
+def test_window_kernels_compile_and_match(tpu):
+    """Both kernels under a WINDOW, COMPILED, at the widths of
+    ``smallthinker21b_serve_longctx`` (28 query and 4 key/value heads of
+    128, window 4,096, blocks of 128, bf16): the paged kernel over ring
+    tables of 33 entries, rows shorter than the window, with a first live
+    block partly masked, on a block's border, wrapped several times and an
+    idle row, against its XLA twin (the ring gather and the masked einsum the
+    CPU tier serves with); the banded forward flash kernel at 8,192 tokens
+    on its plain path against the masked einsum. The served check of that
+    cell is blunt to the mask (PERF.md section 7, PR 37): this is what holds
+    the compiled band."""
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.ops.attention import mha_attention
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+    from deepspeed_tpu.ops.pallas.paged_decode_attention import \
+        paged_decode_attention
+
+    rng = np.random.default_rng(37)
+    H, KV, Hd, W, bs = 28, 4, 128, 4096, 128
+    R = W // bs + 1
+    cfg = T.TransformerConfig(n_head=H, n_kv_head=KV, head_size=Hd,
+                              d_model=H * Hd, pos_embedding="none")
+    pos = np.array([5, 4095, 4096, 4200, 6145, 8191, 8192, 11519, 16000, 0],
+                   np.int32)
+    slots = np.arange(1, len(pos) + 1, dtype=np.int32)
+    slots[-1] = 0                                    # an idle row
+    tables = T.ring_tables(slots, R)
+    q = jnp.asarray(rng.normal(size=(len(pos), H, Hd)), jnp.bfloat16)
+    kp = jnp.asarray(rng.normal(size=(len(pos) * R + 1, bs, KV * Hd)),
+                     jnp.bfloat16)
+    vp = jnp.asarray(rng.normal(size=kp.shape), jnp.bfloat16)
+    out = paged_decode_attention(q, kp, vp, tables, jnp.asarray(pos),
+                                 window=W, interpret=False)
+    ref = T._grouped_cache_einsum(
+        cfg, q[:, None].astype(jnp.float32),
+        T._paged_gather(kp, tables, KV).astype(jnp.float32),
+        T._paged_gather(vp, tables, KV).astype(jnp.float32),
+        jnp.asarray(pos)[:, None], None, kpos=T._ring_kpos(pos, R, bs),
+        window=W).reshape(q.shape)
+    err = float(jnp.abs(out.astype(jnp.float32) - ref)[:-1].max())
+    assert np.isfinite(err) and err < 0.05, err
+
+    S = 8192
+    q = jnp.asarray(rng.normal(size=(1, S, H, Hd)), jnp.bfloat16)
+    k = jnp.asarray(rng.normal(size=(1, S, KV, Hd)), jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(1, S, KV, Hd)), jnp.bfloat16)
+    out = flash_attention(q, k, v, causal=True, window=W, interpret=False)
+    ref = mha_attention(q[:, :, :7], k[:, :, :1], v[:, :, :1], causal=True,
+                        window=W)                    # one kv head's group
+    err = float(jnp.abs(out[:, :, :7].astype(jnp.float32)
+                        - ref.astype(jnp.float32)).max())
+    assert np.isfinite(err) and err < 0.05, err
+    # and the band is not the triangle
+    tri = flash_attention(q, k, v, causal=True, interpret=False)
+    assert float(jnp.abs(out.astype(jnp.float32)
+                         - tri.astype(jnp.float32))[:, W + 8:].max()) > 0.05
+
+
+@tpu_tier
 def test_paged_matches_dense_decode_kernel(tpu):
     """Same cache content through both decode kernels: the paged kernel on a
     shuffled pool and ``decode_attention`` on the contiguous workspace give
