@@ -491,7 +491,9 @@ def blockgen_leg(name, dry_run, n_dev):
     different blocks in one fused step. The block step must have taken the
     paged kernel (a row's 4 positions on its position axis) and the
     prefill the flash kernel's staircase, and the tokens are those of the
-    same weights on the plain-XLA forms."""
+    same weights on the plain-XLA forms. The experts of a pass (a call of
+    16 positions) must have been read by the grouped expert kernel
+    (``experts=grouped_kernel``)."""
     import jax
     import numpy as np
 
@@ -499,14 +501,17 @@ def blockgen_leg(name, dry_run, n_dev):
     from deepspeed_tpu.models.presets import get_model
     from deepspeed_tpu.ops import dispatch
 
-    say(f"{name}: sdar toy (GQA heads of 64, 4 of 8 experts held, blocks of "
-        "4) fp32 block_size 128 max_running 4")
+    say(f"{name}: sdar toy (d 128, GQA heads of 64, 4 of 8 experts of width "
+        "128 held, blocks of 4) fp32 block_size 128 max_running 4")
     fresh_leg()
 
     def toy(backend):
         # matrices at 0.3 and the embedding at 1.0: at the default 0.02 a
-        # toy of d 64 answers the same few tokens whatever it is asked
-        return get_model("sdar", "tiny", head_size=64, init_std=0.3,
+        # toy this narrow answers the same few tokens whatever it is asked;
+        # d and the experts' width whole lanes, which the grouped expert
+        # kernel tiles
+        return get_model("sdar", "tiny", head_size=64, d_model=128,
+                         moe=dict(expert_d_ff=128), init_std=0.3,
                          embed_init_std=1.0, attention_backend=backend)
 
     rng = np.random.default_rng(3)
@@ -525,9 +530,13 @@ def blockgen_leg(name, dry_run, n_dev):
     assert dry_run or not any(k.endswith("=interpret") for k in forms), forms
     if n_dev == 1:
         how = "interpret" if dry_run else "compiled"
+        # a pass's 16 positions and the 128-token bucket take the grouped
+        # expert kernel, the 256-token bucket the dense form
         for need in ("paged_block=paged_kernel", "paged_prefill=flash",
+                     "experts=grouped_kernel",
                      f"kernel/paged_decode_attention={how}",
-                     f"kernel/flash_attention={how}"):
+                     f"kernel/flash_attention={how}",
+                     f"kernel/grouped_expert_mlp={how}"):
             assert need in forms, (need, forms)
     assert "paged_block=gather_einsum" in dispatch.selected()
     same = sum(np.array_equal(a, b) for a, b in zip(served, want))

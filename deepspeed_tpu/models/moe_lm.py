@@ -20,18 +20,53 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models import transformer as T
+from deepspeed_tpu.ops import dispatch
+from deepspeed_tpu.ops.quant import Quantized8
+from deepspeed_tpu.ops.pallas.grouped_expert_mlp import (MAX_ROWS,
+                                                         dense_expert_mlp,
+                                                         envelope_ok,
+                                                         grouped_expert_mlp,
+                                                         touched_visits)
 from deepspeed_tpu.utils.init_on_device import honors_on_device
 from deepspeed_tpu.moe.sharded_moe import (dense_dispatch, dispatch_combine,
                                            sorted_dispatch, top1gating,
                                            top2gating, topk_balance_loss,
                                            topk_routing)
 
-# The no-drop dispatch computes every expert over every row
-# (sharded_moe.dense_dispatch) for a call of fewer rows than this, and sorts
-# rows into ragged groups (sorted_dispatch over jax.lax.ragged_dot) from here
-# on. Measured on a v5e at OLMoE's sizes (64 experts of 2,048 x 1,024, top-8)
-# with the layer's weights a scan's slices, as every path of this model has
-# them (benchmarks/moe_dispatch_bench.py, device times; PERF.md section 6,
+# The three forms of the no-drop experts (``_nodrop_mlp``; ``ops.dispatch``
+# site ``experts``), chosen by the rows of a call and by nothing else.
+#
+# ``grouped_kernel``: a PAGED program's call of at most
+# ``_GROUPED_KERNEL_MAX_ROWS`` rows (a decode step; a short prefill bucket)
+# where a bare Pallas call is legal (one device; ``T._use_flash``) reads the
+# experts its rows chose from the layer stack in place
+# (ops/pallas/grouped_expert_mlp.py): time goes with the TOUCHED experts'
+# bytes. Measured on a v5e (benchmarks/moe_dispatch_bench.py --forms dense
+# kernel [--touched N], device times under the layer scan; PERF.md section
+# 6, PR 40, calls 1 and 6), ms a layer, dense | kernel (touched experts):
+# SmallThinker's 16 rows x top-6 of 64 experts of 2,560 x 768: 1.007 | 0.802
+# (50.75; 746 GB/s of the chip's 819), the routing folded onto 16 / 32 / 50:
+# 1.007 | 0.261 (15.75), 1.007 | 0.483 (30.1), 1.007 | 0.661 (41.6); OLMoE's
+# 64 rows x top-8 of 64 of 2,048 x 1,024: 1.123 | 1.076 (64: every expert
+# touched and still ahead: no scan slice, 749 GB/s), 16 rows 1.188 | 0.931
+# (55.25), 128 rows 1.127 | 1.081; Solar's 128 rows, 40 held of 320 of 4,096
+# x 1,280: 1.811 | 1.712 (38.5); SDAR's 16 held of 128 of 2,048 x 768: 64
+# rows 0.210 | 0.208, 128 rows 0.216 | 0.215 (16). jax's own megablox.gmm
+# over the same whole stacks (three calls behind a sort): 0.898 at
+# SmallThinker's 16 rows, 0.333 / 0.567 / 0.754 at 16 / 32 / 50. The kernel
+# carries every row on every visit, one MXU row tile: past 128 rows a
+# visit's products outlast its weights' copy (SDAR's 256 positions: dense
+# 0.244 at the chip's ridge) and rows would have to be sorted to their
+# experts first, which is not built (ROADMAP S13).
+#
+# ``dense`` (sharded_moe.dense_dispatch: every held expert over every row):
+# every other call of fewer than ``_SORTED_DISPATCH_MIN_ROWS`` rows (more
+# than 128 rows; a mesh; int8 experts; ungated experts; training and the
+# dense-workspace cache), the kernel's plain-XLA twin and the CPU's form.
+#
+# ``ragged`` (sorted_dispatch over jax.lax.ragged_dot) from
+# ``_SORTED_DISPATCH_MIN_ROWS`` rows on. Measured on a v5e at OLMoE's sizes
+# with the layer's weights a scan's slices (the same bench, PERF.md section 6,
 # PR 26, call 7), ms a layer, dense | sorted: 1 row 1.07 | 2.60, 16 rows
 # 1.19 | 3.59, 64 rows 1.12 | 5.01, 512 rows 2.21 | 5.44, 1,024 rows 4.36 |
 # 5.92, 1,536 rows 6.53 | 6.44, 2,048 rows 8.66 | 6.90. The dense form costs
@@ -40,8 +75,10 @@ from deepspeed_tpu.moe.sharded_moe import (dense_dispatch, dispatch_combine,
 # changes (XLA's ragged matmul takes a scan's slice as a copy and reads it at
 # a fifth of the memory's rate). Where they cross is the chip's balance of
 # arithmetic to memory and moves little with E and k (E / (E - 1.8 k)), so
-# one number and no option; no cell runs the ragged side yet (ROADMAP S13).
+# one number and no option; SmallThinker's 6-10 k-token prefills run the
+# ragged side (ROADMAP S13 keeps its copy).
 _SORTED_DISPATCH_MIN_ROWS = 1536
+_GROUPED_KERNEL_MAX_ROWS = MAX_ROWS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -239,7 +276,7 @@ class MoECausalLM:
         return jax.nn.gelu(up, approximate=True)
 
     def _moe_mlp(self, lp, x, rng, train: bool, used_token=None,
-                 with_owed: bool = False, mixer_in=None):
+                 with_owed: bool = False, mixer_in=None, stack=None):
         """x [B,S,D] → ([B,S,D], l_aux, counts) via top-k expert routing.
         ``used_token`` [B*S] 1/0 keeps masked tokens away from the experts
         (nodrop: any k; capacity: top-1 only, the reference's top-2 gate has
@@ -249,11 +286,13 @@ class MoECausalLM:
         ``with_owed``: fourth, the assignments the layer owed (those of
         ``used_token`` rows to experts held here). ``mixer_in`` [B,S,D]:
         what the block's mixer read, which the router reads in x's place
-        under ``router_input="mixer_input"``."""
+        under ``router_input="mixer_input"``. ``stack``: see
+        ``_nodrop_mlp``."""
         if self.moe.dispatch == "nodrop":
             out = self._nodrop_mlp(
                 lp, x, used_token,
-                mixer_in if self.moe.router_input == "mixer_input" else None)
+                mixer_in if self.moe.router_input == "mixer_input" else None,
+                stack)
         else:
             used = x.shape[0] * x.shape[1] if used_token is None \
                 else jnp.sum(used_token > 0, dtype=jnp.int32)
@@ -284,32 +323,60 @@ class MoECausalLM:
                                 local, moe.num_experts)
         return weights, experts, probs
 
-    def _nodrop_mlp(self, lp, x, valid=None, route_x=None):
+    def _grouped_kernel(self, params, rows: int) -> bool:
+        """Whether a paged program's calls of ``rows`` rows take the grouped
+        expert kernel (``_GROUPED_KERNEL_MAX_ROWS``): a rule on static
+        shapes and on what a bare ``pallas_call`` may be handed, chosen as
+        the paged kernel is (``T._use_flash``), never by the model."""
+        cfg = self.config
+        groups = params["layers"] if cfg.layer_kinds is not None \
+            else (params["layers"],)
+        return (self.moe.dispatch == "nodrop" and self._gated
+                and rows <= _GROUPED_KERNEL_MAX_ROWS and T._use_flash(cfg)
+                and (envelope_ok(rows, cfg.d_model, self.expert_ff)
+                     or not dispatch.on_tpu())
+                # a Quantized8 leaf keeps the XLA forms (T._w dequantises)
+                and not any(isinstance(g["mlp"][k], Quantized8)
+                            for g in groups for k in self._expert_keys()))
+
+    def _nodrop_mlp(self, lp, x, valid=None, route_x=None, stack=None):
         """A score an expert in float32 (softmax, or sigmoid with a
         selection bias), the k largest as they are, every assignment to an
-        expert held here computed: every held expert over every row for a
-        call of fewer than ``_SORTED_DISPATCH_MIN_ROWS`` rows, rows sorted
-        into ragged groups (``jax.lax.ragged_dot``) from there on; then the
-        shared expert, if the model has one. Scopes ``router`` /
-        ``moe_dispatch`` / ``experts`` / ``shared_expert`` name the parts in
-        a device trace. ``route_x``: what the router reads where that is
-        not ``x``. Returns (out, l_aux, counts [E], owed)."""
+        expert held here computed, in one of three forms (``ops.dispatch``
+        site ``experts``): ``grouped_kernel``, the touched experts read from
+        the layer stack in place (``stack = (leaves [n, E, ...] whole, the
+        layer's index in them)``: a paged program whose rows
+        ``_grouped_kernel`` accepts); ``dense``, every held expert over
+        every row, for any other call of fewer than
+        ``_SORTED_DISPATCH_MIN_ROWS`` rows; ``ragged``, rows sorted into
+        groups (``jax.lax.ragged_dot``), from there on; then the shared
+        expert, if the model has one. Scopes ``router`` / ``moe_dispatch``
+        / ``experts`` / ``shared_expert`` name the parts in a device trace.
+        ``route_x``: what the router reads where that is not ``x``. Returns
+        (out, l_aux, counts [E], owed)."""
         moe = self.moe
         B, S, D = x.shape
         E = moe.num_experts
         tokens = x.reshape(-1, D)
+        rows = tokens.shape[0]
+        form = "grouped_kernel" if stack is not None else \
+            "dense" if rows < _SORTED_DISPATCH_MIN_ROWS else "ragged"
+        dispatch.record("experts", form,
+                        f"rows={rows} k={moe.k} E={E} D={D} F={self.expert_ff}")
         with jax.named_scope("router"):
             weights, experts, probs = self._route(
                 lp, tokens if route_x is None else route_x.reshape(-1, D))
         if self.router_width == E:
-            owed = (tokens.shape[0] if valid is None
+            owed = (rows if valid is None
                     else jnp.sum(valid, dtype=jnp.int32)) * moe.k
         else:
             held = experts < E
             if valid is not None:
                 held = held & valid.astype(bool)[:, None]
             owed = jnp.sum(held, dtype=jnp.int32)
-        p = {k: T._w(lp[k], tokens) for k in self._expert_keys()}
+        relu = moe.expert_activation == "reglu"
+        if stack is None:
+            p = {k: T._w(lp[k], tokens) for k in self._expert_keys()}
 
         def grouped(xs, sizes):
             """xs [T*k, D] sorted by expert, ``sizes`` [E] rows a group."""
@@ -326,21 +393,34 @@ class MoECausalLM:
         def dense(xs, combine):
             """Every expert over every row of xs [T, D]; combine [T, E]."""
             with jax.named_scope("experts"):
-                ein = lambda w: jnp.einsum(  # noqa: E731
-                    "td,edf->tef", xs, w, preferred_element_type=jnp.float32)
                 if self._gated:
-                    h = self._act(ein(p["w_up"]), ein(p["w_gate"]))
-                else:
-                    h = self._act(ein(p["w_up"]) + p["b_up"][None])
+                    return dense_expert_mlp(xs, combine, p["w_gate"],
+                                            p["w_up"], p["w_down"], relu=relu)
+                h = self._act(jnp.einsum(
+                    "td,edf->tef", xs, p["w_up"],
+                    preferred_element_type=jnp.float32) + p["b_up"][None])
                 out = jnp.einsum(
                     "tef,efd->td", (h * combine[:, :, None]).astype(xs.dtype),
                     p["w_down"], preferred_element_type=jnp.float32)
-                return out if self._gated else out + combine @ p["b_down"]
+                return out + combine @ p["b_down"]
 
-        if tokens.shape[0] < _SORTED_DISPATCH_MIN_ROWS:
-            out, counts = dense_dispatch(tokens, weights, experts, E, dense, valid)
-        else:
+        def kernel(xs, combine):
+            """The touched experts over every row, the stacks in place."""
+            whole, layer = stack
+            with jax.named_scope("moe_dispatch"):
+                visits = touched_visits(combine)
+            with jax.named_scope("experts"):
+                w = {k: a.reshape(-1, *a.shape[2:]) for k, a in whole.items()}
+                return grouped_expert_mlp(
+                    xs, combine, w["w_gate"], w["w_up"], w["w_down"],
+                    layer * E, relu=relu, visits=visits)
+
+        if form == "ragged":
             out, counts = sorted_dispatch(tokens, weights, experts, E, grouped, valid)
+        else:
+            out, counts = dense_dispatch(
+                tokens, weights, experts, E,
+                kernel if form == "grouped_kernel" else dense, valid)
         if self.router_width == E:
             l_aux = topk_balance_loss(probs, counts, moe.k)
         else:
@@ -483,26 +563,30 @@ class MoECausalLM:
         return T.init_paged_kv_cache(self.config, num_blocks, block_size, dtype,
                                      state_slots=state_slots)
 
-    def _paged(self, pools, slots, counts: bool = False):
+    def _paged(self, params, pools, slots, counts: bool = False):
         """The ``mlp_fn`` of a ``transformer.forward_paged_*`` call whose
         positions write to ``slots``. ``counts``: it also returns [E + 1]
         int32, the assignments each expert computed and, last, those the
-        layer owed (real rows x k)."""
+        layer owed (real rows x k). Where the call's rows take the grouped
+        kernel (``_grouped_kernel``) the function asks the layer scan for
+        the expert stacks whole (``stack_keys``)."""
         used = T.paged_real_rows(pools, slots).reshape(-1)
 
-        def mlp_fn(cfg, x_normed, lp, mixer_in):
+        def mlp_fn(cfg, x_normed, lp, mixer_in, stack=None):
             with jax.named_scope("mlp"):
                 out, _, n, owed = self._moe_mlp(
                     lp["mlp"], x_normed, None, train=False, used_token=used,
-                    with_owed=True, mixer_in=mixer_in)
+                    with_owed=True, mixer_in=mixer_in, stack=stack)
             if not counts:
                 return out
             return out, jnp.append(n, owed)
+        if self._grouped_kernel(params, used.shape[0]):
+            mlp_fn.stack_keys = self._expert_keys()
         return mlp_fn
 
     def forward_paged_prefill(self, params, tokens, pools, slots, last_idx,
                               state_slot=None):
-        mlp_fn = self._paged(pools, slots)
+        mlp_fn = self._paged(params, pools, slots)
         return T.forward_paged_prefill(self.config, params, tokens, pools,
                                        slots, last_idx, mlp_fn=mlp_fn,
                                        state_slot=state_slot)
@@ -510,14 +594,14 @@ class MoECausalLM:
     def forward_paged_prefill_chunk(self, params, tokens, pools,
                                     block_tables, slots, start_pos, last_idx,
                                     state_slot=None):
-        mlp_fn = self._paged(pools, slots)
+        mlp_fn = self._paged(params, pools, slots)
         return T.forward_paged_prefill_chunk(
             self.config, params, tokens, pools, block_tables, slots,
             start_pos, last_idx, mlp_fn=mlp_fn, state_slot=state_slot)
 
     def forward_paged_verify(self, params, tokens, pools, block_tables,
                              slots, pos):
-        mlp_fn = self._paged(pools, slots)
+        mlp_fn = self._paged(params, pools, slots)
         return T.forward_paged_verify(self.config, params, tokens, pools,
                                       block_tables, slots, pos, mlp_fn=mlp_fn)
 
@@ -529,7 +613,7 @@ class MoECausalLM:
         engine's ``serving/moe_*`` counters are fed."""
         bs = pools["k"].shape[2]
         slots = block_tables[jnp.arange(pos.shape[0]), pos // bs] * bs + pos % bs
-        mlp_fn = self._paged(pools, slots, counts=True)
+        mlp_fn = self._paged(params, pools, slots, counts=True)
         return T.forward_paged_decode(self.config, params, tokens, pools,
                                       block_tables, pos, pad_bias, mlp_fn=mlp_fn,
                                       state_slots=state_slots)
@@ -541,7 +625,7 @@ class MoECausalLM:
         bs = pools["k"].shape[2]
         slots = (block_tables[jnp.arange(pos.shape[0]), pos // bs] * bs
                  + pos % bs)[:, None] + jnp.zeros_like(tokens)
-        mlp_fn = self._paged(pools, slots, counts=True)
+        mlp_fn = self._paged(params, pools, slots, counts=True)
         return T.forward_paged_block(self.config, params, tokens, pools,
                                      block_tables, pos, mlp_fn=mlp_fn)
 

@@ -1780,7 +1780,12 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
     lp_attn, wk, wv, block0)``. No per-layer slice of
     a pool exists and nothing is stacked. An ``mlp_fn`` may return ``(out,
     aux)``: ``aux`` (an MoE layer's per-expert counts) comes back stacked
-    over the layers, else None. Returns (x, new pools, aux)."""
+    over the layers, else None. An ``mlp_fn`` with ``stack_keys`` (leaves of
+    a layer's ``mlp`` that it reads from the stack IN PLACE, as the pools
+    are read: an MoE's expert matrices under the grouped kernel) gets those
+    leaves WHOLE, ``[n_periods, ...]`` each, as ``stack=(leaves, p)`` with
+    ``p`` the layer's index in them; they are closed over and no slice of
+    them is made. Returns (x, new pools, aux)."""
     period = cfg.period
     n_att, n_lin = period.count(ATTENTION), period.count(LINEAR_ATTENTION)
     n_win = period.count(WINDOW_ATTENTION)
@@ -1789,6 +1794,12 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
     Nwb = pools["wk"].shape[1] if n_win else 0
     groups = params["layers"] if cfg.layer_kinds is not None \
         else (params["layers"],)
+    stack_keys = getattr(mlp_fn, "stack_keys", ())
+    if stack_keys:
+        whole = [{k: g["mlp"][k] for k in stack_keys} for g in groups]
+        groups = tuple(
+            {**g, "mlp": {k: w for k, w in g["mlp"].items()
+                          if k not in stack_keys}} for g in groups)
 
     def run_period(carry, xs):
         h, flat = carry
@@ -1799,13 +1810,14 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
         mfn = mlp_fn
         if mlp_fn is not None:
             def mfn(c, xx, lpp, xa):
-                out = mlp_fn(c, xx, lpp, xa)
+                kw = {"stack": (whole[j], p)} if stack_keys else {}
+                out = mlp_fn(c, xx, lpp, xa, **kw)
                 if isinstance(out, tuple):
                     out, a = out
                     aux.append(a)
                 return out
         i_att = i_lin = i_win = 0
-        for kind, lp in zip(period, lps):
+        for j, (kind, lp) in enumerate(zip(period, lps)):
             if kind == ATTENTION:
                 l = p * n_att + i_att
                 i_att += 1

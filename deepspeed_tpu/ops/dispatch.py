@@ -25,6 +25,13 @@ Sites and their forms:
                       layer's one-token state update: the Pallas kernel
                       over the live rows' slots | ``kda_recurrent_step``
                       over the layer's whole slice of the pool)
+``experts``           ``grouped_kernel`` | ``dense`` | ``ragged`` (a no-drop
+                      MoE layer's expert matmuls, from the rows of a call:
+                      the Pallas kernel over the touched experts of the
+                      layer stack in place, a paged program's calls of at
+                      most 128 rows on one device | every held expert over
+                      every row | ``jax.lax.ragged_dot`` over sorted rows,
+                      from 1,536 rows on)
 ``kernel/<name>``     ``compiled`` | ``interpret`` | ``jnp`` (one per Pallas
                       entry point; ``jnp`` = the kernel's plain-XLA twin)
 ====================  ====================================================

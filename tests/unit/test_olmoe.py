@@ -30,6 +30,7 @@ from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.moe_lm import MoECausalLM, MoEConfig
 from deepspeed_tpu.models.presets import get_model
 from deepspeed_tpu.moe import sharded_moe
+from deepspeed_tpu.ops import dispatch
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__)))), "perfbench")
@@ -256,6 +257,42 @@ def test_the_form_follows_the_rows_of_the_call():
         assert ("ragged_dot" in jaxpr) == ragged, rows
 
 
+@pytest.mark.parametrize("case,rows,want", [
+    ("a_decode_step", 16, True),
+    ("one_row_tile", moe_lm._GROUPED_KERNEL_MAX_ROWS, True),
+    ("a_row_more", moe_lm._GROUPED_KERNEL_MAX_ROWS + 1, False),
+    ("the_xla_backend", 16, False),
+    ("off_a_tpu_on_auto", 16, False),
+    ("int8_experts", 16, False),
+    ("ungated_experts", 16, False),
+    ("a_mesh_of_eight", 16, False),
+])
+def test_the_paged_form_follows_the_rows_and_where_a_kernel_is_legal(
+        case, rows, want):
+    """A paged program asks the layer scan for the expert stacks whole
+    (``stack_keys``: the grouped kernel) by the rows of its calls, where a
+    bare Pallas call is legal, for plain gated experts; no option chooses."""
+    from deepspeed_tpu.ops.quant import quantize_int8
+    backend = {"the_xla_backend": "xla", "off_a_tpu_on_auto": "auto"}.get(
+        case, "flash")
+    cfg = T.TransformerConfig(vocab_size=64, n_layer=2, n_head=2, d_model=16,
+                              max_seq=32, remat=False, attention_backend=backend)
+    model = MoECausalLM(cfg, MoEConfig(
+        dispatch="nodrop", num_experts=4, k=2, expert_d_ff=8,
+        expert_activation="gelu" if case == "ungated_experts" else "swiglu"))
+    params = model.init_params(jax.random.key(0))
+    if case == "int8_experts":
+        params["layers"]["mlp"]["w_up"] = quantize_int8(
+            params["layers"]["mlp"]["w_up"])
+    if case == "a_mesh_of_eight":
+        dist.set_mesh(dist.build_mesh({"dp": 8}))
+    pools = model.init_paged_cache(4, 16, dtype=jnp.float32)
+    mlp_fn = model._paged(params, pools, jnp.zeros((rows,), jnp.int32))
+    assert (getattr(mlp_fn, "stack_keys", None) is not None) == want
+    if want:
+        assert mlp_fn.stack_keys == ("w_gate", "w_up", "w_down")
+
+
 def _per_head_qk_norm(cfg, q, k, lp):
     def rms(x, p):
         h = x.reshape(*x.shape[:-1], cfg.n_head, cfg.head_dim)
@@ -359,6 +396,50 @@ def test_paged_prefill_then_decode_against_the_reference(toy, form):
         assert counts.shape == (L, model.moe.num_experts + 1)
         np.testing.assert_array_equal(counts[:, :-1].sum(axis=1), 2 * k)
         np.testing.assert_array_equal(counts[:, -1], 2 * k)   # what was owed
+
+
+def test_paged_prefill_then_decode_through_the_grouped_kernel(toy):
+    """The paged programs with the experts read by
+    ``ops/pallas/grouped_expert_mlp.py`` (interpreted) from the layer stack
+    in place: what they select on one TPU for calls of at most
+    ``_GROUPED_KERNEL_MAX_ROWS`` rows, chosen here as the paged kernel is
+    (``attention_backend="flash"``; no mesh, so a bare kernel is legal). A
+    prompt in a padded bucket, then decode steps over three rows of which
+    two are empty, logits against the reference's full forward."""
+    with open(os.path.join(BENCH, "configs", TOY + ".json")) as f:
+        preset = json.load(f)["preset"]
+    model, params = get_model(**preset, attention_backend="flash"), toy[1]
+    bs, rows, n, S = 16, 3, 21, 29
+    seq = tokens_of(8, (S,))
+    want = reference_logits(toy, seq[None])[0]
+    pools = model.init_paged_cache(6, bs, dtype=jnp.float32)
+    table = np.asarray([3, 1, 4, 0], np.int32)
+    dispatch.reset()
+    t = np.arange(32)
+    toks = np.zeros((1, 32), np.int32)
+    toks[0, :n] = seq[:n]
+    slots = np.where(t < n, table[t // bs] * bs + t % bs, t % bs).astype(np.int32)
+    logits, pools = jax.jit(model.forward_paged_prefill)(
+        params, toks, pools, slots, np.int32(n - 1))
+    assert np.abs(np.asarray(logits)[0] - want[n - 1]).max() < LOGIT_TOL
+    decode = jax.jit(model.forward_paged_decode)
+    bt = np.zeros((rows, 4), np.int32)
+    bt[2] = table
+    for p in range(n, S):
+        pos = np.zeros((rows,), np.int32)
+        pos[2] = p
+        nt = np.zeros((rows, 1), np.int32)
+        nt[2, 0] = seq[p]
+        logits, pools, counts = decode(params, nt, pools, bt, pos)
+        assert np.abs(np.asarray(logits)[2] - want[p]).max() < LOGIT_TOL, p
+        # the one real row's assignments, computed and owed; none of the
+        # empty rows'
+        np.testing.assert_array_equal(np.asarray(counts).sum(axis=1),
+                                      2 * model.moe.k)
+    chosen = dispatch.selected()
+    assert chosen["experts=grouped_kernel"] == 2     # the bucket and the step
+    assert chosen["kernel/grouped_expert_mlp=interpret"] == 2
+    assert "experts=dense" not in chosen
 
 
 def test_generate_batch_pages_an_moe_model_and_counts_it(toy):

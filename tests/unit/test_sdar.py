@@ -241,6 +241,22 @@ def test_pass_logits_are_the_references(toy, recorded, n_prompt):
     assert worst(pairs) <= LOGIT_TOL, worst(pairs)
 
 
+def test_pass_logits_through_the_grouped_expert_kernel(toy, recorded):
+    """The same passes with the experts read by
+    ``ops/pallas/grouped_expert_mlp.py`` (interpreted) from the layer stack
+    in place: a share of the router's experts, a pass's 4 positions the
+    call's rows, the prefill's 32-token bucket too."""
+    from deepspeed_tpu.ops import dispatch
+    flash = (load_toy(attention_backend="flash")[0], *toy[1:])
+    dispatch.reset()
+    pairs = replay(flash, *recorded[9])
+    chosen = dispatch.selected()
+    assert chosen["experts=grouped_kernel"] >= len(pairs) + 1
+    assert "experts=dense" not in chosen
+    assert len(pairs) >= 8
+    assert worst(pairs) <= LOGIT_TOL, worst(pairs)
+
+
 @pytest.mark.parametrize("rule", ["low_confidence_static",
                                   "low_confidence_dynamic"])
 def test_pass_logits_under_a_confidence_order(rule):

@@ -116,6 +116,29 @@ def test_prefill_then_decode_is_the_reference(toy, n_prompt):
     assert np.abs(got - want).max() < LOGIT_TOL, np.abs(got - want).max(axis=-1)
 
 
+@pytest.mark.parametrize("n_prompt", [60, 300])
+def test_the_grouped_expert_kernel_is_the_reference_too(toy, n_prompt):
+    """The paged programs on their Pallas forms, interpreted: a period of
+    four layers, one stack of experts a position of the period read in place
+    by ``ops/pallas/grouped_expert_mlp.py`` through the period's offset (the
+    decode step's 3 rows; the 64-token bucket too, the 512-token one keeps
+    the dense form), the router fed the mixer's input, the ReLU gate."""
+    from deepspeed_tpu.ops import dispatch
+    with open(os.path.join(BENCH, "configs", TOY + ".json")) as f:
+        preset = json.load(f)["preset"]
+    model = get_model(**preset, attention_backend="flash")
+    tokens = np.random.default_rng(n_prompt).integers(
+        0, 512, n_prompt + 12).astype(np.int32)
+    dispatch.reset()
+    got = paged_logits(model, toy[1], tokens, n_prompt, bs=128)
+    chosen = dispatch.selected()
+    n_layer = len(model.config.period)
+    assert chosen["experts=grouped_kernel"] == n_layer * (2 if n_prompt == 60 else 1)
+    assert chosen.get("experts=dense", 0) == n_layer * (n_prompt != 60)
+    want = reference_logits(toy, tokens)[n_prompt - 1:]
+    assert np.abs(got - want).max() < LOGIT_TOL, np.abs(got - want).max(axis=-1)
+
+
 @pytest.mark.parametrize("bs,n_prompt", [(16, 250), (16, 300), (64, 500)])
 def test_a_ring_of_many_small_blocks_too(toy, bs, n_prompt):
     """Block 16: a ring of 17 blocks, a decode that crosses block borders

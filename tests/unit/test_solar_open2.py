@@ -273,6 +273,25 @@ def test_the_decode_kernel_against_the_full_forward():
             assert float(jnp.abs(st[0, slot] - 3.0).max()) == 0
 
 
+def test_the_grouped_expert_kernel_against_the_full_forward():
+    """The paged programs' experts through
+    ``ops/pallas/grouped_expert_mlp.py`` (interpreted): a SHARE of the
+    router's experts (choices held elsewhere reach no expert here), sigmoid
+    scores with the selection bias, the shared expert beside them, one
+    stack of experts a position of the period read in place. The prefill's
+    128-row bucket and the decode step's 3 rows both take the kernel."""
+    from deepspeed_tpu.ops import dispatch
+    flash = load_toy("flash")
+    dispatch.reset()
+    tokens = tokens_of(33, 70 + 12)
+    got, _ = paged_logits(*flash[:2], tokens, 70)
+    chosen = dispatch.selected()
+    assert chosen["experts=grouped_kernel"] == 2 * len(flash[0].config.period)
+    assert "experts=dense" not in chosen
+    want = reference_logits(flash, tokens)[69:]
+    assert np.abs(got - want).max() <= LOGIT_TOL, np.abs(got - want).max()
+
+
 def test_a_bf16_state_fails_the_tolerance(toy, monkeypatch):
     """The control of LOGIT_TOL: the same run with the recurrent state
     rounded to bf16 after every update, as a state pool kept in bf16 would

@@ -643,6 +643,123 @@ def test_sdar_toy_logits_through_the_compiled_kernels(tpu):
     assert err["auto"] <= max(2 * err["xla"], 1e-4), err
 
 
+# the four routed cells' calls: rows, top-k, held experts, of a router's, D, F
+EXPERT_CELLS = {
+    "smallthinker": (16, 6, 64, 64, 2560, 768),
+    "olmoe": (64, 8, 64, 64, 2048, 1024),
+    "sdar_bucket": (128, 8, 16, 128, 2048, 768),
+    "solar": (128, 8, 40, 320, 4096, 1280),
+}
+
+
+@tpu_tier
+@pytest.mark.parametrize("cell", sorted(EXPERT_CELLS))
+def test_grouped_expert_mlp_compiles_and_matches(tpu, cell):
+    """The COMPILED grouped expert kernel at a routed cell's widths, layer 1
+    of a two-layer stack read through the layer offset, against its
+    plain-XLA twin over that layer's slice: bf16 operands, float32 sums.
+    Both round the hidden values to bf16 (the twin after the routing
+    weight, the kernel before it), so they differ by that rounding."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.moe.sharded_moe import dense_dispatch, topk_routing
+    from deepspeed_tpu.ops.pallas.grouped_expert_mlp import (
+        dense_expert_mlp, grouped_expert_mlp)
+
+    T, k, E, R, D, F = EXPERT_CELLS[cell]
+    ks = jax.random.split(jax.random.key(40), 5)
+    draw = lambda key, shape, std: (  # noqa: E731
+        jax.random.normal(key, shape, jnp.float32) * std).astype(jnp.bfloat16)
+    w_gate, w_up = draw(ks[0], (2 * E, D, F), 0.02), draw(ks[1], (2 * E, D, F), 0.02)
+    w_down = draw(ks[2], (2 * E, F, D), 0.02)
+    x = draw(ks[3], (T, D), 1.0)
+    weights, experts, _ = topk_routing(jax.random.normal(ks[4], (T, R)), k, True)
+    experts = jnp.where(experts < E, experts, E)       # held elsewhere
+    valid = (jnp.arange(T) % 5 != 4).astype(jnp.int32)  # padding rows
+
+    @jax.jit
+    def both(x, w_gate, w_up, w_down):
+        got, n = dense_dispatch(
+            x, weights, experts, E,
+            lambda xs, c: grouped_expert_mlp(xs, c, w_gate, w_up, w_down,
+                                             jnp.int32(E), interpret=False),
+            valid)
+        want, _ = dense_dispatch(
+            x, weights, experts, E,
+            lambda xs, c: dense_expert_mlp(xs, c, w_gate[E:], w_up[E:],
+                                           w_down[E:]), valid)
+        return got, want, n
+
+    got, want, n = both(x, w_gate, w_up, w_down)
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    assert 0 < int((np.asarray(n) > 0).sum()) <= E
+    scale = float(np.abs(want).max())
+    assert scale > 0
+    assert float(np.abs(got - want).max()) <= 0.02 * scale, (
+        float(np.abs(got - want).max()), scale)
+    assert not got[np.asarray(valid) == 0].any()
+
+
+def expert_stack_moves(hlo_text, n, E, D, F):
+    """The instructions of a compiled program that MAKE an array of the
+    shape of a layer's experts (``[E, D, F]`` / ``[E, F, D]``: a slice or a
+    copy of a layer) or make a whole stack (``[n, E, ...]`` or its merged
+    view ``[n * E, ...]``) by anything but a parameter, a tuple element or
+    a bitcast."""
+    import re
+    mats = rf"({D},{F}|{F},{D})\]"
+    layer = re.compile(rf"^bf16\[{E},{mats}")
+    stack = re.compile(rf"^bf16\[({n},{E}|{n * E}),{mats}")
+    moves = []
+    for line in hlo_text.splitlines():
+        made = re.match(r"\s*(?:ROOT )?%\S+ = (\S+) ([\w\-]+)\(", line)
+        if not made:
+            continue
+        shape, op = made.groups()
+        if layer.match(shape) or (stack.match(shape) and op not in (
+                "parameter", "get-tuple-element", "bitcast")):
+            moves.append(line.strip()[:240])
+    return moves
+
+
+@tpu_tier
+def test_the_decode_program_reads_the_expert_stacks_in_place(tpu):
+    """The compiled decode program of a scanned MoE stack at lane-whole toy
+    widths, on one device: the experts are the grouped kernel's custom call
+    and the layer stacks reach it through bitcasts alone: no instruction
+    makes a layer's slice of an expert stack or a copy of one
+    (``expert_stack_moves``), as the same program on the dense form does."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models.moe_lm import MoECausalLM, MoEConfig
+    from deepspeed_tpu.ops import dispatch
+
+    n, E, D, F, rows = 4, 8, 2048, 1024, 4
+    texts = {}
+    for backend in ("auto", "xla"):
+        model = MoECausalLM(
+            T.TransformerConfig(vocab_size=512, n_layer=n, n_head=16, d_model=D,
+                                max_seq=512, remat=False,
+                                attention_backend=backend),
+            MoEConfig(dispatch="nodrop", num_experts=E, k=2, expert_d_ff=F,
+                      expert_activation="swiglu"), param_dtype=jnp.bfloat16)
+        params = jax.jit(model.init_params)(jax.random.key(0))
+        pools = model.init_paged_cache(9, 128, jnp.bfloat16)
+        args = (params, jnp.ones((rows, 1), jnp.int32), pools,
+                jnp.ones((rows, 2), jnp.int32), jnp.full((rows,), 5, jnp.int32))
+        dispatch.reset()
+        texts[backend] = jax.jit(model.forward_paged_decode).lower(
+            *args).compile().as_text()
+        assert ("experts=grouped_kernel" in dispatch.selected()) \
+            == (backend == "auto")
+    assert "grouped_expert_mlp" in texts["auto"]
+    assert expert_stack_moves(texts["auto"], n, E, D, F) == []
+    assert expert_stack_moves(texts["xla"], n, E, D, F)      # the check bites
+
+
 @tpu_tier
 @pytest.mark.parametrize("remat,forwards", [("dots", 1), (True, 2)])
 def test_dots_runs_the_forward_flash_kernel_once_a_layer(tpu, remat, forwards):
