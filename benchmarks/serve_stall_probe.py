@@ -9,8 +9,9 @@ takes ``perfbench/run.py``'s own arguments (``--stacks`` and
 ``--trace-seconds``, a trace of another length than the cell's, are its own)
 and prints, beside its lines,
 
-* ``stall counters over the window``: the growth of ``host/gc_*`` and of
-  ``serving/decode_steps{,_ahead,_late}`` between the window's marks, and the
+* ``stall counters over the window``: the growth of ``host/gc_*``, of
+  ``serving/decode_steps{,_ahead,_late}`` and of ``serving/prefill_{steps,
+  tokens,padded_tokens}`` between the window's marks, and the
   mean full (generation 2) pause, ``host/gc_full_pause_ms`` over
   ``host/gc_full_collections``: the per-layer metrics hold the count and the
   share, no line of the harness holds the pause;
@@ -60,7 +61,11 @@ sys.path[:0] = [ROOT, os.path.join(ROOT, "perfbench")]
 
 COUNTERS = ("host/gc_pause_ms", "host/gc_full_pause_ms",
             "host/gc_full_collections", "serving/decode_steps",
-            "serving/decode_steps_ahead", "serving/decode_steps_late")
+            "serving/decode_steps_ahead", "serving/decode_steps_late",
+            # what a window holds of prompts: a seed's draw of them moves a
+            # closed loop's tokens/s (PERF.md section 6, PR 43)
+            "serving/prefill_steps", "serving/prefill_tokens",
+            "serving/prefill_padded_tokens")
 
 
 def stall_counters(marks):

@@ -109,7 +109,16 @@ class CausalLM:
         if cfg.embed_layernorm:
             final_norm += (2 if cfg.norm == "layernorm" else 1) * cfg.d_model
         head = 0 if cfg.tie_embeddings else cfg.d_model * cfg.vocab_size
-        return embed + cfg.n_layer * (attn + mlp + norms) + final_norm + head
+        # a Mamba-2 layer's mixer in an attention layer's place: the input
+        # and output projections, the conv with its bias, A_log, dt_bias, D
+        # and the gated norm's scale
+        inner = cfg.ssm_heads * cfg.ssm_head_dim
+        conv_dim = inner + 2 * cfg.ssm_state
+        ssm = cfg.d_model * (2 * inner + conv_dim + cfg.ssm_heads) \
+            + conv_dim * (cfg.ssm_conv_kernel + 1) + 3 * cfg.ssm_heads + inner
+        n_ssm = cfg.layers_of(T.MAMBA2)
+        return embed + cfg.n_layer * (mlp + norms) + n_ssm * ssm \
+            + (cfg.n_layer - n_ssm) * attn + final_norm + head
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Approximate training FLOPs/token (6N + attention term)."""

@@ -496,7 +496,7 @@ class MoECausalLM:
 
     def forward(self, params, tokens, attn_mask=None, rng=None, train: bool = True):
         cfg = self.config
-        T._no_layer_pattern(cfg, "the training forward (and its scan's backward)")
+        T._paged_path_only(cfg, "the training forward (and its scan's backward)")
         B, S = tokens.shape
         x = params["embed"]["tokens"][tokens]
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None, :], (B, S))

@@ -300,6 +300,86 @@ def smallthinker(size: str = "21b-a3b-12l", **over):
         router_input="mixer_input"), **moe}), param_dtype=param_dtype)
 
 
+def granite_hybrid(size: str = "4.0-h-micro", **over) -> CausalLM:
+    """granite-4.0-h-micro (``ibm-granite/granite-4.0-h-micro`` config.json,
+    ``model_type`` ``granitemoehybrid``): 40 pre-RMSNorm layers (eps 1e-5)
+    of d 2,048 WITHOUT positions, in periods of ten: five Mamba-2 layers, a
+    softmax GQA layer (32 query and 8 key/value heads of 64, no bias, its
+    scores times ``attention_multiplier`` 1/64, not 1/sqrt(64)), four more
+    Mamba-2 layers (64 heads of 64 channels, a float32 state of 128 a
+    channel, one group of B and C, a causal conv of 4 taps with a bias over
+    4,352 channels, SSD chunks of 256); in every layer a dense gated-SiLU
+    MLP of width 8,192 (``num_local_experts`` 0: the ``shared_mlp`` alone);
+    a tied head over 100,352 rows; and Granite's scalars: the embedding
+    times 12, every branch times 0.22 before it joins the residual stream,
+    the logits over 8. 3,191,396,096 parameters, 6.38 GB in bf16: ONE
+    v5e CHIP SERVES THE WHOLE MODEL, nothing cut (``reduced`` is empty in
+    perfbench's ``granite4hmicro_serve_chat``). ``max_seq`` is what the
+    deployment serves (it sizes the block tables; the model has no
+    positions to run out of).
+    Its seeded init: ``A_log`` = log U(1, 16), ``dt_bias`` the inverse
+    softplus of a log-uniform (1e-3, 1e-1), ``D`` 1, the conv U(-1/2, 1/2)
+    (the family's own draws), output projections depth-scaled, and EVERY
+    MATRIX AT 0.0235 OVER A TOKEN EMBEDDING AT 0.004 (``init_std`` /
+    ``embed_init_std``: the two knobs moved, on the chip, with
+    ``benchmarks/granite_check_controls.py``; PERF.md section 6, PR 43). At
+    the published scheme's 0.02 / 0.02 the residual stream is 12 x 0.02 =
+    0.24 of embedding against 0.22 of everything the 40 layers add, the
+    tied head serves the INPUT token back whatever the layers do, and a
+    lost state, a dropped conv state and a residual multiplier of 1.0 all
+    pass the served check (100% of served tokens the token they were
+    computed from). A fault inside a Mamba-2 mixer turns the layers' sum
+    without lengthening it (the gated norm fixes the mixer's output), so an
+    echoed token sees none of them: the check sees them where the SOUND
+    model leaves the echo and the faulted one does not, and on those tokens
+    a sound program's bf16 noise is read against the check's 4 bf16 steps
+    at the magnitude of the largest logit. The embedding at 0.004 puts the
+    largest logit of a token on the edge of the echo just over 0.0625,
+    where 4 steps are 3.1% of it (at 0.02 / 0.003 it lies just under, 4
+    steps are 1.6%, and sound prompts read 3.1 and 3.4 of 4 with the
+    state's faults seen on 1 prompt in 16); the matrices at 0.0235 let 1-2%
+    of sound tokens leave the echo: sound prompts 0 of 320 refused, worst
+    2.63 of 4; a lost state refused on 10 of 64 prompts, a dropped conv
+    state on 9 of 64 (readings of 6-198 steps), a residual multiplier of
+    1.0 on 16 of 16; a state rounded to bf16 and the attention's scale on
+    none. At 0.022 nothing of the state is seen (0 of 32), at 0.025 it is
+    seen on 4 prompts in 10 and sound prompts read 3.5, at 0.027 on 8 in
+    10 and 2 of 64 SOUND prompts are refused: no seeded init refuses the
+    state's faults on most prompts and keeps the room. What the tokens
+    cannot hold, the logits do (``tests/unit/test_granite_hybrid.py``; the
+    same tool's ``--logits`` on the chip and ``--logits --float32``).
+    ``tiny`` is one whole published period of ten (attention sixth) at toy
+    widths, every multiplier at its published value, IN THE PUBLISHED
+    WIDTHS' REGIME: its matrices at 0.113 = 0.02 sqrt(2048 / 64), so that
+    its projections' outputs are as large as the model's (at 0.02 over d 64
+    its state is a thousandth of ``D x`` and losing it moves the logits by
+    3e-6 of 0.17), and its embedding at 0.006, where its ten layers and not
+    the x12 embedding decide the logits (the served check of its rehearsal
+    refuses a wrong multiplier and a dropped conv state; a state rounded to
+    bf16 moves its logits by 6.7e-6, a lost one by 2e-3:
+    ``tests/unit/test_granite_hybrid.py``)."""
+    dims = {
+        "tiny": dict(n_layer=10, n_head=4, n_kv_head=2, head_size=16,
+                     d_model=64, d_ff=128, vocab_size=512, max_seq=1024,
+                     ssm_heads=4, ssm_head_dim=32, ssm_state=16, ssm_chunk=8,
+                     init_std=0.113, embed_init_std=0.006),
+        "4.0-h-micro": dict(n_layer=40, n_head=32, n_kv_head=8, head_size=64,
+                            d_model=2048, d_ff=8192, vocab_size=100352,
+                            max_seq=2048, ssm_heads=64, ssm_head_dim=64,
+                            ssm_state=128, ssm_chunk=256,
+                            init_std=0.0235, embed_init_std=0.004),
+    }[size]
+    param_dtype = over.pop("param_dtype", jnp.float32)
+    published = dict(
+        pos_embedding="none", norm="rmsnorm", norm_eps=1e-5,
+        activation="swiglu", tie_embeddings=True, attn_bias=False,
+        attn_scale=0.015625, embedding_multiplier=12.0,
+        residual_multiplier=0.22, logits_scaling=8.0, ssm_conv_kernel=4,
+        layer_kinds=("mamba2",) * 5 + ("attention",) + ("mamba2",) * 4)
+    return CausalLM(TransformerConfig(**{**published, **dims, **over}),
+                    param_dtype=param_dtype)
+
+
 MODEL_PRESETS: Dict[str, Callable] = {
     "gpt2": gpt2,
     "llama": llama,
@@ -310,6 +390,7 @@ MODEL_PRESETS: Dict[str, Callable] = {
     "solar_open2": solar_open2,
     "sdar": sdar,
     "smallthinker": smallthinker,
+    "granite_hybrid": granite_hybrid,
 }
 
 

@@ -32,6 +32,11 @@ from deepspeed_tpu.ops import dispatch
 ATTENTION, LINEAR_ATTENTION = "attention", "linear_attention"
 #: softmax attention over the last ``attn_window`` positions only
 WINDOW_ATTENTION = "window_attention"
+#: the Mamba-2 mixer: a state-space recurrence in its SSD form
+MAMBA2 = "mamba2"
+#: the kinds whose layers keep a recurrent state and a conv state in a slot
+#: a request, each with the key of its mixer's parameters in a layer group
+STATE_MIXERS = {LINEAR_ATTENTION: "lin", MAMBA2: "ssm"}
 
 
 #: the unmasking rules of a block-diffusion model's generation
@@ -114,13 +119,15 @@ class TransformerConfig:
     attn_out_gate: bool = False
     # ONE period of the stack's layer pattern, the kinds of its mixers in
     # order ("attention": softmax attention over the KV cache;
-    # "linear_attention": the gated delta rule with a recurrent state, the
-    # lin_* sizes below; "window_attention": softmax attention whose query
+    # "linear_attention": the gated delta rule (KDA) with a recurrent state,
+    # the lin_* sizes below; "mamba2": the Mamba-2 state-space recurrence,
+    # the ssm_* sizes; "window_attention": softmax attention whose query
     # at i sees keys i - attn_window < j <= i, the attention layer's
     # parameters); the stack is n_layer / len(layer_kinds) periods.
     # None: every layer is "attention". A kind says what cache it keeps
-    # (``cache_spec``): KV blocks, a state slot a request, or a ring of KV
-    # blocks a request that holds the window and no more.
+    # (``cache_spec``): KV blocks, a state slot a request (both recurrent
+    # kinds, each with its own state shapes: ``state_shapes``), or a ring
+    # of KV blocks a request that holds the window and no more.
     layer_kinds: Optional[Tuple[str, ...]] = None
     # keys a window_attention layer's query sees, its own among them
     attn_window: int = 0
@@ -132,6 +139,25 @@ class TransformerConfig:
     # (its two factored gate projections have rank lin_head_dim)
     lin_heads: int = 0
     lin_head_dim: int = 0
+    # the Mamba-2 mixer: ``ssm_heads`` heads of ``ssm_head_dim`` channels
+    # (their product is the mixer's inner width), a state of ``ssm_state``
+    # a channel, B and C of ONE group shared by all heads, a causal
+    # depthwise conv of ``ssm_conv_kernel`` taps, and the tokens a chunk of
+    # the prompt's chunked (SSD) form
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 256
+    # Granite's scalars, each 1.0 where a model has none: the token
+    # embedding is multiplied by ``embedding_multiplier``, every branch by
+    # ``residual_multiplier`` before it joins the residual stream, and the
+    # logits are divided by ``logits_scaling`` (its ``attention_multiplier``
+    # is ``attn_scale``). Applied on the paged serving path; the training
+    # forward and the dense-workspace cache refuse a model that sets one.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # numerics
     rope_theta: float = 10000.0
     rope_dim: int = 0                    # 0 = full head dim; else partial
@@ -235,14 +261,25 @@ class TransformerConfig:
     def cache_spec(self) -> Dict[str, int]:
         """What the stack keeps between steps, by cache: ``kv`` the layers
         that hold KV blocks, ``state`` those that hold a recurrent state
-        and a conv state in a slot a request, ``window`` those that hold
+        and a conv state in a slot a request (KDA and Mamba-2 layers
+        alike: ``STATE_MIXERS``), ``window`` those that hold
         the last ``attn_window`` positions' KV in a ring of blocks a
         request. What cannot hold with a state or a ring yet (a prefix
         hit, a verify window's rewind, a block moved to the host) is
         refused from here, not by an option."""
         return {"kv": self.layers_of(ATTENTION),
-                "state": self.layers_of(LINEAR_ATTENTION),
+                "state": sum(self.layers_of(k) for k in STATE_MIXERS),
                 "window": self.layers_of(WINDOW_ATTENTION)}
+
+    def state_shapes(self, kind: str):
+        """What one layer of a recurrent ``kind`` keeps a request: the
+        shape of its float32 state and of its conv state (the last K - 1
+        inputs of its causal conv)."""
+        if kind == MAMBA2:
+            H, Pd, N = self.ssm_heads, self.ssm_head_dim, self.ssm_state
+            return (H, Pd, N), (self.ssm_conv_kernel - 1, H * Pd + 2 * N)
+        H, dk = self.lin_heads, self.lin_head_dim
+        return (H, dk, dk), (KDA_CONV_KERNEL - 1, 3 * H * dk)
 
     def ring_blocks(self, block_size: int) -> int:
         """Blocks of a request's ring in a window layer: the window's, and
@@ -273,7 +310,7 @@ class TransformerConfig:
 
 def _check_pattern(cfg: TransformerConfig):
     kinds = cfg.period
-    known = (ATTENTION, LINEAR_ATTENTION, WINDOW_ATTENTION)
+    known = (ATTENTION, LINEAR_ATTENTION, WINDOW_ATTENTION, MAMBA2)
     bad = [k for k in kinds if k not in known]
     if bad:
         raise ValueError(f"layer_kinds {kinds}: unknown kind {bad[0]!r} "
@@ -286,13 +323,17 @@ def _check_pattern(cfg: TransformerConfig):
     if LINEAR_ATTENTION in kinds and not (cfg.lin_heads and cfg.lin_head_dim):
         raise ValueError("a linear_attention layer needs lin_heads and "
                          "lin_head_dim")
+    if MAMBA2 in kinds and not (cfg.ssm_heads and cfg.ssm_head_dim
+                                and cfg.ssm_state):
+        raise ValueError("a mamba2 layer needs ssm_heads, ssm_head_dim and "
+                         "ssm_state")
 
 
 def _layer_group(cfg: TransformerConfig, kind: str, n: int, ks, dtype):
     """The stacked parameters of ``n`` like layers of ``kind`` (leading dim
     ``n``, what ``lax.scan`` runs one compiled block over) from the keys
-    ``ks``: the mixer under ``attn`` or ``lin``, its norm, the dense MLP and
-    its norm (a window layer's are an attention layer's)."""
+    ``ks``: the mixer under ``attn``, ``lin`` or ``ssm``, its norm, the
+    dense MLP and its norm (a window layer's are an attention layer's)."""
     std = cfg.init_std
     D, F = cfg.d_model, cfg.ff_dim
     H, KV, Hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
@@ -309,7 +350,9 @@ def _layer_group(cfg: TransformerConfig, kind: str, n: int, ks, dtype):
         return (jax.random.normal(key, shape) * scale).astype(dtype)
 
     group = {"ln_attn": norm_params()}
-    if kind != LINEAR_ATTENTION:
+    if kind == MAMBA2:
+        group["ssm"] = _init_mamba2(cfg, n, ks[8], dtype, out_std)
+    elif kind != LINEAR_ATTENTION:
         group["attn"] = {
             "wq": dense(ks[0], (n, D, H * Hd)),
             "wk": dense(ks[1], (n, D, KV * Hd)),
@@ -1304,6 +1347,8 @@ def cached_embed(cfg: TransformerConfig, params, tokens, pos, dtype):
     vector (per-request offsets, the paged continuous-batching path)."""
     B, T = tokens.shape
     x = params["embed"]["tokens"][tokens].astype(dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     positions = jnp.asarray(pos, jnp.int32).reshape(-1, 1) \
         + jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None, :], (B, T))
     if cfg.pos_embedding == "learned":
@@ -1318,21 +1363,26 @@ def _decode_block(cfg: TransformerConfig, h, lp, mix_fn, mlp_fn=None,
     """The ONE pre-LN residual wiring of every cache-decode block (dense
     workspace via :func:`cached_block`, paged prefill and paged decode):
     ``mix_fn(x_normed)`` returns (mixer_out, *the mixer's new caches): k and
-    v of an attention layer, state and conv state of a linear-attention
-    one, under ``scope``; ``mlp_fn(cfg, x_normed, lp, mixer_in)`` overrides
-    the dense MLP (MoE; ``mixer_in`` is what the mixer read, for a router
-    placed before attention). Returns (h, *new caches)."""
+    v of an attention layer, state and conv state of a recurrent one (KDA,
+    Mamba-2), under ``scope``; ``mlp_fn(cfg, x_normed, lp, mixer_in)``
+    overrides the dense MLP (MoE; ``mixer_in`` is what the mixer read, for a
+    router placed before attention). A branch joins the residual stream
+    times ``cfg.residual_multiplier`` where the model has one. Returns (h,
+    *new caches)."""
     mfn = mlp_fn if mlp_fn is not None else (
         lambda c, xx, lpp, _: mlp(c, xx, lpp["mlp"]))
+    # 1.0 puts no multiply into a model's step that has no multiplier
+    rm = cfg.residual_multiplier
+    scaled = (lambda b: b) if rm == 1.0 else (lambda b: b * rm)
     xa = _norm(cfg, h, lp["ln_attn"])
     with jax.named_scope(scope):
         a, *caches = mix_fn(xa)
     if cfg.parallel_residual:
         m = mfn(cfg, _norm(cfg, h, lp["ln_mlp"]), lp, xa)
-        return (h + a + m, *caches)
-    h = h + a
+        return (h + scaled(a) + scaled(m), *caches)
+    h = h + scaled(a)
     m = mfn(cfg, _norm(cfg, h, lp["ln_mlp"]), lp, xa)
-    return (h + m, *caches)
+    return (h + scaled(m), *caches)
 
 
 def cached_block(cfg: TransformerConfig, h, lp, ck, cv, positions, pos,
@@ -1351,9 +1401,11 @@ def cached_block(cfg: TransformerConfig, h, lp, ck, cv, positions, pos,
 
 @jax.named_scope("vocab_head")
 def cached_head(cfg: TransformerConfig, params, x):
-    """Final norm + logits projection of the cached path."""
+    """Final norm + logits projection of the cached path (the logits over
+    ``cfg.logits_scaling`` where the model has one)."""
     x = _norm(cfg, x, params["ln_f"])
-    return x @ _head_weight(cfg, params) + _head_bias(params)
+    logits = x @ _head_weight(cfg, params) + _head_bias(params)
+    return logits if cfg.logits_scaling == 1.0 else logits / cfg.logits_scaling
 
 
 def forward_cached(cfg: TransformerConfig, params, tokens, cache, pos, pad_bias=None,
@@ -1362,7 +1414,7 @@ def forward_cached(cfg: TransformerConfig, params, tokens, cache, pos, pad_bias=
     to ``cache`` at offset ``pos`` ([] int32). Returns (logits [B, T, vocab],
     new cache). ``pad_bias`` [B, Smax] additive f32 masks cache slots of
     left-padded prompts; ``mlp_fn`` see :func:`cached_block`."""
-    _no_layer_pattern(cfg, "the dense-workspace KV cache")
+    _paged_path_only(cfg, "the dense-workspace KV cache")
     if cfg.norm_position == "post":
         raise ValueError("norm_position='post' is not supported by the "
                          "KV-cache decode path (pre-LN only)")
@@ -1554,6 +1606,14 @@ def kda_chunked(S0, qh, kh, v, g, beta, chunk: int = KDA_CHUNK):
     return jnp.moveaxis(o, 1, 2).reshape(T, H, -1), S
 
 
+def _slot_start(pool, slot, fresh):
+    """Row ``slot`` of a state or conv pool as a request's piece starts from
+    it ([1, ...]): zero where the piece is the request's first (``fresh``, a
+    bool, traced or not), whatever the slot's last holder left there."""
+    row = jax.lax.dynamic_slice_in_dim(pool, slot, 1, axis=0)
+    return jnp.where(jnp.logical_not(fresh), row, jnp.zeros_like(row))
+
+
 def _kda_prefill(cfg: TransformerConfig, x, lp, state, conv, slot, n_valid,
                  fresh):
     """The mixer over a prompt, or a chunk of one, of ONE request: x [1, T,
@@ -1565,15 +1625,12 @@ def _kda_prefill(cfg: TransformerConfig, x, lp, state, conv, slot, n_valid,
     is the state after position ``n_valid``."""
     T = x.shape[1]
     K = KDA_CONV_KERNEL
-    keep = jnp.logical_not(fresh)
-    ctx = jax.lax.dynamic_slice_in_dim(conv, slot, 1, axis=0)
-    ctx = jnp.where(keep, ctx, jnp.zeros_like(ctx))
-    qh, kh, v, g, beta, win = _kda_project(cfg, x, lp, ctx)
+    qh, kh, v, g, beta, win = _kda_project(cfg, x, lp,
+                                           _slot_start(conv, slot, fresh))
     real = (jnp.arange(T) < n_valid)[None, :, None]
     g = jnp.where(real[..., None], g, 0.0)
     beta = jnp.where(real, beta, 0.0)
-    S0 = jax.lax.dynamic_slice_in_dim(state, slot, 1, axis=0)[0]
-    S0 = jnp.where(keep, S0, jnp.zeros_like(S0))
+    S0 = _slot_start(state, slot, fresh)[0]
     with jax.named_scope("kda_state_update"):
         o, S = kda_chunked(S0, qh[0], kh[0], v[0], g[0], beta[0])
     state = jax.lax.dynamic_update_slice_in_dim(state, S[None], slot, axis=0)
@@ -1643,6 +1700,245 @@ def _kda_decode(cfg: TransformerConfig, x, lp, state, conv, base, slots,
 
 
 # --------------------------------------------------------------------- #
+# Mamba-2: a state-space recurrence in its SSD form (Dao & Gu 2024), as the
+# Granite 4.0-H family runs it (one group of B and C shared by the heads)
+#
+#   [z, xBC, dt] = x W_in ;  xBC = silu(conv(xBC) + b_conv)
+#   x [H, P], B [N], C [N] = split(xBC) ;  dt = softplus(dt + dt_bias) [H]
+#   S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T   (A = -exp(A_log); per head,
+#   y_t = S_t C_t + D x_t                         S [P, N] float32)
+#   out = (RMSNorm(y * silu(z)) * scale) W_out    (the norm over all H*P)
+#
+# A request's state ([H, P, N] float32 a layer) and conv state (the last
+# K-1 inputs of the conv, [K-1, H*P + 2N]) live in its SLOT of the same two
+# pools a KDA layer's do (``init_paged_kv_cache``, ``cfg.state_shapes``).
+# Decode updates each LIVE row's state where it lies (``_ssd_state_update``:
+# on TPU a Pallas kernel, a row's state a block; elsewhere XLA's gather,
+# update and scatter of the rows' states); a prompt runs the recurrence in chunks of
+# ``cfg.ssm_chunk`` tokens (``ssd_chunked``): inside a chunk the quadratic
+# form (C B^T * L)(dt x), L_ij = exp(sum_{j<k<=i} dt_k A), between chunks
+# the state. No matrix inverse (KDA has one).
+
+
+def _init_mamba2(cfg: TransformerConfig, n: int, key, dtype, out_std):
+    D, H, Pd, N, K = (cfg.d_model, cfg.ssm_heads, cfg.ssm_head_dim,
+                      cfg.ssm_state, cfg.ssm_conv_kernel)
+    inner, conv_dim = H * Pd, H * Pd + 2 * N
+    ks = jax.random.split(key, 5)
+
+    def dense(k, shape, scale=cfg.init_std):
+        return (jax.random.normal(k, shape) * scale).astype(dtype)
+
+    # the family's own draws (mamba_ssm Mamba2): A in U(1, 16), dt
+    # log-uniform over (1e-3, 1e-1) through the inverse of softplus, D 1, the
+    # conv torch's default U(-K^-1/2, K^-1/2): a step's decay exp(dt A) lies
+    # where a trained model's does
+    a = jax.random.uniform(ks[2], (n, H), minval=1.0, maxval=16.0)
+    dt = jnp.exp(jax.random.uniform(ks[3], (n, H), minval=math.log(1e-3),
+                                    maxval=math.log(1e-1)))
+    return {
+        # z | x B C | dt, one projection
+        "w_in": dense(ks[0], (n, D, inner + conv_dim + H)),
+        # depthwise, causal: tap K-1 multiplies the current input
+        "conv_w": jax.random.uniform(ks[1], (n, K, conv_dim), minval=-K ** -0.5,
+                                     maxval=K ** -0.5).astype(dtype),
+        "b_conv": jnp.zeros((n, conv_dim), dtype),
+        "A_log": jnp.log(a).astype(dtype),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+        "D": jnp.ones((n, H), dtype),
+        "norm": {"scale": jnp.ones((n, inner), dtype)},
+        "w_out": dense(ks[4], (n, inner, D), out_std),
+    }
+
+
+def _mamba2_project(cfg: TransformerConfig, x, lp, conv_ctx):
+    """x [R, T, D], conv_ctx [R, K-1, conv_dim] the conv inputs before x ->
+    (z [R, T, H*P] the gate, xs [R, T, H, P], Bm and Cm [R, T, N] in x's
+    type, dt [R, T, H] float32 > 0, and the conv window [R, T+K-1,
+    conv_dim] the next conv state is cut from)."""
+    R, T, _ = x.shape
+    H, Pd, N, K = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
+                   cfg.ssm_conv_kernel)
+    inner = H * Pd
+    f32 = jnp.float32
+    with jax.named_scope("in_proj"):
+        u = x @ _w(lp["w_in"], x)
+    z, xbc, dt = jnp.split(u, [inner, 2 * inner + 2 * N], axis=-1)
+    win = jnp.concatenate([conv_ctx.astype(xbc.dtype), xbc], axis=1)
+    with jax.named_scope("short_conv"):
+        w = lp["conv_w"].astype(f32)
+        y = sum(win[:, j:j + T].astype(f32) * w[j] for j in range(K))
+        y = jax.nn.silu(y + lp["b_conv"].astype(f32)).astype(x.dtype)
+    xs, Bm, Cm = jnp.split(y, [inner, inner + N], axis=-1)
+    dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+    return z, xs.reshape(R, T, H, Pd), Bm, Cm, dt, win
+
+
+def _mamba2_output(cfg: TransformerConfig, y, z, lp):
+    """y [R, T, H, P] float32, z [R, T, H*P] -> (RMSNorm(y * silu(z)) *
+    scale) W_out: the gate BEFORE the norm, in float32, the norm over all
+    H*P channels."""
+    R, T = y.shape[:2]
+    with jax.named_scope("gated_norm"):
+        g = y.reshape(R, T, -1) * jax.nn.silu(z.astype(jnp.float32))
+        g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True) + cfg.norm_eps)
+        g = (g * lp["norm"]["scale"].astype(jnp.float32)).astype(z.dtype)
+    return g @ _w(lp["w_out"], g)
+
+
+def ssd_recurrent_step(S, x, dt, A, Bm, Cm, D):
+    """The one-token update of one row: S [H, P, N] float32, x [H, P], dt,
+    A, D [H], Bm, Cm [N], all float32 -> (y [H, P], new S)."""
+    S = jnp.exp(dt * A)[:, None, None] * S \
+        + (dt[:, None] * x)[:, :, None] * Bm[None, None, :]
+    return jnp.sum(S * Cm[None, None, :], axis=-1) + D[:, None] * x, S
+
+
+def ssd_chunked(S0, x, dt, A, Bm, Cm, D, chunk: int):
+    """The recurrence over T tokens of one sequence in chunks of ``chunk``
+    (the last one what is left of T): S0 [H, P, N] float32, x [T, H, P], Bm,
+    Cm [T, N] (their type is the matmuls': bf16 served, float32 in the
+    tests), dt [T, H] and A, D [H] float32 -> (y [T, H, P] float32, S_T). A
+    position of dt 0 is no position: its decay is 1 and it adds nothing (a
+    bucket's padding). Inside a chunk, with G_i the cumulated log decay, y_i
+    = sum_{j<=i} (C_i . B_j) e^(G_i - G_j) dt_j x_j + e^G_i S0 C_i + D x_i;
+    S_C = e^G_C S0 + sum_j e^(G_C - G_j) dt_j x_j B_j^T. Decays are float32
+    and only ever taken between two positions j <= i of one chunk, so every
+    exponent is <= 0. The heads lead every operand inside a chunk, and the
+    scan stacks a chunk's y as the matmul leaves it, [H, C, P]: stacked [C,
+    H, P], each chunk's y was written by a ``dynamic-update-slice`` of 134
+    us on the TPU, three times the chunk's arithmetic (PERF.md section 6,
+    PR 43)."""
+    T, H, Pd = x.shape
+    C = min(chunk, T)
+    f32, mm = jnp.float32, x.dtype
+    xh, dth = jnp.swapaxes(x, 0, 1), dt.T                    # [H, T, P] [H, T]
+
+    def one_chunk(S, xs):
+        x_c, b_c, c_c, dt_c = xs              # [H, c, P] [c, N] [c, N] [H, c]
+        G = jnp.cumsum(dt_c * A[:, None], axis=1)            # [H, c] <= 0
+        incl = jnp.tril(jnp.ones((G.shape[1],) * 2, bool))   # j <= i
+        L = jnp.exp(jnp.where(incl, G[:, :, None] - G[:, None, :], -jnp.inf))
+        cb = jnp.einsum("in,jn->ij", c_c, b_c, preferred_element_type=f32)
+        dtx = dt_c[:, :, None] * x_c.astype(f32)             # [H, c, P]
+        y = jnp.einsum("hij,hjp->hip", (cb * L).astype(mm), dtx.astype(mm),
+                       preferred_element_type=f32)
+        y = y + jnp.exp(G)[:, :, None] * jnp.einsum(
+            "in,hpn->hip", c_c.astype(f32), S, precision=_HI)
+        to_end = jnp.exp(G[:, -1:] - G)                      # [H, c]
+        S = jnp.exp(G[:, -1])[:, None, None] * S + jnp.einsum(
+            "hjp,jn->hpn", (to_end[:, :, None] * dtx).astype(mm), b_c,
+            preferred_element_type=f32)
+        return S, y
+
+    n, whole = T // C, T // C * C
+
+    def cut(a, ax):              # the whole chunks of axis ``ax``, stacked
+        a = jax.lax.slice_in_dim(a, 0, whole, axis=ax)
+        return jnp.moveaxis(
+            a.reshape(*a.shape[:ax], n, C, *a.shape[ax + 1:]), ax, 0)
+
+    S, y = jax.lax.scan(one_chunk, S0,
+                        (cut(xh, 1), cut(Bm, 0), cut(Cm, 0), cut(dth, 1)))
+    y = jnp.moveaxis(y, 0, 1).reshape(H, whole, Pd)
+    if whole < T:
+        S, rest = one_chunk(S, (xh[:, whole:], Bm[whole:], Cm[whole:],
+                                dth[:, whole:]))
+        y = jnp.concatenate([y, rest], axis=1)
+    return jnp.swapaxes(y, 0, 1) + D[:, None] * x.astype(f32), S
+
+
+def _mamba2_prefill(cfg: TransformerConfig, x, lp, state, conv, slot,
+                    n_valid, fresh):
+    """The mixer over a prompt, or a chunk of one, of ONE request: x [1, T,
+    D] (T a compile bucket, the first ``n_valid`` positions real), ``slot``
+    the request's row of the layer in ``state`` [rows, H, P, N] and ``conv``
+    [rows, K-1, conv_dim]. ``fresh`` (a bool, traced or not): the request's
+    first piece starts from zero, whatever the slot's last holder left
+    there. Padding has dt 0: the state after the bucket is the state after
+    position ``n_valid``."""
+    T = x.shape[1]
+    f32 = jnp.float32
+    z, xs, Bm, Cm, dt, win = _mamba2_project(cfg, x, lp,
+                                             _slot_start(conv, slot, fresh))
+    dt = jnp.where((jnp.arange(T) < n_valid)[None, :, None], dt, 0.0)
+    S0 = _slot_start(state, slot, fresh)[0]
+    with jax.named_scope("ssd_chunk_scan"):
+        y, S = ssd_chunked(S0, xs[0], dt[0], -jnp.exp(lp["A_log"].astype(f32)),
+                           Bm[0], Cm[0], lp["D"].astype(f32), cfg.ssm_chunk)
+    state = jax.lax.dynamic_update_slice_in_dim(state, S[None], slot, axis=0)
+    # the K-1 rows as a gather of rows: a dynamic slice along the time axis
+    # made the TPU compiler keep the whole conv pool time-minor in this
+    # program (3 padded to 128 lanes: 289 MB a pool copied in and out a
+    # prefill, 2.6 GB of temporaries at the published sizes)
+    tail = win[:, n_valid + jnp.arange(cfg.ssm_conv_kernel - 1)]
+    conv = jax.lax.dynamic_update_slice_in_dim(
+        conv, tail.astype(conv.dtype), slot, axis=0)
+    return _mamba2_output(cfg, y[None], z, lp), state, conv
+
+
+def _ssd_decode_update(state, x, dt, A, Bm, Cm, D, slots, base):
+    """The plain-XLA form of a decode step's state update, and what the
+    tests compare the kernel against: ``state`` [pool rows, H, P, N]
+    float32, the rows' x [B, H, P], dt [B, H], Bm, Cm [B, N] float32 by
+    row, row ``b`` at ``state[base + slots[b]]``: the rows' states gathered,
+    ``ssd_recurrent_step`` a row, and scattered back. An idle row (slot 0)
+    writes the dummy back as it was and its ``y`` is zero. Returns (y [B,
+    H, P], the pool)."""
+    rows = base + slots
+    live = (slots != 0)[:, None, None]
+    old = state[rows]
+    y, new = jax.vmap(
+        lambda S, xb, dtb, bb, cb: ssd_recurrent_step(S, xb, dtb, A, bb, cb, D)
+    )(old, x, dt, Bm, Cm)
+    new = jnp.where(live[..., None], new, old)
+    return jnp.where(live, y, 0.0), state.at[rows].set(new)
+
+
+def _ssd_state_update(cfg: TransformerConfig, state, x, dt, A, Bm, Cm, D,
+                      slots, base):
+    """A decode step's state update in one of two forms of the same float32
+    arithmetic, chosen as the paged kernel is (``_use_flash``: the backend
+    and the shape, never the model), both over the LIVE rows' slots alone:
+
+    * ``mamba2_kernel`` (TPU): ``ops/pallas/mamba2_decode_update.py``, a
+      live row's state one block of the pool, read once and written once
+      under Pallas's own pipeline;
+    * ``slot_gather`` (elsewhere, and shapes the kernel cannot tile):
+      ``_ssd_decode_update``, the rows' states gathered, updated and
+      scattered back by XLA."""
+    out = None
+    if _use_flash(cfg):
+        from deepspeed_tpu.ops.pallas.mamba2_decode_update import \
+            mamba2_decode_update
+        out = mamba2_decode_update(state, x, dt, A, Bm, Cm, slots, base)
+    dispatch.record("ssd_decode", "slot_gather" if out is None else "mamba2_kernel",
+                    f"B={x.shape[0]} H={x.shape[1]} P={x.shape[2]} "
+                    f"N={Bm.shape[1]}")
+    if out is None:
+        return _ssd_decode_update(state, x, dt, A, Bm, Cm, D, slots, base)
+    y, state = out
+    live = (slots != 0)[:, None, None]
+    return y + jnp.where(live, D[:, None] * x, 0.0), state
+
+
+def _mamba2_decode(cfg: TransformerConfig, x, lp, state, conv, base, slots):
+    """One token a row: x [B, 1, D], ``slots`` [B] each row's state slot
+    (0, the dummy, for an inactive row), the layer's slots from row ``base``
+    of ``state`` and ``conv``."""
+    f32 = jnp.float32
+    rows = base + slots
+    z, xs, Bm, Cm, dt, win = _mamba2_project(cfg, x, lp, conv[rows])
+    conv = conv.at[rows].set(win[:, 1:].astype(conv.dtype))
+    with jax.named_scope("ssd_state_update"):
+        y, state = _ssd_state_update(
+            cfg, state, xs[:, 0].astype(f32), dt[:, 0],
+            -jnp.exp(lp["A_log"].astype(f32)), Bm[:, 0].astype(f32),
+            Cm[:, 0].astype(f32), lp["D"].astype(f32), slots, base)
+    return _mamba2_output(cfg, y[:, None], z, lp), state, conv
+
+
+# --------------------------------------------------------------------- #
 # Paged KV cache (vLLM PagedAttention / Orca continuous batching, TPU form):
 # KV lives in fixed-size block POOLS [n_layer, num_blocks, block_size, KV*Hd]
 # shared by every in-flight request; each request owns a block table mapping
@@ -1666,11 +1962,14 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
     layers: k/v [kv layers, num_blocks, block_size, kv_heads * Hd] (kv head
     ``g`` of a token at ``[g*Hd, (g+1)*Hd)`` of its row). Block 0 is
     conventionally the allocator's dummy block (padding tokens and inactive
-    decode rows write there; nothing ever reads it). Linear-attention
-    layers: ``state`` and ``conv``, each a tuple of one array a
-    linear-attention POSITION of the period, [periods, state_slots, H, dk,
-    dv] float32 and [periods, state_slots, K-1, 3*H*dk]: a slot a running
-    request and slot 0 the dummy. (One array a position, not one for all:
+    decode rows write there; nothing ever reads it). Recurrent layers
+    (KDA and Mamba-2: ``STATE_MIXERS``): ``state`` and ``conv``, each a
+    tuple of one array a recurrent POSITION of the period, [periods,
+    state_slots, *state shape] float32 and [periods, state_slots, *conv
+    shape] with the kind's own shapes (``cfg.state_shapes``: a KDA layer's
+    [H, dk, dv] and [K-1, 3*H*dk], a Mamba-2 layer's [H, P, N] and [K-1,
+    H*P + 2N]): a slot a running request and slot 0 the dummy. (One array
+    a position, not one for all:
     the period's layers are unrolled, so their offsets into a shared array
     would be constants, XLA would fold a later layer's read through the
     earlier layer's update back to the buffer as it came in, and a buffer
@@ -1699,14 +1998,11 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
         pools["wk"] = jnp.zeros(wshape, dtype)
         pools["wv"] = jnp.zeros(wshape, dtype)
     if spec["state"]:
-        H, dk, K = cfg.lin_heads, cfg.lin_head_dim, KDA_CONV_KERNEL
-        n_lin, P_ = cfg.period.count(LINEAR_ATTENTION), cfg.n_periods
-        pools["state"] = tuple(
-            jnp.zeros((P_, state_slots, H, dk, dk), jnp.float32)
-            for _ in range(n_lin))
-        pools["conv"] = tuple(
-            jnp.zeros((P_, state_slots, K - 1, 3 * H * dk), dtype)
-            for _ in range(n_lin))
+        shapes = [cfg.state_shapes(k) for k in cfg.period if k in STATE_MIXERS]
+        lead = (cfg.n_periods, state_slots)
+        pools["state"] = tuple(jnp.zeros(lead + st, jnp.float32)
+                               for st, _ in shapes)
+        pools["conv"] = tuple(jnp.zeros(lead + cv, dtype) for _, cv in shapes)
     return pools
 
 
@@ -1774,8 +2070,9 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
     Attention layer ``l`` lives at blocks ``[l*num_blocks, (l+1)*num_blocks)``
     of that view, so ``attn_fn(x_normed, lp_attn, kp, vp, block0, slot0)``
     reads and writes it through ``block_tables + block0`` / ``slots +
-    slot0``; linear-attention layer ``l`` at rows ``[l*slots, (l+1)*slots)``,
-    ``lin_fn(x_normed, lp_lin, state, conv, row0)``; window layer ``l`` at
+    slot0``; recurrent layer ``l`` (either kind of ``STATE_MIXERS``) at rows
+    ``[l*slots, (l+1)*slots)``, ``lin_fn(kind, x_normed, lp_mixer, state,
+    conv, row0)``; window layer ``l`` at
     blocks ``[l*ring blocks, ...)`` of ``wk`` / ``wv``, ``win_fn(x_normed,
     lp_attn, wk, wv, block0)``. No per-layer slice of
     a pool exists and nothing is stacked. An ``mlp_fn`` may return ``(out,
@@ -1787,10 +2084,9 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
     ``p`` the layer's index in them; they are closed over and no slice of
     them is made. Returns (x, new pools, aux)."""
     period = cfg.period
-    n_att, n_lin = period.count(ATTENTION), period.count(LINEAR_ATTENTION)
-    n_win = period.count(WINDOW_ATTENTION)
+    n_att, n_win = period.count(ATTENTION), period.count(WINDOW_ATTENTION)
     Nb, bs = pools["k"].shape[1:3]
-    n_slots = pools["state"][0].shape[1] if n_lin else 0
+    n_slots = pools["state"][0].shape[1] if "state" in pools else 0
     Nwb = pools["wk"].shape[1] if n_win else 0
     groups = params["layers"] if cfg.layer_kinds is not None \
         else (params["layers"],)
@@ -1839,9 +2135,10 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
                 i_lin += 1
                 h, flat["state"][i], flat["conv"][i] = _decode_block(
                     cfg, h, lp,
-                    lambda xn: lin_fn(xn, lp["lin"], flat["state"][i],
-                                      flat["conv"][i], p * n_slots),
-                    mfn, scope=LINEAR_ATTENTION)
+                    lambda xn: lin_fn(kind, xn, lp[STATE_MIXERS[kind]],
+                                      flat["state"][i], flat["conv"][i],
+                                      p * n_slots),
+                    mfn, scope=kind)
         flat = {n: tuple(a) if isinstance(a, list) else a
                 for n, a in flat.items()}
         out = None if not aux else aux[0] if len(aux) == 1 else jnp.stack(aux)
@@ -1997,11 +2294,19 @@ def _paged_chunk_attention(cfg: TransformerConfig, x, lp, positions,
     return out, kp, vp
 
 
-def _no_layer_pattern(cfg: TransformerConfig, what: str):
+def _paged_path_only(cfg: TransformerConfig, what: str):
+    """What only the paged serving path builds: a layer pattern, and the
+    embedding, residual and logit multipliers."""
     if cfg.layer_kinds is not None:
         raise NotImplementedError(
             f"{what} is not built for a stack with a layer pattern "
             f"({cfg.period}): it runs on the paged serving path only")
+    if (cfg.embedding_multiplier, cfg.residual_multiplier,
+            cfg.logits_scaling) != (1.0, 1.0, 1.0):
+        raise NotImplementedError(
+            f"{what} does not apply embedding_multiplier, "
+            "residual_multiplier or logits_scaling: a model that sets one "
+            "runs on the paged serving path only")
 
 
 def _check_paged_config(cfg: TransformerConfig):
@@ -2014,13 +2319,15 @@ def _check_paged_config(cfg: TransformerConfig):
 
 def _lin_prefill_fn(cfg: TransformerConfig, pools, state_slot, n_valid, fresh):
     """The ``lin_fn`` of a prefill or chunk program (None for a stack
-    without state): the request's slot in each linear-attention layer."""
+    without state): the request's slot in each recurrent layer, through the
+    layer's kind's own chunked form."""
     if "state" not in pools:
         return None
     if state_slot is None:
         raise ValueError("a stack with recurrent state needs the request's "
                          "state slot")
-    return lambda xn, lp, st, cv, row0: _kda_prefill(
+    forms = {LINEAR_ATTENTION: _kda_prefill, MAMBA2: _mamba2_prefill}
+    return lambda kind, xn, lp, st, cv, row0: forms[kind](
         cfg, xn, lp, st, cv, row0 + state_slot, n_valid, fresh)
 
 
@@ -2248,8 +2555,11 @@ def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
                          "needs the rows' slots")
     if "state" in pools:
         n_slots = pools["state"][0].shape[1]
-        lin_fn = lambda xn, lp, st, cv, row0: _kda_decode(  # noqa: E731
-            cfg, xn, lp, st, cv, row0, state_slots, n_slots)
+
+        def lin_fn(kind, xn, lp, st, cv, row0):
+            if kind == MAMBA2:
+                return _mamba2_decode(cfg, xn, lp, st, cv, row0, state_slots)
+            return _kda_decode(cfg, xn, lp, st, cv, row0, state_slots, n_slots)
     if "wk" in pools:
         rings = ring_tables(state_slots,
                             cfg.ring_blocks(pools["wk"].shape[2]))
@@ -2352,7 +2662,7 @@ def run_layers(cfg: TransformerConfig, x, layer_params, positions, mask_bias,
     encoders (e.g. the CLIP vision tower). ``rng`` (training loss paths
     only) seeds per-layer dropout keys; None keeps every path deterministic
     and the traced program identical to the dropout-free form."""
-    _no_layer_pattern(cfg, "the training forward (and its scan's backward)")
+    _paged_path_only(cfg, "the training forward (and its scan's backward)")
     with_keys = rng is not None and bool(cfg.dropout)
     n_layer = jax.tree.leaves(layer_params)[0].shape[0]
 

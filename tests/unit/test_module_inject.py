@@ -419,3 +419,96 @@ class TestCLIPPolicy:
                                       jnp.asarray(img.transpose(0, 2, 3, 1)))
         np.testing.assert_allclose(np.asarray(got_t), tfeat, rtol=2e-2, atol=2e-3)
         np.testing.assert_allclose(np.asarray(got_i), ifeat, rtol=2e-2, atol=2e-3)
+
+
+# --------------------------------------------------------------------- #
+# The benchmark's plain reference of the Granite 4.0-H block against the
+# published code. Here because this file's worker has paid for torch and
+# transformers already (~30 s); what the PROGRAM is held to that reference
+# by is in ``test_granite_hybrid.py``.
+
+class _HFGraniteWeights:
+    """``GraniteMoeHybridForCausalLM``'s state dict under the reference's
+    names (``reference/maps/granite-4.0-h-micro.json`` says what each is in
+    the program's tree): torch keeps ``[out, in]``, the conv ``[C, 1, K]``,
+    and ``shared_mlp.input_linear`` the gate over the up projection."""
+
+    def __init__(self, hf_model, d_ff):
+        self.sd = {k: v.detach().numpy() for k, v in hf_model.state_dict().items()}
+        self.d_ff = d_ff
+
+    def top(self):
+        return {"wte": jnp.asarray(self.sd["model.embed_tokens.weight"]),
+                "lnf_g": jnp.asarray(self.sd["model.norm.weight"])}
+
+    def layer(self, l):
+        sd, p, F = self.sd, f"model.layers.{l}.", self.d_ff
+        w_in = sd[p + "shared_mlp.input_linear.weight"]
+        w = {"ln1_g": sd[p + "input_layernorm.weight"],
+             "ln2_g": sd[p + "post_attention_layernorm.weight"],
+             "w_gate": w_in[:F].T, "w_up": w_in[F:].T,
+             "w_down": sd[p + "shared_mlp.output_linear.weight"].T}
+        if p + "mamba.A_log" in sd:
+            q = p + "mamba."
+            w.update(w_in=sd[q + "in_proj.weight"].T,
+                     conv=sd[q + "conv1d.weight"][:, 0, :].T,
+                     conv_b=sd[q + "conv1d.bias"], A_log=sd[q + "A_log"],
+                     dt_bias=sd[q + "dt_bias"], D=sd[q + "D"],
+                     norm_g=sd[q + "norm.weight"],
+                     w_out=sd[q + "out_proj.weight"].T)
+        else:
+            w.update({k: sd[p + f"self_attn.{k[1]}_proj.weight"].T
+                      for k in ("wq", "wk", "wv", "wo")})
+        return {k: jnp.asarray(v) for k, v in w.items()}
+
+
+@pytest.mark.parametrize("seed,shape", [(0, (2, 21)), (1, (1, 40))])
+def test_the_granite_hybrid_reference_is_the_published_model(seed, shape):
+    """One published period of ten at toy widths (the ``granite_hybrid``
+    ``tiny`` preset's sizes), the four multipliers at their published
+    values, random weights with non-trivial ``A_log``, ``dt_bias``, ``D``,
+    conv bias and norm scales, every matrix four times the initializer's
+    0.02 so that the mixers weigh beside the x12 embedding: logits of ~0.7
+    to 1e-4 (measured: 1.2e-7, both float32; ``transformers``' naive torch
+    path runs the CHUNKED form, the reference the sequential one)."""
+    import sys
+    bench = os.path.join(os.path.dirname(__file__), "..", "..", "perfbench")
+    sys.path.insert(0, os.path.abspath(bench))
+    from reference import granite_hybrid_decoder as ref
+    import correctness
+
+    toy = "rehearsal-granite-hybrid-tiny"
+    with open(os.path.join(bench, "configs", toy + ".json")) as f:
+        cfg = correctness.reference_config(json.load(f), correctness.load_map(toy))
+    hc = transformers.GraniteMoeHybridConfig(
+        vocab_size=512, hidden_size=cfg["d_model"], intermediate_size=cfg["d_ff"],
+        shared_intermediate_size=cfg["d_ff"], num_hidden_layers=cfg["n_layer"],
+        num_attention_heads=cfg["n_head"], num_key_value_heads=cfg["n_kv_head"],
+        layer_types=cfg["layer_types"], num_local_experts=0,
+        num_experts_per_tok=0, mamba_n_heads=cfg["ssm_heads"],
+        mamba_d_head=cfg["ssm_head_dim"], mamba_d_state=cfg["ssm_state"],
+        mamba_n_groups=cfg["ssm_groups"], mamba_d_conv=cfg["conv_kernel"],
+        mamba_expand=2, mamba_chunk_size=cfg["ssm_chunk"],
+        position_embedding_type="nope", rms_norm_eps=cfg["eps"],
+        embedding_multiplier=cfg["embedding_multiplier"],
+        attention_multiplier=cfg["attention_multiplier"],
+        residual_multiplier=cfg["residual_multiplier"],
+        logits_scaling=cfg["logits_scaling"], tie_word_embeddings=True)
+    torch.manual_seed(seed)
+    hf_model = transformers.GraniteMoeHybridForCausalLM(hc).eval()
+    assert sum(p.numel() for p in hf_model.parameters()) == 542_540
+    with torch.no_grad():
+        for name, p in hf_model.named_parameters():
+            if name.endswith(("A_log", "dt_bias", ".D", "conv1d.bias",
+                              "norm.weight", "layernorm.weight")):
+                p.add_(0.3 * torch.randn_like(p))
+            else:
+                p.mul_(4.0)
+    tok = torch.randint(0, 512, shape)
+    with torch.no_grad():
+        want = hf_model(input_ids=tok).logits.numpy()
+    w = _HFGraniteWeights(hf_model, cfg["d_ff"])
+    h = ref.final_hidden(cfg, w, jnp.asarray(tok.numpy().astype(np.int32)))
+    got = np.asarray(ref.logits_rows(cfg, w, h.reshape(-1, h.shape[-1])))
+    assert np.abs(want).max() > 0.3
+    np.testing.assert_allclose(got.reshape(want.shape), want, rtol=0, atol=1e-4)

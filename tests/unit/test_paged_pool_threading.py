@@ -311,6 +311,103 @@ def test_kda_kernel_compiles_for_v5e_at_real_widths(one_v5e, monkeypatch):
     assert mem.temp_size_in_bytes < pool_bytes / 64, mem.temp_size_in_bytes
 
 
+def test_mamba2_state_pools_stay_in_one_buffer_for_v5e(one_v5e, monkeypatch):
+    """The second recurrent kind: the decode program of a two-period
+    ``granite_hybrid`` toy (a Mamba-2 state the kernel tiles: 2 heads of 64
+    x 128) compiled for a described v5e runs ``mamba2_decode_update`` on
+    each of its nine state pools where it lies: every pool aliased, the
+    temporaries under a quarter of the state, and no ``copy``,
+    ``dynamic-slice`` or ``dynamic-update-slice`` of a layer's state size.
+    And the prefill keeps the conv pools in the layout they come in (a
+    dynamic slice along the time axis made the compiler hold them
+    time-minor: 3 padded to 128 lanes, forty times their size in
+    temporaries; PERF.md section 6, PR 43)."""
+    import deepspeed_tpu.comm as dist
+    from deepspeed_tpu.models.presets import get_model
+    from deepspeed_tpu.ops import dispatch
+    dist.set_mesh(None)
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    rows, num_blocks = 8, 16
+    model = get_model("granite_hybrid", "tiny", n_layer=20, head_size=64,
+                      ssm_heads=2, ssm_head_dim=64, ssm_state=128)
+    assert model.config.n_periods == 2
+    params = jax.tree.map(lambda a: sds(a.shape, jnp.bfloat16),
+                          jax.eval_shape(model.init_params, jax.random.key(0)))
+    pools = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda: model.init_paged_cache(num_blocks, BS, dtype=jnp.bfloat16,
+                                       state_slots=rows + 1)))
+    nbytes = lambda tree: sum(  # noqa: E731
+        int(np.prod(a.shape)) * jnp.dtype(a.dtype).itemsize
+        for a in jax.tree.leaves(tree))
+    n_max = -(-model.config.max_seq // BS)
+    dispatch.reset()
+    compiled = jax.jit(
+        lambda p, po, t, bt, pos, ss: model.forward_paged_decode(
+            p, t, po, bt, pos, state_slots=ss), donate_argnums=(1,)).lower(
+        params, pools, sds((rows, 1), I32), sds((rows, n_max), I32),
+        sds((rows,), I32), sds((rows,), I32)).compile()
+    assert dispatch.selected()["ssd_decode=mamba2_kernel"] == 9
+    assert dispatch.selected()["kernel/mamba2_decode_update=compiled"] == 9
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes(pools), "the pools are not aliased"
+    assert mem.temp_size_in_bytes < nbytes(pools["state"]) / 4, (
+        mem.temp_size_in_bytes, nbytes(pools["state"]))
+    text = compiled.as_text()
+    # the period's body: the paged kernel and nine of the Mamba-2 kernel
+    assert text.count('custom_call_target="tpu_custom_call"') == 10
+    layer_state = int(np.prod(pools["state"][0].shape[1:]))
+    moved = []
+    for m in re.finditer(
+            r"= \w+\[([\d,]+)\]\S* "
+            r"(copy|dynamic-slice|dynamic-update-slice)\(", text):
+        if int(np.prod([int(d) for d in m.group(1).split(",")])) >= layer_state:
+            moved.append(m.group(0))
+    assert not moved, moved
+    prefill = jax.jit(
+        lambda p, po, t, sl, li, ss: model.forward_paged_prefill(
+            p, t, po, sl, li, state_slot=ss), donate_argnums=(1,)).lower(
+        params, pools, sds((1, 256), I32), sds((256,), I32), sds((), I32),
+        sds((), I32)).compile()
+    mem = prefill.memory_analysis()
+    assert mem.alias_size_in_bytes >= nbytes(pools), "the pools are not aliased"
+    assert mem.temp_size_in_bytes < 4 * nbytes(pools["conv"]), (
+        mem.temp_size_in_bytes, nbytes(pools["conv"]))
+
+
+def test_mamba2_kernel_compiles_for_v5e_at_real_widths(one_v5e, monkeypatch):
+    """Mosaic takes the Mamba-2 decode kernel at
+    ``granite4hmicro_serve_chat``'s widths (64 rows, 64 heads of a 64 x 128
+    float32 state, 4 x 65 slots of one pool: what interpret mode cannot see
+    is the turned vectors' lane slices, the lane sums stored a column, the
+    decays in SMEM and the VMEM four 2 MB blocks take), and the pool is its
+    input and its output in one buffer."""
+    from deepspeed_tpu.ops import dispatch
+    from deepspeed_tpu.ops.pallas.mamba2_decode_update import \
+        mamba2_decode_update
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+    B, H, P, N, slots = 64, 64, 64, 128, 4 * 65
+    f32 = jnp.float32
+
+    def sds(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    compiled = jax.jit(
+        lambda S, x, dt, A, b, c, ss: mamba2_decode_update(
+            S, x, dt, A, b, c, ss, 65, interpret=False),
+        donate_argnums=(0,)).lower(
+        sds((slots, H, P, N)), sds((B, H, P)), sds((B, H)), sds((H,)),
+        sds((B, N)), sds((B, N)), sds((B,), I32)).compile()
+    assert "mamba2_decode_update" in compiled.as_text()
+    pool_bytes = slots * H * P * N * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes, "the pool is not aliased"
+    assert mem.temp_size_in_bytes < pool_bytes / 64, mem.temp_size_in_bytes
+
+
 def test_block_step_keeps_the_pools_in_place_for_v5e(one_v5e, monkeypatch):
     """The fused step of generation by blocks at ``sdar30b_serve_blockgen``'s
     real widths (64 rows of 4 positions, 32 query and 4 key/value heads of

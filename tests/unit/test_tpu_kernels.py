@@ -568,6 +568,50 @@ def test_kda_decode_update_compiles_and_matches(tpu, live):
 
 
 @tpu_tier
+@pytest.mark.parametrize("live", [64, 5, 0])
+def test_mamba2_decode_update_compiles_and_matches(tpu, live):
+    """The Mamba-2 decode kernel compiled at ``granite4hmicro_serve_chat``'s
+    widths (64 rows, 64 heads of a 64 x 128 float32 state, two periods of 65
+    slots), every row live, at 5 live rows of 64 and with no live row (the
+    dummy's block, which every step then names, goes out as it came in:
+    interpret mode cannot see that, its outputs start as the aliased input),
+    against the plain-XLA form on the same chip: the live rows' ``y`` and
+    states to 1e-5 of their largest value, every other pool row
+    bit-identical to what it was, an idle row's ``y`` zero."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.transformer import _ssd_decode_update
+    from deepspeed_tpu.ops.pallas.mamba2_decode_update import \
+        mamba2_decode_update
+    from tests.unit.ops.test_mamba2_decode_update import draw_step
+
+    r = np.random.default_rng(live)
+    B, H, P, N, n_slots, base = 64, 64, 64, 128, 65, 65
+    x, dt, A, Bm, Cm, D = draw_step(r, B, H, P, N)
+    slots = np.zeros(B, np.int32)
+    slots[r.choice(B, live, replace=False)] = \
+        r.permutation(np.arange(1, n_slots))[:live]
+    pool = jax.random.normal(jax.random.key(live), (2 * n_slots, H, P, N),
+                             jnp.float32)
+    before = np.asarray(pool)
+    zero = jnp.zeros_like(D)
+    want_y, want = jax.jit(_ssd_decode_update)(
+        pool, x, dt, A, Bm, Cm, zero, jnp.asarray(slots), base)
+    y, new = jax.jit(lambda S, *a: mamba2_decode_update(S, *a, interpret=False))(
+        pool, x, dt, A, Bm, Cm, jnp.asarray(slots), base)
+    y, new, want_y, want = (np.asarray(a) for a in (y, new, want_y, want))
+    on = slots != 0
+    if live:
+        assert np.abs(y[on] - want_y[on]).max() <= 1e-5 * np.abs(want_y[on]).max()
+    assert np.abs(new - want).max() <= 1e-5 * np.abs(want).max()
+    idle = np.ones(2 * n_slots, bool)
+    idle[base + slots[on]] = False
+    np.testing.assert_array_equal(new[idle], before[idle])
+    assert not y[~on].any()
+
+
+@tpu_tier
 def test_solar_toy_logits_through_the_compiled_kda_kernel(tpu):
     """PERF.md section 7(a): the served check of ``solaropen2_serve_decode``
     sees only a wrong or lost state, so the kernel owes the unit tests'
