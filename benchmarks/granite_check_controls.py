@@ -78,7 +78,7 @@ def _faults():
     step, chunked, project = T.ssd_recurrent_step, T.ssd_chunked, T._mamba2_project
 
     def lost_state(cfg, state, x, dt, A, Bm, Cm, D, slots, base):
-        zero = jnp.zeros(state.shape[1:], jnp.float32)
+        zero = jnp.zeros((*x.shape[1:], Bm.shape[-1]), jnp.float32)
         y, _ = jax.vmap(lambda xb, dtb, bb, cb: step(zero, xb, dtb, A, bb, cb, D))(
             x, dt, Bm, Cm)
         return y, state

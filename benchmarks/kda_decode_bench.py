@@ -12,14 +12,15 @@ slots in slot order, read twice and written once). Each is timed at 1, 16,
 64 and 128 live rows of 128, the others idle on the dummy slot: the only
 place the low-occupancy saving is measured, since no cell serves a stateful
 model under an open loop (PERF.md section 7). ``--phase-rows`` times the
-kernel at those rows a phase (``_phase_rows`` is what the program takes).
+kernel at those rows a phase (``state_phases.phase_rows`` is what the
+program takes).
 
 The time is the device's: a program of ``LAYERS`` updates of one pool, its
 ``XLA Modules`` event in a profiler trace over ``LAYERS`` (so the vectors'
 preparation counts), and beside it the kernel's own events. ``GB/s`` is the
 LIVE rows' state read once and written once over that time. Each line also
 checks the live rows' ``o`` and the pool past the dummy against the twin's. The numbers behind
-``_PHASE_BYTES`` (PERF.md section 6, PR 32). TPU only: the script
+``state_phases._PHASE_BYTES`` (PERF.md section 6, PR 32). TPU only: the script
 refuses to print a time from another backend.
 """
 
@@ -75,7 +76,8 @@ def main():
                  "not a TPU: no time is taken")
     here = importlib.import_module("deepspeed_tpu.ops.pallas.kda_decode_update")
     from deepspeed_tpu.models.transformer import _kda_slot_update
-    phase_rows = here._phase_rows
+    from deepspeed_tpu.ops.pallas import state_phases
+    phase_rows = state_phases.phase_rows
     variants = [("twin", None), ("kernel", None)]
     variants += [(f"kernel_r{n}", n) for n in args.phase_rows]
 
@@ -101,7 +103,7 @@ def main():
         vecs, slots = draw(args.seed + live, live)
         want = None
         for label, n in variants:
-            here._phase_rows = (lambda *a, n=n: n) if n else phase_rows
+            state_phases.phase_rows = (lambda *a, n=n: n) if n else phase_rows
             stack = program(label)
             stack.__name__ = f"kda_{live}_{label}"
             run = jax.jit(stack, donate_argnums=(0,))
@@ -113,7 +115,7 @@ def main():
             err = max(float(np.abs(a - b).max() / np.abs(b).max())
                       for a, b in zip(got, want))
             runs[live, label] = (run, vecs, slots, err)
-    here._phase_rows = phase_rows
+    state_phases.phase_rows = phase_rows
 
     trace_dir = tempfile.mkdtemp(prefix="kda_decode_bench_")
     opts = jax.profiler.ProfileOptions()
@@ -150,7 +152,7 @@ def main():
             "live_state_gb_per_s": round(state_bytes / ms / 1e6, 1),
             "rows_a_phase": None if label == "twin" else
             int(label.split("_r")[1]) if "_r" in label else
-            phase_rows(ROWS, HEADS, DK, DV),
+            phase_rows(ROWS, HEADS * DK * DV * 4),
             "max_rel_err_from_twin": float(f"{err:.3g}")}), flush=True)
 
 

@@ -276,8 +276,10 @@ class TransformerConfig:
         shape of its float32 state and of its conv state (the last K - 1
         inputs of its causal conv)."""
         if kind == MAMBA2:
+            # the state N first, the H * P channels on the lanes: the decode
+            # kernel's sums over N are sums of whole vregs
             H, Pd, N = self.ssm_heads, self.ssm_head_dim, self.ssm_state
-            return (H, Pd, N), (self.ssm_conv_kernel - 1, H * Pd + 2 * N)
+            return (N, H * Pd), (self.ssm_conv_kernel - 1, H * Pd + 2 * N)
         H, dk = self.lin_heads, self.lin_head_dim
         return (H, dk, dk), (KDA_CONV_KERNEL - 1, 3 * H * dk)
 
@@ -1709,12 +1711,13 @@ def _kda_decode(cfg: TransformerConfig, x, lp, state, conv, base, slots,
 #   y_t = S_t C_t + D x_t                         S [P, N] float32)
 #   out = (RMSNorm(y * silu(z)) * scale) W_out    (the norm over all H*P)
 #
-# A request's state ([H, P, N] float32 a layer) and conv state (the last
-# K-1 inputs of the conv, [K-1, H*P + 2N]) live in its SLOT of the same two
-# pools a KDA layer's do (``init_paged_kv_cache``, ``cfg.state_shapes``).
-# Decode updates each LIVE row's state where it lies (``_ssd_state_update``:
-# on TPU a Pallas kernel, a row's state a block; elsewhere XLA's gather,
-# update and scatter of the rows' states); a prompt runs the recurrence in chunks of
+# A request's state (float32, kept [N, H*P] a layer: ``_ssd_to_pool``) and
+# conv state (the last K-1 inputs of the conv, [K-1, H*P + 2N]) live in its
+# SLOT of the same two pools a KDA layer's do (``init_paged_kv_cache``,
+# ``cfg.state_shapes``). Decode updates each LIVE row's state where it lies
+# (``_ssd_state_update``: on TPU a Pallas kernel, the live rows' states
+# through VMEM in phases; elsewhere XLA's gather, update and scatter of the
+# rows' states); a prompt runs the recurrence in chunks of
 # ``cfg.ssm_chunk`` tokens (``ssd_chunked``): inside a chunk the quadratic
 # form (C B^T * L)(dt x), L_ij = exp(sum_{j<k<=i} dt_k A), between chunks
 # the state. No matrix inverse (KDA has one).
@@ -1786,6 +1789,20 @@ def _mamba2_output(cfg: TransformerConfig, y, z, lp):
     return g @ _w(lp["w_out"], g)
 
 
+def _ssd_to_pool(S):
+    """A state as the recurrence writes it, ``[..., H, P, N]``, as the pool
+    keeps it: ``[..., N, H * P]``, N on sublanes and the ``(h, p)`` channels
+    on lanes, so that the decode kernel's ``y = S C`` sums whole vregs."""
+    *lead, H, Pd, N = S.shape
+    return jnp.moveaxis(S.reshape(*lead, H * Pd, N), -1, -2)
+
+
+def _ssd_from_pool(S, H: int):
+    """``_ssd_to_pool``'s inverse: ``[..., N, H * P]`` -> ``[..., H, P, N]``."""
+    *lead, N, HP = S.shape
+    return jnp.moveaxis(S, -2, -1).reshape(*lead, H, HP // H, N)
+
+
 def ssd_recurrent_step(S, x, dt, A, Bm, Cm, D):
     """The one-token update of one row: S [H, P, N] float32, x [H, P], dt,
     A, D [H], Bm, Cm [N], all float32 -> (y [H, P], new S)."""
@@ -1796,15 +1813,21 @@ def ssd_recurrent_step(S, x, dt, A, Bm, Cm, D):
 
 def ssd_chunked(S0, x, dt, A, Bm, Cm, D, chunk: int):
     """The recurrence over T tokens of one sequence in chunks of ``chunk``
-    (the last one what is left of T): S0 [H, P, N] float32, x [T, H, P], Bm,
-    Cm [T, N] (their type is the matmuls': bf16 served, float32 in the
-    tests), dt [T, H] and A, D [H] float32 -> (y [T, H, P] float32, S_T). A
+    (the last one what is left of T): S0 [N, H*P] float32, the state AS THE
+    POOL KEEPS IT (``_ssd_to_pool``), x [T, H, P], Bm, Cm [T, N] (their type
+    is the matmuls': bf16 served, float32 in the tests), dt [T, H] and A, D
+    [H] float32 -> (y [T, H, P] float32, S_T [N, H*P]). The state's two
+    products are plain matmuls that way round, ``C S`` [c, N] x [N, H*P] and
+    ``B^T (dt x)`` [N, c] x [c, H*P], and nothing is turned between the
+    pool and the scan (turned after the scan, the compiler kept every state
+    pool of the prefill program N-minor and copied each in and out: 4.7 GB
+    of temporaries at the published sizes, PERF.md section 6, PR 44). A
     position of dt 0 is no position: its decay is 1 and it adds nothing (a
     bucket's padding). Inside a chunk, with G_i the cumulated log decay, y_i
     = sum_{j<=i} (C_i . B_j) e^(G_i - G_j) dt_j x_j + e^G_i S0 C_i + D x_i;
-    S_C = e^G_C S0 + sum_j e^(G_C - G_j) dt_j x_j B_j^T. Decays are float32
-    and only ever taken between two positions j <= i of one chunk, so every
-    exponent is <= 0. The heads lead every operand inside a chunk, and the
+    S_C = e^G_C S0 + sum_j e^(G_C - G_j) dt_j x_j B_j^T (S as [P, N] a
+    head). Decays are float32 and only ever taken between two positions j
+    <= i of one chunk, so every exponent is <= 0. The heads lead every operand inside a chunk, and the
     scan stacks a chunk's y as the matmul leaves it, [H, C, P]: stacked [C,
     H, P], each chunk's y was written by a ``dynamic-update-slice`` of 134
     us on the TPU, three times the chunk's arithmetic (PERF.md section 6,
@@ -1823,12 +1846,14 @@ def ssd_chunked(S0, x, dt, A, Bm, Cm, D, chunk: int):
         dtx = dt_c[:, :, None] * x_c.astype(f32)             # [H, c, P]
         y = jnp.einsum("hij,hjp->hip", (cb * L).astype(mm), dtx.astype(mm),
                        preferred_element_type=f32)
-        y = y + jnp.exp(G)[:, :, None] * jnp.einsum(
-            "in,hpn->hip", c_c.astype(f32), S, precision=_HI)
+        c = dtx.shape[1]
+        sc = jnp.einsum("in,nk->ik", c_c.astype(f32), S, precision=_HI)
+        y = y + jnp.exp(G)[:, :, None] * jnp.swapaxes(
+            sc.reshape(c, H, Pd), 0, 1)
         to_end = jnp.exp(G[:, -1:] - G)                      # [H, c]
-        S = jnp.exp(G[:, -1])[:, None, None] * S + jnp.einsum(
-            "hjp,jn->hpn", (to_end[:, :, None] * dtx).astype(mm), b_c,
-            preferred_element_type=f32)
+        w = jnp.swapaxes((to_end[:, :, None] * dtx).astype(mm), 0, 1)
+        S = jnp.repeat(jnp.exp(G[:, -1]), Pd)[None, :] * S + jnp.einsum(
+            "jn,jk->nk", b_c, w.reshape(c, H * Pd), preferred_element_type=f32)
         return S, y
 
     n, whole = T // C, T // C * C
@@ -1852,7 +1877,7 @@ def _mamba2_prefill(cfg: TransformerConfig, x, lp, state, conv, slot,
                     n_valid, fresh):
     """The mixer over a prompt, or a chunk of one, of ONE request: x [1, T,
     D] (T a compile bucket, the first ``n_valid`` positions real), ``slot``
-    the request's row of the layer in ``state`` [rows, H, P, N] and ``conv``
+    the request's row of the layer in ``state`` [rows, N, H*P] and ``conv``
     [rows, K-1, conv_dim]. ``fresh`` (a bool, traced or not): the request's
     first piece starts from zero, whatever the slot's last holder left
     there. Padding has dt 0: the state after the bucket is the state after
@@ -1879,7 +1904,7 @@ def _mamba2_prefill(cfg: TransformerConfig, x, lp, state, conv, slot,
 
 def _ssd_decode_update(state, x, dt, A, Bm, Cm, D, slots, base):
     """The plain-XLA form of a decode step's state update, and what the
-    tests compare the kernel against: ``state`` [pool rows, H, P, N]
+    tests compare the kernel against: ``state`` [pool rows, N, H*P]
     float32, the rows' x [B, H, P], dt [B, H], Bm, Cm [B, N] float32 by
     row, row ``b`` at ``state[base + slots[b]]``: the rows' states gathered,
     ``ssd_recurrent_step`` a row, and scattered back. An idle row (slot 0)
@@ -1890,8 +1915,8 @@ def _ssd_decode_update(state, x, dt, A, Bm, Cm, D, slots, base):
     old = state[rows]
     y, new = jax.vmap(
         lambda S, xb, dtb, bb, cb: ssd_recurrent_step(S, xb, dtb, A, bb, cb, D)
-    )(old, x, dt, Bm, Cm)
-    new = jnp.where(live[..., None], new, old)
+    )(_ssd_from_pool(old, x.shape[1]), x, dt, Bm, Cm)
+    new = jnp.where(live, _ssd_to_pool(new), old)
     return jnp.where(live, y, 0.0), state.at[rows].set(new)
 
 
@@ -1901,9 +1926,10 @@ def _ssd_state_update(cfg: TransformerConfig, state, x, dt, A, Bm, Cm, D,
     arithmetic, chosen as the paged kernel is (``_use_flash``: the backend
     and the shape, never the model), both over the LIVE rows' slots alone:
 
-    * ``mamba2_kernel`` (TPU): ``ops/pallas/mamba2_decode_update.py``, a
-      live row's state one block of the pool, read once and written once
-      under Pallas's own pipeline;
+    * ``mamba2_kernel`` (TPU): ``ops/pallas/mamba2_decode_update.py``, the
+      live rows' states read once and written once where they lie, in
+      phases of one direction at a time (``state_phases.py``, the KDA
+      kernel's schedule);
     * ``slot_gather`` (elsewhere, and shapes the kernel cannot tile):
       ``_ssd_decode_update``, the rows' states gathered, updated and
       scattered back by XLA."""
@@ -1918,14 +1944,18 @@ def _ssd_state_update(cfg: TransformerConfig, state, x, dt, A, Bm, Cm, D,
     if out is None:
         return _ssd_decode_update(state, x, dt, A, Bm, Cm, D, slots, base)
     y, state = out
-    live = (slots != 0)[:, None, None]
-    return y + jnp.where(live, D[:, None] * x, 0.0), state
+    # D x over the H * P channels as the kernel leaves y, one lane row a
+    # row (laid out [H, P], each operand would be turned and turned back)
+    B, H, Pd = x.shape
+    dx = jnp.repeat(D, Pd) * x.reshape(B, H * Pd)
+    y = y.reshape(B, H * Pd) + jnp.where((slots != 0)[:, None], dx, 0.0)
+    return y.reshape(B, H, Pd), state
 
 
 def _mamba2_decode(cfg: TransformerConfig, x, lp, state, conv, base, slots):
     """One token a row: x [B, 1, D], ``slots`` [B] each row's state slot
     (0, the dummy, for an inactive row), the layer's slots from row ``base``
-    of ``state`` and ``conv``."""
+    of ``state`` [rows, N, H*P] and ``conv``."""
     f32 = jnp.float32
     rows = base + slots
     z, xs, Bm, Cm, dt, win = _mamba2_project(cfg, x, lp, conv[rows])
@@ -1967,7 +1997,7 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
     tuple of one array a recurrent POSITION of the period, [periods,
     state_slots, *state shape] float32 and [periods, state_slots, *conv
     shape] with the kind's own shapes (``cfg.state_shapes``: a KDA layer's
-    [H, dk, dv] and [K-1, 3*H*dk], a Mamba-2 layer's [H, P, N] and [K-1,
+    [H, dk, dv] and [K-1, 3*H*dk], a Mamba-2 layer's [N, H*P] and [K-1,
     H*P + 2N]): a slot a running request and slot 0 the dummy. (One array
     a position, not one for all:
     the period's layers are unrolled, so their offsets into a shared array

@@ -26,8 +26,8 @@ Sites and their forms:
                       over the live rows' slots | ``kda_recurrent_step``
                       over the layer's whole slice of the pool)
 ``ssd_decode``        ``mamba2_kernel`` | ``slot_gather`` (a Mamba-2 layer's
-                      decode-step state update: the Pallas kernel, a live
-                      row's state a block | XLA's gather, update and
+                      decode-step state update: the Pallas kernel over the
+                      live rows' slots | XLA's gather, update and
                       scatter of the rows' states)
 ``experts``           ``grouped_kernel`` | ``dense`` | ``ragged`` (a no-drop
                       MoE layer's expert matmuls, from the rows of a call:

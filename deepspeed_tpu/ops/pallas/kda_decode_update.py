@@ -19,14 +19,11 @@ ONCE each way:
   (``input_output_aliases``): no second buffer exists. Rows are addressed
   ``row -> pool row`` through scalar prefetch, as ``paged_decode_attention``
   addresses blocks through its table;
-* the live rows come FIRST (``order``, a stable sort by "holds the dummy
-  slot"), in PHASES of R rows (``_PHASE_BYTES``: 16 MB of state). A phase's
-  states are brought to VMEM by the kernel's own ``make_async_copy``, one
-  copy a row, updated there in place over the phase's R grid steps, and
-  copied back to where they came from. Two buffers take turns, and the
-  copies are ordered so that reads and writes never share the HBM: while
-  phase p is worked on, phase p - 1 goes out; when both are done, phase p +
-  1 comes in. Measured (PERF.md section 6, PR 32): the chip writes at 644
+* the live rows come FIRST, in PHASES of R rows (16 MB of state), one
+  direction at a time: the schedule is ``state_phases.py``'s (``in_phases``:
+  the kernel's own ``make_async_copy`` a row, two buffers in turn, never a
+  read and a write in flight together; the Mamba-2 decode kernel runs the
+  same one). Measured (PERF.md section 6, PR 32): the chip writes at 644
   GB/s and reads at 731, a round trip with both directions in flight at
   once runs at 657, and one direction at a time in 16 MB turns at 692;
 * an inactive row (pool row ``base``: slot 0, the dummy) issues no copy and
@@ -57,19 +54,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.dispatch import resolve_interpret
+from deepspeed_tpu.ops.pallas import state_phases
 from deepspeed_tpu.utils.logging import warn_once
-
-# State a PHASE moves: the rows whose states are read together, updated in
-# VMEM and written back together, two phases' buffers in turn. Measured on a
-# v5e (PERF.md section 6, PR 32): the chip writes HBM at 644 GB/s and reads
-# it at 731; a read and a write in flight at once share it at 657 for the
-# round trip, while 16 MB one way and then 16 MB the other reach 692.
-_PHASE_BYTES = 16 * 1024 * 1024
-
-
-def _phase_rows(B: int, H: int, dk: int, dv: int) -> int:
-    """Rows a phase (R): as many as the budget holds, at least one."""
-    return int(max(1, min(B, _PHASE_BYTES // (H * dk * dv * 4))))
 
 
 def kda_envelope_ok(dk: int, dv: int) -> bool:
@@ -79,44 +65,12 @@ def kda_envelope_ok(dk: int, dv: int) -> bool:
 
 
 def _kernel(order_ref, rows_ref, nlive_ref, eg_ref, k_ref, q_ref, v_ref,
-            sc_ref, pool_in, o_ref, pool_out, buf, cols, rsem, wsem, *, R):
+            sc_ref, pool_in, o_ref, pool_out, buf, cols, rsem, wsem):
     i = pl.program_id(0)
     n_live = nlive_ref[0]
     H = k_ref.shape[1]
-    ph, at = i // R, i % R              # the row's phase, its place in it
 
-    def copies(phase, write, wait):
-        """Start, or wait for, a phase's copies: one a LIVE row of it, the
-        row's whole state, in (pool -> VMEM) or out (VMEM -> pool)."""
-        for j in range(R):
-            pos = phase * R + j
-
-            @pl.when(pos < n_live)
-            def _():
-                row, turn = rows_ref[order_ref[pos]], phase % 2
-                vm = buf.at[turn, j]
-                cp = pltpu.make_async_copy(vm, pool_out.at[row], wsem.at[turn]) \
-                    if write else \
-                    pltpu.make_async_copy(pool_in.at[row], vm, rsem.at[turn])
-                cp.wait() if wait else cp.start()
-
-    @pl.when(jnp.logical_and(i == 0, n_live > 0))
-    def _():
-        copies(0, write=False, wait=False)
-
-    @pl.when(i < n_live)
-    def _():
-        # a phase opens: its states have landed, and the phase before it,
-        # updated by now, goes out while this one is worked on. Never a read
-        # and a write in flight together.
-        @pl.when(at == 0)
-        def _():
-            copies(ph, write=False, wait=True)
-
-            @pl.when(ph > 0)
-            def _():
-                copies(ph - 1, write=True, wait=False)
-
+    def update(ph, at):
         # the row's per-channel vectors with dk on sublanes: head h's column
         # then broadcasts along the lanes of its state
         for c, ref in enumerate((eg_ref, k_ref, q_ref)):
@@ -132,24 +86,8 @@ def _kernel(order_ref, rows_ref, nlive_ref, eg_ref, k_ref, q_ref, v_ref,
             o_ref[0, h:h + 1, :] = p + sc_ref[0, 0, H + h] * u
             buf[ph % 2, at, h] = s1 + kc * u
 
-        # a phase closes: the phase before it has landed, so its buffer
-        # takes the next phase's reads; the last phase goes out itself
-        last = i + 1 == n_live
-
-        @pl.when(jnp.logical_or(at == R - 1, last))
-        def _():
-            @pl.when(ph > 0)
-            def _():
-                copies(ph - 1, write=True, wait=True)
-
-            @pl.when((ph + 1) * R < n_live)
-            def _():
-                copies(ph + 1, write=False, wait=False)
-
-            @pl.when(last)
-            def _():
-                copies(ph, write=True, wait=False)
-                copies(ph, write=True, wait=True)
+    state_phases.in_phases(i, n_live, order_ref, rows_ref, pool_in, pool_out,
+                           buf, rsem, wsem, update)
 
     @pl.when(i >= n_live)
     def _():
@@ -161,14 +99,11 @@ def _kda_call(state, order, rows, n_live, eg, kh, qh, v, sc, *, R, interpret):
     B, H, dk = kh.shape
     dv = v.shape[-1]
 
-    def by_row(i, order, rows, n_live):
-        # a step past the live rows names the last live row's block again:
-        # the pipeline fetches nothing for it
-        return (order[jnp.minimum(i, jnp.maximum(n_live[0] - 1, 0))], 0, 0)
-
+    by_row = state_phases.by_live_row
+    buf, *sems = state_phases.phase_scratch(R, (H, dk, dv))
     vec = lambda d: pl.BlockSpec((1, H, d), by_row)         # noqa: E731
     o, state = pl.pallas_call(
-        functools.partial(_kernel, R=R),
+        _kernel,
         name="kda_decode_update",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -183,11 +118,8 @@ def _kda_call(state, order, rows, n_live, eg, kh, qh, v, sc, *, R, interpret):
                 pl.BlockSpec(memory_space=pl.ANY),
             ],
             scratch_shapes=[
-                pltpu.VMEM((2, R, H, dk, dv), jnp.float32),  # two phases
-                pltpu.VMEM((3, dk, H), jnp.float32),         # exp(g), kh, qh
-                pltpu.SemaphoreType.DMA((2,)),               # reads, a buffer
-                pltpu.SemaphoreType.DMA((2,)),               # writes, a buffer
-            ],
+                buf, pltpu.VMEM((3, dk, H), jnp.float32),    # exp(g), kh, qh
+                *sems],
         ),
         # operand 8 (after the three prefetched scalars): the pool is output 1
         input_output_aliases={8: 1},
@@ -227,13 +159,10 @@ def kda_decode_update(state, qh, kh, v, g, beta, slots, base, *,
         return None
     interpret = resolve_interpret("kda_decode_update", interpret)
     f32 = jnp.float32
-    slots = jnp.asarray(slots, jnp.int32).reshape(B)
-    live = slots != 0
-    order = jnp.argsort(jnp.logical_not(live), stable=True).astype(jnp.int32)
-    n_live = jnp.sum(live, dtype=jnp.int32).reshape(1)
-    rows = jnp.asarray(base, jnp.int32) + slots
+    order, rows, n_live = state_phases.live_rows(slots, base, B)
     sc = jnp.concatenate([beta, jnp.sum(qh * kh, axis=-1)], axis=-1)[:, None]
     return _kda_call(state, order, rows, n_live, jnp.exp(g).astype(f32),
                      kh.astype(f32), qh.astype(f32), v.astype(f32),
-                     sc.astype(f32), R=_phase_rows(B, H, dk, dv),
+                     sc.astype(f32),
+                     R=state_phases.phase_rows(B, H * dk * dv * 4),
                      interpret=bool(interpret))

@@ -5,14 +5,13 @@ afterwards (the dummy among them: the kernel issues no copy for an inactive
 row). One parametrised test, a case each state the serving loop puts it in.
 """
 
-import importlib
-
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.ops import dispatch
+from deepspeed_tpu.ops.pallas import state_phases
 from deepspeed_tpu.ops.pallas.kda_decode_update import kda_decode_update
 
 REL = 1e-6
@@ -59,10 +58,8 @@ def test_kernel_against_the_recurrence(case, monkeypatch):
     pool = jnp.asarray(r.standard_normal((2 * SLOTS, H, dk, dv)), jnp.float32)
     slots = np.asarray(slots, np.int32)
     if phase_rows:
-        # (the package exports the function under the module's name)
-        monkeypatch.setattr(
-            importlib.import_module("deepspeed_tpu.ops.pallas.kda_decode_update"),
-            "_PHASE_BYTES", phase_rows * H * dk * dv * 4)
+        monkeypatch.setattr(state_phases, "_PHASE_BYTES",
+                            phase_rows * H * dk * dv * 4)
 
     dispatch.reset()
     out = kda_decode_update(pool + 0.0, qh, kh, v, g, beta, slots, base)

@@ -172,7 +172,7 @@ def test_the_published_model_is_built_whole():
         and ssm["b_conv"].shape == (4, 4352) and ssm["w_out"].shape == (4, 4096, 2048)
     pools = jax.eval_shape(lambda: model.init_paged_cache(
         769, 128, jnp.bfloat16, state_slots=65))
-    assert [a.shape for a in pools["state"]] == [(4, 65, 64, 64, 128)] * 9
+    assert [a.shape for a in pools["state"]] == [(4, 65, 128, 64 * 64)] * 9
     assert {a.dtype for a in pools["state"]} == {jnp.dtype(jnp.float32)}
     assert [a.shape for a in pools["conv"]] == [(4, 65, 3, 4352)] * 9
     assert pools["conv"][0].dtype == jnp.bfloat16
@@ -240,10 +240,11 @@ def test_the_chunked_form_is_the_sequential_recurrence(n, from_zero):
     S0 = jnp.zeros((4, 32, 16), jnp.float32) if from_zero else \
         jnp.asarray(np.random.default_rng(99).standard_normal((4, 32, 16)),
                     jnp.float32)
-    y, S = T.ssd_chunked(S0, x, dt, A, Bm, Cm, D, chunk=8)
+    # the chunked form takes and leaves the state as the pool keeps it
+    y, S = T.ssd_chunked(T._ssd_to_pool(S0), x, dt, A, Bm, Cm, D, chunk=8)
     want_y, want_S = _sequential(S0, x, dt, A, Bm, Cm, D)
     np.testing.assert_allclose(y, want_y, rtol=0, atol=2e-5)
-    np.testing.assert_allclose(S, want_S, rtol=0, atol=2e-5)
+    np.testing.assert_allclose(T._ssd_from_pool(S, 4), want_S, rtol=0, atol=2e-5)
 
 
 def test_a_buckets_padding_touches_neither_state_nor_conv(toy):
@@ -276,8 +277,8 @@ def test_the_decode_update_moves_the_live_rows_alone():
     update gives, every other pool row is bit for bit what it was, an idle
     row's output is zero."""
     x, dt, A, Bm, Cm, D = _ssd_inputs(3, 5)
-    pool = jnp.asarray(np.random.default_rng(4).standard_normal((12, 4, 32, 16)),
-                       jnp.float32)
+    pool = jnp.asarray(np.random.default_rng(4).standard_normal((12, 16, 4 * 32)),
+                       jnp.float32)     # kept [N, H * P]: T._ssd_to_pool
     slots = jnp.asarray([3, 0, 1, 0, 4], jnp.int32)
     y, new = jax.jit(T._ssd_decode_update)(pool, x, dt, A, Bm, Cm, D, slots,
                                            jnp.int32(6))
@@ -285,10 +286,11 @@ def test_the_decode_update_moves_the_live_rows_alone():
         if s == 0:
             assert float(jnp.abs(y[b]).max()) == 0.0
             continue
-        want_y, want_S = T.ssd_recurrent_step(pool[6 + s], x[b], dt[b], A,
-                                              Bm[b], Cm[b], D)
+        want_y, want_S = T.ssd_recurrent_step(
+            T._ssd_from_pool(pool[6 + s], 4), x[b], dt[b], A, Bm[b], Cm[b], D)
         np.testing.assert_allclose(y[b], want_y, rtol=1e-6, atol=1e-6)
-        np.testing.assert_allclose(new[6 + s], want_S, rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(new[6 + s], T._ssd_to_pool(want_S),
+                                   rtol=1e-6, atol=1e-6)
     untouched = np.array([r for r in range(12) if r not in (7, 9, 10)])
     np.testing.assert_array_equal(new[untouched], pool[untouched])
 

@@ -380,11 +380,12 @@ def test_mamba2_state_pools_stay_in_one_buffer_for_v5e(one_v5e, monkeypatch):
 
 def test_mamba2_kernel_compiles_for_v5e_at_real_widths(one_v5e, monkeypatch):
     """Mosaic takes the Mamba-2 decode kernel at
-    ``granite4hmicro_serve_chat``'s widths (64 rows, 64 heads of a 64 x 128
-    float32 state, 4 x 65 slots of one pool: what interpret mode cannot see
-    is the turned vectors' lane slices, the lane sums stored a column, the
-    decays in SMEM and the VMEM four 2 MB blocks take), and the pool is its
-    input and its output in one buffer."""
+    ``granite4hmicro_serve_chat``'s widths (64 rows, a 128 x 4,096 float32
+    state a row: 64 heads of 64 x 128, 4 x 65 slots of one pool: what
+    interpret mode cannot see is B and C turned in a tile of eight
+    sublanes, the pool's rows copied from and to HBM where it lies and the
+    VMEM two phases of eight 2 MB states take), and the pool is its input
+    and its output in one buffer."""
     from deepspeed_tpu.ops import dispatch
     from deepspeed_tpu.ops.pallas.mamba2_decode_update import \
         mamba2_decode_update
@@ -399,7 +400,7 @@ def test_mamba2_kernel_compiles_for_v5e_at_real_widths(one_v5e, monkeypatch):
         lambda S, x, dt, A, b, c, ss: mamba2_decode_update(
             S, x, dt, A, b, c, ss, 65, interpret=False),
         donate_argnums=(0,)).lower(
-        sds((slots, H, P, N)), sds((B, H, P)), sds((B, H)), sds((H,)),
+        sds((slots, N, H * P)), sds((B, H, P)), sds((B, H)), sds((H,)),
         sds((B, N)), sds((B, N)), sds((B,), I32)).compile()
     assert "mamba2_decode_update" in compiled.as_text()
     pool_bytes = slots * H * P * N * 4

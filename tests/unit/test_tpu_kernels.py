@@ -568,14 +568,14 @@ def test_kda_decode_update_compiles_and_matches(tpu, live):
 
 
 @tpu_tier
-@pytest.mark.parametrize("live", [64, 5, 0])
+@pytest.mark.parametrize("live", [64, 9, 5, 0])
 def test_mamba2_decode_update_compiles_and_matches(tpu, live):
     """The Mamba-2 decode kernel compiled at ``granite4hmicro_serve_chat``'s
-    widths (64 rows, 64 heads of a 64 x 128 float32 state, two periods of 65
-    slots), every row live, at 5 live rows of 64 and with no live row (the
-    dummy's block, which every step then names, goes out as it came in:
-    interpret mode cannot see that, its outputs start as the aliased input),
-    against the plain-XLA form on the same chip: the live rows' ``y`` and
+    widths (64 rows, a 128 x 4,096 float32 state a row: 64 heads of 64 x
+    128, two periods of 65 slots), every row live (eight phases of eight
+    rows), at 9 live rows of 64 (a phase and one row), at 5 (a short phase)
+    and with no live row (no copy is issued: the pool goes out as it came
+    in), against the plain-XLA form on the same chip: the live rows' ``y`` and
     states to 1e-5 of their largest value, every other pool row
     bit-identical to what it was, an idle row's ``y`` zero."""
     import jax
@@ -592,7 +592,7 @@ def test_mamba2_decode_update_compiles_and_matches(tpu, live):
     slots = np.zeros(B, np.int32)
     slots[r.choice(B, live, replace=False)] = \
         r.permutation(np.arange(1, n_slots))[:live]
-    pool = jax.random.normal(jax.random.key(live), (2 * n_slots, H, P, N),
+    pool = jax.random.normal(jax.random.key(live), (2 * n_slots, N, H * P),
                              jnp.float32)
     before = np.asarray(pool)
     zero = jnp.zeros_like(D)
