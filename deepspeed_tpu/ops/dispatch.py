@@ -36,6 +36,13 @@ Sites and their forms:
                       most 128 rows on one device | every held expert over
                       every row | ``jax.lax.ragged_dot`` over sorted rows,
                       from 1,536 rows on)
+``flash_bwd_diag``    ``chunks`` | ``whole`` (how the flash backward kernels
+                      take a diagonal block, from the static shapes of a
+                      call, once a backward pass traced: walked in chunks
+                      of ``flash_attention._DIAG_CHUNK`` rows / keys, only
+                      the pairs on or below the diagonal computed | whole,
+                      where the call is not causal, ``bq != bk`` or the
+                      block is no two whole chunks)
 ``kernel/<name>``     ``compiled`` | ``interpret`` | ``jnp`` (one per Pallas
                       entry point; ``jnp`` = the kernel's plain-XLA twin)
 ====================  ====================================================

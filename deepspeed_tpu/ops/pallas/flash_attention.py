@@ -14,7 +14,14 @@ innermost, so the (m, l, acc) running-softmax state lives in VMEM scratch
 across kv steps and the output block is written once on the last step.
 Backward recomputes p from the saved logsumexp (no S² residuals): one
 kernel accumulates dq over kv blocks, a second accumulates dk/dv over q
-blocks.
+blocks. Both WALK a causal diagonal block in chunks of ``_DIAG_CHUNK`` query
+rows and compute, a chunk, only the keys up to its own (``_walk``): 9/16 of a
+1,024 x 1,024 block's scores at a chunk of 128 where the mask keeps 1/2, so
+the masked half of the block is never multiplied; a grid step above the
+diagonal names blocks the kernel already has (``_skipped_block_maps``), and
+dk/dv compute their scores TRANSPOSED, (keys, rows), so that p^T and ds^T are
+the products' left sides as they come. The forward takes a diagonal block
+whole.
 
 Two VPU optimisations matter on TPU (softmax is VPU-bound while the dots
 ride the MXU):
@@ -24,7 +31,9 @@ ride the MXU):
 * the common case (causal, no user mask, no alibi, no padding) takes a
   **plain fast path**: fully-visible blocks below the diagonal skip masking
   entirely, and diagonal blocks add one precomputed triangular bias block
-  instead of running per-element iota/compare/select.
+  instead of running per-element iota/compare/select (the forward: the whole
+  (bq, bk) block's; the backward kernels' walk: its (chunk, chunk) corner,
+  added to a query chunk's OWN keys alone).
 
 The kernel's forward outputs (o, lse) carry ``checkpoint_name`` tags
 ("flash_o"/"flash_lse") so activation-checkpoint policies can save the
@@ -50,10 +59,17 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.dispatch import resolve_interpret
+from deepspeed_tpu.ops.dispatch import record, resolve_interpret
 
 _MASKED = -1e30  # large-negative for masked logits (exp2 underflows to 0)
 _LOG2E = 1.4426950408889634
+#: query rows a chunk of the BACKWARD kernels' walk over a diagonal block
+#: (``_diag_chunk``; chosen on the chip, ``benchmarks/flash_train_bench.py``:
+#: PERF.md section 6, PR 46: 128 and 256 within 2-4% of each other, 512 8-13%
+#: behind). A multiple of 128 on a TPU (the chunks slice the lse / delta /
+#: mask rows along lanes), and a 1,024 block is 8 chunks, the longest walk
+#: unrolled; the CPU tests patch it small.
+_DIAG_CHUNK = 128
 #: the ``checkpoint_name`` tags of the forward outputs (o, lse): what a remat
 #: policy lists to keep them
 RESIDUAL_NAMES = ("flash_o", "flash_lse")
@@ -65,14 +81,19 @@ def _named(o, lse):
 
 
 def _block_bias(qoff, koff, bq, bk, seq_len, causal, slope, mask_blk,
-                stair=1, window=0):
+                stair=1, window=0, keys_first=False):
     """Additive log2-domain bias for a (bq, bk) score block from GLOBAL
     positions: alibi + causal/pad masking + user key mask. ``stair`` > 1:
     the causal triangle is a staircase of that step (a query sees all of
     its own group of ``stair`` positions). ``window`` > 0: a query sees the
     ``window`` keys up to its own and none before them."""
-    qpos = qoff + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    kpos = koff + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    if keys_first:
+        # the (bk, bq) bias of a block of TRANSPOSED scores (dk/dv)
+        qpos = qoff + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+        kpos = koff + jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+    else:
+        qpos = qoff + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        kpos = koff + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
     bias = (slope * _LOG2E) * (kpos - qpos).astype(jnp.float32)  # slope==0 → no-op
     valid = kpos < seq_len
     if causal and stair > 1:
@@ -82,7 +103,8 @@ def _block_bias(qoff, koff, bq, bk, seq_len, causal, slope, mask_blk,
     if window:
         valid = valid & (kpos > qpos - window)
     bias = jnp.where(valid, bias, _MASKED)
-    return bias + mask_blk[None, :] * _LOG2E
+    mask_blk = mask_blk[:, None] if keys_first else mask_blk[None, :]
+    return bias + mask_blk * _LOG2E
 
 
 def _dispatch(run, i, j, plain, causal, update, logits, tri_ref, bias,
@@ -139,6 +161,89 @@ def _make_tri(bq, bk, stair=1):
     return jnp.where(r >= c, 0.0, _MASKED).astype(jnp.float32)
 
 
+def _diag_chunk(causal, bq, bk):
+    """Query rows a chunk of the backward kernels' walk over a diagonal
+    block, or 0 where they take every block whole (``_dispatch``, as the
+    forward does): non-causal, ``bq != bk``, or a block that is no two whole
+    chunks."""
+    c = _DIAG_CHUNK
+    return c if causal and bq == bk and bq % c == 0 and bq >= 2 * c else 0
+
+
+def _skipped_block_maps(causal, bq, bk):
+    """(key block, query block) index maps ``f(i, j)`` of the backward
+    kernels' in-specs. Causal with bq == bk, a step above the diagonal
+    (j > i) is skipped: ``dq`` names the key block it has (the diagonal's,
+    min(j, i)) and ``dk/dv`` the query block it is about to need (max(i,
+    j)), so a skipped step copies nothing it will not read and the step
+    after it need not wait for its blocks (as the forward band's
+    ``_band_kv_spec``). Anything else: the step's own blocks."""
+    if causal and bq == bk:
+        return (lambda i, j: jnp.minimum(j, i)), (lambda i, j: jnp.maximum(i, j))
+    return (lambda i, j: j), (lambda i, j: i)
+
+
+def _walk(run, i, j, bq, chunk, pair):
+    """The backward kernels' analogue of :func:`_dispatch` where a diagonal
+    block is walked in chunks (``_diag_chunk`` > 0, so causal and bq == bk).
+    On block i == j: ``pair(rows, keys, True)`` once a chunk of ``chunk`` query
+    rows, with the keys up to and including the chunk's own (static slices
+    of the block): every score on or below the diagonal once, nothing right
+    of the chunk's own keys, and the causal edge crosses the LAST ``chunk``
+    keys of a pair only (a staircase's too: its step divides 8). On a block
+    below the diagonal: the whole of it, ``pair(all rows, all keys, False)``.
+    Query chunk by query chunk for ``dq`` AND ``dk/dv`` (PERF.md section 6,
+    PR 46: key chunk by key chunk, every pair a chunk wide, read 1.3-1.75 x
+    ``dq``'s time at a chunk of 128 and 1.0-1.3 x ``dk/dv``'s, and a chunk's
+    own keys as a product of their own up to 1.37 x)."""
+    @pl.when(jnp.logical_and(run, i == j))
+    def _():
+        for lo in range(0, bq, chunk):
+            pair(slice(lo, lo + chunk), slice(0, lo + chunk), True)
+
+    @pl.when(jnp.logical_and(run, i != j))
+    def _():
+        pair(slice(0, bq), slice(0, bq), False)
+
+
+def _walk_bias(s, rs, ks, diag, below, edge, keys_first=False):
+    """The scores of a walk's pair plus their bias. ``diag``: a pair of a
+    diagonal block, whose keys end with the query chunk's own: those take
+    ``edge(rows, keys)``, the bias of a chunk pair the causal edge crosses,
+    and the keys before them ``below(rows, keys)``, as the whole of a block
+    below the diagonal does (``below`` None: no bias there)."""
+    def clear(x, keys):
+        return x if below is None else x + below(rs, keys)
+    if not diag:
+        return clear(s, ks)
+    own = slice(rs.start, ks.stop)
+    if own == ks:
+        return s + edge(rs, own)
+    lo = own.start - ks.start
+    if keys_first:
+        return jnp.concatenate([clear(s[:lo], slice(ks.start, own.start)),
+                                s[lo:] + edge(rs, own)], axis=0)
+    return jnp.concatenate([clear(s[:, :lo], slice(ks.start, own.start)),
+                            s[:, lo:] + edge(rs, own)], axis=1)
+
+
+def _general_walk(run, i, j, bq, chunk, plain, update, logits, tri_ref, bias,
+                  keys_first=False):
+    """:func:`_walk` for the general kernels: a plain call's bias is tri on
+    a chunk's own keys and nothing before them, another's is computed a
+    pair, with the causal compare on a chunk's own keys alone."""
+    if plain:
+        below, edge = None, lambda rs, ks: tri_ref[:]
+    else:
+        below, edge = (functools.partial(bias, edge=e) for e in (False, True))
+
+    def pair(rs, ks, diag):
+        update(_walk_bias(logits(rs, ks), rs, ks, diag, below, edge, keys_first),
+               rs, ks)
+
+    _walk(run, i, j, bq, chunk, pair)
+
+
 def _packed_dispatch(run, i, j, causal, step, logits, tri_ref, P):
     """Packed-kernel analogue of :func:`_dispatch`: per packed head p, run
     ``step(p, logits(p) [+ tri])`` with the diagonal tri only where needed."""
@@ -160,6 +265,8 @@ def _packed_dispatch(run, i, j, causal, step, logits, tri_ref, P):
 
 
 def _parse_rest(rest, plain, has_layout):
+    # tri: the forward's (bq, bk) diagonal-block bias; in a backward kernel
+    # that walks (``chunk`` > 0) the (chunk, chunk) bias of a diagonal pair
     idx = 0
     tri_ref = None
     if plain:
@@ -234,7 +341,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, slope_ref, *rest,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, slope_ref,
-               *rest, scale, causal, seq_len, bq, bk, plain, has_layout, stair=1):
+               *rest, scale, causal, seq_len, bq, bk, plain, has_layout, stair=1,
+               chunk=0):
+    # ``chunk`` > 0: a diagonal block is walked in chunks (``_walk``) and
+    # tri_ref is the (chunk, chunk) bias of a pair on the diagonal
     tri_ref, layout_ref, (dq_ref, dq_scr) = _parse_rest(rest, plain, has_layout)
     j = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -247,23 +357,32 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, slope_
     qoff, koff = i * bq, j * bk
     needed = True if not causal else (koff <= qoff + bq - 1)
     run = needed if layout_ref is None else jnp.logical_and(needed, layout_ref[0, 0] > 0)
+    # rows / keys of the block a call covers: all of it, or a walk's pair
+    rows, keys = slice(0, bq), slice(0, bk)
 
-    def logits():
-        return jax.lax.dot_general(q_ref[:], k_ref[:], (((1,), (1,)), ((), ())),
+    def logits(rs=rows, ks=keys):
+        return jax.lax.dot_general(q_ref[rs, :], k_ref[ks, :], (((1,), (1,)), ((), ())),
                                    preferred_element_type=jnp.float32) * (scale * _LOG2E)
 
-    def update(s):
-        p = jnp.exp2(s - lse_ref[0][:, None])
-        dp = jax.lax.dot_general(do_ref[:], v_ref[:], (((1,), (1,)), ((), ())),
+    def update(s, rs=rows, ks=keys):
+        k = k_ref[ks, :]
+        p = jnp.exp2(s - lse_ref[0, rs][:, None])
+        dp = jax.lax.dot_general(do_ref[rs, :], v_ref[ks, :], (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0][:, None]) * scale).astype(k_ref.dtype)
-        dq_scr[:] = dq_scr[:] + jax.lax.dot_general(
-            ds, k_ref[:], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_ref[0, rs][:, None]) * scale).astype(k.dtype)
+        dq_scr[rs, :] = dq_scr[rs, :] + jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    _dispatch(run, i, j, plain, causal, update, logits, tri_ref,
-              lambda: _block_bias(qoff, koff, bq, bk, seq_len, causal,
-                                  slope_ref[0, 0], mask_ref[0].astype(jnp.float32),
-                                  stair))
+    def bias(rs=rows, ks=keys, edge=causal):
+        # ``edge``: the causal edge crosses these rows and keys
+        return _block_bias(qoff + rs.start, koff + ks.start, rs.stop - rs.start,
+                           ks.stop - ks.start, seq_len, edge, slope_ref[0, 0],
+                           mask_ref[0, ks].astype(jnp.float32), stair)
+
+    if chunk:
+        _general_walk(run, i, j, bq, chunk, plain, update, logits, tri_ref, bias)
+    else:
+        _dispatch(run, i, j, plain, causal, update, logits, tri_ref, bias)
 
     @pl.when(j == nk - 1)
     def _():
@@ -271,7 +390,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, slope_
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, slope_ref,
-                *rest, scale, causal, seq_len, bq, bk, plain, has_layout, stair=1):
+                *rest, scale, causal, seq_len, bq, bk, plain, has_layout, stair=1,
+                chunk=0):
     tri_ref, layout_ref, (dk_ref, dv_ref, dk_scr, dv_scr) = \
         _parse_rest(rest, plain, has_layout)
     # grid (B, KV, nk, G, nq): q blocks innermost, then the G query heads of
@@ -292,25 +412,37 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref, slope
     qoff, koff = i * bq, j * bk
     needed = True if not causal else (koff <= qoff + bq - 1)
     run = needed if layout_ref is None else jnp.logical_and(needed, layout_ref[0, 0] > 0)
+    rows, keys = slice(0, bq), slice(0, bk)
 
-    def logits():
-        return jax.lax.dot_general(q_ref[:], k_ref[:], (((1,), (1,)), ((), ())),
+    # the scores TRANSPOSED, (keys, rows): p^T and ds^T then ARE the left
+    # sides of the dv and dk products (no transpose of a score-sized array),
+    # the lse / delta rows broadcast along sublanes as they lie, and all
+    # four products stream the keys (PERF.md section 6, PR 46)
+    def logits(rs=rows, ks=keys):
+        return jax.lax.dot_general(k_ref[ks, :], q_ref[rs, :], (((1,), (1,)), ((), ())),
                                    preferred_element_type=jnp.float32) * (scale * _LOG2E)
 
-    def update(s):
-        p = jnp.exp2(s - lse_ref[0][:, None]).astype(do_ref.dtype)
-        dv_scr[:] = dv_scr[:] + jax.lax.dot_general(
-            p, do_ref[:], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do_ref[:], v_ref[:],
+    def update(s, rs=rows, ks=keys):
+        q, do = q_ref[rs, :], do_ref[rs, :]
+        p = jnp.exp2(s - lse_ref[0, rs][None, :]).astype(do.dtype)
+        dv_scr[ks, :] = dv_scr[ks, :] + jax.lax.dot_general(
+            p, do, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v_ref[ks, :], do,
                                  (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = (p.astype(jnp.float32) * (dp - delta_ref[0][:, None]) * scale).astype(q_ref.dtype)
-        dk_scr[:] = dk_scr[:] + jax.lax.dot_general(
-            ds, q_ref[:], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        ds = (p.astype(jnp.float32) * (dp - delta_ref[0, rs][None, :]) * scale).astype(q.dtype)
+        dk_scr[ks, :] = dk_scr[ks, :] + jax.lax.dot_general(
+            ds, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    _dispatch(run, i, j, plain, causal, update, logits, tri_ref,
-              lambda: _block_bias(qoff, koff, bq, bk, seq_len, causal,
-                                  slope_ref[0, 0], mask_ref[0].astype(jnp.float32),
-                                  stair))
+    def bias(rs=rows, ks=keys, edge=causal):
+        return _block_bias(qoff + rs.start, koff + ks.start, rs.stop - rs.start,
+                           ks.stop - ks.start, seq_len, edge, slope_ref[0, 0],
+                           mask_ref[0, ks].astype(jnp.float32), stair,
+                           keys_first=True)
+
+    if chunk:
+        _general_walk(run, i, j, bq, chunk, plain, update, logits, tri_ref, bias, True)
+    else:
+        _dispatch(run, i, j, plain, causal, update, logits, tri_ref, bias)
 
     @pl.when(jnp.logical_and(i == nq - 1, g == ng - 1))
     def _():
@@ -371,8 +503,18 @@ def _packed_fwd_kernel(q_ref, k_ref, v_ref, tri_ref, o_ref, lse_ref,
                                    -_MASKED)
 
 
+def _packed_walk(run, i, j, bq, chunk, step, logits, tri_ref, P, keys_first=False):
+    """:func:`_walk` for the packed kernels: every pair once a packed head."""
+    def pair(rs, ks, diag):
+        for p in range(P):
+            step(p, _walk_bias(logits(p, rs, ks), rs, ks, diag, None,
+                               lambda rs, ks: tri_ref[:], keys_first), rs, ks)
+
+    _walk(run, i, j, bq, chunk, pair)
+
+
 def _packed_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, tri_ref,
-                      dq_ref, dq_scr, *, scale, causal, bq, bk, P, Hd):
+                      dq_ref, dq_scr, *, scale, causal, bq, bk, P, Hd, chunk=0):
     j = pl.program_id(3)
     nk = pl.num_programs(3)
     i = pl.program_id(2)
@@ -382,22 +524,27 @@ def _packed_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, tri_ref,
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     run = True if not causal else (j * bk <= i * bq + bq - 1)
+    rows, keys = slice(0, bq), slice(0, bk)
 
-    def logits(p):
+    def logits(p, rs=rows, ks=keys):
         sl = slice(p * Hd, (p + 1) * Hd)
-        return jax.lax.dot_general(q_ref[:, sl], k_ref[:, sl], (((1,), (1,)), ((), ())),
+        return jax.lax.dot_general(q_ref[rs, sl], k_ref[ks, sl], (((1,), (1,)), ((), ())),
                                    preferred_element_type=jnp.float32) * (scale * _LOG2E)
 
-    def step(p, s):
+    def step(p, s, rs=rows, ks=keys):
         sl = slice(p * Hd, (p + 1) * Hd)
-        pmat = jnp.exp2(s - lse_ref[p][:, None])
-        dp = jax.lax.dot_general(do_ref[:, sl], v_ref[:, sl], (((1,), (1,)), ((), ())),
+        k = k_ref[ks, sl]
+        pmat = jnp.exp2(s - lse_ref[p, rs][:, None])
+        dp = jax.lax.dot_general(do_ref[rs, sl], v_ref[ks, sl], (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = (pmat * (dp - delta_ref[p][:, None]) * scale).astype(k_ref.dtype)
-        dq_scr[:, sl] = dq_scr[:, sl] + jax.lax.dot_general(
-            ds, k_ref[:, sl], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        ds = (pmat * (dp - delta_ref[p, rs][:, None]) * scale).astype(k.dtype)
+        dq_scr[rs, sl] = dq_scr[rs, sl] + jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    _packed_dispatch(run, i, j, causal, step, logits, tri_ref, P)
+    if chunk:
+        _packed_walk(run, i, j, bq, chunk, step, logits, tri_ref, P)
+    else:
+        _packed_dispatch(run, i, j, causal, step, logits, tri_ref, P)
 
     @pl.when(j == nk - 1)
     def _():
@@ -405,7 +552,8 @@ def _packed_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, tri_ref,
 
 
 def _packed_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, tri_ref,
-                       dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal, bq, bk, P, Hd):
+                       dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal, bq, bk, P, Hd,
+                       chunk=0):
     # grid (B, H2, nk, nq): q blocks innermost
     i = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -417,24 +565,30 @@ def _packed_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, tri_ref,
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     run = True if not causal else (j * bk <= i * bq + bq - 1)
+    rows, keys = slice(0, bq), slice(0, bk)
 
-    def logits(p):
+    # the scores transposed, (keys, rows), as ``_dkv_kernel``'s
+    def logits(p, rs=rows, ks=keys):
         sl = slice(p * Hd, (p + 1) * Hd)
-        return jax.lax.dot_general(q_ref[:, sl], k_ref[:, sl], (((1,), (1,)), ((), ())),
+        return jax.lax.dot_general(k_ref[ks, sl], q_ref[rs, sl], (((1,), (1,)), ((), ())),
                                    preferred_element_type=jnp.float32) * (scale * _LOG2E)
 
-    def step(p, s):
+    def step(p, s, rs=rows, ks=keys):
         sl = slice(p * Hd, (p + 1) * Hd)
-        pmat = jnp.exp2(s - lse_ref[p][:, None]).astype(do_ref.dtype)
-        dv_scr[:, sl] = dv_scr[:, sl] + jax.lax.dot_general(
-            pmat, do_ref[:, sl], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do_ref[:, sl], v_ref[:, sl], (((1,), (1,)), ((), ())),
+        q, do = q_ref[rs, sl], do_ref[rs, sl]
+        pmat = jnp.exp2(s - lse_ref[p, rs][None, :]).astype(do.dtype)
+        dv_scr[ks, sl] = dv_scr[ks, sl] + jax.lax.dot_general(
+            pmat, do, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(v_ref[ks, sl], do, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = (pmat.astype(jnp.float32) * (dp - delta_ref[p][:, None]) * scale).astype(q_ref.dtype)
-        dk_scr[:, sl] = dk_scr[:, sl] + jax.lax.dot_general(
-            ds, q_ref[:, sl], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        ds = (pmat.astype(jnp.float32) * (dp - delta_ref[p, rs][None, :]) * scale).astype(q.dtype)
+        dk_scr[ks, sl] = dk_scr[ks, sl] + jax.lax.dot_general(
+            ds, q, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
-    _packed_dispatch(run, i, j, causal, step, logits, tri_ref, P)
+    if chunk:
+        _packed_walk(run, i, j, bq, chunk, step, logits, tri_ref, P, True)
+    else:
+        _packed_dispatch(run, i, j, causal, step, logits, tri_ref, P)
 
     @pl.when(i == nq - 1)
     def _():
@@ -444,8 +598,10 @@ def _packed_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, tri_ref,
 
 @functools.lru_cache(maxsize=32)
 def _build_packed(causal: bool, scale: float, bq: int, bk: int, interpret: bool,
-                  P: int, Hd: int):
-    """Custom-VJP flash on [B, S, H*Hd] inputs, P heads per program."""
+                  P: int, Hd: int, chunk: int = 0):
+    """Custom-VJP flash on [B, S, H*Hd] inputs, P heads per program.
+    ``chunk`` (``_diag_chunk``) > 0: the backward kernels walk a diagonal
+    block in chunks of that many query rows."""
     lanes = P * Hd
 
     def xq_spec():
@@ -499,28 +655,39 @@ def _build_packed(causal: bool, scale: float, bq: int, bk: int, interpret: bool,
         # per-head delta rows: sum g*o over each head's lane group
         delta = (g.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
             B, Sp, H2, P, Hd).sum(-1).transpose(0, 2, 3, 1)  # [B, H2, P, Sp]
+        record("flash_bwd_diag", "chunks" if chunk else "whole", f"bq={bq} c={chunk}")
+        # a walk's bias is that of a pair ON the diagonal: the block's own
+        # top-left corner (a staircase's step divides the chunk)
+        bias = tri[:chunk, :chunk] if chunk else tri
+        key_of, query_of = _skipped_block_maps(causal, bq, bk)
+        dq_kv_spec = pl.BlockSpec((None, bk, lanes),
+                                  lambda b, h, i, j: (b, key_of(i, j), h))
 
         dq_kernel = functools.partial(_packed_dq_kernel, scale=scale, causal=causal,
-                                      bq=bq, bk=bk, P=P, Hd=Hd)
+                                      bq=bq, bk=bk, P=P, Hd=Hd, chunk=chunk)
         dq = pl.pallas_call(
             dq_kernel,
             name="flash_packed_dq",
             grid=(B, H2, nq, nk),
-            in_specs=[xq_spec(), xkv_spec(), xkv_spec(), xq_spec(),
-                      row_spec, row_spec, tri_spec],
+            in_specs=[xq_spec(), dq_kv_spec, dq_kv_spec, xq_spec(),
+                      row_spec, row_spec,
+                      pl.BlockSpec(bias.shape, lambda b, h, i, j: (0, 0))],
             out_specs=xq_spec(),
             out_shape=jax.ShapeDtypeStruct((B, Sp, D), q.dtype),
             scratch_shapes=[pltpu.VMEM((bq, lanes), jnp.float32)],
             interpret=interpret,
-        )(q, k, v, g, lse, delta, tri)
+        )(q, k, v, g, lse, delta, bias)
 
-        kq_spec = pl.BlockSpec((None, bq, lanes), lambda b, h, j, i: (b, i, h))
+        kq_spec = pl.BlockSpec((None, bq, lanes),
+                               lambda b, h, j, i: (b, query_of(i, j), h))
         kkv_spec = pl.BlockSpec((None, bk, lanes), lambda b, h, j, i: (b, j, h))
-        krow_spec = pl.BlockSpec((None, None, P, bq), lambda b, h, j, i: (b, h, 0, i))
-        ktri_spec = pl.BlockSpec((bq, bk), lambda b, h, j, i: (0, 0))
+        krow_spec = pl.BlockSpec((None, None, P, bq),
+                                 lambda b, h, j, i: (b, h, 0, query_of(i, j)))
+        # dk/dv take their scores (keys, rows): the bias transposed
+        ktri_spec = pl.BlockSpec(bias.T.shape, lambda b, h, j, i: (0, 0))
 
         dkv_kernel = functools.partial(_packed_dkv_kernel, scale=scale, causal=causal,
-                                       bq=bq, bk=bk, P=P, Hd=Hd)
+                                       bq=bq, bk=bk, P=P, Hd=Hd, chunk=chunk)
         dk, dv = pl.pallas_call(
             dkv_kernel,
             name="flash_packed_dkv",
@@ -537,7 +704,7 @@ def _build_packed(causal: bool, scale: float, bq: int, bk: int, interpret: bool,
                 pltpu.VMEM((bk, lanes), jnp.float32),
             ],
             interpret=interpret,
-        )(q, k, v, g, lse, delta, tri)
+        )(q, k, v, g, lse, delta, bias.T)
 
         return dq, dk, dv, jnp.zeros_like(tri)
 
@@ -597,7 +764,7 @@ def _layout_spec():
 @functools.lru_cache(maxsize=32)
 def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret: bool,
            has_layout: bool = False, plain: bool = False, kv_group: int = 1,
-           stair: int = 1, window: int = 0):
+           stair: int = 1, window: int = 0, chunk: int = 0):
     """Build the custom-VJP flash function for one static configuration.
 
     Operates on padded [B, H, Sp, Hd] q / [B, KV, Sp, Hd] k,v
@@ -608,6 +775,9 @@ def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret
     ``window`` > 0: the forward is the band ``i - window < j <= i`` (a plain
     one reads its lower edge's bias off the diagonal's: the caller sees to
     ``bq == bk`` and a band of whole blocks); its backward raises.
+    ``chunk`` (``_diag_chunk``) > 0: the backward kernels walk a diagonal
+    block in chunks of that many query rows, and a plain call hands them
+    the (chunk, chunk) bias of a chunk's own keys in tri's place.
     """
 
     G = kv_group
@@ -671,40 +841,59 @@ def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret
         delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)[:, :, None, :]
         if glse is not None:
             delta = delta - _LOG2E * glse.astype(jnp.float32)
+        record("flash_bwd_diag", "chunks" if chunk else "whole", f"bq={bq} c={chunk}")
+        bwd_statics = {**statics, "chunk": chunk}
+        operands = (q, k, v, g, lse, delta, mask, slopes)
+        dq_extra = dkv_extra = extra        # the layout, or nothing
+        if plain:
+            # extra is (tri,). A walk's bias is that of a pair ON the
+            # diagonal: the block's own top-left corner (a staircase's step
+            # divides the chunk). dk/dv take their scores (keys, rows): the
+            # bias transposed
+            bias = extra[0][:chunk, :chunk] if chunk else extra[0]
+            dq_extra, dkv_extra = (bias,), (bias.T,)
+        key_of, query_of = _skipped_block_maps(causal, bq, bk)
+        dq_kv_spec = pl.BlockSpec((None, None, bk, Hd),
+                                  lambda b, h, i, j: (b, h // G, key_of(i, j), 0))
+        dq_mask_spec = pl.BlockSpec((None, 1, bk),
+                                    lambda b, h, i, j: (b, 0, key_of(i, j)))
 
-        dq_kernel = functools.partial(_dq_kernel, **statics)
+        dq_kernel = functools.partial(_dq_kernel, **bwd_statics)
         dq = pl.pallas_call(
             dq_kernel,
             name="flash_dq",
             grid=(B, H, nq, nk),
-            in_specs=[_q_spec(bq, Hd), _kv_spec(bk, Hd, G), _kv_spec(bk, Hd, G),
+            in_specs=[_q_spec(bq, Hd), dq_kv_spec, dq_kv_spec,
                       _q_spec(bq, Hd), _row_spec(bq), _row_spec(bq),
-                      _mask_spec(bk), _slope_spec()] + maybe_tri + maybe_layout,
+                      dq_mask_spec, _slope_spec()]
+            + ([_tri_spec(*dq_extra[0].shape)] if plain else []) + maybe_layout,
             out_specs=_q_spec(bq, Hd),
             out_shape=jax.ShapeDtypeStruct((B, H, Sp, Hd), q.dtype),
             scratch_shapes=[pltpu.VMEM((bq, Hd), jnp.float32)],
             interpret=interpret,
-        )(q, k, v, g, lse, delta, mask, slopes, *extra)
+        )(*operands, *dq_extra)
 
         # grid (B, KV, nk, G, nq): q blocks innermost, then the group's query
         # heads — one dk/dv block accumulates across both in scratch
         KV = H // G
-        kq_spec = pl.BlockSpec((None, None, bq, Hd),
-                               lambda b, kv, j, gg, i: (b, kv * G + gg, i, 0))
+        kq_spec = pl.BlockSpec(
+            (None, None, bq, Hd),
+            lambda b, kv, j, gg, i: (b, kv * G + gg, query_of(i, j), 0))
         kk_spec = pl.BlockSpec((None, None, bk, Hd),
                                lambda b, kv, j, gg, i: (b, kv, j, 0))
-        krow_spec = pl.BlockSpec((None, None, 1, bq),
-                                 lambda b, kv, j, gg, i: (b, kv * G + gg, 0, i))
+        krow_spec = pl.BlockSpec(
+            (None, None, 1, bq),
+            lambda b, kv, j, gg, i: (b, kv * G + gg, 0, query_of(i, j)))
         kmask_spec = pl.BlockSpec((None, 1, bk), lambda b, kv, j, gg, i: (b, 0, j))
         kslope_spec = pl.BlockSpec((None, 8, 128),
                                    lambda b, kv, j, gg, i: (kv * G + gg, 0, 0))
-        kmaybe_tri = ([pl.BlockSpec((bq, bk), lambda b, kv, j, gg, i: (0, 0))]
+        kmaybe_tri = ([pl.BlockSpec(dkv_extra[0].shape, lambda b, kv, j, gg, i: (0, 0))]
                       if plain else [])
         kmaybe_layout = ([pl.BlockSpec((None, 8, 128),
                                        lambda b, kv, j, gg, i: (kv * G + gg, i, j))]
                          if has_layout else [])
 
-        dkv_kernel = functools.partial(_dkv_kernel, **statics)
+        dkv_kernel = functools.partial(_dkv_kernel, **bwd_statics)
         dk, dv = pl.pallas_call(
             dkv_kernel,
             name="flash_dkv",
@@ -721,7 +910,7 @@ def _build(causal: bool, scale: float, bq: int, bk: int, seq_len: int, interpret
                 pltpu.VMEM((bk, Hd), jnp.float32),
             ],
             interpret=interpret,
-        )(q, k, v, g, lse, delta, mask, slopes, *extra)
+        )(*operands, *dkv_extra)
 
         return (dq, dk, dv, jnp.zeros_like(mask), jnp.zeros_like(slopes),
                 *(jnp.zeros_like(l) for l in extra))
@@ -868,7 +1057,8 @@ def flash_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=N
     if (plain and kv_group == 1 and not return_lse and Hd < 128
             and 128 % Hd == 0 and H % (128 // Hd) == 0 and not window):
         P128 = 128 // Hd
-        fn = _build_packed(causal, scale, bq, bk, interpret, P128, Hd)
+        fn = _build_packed(causal, scale, bq, bk, interpret, P128, Hd,
+                           _diag_chunk(causal, bq, bk))
         tri = _make_tri(bq, bk, causal_block)
         out = fn(q.reshape(B, S, H * Hd), k.reshape(B, S, H * Hd),
                  v.reshape(B, S, H * Hd), tri)
@@ -906,7 +1096,8 @@ def flash_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=N
         extra = extra + (layout,)
 
     fn, fn_lse = _build(causal, scale, bq, bk, S, interpret, block_layout is not None,
-                        plain, kv_group, causal_block, window)
+                        plain, kv_group, causal_block, window,
+                        _diag_chunk(causal, bq, bk))
     if return_lse:
         out, lse = fn_lse(qt, kt, vt, mask, slopes, *extra)
         return (jnp.transpose(out[:, :, :S, :], (0, 2, 1, 3)),

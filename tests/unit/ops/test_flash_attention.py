@@ -4,13 +4,19 @@ Analogue of the reference's kernel-vs-torch comparisons in
 tests/unit/ops/transformer/.
 """
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.ops import dispatch
 from deepspeed_tpu.ops.attention import mha_attention
 from deepspeed_tpu.ops.pallas import flash_attention
+
+# the module (the package's attribute of that name is the function)
+flash_module = sys.modules["deepspeed_tpu.ops.pallas.flash_attention"]
 
 
 def _qkv(key, B=1, S=256, H=2, Hd=64):
@@ -114,6 +120,118 @@ class TestFlashBackward:
 
         gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
         gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gf, gr):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
+
+
+def _dense(q, k, v, keep, bias=None):
+    """Softmax attention in which query i sees key j where ``keep[..., i, j]``
+    ([S, S] or broadcastable to [B, H, S, S]), scores plus ``bias``: the
+    output [B, S, H, Hd] and the LOG2-domain logsumexp [B, H, S]."""
+    G = q.shape[2] // k.shape[2]
+    k, v = jnp.repeat(k, G, axis=2), jnp.repeat(v, G, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * q.shape[-1] ** -0.5
+    if bias is not None:
+        s = s + bias
+    s = jnp.where(keep, s, -1e30)
+    lse = jax.nn.logsumexp(s, axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", jnp.exp(s - lse[..., None]), v)
+    return out, lse * np.log2(np.e)
+
+
+def _walk_case(path, bq):
+    """What ``path`` adds to a causal call at two blocks of ``bq`` a side:
+    (heads, kv heads, S, the flash kwargs, the dense reference's keep and
+    bias)."""
+    S = 2 * bq - 56 if path == "padded" else 2 * bq
+    i, j = np.arange(S)[:, None], np.arange(S)[None, :]
+    keep, bias, kw, H, KV = i >= j, None, {}, 2, 2
+    if path == "gqa4":
+        H, KV = 4, 1
+    elif path == "alibi_mask":
+        seen = jax.random.uniform(jax.random.key(8), (1, S)) > 0.25
+        mask = jnp.where(seen.at[:, 0].set(True), 0.0, -1e9).astype(jnp.float32)
+        slopes = jnp.asarray([0.25, 0.125], jnp.float32)
+        kw = dict(mask_bias=mask, alibi_slopes=slopes)
+        bias = slopes[None, :, None, None] * (j - i) + mask[:, None, None, :]
+    elif path == "stair4":
+        keep, kw = i // 4 >= j // 4, dict(causal_block=4)
+    elif path == "layout":
+        # both diagonal blocks kept, the block below the diagonal dropped
+        keep = keep & (i // bq == j // bq)
+        kw = dict(block_layout=jnp.eye(2, dtype=jnp.float32))
+    elif path == "lse":
+        kw = dict(return_lse=True)
+    if "block_layout" not in kw:
+        kw.update(block_q=bq, block_k=bq)
+    return H, KV, S, kw, jnp.asarray(keep), bias
+
+
+class TestDiagonalWalk:
+    """The backward kernels walk a diagonal block in chunks (PR 46): the
+    gradients with the walk ENGAGED on every path, held to the dense
+    reference; the chunk is patched small so the interpreter stays fast."""
+
+    # chunks a block -> (block, chunk): a block spanning part of a sequence
+    # is a multiple of 128
+    BLOCKS = {2: (128, 64), 3: (384, 128), 4: (128, 32)}
+
+    @pytest.mark.parametrize("chunks", [2, 3, 4])
+    @pytest.mark.parametrize("path", ["packed", "gqa4", "alibi_mask", "padded",
+                                      "stair4", "layout", "lse"])
+    def test_grads_match_dense(self, monkeypatch, path, chunks):
+        bq, c = self.BLOCKS[chunks]
+        monkeypatch.setattr(flash_module, "_DIAG_CHUNK", c)
+        H, KV, S, kw, keep, bias = _walk_case(path, bq)
+        kq, kk, kv, kw_ = jax.random.split(jax.random.key(40 + chunks), 4)
+        q = jax.random.normal(kq, (1, S, H, 64), jnp.float32)
+        k = jax.random.normal(kk, (1, S, KV, 64), jnp.float32)
+        v = jax.random.normal(kv, (1, S, KV, 64), jnp.float32)
+        # a cotangent on lse too where the call returns one (ring attention)
+        w = jax.random.normal(kw_, (1, H, S), jnp.float32)
+
+        def loss(out, lse):
+            return jnp.sum(out ** 2) + jnp.sum(w * lse)
+
+        def loss_ref(q, k, v):
+            out, lse = _dense(q, k, v, keep, bias)
+            return loss(out, lse if path == "lse" else 0.0)
+
+        def loss_flash(q, k, v):
+            got = flash_attention(q, k, v, causal=True, interpret=True, **kw)
+            return loss(*got) if path == "lse" else loss(got, 0.0)
+
+        dispatch.reset()
+        gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+        assert dispatch.selected().get("flash_bwd_diag=chunks") == 1
+        assert "flash_bwd_diag=whole" not in dispatch.selected()
+        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+        for a, b, name in zip(gf, gr, "qkv"):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4,
+                                       err_msg=f"d{name} mismatch ({path}, {chunks} chunks)")
+
+    @pytest.mark.parametrize("why,causal,c", [("non_causal", False, 64),
+                                              ("one_chunk", True, 128),
+                                              ("ragged_chunk", True, 96)])
+    def test_takes_blocks_whole(self, monkeypatch, why, causal, c):
+        """No walk where it cannot be one: a non-causal call, a block of one
+        chunk, a block that is no whole number of chunks."""
+        monkeypatch.setattr(flash_module, "_DIAG_CHUNK", c)
+        q, k, v = _qkv(jax.random.key(50), S=256)
+
+        def loss_ref(q, k, v):
+            return jnp.sum(mha_attention(q, k, v, causal=causal) ** 2)
+
+        def loss_flash(q, k, v):
+            return jnp.sum(flash_attention(q, k, v, causal=causal, interpret=True,
+                                           block_q=128, block_k=128) ** 2)
+
+        dispatch.reset()
+        gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
+        assert dispatch.selected().get("flash_bwd_diag=whole") == 1
+        assert "flash_bwd_diag=chunks" not in dispatch.selected()
+        gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
         for a, b in zip(gf, gr):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-4, atol=1e-4)
 

@@ -679,7 +679,9 @@ def test_a_model_that_decodes_has_no_block_counter():
 
 
 # (7) an autoregressive model's programs trace to the same jaxprs as before
-#: ``tests/unit/paged_program_digests.py`` run on the commit before PR 33
+#: ``tests/unit/paged_program_digests.py`` run on the commit before PR 33;
+#: the three ``flash.*.grad`` values re-recorded by PR 46, whose backward
+#: kernels changed on purpose (the ``.fwd`` values are PR 33's still)
 BEFORE = {
     "opt.decode": "034019a30d25a849",
     "opt.prefill": "062951df660bebc5",
@@ -688,11 +690,11 @@ BEFORE = {
     "olmoe.prefill": "be28345862480dd1",
     "olmoe.prefill_chunk": "90687ed94108693c",
     "flash.gqa.fwd": "dc915c8588b95969",
-    "flash.gqa.grad": "1d9fe7884b4ad9b4",
+    "flash.gqa.grad": "0a4301cd137d40bd",
     "flash.packed.fwd": "5b203011d0a6297f",
-    "flash.packed.grad": "8b885550fc75346c",
+    "flash.packed.grad": "f70d37dc827f92e9",
     "flash.padded.fwd": "172e60c7c196456f",
-    "flash.padded.grad": "c9a0cc2942119895",
+    "flash.padded.grad": "6426b4067fb4feb8",
 }
 
 

@@ -317,6 +317,58 @@ def test_blocksparse_flash_compiles_and_matches(tpu):
     assert gerr < 0.05, gerr
 
 
+@tpu_tier
+@pytest.mark.parametrize("cell,B,H,alibi,kernels", [
+    ("bloom560m_train_1chip", 4, 16, True, ("flash_dq", "flash_dkv")),
+    ("opt1b3_train_zero3_4chip", 2, 32, False,
+     ("flash_packed_dq", "flash_packed_dkv")),
+])
+def test_flash_backward_at_the_train_cells_shapes(tpu, cell, B, H, alibi, kernels):
+    """``dq``, ``dk`` and ``dv`` COMPILED at a train cell's exact attention
+    shapes (a chip's micro-batch, heads of 64, 2,048 positions: 1,024 x 1,024
+    blocks, the diagonal ones walked in chunks since PR 46) against the
+    float32 reference's gradients. The cells' own check is a forward-only
+    eval loss and "the loss fell", blunt to a wrong gradient: this test is
+    what holds the compiled backward kernels."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.transformer import _alibi_slopes
+    from deepspeed_tpu.ops import dispatch
+    from deepspeed_tpu.ops.attention import mha_attention
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    S, Hd = 2048, 64
+    slopes = jnp.asarray(_alibi_slopes(H), jnp.float32) if alibi else None
+    q, k, v, g = (jax.random.normal(kk, (B, S, H, Hd), jnp.float32)
+                  .astype(jnp.bfloat16)
+                  for kk in jax.random.split(jax.random.key(46), 4))
+
+    def grads(attn):
+        def run(q, k, v, g):
+            o, vjp = jax.vjp(attn, q, k, v)
+            return vjp(g.astype(o.dtype))
+        return jax.jit(run)
+
+    dispatch.reset()
+    flash = grads(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, alibi_slopes=slopes, interpret=False))
+    text = flash.lower(q, k, v, g).as_text()
+    assert all(f'"{name}"' in text for name in kernels), cell
+    assert dispatch.selected().get("flash_bwd_diag=chunks") == 1, dispatch.selected()
+    got = flash(q, k, v, g)
+    with jax.default_matmul_precision("highest"):
+        want = grads(lambda q, k, v: mha_attention(
+            q, k, v, causal=True, alibi_slopes=slopes))(
+                *(x.astype(jnp.float32) for x in (q, k, v, g)))
+    for a, b, name in zip(got, want, ("dq", "dk", "dv")):
+        assert a.dtype == jnp.bfloat16
+        b = np.asarray(b)
+        err = float(np.abs(np.asarray(a, np.float32) - b).max() / np.abs(b).max())
+        # bf16 operands (p and ds are rounded to bf16 before their products)
+        assert err < 0.02, (cell, name, err)
+
+
 def _paged_reference(q, kp, vp, bt, pos, pad_bias=None, slopes=None):
     """fp32 einsum reference for paged decode attention: gather each
     request's logical cache through its block table, then masked softmax."""
