@@ -68,14 +68,14 @@ CONTROLS = ("sound", "lost_state", "conv_dropped", "no_dt_bias", "no_D",
 
 def _faults():
     """control -> (config override, parameter leaf to zero, patches of
-    ``models/transformer.py``'s functions): planted here, so that the
+    ``models/state_mixers.py``'s functions): planted here, so that the
     program has no option that selects them."""
     import jax
     import jax.numpy as jnp
 
-    from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models import state_mixers as SM
 
-    step, chunked, project = T.ssd_recurrent_step, T.ssd_chunked, T._mamba2_project
+    step, chunked, project = SM.ssd_recurrent_step, SM.ssd_chunked, SM._mamba2_project
 
     def lost_state(cfg, state, x, dt, A, Bm, Cm, D, slots, base):
         zero = jnp.zeros((*x.shape[1:], Bm.shape[-1]), jnp.float32)
@@ -112,21 +112,21 @@ def _faults():
         # through the plain-XLA form, whose step is the patched one
         "bf16_state": ({}, None, {
             "ssd_recurrent_step": step_bf16, "ssd_chunked": chunked_bf16,
-            "_ssd_state_update": lambda cfg, *a: T._ssd_decode_update(*a)}),
+            "_ssd_state_update": lambda cfg, *a: SM._ssd_decode_update(*a)}),
     }
 
 
 @contextlib.contextmanager
 def planted(patches):
-    from deepspeed_tpu.models import transformer as T
-    kept = {name: getattr(T, name) for name in patches}
+    from deepspeed_tpu.models import state_mixers as SM
+    kept = {name: getattr(SM, name) for name in patches}
     try:
         for name, fn in patches.items():
-            setattr(T, name, fn)
+            setattr(SM, name, fn)
         yield
     finally:
         for name, fn in kept.items():
-            setattr(T, name, fn)
+            setattr(SM, name, fn)
 
 
 def without(params, leaf):

@@ -7,7 +7,7 @@ the ``solaropen2_serve_decode`` cell's widths: 128 rows, 64 heads, a float32
 128 x 128 state a head, 129 slots. ``kernel`` is
 ``ops/pallas/kda_decode_update.py`` (each live row's state read once and
 written once, addressed row -> slot), ``twin`` the plain-XLA form the program
-takes off a TPU (``models/transformer.py`` ``_kda_slot_update``: all 129
+takes off a TPU (``models/state_mixers.py`` ``_kda_slot_update``: all 129
 slots in slot order, read twice and written once). Each is timed at 1, 16,
 64 and 128 live rows of 128, the others idle on the dummy slot: the only
 place the low-occupancy saving is measured, since no cell serves a stateful
@@ -75,7 +75,7 @@ def main():
         sys.exit(f"kda_decode_bench: the default device is {platform!r}, "
                  "not a TPU: no time is taken")
     here = importlib.import_module("deepspeed_tpu.ops.pallas.kda_decode_update")
-    from deepspeed_tpu.models.transformer import _kda_slot_update
+    from deepspeed_tpu.models.state_mixers import _kda_slot_update
     from deepspeed_tpu.ops.pallas import state_phases
     phase_rows = state_phases.phase_rows
     variants = [("twin", None), ("kernel", None)]

@@ -8,7 +8,7 @@ One call is a decode step's state update of ONE Mamba-2 layer at the
 float32 state (2 MB a row), 65 slots. ``kernel`` is
 ``ops/pallas/mamba2_decode_update.py`` (each live row's state read once and
 written once where it lies, in phases of one direction at a time), ``twin``
-the plain-XLA form the program takes off a TPU (``models/transformer.py``
+the plain-XLA form the program takes off a TPU (``models/state_mixers.py``
 ``_ssd_decode_update``: the rows' states gathered, updated and scattered
 back). Each is timed at 1, 16 and 64 live rows of 64, the others idle on the
 dummy slot: the only place the low-occupancy cost is measured, since no cell
@@ -96,7 +96,7 @@ def main():
     if platform != "tpu":
         sys.exit(f"mamba2_decode_bench: the default device is {platform!r}, "
                  "not a TPU: no time is taken")
-    from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.models import state_mixers as SM
     from deepspeed_tpu.ops.pallas import state_phases
     from deepspeed_tpu.ops.pallas.mamba2_decode_update import \
         mamba2_decode_update
@@ -118,7 +118,7 @@ def main():
             def one(state, v):
                 step = (v["x"], v["dt"], A, v["Bm"], v["Cm"])
                 if fn is None:
-                    y, state = T._ssd_decode_update(state, *step, zero, slots, 0)
+                    y, state = SM._ssd_decode_update(state, *step, zero, slots, 0)
                 else:
                     y, state = fn(state, *step, slots, 0)
                 return state, y
@@ -130,7 +130,7 @@ def main():
                               (SLOTS, N, HEADS * P), jnp.float32)
     pools = {False: pool0}
     if any(hpn for *_, hpn in variants.values()):
-        pools[True] = T._ssd_from_pool(pool0, HEADS)
+        pools[True] = SM._ssd_from_pool(pool0, HEADS)
     runs = {}
     for live in args.live:
         vecs, A, slots = draw(args.seed + live, live)
@@ -143,7 +143,7 @@ def main():
             y, pool = jax.block_until_ready(run(pools[hpn] + 0.0, vecs, A, slots))
             on = np.asarray(slots) != 0
             if hpn:
-                pool = T._ssd_to_pool(pool)
+                pool = SM._ssd_to_pool(pool)
             got = (np.asarray(y)[:, on], np.asarray(pool))
             want = want or got
             err = max(float(np.abs(a - b).max() / np.abs(b).max())

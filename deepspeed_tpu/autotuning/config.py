@@ -15,8 +15,6 @@ from pydantic import Field
 
 from deepspeed_tpu.config.config_utils import ConfigModel
 
-AUTOTUNING = "autotuning"
-
 METRIC_THROUGHPUT = "throughput"
 METRIC_LATENCY = "latency"
 

@@ -22,10 +22,6 @@ LAYER_REDUCTION = "layer_reduction"
 SHARED_PARAMETERS = "shared_parameters"
 DIFFERENT_GROUPS = "different_groups"
 
-TECHNIQUE_ENABLED = "enabled"
-TECHNIQUE_SCHEDULE_OFFSET = "schedule_offset"
-TECHNIQUE_SCHEDULE_OFFSET_END = "schedule_offset_end"
-
 _SHARED_DEFAULTS = {
     WEIGHT_QUANTIZATION: {
         "enabled": False,

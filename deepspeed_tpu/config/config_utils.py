@@ -95,14 +95,6 @@ def get_scalar_param(param_dict: Dict, param_name: str, param_default_value: Any
     return param_dict.get(param_name, param_default_value)
 
 
-def get_list_param(param_dict: Dict, param_name: str, param_default_value: Any) -> Any:
-    return param_dict.get(param_name, param_default_value)
-
-
-def get_dict_param(param_dict: Dict, param_name: str, param_default_value: Any) -> Any:
-    return param_dict.get(param_name, param_default_value)
-
-
 def dict_raise_error_on_duplicate_keys(ordered_pairs):
     """Reject duplicate keys when parsing a JSON config (reference behavior)."""
     d = dict((k, v) for k, v in ordered_pairs)

@@ -1,14 +1,6 @@
 """Config key names and defaults (reference: deepspeed/runtime/constants.py)."""
 
 #############################################
-# Routes
-#############################################
-ROUTE_TRAIN = "train"
-ROUTE_EVAL = "eval"
-ROUTE_PREDICT = "predict"
-ROUTE_ENCODE = "encode"
-
-#############################################
 # Batch size
 #############################################
 TRAIN_BATCH_SIZE = "train_batch_size"
@@ -36,8 +28,6 @@ MAX_GRAD_NORM = "max_grad_norm"
 
 ZERO_ALLOW_UNTESTED_OPTIMIZER = "zero_allow_untested_optimizer"
 ZERO_ALLOW_UNTESTED_OPTIMIZER_DEFAULT = False
-ZERO_FORCE_DS_CPU_OPTIMIZER = "zero_force_ds_cpu_optimizer"
-ZERO_FORCE_DS_CPU_OPTIMIZER_DEFAULT = True
 
 #############################################
 # Steps
@@ -62,30 +52,10 @@ SPARSE_GRADIENTS_DEFAULT = False
 #############################################
 BFLOAT16 = "bf16"
 BFLOAT16_OLD = "bfloat16"  # keeping for backwards compatibility
-BFLOAT16_ENABLED = "enabled"
-BFLOAT16_ENABLED_DEFAULT = False
 
 FP16 = "fp16"
-FP16_ENABLED = "enabled"
-FP16_ENABLED_DEFAULT = False
-FP16_AUTO_CAST = "auto_cast"
-FP16_AUTO_CAST_DEFAULT = False
-FP16_LOSS_SCALE = "loss_scale"
-FP16_LOSS_SCALE_DEFAULT = 0
-FP16_INITIAL_SCALE_POWER = "initial_scale_power"
-FP16_INITIAL_SCALE_POWER_DEFAULT = 16
-FP16_LOSS_SCALE_WINDOW = "loss_scale_window"
-FP16_LOSS_SCALE_WINDOW_DEFAULT = 1000
-FP16_HYSTERESIS = "hysteresis"
-FP16_HYSTERESIS_DEFAULT = 2
-FP16_MIN_LOSS_SCALE = "min_loss_scale"
-FP16_MIN_LOSS_SCALE_DEFAULT = 1
-FP16_MASTER_WEIGHTS_AND_GRADS = "fp16_master_weights_and_grads"
-FP16_MASTER_WEIGHTS_AND_GRADS_DEFAULT = False
 
 AMP = "amp"
-AMP_ENABLED = "enabled"
-AMP_ENABLED_DEFAULT = False
 
 #############################################
 # Gradient clipping
@@ -103,13 +73,6 @@ COMMUNICATION_DATA_TYPE_DEFAULT = None
 # Sparse attention, checkpointing, misc
 #############################################
 SPARSE_ATTENTION = "sparse_attention"
-SPARSE_DENSE_MODE = "dense"
-SPARSE_FIXED_MODE = "fixed"
-SPARSE_VARIABLE_MODE = "variable"
-SPARSE_BIGBIRD_MODE = "bigbird"
-SPARSE_BSLONGFORMER_MODE = "bslongformer"
-SPARSE_MODE = "mode"
-SPARSE_MODE_DEFAULT = SPARSE_FIXED_MODE
 
 WALL_CLOCK_BREAKDOWN = "wall_clock_breakdown"
 WALL_CLOCK_BREAKDOWN_DEFAULT = False
@@ -161,10 +124,6 @@ DATALOADER_DROP_LAST_DEFAULT = False
 PROGRESSIVE_LAYER_DROP = "progressive_layer_drop"
 PLD_ENABLED = "enabled"
 PLD_ENABLED_DEFAULT = False
-PLD_THETA = "theta"
-PLD_THETA_DEFAULT = 1.0
-PLD_GAMMA = "gamma"
-PLD_GAMMA_DEFAULT = 0.001
 
 #############################################
 # Curriculum learning (legacy path)
@@ -190,7 +149,6 @@ MAX_GPUS_DEFAULT = 10000
 MIN_TIME = "min_time"
 MIN_TIME_DEFAULT = 0
 VERSION = "version"
-LATEST_ELASTICITY_VERSION = 0.2
 ELASTICITY_DEFAULT_VERSION = 0.2
 PREFER_LARGER_BATCH = "prefer_larger_batch"
 PREFER_LARGER_BATCH_DEFAULT = True

@@ -97,9 +97,6 @@ class CausalLM:
     def num_parameters(self) -> int:
         cfg = self.config
         embed = cfg.vocab_size * cfg.d_model + (cfg.max_seq * cfg.d_model if cfg.pos_embedding == "learned" else 0)
-        attn = cfg.d_model * cfg.head_dim * (cfg.n_head + 2 * cfg.kv_heads) + cfg.n_head * cfg.head_dim * cfg.d_model
-        if cfg.attn_bias:
-            attn += cfg.head_dim * (cfg.n_head + 2 * cfg.kv_heads) + cfg.d_model
         if cfg.activation == "swiglu":
             mlp = 3 * cfg.d_model * cfg.ff_dim
         else:
@@ -109,16 +106,8 @@ class CausalLM:
         if cfg.embed_layernorm:
             final_norm += (2 if cfg.norm == "layernorm" else 1) * cfg.d_model
         head = 0 if cfg.tie_embeddings else cfg.d_model * cfg.vocab_size
-        # a Mamba-2 layer's mixer in an attention layer's place: the input
-        # and output projections, the conv with its bias, A_log, dt_bias, D
-        # and the gated norm's scale
-        inner = cfg.ssm_heads * cfg.ssm_head_dim
-        conv_dim = inner + 2 * cfg.ssm_state
-        ssm = cfg.d_model * (2 * inner + conv_dim + cfg.ssm_heads) \
-            + conv_dim * (cfg.ssm_conv_kernel + 1) + 3 * cfg.ssm_heads + inner
-        n_ssm = cfg.layers_of(T.MAMBA2)
-        return embed + cfg.n_layer * (mlp + norms) + n_ssm * ssm \
-            + (cfg.n_layer - n_ssm) * attn + final_norm + head
+        return embed + cfg.n_layer * (mlp + norms) + T.mixer_params(cfg) \
+            + final_norm + head
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:
         """Approximate training FLOPs/token (6N + attention term)."""

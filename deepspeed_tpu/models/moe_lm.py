@@ -650,13 +650,13 @@ class MoECausalLM:
         D, E = cfg.d_model, moe.num_experts
         F = self.expert_ff
         embed = cfg.vocab_size * D + (cfg.max_seq * D if cfg.pos_embedding == "learned" else 0)
-        attn = D * cfg.head_dim * (cfg.n_head + 2 * cfg.kv_heads) + cfg.n_head * cfg.head_dim * D
         moe_mlp = D * self.router_width \
             + E * (3 * D * F if self._gated else 2 * D * F + F + D)
-        if cfg.qk_norm:
-            attn += cfg.head_dim * (2 if cfg.qk_norm == "head"
-                                    else cfg.n_head + cfg.kv_heads)
+        if moe.scoring == "sigmoid":
+            moe_mlp += self.router_width                   # b_select
+        moe_mlp += 3 * D * moe.shared_expert_d_ff
         norms = (4 if cfg.norm == "layernorm" else 2) * D
         final_norm = (2 if cfg.norm == "layernorm" else 1) * D
         head = 0 if cfg.tie_embeddings else D * cfg.vocab_size
-        return embed + cfg.n_layer * (attn + moe_mlp + norms) + final_norm + head
+        return embed + cfg.n_layer * (moe_mlp + norms) + T.mixer_params(cfg) \
+            + final_norm + head

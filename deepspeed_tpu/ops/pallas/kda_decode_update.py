@@ -9,7 +9,7 @@ KV pools. A decode step is, for every live row and head,
     u  = beta (v - r)               o = p + (qh . kh) u
     S  = S1 + kh u^T
 
-(``kda_recurrent_step`` of ``models/transformer.py``, which stays what the
+(``kda_recurrent_step`` of ``models/state_mixers.py``, which stays what the
 tests compare against). The arithmetic is ~7 operations an element; the
 step is the state's HBM traffic, so the kernel moves each live row's state
 ONCE each way:

@@ -7,7 +7,7 @@ and head,
 
     S = exp(dt A) S + (dt x) B^T        y = S C
 
-(``ssd_recurrent_step`` of ``models/transformer.py`` without its ``D x``,
+(``ssd_recurrent_step`` of ``models/state_mixers.py`` without its ``D x``,
 which the caller adds; that function stays what the tests compare against).
 The arithmetic is ~5 operations an element; the step is the state's HBM
 traffic, so the kernel moves each live row's state ONCE each way, and lays

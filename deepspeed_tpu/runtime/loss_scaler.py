@@ -173,12 +173,6 @@ class DynamicLossScaler(LossScalerBase):
         self.cur_iter += 1
 
 
-INITIAL_LOSS_SCALE = "init_scale"
-SCALE_WINDOW = "scale_window"
-DELAYED_SHIFT = "delayed_shift"
-MIN_LOSS_SCALE = "min_scale"
-
-
 def CreateLossScaler(dtype, static_loss_scale, dynamic_scaling, dynamic_loss_args):
     """Factory mirroring the reference's loss_scaler.CreateLossScaler."""
     import jax.numpy as jnp_

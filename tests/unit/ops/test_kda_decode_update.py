@@ -9,6 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deepspeed_tpu.models import state_mixers as SM
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.ops import dispatch
 from deepspeed_tpu.ops.pallas import state_phases
@@ -70,9 +71,9 @@ def test_kernel_against_the_recurrence(case, monkeypatch):
         cfg = T.TransformerConfig(vocab_size=8, n_layer=1, n_head=1, d_model=8,
                                   attention_backend="flash")
         step = (qh, kh, v, g, beta, jnp.asarray(slots), base)
-        o, new = T._kda_state_update(cfg, pool, *step, SLOTS)
+        o, new = SM._kda_state_update(cfg, pool, *step, SLOTS)
         assert dispatch.selected() == {"kda_decode=slot_update": 1}
-        wo, wnew = T._kda_slot_update(pool, *step, SLOTS)
+        wo, wnew = SM._kda_slot_update(pool, *step, SLOTS)
         np.testing.assert_array_equal(np.asarray(o), np.asarray(wo))
         np.testing.assert_array_equal(np.asarray(new), np.asarray(wnew))
         return
@@ -80,7 +81,7 @@ def test_kernel_against_the_recurrence(case, monkeypatch):
     o, new = (np.asarray(a) for a in out)
     live = slots != 0
     rows = base + slots
-    want_o, want_S = T.kda_recurrent_step(pool[rows], qh, kh, v, g, beta)
+    want_o, want_S = SM.kda_recurrent_step(pool[rows], qh, kh, v, g, beta)
     want_o, want_S = np.asarray(want_o), np.asarray(want_S)
     assert np.isfinite(o).all() and np.isfinite(new).all()
     if live.any():
@@ -93,7 +94,7 @@ def test_kernel_against_the_recurrence(case, monkeypatch):
     untouched[rows[live]] = False
     np.testing.assert_array_equal(new[untouched], np.asarray(pool)[untouched])
     # and the form the model takes off a TPU agrees on what both define
-    to, tnew = T._kda_slot_update(pool, qh, kh, v, g, beta, jnp.asarray(slots),
+    to, tnew = SM._kda_slot_update(pool, qh, kh, v, g, beta, jnp.asarray(slots),
                                   base, SLOTS)
     if live.any():
         np.testing.assert_allclose(np.asarray(to)[live], o[live],

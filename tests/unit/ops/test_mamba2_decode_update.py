@@ -6,7 +6,7 @@ row), an inactive row's ``y`` zero. One parametrised test, a case each state
 the serving loop puts it in and each place the live rows can end in the copy
 schedule's phases (``state_phases.py``); and the model's dispatch between
 the kernel and its plain-XLA twin. The pool is kept ``[rows, N, H * P]``
-(``T._ssd_to_pool``); the recurrence takes ``[H, P, N]``."""
+(``SM._ssd_to_pool``); the recurrence takes ``[H, P, N]``."""
 
 import jax
 import jax.numpy as jnp
@@ -14,6 +14,7 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu.comm as dist
+from deepspeed_tpu.models import state_mixers as SM
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.ops import dispatch
 from deepspeed_tpu.ops.pallas import state_phases
@@ -86,9 +87,9 @@ def test_kernel_against_the_recurrence(case, monkeypatch):
         # outside the envelope: None, nothing selected, and the model's step
         # takes (and records) its plain-XLA form
         assert out is None and not dispatch.selected()
-        y, new = T._ssd_state_update(cfg, pool, *step)
+        y, new = SM._ssd_state_update(cfg, pool, *step)
         assert dispatch.selected() == {"ssd_decode=slot_gather": 1}
-        wy, wnew = T._ssd_decode_update(pool, *step)
+        wy, wnew = SM._ssd_decode_update(pool, *step)
         np.testing.assert_array_equal(np.asarray(y), np.asarray(wy))
         np.testing.assert_array_equal(np.asarray(new), np.asarray(wnew))
         return
@@ -98,9 +99,9 @@ def test_kernel_against_the_recurrence(case, monkeypatch):
     rows = base + slots
     zero = jnp.zeros_like(D)                 # the kernel's y is S C alone
     want_y, want_S = jax.vmap(
-        lambda S, xb, dtb, bb, cb: T.ssd_recurrent_step(S, xb, dtb, A, bb, cb, zero)
-    )(T._ssd_from_pool(pool[rows], H), x, dt, Bm, Cm)
-    want_y, want_S = np.asarray(want_y), np.asarray(T._ssd_to_pool(want_S))
+        lambda S, xb, dtb, bb, cb: SM.ssd_recurrent_step(S, xb, dtb, A, bb, cb, zero)
+    )(SM._ssd_from_pool(pool[rows], H), x, dt, Bm, Cm)
+    want_y, want_S = np.asarray(want_y), np.asarray(SM._ssd_to_pool(want_S))
     assert np.isfinite(y).all() and np.isfinite(new).all()
     if live.any():
         assert np.abs(y[live] - want_y[live]).max() \
@@ -113,9 +114,9 @@ def test_kernel_against_the_recurrence(case, monkeypatch):
     np.testing.assert_array_equal(new[untouched], np.asarray(pool)[untouched])
     # the model's step takes the kernel, adds D x, and agrees with its twin
     dispatch.reset()
-    my, mnew = T._ssd_state_update(cfg, pool + 0.0, *step)
+    my, mnew = SM._ssd_state_update(cfg, pool + 0.0, *step)
     assert dispatch.selected()["ssd_decode=mamba2_kernel"] == 1
-    ty, tnew = T._ssd_decode_update(pool, *step)
+    ty, tnew = SM._ssd_decode_update(pool, *step)
     scale = max(float(np.abs(ty).max()), 1.0)
     np.testing.assert_allclose(np.asarray(my), np.asarray(ty), rtol=0,
                                atol=REL * scale)

@@ -34,7 +34,20 @@ def _models():
     opt = CausalLM(TransformerConfig(
         vocab_size=512, max_seq=256, n_layer=2, n_head=4, d_model=64,
         d_ff=128, activation="relu", attn_bias=True))
-    return {"opt": opt, "olmoe": get_model("olmoe", "tiny", max_seq=256)}
+    return {"opt": opt, "olmoe": get_model("olmoe", "tiny", max_seq=256),
+            # the two presets with recurrent layers (KDA over an MoE; Mamba-2):
+            # recorded on the commit before PR 47 moved their mixers
+            "solar_open2": get_model("solar_open2", "tiny", max_seq=256),
+            "granite_hybrid": get_model("granite_hybrid", "tiny", max_seq=256),
+            # and their programs as the chip takes them: the state-update
+            # kernels (interpreted here) in the plain-XLA twins' place (the
+            # KDA kernel wants a value width of whole lane tiles)
+            "solar_open2.kernels": get_model(
+                "solar_open2", "tiny", max_seq=256, lin_head_dim=128,
+                attention_backend="flash"),
+            "granite_hybrid.kernels": get_model(
+                "granite_hybrid", "tiny", max_seq=256,
+                attention_backend="flash")}
 
 
 def program_digests() -> dict:
@@ -42,19 +55,25 @@ def program_digests() -> dict:
     out = {}
     for name, model in _models().items():
         params = jax.eval_shape(model.init_params, jax.random.key(0))
-        pools = jax.eval_shape(
-            lambda: model.init_paged_cache(8, BS, dtype=jnp.float32))
+        stateful = bool(model.config.cache_spec["state"])
+        pools = jax.eval_shape(lambda: model.init_paged_cache(
+            8, BS, dtype=jnp.float32, state_slots=4 if stateful else 0))
         sds = jax.ShapeDtypeStruct
+        # a stack with recurrent state takes its rows' slots / its request's
+        # slot as the programs' last operand, and prompts in whole KDA chunks
+        rows = (None, sds((3,), i32)) if stateful else ()
+        one = (sds((), i32),) if stateful else ()
+        T = 64 if stateful else 32               # the prefill bucket
         out[f"{name}.decode"] = _digest(
             model.forward_paged_decode, params, sds((3, 1), i32), pools,
-            sds((3, 16), i32), sds((3,), i32))
+            sds((3, 16), i32), sds((3,), i32), *rows)
         out[f"{name}.prefill"] = _digest(
-            model.forward_paged_prefill, params, sds((1, 32), i32), pools,
-            sds((32,), i32), sds((), i32))
+            model.forward_paged_prefill, params, sds((1, T), i32), pools,
+            sds((T,), i32), sds((), i32), *one)
         out[f"{name}.prefill_chunk"] = _digest(
-            model.forward_paged_prefill_chunk, params, sds((1, 32), i32),
-            pools, sds((1, 16), i32), sds((32,), i32), sds((), i32),
-            sds((), i32))
+            model.forward_paged_prefill_chunk, params, sds((1, T), i32),
+            pools, sds((1, 16), i32), sds((T,), i32), sds((), i32),
+            sds((), i32), *one)
     from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
     def flash(q, k, v):

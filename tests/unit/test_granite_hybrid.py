@@ -34,6 +34,7 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu.comm as dist
+from deepspeed_tpu.models import state_mixers as SM
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.presets import get_model
 
@@ -225,7 +226,7 @@ def _ssd_inputs(seed, n, H=4, Pd=32, N=16):
 def _sequential(S, x, dt, A, Bm, Cm, D):
     ys = []
     for t in range(x.shape[0]):
-        y, S = T.ssd_recurrent_step(S, x[t], dt[t], A, Bm[t], Cm[t], D)
+        y, S = SM.ssd_recurrent_step(S, x[t], dt[t], A, Bm[t], Cm[t], D)
         ys.append(y)
     return jnp.stack(ys), S
 
@@ -241,10 +242,10 @@ def test_the_chunked_form_is_the_sequential_recurrence(n, from_zero):
         jnp.asarray(np.random.default_rng(99).standard_normal((4, 32, 16)),
                     jnp.float32)
     # the chunked form takes and leaves the state as the pool keeps it
-    y, S = T.ssd_chunked(T._ssd_to_pool(S0), x, dt, A, Bm, Cm, D, chunk=8)
+    y, S = SM.ssd_chunked(SM._ssd_to_pool(S0), x, dt, A, Bm, Cm, D, chunk=8)
     want_y, want_S = _sequential(S0, x, dt, A, Bm, Cm, D)
     np.testing.assert_allclose(y, want_y, rtol=0, atol=2e-5)
-    np.testing.assert_allclose(T._ssd_from_pool(S, 4), want_S, rtol=0, atol=2e-5)
+    np.testing.assert_allclose(SM._ssd_from_pool(S, 4), want_S, rtol=0, atol=2e-5)
 
 
 def test_a_buckets_padding_touches_neither_state_nor_conv(toy):
@@ -259,7 +260,7 @@ def test_a_buckets_padding_touches_neither_state_nor_conv(toy):
     state = jnp.asarray(rng.standard_normal((3,) + sh), jnp.float32)
     conv = jnp.asarray(rng.standard_normal((3,) + ch), jnp.float32)
     x = jnp.asarray(rng.standard_normal((1, 32, cfg.d_model)), jnp.float32)
-    run = jax.jit(lambda xb: T._mamba2_prefill(
+    run = jax.jit(lambda xb: SM._mamba2_prefill(
         cfg, xb, lp, state, conv, jnp.int32(1), jnp.int32(13), False))
     outs = [run(x[:, :Tb]) for Tb in (16, 32)]
     for a, b in zip(outs[0][1:], outs[1][1:]):
@@ -278,18 +279,18 @@ def test_the_decode_update_moves_the_live_rows_alone():
     row's output is zero."""
     x, dt, A, Bm, Cm, D = _ssd_inputs(3, 5)
     pool = jnp.asarray(np.random.default_rng(4).standard_normal((12, 16, 4 * 32)),
-                       jnp.float32)     # kept [N, H * P]: T._ssd_to_pool
+                       jnp.float32)     # kept [N, H * P]: SM._ssd_to_pool
     slots = jnp.asarray([3, 0, 1, 0, 4], jnp.int32)
-    y, new = jax.jit(T._ssd_decode_update)(pool, x, dt, A, Bm, Cm, D, slots,
+    y, new = jax.jit(SM._ssd_decode_update)(pool, x, dt, A, Bm, Cm, D, slots,
                                            jnp.int32(6))
     for b, s in enumerate(np.asarray(slots)):
         if s == 0:
             assert float(jnp.abs(y[b]).max()) == 0.0
             continue
-        want_y, want_S = T.ssd_recurrent_step(
-            T._ssd_from_pool(pool[6 + s], 4), x[b], dt[b], A, Bm[b], Cm[b], D)
+        want_y, want_S = SM.ssd_recurrent_step(
+            SM._ssd_from_pool(pool[6 + s], 4), x[b], dt[b], A, Bm[b], Cm[b], D)
         np.testing.assert_allclose(y[b], want_y, rtol=1e-6, atol=1e-6)
-        np.testing.assert_allclose(new[6 + s], T._ssd_to_pool(want_S),
+        np.testing.assert_allclose(new[6 + s], SM._ssd_to_pool(want_S),
                                    rtol=1e-6, atol=1e-6)
     untouched = np.array([r for r in range(12) if r not in (7, 9, 10)])
     np.testing.assert_array_equal(new[untouched], pool[untouched])
@@ -357,13 +358,13 @@ def test_a_bf16_state_fails_the_tolerance(toy, monkeypatch):
     to bf16 wherever a step writes it (``reduce_precision``: a pair of casts
     XLA may elide) moves a decode step's logits past the tolerance."""
     model, params = toy[:2]
-    step = T.ssd_recurrent_step
+    step = SM.ssd_recurrent_step
 
     def rounding(S, *a):
         y, S = step(S, *a)
         return y, jax.lax.reduce_precision(S, exponent_bits=8, mantissa_bits=7)
 
-    monkeypatch.setattr(T, "ssd_recurrent_step", rounding)
+    monkeypatch.setattr(SM, "ssd_recurrent_step", rounding)
     faulted = type(model)(model.config)          # a jit cache of its own
     toks = tokens_of(1, 37 + 24)
     want = reference_logits(toy, toks)

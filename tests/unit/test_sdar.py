@@ -681,7 +681,11 @@ def test_a_model_that_decodes_has_no_block_counter():
 # (7) an autoregressive model's programs trace to the same jaxprs as before
 #: ``tests/unit/paged_program_digests.py`` run on the commit before PR 33;
 #: the three ``flash.*.grad`` values re-recorded by PR 46, whose backward
-#: kernels changed on purpose (the ``.fwd`` values are PR 33's still)
+#: kernels changed on purpose (the ``.fwd`` values are PR 33's still); the
+#: recurrent presets' (``solar_open2.*``: KDA layers over an MoE,
+#: ``granite_hybrid.*``: Mamba-2 layers; ``.kernels``: with the Pallas state
+#: updates, interpreted) taken on the commit before PR 47 moved the mixers
+#: to ``models/state_mixers.py``
 BEFORE = {
     "opt.decode": "034019a30d25a849",
     "opt.prefill": "062951df660bebc5",
@@ -689,6 +693,18 @@ BEFORE = {
     "olmoe.decode": "91c915b574c1bd7c",
     "olmoe.prefill": "be28345862480dd1",
     "olmoe.prefill_chunk": "90687ed94108693c",
+    "solar_open2.decode": "ab9a13322a925b10",
+    "solar_open2.prefill": "503c1ebdb7a448a5",
+    "solar_open2.prefill_chunk": "164020a480fc542d",
+    "granite_hybrid.decode": "6e44be6a462cdea1",
+    "granite_hybrid.prefill": "7efaaea8b78082ba",
+    "granite_hybrid.prefill_chunk": "8db856d16970456b",
+    "solar_open2.kernels.decode": "1911d63ab3ea8716",
+    "solar_open2.kernels.prefill": "62df7c2dfc61f0db",
+    "solar_open2.kernels.prefill_chunk": "a4a8a8880938a383",
+    "granite_hybrid.kernels.decode": "79c3f8f22b049903",
+    "granite_hybrid.kernels.prefill": "36802ff009ef7c0a",
+    "granite_hybrid.kernels.prefill_chunk": "8db856d16970456b",
     "flash.gqa.fwd": "dc915c8588b95969",
     "flash.gqa.grad": "0a4301cd137d40bd",
     "flash.packed.fwd": "5b203011d0a6297f",

@@ -28,6 +28,7 @@ import numpy as np
 import pytest
 
 import deepspeed_tpu.comm as dist
+from deepspeed_tpu.models import state_mixers as SM
 from deepspeed_tpu.models import transformer as T
 from deepspeed_tpu.models.moe_lm import MoECausalLM, MoEConfig
 from deepspeed_tpu.models.presets import get_model
@@ -159,10 +160,10 @@ def test_chunked_form_against_the_recurrence(length, decay):
         else np.full((length, H, dk), -10.0)
     S0 = rng.standard_normal((H, dk, dk))
     f = lambda a: jnp.asarray(a, jnp.float32)  # noqa: E731
-    o, S = jax.jit(T.kda_chunked)(f(S0), f(q), f(k), f(v), f(g), f(beta))
+    o, S = jax.jit(SM.kda_chunked)(f(S0), f(q), f(k), f(v), f(g), f(beta))
     want_S, want_o = f(S0), []
     for t in range(length):
-        ot, want_S = T.kda_recurrent_step(want_S, f(q[t]), f(k[t]), f(v[t]),
+        ot, want_S = SM.kda_recurrent_step(want_S, f(q[t]), f(k[t]), f(v[t]),
                                           f(g[t]), f(beta[t]))
         want_o.append(ot)
     assert np.isfinite(np.asarray(o)).all() and np.isfinite(np.asarray(S)).all()
@@ -206,12 +207,12 @@ def test_the_kda_layer_alone(toy, n):
     not whole chunks; the state and the conv state it leaves are those after
     the last REAL position."""
     mcfg, lp, cfg, w, x, _ = _layer_inputs(toy, 1)
-    H, dk, K = mcfg.lin_heads, mcfg.lin_head_dim, T.KDA_CONV_KERNEL
+    H, dk, K = mcfg.lin_heads, mcfg.lin_head_dim, SM.KDA_CONV_KERNEL
     state = jnp.full((4, H, dk, dk), 7.0, jnp.float32)
     conv = jnp.full((4, K - 1, 3 * H * dk), -5.0, jnp.float32)
     xn = T._norm(mcfg, x, lp["ln_attn"])
     a, state, conv = jax.jit(
-        lambda *args: T._kda_prefill(mcfg, *args, True))(
+        lambda *args: SM._kda_prefill(mcfg, *args, True))(
         xn, lp["lin"], state, conv, jnp.int32(2), jnp.int32(n))
     want = ref.kda(ref._Cfg(cfg), w, x[:, :n])
     np.testing.assert_allclose(np.asarray(x + a)[:, :n], np.asarray(want),
@@ -223,8 +224,8 @@ def test_the_kda_layer_alone(toy, n):
     u = np.concatenate([np.asarray(xn[0] @ lp["lin"][k]) for k in ("wq", "wk", "wv")], -1)
     np.testing.assert_allclose(np.asarray(conv[2]), u[n - (K - 1):n], atol=1e-5)
     # one more token from that state is the reference's next position
-    a1, _, _ = T._kda_decode(mcfg, xn[:, n:n + 1] if n < x.shape[1] else xn[:, :1],
-                             lp["lin"], state, conv, 0, jnp.asarray([2]), 4)
+    a1, _, _ = SM._kda_decode(mcfg, xn[:, n:n + 1] if n < x.shape[1] else xn[:, :1],
+                              lp["lin"], state, conv, 0, jnp.asarray([2]))
     if n < x.shape[1]:
         want1 = ref.kda(ref._Cfg(cfg), w, x[:, :n + 1])[:, n]
         np.testing.assert_allclose(np.asarray(x[:, n] + a1[:, 0]),
@@ -297,7 +298,7 @@ def test_a_bf16_state_fails_the_tolerance(toy, monkeypatch):
     rounded to bf16 after every update, as a state pool kept in bf16 would
     hold it, is not within it."""
     model, params, _, _ = toy
-    step, chunked = T.kda_recurrent_step, T.kda_chunked
+    step, chunked = SM.kda_recurrent_step, SM.kda_chunked
     bf16 = lambda a: a.astype(jnp.bfloat16).astype(jnp.float32)  # noqa: E731
 
     def rounded_step(*a):
@@ -308,8 +309,8 @@ def test_a_bf16_state_fails_the_tolerance(toy, monkeypatch):
         o, S = chunked(*a, **k)
         return o, bf16(S)
 
-    monkeypatch.setattr(T, "kda_recurrent_step", rounded_step)
-    monkeypatch.setattr(T, "kda_chunked", rounded_chunked)
+    monkeypatch.setattr(SM, "kda_recurrent_step", rounded_step)
+    monkeypatch.setattr(SM, "kda_chunked", rounded_chunked)
     tokens = tokens_of(5, 70 + 12)
     got, _ = paged_logits(model, params, tokens, 70)
     want = reference_logits(toy, tokens)[69:]
@@ -317,22 +318,26 @@ def test_a_bf16_state_fails_the_tolerance(toy, monkeypatch):
 
 
 def _beta_1(monkeypatch, model):
-    monkeypatch.setattr(T, "KDA_BETA_SCALE", 1.0)
+    monkeypatch.setattr(SM, "KDA_BETA_SCALE", 1.0)
     return model
 
 
+def _planted(monkeypatch, **fields):
+    """The KDA kind's record with ``fields`` in place of its own."""
+    monkeypatch.setitem(SM.STATE_MIXERS, T.LINEAR_ATTENTION,
+                        SM.STATE_MIXERS[T.LINEAR_ATTENTION]._replace(**fields))
+
+
 def _lost_conv_state(monkeypatch, model):
-    real = T._kda_decode
-    monkeypatch.setattr(
-        T, "_kda_decode", lambda cfg, x, lp, state, conv, *a:
-        real(cfg, x, lp, state, jnp.zeros_like(conv), *a))
+    real = SM._kda_decode
+    _planted(monkeypatch, decode=lambda cfg, x, lp, state, conv, *a:
+             real(cfg, x, lp, state, jnp.zeros_like(conv), *a))
     return model
 
 
 def _inherited_slot(monkeypatch, model):
-    real = T._kda_prefill
-    monkeypatch.setattr(
-        T, "_kda_prefill", lambda *a: real(*a[:-1], False))
+    real = SM._kda_prefill
+    _planted(monkeypatch, prefill=lambda *a: real(*a[:-1], False))
     return model
 
 

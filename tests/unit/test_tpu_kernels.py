@@ -592,7 +592,7 @@ def test_kda_decode_update_compiles_and_matches(tpu, live):
     import jax
     import jax.numpy as jnp
 
-    from deepspeed_tpu.models.transformer import _kda_slot_update
+    from deepspeed_tpu.models.state_mixers import _kda_slot_update
     from deepspeed_tpu.ops.pallas.kda_decode_update import kda_decode_update
     from tests.unit.ops.test_kda_decode_update import draw_step
 
@@ -633,7 +633,7 @@ def test_mamba2_decode_update_compiles_and_matches(tpu, live):
     import jax
     import jax.numpy as jnp
 
-    from deepspeed_tpu.models.transformer import _ssd_decode_update
+    from deepspeed_tpu.models.state_mixers import _ssd_decode_update
     from deepspeed_tpu.ops.pallas.mamba2_decode_update import \
         mamba2_decode_update
     from tests.unit.ops.test_mamba2_decode_update import draw_step
