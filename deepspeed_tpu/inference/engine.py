@@ -814,6 +814,12 @@ class InferenceEngine:
         otherwise — serving stays correct, just without the memory split."""
         from jax.sharding import NamedSharding, PartitionSpec as P
         tp = self.mesh.shape.get("tp", 1)
+        if tp > 1 and self._latent_rows():
+            raise ValueError(
+                f"serving tp={tp} but the model keeps latent rows "
+                "(cache_spec['latent']): a latent row has no head axis to "
+                "split over tp, and no form shards the query heads over one "
+                "replicated row yet")
         if tp > 1:
             kvh = getattr(getattr(self.module, "config", None),
                           "kv_heads", None)
@@ -849,6 +855,12 @@ class InferenceEngine:
         return int(self._config.serving.max_running) + 1 \
             if spec.get("state") or spec.get("window") else 0
 
+    def _latent_rows(self) -> int:
+        """The model's layers that keep latent rows (its cache spec)."""
+        spec = getattr(getattr(self.module, "config", None), "cache_spec",
+                       None) or {}
+        return int(spec.get("latent", 0))
+
     def _slot_kept(self) -> str:
         """What the model keeps a slot, for a refusal's message."""
         spec = self.module.config.cache_spec
@@ -863,6 +875,12 @@ class InferenceEngine:
         Content addressing makes entries valid across serves and even
         fresh pool workspaces; only a geometry/dtype change rebuilds."""
         kh = getattr(self._config.serving, "kv_host", None)
+        if kh is not None and kh.enabled and self._latent_rows():
+            raise ValueError(
+                "serving.kv_host is on but the model keeps latent rows "
+                "(cache_spec['latent']): the host tier's block slice is "
+                "[layers, block_size, kv_heads * head_dim] of a k and a v "
+                "pool, and a latent block is neither")
         if kh is not None and kh.enabled and self._state_slots():
             raise ValueError(
                 f"serving.kv_host is on but the model keeps {self._slot_kept()} "
@@ -919,6 +937,11 @@ class InferenceEngine:
         if pool is None:
             self._kv_host_pool = None
             return
+        if self._latent_rows():
+            raise ValueError(
+                "a host KV pool holds [layers, block_size, kv_heads * "
+                "head_dim] slices of a k and a v pool: a model that keeps "
+                "latent rows (cache_spec['latent']) has neither")
         cfg = self.module.config
         shape = (cfg.n_layer, int(self._config.serving.block_size),
                  cfg.kv_heads * cfg.head_dim)
@@ -1479,6 +1502,19 @@ class InferenceEngine:
             raise ValueError(f"serving.tp > 1 but the model keeps "
                              f"{self._slot_kept()}: the pools of its slots "
                              "are not sharded")
+        # what no program reads a latent row for yet (a hit's tail and a
+        # chunk ride the chunk forward, speculation the verify window)
+        latent = bool(self._latent_rows())
+        for on, what in ((pc_mode == "on", "serving.prefix_caching='on'"),
+                         (chunk_tokens, "serving.prefill_chunk_tokens"),
+                         (str(srv.speculative.mode) != "off",
+                          "serving.speculative.mode")):
+            if latent and on:
+                raise ValueError(
+                    f"{what} set but the model keeps latent rows "
+                    "(cache_spec['latent']): whole-prompt prefill and "
+                    "decode alone read a latent row (no chunk or verify "
+                    "form is built)")
         if not chunk_ok:
             if pc_mode == "on":
                 raise ValueError(
@@ -1489,7 +1525,8 @@ class InferenceEngine:
                 raise ValueError(
                     "serving.prefill_chunk_tokens set but the model has no "
                     "forward_paged_prefill_chunk")
-        caching = chunk_ok and pc_mode != "off" and not stateful
+        caching = chunk_ok and pc_mode != "off" and not stateful \
+            and not latent
 
         # ---- speculative decoding (n-gram self-speculation) ----
         spec = srv.speculative
@@ -1659,6 +1696,10 @@ class _ServeSession:
         self.engine = engine
         self.sched = sched
         self.pools = pools
+        # whether the decode program's MoE counts carry the two columns of
+        # a model with zero-compute experts (``count_moe``)
+        self.zero_experts = bool(getattr(getattr(
+            engine.module, "moe", None), "zero_experts", 0))
         self._programs = dict(zip(_DISPATCH_SITES, jits))
         # fault containment (serving.fault): the action a fault can be
         # attributed to, the finer-grained dispatch site for the
@@ -2182,7 +2223,7 @@ class _ServeSession:
         rows = [(r, t) for r, t, live in zip(
             reqs, step.kind.tokens(got, reqs), step.live) if live]
         if step.aux and tel is not None:
-            tel.count_moe(np.asarray(step.aux[0]))
+            tel.count_moe(np.asarray(step.aux[0]), self.zero_experts)
         if self.ev is not None:
             # AFTER the tokens' fetch (it is the sync: emitting first
             # would clock async dispatch; a step that fetched none

@@ -436,16 +436,29 @@ class ServingTelemetry:
             "its first block is not one): over block_row_passes, the tokens "
             "a row's pass yields").inc(n)
 
-    def count_moe(self, counts) -> None:
+    def count_moe(self, counts, zero_experts: bool = False) -> None:
         """One fused decode step of an MoE model. ``counts`` [L, E + 1], the
         program's own: the assignments each expert of each layer computed
         (padding rows excluded) and, in the last column, those the layer
-        owed (real rows x k). The ``serving/moe_*`` counters exist only once
+        owed (real rows x k). ``zero_experts`` (a model with zero-compute
+        experts): two more columns, the assignments those took and all the
+        router made. The ``serving/moe_*`` counters exist only once
         an MoE model has decoded (they are not pre-created: a reader that
         requires them finds nothing under a dense model); resolved per
         access like every family here (0.4 us each), so a registry reset
         cannot orphan them."""
         c = self.registry.counter
+        if zero_experts:
+            c("serving/moe_zero_expert_assignments",
+              "assignments of real rows that chose a zero-compute (identity) "
+              "expert: over moe_router_assignments, the share of the "
+              "router's picks that cost no expert's weights"
+              ).inc(int(counts[:, -2].sum()))
+            c("serving/moe_router_assignments",
+              "every assignment the routers made for real rows (rows x k), "
+              "those to zero-compute experts and to experts held on other "
+              "chips among them").inc(int(counts[:, -1].sum()))
+            counts = counts[:, :-2]
         computed, owed = counts[:, :-1], counts[:, -1]
         c("serving/moe_layer_steps",
           "MoE layers run by fused decode steps (steps x layers)"

@@ -126,6 +126,35 @@ class MoEConfig:
     # a gated-SiLU expert every token takes beside its routed ones, of this
     # width (n_shared_experts x their width); 0 = none
     shared_expert_d_ff: int = 0
+    # nodrop: the k weights times this, after any normalisation
+    # (DeepSeek-V3's and LongCat's ``routed_scaling_factor``); 1.0 puts no
+    # multiply into the program
+    routed_scaling_factor: float = 1.0
+    # nodrop: a selection bias under softmax scoring too (LongCat's
+    # ``e_score_correction_bias``; sigmoid scoring always has one). Its leaf
+    # is ``select_bias``, zero at init like ``b_select`` unless a preset's
+    # seeded init gives ``select_bias_init_std``: a softmax router's scores
+    # are ~1 / its width, and a seeded bias of that order moves a token's
+    # marginal picks, as a trained balance correction does
+    select_bias: bool = False
+    select_bias_init_std: float = 0.0
+    # ZERO-COMPUTE experts (LongCat-Flash): the router's LAST
+    # ``zero_experts`` outputs, past its ``router_experts`` real ones, are
+    # identity experts without weights: an assignment to one adds its
+    # weight times the expert's INPUT. Every chip computes them for its own
+    # rows (they are all "here", as a shared expert is)
+    zero_experts: int = 0
+    # SHORTCUT-CONNECTED MoE (LongCat-Flash): the stack's period is one
+    # published layer of several sub-blocks, each with its own DENSE MLP;
+    # the period's ONE MoE reads what the FIRST sub-block's dense MLP reads
+    # (that sub-block's post-attention norm) and joins the residual stream
+    # at the END of the period, with the last sub-block's dense MLP. Its
+    # parameters lie under ``moe`` of the period's first group
+    shortcut: bool = False
+    # the router's draw is N(0, 1/d_model) times this: a seeded model's
+    # logits have this standard deviation (over hundreds of outputs a
+    # softmax of unit logits is nearly flat, and the k weights vanish)
+    router_init_scale: float = 1.0
     # Residual (PR-)MoE, arXiv:2201.05596: each MoE MLP is blended with a
     # dense MLP through a learned 2-way softmax coefficient (reference
     # moe/layer.py use_residual + inference moe_type='residual')
@@ -161,11 +190,19 @@ class MoECausalLM:
         share = (moe_config.router_experts is not None
                  or moe_config.expert_offset or moe_config.shared_expert_d_ff
                  or moe_config.scoring != "softmax"
-                 or moe_config.router_input != "mlp_input")
+                 or moe_config.router_input != "mlp_input"
+                 or moe_config.routed_scaling_factor != 1.0
+                 or moe_config.select_bias or moe_config.zero_experts
+                 or moe_config.shortcut)
         if share and moe_config.dispatch != "nodrop":
             raise ValueError("a share of the experts, a shared expert, "
-                             "sigmoid scoring and a router that reads the "
-                             "mixer's input need dispatch='nodrop'")
+                             "sigmoid scoring, a router that reads the "
+                             "mixer's input, a scaling factor, a selection "
+                             "bias, zero-compute experts and a shortcut MoE "
+                             "need dispatch='nodrop'")
+        if moe_config.shortcut and len(config.period) < 2:
+            raise ValueError("a shortcut MoE spans a period of two or more "
+                             "sub-blocks (config.layer_kinds)")
         if moe_config.expert_offset + moe_config.num_experts > self.router_width:
             raise ValueError(
                 f"experts {moe_config.expert_offset}.."
@@ -181,8 +218,27 @@ class MoECausalLM:
         return self.moe.expert_activation in ("swiglu", "reglu")
 
     @property
-    def router_width(self) -> int:
+    def real_router_width(self) -> int:
+        """The router's outputs that are experts with weights."""
         return self.moe.router_experts or self.moe.num_experts
+
+    @property
+    def router_width(self) -> int:
+        return self.real_router_width + self.moe.zero_experts
+
+    @property
+    def _select_bias(self) -> bool:
+        return self.moe.scoring == "sigmoid" or self.moe.select_bias
+
+    @property
+    def _moe_root(self) -> str:
+        """The key of a layer group the MoE's parameters lie under."""
+        return "moe" if self.moe.shortcut else "mlp"
+
+    @property
+    def n_moe_layers(self) -> int:
+        cfg = self.config
+        return cfg.n_periods if self.moe.shortcut else cfg.n_layer
 
     # -------------------- params -------------------- #
 
@@ -190,7 +246,12 @@ class MoECausalLM:
     def init_params(self, rng) -> Dict[str, Any]:
         cfg = self.config
         base = T.init_params(cfg, rng, dtype=self.param_dtype)
-        if cfg.layer_kinds is None:
+        if self.moe.shortcut:
+            # every sub-block keeps its dense MLP; the period's MoE beside
+            # the first
+            base["layers"][0]["moe"] = self._mlp_params(
+                jax.random.fold_in(rng, 2000), cfg.n_periods)
+        elif cfg.layer_kinds is None:
             base["layers"]["mlp"] = self._mlp_params(rng, cfg.n_layer)
         else:
             for j, group in enumerate(base["layers"]):
@@ -208,7 +269,8 @@ class MoECausalLM:
         k1, k2, k3 = jax.random.split(jax.random.fold_in(rng, 999), 3)
         s_in, s_out = cfg.init_std, cfg.init_std / math.sqrt(2 * L)
         mlp = {
-            "gate_w": (jax.random.normal(k1, (n, D, self.router_width)) / math.sqrt(D)).astype(dt),
+            "gate_w": (jax.random.normal(k1, (n, D, self.router_width))
+                       * (moe.router_init_scale / math.sqrt(D))).astype(dt),
             "w_up": (jax.random.normal(k2, (n, E, D, F)) * s_in).astype(dt),
             "w_down": (jax.random.normal(k3, (n, E, F, D)) * s_out).astype(dt),
         }
@@ -220,6 +282,11 @@ class MoECausalLM:
                         "b_down": jnp.zeros((n, E, D), dt)})
         if moe.scoring == "sigmoid":
             mlp["b_select"] = jnp.zeros((n, self.router_width), dt)
+        elif moe.select_bias:
+            kb = jax.random.fold_in(rng, 1007)
+            mlp["select_bias"] = (
+                jax.random.normal(kb, (n, self.router_width))
+                * moe.select_bias_init_std).astype(dt)
         if moe.shared_expert_d_ff:
             Fs = moe.shared_expert_d_ff
             k8, k9, k10 = jax.random.split(jax.random.fold_in(rng, 1005), 3)
@@ -241,7 +308,7 @@ class MoECausalLM:
 
     def tp_specs(self) -> Dict[str, Any]:
         if self.config.layer_kinds is not None or self.moe.shared_expert_d_ff \
-                or self.moe.scoring != "softmax":
+                or self._select_bias:
             # replicated: no sharded form of these stacks is built
             return T.replicated_specs(
                 lambda: self.init_params(jax.random.key(0)))
@@ -303,25 +370,31 @@ class MoECausalLM:
     def _route(self, lp, tokens):
         """tokens [T, D] -> (weights [T, k] float32, experts [T, k] int32 as
         indices into the experts HELD HERE, ``num_experts`` for one held
-        elsewhere, scores [T, router width])."""
+        elsewhere or a zero-compute expert, scores [T, router width], and
+        fourth which assignments [T, k] chose a zero-compute expert; None
+        for a model without)."""
         moe = self.moe
         # float32 for real: on the chip a default-precision float32
         # matmul rounds its operands to bf16
         logits = jnp.dot(tokens.astype(jnp.float32),
                          lp["gate_w"].astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
-        if moe.scoring == "softmax":
+        if not self._select_bias:
             weights, experts, probs = topk_routing(logits, moe.k,
                                                    moe.norm_topk_prob)
         else:
             weights, experts, probs = topk_routing(
                 logits, moe.k, moe.norm_topk_prob, scoring=moe.scoring,
-                select_bias=lp["b_select"])
+                select_bias=lp["b_select" if moe.scoring == "sigmoid"
+                               else "select_bias"])
+        if moe.routed_scaling_factor != 1.0:
+            weights = weights * moe.routed_scaling_factor
+        zero = experts >= self.real_router_width if moe.zero_experts else None
         if self.router_width != moe.num_experts:
             local = experts - moe.expert_offset
             experts = jnp.where((local >= 0) & (local < moe.num_experts),
                                 local, moe.num_experts)
-        return weights, experts, probs
+        return weights, experts, probs, zero
 
     def _grouped_kernel(self, params, rows: int) -> bool:
         """Whether a paged program's calls of ``rows`` rows take the grouped
@@ -331,13 +404,15 @@ class MoECausalLM:
         cfg = self.config
         groups = params["layers"] if cfg.layer_kinds is not None \
             else (params["layers"],)
+        root = self._moe_root
         return (self.moe.dispatch == "nodrop" and self._gated
                 and rows <= _GROUPED_KERNEL_MAX_ROWS and T._use_flash(cfg)
                 and (envelope_ok(rows, cfg.d_model, self.expert_ff)
                      or not dispatch.on_tpu())
                 # a Quantized8 leaf keeps the XLA forms (T._w dequantises)
-                and not any(isinstance(g["mlp"][k], Quantized8)
-                            for g in groups for k in self._expert_keys()))
+                and not any(isinstance(g[root][k], Quantized8)
+                            for g in groups if root in g
+                            for k in self._expert_keys()))
 
     def _nodrop_mlp(self, lp, x, valid=None, route_x=None, stack=None):
         """A score an expert in float32 (softmax, or sigmoid with a
@@ -350,10 +425,13 @@ class MoECausalLM:
         every row, for any other call of fewer than
         ``_SORTED_DISPATCH_MIN_ROWS`` rows; ``ragged``, rows sorted into
         groups (``jax.lax.ragged_dot``), from there on; then the shared
-        expert, if the model has one. Scopes ``router`` / ``moe_dispatch``
-        / ``experts`` / ``shared_expert`` name the parts in a device trace.
-        ``route_x``: what the router reads where that is not ``x``. Returns
-        (out, l_aux, counts [E], owed)."""
+        expert, if the model has one, and the zero-compute experts (a row's
+        weights for them times the row itself). Scopes ``router`` /
+        ``moe_dispatch`` / ``experts`` / ``shared_expert`` / ``zero_experts``
+        name the parts in a device trace. ``route_x``: what the router reads
+        where that is not ``x``. Returns (out, l_aux, counts [E], owed); a
+        model with zero-compute experts fifth [2] int32: the assignments of
+        ``valid`` rows to them, and all the router made for those rows."""
         moe = self.moe
         B, S, D = x.shape
         E = moe.num_experts
@@ -364,7 +442,7 @@ class MoECausalLM:
         dispatch.record("experts", form,
                         f"rows={rows} k={moe.k} E={E} D={D} F={self.expert_ff}")
         with jax.named_scope("router"):
-            weights, experts, probs = self._route(
+            weights, experts, probs, zero = self._route(
                 lp, tokens if route_x is None else route_x.reshape(-1, D))
         if self.router_width == E:
             owed = (rows if valid is None
@@ -416,7 +494,12 @@ class MoECausalLM:
                     layer * E, relu=relu, visits=visits)
 
         if form == "ragged":
-            out, counts = sorted_dispatch(tokens, weights, experts, E, grouped, valid)
+            # a share computes ~E / width of its rows' assignments: four
+            # times that many rows of the sorted order, or all of it
+            cap = 0 if self.router_width == E else \
+                -(-4 * rows * moe.k * E // self.router_width // 128) * 128
+            out, counts = sorted_dispatch(tokens, weights, experts, E, grouped,
+                                          valid, cap=cap)
         else:
             out, counts = dense_dispatch(
                 tokens, weights, experts, E,
@@ -433,7 +516,17 @@ class MoECausalLM:
                 sp = {k: T._w(w, tokens) for k, w in lp["shared"].items()}
                 out = out + (jax.nn.silu(tokens @ sp["w_gate"])
                              * (tokens @ sp["w_up"])) @ sp["w_down"]
-        return out.reshape(B, S, D), l_aux, counts, owed
+        if zero is None:
+            return out.reshape(B, S, D), l_aux, counts, owed
+        with jax.named_scope("zero_experts"):
+            wz = jnp.sum(jnp.where(zero, weights, 0.0), axis=1, keepdims=True)
+            out = out + (wz * tokens.astype(jnp.float32)).astype(out.dtype)
+            real = jnp.ones((rows,), bool) if valid is None \
+                else valid.astype(bool)
+            routed = jnp.stack([
+                jnp.sum(zero & real[:, None], dtype=jnp.int32),
+                jnp.sum(real, dtype=jnp.int32) * moe.k])
+        return out.reshape(B, S, D), l_aux, counts, owed, routed
 
     def _capacity_mlp(self, lp, x, rng, train: bool, used_token=None):
         moe = self.moe
@@ -571,17 +664,38 @@ class MoECausalLM:
         kernel (``_grouped_kernel``) the function asks the layer scan for
         the expert stacks whole (``stack_keys``)."""
         used = T.paged_real_rows(pools, slots).reshape(-1)
+        root = self._moe_root
 
-        def mlp_fn(cfg, x_normed, lp, mixer_in, stack=None):
+        def moe_fn(cfg, x_normed, lp, mixer_in, stack=None):
             with jax.named_scope("mlp"):
-                out, _, n, owed = self._moe_mlp(
-                    lp["mlp"], x_normed, None, train=False, used_token=used,
+                out, _, n, owed, *routed = self._moe_mlp(
+                    lp[root], x_normed, None, train=False, used_token=used,
                     with_owed=True, mixer_in=mixer_in, stack=stack)
             if not counts:
                 return out
-            return out, jnp.append(n, owed)
+            aux = jnp.append(n, owed)
+            return out, jnp.concatenate([aux, *routed]) if routed else aux
+
+        mlp_fn = moe_fn
+        if self.moe.shortcut:
+            def mlp_fn(cfg, x_normed, lp, mixer_in, held, last, stack=None):
+                """A sub-block's dense MLP. The period's MoE rides beside it
+                as ``held`` (``carries``: the layer scan hands a sub-block
+                what the one before returned): computed on what the dense
+                MLP reads by the sub-block whose group holds it, the first,
+                and added by the ``last``."""
+                out, aux = T.mlp(cfg, x_normed, lp["mlp"]), None
+                if root in lp:
+                    with jax.named_scope("shortcut_moe"):
+                        held = moe_fn(cfg, x_normed, lp, mixer_in, stack)
+                    held, aux = held if counts else (held, None)
+                if last:
+                    out = out + held
+                return (out if aux is None else (out, aux)), held
+            mlp_fn.carries = True
         if self._grouped_kernel(params, used.shape[0]):
             mlp_fn.stack_keys = self._expert_keys()
+            mlp_fn.stack_root = root
         return mlp_fn
 
     def forward_paged_prefill(self, params, tokens, pools, slots, last_idx,
@@ -608,10 +722,12 @@ class MoECausalLM:
     def forward_paged_decode(self, params, tokens, pools, block_tables, pos,
                              pad_bias=None, state_slots=None):
         """(logits [B, vocab], new pools, counts [L, E + 1]): third, the
-        assignments each expert of each layer computed in this step and, in
-        the last column, those the layer owed (real rows x k): what the
-        engine's ``serving/moe_*`` counters are fed."""
-        bs = pools["k"].shape[2]
+        assignments each expert of each MoE layer computed in this step and,
+        in column E, those the layer owed (real rows x k; a share: those to
+        experts held here): what the engine's ``serving/moe_*`` counters are
+        fed. A model with zero-compute experts adds two columns: the
+        assignments they took, and all the router made."""
+        bs = T.pool_geometry(pools)[1]
         slots = block_tables[jnp.arange(pos.shape[0]), pos // bs] * bs + pos % bs
         mlp_fn = self._paged(params, pools, slots, counts=True)
         return T.forward_paged_decode(self.config, params, tokens, pools,
@@ -652,11 +768,13 @@ class MoECausalLM:
         embed = cfg.vocab_size * D + (cfg.max_seq * D if cfg.pos_embedding == "learned" else 0)
         moe_mlp = D * self.router_width \
             + E * (3 * D * F if self._gated else 2 * D * F + F + D)
-        if moe.scoring == "sigmoid":
-            moe_mlp += self.router_width                   # b_select
+        if self._select_bias:
+            moe_mlp += self.router_width                   # the bias
         moe_mlp += 3 * D * moe.shared_expert_d_ff
         norms = (4 if cfg.norm == "layernorm" else 2) * D
         final_norm = (2 if cfg.norm == "layernorm" else 1) * D
         head = 0 if cfg.tie_embeddings else D * cfg.vocab_size
-        return embed + cfg.n_layer * (moe_mlp + norms) + T.mixer_params(cfg) \
-            + final_norm + head
+        # a shortcut MoE: one a period, and a dense MLP in every sub-block
+        dense = cfg.n_layer * 3 * D * cfg.ff_dim if moe.shortcut else 0
+        return embed + self.n_moe_layers * moe_mlp + cfg.n_layer * norms \
+            + dense + T.mixer_params(cfg) + final_norm + head

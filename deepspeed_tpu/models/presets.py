@@ -380,6 +380,72 @@ def granite_hybrid(size: str = "4.0-h-micro", **over) -> CausalLM:
                     param_dtype=param_dtype)
 
 
+def longcat_flash(size: str = "omni-4l-ep32", **over):
+    """LongCat-Flash-Omni's language model (``meituan-longcat/LongCat-Flash-
+    Omni`` config.json; the audio/vision encoders and the codec decoder are
+    no part of this family): 28 published layers of d 6,144, each TWO
+    sub-blocks of pre-RMSNorm (eps 1e-5) multi-head LATENT attention (64
+    heads; queries through a rank-1,536 bottleneck, keys and values from one
+    latent of 512 a token beside a roped key part of 64 shared by the heads;
+    keys 128 + 64, values 128; both bottlenecks RMSNormed (eps 1e-6) and
+    scaled by sqrt(6144 / rank); rope theta 1e7 on interleaved pairs) and a
+    dense gated-SiLU MLP of 12,288, around a SHORTCUT MoE that reads the
+    first sub-block's post-attention norm and joins the stream at the end of
+    the layer: a float32 softmax router over 768 outputs with a selection
+    bias, top-12 weights NOT normalised and times 6, outputs 0-511 gated-SiLU
+    experts of width 2,048 and outputs 512-767 ZERO-COMPUTE experts (weight
+    times the input); an untied head over 131,072 rows. Here a published
+    layer is one PERIOD of two ``latent_attention`` sub-blocks
+    (``n_layer`` counts sub-blocks: two cache layers a published layer).
+    ``omni-4l-ep32`` is ONE CHIP OF THE 32 that share each layer (expert
+    parallel: experts 0-15 of 512 held, every zero expert, the attention,
+    both dense MLPs and the router whole), of a pipeline stage of 4 of the
+    28 layers, with an eighth of the vocabulary (16,384): 5,172,749,312
+    parameters (perfbench's ``longcatflashomni_serve_ctx3k``). ``share``
+    picks another of the 32. Its seeded init (``init_std``,
+    ``embed_init_std``, the router's scale, the selection bias's deviation
+    of half a mean score) is measured by
+    ``benchmarks/longcat_check_controls.py`` (PERF.md section 6, PR 48).
+    ``tiny``: 2 published layers at toy widths, 8 real experts of which 4
+    held and 4 zero experts, top-3, keys 32 + 64 and values 16 (Dqk != Dv
+    kept), a latent of 128."""
+    from deepspeed_tpu.models.moe_lm import MoECausalLM, MoEConfig
+    dims, moe = {
+        "tiny": (dict(n_layer=4, n_head=8, d_model=64, d_ff=128,
+                      vocab_size=512, max_seq=1024, q_lora_rank=48,
+                      kv_lora_rank=128, qk_nope_head_dim=32,
+                      qk_rope_head_dim=64, v_head_dim=16, init_std=0.1,
+                      embed_init_std=1.0),
+                 dict(num_experts=4, router_experts=8, zero_experts=4, k=3,
+                      expert_d_ff=32, router_init_scale=2.0,
+                      select_bias_init_std=0.5 / 12)),
+        "omni-4l-ep32": (dict(n_layer=8, n_head=64, d_model=6144, d_ff=12288,
+                              vocab_size=16384, max_seq=4608,
+                              q_lora_rank=1536, kv_lora_rank=512,
+                              qk_nope_head_dim=128, qk_rope_head_dim=64,
+                              v_head_dim=128, init_std=0.02,
+                              embed_init_std=1.0),
+                         dict(num_experts=16, router_experts=512,
+                              zero_experts=256, k=12, expert_d_ff=2048,
+                              router_init_scale=3.0,
+                              select_bias_init_std=0.5 / 768)),
+    }[size]
+    param_dtype = over.pop("param_dtype", jnp.float32)
+    share = over.pop("share", 0)
+    moe = {**moe, **over.pop("moe", {})}
+    cfg = TransformerConfig(**{**dict(
+        pos_embedding="rope", rope_theta=1e7, rope_interleaved=True,
+        norm="rmsnorm", norm_eps=1e-5, activation="swiglu",
+        tie_embeddings=False, attn_bias=False, mla_lora_scale=True,
+        layer_kinds=("latent_attention",) * 2), **dims, **over})
+    return MoECausalLM(cfg, MoEConfig(**{**dict(
+        dispatch="nodrop", expert_activation="swiglu", scoring="softmax",
+        select_bias=True, norm_topk_prob=False, routed_scaling_factor=6.0,
+        shortcut=True, aux_loss_coef=0.0,
+        expert_offset=share * moe["num_experts"]), **moe}),
+        param_dtype=param_dtype)
+
+
 MODEL_PRESETS: Dict[str, Callable] = {
     "gpt2": gpt2,
     "llama": llama,
@@ -391,6 +457,7 @@ MODEL_PRESETS: Dict[str, Callable] = {
     "sdar": sdar,
     "smallthinker": smallthinker,
     "granite_hybrid": granite_hybrid,
+    "longcat_flash": longcat_flash,
 }
 
 

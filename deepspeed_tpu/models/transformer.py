@@ -33,6 +33,10 @@ ATTENTION, LINEAR_ATTENTION = "attention", "linear_attention"
 WINDOW_ATTENTION = "window_attention"
 #: the Mamba-2 mixer: a state-space recurrence in its SSD form
 MAMBA2 = "mamba2"
+#: softmax attention over a LATENT cache (multi-head latent attention): a
+#: token keeps one row of ``kv_lora_rank + qk_rope_head_dim`` values a layer,
+#: no k/v pair and no head axis; ``models/latent_attention.py``
+LATENT_ATTENTION = "latent_attention"
 #: the kinds whose layers keep a recurrent state and a conv state in a slot
 #: a request; what each is, is its record in ``models/state_mixers.py``
 STATE_KINDS = (LINEAR_ATTENTION, MAMBA2)
@@ -130,10 +134,13 @@ class TransformerConfig:
     # the ssm_* sizes; "window_attention": softmax attention whose query
     # at i sees keys i - attn_window < j <= i, the attention layer's
     # parameters); the stack is n_layer / len(layer_kinds) periods.
+    # "latent_attention": multi-head latent attention, the mla sizes below,
+    # its cache one latent row a token).
     # None: every layer is "attention". A kind says what cache it keeps
     # (``cache_spec``): KV blocks, a state slot a request (both recurrent
-    # kinds, each with its own state shapes: ``state_shapes``), or a ring
-    # of KV blocks a request that holds the window and no more.
+    # kinds, each with its own state shapes: ``state_shapes``), a ring
+    # of KV blocks a request that holds the window and no more, or blocks
+    # of latent rows.
     layer_kinds: Optional[Tuple[str, ...]] = None
     # keys a window_attention layer's query sees, its own among them
     attn_window: int = 0
@@ -155,6 +162,21 @@ class TransformerConfig:
     ssm_state: int = 0
     ssm_conv_kernel: int = 4
     ssm_chunk: int = 256
+    # the latent (MLA) mixer: queries through a bottleneck of
+    # ``q_lora_rank`` (RMSNorm in it), ``n_head`` heads of ``qk_nope_head_dim
+    # + qk_rope_head_dim``; keys and values from ONE latent of
+    # ``kv_lora_rank`` (RMSNorm) a token, expanded a head to
+    # ``qk_nope_head_dim + v_head_dim``, beside one roped key part of
+    # ``qk_rope_head_dim`` shared by all heads. ``mla_lora_scale``: the
+    # query times sqrt(d_model / q_lora_rank) and the normed latent times
+    # sqrt(d_model / kv_lora_rank) (LongCat's ``mla_scale_q_lora`` /
+    # ``mla_scale_kv_lora``)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    mla_lora_scale: bool = False
     # Granite's scalars, each 1.0 where a model has none: the token
     # embedding is multiplied by ``embedding_multiplier``, every branch by
     # ``residual_multiplier`` before it joins the residual stream, and the
@@ -270,12 +292,31 @@ class TransformerConfig:
         and a conv state in a slot a request (KDA and Mamba-2 layers
         alike: ``STATE_KINDS``), ``window`` those that hold
         the last ``attn_window`` positions' KV in a ring of blocks a
-        request. What cannot hold with a state or a ring yet (a prefix
-        hit, a verify window's rewind, a block moved to the host) is
-        refused from here, not by an option."""
+        request, ``latent`` those that hold blocks of latent rows
+        (``latent_row`` values a token, addressed by the same block tables
+        as KV blocks). What cannot hold with a state, a ring or a latent
+        row yet (a prefix hit, a verify window's rewind, a block moved to
+        the host, a head-sharded pool) is refused from here, not by an
+        option."""
         return {"kv": self.layers_of(ATTENTION),
                 "state": sum(self.layers_of(k) for k in STATE_KINDS),
-                "window": self.layers_of(WINDOW_ATTENTION)}
+                "window": self.layers_of(WINDOW_ATTENTION),
+                "latent": self.layers_of(LATENT_ATTENTION)}
+
+    @property
+    def latent_row(self) -> int:
+        """Values a token keeps in a latent layer's cache: the normed
+        latent, then the roped key part all heads share."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_pool_row(self) -> int:
+        """Lanes a token's row takes in the latent pool: ``latent_row``
+        rounded up to whole tiles of 128 lanes, the rest zeros. The chip's
+        memory is tiled 128 lanes wide, so a row of 576 values takes 640
+        whether the array says so or not, and a kernel's copies are whole
+        tiles."""
+        return -(-self.latent_row // 128) * 128
 
     def state_shapes(self, kind: str):
         """What one layer of a recurrent ``kind`` keeps a request: the
@@ -312,7 +353,7 @@ class TransformerConfig:
 
 def _check_pattern(cfg: TransformerConfig):
     kinds = cfg.period
-    known = (ATTENTION, WINDOW_ATTENTION, *STATE_KINDS)
+    known = (ATTENTION, WINDOW_ATTENTION, LATENT_ATTENTION, *STATE_KINDS)
     bad = [k for k in kinds if k not in known]
     if bad:
         raise ValueError(f"layer_kinds {kinds}: unknown kind {bad[0]!r} "
@@ -325,6 +366,9 @@ def _check_pattern(cfg: TransformerConfig):
     for kind in STATE_KINDS:
         if kind in kinds:
             _mixer(kind).check(cfg)
+    if LATENT_ATTENTION in kinds:
+        from deepspeed_tpu.models import latent_attention
+        latent_attention.check(cfg)
 
 
 def _layer_group(cfg: TransformerConfig, kind: str, n: int, ks, dtype):
@@ -351,6 +395,9 @@ def _layer_group(cfg: TransformerConfig, kind: str, n: int, ks, dtype):
     if kind in STATE_KINDS:
         m = _mixer(kind)
         group[m.key] = m.init(cfg, n, ks[8], dtype, out_std)
+    elif kind == LATENT_ATTENTION:
+        from deepspeed_tpu.models import latent_attention
+        group["attn"] = latent_attention.init(cfg, n, ks[8], dtype, out_std)
     else:
         group["attn"] = {
             "wq": dense(ks[0], (n, D, H * Hd)),
@@ -394,11 +441,15 @@ def mixer_params(cfg: TransformerConfig) -> int:
             + (D * H * Hd if cfg.attn_out_gate else 0))
 
     def layer(kind):
-        if kind not in STATE_KINDS:
+        if kind == LATENT_ATTENTION:
+            from deepspeed_tpu.models import latent_attention
+            init = latent_attention.init
+        elif kind in STATE_KINDS:
+            init = _mixer(kind).init
+        else:
             return attn
         return sum(a.size for a in jax.tree.leaves(jax.eval_shape(
-            lambda: _mixer(kind).init(cfg, 1, jax.random.key(0), jnp.float32,
-                                      1.0))))
+            lambda: init(cfg, 1, jax.random.key(0), jnp.float32, 1.0))))
 
     return cfg.n_periods * sum(layer(k) for k in cfg.period)
 
@@ -1495,12 +1546,22 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
     as a RING (position ``p`` in its block ``(p // block_size) % R``), and
     block 0 is their dummy. The ring comes and goes with the slot: no
     table changes while a request runs, and the window pools cannot run
-    dry (admission reads the full layers' pool alone). This is the one
-    home of the shapes."""
+    dry (admission reads the full layers' pool alone). Latent-attention
+    layers: ``c`` [latent layers, num_blocks, block_size,
+    cfg.latent_pool_row], ONE row a token (no k/v pair, no head axis; its
+    ``cfg.latent_row`` values, then zeros to whole lane tiles), its blocks those of the
+    same tables and block 0 the same dummy; a stack whose every attention
+    is latent has no ``k`` / ``v`` at all (``pool_geometry``). This is the
+    one home of the shapes."""
     spec = cfg.cache_spec
     row = cfg.kv_heads * cfg.head_dim
     shape = (spec["kv"], num_blocks, block_size, row)
-    pools = {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+    pools = {}
+    if spec["kv"] or not spec["latent"]:
+        pools.update(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype))
+    if spec["latent"]:
+        pools["c"] = jnp.zeros((spec["latent"], num_blocks, block_size,
+                                cfg.latent_pool_row), dtype)
     if (spec["state"] or spec["window"]) and state_slots < 2:
         raise ValueError("a stack with recurrent state or window layers "
                          "needs state_slots >= 2 (the dummy and one a "
@@ -1518,6 +1579,13 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
                                for st, _ in shapes)
         pools["conv"] = tuple(jnp.zeros(lead + cv, dtype) for _, cv in shapes)
     return pools
+
+
+def pool_geometry(pools):
+    """(num_blocks, block_size, dtype) of the block pools: the KV blocks',
+    or the latent rows' where the stack keeps no KV."""
+    ref = pools["k"] if "k" in pools else pools["c"]
+    return ref.shape[1], ref.shape[2], ref.dtype
 
 
 def _pool_scatter(pool, kv_new, slots):
@@ -1574,7 +1642,7 @@ def _ring_slots(table, positions, last, bs: int):
 
 
 def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
-                       mlp_fn=None, lin_fn=None, win_fn=None):
+                       mlp_fn=None, lin_fn=None, win_fn=None, lat_fn=None):
     """The ONE way the paged programs thread the pools through the layer
     stack: as the carry of a scan over the PERIODS of the layer pattern (a
     period of one attention layer where the stack has no pattern), a
@@ -1588,7 +1656,9 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
     ``[l*slots, (l+1)*slots)``, ``lin_fn(mixer, x_normed, lp_mixer, state,
     conv, row0)`` with ``mixer`` the kind's record; window layer ``l`` at
     blocks ``[l*ring blocks, ...)`` of ``wk`` / ``wv``, ``win_fn(x_normed,
-    lp_attn, wk, wv, block0)``. No per-layer slice of
+    lp_attn, wk, wv, block0)``; latent-attention layer ``l`` at blocks
+    ``[l*num_blocks, ...)`` of ``c``, ``lat_fn(x_normed, lp_attn, cp, block0,
+    slot0)``. No per-layer slice of
     a pool exists and nothing is stacked. An ``mlp_fn`` may return ``(out,
     aux)``: ``aux`` (an MoE layer's per-expert counts) comes back stacked
     over the layers, else None. An ``mlp_fn`` with ``stack_keys`` (leaves of
@@ -1596,20 +1666,31 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
     are read: an MoE's expert matrices under the grouped kernel) gets those
     leaves WHOLE, ``[n_periods, ...]`` each, as ``stack=(leaves, p)`` with
     ``p`` the layer's index in them; they are closed over and no slice of
-    them is made. Returns (x, new pools, aux)."""
+    them is made (``stack_root``: the key of a layer's group they lie under
+    where that is not ``mlp``; a group without it hands none). An
+    ``mlp_fn`` that ``carries`` a branch across a period's sub-blocks (a
+    shortcut MoE) is called ``mlp_fn(cfg, x_normed, lp, mixer_in, held,
+    last)`` and returns ``(out, held)``: ``held`` is what the sub-block
+    before it returned (None to the period's first), ``last`` whether this
+    is the period's last. Returns (x, new pools, aux)."""
     period = cfg.period
     n_att, n_win = period.count(ATTENTION), period.count(WINDOW_ATTENTION)
-    Nb, bs = pools["k"].shape[1:3]
+    n_lat = period.count(LATENT_ATTENTION)
+    Nb, bs, _ = pool_geometry(pools)
     n_slots = pools["state"][0].shape[1] if "state" in pools else 0
     Nwb = pools["wk"].shape[1] if n_win else 0
     groups = params["layers"] if cfg.layer_kinds is not None \
         else (params["layers"],)
     stack_keys = getattr(mlp_fn, "stack_keys", ())
+    carries = getattr(mlp_fn, "carries", False)
     if stack_keys:
-        whole = [{k: g["mlp"][k] for k in stack_keys} for g in groups]
+        root = getattr(mlp_fn, "stack_root", "mlp")
+        whole = [{k: g[root][k] for k in stack_keys} if root in g else None
+                 for g in groups]
         groups = tuple(
-            {**g, "mlp": {k: w for k, w in g["mlp"].items()
-                          if k not in stack_keys}} for g in groups)
+            {**g, root: {k: w for k, w in g[root].items()
+                         if k not in stack_keys}} if root in g else g
+            for g in groups)
 
     def run_period(carry, xs):
         h, flat = carry
@@ -1617,16 +1698,23 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
                 for n, a in flat.items()}
         lps, p = xs
         aux = []
+        held = None
         mfn = mlp_fn
         if mlp_fn is not None:
             def mfn(c, xx, lpp, xa):
-                kw = {"stack": (whole[j], p)} if stack_keys else {}
-                out = mlp_fn(c, xx, lpp, xa, **kw)
+                nonlocal held
+                kw = {"stack": (whole[j], p)} \
+                    if stack_keys and whole[j] is not None else {}
+                if carries:
+                    out, held = mlp_fn(c, xx, lpp, xa, held,
+                                       j == len(period) - 1, **kw)
+                else:
+                    out = mlp_fn(c, xx, lpp, xa, **kw)
                 if isinstance(out, tuple):
                     out, a = out
                     aux.append(a)
                 return out
-        i_att = i_lin = i_win = 0
+        i_att = i_lin = i_win = i_lat = 0
         for j, (kind, lp) in enumerate(zip(period, lps)):
             if kind == ATTENTION:
                 l = p * n_att + i_att
@@ -1644,6 +1732,14 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
                     lambda xn: win_fn(xn, lp["attn"], flat["wk"], flat["wv"],
                                       l * Nwb),
                     mfn, scope=WINDOW_ATTENTION)
+            elif kind == LATENT_ATTENTION:
+                l = p * n_lat + i_lat
+                i_lat += 1
+                h, flat["c"] = _decode_block(
+                    cfg, h, lp,
+                    lambda xn: lat_fn(xn, lp["attn"], flat["c"], l * Nb,
+                                      l * (Nb * bs)),
+                    mfn, scope=LATENT_ATTENTION)
             else:
                 i, m = i_lin, _mixer(kind)
                 i_lin += 1
@@ -1662,8 +1758,9 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
     (x, flat), aux = jax.lax.scan(
         run_period, (x, flat),
         (groups, jnp.arange(cfg.n_periods, dtype=jnp.int32)))
-    if aux is not None and len(period) > 1:
-        aux = aux.reshape(cfg.n_layer, *aux.shape[2:])
+    if aux is not None and aux.ndim > 2 and len(period) > 1:
+        # a period's layers that gave one each, stacked: one row a layer
+        aux = aux.reshape(-1, *aux.shape[2:])
     return x, jax.tree.map(lambda a, b: a.reshape(b.shape), flat, pools), aux
 
 
@@ -1672,7 +1769,7 @@ def paged_real_rows(pools, slots):
     tail, an inactive decode or verify row) is what the engine routes to the
     dummy block 0, so a flat slot under ``block_size`` is padding. What an
     MoE MLP keeps away from its experts."""
-    return slots >= pools["k"].shape[2]
+    return slots >= pool_geometry(pools)[1]
 
 
 def _paged_decode_attention(cfg: TransformerConfig, x, lp, positions, pos,
@@ -1830,6 +1927,24 @@ def _check_paged_config(cfg: TransformerConfig):
             "sparse_attention is not supported by the paged KV decode path")
 
 
+def _lat_fn(pools, fn):
+    """The ``lat_fn`` of a paged program (None for a stack without latent
+    rows): ``fn`` with ``models/latent_attention.py`` as its first argument
+    (imported here and not above: that module imports this one)."""
+    if "c" not in pools:
+        return None
+    from deepspeed_tpu.models import latent_attention
+    return lambda *a: fn(latent_attention, *a)
+
+
+def _no_latent_form(pools, what: str):
+    """What no form reads a latent row for yet."""
+    if "c" in pools:
+        raise NotImplementedError(
+            f"{what} is not built for latent rows (cache_spec['latent']): "
+            "whole-prompt prefill and decode alone read them")
+
+
 def _lin_prefill_fn(cfg: TransformerConfig, pools, state_slot, n_valid, fresh):
     """The ``lin_fn`` of a prefill or chunk program (None for a stack
     without state): the request's slot in each recurrent layer, through the
@@ -1875,14 +1990,16 @@ def forward_paged_prefill(cfg: TransformerConfig, params, tokens, pools,
     zero and whose ring it fills with the prompt's last blocks."""
     _check_paged_config(cfg)
     x, positions = cached_embed(cfg, params, tokens, jnp.int32(0),
-                                pools["k"].dtype)
+                                pool_geometry(pools)[2])
 
     x, pools, _ = _scan_paged_layers(
         cfg, params, pools, x,
         lambda xn, lp, kp, vp, block0, slot0: _paged_prefill_attention(
             cfg, xn, lp, positions, kp, vp, slots + slot0),
         mlp_fn, _lin_prefill_fn(cfg, pools, state_slot, last_idx + 1, True),
-        _win_prefill_fn(cfg, pools, state_slot, positions, last_idx))
+        _win_prefill_fn(cfg, pools, state_slot, positions, last_idx),
+        _lat_fn(pools, lambda la, xn, lp, cp, block0, slot0: la.prefill(
+            cfg, xn, lp, positions, cp, slots + slot0)))
     # head on the sampled position only: the [1, vocab] projection, not
     # the whole bucket's [T, vocab]
     xl = jax.lax.dynamic_slice_in_dim(x, last_idx, 1, axis=1)
@@ -1915,6 +2032,7 @@ def forward_paged_prefill_chunk(cfg: TransformerConfig, params, tokens,
         raise NotImplementedError(
             "a prefill chunk is not built for a stack with window layers: "
             "their rings take whole prompts only")
+    _no_latent_form(pools, "a prefill chunk")
     x, positions = cached_embed(cfg, params, tokens, start_pos,
                                 pools["k"].dtype)
 
@@ -1933,9 +2051,8 @@ def copy_paged_block(pools, src, dst):
     """Device copy of one pool block across every layer (the scheduler's
     copy-on-write split: a request restarting mid-block inside a SHARED
     block gets a private copy before it writes). src/dst [] int32."""
-    return {**pools,
-            "k": pools["k"].at[:, dst].set(pools["k"][:, src]),
-            "v": pools["v"].at[:, dst].set(pools["v"][:, src])}
+    return {**pools, **{n: pools[n].at[:, dst].set(pools[n][:, src])
+                        for n in ("k", "v", "c") if n in pools}}
 
 
 def _paged_verify_attention(cfg: TransformerConfig, x, lp, positions,
@@ -2038,6 +2155,7 @@ def forward_paged_verify(cfg: TransformerConfig, params, tokens, pools,
             "a verify window rewinds to the last accepted position: a "
             "recurrent state cannot be rewound (no snapshot is kept), and a "
             "window layer's ring has written over what the rewind would read")
+    _no_latent_form(pools, "a verify window")
     x, positions = cached_embed(cfg, params, tokens, pos, pools["k"].dtype)
 
     x, pools, _ = _scan_paged_layers(
@@ -2060,7 +2178,10 @@ def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
     ``state_slots`` [B] int32 (a stack with recurrent state or window
     layers): each row's slot, 0 (the dummy) for an inactive row."""
     _check_paged_config(cfg)
-    x, positions = cached_embed(cfg, params, tokens, pos, pools["k"].dtype)
+    x, positions = cached_embed(cfg, params, tokens, pos,
+                                pool_geometry(pools)[2])
+    if "c" in pools and pad_bias is not None:
+        _no_latent_form(pools, "a key-side pad bias")
     lin_fn = win_fn = None
     if ("state" in pools or "wk" in pools) and state_slots is None:
         raise ValueError("a stack with recurrent state or window layers "
@@ -2081,7 +2202,9 @@ def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
         lambda xn, lp, kp, vp, block0, slot0: _paged_decode_attention(
             cfg, xn, lp, positions, pos, kp, vp, block_tables + block0,
             pad_bias),
-        mlp_fn, lin_fn, win_fn)
+        mlp_fn, lin_fn, win_fn,
+        _lat_fn(pools, lambda la, xn, lp, cp, block0, slot0: la.decode(
+            cfg, xn, lp, positions, pos, cp, block_tables + block0)))
     logits = cached_head(cfg, params, x)[:, 0, :]
     return (logits, pools) if aux is None else (logits, pools, aux)
 
@@ -2151,6 +2274,7 @@ def forward_paged_block(cfg: TransformerConfig, params, tokens, pools,
         raise NotImplementedError(
             "a block pass needs a model that generates by blocks "
             "(cfg.generation) and keeps no recurrent state and no window")
+    _no_latent_form(pools, "a block pass")
     if pools["k"].shape[2] % cfg.causal_block:
         raise ValueError("a pool block must hold whole generation blocks")
     x, positions = cached_embed(cfg, params, tokens, pos, pools["k"].dtype)

@@ -238,18 +238,49 @@ def _group_sizes(flat, num_experts: int):
 
 def sorted_dispatch(tokens: jnp.ndarray, weights: jnp.ndarray,
                     experts: jnp.ndarray, num_experts: int,
-                    grouped_fn: Callable, valid=None):
+                    grouped_fn: Callable, valid=None, cap: int = 0):
     """No-drop dispatch over ragged groups: the ``T * k`` assignments sorted
     by expert, ``grouped_fn(xs [T*k, D], group_sizes [E]) -> [T*k, D]`` (the
     experts as grouped matmuls, ``jax.lax.ragged_dot``) run over them, and
     the rows weighted and summed back per token. Differentiable; work and
     memory are ``T * k`` rows whatever ``E`` is. ``valid`` [T] keeps padding
-    out (see :func:`_masked_experts`). Returns (out [T, D], counts [E])."""
+    out (see :func:`_masked_experts`). ``cap`` (static, under ``T * k``;
+    inference only): where ``experts`` names mostly experts that are not
+    here (a chip's share of an expert-parallel layer: index ``num_experts``),
+    the sorted order holds the assignments that ARE first, and only its
+    first ``cap`` rows are gathered, multiplied and summed back; a call
+    with more than ``cap`` of them takes the whole order, so nothing is
+    ever dropped. Two bodies, on purpose: the whole order is un-sorted by a
+    gather and summed by an einsum (what training differentiates and what
+    every model that holds all its experts traces to: its programs are
+    pinned, ``tests/unit/paged_program_digests.py``); a head of the order
+    has no whole order to un-sort and is summed back by a scatter-add,
+    at either length (``over``). Returns (out [T, D], counts [E])."""
     T, k = experts.shape
     with jax.named_scope("moe_dispatch"):
         flat = _masked_experts(experts, num_experts, valid)
         order = jnp.argsort(flat)
         counts = _group_sizes(flat, num_experts)
+
+    def over(n):
+        """The first ``n`` rows of the sorted order, summed back a token."""
+        rows = order[:n]
+        with jax.named_scope("moe_dispatch"):
+            xs = tokens[rows // k]
+        ys = grouped_fn(xs, counts)
+        with jax.named_scope("moe_dispatch"):
+            # rows past the last group belong to no expert: whatever the
+            # grouped matmul left there must not reach a token
+            w = jnp.where(flat[rows] < num_experts,
+                          weights.reshape(-1)[rows].astype(jnp.float32), 0.0)
+            return jnp.zeros((T, tokens.shape[1]), jnp.float32).at[
+                rows // k].add(ys.astype(jnp.float32) * w[:, None])
+
+    if cap and cap < T * k:
+        out = jax.lax.cond(jnp.sum(counts) <= cap, lambda: over(cap),
+                           lambda: over(T * k))
+        return out.astype(tokens.dtype), counts
+    with jax.named_scope("moe_dispatch"):
         xs = tokens[order // k]
     ys = grouped_fn(xs, counts)
     with jax.named_scope("moe_dispatch"):

@@ -17,7 +17,8 @@ import jax.numpy as jnp
 
 def mha_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=None, scale: Optional[float] = None,
                   causal_block: int = 1, window: int = 0):
-    """q: [B, S, H, Hd]; k,v: [B, S, KV, Hd] with KV | H → [B, S, H, Hd].
+    """q: [B, S, H, Hd]; k,v: [B, S, KV, Hd] with KV | H → [B, S, H, Hd]
+    (v may have a width of its own, Dv: the result's).
 
     GQA-native: when KV < H the query heads are reshaped into [KV, G] groups
     (query head h reads kv head ``h // G`` — ``jnp.repeat`` order, matching
@@ -63,4 +64,5 @@ def mha_attention(q, k, v, mask_bias=None, causal: bool = True, alibi_slopes=Non
 
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bcgqk,bkcd->bqcgd", probs, v)
-    return out.reshape(B, S, H, Hd)
+    # the values' own width (a latent-attention head's is not its keys')
+    return out.reshape(B, S, H, v.shape[-1])

@@ -18,6 +18,14 @@ Sites and their forms:
                       ``gather_einsum``
 ``paged_prefill``     ``flash`` | ``einsum``
 ``paged_block``       ``paged_kernel`` | ``gather_einsum``
+``latent_prefill``    ``flash`` | ``einsum`` (a latent-attention layer's
+                      prefill, the latent expanded to heads: the flash
+                      kernel with the values padded to the keys' width |
+                      the einsum with the two widths as they are)
+``latent_decode``     ``latent_kernel`` | ``gather_einsum`` (its decode
+                      step, the expansion absorbed: the Pallas kernel that
+                      reads each live latent row once | XLA's gather of the
+                      rows and two einsums)
 ``paged_decode_attention``  ``per_kv_head`` | ``block_diagonal`` (how the
                       paged kernel takes its products, from the static
                       shapes of a call: query rows a kv head, head size)

@@ -17,11 +17,14 @@ from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.fused_cross_entropy import fused_cross_entropy
 from deepspeed_tpu.ops.pallas.grouped_expert_mlp import grouped_expert_mlp
 from deepspeed_tpu.ops.pallas.kda_decode_update import kda_decode_update
+from deepspeed_tpu.ops.pallas.latent_decode_attention import \
+    latent_decode_attention
 from deepspeed_tpu.ops.pallas.mamba2_decode_update import \
     mamba2_decode_update
 from deepspeed_tpu.ops.pallas.paged_decode_attention import \
     paged_decode_attention
 
 __all__ = ["decode_attention", "flash_attention", "fused_cross_entropy",
-           "grouped_expert_mlp", "kda_decode_update", "mamba2_decode_update",
+           "grouped_expert_mlp", "kda_decode_update", "latent_decode_attention",
+           "mamba2_decode_update",
            "paged_decode_attention"]

@@ -166,7 +166,7 @@ def test_the_published_model_is_built_whole():
             cfg.logits_scaling, cfg.attn_scale) == (12.0, 0.22, 8.0, 0.015625)
     assert cfg.pos_embedding == "none" and cfg.tie_embeddings \
         and cfg.vocab_size == 100352 and cfg.norm_eps == 1e-5
-    assert cfg.cache_spec == {"kv": 4, "state": 36, "window": 0}
+    assert cfg.cache_spec == {"kv": 4, "state": 36, "window": 0, "latent": 0}
     ssm = shapes["layers"][0]["ssm"]
     assert ssm["w_in"].shape == (4, 2048, 8512) \
         and ssm["conv_w"].shape == (4, 4, 4352) \

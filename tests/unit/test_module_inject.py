@@ -512,3 +512,110 @@ def test_the_granite_hybrid_reference_is_the_published_model(seed, shape):
     got = np.asarray(ref.logits_rows(cfg, w, h.reshape(-1, h.shape[-1])))
     assert np.abs(want).max() > 0.3
     np.testing.assert_allclose(got.reshape(want.shape), want, rtol=0, atol=1e-4)
+
+
+# --------------------------------------------------------------------- #
+# The benchmark's plain reference of the LongCat-Flash layer against the
+# published code (``LongcatFlashForCausalLM``: ``LongcatFlashMLA``,
+# ``LongcatFlashTopkRouter``, ``LongcatFlashMoE``, ``LongcatFlashDecoderLayer``).
+# Here for the same reason as Granite's above; what the PROGRAM is held to
+# that reference by is in ``test_longcat_flash.py``.
+
+class _HFLongcatWeights:
+    """``LongcatFlashForCausalLM``'s state dict under the reference's names
+    (``reference/maps/longcat-flash-omni.json`` says what each is in the
+    program's tree): torch keeps ``[out, in]``; the experts are a module
+    list, stacked here."""
+
+    def __init__(self, hf_model, n_experts):
+        self.sd = {k: v.detach().numpy() for k, v in hf_model.state_dict().items()}
+        self.n_experts = n_experts
+
+    def top(self):
+        return {"wte": jnp.asarray(self.sd["model.embed_tokens.weight"]),
+                "head": jnp.asarray(self.sd["lm_head.weight"].T),
+                "lnf_g": jnp.asarray(self.sd["model.norm.weight"])}
+
+    def layer(self, l):
+        sd, p = self.sd, f"model.layers.{l}."
+
+        def sub(i):
+            a, m = p + f"self_attn.{i}.", p + f"mlps.{i}."
+            return {"ln1_g": sd[p + f"input_layernorm.{i}.weight"],
+                    "wq_a": sd[a + "q_a_proj.weight"].T,
+                    "q_g": sd[a + "q_a_layernorm.weight"],
+                    "wq_b": sd[a + "q_b_proj.weight"].T,
+                    "wkv_a": sd[a + "kv_a_proj_with_mqa.weight"].T,
+                    "kv_g": sd[a + "kv_a_layernorm.weight"],
+                    "wkv_b": sd[a + "kv_b_proj.weight"].T,
+                    "wo": sd[a + "o_proj.weight"].T,
+                    "ln2_g": sd[p + f"post_attention_layernorm.{i}.weight"],
+                    "w_gate": sd[m + "gate_proj.weight"].T,
+                    "w_up": sd[m + "up_proj.weight"].T,
+                    "w_down": sd[m + "down_proj.weight"].T}
+
+        stack = lambda name: np.stack([  # noqa: E731
+            sd[p + f"mlp.experts.{e}.{name}.weight"].T
+            for e in range(self.n_experts)])
+        moe = {"router": sd[p + "mlp.router.classifier.weight"].T,
+               "b_select": sd[p + "mlp.router.e_score_correction_bias"],
+               "e_gate": stack("gate_proj"), "e_up": stack("up_proj"),
+               "e_down": stack("down_proj")}
+        as_jnp = lambda d: {k: jnp.asarray(v) for k, v in d.items()}  # noqa: E731
+        return {"sub": [as_jnp(sub(0)), as_jnp(sub(1))], "moe": as_jnp(moe)}
+
+
+@pytest.mark.parametrize("seed,shape", [(0, (2, 21)), (1, (1, 40))])
+def test_the_longcat_flash_reference_is_the_published_model(seed, shape):
+    """Two published layers at toy widths (the ``longcat_flash`` ``tiny``
+    preset's sizes) with ALL 8 experts held (the published code has no
+    shares), 4 zero-compute experts, top-3, factor 6, both lora scales, a
+    random selection bias, norm scales off 1, every matrix 4 times the
+    initializer's 0.02 and the router 40 times (logits of deviation ~1:
+    a router that chooses): logits of ~1 agree to 1e-4, both float32."""
+    import sys
+    bench = os.path.join(os.path.dirname(__file__), "..", "..", "perfbench")
+    sys.path.insert(0, os.path.abspath(bench))
+    from reference import longcat_flash_decoder as ref
+    import correctness
+
+    toy = "rehearsal-longcat-flash-tiny"
+    with open(os.path.join(bench, "configs", toy + ".json")) as f:
+        cfg = correctness.reference_config(json.load(f), correctness.load_map(toy))
+    cfg["experts_held"] = cfg["n_experts"]
+    hc = transformers.LongcatFlashConfig(
+        vocab_size=512, hidden_size=cfg["d_model"], num_layers=cfg["n_layer"],
+        num_hidden_layers=2 * cfg["n_layer"],
+        num_attention_heads=cfg["n_head"], ffn_hidden_size=cfg["d_ff"],
+        q_lora_rank=cfg["q_lora_rank"], kv_lora_rank=cfg["kv_lora_rank"],
+        qk_nope_head_dim=cfg["qk_nope_head_dim"],
+        qk_rope_head_dim=cfg["qk_rope_head_dim"],
+        head_dim=cfg["qk_rope_head_dim"], v_head_dim=cfg["v_head_dim"],
+        moe_topk=cfg["experts_per_token"], n_routed_experts=cfg["n_experts"],
+        zero_expert_num=cfg["zero_experts"],
+        expert_ffn_hidden_size=cfg["d_expert"],
+        routed_scaling_factor=cfg["routed_scaling"],
+        rms_norm_eps=cfg["eps"], rope_theta=cfg["rope_theta"],
+        max_position_embeddings=1024, attention_bias=False,
+        tie_word_embeddings=False)
+    hc._attn_implementation = "eager"
+    torch.manual_seed(seed)
+    hf_model = transformers.LongcatFlashForCausalLM(hc).eval()
+    with torch.no_grad():
+        for name, p in hf_model.named_parameters():
+            if name.endswith("norm.weight") or "layernorm" in name:
+                p.add_(0.3 * torch.randn_like(p))
+            elif "router.classifier" in name:
+                p.mul_(40.0)
+            else:
+                p.mul_(4.0)
+        for layer in hf_model.model.layers:
+            layer.mlp.router.e_score_correction_bias.normal_(0.0, 0.05)
+    tok = torch.randint(0, 512, shape)
+    with torch.no_grad():
+        want = hf_model(input_ids=tok).logits.numpy()
+    w = _HFLongcatWeights(hf_model, cfg["n_experts"])
+    h = ref.final_hidden(cfg, w, jnp.asarray(tok.numpy().astype(np.int32)))
+    got = np.asarray(ref.logits_rows(cfg, w, h.reshape(-1, h.shape[-1])))
+    assert np.abs(want).max() > 0.3
+    np.testing.assert_allclose(got.reshape(want.shape), want, rtol=0, atol=1e-4)

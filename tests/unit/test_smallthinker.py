@@ -185,7 +185,7 @@ def test_the_preset_says_what_it_is():
     cut = get_model("smallthinker", "21b-a3b-12l")
     cfg = cut.config
     assert cfg.period == ("attention",) + ("window_attention",) * 3
-    assert cfg.cache_spec == {"kv": 3, "state": 0, "window": 9}
+    assert cfg.cache_spec == {"kv": 3, "state": 0, "window": 9, "latent": 0}
     assert cfg.attn_window == 4096 and cfg.ring_blocks(128) == 33
     assert cfg.takes_rope("window_attention") and not cfg.takes_rope("attention")
     assert cut.num_parameters == 5_561_448_960
@@ -198,7 +198,8 @@ def test_the_preset_says_what_it_is():
     # a stack without window layers builds the pools it built before
     other = get_model("olmoe", "tiny")
     assert set(jax.eval_shape(lambda: other.init_paged_cache(8, 16))) == {"k", "v"}
-    assert other.config.cache_spec == {"kv": 2, "state": 0, "window": 0}
+    assert other.config.cache_spec == {"kv": 2, "state": 0, "window": 0,
+                                       "latent": 0}
 
 
 # --------------------------------------------------------------------- #

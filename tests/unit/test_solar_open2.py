@@ -490,7 +490,7 @@ def test_the_published_model_counts_its_parameters():
                      moe=dict(num_experts=320, router_experts=None))
     assert abs(count(full) / 1e9 - 250.29) < 0.005
     cfg = cut.config
-    assert cfg.cache_spec == {"kv": 1, "state": 3, "window": 0} \
+    assert cfg.cache_spec == {"kv": 1, "state": 3, "window": 0, "latent": 0} \
         and cfg.n_periods == 1
     assert cfg.period == ("attention",) + ("linear_attention",) * 3
     pools = jax.eval_shape(lambda: cut.init_paged_cache(2064, 128, jnp.bfloat16,

@@ -664,6 +664,73 @@ def test_mamba2_decode_update_compiles_and_matches(tpu, live):
 
 
 @tpu_tier
+@pytest.mark.parametrize("rows,deepest", [(64, 3071), (5, 400)])
+def test_latent_decode_attention_compiles_and_matches(tpu, rows, deepest):
+    """The latent paged kernel at the LongCat cell's shapes (64 query heads
+    over rows of 512 + 64 values in 640 lanes, bf16, tables of 36 blocks,
+    rows at depths from 0 to ``deepest``) against the plain form in
+    float32: bf16 probabilities over up to 3,072 keys."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.pallas.latent_decode_attention import \
+        latent_decode_attention
+
+    rng = np.random.default_rng(rows)
+    H, R, row, bs, width = 64, 512, 640, 128, 36
+    pos = rng.integers(0, deepest + 1, size=rows)
+    pos[0], pos[-1] = 0, deepest
+    n_blocks = int((pos // bs + 1).sum()) + 1
+    cp = rng.standard_normal((n_blocks, bs, row)).astype(np.float32)
+    cp[:, :, 576:] = 0.0
+    q = rng.standard_normal((rows, H, row)).astype(np.float32)
+    bt = np.zeros((rows, width), np.int32)
+    free = list(rng.permutation(np.arange(1, n_blocks)))
+    for b in range(rows):
+        for j in range(pos[b] // bs + 1):
+            bt[b, j] = free.pop()
+    scale = 192 ** -0.5 / 8.0          # the rows are not normed here
+    qb, cb = jnp.asarray(q, jnp.bfloat16), jnp.asarray(cp, jnp.bfloat16)
+    got = latent_decode_attention(qb, cb, jnp.asarray(bt),
+                                  jnp.asarray(pos, jnp.int32), latent=R,
+                                  scale=scale, interpret=False)
+    c = cb[jnp.asarray(bt)].reshape(rows, -1, row).astype(jnp.float32)
+    s = jnp.einsum("bhr,bsr->bhs", qb.astype(jnp.float32), c,
+                   precision="highest") * scale
+    s = jnp.where(jnp.arange(c.shape[1])[None, None] <= pos[:, None, None],
+                  s, -jnp.inf)
+    want = jnp.einsum("bhs,bsr->bhr", jax.nn.softmax(s, axis=-1), c[..., :R],
+                      precision="highest")
+    err = float(jnp.abs(got.astype(jnp.float32) - want).max())
+    assert got.shape == (rows, H, R) and err < 0.03, err
+
+
+@tpu_tier
+def test_flash_with_keys_wider_than_values_compiles_and_matches(tpu):
+    """The latent prefill's call of the flash kernel: 64 heads of keys 192
+    wide, the values (128) padded to the keys' width, at the cell's longest
+    prompt bucket; against the einsum with the two widths as they are."""
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops.attention import mha_attention
+    from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+
+    rng = np.random.default_rng(7)
+    S, H = 3072, 64
+    q = jnp.asarray(rng.normal(size=(1, S, H, 192)), jnp.bfloat16)
+    k = jnp.asarray(rng.normal(size=(1, S, H, 192)), jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(1, S, H, 128)), jnp.bfloat16)
+    vp = jnp.pad(v, ((0, 0),) * 3 + ((0, 64),))
+    out = flash_attention(q, k, vp, causal=True, scale=192 ** -0.5,
+                          interpret=False)[..., :128]
+    want = mha_attention(q[:, :, :8], k[:, :, :8], v[:, :, :8], causal=True,
+                         scale=192 ** -0.5)
+    err = float(jnp.abs(out[:, :, :8].astype(jnp.float32)
+                        - want.astype(jnp.float32)).max())
+    assert err < 0.05, err
+
+
+@tpu_tier
 def test_solar_toy_logits_through_the_compiled_kda_kernel(tpu):
     """PERF.md section 7(a): the served check of ``solaropen2_serve_decode``
     sees only a wrong or lost state, so the kernel owes the unit tests'
