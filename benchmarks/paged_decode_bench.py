@@ -31,7 +31,12 @@ largest first, a digit each (``_step_blocks``: 421 at G 6); ``--parent``
 names another version of the kernel's module (a file) to time beside it:
 one that takes no position axis is handed a block's positions as query
 rows, a kv head's together, as the program did before the axis. Each line
-also checks the output against a float32 gather + softmax. The numbers
+also checks the output against a float32 gather + softmax (a row without a
+request, its table zeroed, yields zeros since PR 49 and is left out of the
+check) and reads the time beside what the version COPIES (``copied_blocks``:
+the live rows' blocks; a version from before PR 49, one that does not know
+the dummy block, also copies it once an idle row) and the time those bytes
+take at the chip's 819 GB/s (``copies_ms_at_819_gb_s``). The numbers
 behind ``_STREAM_VMEM_BYTES`` (PERF.md section 6, PR 27),
 ``_PER_KV_HEAD_MIN_ROWS`` and ``_step_blocks`` (PR 36). TPU only: the
 script refuses to print a time from another backend.
@@ -85,7 +90,14 @@ def draw_state(name, seed):
     for b in range(B):
         if pos[b]:
             bt[b, :live[b]] = [next(ids) for _ in range(live[b])]
-    return bt, pos.astype(np.int32), int(live.sum())
+    return bt, pos.astype(np.int32)
+
+
+def copied(mod, pos, live_rows):
+    """Blocks one call of ``mod`` copies a pool: every live row's, and
+    before PR 49 the dummy block for each idle row."""
+    rows = live_rows if hasattr(mod, "DUMMY_BLOCK") else slice(None)
+    return int((pos[rows] // BS + 1).sum())
 
 
 def reference(q, kp, vp, bt, pos):
@@ -161,13 +173,14 @@ def main():
     runs = {}
     for name in args.states:
         B, H, Hd, width, blocks, KV, Q = STATES[name]
-        bt, pos, live = draw_state(name, args.seed)
+        bt_np, pos_np = draw_state(name, args.seed)
+        live_rows = bt_np[:, 0] != 0
         key = jax.random.key(args.seed % (1 << 31))
         kq, kk, kv = jax.random.split(key, 3)
         q = jax.random.normal(kq, (LAYERS, B, Q, H, Hd), jnp.bfloat16)
         kp = jax.random.normal(kk, (blocks, BS, KV * Hd), jnp.bfloat16)
         vp = jax.random.normal(kv, (blocks, BS, KV * Hd), jnp.bfloat16)
-        bt, pos = jnp.asarray(bt), jnp.asarray(pos)
+        bt, pos = jnp.asarray(bt_np), jnp.asarray(pos_np)
         want = jax.jit(reference)(q[0], kp, vp, bt, pos)
         for label, mod, override in variants:
             if label == "per_kv_head" and ((Q * H // KV) % 8 or Hd % 128):
@@ -181,8 +194,10 @@ def main():
                 setattr(here, attr, fn)
             run = jax.jit(stack)
             out = jax.block_until_ready(run(q, kp, vp, bt, pos))
-            err = float(jnp.abs(out[0].astype(jnp.float32) - want).max())
-            runs[name, label] = (run, (q, kp, vp, bt, pos), live, err)
+            err = float(jnp.abs(out[0].astype(jnp.float32)
+                                - want)[live_rows].max())
+            runs[name, label] = (run, (q, kp, vp, bt, pos),
+                                 copied(mod, pos_np, live_rows), err)
     for attr, fn in chosen.items():
         setattr(here, attr, fn)
 
@@ -205,7 +220,7 @@ def main():
     if len(execs) != len(runs) * args.reps:
         sys.exit(f"{len(execs)} executions in the trace, {len(runs)} x "
                  f"{args.reps} were run: {sorted({p[0] for p in execs})}")
-    for i, ((name, label), (_, _, live, err)) in enumerate(runs.items()):
+    for i, ((name, label), (_, _, blocks, err)) in enumerate(runs.items()):
         B, H, Hd, width, _, KV, _ = STATES[name]
         took, calls = 0.0, 0
         for _, start, dur in execs[i * args.reps:(i + 1) * args.reps]:
@@ -218,9 +233,10 @@ def main():
         print(json.dumps({
             "state": name, "kernel": label, "device_ms_per_call": round(ms, 4),
             "trace_events_per_call": events / calls,
-            "rows": B, "table_entries": B * width, "live_blocks": live,
-            "us_per_live_block": round(ms * 1e3 / live, 3),
-            "live_block_gb_per_s": round(live * block_bytes / ms / 1e6, 1),
+            "rows": B, "table_entries": B * width, "copied_blocks": blocks,
+            "us_per_copied_block": round(ms * 1e3 / blocks, 3),
+            "copied_gb_per_s": round(blocks * block_bytes / ms / 1e6, 1),
+            "copies_ms_at_819_gb_s": round(blocks * block_bytes / 819e6, 4),
             "max_abs_err_from_float32": round(err, 5)}), flush=True)
 
 

@@ -2319,8 +2319,9 @@ class _ServeSession:
                 idx[i] = j
         if tel is not None:
             tel.decode_live_kv_tokens.inc(int(pos.sum()))
-            # an idle row reads the dummy block: one copy too
-            tel.decode_live_kv_blocks.inc(int((pos // self.bs + 1).sum()))
+            # an idle row is copied nothing
+            tel.decode_live_kv_blocks.inc(
+                int((pos[:len(reqs)] // self.bs + 1).sum()))
             if self._stateful:
                 tel.count_state(len(reqs))
             if self._window:
@@ -2365,7 +2366,8 @@ class _ServeSession:
         if tel is not None:
             # a row's pass reads its committed tokens and its open block
             tel.decode_live_kv_tokens.inc(int(pos.sum()) + Bg * len(reqs))
-            tel.decode_live_kv_blocks.inc(int(((pos + Bg - 1) // self.bs + 1).sum()))
+            tel.decode_live_kv_blocks.inc(
+                int(((pos[:len(reqs)] + Bg - 1) // self.bs + 1).sum()))
             tel.count_block(len(reqs), int(commit[:len(reqs)].sum()))
         prev = self._blk_dev if self._blk_dev is not None \
             else np.full((W, Bg), -1, np.int32)

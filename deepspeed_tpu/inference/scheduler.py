@@ -375,9 +375,9 @@ class ServingTelemetry:
     def decode_live_kv_blocks(self):
         return self.registry.counter(
             "serving/decode_live_kv_blocks",
-            "per fused decode step, the sum over ALL its rows (idle ones "
-            "read the dummy block) of pos // block_size + 1: the block "
-            "copies the paged kernel issues a layer and pool")
+            "per fused decode step, the sum over its live rows of pos // "
+            "block_size + 1: the block copies the paged kernel issues a "
+            "layer and pool (it copies nothing for an idle row)")
 
     def count_state(self, rows: int) -> None:
         """One fused decode step of a model with recurrent state over
@@ -401,10 +401,11 @@ class ServingTelemetry:
           "(decode_live_kv_tokens is what a full layer reads)"
           ).inc(int(np.minimum(pos[:rows] + 1, window).sum()))
         c("serving/decode_live_window_kv_blocks",
-          "per fused decode step, the sum over ALL its rows (idle ones read "
-          "the dummy block) of the blocks from the window's first to the "
-          "row's newest: the block copies the paged kernel issues a window "
-          "layer and pool").inc(int((pos // bs + 1 - first).sum()))
+          "per fused decode step, the sum over its live rows of the blocks "
+          "from the window's first to the row's newest: the block copies "
+          "the paged kernel issues a window layer and pool (it copies "
+          "nothing for an idle row)"
+          ).inc(int((pos // bs + 1 - first)[:rows].sum()))
 
     def count_state_reset(self) -> None:
         self.registry.counter(

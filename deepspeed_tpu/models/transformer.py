@@ -1096,7 +1096,7 @@ def _paged_shard_ok(mesh, H: int, KV: int, Hd: int, bs: int) -> bool:
 
 
 def _paged_decode_sharded(q1, kp, vp, block_tables, pos, pad_bias, slopes,
-                          mesh, scale=None):
+                          mesh, scale=None, dummy_block=0):
     """Paged decode-attention kernel under an SPMD mesh: shard_map over the
     KV-HEAD axis — q splits over ``tp`` by heads and the block pools' merged
     ``KV*Hd`` rows by whole kv heads, while block tables, positions and the
@@ -1122,8 +1122,9 @@ def _paged_decode_sharded(q1, kp, vp, block_tables, pos, pad_bias, slopes,
     qspec = P(None, head_axis, None)
     pspec = P(None, None, head_axis)
     operands = [q1, kp, vp, jnp.asarray(block_tables, jnp.int32),
-                jnp.asarray(pos, jnp.int32)]
-    specs = [qspec, pspec, pspec, P(), P()]
+                jnp.asarray(pos, jnp.int32),
+                jnp.asarray(dummy_block, jnp.int32)]
+    specs = [qspec, pspec, pspec, P(), P(), P()]
     if pad_bias is not None:
         operands.append(pad_bias.astype(jnp.float32))
         specs.append(P(None, None))
@@ -1133,12 +1134,13 @@ def _paged_decode_sharded(q1, kp, vp, block_tables, pos, pad_bias, slopes,
         operands.append(jnp.asarray(slopes, jnp.float32).reshape(H))
         specs.append(P(head_axis))
 
-    def inner(qs, kps, vps, bts, ps, *rest):
+    def inner(qs, kps, vps, bts, ps, dummy, *rest):
         rest = list(rest)
         ms = rest.pop(0) if pad_bias is not None else None
         ss = rest.pop(0) if slopes is not None else None
         return paged_decode_attention(qs, kps, vps, bts, ps, pad_bias=ms,
-                                      alibi_slopes=ss, scale=scale)
+                                      alibi_slopes=ss, scale=scale,
+                                      dummy_block=dummy)
 
     wrapped = shard_map(inner, mesh=mesh, in_specs=tuple(specs),
                         out_specs=qspec, check_vma=False)
@@ -1773,10 +1775,13 @@ def paged_real_rows(pools, slots):
 
 
 def _paged_decode_attention(cfg: TransformerConfig, x, lp, positions, pos,
-                            kp, vp, block_tables, pad_bias, window: int = 0):
+                            kp, vp, block_tables, pad_bias, window: int = 0,
+                            dummy_block=0):
     """One fused decode step over all running requests against the paged
     pools: x [B, 1, D] (one new token per request), pos [B] per-request
-    cache depths, kp/vp [blocks, bs, KV*Hd], block_tables [B, max_blocks].
+    cache depths, kp/vp [blocks, bs, KV*Hd], block_tables [B, max_blocks]
+    (``dummy_block``: what an inactive row's entries name, the layer's first
+    block of the stacked pools; the kernel copies nothing for such a row).
     ``window`` > 0 (a window layer): the tables are the requests' RINGS
     (logical block ``j`` at entry ``j % width``) and a row reads positions
     ``pos - window < j <= pos`` only.
@@ -1789,7 +1794,11 @@ def _paged_decode_attention(cfg: TransformerConfig, x, lp, positions, pos,
                            WINDOW_ATTENTION if window else ATTENTION)
 
     # each request's new k/v lands at its block-table slot; inactive rows
-    # carry a zeroed table and write into the dummy block
+    # carry a zeroed table and write into the dummy block. Their attention
+    # is the kernel's zeros (it copies nothing for them) or, from the gather
+    # + einsum form, the dummy block's first value: nothing reads either
+    # (``paged_real_rows`` keeps them from the experts, the sampler's pick
+    # for them is dropped)
     rows = jnp.arange(B)
     blk = pos // bs
     if window:
@@ -1806,7 +1815,8 @@ def _paged_decode_attention(cfg: TransformerConfig, x, lp, positions, pos,
             paged_decode_attention
         o = paged_decode_attention(q[:, 0], kp, vp, block_tables, pos,
                                    pad_bias=pad_bias, alibi_slopes=slopes,
-                                   scale=cfg.attn_scale, **band)
+                                   scale=cfg.attn_scale,
+                                   dummy_block=dummy_block, **band)
         form = "paged_kernel"
     elif not window:
         # SPMD mesh (a bare pallas_call is illegal): shard_map the kernel
@@ -1816,7 +1826,8 @@ def _paged_decode_attention(cfg: TransformerConfig, x, lp, positions, pos,
         if pmesh is not None:
             o = _paged_decode_sharded(q[:, 0], kp, vp, block_tables, pos,
                                       pad_bias, slopes, pmesh,
-                                      scale=cfg.attn_scale)
+                                      scale=cfg.attn_scale,
+                                      dummy_block=dummy_block)
             form = "paged_kernel_sharded"
     if o is not None:
         out = o.reshape(B, 1, H * cfg.head_dim)
@@ -2056,7 +2067,7 @@ def copy_paged_block(pools, src, dst):
 
 
 def _paged_verify_attention(cfg: TransformerConfig, x, lp, positions,
-                            kp, vp, block_tables, slots):
+                            kp, vp, block_tables, slots, dummy_block=0):
     """Verify attention over ALL running requests at once: each row's
     speculation window (its pending last token + proposed candidates) has
     its k/v scattered into the row's pool blocks at ``slots`` ([B, W] flat
@@ -2101,11 +2112,13 @@ def _paged_verify_attention(cfg: TransformerConfig, x, lp, positions,
                 o = paged_decode_attention(q[:, t], kp, vp, block_tables,
                                            positions[:, t],
                                            alibi_slopes=slopes,
-                                           scale=cfg.attn_scale)
+                                           scale=cfg.attn_scale,
+                                           dummy_block=dummy_block)
             else:
                 o = _paged_decode_sharded(q[:, t], kp, vp, block_tables,
                                           positions[:, t], None, slopes,
-                                          pmesh, scale=cfg.attn_scale)
+                                          pmesh, scale=cfg.attn_scale,
+                                          dummy_block=dummy_block)
             if o is None:
                 break          # off-envelope: the einsum core below
             outs.append(o)
@@ -2162,7 +2175,7 @@ def forward_paged_verify(cfg: TransformerConfig, params, tokens, pools,
         cfg, params, pools, x,
         lambda xn, lp, kp, vp, block0, slot0: _paged_verify_attention(
             cfg, xn, lp, positions, kp, vp, block_tables + block0,
-            slots + slot0),
+            slots + slot0, dummy_block=block0),
         mlp_fn)
     return cached_head(cfg, params, x), pools
 
@@ -2194,14 +2207,14 @@ def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
                             cfg.ring_blocks(pools["wk"].shape[2]))
         win_fn = lambda xn, lp, kp, vp, block0: _paged_decode_attention(  # noqa: E731
             cfg, xn, lp, positions, pos, kp, vp, rings + block0, None,
-            window=cfg.attn_window)
+            window=cfg.attn_window, dummy_block=block0)
 
     # the decode step derives its write slots from the (layer-offset) table
     x, pools, aux = _scan_paged_layers(
         cfg, params, pools, x,
         lambda xn, lp, kp, vp, block0, slot0: _paged_decode_attention(
             cfg, xn, lp, positions, pos, kp, vp, block_tables + block0,
-            pad_bias),
+            pad_bias, dummy_block=block0),
         mlp_fn, lin_fn, win_fn,
         _lat_fn(pools, lambda la, xn, lp, cp, block0, slot0: la.decode(
             cfg, xn, lp, positions, pos, cp, block_tables + block0)))
@@ -2210,7 +2223,7 @@ def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
 
 
 def _paged_block_attention(cfg: TransformerConfig, x, lp, positions, pos,
-                           kp, vp, block_tables):
+                           kp, vp, block_tables, dummy_block=0):
     """Attention of one block-generation pass over all running rows: x
     [W, Bg, D], the ``Bg = cfg.causal_block`` positions ``pos .. pos + Bg -
     1`` of each row's open block (``pos`` a multiple of Bg, so a block never
@@ -2240,7 +2253,8 @@ def _paged_block_attention(cfg: TransformerConfig, x, lp, positions, pos,
         # the block's positions are the kernel's own axis: one read of the
         # row's KV for all Bg of them
         o = paged_decode_attention(q, kp, vp, block_tables, pos + (Bg - 1),
-                                   scale=cfg.attn_scale)
+                                   scale=cfg.attn_scale,
+                                   dummy_block=dummy_block)
     if o is not None:
         form = "paged_kernel"
         out = o.reshape(W, Bg, H * Hd)
@@ -2281,7 +2295,8 @@ def forward_paged_block(cfg: TransformerConfig, params, tokens, pools,
     x, pools, aux = _scan_paged_layers(
         cfg, params, pools, x,
         lambda xn, lp, kp, vp, block0, slot0: _paged_block_attention(
-            cfg, xn, lp, positions, pos, kp, vp, block_tables + block0),
+            cfg, xn, lp, positions, pos, kp, vp, block_tables + block0,
+            dummy_block=block0),
         mlp_fn)
     logits = cached_head(cfg, params, x)
     return (logits, pools) if aux is None else (logits, pools, aux)

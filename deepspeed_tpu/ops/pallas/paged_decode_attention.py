@@ -10,25 +10,41 @@ the list of pool blocks holding its logical token positions. Continuous
 batching retires/admits requests per step, so physical KV placement is
 arbitrary; the kernel follows the table instead of a dense stride.
 
-Design: the kernel's work is (rows) x (a small fixed cost) + (live blocks)
-x (about a block's copy time); the table's width costs nothing, and the
-arithmetic follows the work it is given.
+Design: the kernel's work is (rows) x (a grid step) + (live rows) x (a
+small fixed cost) + (live blocks) x (about a block's copy time); the table's
+width costs nothing, and the arithmetic follows the work it is given.
 
 * grid ``(num_requests,)``, rows in order. The pools enter whole, where
   they lie in HBM (``pl.ANY``: no BlockSpec moves them); the block table and
-  ``pos`` are scalar-prefetched. Row ``b`` has ``pos[b] // block_size + 1``
-  live blocks, and a loop with that trip count walks its table in groups of
-  G blocks. A dead table entry costs no grid step, no iteration and no copy,
-  and what it names is never read (it may be anything);
+  ``pos`` are scalar-prefetched. A row tells the kernel whether it holds a
+  request: the FIRST table entry it would read (entry 0; under ``window``
+  the entry of its first live block) names a block of its own, or the
+  call's ``dummy_block``: ``DUMMY_BLOCK`` (0), which ``BlockAllocator``
+  never hands out and the engine leaves in an idle row's zeroed table (the
+  convention ``state_phases`` has for slot 0), moved with the table to the
+  layer's first block where the pools are stacked over layers (it rides
+  the prefetched table as a last row). A row without a request costs its grid
+  step and nothing else: no copy started or waited for, no query loaded, no
+  step of the softmax, and ZEROS for its output. A live row ``b`` has
+  ``pos[b] // block_size + 1`` live blocks, and a loop with that trip count
+  walks its table in groups of G blocks. A dead table entry costs no
+  iteration and no copy, and what it names is never read (past a live
+  row's first entry it may be anything);
 * each live block is brought in by the kernel's own ``make_async_copy``
   (pool block ``bt[b, j]`` -> a VMEM slot), double-buffered: the next
-  group's copies are in flight under this group's arithmetic, and the NEXT
-  ROW's first group under this row's last, so only the call's first copy
-  is waited for in the open. G comes from the shapes (``_group_blocks``):
-  groups of about 0.75 MB a pool, one block at 2,048 bf16 lanes, four at
-  768. A group is what is COPIED together; the arithmetic walks a group's
-  live blocks one at a time (a step of the running softmax over a block's
-  ``bs`` keys), so a row pays for the blocks it holds, not for G;
+  group's copies are in flight under this group's arithmetic, and under a
+  row's last group the NEXT ROW's first group where that row holds a
+  request (an idle row starts its successor's in its own grid step, the
+  call's first step row 0's), so with the live rows first, as the engine
+  packs them, only the call's first copy is waited for in the open; a live
+  row behind an idle one waits for its first group. G comes from the
+  shapes (``_group_blocks``): groups of about 0.75 MB a pool, one block at
+  2,048 bf16 lanes, four at 768. A group is what is COPIED together; the
+  arithmetic walks a group's live blocks one at a time (a step of the
+  running softmax over a block's ``bs`` keys), so a row pays for the blocks
+  it holds, not for G. A block is copied WHOLE, a row's newest too (its
+  live quarters alone were 2-5% of a call and kept out: PERF.md section 6,
+  PR 49);
 * a query is ``[B, H, Hd]`` or ``[B, Q, H, Hd]``: Q positions of a row that
   read the SAME keys (a generation block's), in one read of the row's KV.
   A kv head then has ``Q x P`` query rows (P = H / KV), and the products
@@ -84,6 +100,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.inference.block_allocator import DUMMY_BLOCK
 from deepspeed_tpu.ops import dispatch
 from deepspeed_tpu.ops.dispatch import resolve_interpret
 from deepspeed_tpu.utils.logging import warn_once
@@ -164,20 +181,33 @@ def _kernel(bt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, *rest, bs, G, steps,
     exact = None if bf16 else jax.lax.Precision.HIGHEST
 
     def first_block(row):
-        """The first logical block a row with a window reads."""
+        """The first logical block a row reads: 0 without a window."""
+        if not window:
+            return 0
         return jnp.maximum(pos_ref[row] - (window - 1), 0) // bs
 
     def live_blocks(row):
-        n = pos_ref[row] // bs + 1
-        if window:
-            n = n - first_block(row)
-        return jnp.minimum(n, n_blocks)
+        return jnp.minimum(pos_ref[row] // bs + 1 - first_block(row),
+                           n_blocks)
+
+    def alive(row):
+        """Whether ``row`` holds a request: the first table entry it would
+        read names a block of its own and not the dummy block, which the
+        table's last row (no request's) names."""
+        return bt_ref[row, first_block(row) % n_blocks] != bt_ref[nb, 0]
+
+    def first_copies(row, slot):
+        """Start the first group of ``row`` where there is such a row and it
+        holds a request: what the row before it does, under its last group
+        or, idle itself, in its own grid step."""
+        @pl.when(jnp.logical_and(row < nb, alive(jnp.minimum(row, nb - 1))))
+        def _():
+            copies(row, 0, slot)
 
     def copies(row, j, slot, wait=False):
         """Start, or wait for, the copies of group ``j`` of ``row``: one per
         LIVE block and pool. A dead table entry is never read."""
-        live = live_blocks(row)
-        first = first_block(row) if window else None
+        live, first = live_blocks(row), first_block(row)
         for i in range(G):
             blk = j * G + i
 
@@ -208,119 +238,133 @@ def _kernel(bt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, *rest, bs, G, steps,
             # the zeros off the diagonal are written once a call
             qs_ref[:] = jnp.zeros_like(qs_ref)
         slot_ref[0] = 0
-        copies(0, 0, 0)
+        first_copies(0, 0)
 
-    pos = pos_ref[b]
-    live = live_blocks(b)
-    # a row's logical block from its index among the row's live blocks
-    logical = (lambda blk, first=first_block(b): first + blk) if window \
-        else (lambda blk: blk)
-    n_groups = pl.cdiv(live, G)
-    slot0 = slot_ref[0]
+    holds_request = alive(b)
 
-    q32 = q_ref[0].astype(jnp.float32)                         # [Q, Hp, Hd]
-    for g in range(kv):
-        for t in range(nq):
-            r, c = place(g, t)
-            qs_ref[r, c] = q32[t, g * group:(g + 1) * group, :]
-    qs = qs_ref[:].astype(mxu)
-    acc_ref[:] = jnp.zeros_like(acc_ref)
-    if has_alibi:
-        slope = slope_ref[:]                                   # [rows, 1]
+    @pl.when(jnp.logical_not(holds_request))
+    def _():
+        # no copy of its own, no query, no step
+        o_ref[:] = jnp.zeros_like(o_ref)
+        first_copies(b + 1, slot_ref[0])
 
-    # the products' parts, (rows of the scratch, lanes of the pool's row):
-    # a kv head each, or ONE over everything against the block-diagonal query
-    parts = [(slice(g * per, (g + 1) * per), slice(g * hd, (g + 1) * hd))
-             for g in range(kv)] if per_head else [(slice(None), slice(None))]
-
-    def scores(k):
-        return jnp.concatenate([
-            jax.lax.dot_general(qs[r], k[:, c], (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32,
-                                precision=exact)
-            for r, c in parts], axis=0)                        # [rows, bs]
-
-    def values(p, v):
-        """``p @ v`` a part. Against bf16 values the float32 p goes as three
-        bf16 terms stacked as rows: one pass of v through the MXU, float32
-        sums, p exact."""
-        terms = _split3(p) if bf16 else (p,)
-        out = []
-        for r, c in parts:
-            pv = jnp.dot(jnp.concatenate([t[r] for t in terms], axis=0)
-                         .astype(mxu), v[:, c],
-                         preferred_element_type=jnp.float32, precision=exact)
-            n = pv.shape[0] // len(terms)
-            out.append(functools.reduce(
-                jnp.add, [pv[i * n:(i + 1) * n] for i in range(len(terms))]))
-        return jnp.concatenate(out, axis=0)                    # [rows, lanes]
-
-    def step(slot, blk, i, n, carry):
-        """One step of the running softmax over ``n`` blocks of the row from
-        block ``blk`` on, the ``i``-th and following of the group in
-        ``slot``: ``n * bs`` keys."""
-        m_prev, l_prev = carry
-        k = kbuf[slot, pl.ds(i, n)].reshape(n * bs, -1).astype(mxu)
-        v = vbuf[slot, pl.ds(i, n)].reshape(n * bs, -1).astype(mxu)
-        s = scores(k)                                          # [rows, n*bs]
-        # LOGICAL key positions: the table only moved the storage
-        kpos = logical(blk) * bs + jax.lax.broadcasted_iota(
-            jnp.int32, (1, n * bs), 1)
+    @pl.when(holds_request)
+    def _():
+        pos = pos_ref[b]
+        live = live_blocks(b)
+        first = first_block(b)
+        n_groups = pl.cdiv(live, G)
+        slot0 = slot_ref[0]
+        q32 = q_ref[0].astype(jnp.float32)                     # [Q, Hp, Hd]
+        for g in range(kv):
+            for t in range(nq):
+                r, c = place(g, t)
+                qs_ref[r, c] = q32[t, g * group:(g + 1) * group, :]
+        qs = qs_ref[:].astype(mxu)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
         if has_alibi:
-            s = s + slope * (kpos - pos).astype(jnp.float32)
-        if has_bias:
-            s = s + jnp.concatenate(
-                [bias_ref[0, pl.ds(blk + t, 1), :] for t in range(n)], axis=1)
-        keep = kpos <= pos
-        if window:
-            keep = jnp.logical_and(keep, kpos > pos - window)
-        s = jnp.where(keep, s, _NEG)
+            slope = slope_ref[:]                               # [rows, 1]
 
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                                 # float32
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + values(p, v)
-        return m_new, l_new
+        # the products' parts, (rows of the scratch, lanes of the pool's
+        # row): a kv head each, or ONE over everything against the
+        # block-diagonal query
+        parts = [(slice(g * per, (g + 1) * per),
+                  slice(g * hd, (g + 1) * hd)) for g in range(kv)] \
+            if per_head else [(slice(None), slice(None))]
 
-    def body(j, carry):
-        slot = (slot0 + j) % 2
+        def scores(k):
+            return jnp.concatenate([
+                jax.lax.dot_general(qs[r], k[:, c], (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32,
+                                    precision=exact)
+                for r, c in parts], axis=0)                    # [rows, bs]
 
-        # the next group's blocks fly under this group's arithmetic: this
-        # row's, or the next row's first group under this row's last
-        @pl.when(j + 1 < n_groups)
-        def _():
-            copies(b, j + 1, 1 - slot)
+        def values(p, v):
+            """``p @ v`` a part. Against bf16 values the float32 p goes as
+            three bf16 terms stacked as rows: one pass of v through the MXU,
+            float32 sums, p exact."""
+            terms = _split3(p) if bf16 else (p,)
+            out = []
+            for r, c in parts:
+                pv = jnp.dot(jnp.concatenate([t[r] for t in terms], axis=0)
+                             .astype(mxu), v[:, c],
+                             preferred_element_type=jnp.float32,
+                             precision=exact)
+                n = pv.shape[0] // len(terms)
+                out.append(functools.reduce(
+                    jnp.add,
+                    [pv[i * n:(i + 1) * n] for i in range(len(terms))]))
+            return jnp.concatenate(out, axis=0)                # [rows, lanes]
 
-        @pl.when(jnp.logical_and(j + 1 == n_groups, b + 1 < nb))
-        def _():
-            copies(b + 1, 0, 1 - slot)
+        def step(slot, blk, i, n, carry):
+            """One step of the running softmax over ``n`` blocks of the row
+            from block ``blk`` on, the ``i``-th and following of the group in
+            ``slot``: ``n * bs`` keys."""
+            m_prev, l_prev = carry
+            k = kbuf[slot, pl.ds(i, n)].reshape(n * bs, -1).astype(mxu)
+            v = vbuf[slot, pl.ds(i, n)].reshape(n * bs, -1).astype(mxu)
+            s = scores(k)                                      # [rows, n*bs]
+            # LOGICAL key positions: the table only moved the storage
+            kpos = (first + blk) * bs + jax.lax.broadcasted_iota(
+                jnp.int32, (1, n * bs), 1)
+            if has_alibi:
+                s = s + slope * (kpos - pos).astype(jnp.float32)
+            if has_bias:
+                s = s + jnp.concatenate(
+                    [bias_ref[0, pl.ds(blk + t, 1), :] for t in range(n)],
+                    axis=1)
+            keep = kpos <= pos
+            if window:
+                keep = jnp.logical_and(keep, kpos > pos - window)
+            s = jnp.where(keep, s, _NEG)
 
-        copies(b, j, slot, wait=True)
-        if G == 1:  # dslint: disable=DS004 (G is a static Python int)
-            return step(slot, j, 0, 1, carry)
-        # the group's LIVE blocks only, the most at a time first: the
-        # arithmetic follows the row, in as few steps as its blocks allow
-        done, left = 0, jnp.minimum(G, live - j * G)
-        for n in steps:
-            carry = jax.lax.fori_loop(
-                0, left // n,
-                lambda t, c, n=n, done=done: step(
-                    slot, j * G + done + t * n, done + t * n, n, c), carry)
-            done, left = done + left // n * n, left % n
-        return carry
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)                             # float32
+            l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_ref[:] = acc_ref[:] * alpha + values(p, v)
+            return m_new, l_new
 
-    _, l = jax.lax.fori_loop(
-        0, n_groups, body,
-        (jnp.full((rows, 1), _NEG, jnp.float32),
-         jnp.zeros((rows, 1), jnp.float32)))
-    slot_ref[0] = (slot0 + n_groups) % 2
+        def body(j, carry):
+            slot = (slot0 + j) % 2
 
-    for g in range(kv):
-        for t in range(nq):
-            r, c = place(g, t)
-            o_ref[0, t, g * group:(g + 1) * group, :] = \
-                (acc_ref[r, c] / l[r]).astype(o_ref.dtype)
+            # the next group's blocks fly under this group's arithmetic:
+            # this row's, or under this row's last the first group of the
+            # next row that holds a request
+            @pl.when(j + 1 < n_groups)
+            def _():
+                copies(b, j + 1, 1 - slot)
+
+            @pl.when(j + 1 == n_groups)
+            def _():
+                first_copies(b + 1, 1 - slot)
+
+            copies(b, j, slot, wait=True)
+            if G == 1:  # dslint: disable=DS004 (G is a static Python int)
+                return step(slot, j, 0, 1, carry)
+            # the group's LIVE blocks only, the most at a time first: the
+            # arithmetic follows the row, in as few steps as its blocks allow
+            done, left = 0, jnp.minimum(G, live - j * G)
+            for n in steps:
+                carry = jax.lax.fori_loop(
+                    0, left // n,
+                    lambda t, c, n=n, done=done: step(
+                        slot, j * G + done + t * n, done + t * n, n, c),
+                    carry)
+                done, left = done + left // n * n, left % n
+            return carry
+
+        _, l = jax.lax.fori_loop(
+            0, n_groups, body,
+            (jnp.full((rows, 1), _NEG, jnp.float32),
+             jnp.zeros((rows, 1), jnp.float32)))
+        slot_ref[0] = (slot0 + n_groups) % 2
+
+        for g in range(kv):
+            for t in range(nq):
+                r, c = place(g, t)
+                o_ref[0, t, g * group:(g + 1) * group, :] = \
+                    (acc_ref[r, c] / l[r]).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -329,8 +373,9 @@ def _kernel(bt_ref, pos_ref, q_ref, kp_hbm, vp_hbm, *rest, bs, G, steps,
 def _paged_call(q, kp, vp, bt, pos, bias, slopes, *, group, G, steps,
                 per_head, interpret, window=0):
     """q ``[B, Q, Hp, Hd]`` (pre-scaled, heads padded to a multiple of 8);
-    ``bias`` ``[B, n_blocks, bs]`` or None; ``slopes`` ``[rows, 1]`` in the
-    kernel's row order or None."""
+    ``bt`` ``[B + 1, n_blocks]``, its last row the block an idle row's table
+    names; ``bias`` ``[B, n_blocks, bs]`` or None; ``slopes`` ``[rows, 1]``
+    in the kernel's row order or None."""
     B, nq, hp, hd = q.shape
     bs, row = kp.shape[1:]
     # a kv head's rows over its own Hd lanes | every head over the pool's row
@@ -390,7 +435,7 @@ def paged_envelope_ok(H: int, KV: int, Hd: int, bs: int) -> bool:
 def paged_decode_attention(q, kp, vp, block_tables, pos, *, pad_bias=None,
                            alibi_slopes=None, scale: Optional[float] = None,
                            interpret: Optional[bool] = None,
-                           window: int = 0):
+                           window: int = 0, dummy_block=DUMMY_BLOCK):
     """Attention of each request's new positions against a PAGED KV cache.
 
     q ``[B, H, Hd]`` (one new token per running request, rope applied) or
@@ -402,8 +447,13 @@ def paged_decode_attention(q, kp, vp, block_tables, pos, *, pad_bias=None,
     is read off ``kp.shape[2] // Hd``), with each request's new k/v already
     written at its slots;
     ``block_tables`` ``[B, max_blocks]`` int32 pool block ids (logical block
-    ``j`` of request ``b`` lives in pool block ``block_tables[b, j]``; dead
-    tail entries may be anything — they are never read);
+    ``j`` of request ``b`` lives in pool block ``block_tables[b, j]``). The
+    FIRST entry a row would read says whether it holds a request:
+    ``dummy_block`` there (an int or a traced scalar; the allocator's
+    ``DUMMY_BLOCK`` 0, which it never hands out, and under pools stacked over
+    layers the layer's first block, where its block 0 lies) makes it an idle
+    row, which is copied nothing and returns zeros; a live row's dead tail
+    entries may be anything — they are never read;
     ``pos`` ``[B]`` int32 per-request 0-based position of the new token, the
     LAST of them where there are Q (request ``b`` attends logical positions
     ``<= pos[b]``; ALiBi distances are taken from it).
@@ -412,7 +462,8 @@ def paged_decode_attention(q, kp, vp, block_tables, pos, *, pad_bias=None,
     ``window`` W: request ``b`` attends ``pos[b] - W < kpos <= pos[b]`` and
     its table is a ring of at least ``W / block_size + 1`` entries (logical
     block ``j`` at ``block_tables[b, j % max_blocks]``; a table as long as
-    the request is one too); one query position a request, no ``pad_bias``.
+    the request is one too; the entry that says whether the row lives is
+    its first live block's); one query position a request, no ``pad_bias``.
     Returns q's shape. Which form the products took (module docstring) is
     static a shape and recorded: ``ops.dispatch`` site
     ``paged_decode_attention``, ``per_kv_head`` | ``block_diagonal``.
@@ -465,8 +516,12 @@ def paged_decode_attention(q, kp, vp, block_tables, pos, *, pad_bias=None,
             slopes = jnp.broadcast_to(jnp.pad(slopes, (0, pad_h)),
                                       (Q, H + pad_h))
         slopes = slopes.reshape(-1, 1)
-    out = _paged_call(qs, kp, vp,
-                      jnp.asarray(block_tables, jnp.int32),
+    # the dummy rides the table as one more row: no operand of its own, and
+    # where the caller derives both from a layer's offset, one fusion
+    tables = jnp.concatenate([
+        jnp.asarray(block_tables, jnp.int32),
+        jnp.full((1, n_blocks), dummy_block, jnp.int32)])
+    out = _paged_call(qs, kp, vp, tables,
                       jnp.asarray(pos, jnp.int32).reshape(B),
                       bias, slopes, group=P, G=G, steps=_step_blocks(G),
                       per_head=per_head, interpret=bool(interpret),

@@ -171,17 +171,24 @@ def test_paged_traced_pos_and_tables():
 # The streaming form: grid over rows, a row's LIVE blocks copied by the
 # kernel itself in groups of G, all heads of a group in one product.
 
-def paged_reference(q, kp, vp, bt, pos, bias=None, slopes=None):
+def paged_reference(q, kp, vp, bt, pos, bias=None, slopes=None, window=0):
     """float32 gather + softmax over each row's live blocks. Dead table
     entries are taken off before the gather: what they name is not read.
-    q [B, Q, H, Hd]: a row's Q positions read the same keys."""
+    q [B, Q, H, Hd]: a row's Q positions read the same keys. ``window``: the
+    band ``pos - window < kpos <= pos`` over a table as long as the row
+    (logical block j at entry j: a ring that never wrapped). A row whose
+    first live entry names the dummy block 0 holds no request: zeros."""
     if q.ndim == 4:
         return jax.vmap(lambda one: paged_reference(one, kp, vp, bt, pos,
-                                                    bias, slopes),
+                                                    bias, slopes, window),
                         in_axes=1, out_axes=1)(q)
     B, H, Hd = q.shape
     bs, KV = kp.shape[1], kp.shape[2] // Hd
-    live = jnp.arange(bt.shape[1])[None, :] <= (pos // bs)[:, None]
+    first = jnp.maximum(pos - (window - 1), 0) // bs if window \
+        else jnp.zeros_like(pos)
+    entries = jnp.arange(bt.shape[1])[None, :]
+    live = (entries >= first[:, None]) & (entries <= (pos // bs)[:, None])
+    idle = bt[jnp.arange(B), first] == 0
     bt = jnp.where(live, bt, 0)
     k, v = (jnp.repeat(pool[bt].reshape(B, -1, KV, Hd).astype(jnp.float32),
                        H // KV, axis=2) for pool in (kp, vp))
@@ -193,9 +200,13 @@ def paged_reference(q, kp, vp, bt, pos, bias=None, slopes=None):
         s = s + slopes[None, :, None] * (kpos - qpos)
     if bias is not None:
         s = s + bias[:, None, :]
-    s = jnp.where(kpos <= qpos, s, -1e30)
-    return jnp.einsum("bhs,bshd->bhd", jax.nn.softmax(s, axis=-1), v,
-                      precision="highest")
+    keep = kpos <= qpos
+    if window:
+        keep = keep & (kpos > qpos - window)
+    s = jnp.where(keep, s, -1e30)
+    out = jnp.einsum("bhs,bshd->bhd", jax.nn.softmax(s, axis=-1), v,
+                     precision="highest")
+    return jnp.where(idle[:, None, None], 0.0, out)
 
 
 def force_group(monkeypatch, G):
@@ -270,8 +281,8 @@ def test_pos_at_a_blocks_first_and_last_slot(G, monkeypatch):
 
 def test_shared_prefix_blocks_and_idle_rows_on_the_dummy_block(monkeypatch):
     """Rows 0 and 1 share their first two pool blocks (a cached prefix) and
-    part; rows 2 and 4 are idle: position 0 under a zeroed table, so they
-    read the dummy block 0, with a decoding row between them."""
+    part; rows 2 and 4 are idle: position 0 under a zeroed table, so their
+    first entry names the dummy block 0, with a decoding row between them."""
     force_group(monkeypatch, 2)
     r = np.random.default_rng(70)
     n_max, bs = 4, 128
@@ -283,10 +294,92 @@ def test_shared_prefix_blocks_and_idle_rows_on_the_dummy_block(monkeypatch):
     bt = jnp.asarray(bt)
     out = paged_decode_attention(q, kp, vp, bt, pos)
     assert max_err(out, paged_reference(q, kp, vp, bt, pos)) < 1e-5
-    # an idle row attends one token: its output is that token's value
-    np.testing.assert_allclose(
-        np.asarray(out[4]), np.asarray(vp[0, 0]).reshape(2, 1, 64).repeat(2, 1)
-        .reshape(4, 64), atol=1e-6)
+    # an idle row reads nothing, the dummy block neither: zeros
+    np.testing.assert_array_equal(np.asarray(out[jnp.asarray([2, 4])]), 0.0)
+
+
+# --------------------------------------------------------------------- #
+# Only live rows are copied: a row without a request (its first live table
+# entry names the dummy block 0) starts no copy and takes no step.
+
+IDLE_ROWS = {"start": [0], "end": [5], "middle": [2], "two_in_a_row": [2, 3],
+             "both_ends_and_a_pair": [0, 1, 4, 5], "all": list(range(6))}
+
+
+@pytest.mark.parametrize("kind", ["plain", "window", "positions",
+                                  "stacked_pools"])
+@pytest.mark.parametrize("G", [1, 2])
+@pytest.mark.parametrize("idle", sorted(IDLE_ROWS))
+def test_rows_without_a_request_read_nothing_and_yield_zeros(idle, G, kind,
+                                                             monkeypatch):
+    """Idle rows wherever they fall: their outputs are exactly zero, and the
+    chain of first copies passes over them (a live row's first group is
+    started by the live row before it, or by the call's first step), so every
+    live row equals the reference. The dummy block holds NaN in both pools:
+    a copy of it would reach an output."""
+    force_group(monkeypatch, G)
+    r = np.random.default_rng(130 + G)
+    n_max, bs, Q = 5, 128, 4
+    q, kp, vp, bt, pos = random_paged_case(r, 6, 2, 64, bs, n_max, group=2)
+    kw = {}
+    if kind == "positions":
+        q = jnp.asarray(r.normal(size=(6, Q, 4, 64)), jnp.float32)
+        pos = jnp.maximum(pos, Q - 1)
+    elif kind == "window":
+        kw["window"] = 200
+    kp, vp = kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan)
+    gone = jnp.asarray(IDLE_ROWS[idle])
+    bt, pos = bt.at[gone].set(0), pos.at[gone].set(0)
+    want = paged_reference(q, kp.at[0].set(0.0), vp.at[0].set(0.0), bt, pos,
+                           **kw)
+    if kind == "stacked_pools":
+        # the SECOND layer of pools stacked over two: its blocks, the dummy
+        # among them, lie one layer's blocks further on, and where the dummy
+        # lies is an operand of the program (the layer scan's index)
+        block0 = kp.shape[0]
+        kp, vp = (jnp.concatenate([jnp.ones_like(pool), pool])
+                  for pool in (kp, vp))
+        out = jax.jit(lambda bt, dummy: paged_decode_attention(
+            q, kp, vp, bt, pos, dummy_block=dummy))(bt + block0, block0)
+    else:
+        out = paged_decode_attention(q, kp, vp, bt, pos, **kw)
+    np.testing.assert_array_equal(np.asarray(out[gone]), 0.0)
+    assert max_err(out, want) < 1e-5
+
+
+POISON_SHAPES = {"per_kv_head": (2, 8, 128), "block_diagonal": (2, 2, 64)}
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 1e-5),
+                                       (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("form", sorted(POISON_SHAPES))
+@pytest.mark.parametrize("depth", [0, 31, 32, 63, 64, 95, 96, 127])
+def test_poisoned_dead_slots_are_not_read(depth, form, dtype, tol,
+                                          monkeypatch):
+    """Rows ``depth`` slots into their newest block, which is the first,
+    second and third of their table (either member of a group of 2), and an
+    idle row. NaN fills the whole dummy block in both pools and every KEY
+    slot past ``pos`` in a row's newest block: the output is finite and the
+    reference's on the clean pools. Before PR 49 the idle row copied the
+    dummy block and its NaN reached its output. (The VALUE slots past
+    ``pos`` stay pool rows: the newest block is copied whole, PERF.md
+    section 6, PR 49, and a dead value meets p = 0 as a product.)"""
+    force_group(monkeypatch, 2)
+    KV, P, Hd = POISON_SHAPES[form]
+    r = np.random.default_rng(140 + depth)
+    bs, n_max = 128, 3
+    q, kp, vp, bt, _ = random_paged_case(r, 4, KV, Hd, bs, n_max, dtype=dtype,
+                                         group=P)
+    pos = np.asarray([depth, bs + depth, 2 * bs + depth, 0], np.int32)
+    bt = np.array(bt)
+    bt[3] = 0
+    want = paged_reference(q, kp, vp, jnp.asarray(bt), jnp.asarray(pos))
+    kp, vp = kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan)
+    for b in range(3):
+        kp = kp.at[bt[b, pos[b] // bs], depth + 1:].set(jnp.nan)
+    out = paged_decode_attention(q, kp, vp, jnp.asarray(bt), jnp.asarray(pos))
+    assert out.dtype == dtype
+    assert max_err(out, want) < tol
 
 
 CELL_HEADS = [(32, 1, 64),      # OPT-1.3B: 32 heads of 64, a 2,048-lane row
@@ -429,6 +522,42 @@ def test_the_form_a_shape_took_is_recorded(Q, KV, P, Hd):
     forms = {k: n for k, n in dispatch.selected().items()
              if k.startswith("paged_decode_attention=")}
     assert forms == {f"paged_decode_attention={want}": 1}, forms
+
+
+def test_every_layers_dummy_block_is_told_to_the_kernel():
+    """Model level: the pools are stacked over layers, so a layer's tables
+    and its dummy block lie ``layer x blocks`` further on; the decode step
+    hands the kernel both. Every layer's dummy block holds NaN (but for the
+    slot the idle row's own token is written to): the idle row's logits stay
+    finite, so no layer's kernel copied its dummy block, and the live rows'
+    are what they are over clean pools."""
+    from deepspeed_tpu.models.causal_lm import CausalLM
+    from deepspeed_tpu.models.transformer import TransformerConfig
+
+    import deepspeed_tpu.comm as dist
+    dist.set_mesh(None)
+    cfg = TransformerConfig(vocab_size=128, max_seq=512, n_layer=3, n_head=4,
+                            n_kv_head=2, d_model=256, pos_embedding="rope",
+                            norm="rmsnorm", activation="swiglu", remat=False,
+                            attention_backend="flash")
+    model = CausalLM(cfg)
+    params = model.init_params(jax.random.key(0))
+    r = np.random.default_rng(29)
+    pools = jax.tree.map(
+        lambda a: jnp.asarray(r.normal(size=a.shape), a.dtype),
+        model.init_paged_cache(6, 128, dtype=jnp.float32))
+    bt = jnp.asarray([[3, 1], [0, 0], [2, 5]], jnp.int32)
+    pos = jnp.asarray([200, 0, 130], jnp.int32)
+    toks = jnp.asarray([[5], [0], [7]], jnp.int32)
+    dispatch.reset()
+    clean, _ = model.forward_paged_decode(params, toks, pools, bt, pos)
+    assert dispatch.selected().get("paged_decode=paged_kernel") == 1
+    poisoned = jax.tree.map(lambda a: a.at[:, 0].set(jnp.nan), pools)
+    got, _ = model.forward_paged_decode(params, toks, poisoned, bt, pos)
+    assert np.isfinite(np.asarray(got)).all()
+    live = jnp.asarray([0, 2])
+    np.testing.assert_allclose(np.asarray(got[live]), np.asarray(clean[live]),
+                               atol=1e-5)
 
 
 def test_forward_paged_matches_forward_cached():

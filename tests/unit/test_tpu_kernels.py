@@ -484,7 +484,87 @@ def test_paged_position_axis_compiles_and_matches(tpu, Q, H, KV, Hd, rows,
     ref = jax.vmap(lambda one: _paged_reference(
         one, kp, vp, jnp.asarray(bt), jnp.asarray(pos)), 1, 1)(q)
     assert out.shape == (rows, Q, H, Hd)
-    err = float(jnp.abs(out.astype(jnp.float32) - ref).max())
+    err = float(jnp.abs(out.astype(jnp.float32) - ref)[:-1].max())
+    assert np.isfinite(err) and err < 0.05, err
+    assert not np.asarray(out[-1].astype(jnp.float32)).any()    # idle: zeros
+
+
+# one row a depth into its newest block, idle rows (None) first, two in a
+# row in the middle, and last
+LIVE_ROWS = [None, 0, 31, 32, None, None, 63, 64, 95, 96, 127, None]
+
+
+@tpu_tier
+@pytest.mark.parametrize("Q,H,KV,Hd,window", [
+    (1, 32, 32, 64, 0),         # OPT-1.3B: 2,048-lane rows, one block a group
+    (1, 16, 16, 128, 0),        # OLMoE
+    (4, 32, 4, 128, 0),         # SDAR: 4 positions, groups of 6 blocks
+    (1, 28, 4, 128, 4096),      # SmallThinker's window layers: rings of 33
+])
+def test_paged_kernel_copies_only_live_rows(tpu, Q, H, KV, Hd, window):
+    """COMPILED, at the cells' shapes: rows without a request (their first
+    live table entry names the dummy block) first, last and two in a row
+    yield exactly zero and break no live row's chain of first copies (under
+    a window: in rings that have not wrapped and that have, several times).
+    NaN fills the whole dummy block in both pools and every KEY slot past
+    ``pos`` in a row's newest block; the output is finite and the float32
+    reference's on the clean pools. Before PR 49 an idle row copied the
+    dummy block and the NaN reached its output. The tables and the dummy
+    block are a stacked pool's second layer's (``dummy_block`` traced)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models import transformer as T
+    from deepspeed_tpu.ops.pallas.paged_decode_attention import \
+        paged_decode_attention
+
+    rng = np.random.default_rng(49 + H)
+    bs, rows = 128, len(LIVE_ROWS)
+    width = window // bs + 1 if window else 8
+    live = np.array([d is not None for d in LIVE_ROWS])
+    # whole blocks before the newest: none, a few, and past a ring's width
+    before = rng.permutation(np.arange(rows)) % (width - 1)
+    if window:
+        before = before * 5
+    before[1] = 0 if Q == 1 else 1
+    pos = np.where(live, before * bs + np.array(
+        [d or 0 for d in LIVE_ROWS]), 0).astype(np.int32)
+    slots = np.where(live, np.arange(1, rows + 1), 0)
+    bt = np.asarray(T.ring_tables(slots, width))    # row i: its own blocks
+    kp = jnp.asarray(rng.normal(size=(rows * width + 1, bs, KV * Hd)),
+                     jnp.bfloat16)
+    vp = jnp.asarray(rng.normal(size=kp.shape), jnp.bfloat16)
+    q = jnp.asarray(rng.normal(size=(rows, Q, H, Hd)), jnp.bfloat16)
+    if window:
+        cfg = T.TransformerConfig(n_head=H, n_kv_head=KV, head_size=Hd,
+                                  d_model=H * Hd, pos_embedding="none")
+        ref = T._grouped_cache_einsum(
+            cfg, q.astype(jnp.float32),
+            T._paged_gather(kp, bt, KV).astype(jnp.float32),
+            T._paged_gather(vp, bt, KV).astype(jnp.float32),
+            jnp.asarray(pos)[:, None], None,
+            kpos=T._ring_kpos(pos, width, bs), window=window
+        ).reshape(q.shape)
+    else:
+        ref = jax.vmap(lambda one: _paged_reference(
+            one, kp, vp, jnp.asarray(bt), jnp.asarray(pos)), 1, 1)(q)
+
+    kp, vp = kp.at[0].set(jnp.nan), vp.at[0].set(jnp.nan)
+    for b in np.flatnonzero(live):
+        kp = kp.at[bt[b, pos[b] // bs % width], pos[b] % bs + 1:].set(jnp.nan)
+    # as the programs hand them over: pools stacked over layers, this
+    # layer's blocks (its dummy among them) after another's, and where the
+    # dummy lies an operand (the layer scan's index times a layer's blocks)
+    block0 = 3
+    kp, vp = (jnp.concatenate([jnp.full_like(pool[:block0], jnp.nan), pool])
+              for pool in (kp, vp))
+    out = jax.jit(lambda bt, dummy: paged_decode_attention(
+        q[:, 0] if Q == 1 else q, kp, vp, bt, jnp.asarray(pos),
+        window=window, interpret=False, dummy_block=dummy))(
+            jnp.asarray(bt) + block0, block0)
+    out = np.asarray(out.astype(jnp.float32)).reshape(q.shape)
+    assert not out[~live].any()
+    err = np.abs(out - np.asarray(ref))[live].max()
     assert np.isfinite(err) and err < 0.05, err
 
 
