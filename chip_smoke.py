@@ -205,9 +205,14 @@ def train_leg(name, dry_run, n_dev, zero_stage, mesh_axes):
     if n_dev == 1 and not dry_run:
         # one chip: the Mosaic kernels, not the einsum / loss_chunk forms
         for want in ("attention=flash", "vocab_head=fused_ce",
+                     "fused_ce_fwd=lane_state",
                      "kernel/flash_attention=compiled",
                      "kernel/fused_cross_entropy=compiled"):
             assert want in forms, (want, forms)
+        # 32 x 1,024 tokens at D 768: the forward's own tile, four of the
+        # backward's
+        tiles = dispatch.details()["fused_ce_fwd=lane_state"]
+        assert tiles == "bt_fwd=1024 bt=256 bv=512", tiles
         assert not {"attention=einsum", "vocab_head=loss_chunk"} & set(forms)
         for pat in ("flash(_packed)?_fwd", "flash(_packed)?_dq",
                     "flash(_packed)?_dkv", "fused_ce_fwd", "fused_ce_dh",

@@ -5,8 +5,9 @@ elsewhere (the CPU tests need the reference forms), so a chip machine whose
 TPU failed to initialise would quietly run the CPU forms. Every dispatch
 site therefore reports its choice here. The Python that chooses runs while
 JAX traces, so one :func:`record` is one selection per compiled program:
-it bumps a process-wide counter and logs the first occurrence of each
-``(site, form)`` pair. ``chip_smoke.py`` asserts on :func:`selected`.
+it bumps a process-wide counter, keeps the selection's detail and logs the
+first occurrence of each ``(site, form)`` pair. ``chip_smoke.py`` asserts on
+:func:`selected` and :func:`details`.
 
 Sites and their forms:
 
@@ -51,6 +52,11 @@ Sites and their forms:
                       the pairs on or below the diagonal computed | whole,
                       where the call is not causal, ``bq != bk`` or the
                       block is no two whole chunks)
+``fused_ce_fwd``      ``lane_state`` (the fused cross-entropy forward: its
+                      softmax state kept a lane wide, reduced across lanes
+                      once a token tile; the detail holds the tiles it took
+                      from D, N, V and the dtype, ``bt_fwd=`` the forward's
+                      own beside the backward's ``bt=`` and ``bv=``)
 ``kernel/<name>``     ``compiled`` | ``interpret`` | ``jnp`` (one per Pallas
                       entry point; ``jnp`` = the kernel's plain-XLA twin)
 ====================  ====================================================
@@ -66,6 +72,7 @@ from deepspeed_tpu.utils.logging import logger
 
 _lock = threading.Lock()
 _counts: "collections.Counter[str]" = collections.Counter()
+_details: Dict[str, str] = {}
 
 
 def record(site: str, form: str, detail: str = "") -> None:
@@ -73,6 +80,7 @@ def record(site: str, form: str, detail: str = "") -> None:
     key = f"{site}={form}"
     with _lock:
         _counts[key] += 1
+        _details[key] = detail
         first = _counts[key] == 1
     if first:
         logger.info(f"dispatch: {key}" + (f" ({detail})" if detail else ""))
@@ -84,9 +92,17 @@ def selected() -> Dict[str, int]:
         return dict(_counts)
 
 
+def details() -> Dict[str, str]:
+    """``{"site=form": detail of its newest selection}`` since the last
+    :func:`reset` (the geometry a site chose, where it records one)."""
+    with _lock:
+        return dict(_details)
+
+
 def reset() -> None:
     with _lock:
         _counts.clear()
+        _details.clear()
 
 
 def on_tpu() -> bool:

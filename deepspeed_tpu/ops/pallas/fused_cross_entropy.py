@@ -6,9 +6,17 @@ running ``(max, logsumexp, label_logit)`` state in VMEM scratch per token
 tile, so HBM traffic is O(tokens·D + D·V) instead of O(tokens·V) — the TPU
 re-expression of the reference's fused softmax/cross-entropy kernels
 (``csrc/transformer/softmax_kernels.cu``, inference fused logits in
-``csrc/transformer/inference``). The backward recomputes each vocab block's
-logits on the fly from the saved logsumexp (no [tokens, V] residual either)
-and accumulates ``dh = (softmax - onehot) @ W_blk^T`` and
+``csrc/transformer/inference``). That state is a LANE wide — lane c of a row
+holds the max and the sum of the columns ``128 k + c`` seen so far — so a
+vocab step is the block's product plus whole-vreg elementwise work, and the
+lanes are reduced once a token tile, on the last step: a row reduction a
+block had kept the matrix unit waiting on the cross-lane unit (58% of the
+product's time at V 250,880). The forward also walks token tiles of its own,
+up to 1,024 rows where the backward's accumulators allow 128-256: it streams
+all of ``W`` once a token tile and has nothing else to keep in VMEM. The
+backward recomputes each vocab block's logits on the fly from the saved
+logsumexp (no [tokens, V] residual either) and accumulates
+``dh = (softmax - onehot) @ W_blk^T`` and
 ``dW_blk = h^T @ (softmax - onehot)`` per block.
 
 Like the flash kernels in this package, the streaming softmax runs in the
@@ -40,10 +48,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.dispatch import resolve_interpret
+from deepspeed_tpu.ops.dispatch import record, resolve_interpret
 
 _MASKED = -1e30  # pad-column bias: exp2 underflows to exactly 0
 _LOG2E = 1.4426950408889634
+_LANES = 128
 
 
 def _round8(n: int) -> int:
@@ -66,9 +75,12 @@ def _block_logits(h_ref, w_ref, b_ref):
 
 
 def _fwd_kernel(h_ref, w_ref, b_ref, lab_ref, nll_ref, lse_ref,
-                m_scr, l_scr, g_scr, *, bt, bv):
-    # grid (nt, nv), vocab innermost: the (m, l, gold) running state lives in
-    # VMEM scratch across vocab steps; outputs written once on the last step
+                m_scr, l_scr, g_scr, rel_scr, *, bv):
+    # grid (nt, nv), vocab innermost. The running (max, sum, gold) state is
+    # kept PER LANE in (bt, 128) scratch: lane c of a row holds the max and
+    # the sum over the columns 128 k + c seen so far, so a vocab step is the
+    # block's product and whole-vreg elementwise work; what crosses lanes
+    # runs once a token tile, on the last vocab step
     j = pl.program_id(1)
     nv = pl.num_programs(1)
 
@@ -77,34 +89,41 @@ def _fwd_kernel(h_ref, w_ref, b_ref, lab_ref, nll_ref, lse_ref,
         m_scr[:] = jnp.full_like(m_scr, _MASKED)
         l_scr[:] = jnp.zeros_like(l_scr)
         g_scr[:] = jnp.zeros_like(g_scr)
+        # a label's distance from each lane's first column: the one turn of
+        # the labels from lanes to sublanes a token tile needs
+        lane = jax.lax.broadcasted_iota(jnp.int32, rel_scr.shape, 1)
+        rel_scr[:] = lab_ref[0][:, None] - lane
 
     s = _block_logits(h_ref, w_ref, b_ref)
+    chunks = [s[:, k:k + _LANES] for k in range(0, bv, _LANES)]
 
-    # gold logit: each token's label falls in exactly one vocab block; a
-    # lane-wise compare-and-sum gathers it without any dynamic indexing
-    lab_local = lab_ref[0] - j * bv
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bt, bv), 1)
-    hit = cols == lab_local[:, None]
-    g_scr[:, :1] = g_scr[:, :1] + jnp.sum(jnp.where(hit, s, 0.0), axis=1,
-                                          keepdims=True)
-
-    m_prev = m_scr[:, :1]
-    l_prev = l_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp2(m_prev - m_new)
-    l_new = l_prev * alpha + jnp.sum(jnp.exp2(s - m_new), axis=1, keepdims=True)
-    m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-    l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
+    m_prev = m_scr[:]
+    m_new = functools.reduce(jnp.maximum, chunks, m_prev)
+    # gold logit: each token's label falls in exactly one column; lane c of
+    # chunk k holds column j bv + 128 k + c, a compare against the stored
+    # distance finds it without any dynamic indexing
+    rel = rel_scr[:]
+    l_new = l_scr[:] * jnp.exp2(m_prev - m_new)
+    g_new = g_scr[:]
+    for k, s_k in enumerate(chunks):
+        l_new = l_new + jnp.exp2(s_k - m_new)
+        g_new = g_new + jnp.where(rel == j * bv + k * _LANES, s_k, 0.0)
+    m_scr[:] = m_new
+    l_scr[:] = l_new
+    g_scr[:] = g_new
 
     @pl.when(j == nv - 1)
     def _():
-        # every vocab tile holds at least one unmasked column (pad < bv), so
-        # l >= exp2(max - max) = 1 and the log is safe
-        lse2 = m_scr[:, 0] + jnp.log2(l_scr[:, 0])
+        # a lane that saw pad columns alone still holds m = _MASKED, l = 0
+        # and drops out as exp2(_MASKED - m) = 0; some lane saw a real
+        # column, so l >= exp2(max - max) = 1 and the log is safe
+        m = jnp.max(m_new, axis=1, keepdims=True)
+        l = jnp.sum(l_new * jnp.exp2(m_new - m), axis=1)
+        lse2 = m[:, 0] + jnp.log2(l)
         lse_ref[0] = lse2
         # natural-log nll; masked/padded tokens get a finite garbage value
         # that the outer (differentiable) masked mean zeroes out
-        nll_ref[0] = (lse2 - g_scr[:, 0]) / _LOG2E
+        nll_ref[0] = (lse2 - jnp.sum(g_new, axis=1)) / _LOG2E
 
 
 def _softmax_minus_onehot(h_ref, w_ref, b_ref, lab_ref, lse_ref, coef_ref,
@@ -168,12 +187,68 @@ def _dw_kernel(h_ref, w_ref, b_ref, lab_ref, lse_ref, coef_ref,
 
 
 # --------------------------------------------------------------------- #
+# tile geometry, from the shapes alone
+
+#: the forward's token tile is the largest of these that the padded token
+#: count divides into and whose blocks fit _FWD_VMEM_BYTES
+_FWD_TILES = (1024, 512, 256, 128)
+_FWD_VMEM_BYTES = 24 << 20
+_SCOPED_VMEM_BYTES = 16 << 20   # what a kernel gets when it asks for nothing
+
+
+def _fwd_vmem_bytes(bt_fwd: int, D: int, bv: int, item: int) -> int:
+    """VMEM of one forward step: the (bt_fwd, D) token tile and the (D, bv)
+    weight block, two buffers each; the block's float32 logits and their
+    exponentials; the three states and the labels' distances."""
+    return (2 * (bt_fwd + bv) * D * item + 2 * bt_fwd * bv * 4
+            + 4 * bt_fwd * _LANES * 4)
+
+
+def _tiles(N: int, D: int, V: int, item: int, block_t: Optional[int] = None,
+           block_v: Optional[int] = None):
+    """``(bt, bt_fwd, bv, bv_dw)`` for N tokens of D features in ``item``
+    bytes against V columns."""
+    # token tile: whole (8-aligned) token set when it fits one block; else
+    # 128-aligned so the [1, Np] row blocks tile lanes legally. Large-D
+    # heads (7B-class, D >= 4096) take the finer defaults so the (bt, D)
+    # dh accumulator and (D, bv) weight blocks stay within VMEM.
+    bt = block_t or (128 if D >= 4096 else 256)
+    n8 = _round8(N)
+    bt = min(bt, n8)
+    if n8 > bt and bt % 128:
+        bt = -(-bt // 128) * 128
+    Np = -(-N // bt) * bt
+
+    # vocab tile: whole lanes, which the [1, Vp] bias/db rows need to tile
+    # and the forward's lane-wide state to mean a column a lane
+    bv = block_v or (256 if D >= 4096 else 512)
+    bv = -(-min(bv, V) // _LANES) * _LANES
+    # dw accumulator (D, bv_dw) f32 must fit VMEM comfortably at large D;
+    # halve while it exceeds ~4 MB. Every halving keeps bv_dw = bv / 2^k, a
+    # divisor of bv and hence of Vp (Vp = ceil(V/bv)·bv), so the dw grid
+    # always tiles exactly.
+    bv_dw = bv
+    while bv_dw % 2 == 0 and bv_dw > 128 and D * bv_dw * 4 > (4 << 20):
+        bv_dw //= 2
+
+    # the forward has no (bt, D) or (D, bv) float32 accumulator to tie its
+    # token tile to the backward's, and streams all of W once a token tile:
+    # it takes the largest tile that adds no padding and fits its budget
+    bt_fwd = next((t for t in _FWD_TILES
+                   if t > bt and t % bt == 0 and Np % t == 0
+                   and _fwd_vmem_bytes(t, D, bv, item) <= _FWD_VMEM_BYTES), bt)
+    return bt, bt_fwd, bv, bv_dw
+
+
+# --------------------------------------------------------------------- #
 # custom-VJP wrapper (one cached build per static geometry)
 
 
 @functools.lru_cache(maxsize=32)
-def _build(D: int, bt: int, bv: int, bv_dw: int, interpret: bool):
+def _build(D: int, bt: int, bt_fwd: int, bv: int, bv_dw: int, interpret: bool):
     """Per-token-nll CE with custom VJP on padded [Np, D] / [D, Vp] operands.
+    The forward walks token tiles of ``bt_fwd`` rows, both backward kernels
+    tiles of ``bt``.
 
     Returns ``nll [1, Np]`` f32; the (masked, differentiable) mean runs in
     XLA outside, so AD delivers each token's loss coefficient — including
@@ -181,8 +256,8 @@ def _build(D: int, bt: int, bv: int, bv_dw: int, interpret: bool):
     backward kernels consume directly.
     """
 
-    def h_spec():
-        return pl.BlockSpec((bt, D), lambda i, j: (i, 0))
+    def h_spec(rows=bt):
+        return pl.BlockSpec((rows, D), lambda i, j: (i, 0))
 
     def w_spec(bvx=bv):
         return pl.BlockSpec((D, bvx), lambda i, j: (0, j))
@@ -191,25 +266,29 @@ def _build(D: int, bt: int, bv: int, bv_dw: int, interpret: bool):
         # bias rides [1, Vp]
         return pl.BlockSpec((1, bvx), lambda i, j: (0, j))
 
-    def trow_spec():
+    def trow_spec(rows=bt):
         # labels / lse / coef / nll ride [1, Np]
-        return pl.BlockSpec((1, bt), lambda i, j: (0, i))
+        return pl.BlockSpec((1, rows), lambda i, j: (0, i))
 
     def fwd_call(hp, wp, bp, labp):
         Np, D = hp.shape
         Vp = wp.shape[1]
-        kernel = functools.partial(_fwd_kernel, bt=bt, bv=bv)
         nll, lse = pl.pallas_call(
-            kernel,
+            functools.partial(_fwd_kernel, bv=bv),
             name="fused_ce_fwd",
-            grid=(Np // bt, Vp // bv),
-            in_specs=[h_spec(), w_spec(), vrow_spec(), trow_spec()],
-            out_specs=[trow_spec(), trow_spec()],
+            grid=(Np // bt_fwd, Vp // bv),
+            in_specs=[h_spec(bt_fwd), w_spec(), vrow_spec(),
+                      trow_spec(bt_fwd)],
+            out_specs=[trow_spec(bt_fwd), trow_spec(bt_fwd)],
             out_shape=[jax.ShapeDtypeStruct((1, Np), jnp.float32),
                        jax.ShapeDtypeStruct((1, Np), jnp.float32)],
-            scratch_shapes=[pltpu.VMEM((bt, 128), jnp.float32),
-                            pltpu.VMEM((bt, 128), jnp.float32),
-                            pltpu.VMEM((bt, 128), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((bt_fwd, _LANES), jnp.float32),
+                            pltpu.VMEM((bt_fwd, _LANES), jnp.float32),
+                            pltpu.VMEM((bt_fwd, _LANES), jnp.float32),
+                            pltpu.VMEM((bt_fwd, _LANES), jnp.int32)],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=max(_SCOPED_VMEM_BYTES, _fwd_vmem_bytes(
+                    bt_fwd, D, bv, hp.dtype.itemsize) + (8 << 20))),
             interpret=interpret,
         )(hp, wp, bp, labp)
         return nll, lse
@@ -301,31 +380,10 @@ def fused_cross_entropy(h, w, labels, bias=None, valid=None,
         raise ValueError(f"h {h.shape} does not match labels {labels.shape}")
     interpret = resolve_interpret("fused_cross_entropy", interpret)
 
-    # token tile: whole (8-aligned) token set when it fits one block; else
-    # 128-aligned so the [1, Np] row blocks tile lanes legally. Large-D
-    # heads (7B-class, D >= 4096) take the finer defaults so the (bt, D)
-    # dh accumulator and (D, bv) weight blocks stay within VMEM.
-    bt = block_t or (128 if D >= 4096 else 256)
-    n8 = _round8(N)
-    bt = min(bt, n8)
-    if n8 > bt and bt % 128:
-        bt = -(-bt // 128) * 128
+    bt, bt_fwd, bv, bv_dw = _tiles(N, D, V, h.dtype.itemsize, block_t, block_v)
     Np = -(-N // bt) * bt
-
-    # vocab tile: same alignment rules on the [1, Vp] bias/db rows
-    bv = block_v or (256 if D >= 4096 else 512)
-    v8 = _round8(V)
-    bv = min(bv, v8)
-    if v8 > bv and bv % 128:
-        bv = -(-bv // 128) * 128
     Vp = -(-V // bv) * bv
-    # dw accumulator (D, bv_dw) f32 must fit VMEM comfortably at large D;
-    # halve while it exceeds ~4 MB. Every halving keeps bv_dw = bv / 2^k, a
-    # divisor of bv and hence of Vp (Vp = ceil(V/bv)·bv), so the dw grid
-    # always tiles exactly.
-    bv_dw = bv
-    while bv_dw % 2 == 0 and bv_dw > 128 and D * bv_dw * 4 > (4 << 20):
-        bv_dw //= 2
+    record("fused_ce_fwd", "lane_state", f"bt_fwd={bt_fwd} bt={bt} bv={bv}")
 
     hp = h.reshape(N, D)
     if w.dtype != hp.dtype:
@@ -347,7 +405,7 @@ def fused_cross_entropy(h, w, labels, bias=None, valid=None,
         # pad columns get a -1e30 bias: zero probability in fwd AND bwd
         b = jnp.pad(b, (0, Vp - V), constant_values=_MASKED)
 
-    ce_nll = _build(D, bt, bv, bv_dw, bool(interpret))
+    ce_nll = _build(D, bt, bt_fwd, bv, bv_dw, bool(interpret))
     nll = ce_nll(hp, w, b[None, :], labp[None, :])  # [1, Np]
     # masked mean OUTSIDE the custom_vjp: AD turns it into the per-token
     # backward coefficient (0 on masked/padded tokens, 1/count elsewhere)

@@ -519,3 +519,36 @@ def test_window_kernels_compile_for_v5e_at_real_widths(one_v5e, monkeypatch):
         q, k, v, causal=True, window=W, interpret=False)).lower(
         q, kv, kv).compile()
     assert "flash_fwd_band" in compiled.as_text()
+
+
+@pytest.mark.parametrize("tokens,D,V,tiles", [
+    (4 * 2048, 1024, 250880, "bt_fwd=1024 bt=256 bv=512"),   # the BLOOM cell's
+    (32 * 1024, 768, 50257, "bt_fwd=1024 bt=256 bv=512"),    # chip_smoke's GPT-2
+    (4096, 4096, 32000, "bt_fwd=1024 bt=128 bv=256"),        # a 7B-class head
+])
+def test_fused_ce_kernels_compile_for_v5e_at_real_widths(tokens, D, V, tiles,
+                                                         one_v5e, monkeypatch):
+    """Mosaic takes the fused cross-entropy forward with the token tile it
+    chooses from the shapes (what interpret mode cannot see: the VMEM a
+    (1024, D) tile, two weight blocks and the block's float32 logits take,
+    asked for through ``vmem_limit_bytes``) and both backward kernels
+    beside it; the program holds no ``[tokens, V]`` temporary."""
+    from deepspeed_tpu.ops import dispatch
+    from deepspeed_tpu.ops.pallas.fused_cross_entropy import fused_cross_entropy
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    dispatch.reset()
+    compiled = jax.jit(jax.value_and_grad(
+        lambda h, w, labels: fused_cross_entropy(h, w, labels, interpret=False),
+        argnums=(0, 1))).lower(
+        sds((tokens, D)), sds((D, V)), sds((tokens,), I32)).compile()
+    assert dispatch.details()["fused_ce_fwd=lane_state"] == tiles
+    text = compiled.as_text()
+    for kernel in ("fused_ce_fwd", "fused_ce_dh", "fused_ce_dw"):
+        assert kernel in text, kernel
+    # padding W's and dW's columns (GPT-2's 50,257 -> 50,688) is the one
+    # large temporary a shape needs: far under the logits' tokens x V x 4
+    assert compiled.memory_analysis().temp_size_in_bytes < tokens * V

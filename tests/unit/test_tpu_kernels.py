@@ -1121,6 +1121,99 @@ class TestFusedCrossEntropy:
             err = float(jnp.abs((a - r).astype(jnp.float32)).max())
             assert err < 2e-2, (name, err)
 
+    def _value_and_grads_match(self, h, w, b, labels, valid, tol=1e-5, **blocks):
+        """Loss and all three gradients against ``_ref``, each within ``tol``
+        of the reference's own scale."""
+        import jax
+        import jax.numpy as jnp
+        from deepspeed_tpu.ops.pallas.fused_cross_entropy import fused_cross_entropy
+
+        got = jax.value_and_grad(lambda h, w, b: fused_cross_entropy(
+            h, w, labels, bias=b, valid=valid, interpret=True, **blocks),
+            argnums=(0, 1, 2))(h, w, b)
+        want = jax.value_and_grad(lambda h, w, b: self._ref(h, w, b, labels, valid),
+                                  argnums=(0, 1, 2))(h, w, b)
+        for name, a, r in zip("loss h w bias".split(), jax.tree.leaves(got),
+                              jax.tree.leaves(want)):
+            err = float(jnp.abs(a - r).max())
+            assert err < tol * max(1.0, float(jnp.abs(r).max())), (name, err)
+
+    @pytest.mark.parametrize("tokens,bt_fwd", [
+        (1500, 512),   # Np 1,536 = 12 x 128 = 3 x 512: a tile of its own
+        (1100, 128),   # Np 1,152 = 9 x 128: nothing larger divides it
+    ])
+    def test_forward_tile_of_its_own(self, tokens, bt_fwd):
+        """The forward walks larger token tiles than the backward where the
+        padded token count allows: value and gradients are the reference's,
+        the pad rows of the last tile included."""
+        import jax.numpy as jnp
+        from deepspeed_tpu.ops import dispatch
+        from deepspeed_tpu.ops.pallas.fused_cross_entropy import _tiles
+
+        assert _tiles(tokens, 64, 700, 4, block_t=128) == (128, bt_fwd, 512, 512)
+        h, w, b, labels, valid = self._case(6, 1, tokens, 64, 700, jnp.float32)
+        dispatch.reset()
+        self._value_and_grads_match(h, w, b, labels, valid, block_t=128)
+        assert dispatch.details()["fused_ce_fwd=lane_state"] == (
+            f"bt_fwd={bt_fwd} bt=128 bv=512")
+
+    @pytest.mark.parametrize("order", ["rising", "falling"])
+    def test_row_maximum_moves_between_lanes_and_blocks(self, order):
+        """Columns 80 apart in logit, by vocab block (a block of large
+        columns after blocks of small ones, and the reverse) and by lane
+        inside a block: every lane's running maximum is rescaled on the way
+        and the lanes meet at different maxima on the last step."""
+        import jax.numpy as jnp
+
+        h, w, b, labels, valid = self._case(7, 2, 150, 64, 1200, jnp.float32)
+        cols = np.arange(1200)
+        block, lane = cols // 256, cols % 128
+        steps = block if order == "rising" else block.max() - block
+        shift = 20.0 * steps + np.where(lane < 64, 0.0, -40.0) * (steps % 2)
+        assert shift.max() - shift.min() >= 80
+        self._value_and_grads_match(h, w, b + jnp.asarray(shift, jnp.float32),
+                                    labels, valid, block_v=256)
+
+    @pytest.mark.parametrize("V", [
+        40,    # one block of 128: lanes 40-127 see pad columns alone
+        520,   # the last block of 512 holds 8 real columns
+    ])
+    def test_labels_at_the_edges_of_the_vocabulary(self, V):
+        """Labels in the first column, a block's last and next block's first
+        column, and the last real column (the one before the pad)."""
+        import jax.numpy as jnp
+
+        h, w, b, _, valid = self._case(8, 2, 60, 32, V, jnp.float32)
+        edges = np.asarray(sorted({0, min(511, V - 2), min(512, V - 1), V - 1}))
+        labels = jnp.asarray(edges[np.arange(120) % len(edges)].reshape(2, 60),
+                             jnp.int32)
+        self._value_and_grads_match(h, w, b, labels, valid)
+
+    @pytest.mark.parametrize("tokens,D,V,dtype,tiles", [
+        (4 * 2048, 1024, 250880, "bfloat16", "bt_fwd=1024 bt=256 bv=512"),
+        (32 * 1024, 768, 50257, "bfloat16", "bt_fwd=1024 bt=256 bv=512"),
+        (4096, 4096, 32000, "bfloat16", "bt_fwd=1024 bt=128 bv=256"),
+        (100, 128, 517, "float32", "bt_fwd=104 bt=104 bv=512"),
+    ])
+    def test_dispatch_site_reports_the_forwards_form_and_tile(
+            self, tokens, D, V, dtype, tiles):
+        """D, N, V and the dtype decide the forward's tile; the site says
+        which was taken (shapes only: no kernel runs)."""
+        import jax
+        import jax.numpy as jnp
+        from deepspeed_tpu.ops import dispatch
+        from deepspeed_tpu.ops.pallas.fused_cross_entropy import fused_cross_entropy
+
+        dispatch.reset()
+        out = jax.eval_shape(
+            lambda h, w, labels: fused_cross_entropy(h, w, labels, interpret=True),
+            jax.ShapeDtypeStruct((tokens, D), jnp.dtype(dtype)),
+            jax.ShapeDtypeStruct((D, V), jnp.dtype(dtype)),
+            jax.ShapeDtypeStruct((tokens,), jnp.int32))
+        assert out.shape == () and out.dtype == jnp.float32
+        assert dispatch.selected()["fused_ce_fwd=lane_state"] == 1
+        assert dispatch.details()["fused_ce_fwd=lane_state"] == tiles
+
     def test_masked_labels_and_empty_mask(self):
         import jax.numpy as jnp
         from deepspeed_tpu.ops.pallas.fused_cross_entropy import fused_cross_entropy
@@ -1237,6 +1330,9 @@ def test_fused_cross_entropy_compiles_and_matches(tpu):
     for seed, (B, S, D, V), dtype, tol in [
         (0, (2, 50, 128, 517), jnp.float32, 1e-4),     # ragged bt=104-ish
         (1, (2, 300, 256, 1200), jnp.bfloat16, 2e-2),  # multi-tile bf16
+        # a 7B-class head: the forward's (1024, 4096) token tile beside the
+        # backward's 128 rows, 24 MB of VMEM asked for
+        (2, (2, 1024, 4096, 32000), jnp.bfloat16, 2e-2),
     ]:
         h, w, b, labels, valid = TestFusedCrossEntropy._case(seed, B, S, D, V,
                                                              dtype)
@@ -1282,6 +1378,51 @@ def test_fused_cross_entropy_gpt2_width(tpu):
         lambda h, w: chunked_vocab_ce(h, w, 0, labels, valid, 2048),
         argnums=(0, 1)))
     lf, gf = fused(h, w)
+    lr, gr = ref(h, w)
+    assert np.isfinite(float(lf))
+    assert abs(float(lf) - float(lr)) < 2e-2, (float(lf), float(lr))
+    for name, a, r in zip(("dh", "dw"), gf, gr):
+        assert a.shape == r.shape and a.dtype == r.dtype, name
+        a32, r32 = a.astype(jnp.float32), r.astype(jnp.float32)
+        assert bool(jnp.isfinite(a32).all()), name
+        # both sides are bf16 pipelines: bound the drift by the gradient's
+        # own scale (elementwise bf16 rounding is ~0.4% of magnitude)
+        tol = 0.03 * float(jnp.abs(r32).max())
+        err = float(jnp.abs(a32 - r32).max())
+        assert err < tol, (name, err, tol)
+
+
+@tpu_tier
+def test_fused_cross_entropy_bloom_width(tpu):
+    """The fused CE kernels at the shape ``bloom560m_train_1chip`` runs
+    them, the one cell of the benchmark that does: D 1,024, V 250,880,
+    N = 4 x 2,048 bf16 tokens, a tenth masked; forward (token tiles of
+    1,024 rows, four of the backward's) and gradients, vs the XLA
+    ``loss_chunk`` streaming path at the GPT-2 width's tolerances."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.transformer import chunked_vocab_ce
+    from deepspeed_tpu.ops import dispatch
+    from deepspeed_tpu.ops.pallas.fused_cross_entropy import fused_cross_entropy
+
+    B, S, D, V = 4, 2048, 1024, 250880
+    rng = np.random.default_rng(12)
+    h = jnp.asarray(rng.normal(size=(B, S, D)), jnp.bfloat16)
+    w = jnp.asarray(rng.normal(size=(D, V)) * 0.02, jnp.bfloat16)
+    labels = jnp.asarray(rng.integers(0, V, size=(B, S)), jnp.int32)
+    valid = jnp.asarray(rng.random((B, S)) > 0.1)
+
+    fused = jax.jit(jax.value_and_grad(
+        lambda h, w: fused_cross_entropy(h, w, labels, valid=valid,
+                                         interpret=False), argnums=(0, 1)))
+    ref = jax.jit(jax.value_and_grad(
+        lambda h, w: chunked_vocab_ce(h, w, 0, labels, valid, 2048),
+        argnums=(0, 1)))
+    dispatch.reset()
+    lf, gf = fused(h, w)
+    assert dispatch.details()["fused_ce_fwd=lane_state"] == (
+        "bt_fwd=1024 bt=256 bv=512")
     lr, gr = ref(h, w)
     assert np.isfinite(float(lf))
     assert abs(float(lf) - float(lr)) < 2e-2, (float(lf), float(lr))
