@@ -32,8 +32,9 @@ most prompts is a mechanism the cell's ``correct`` cannot see. The faults:
   a fault it has to refuse.
 
 ``--init`` tries another seeded init than the preset's (``init_std``,
-``embed_init_std``, ``router_init_scale``), one engine after the other: a
-trial, not the cell's.
+``embed_init_std``, ``router_init_scale``; ``expert_bias_std`` scales what
+the harness drew for a sigmoid router's selection bias), one engine after
+the other: a trial, not the cell's.
 
 **The logits** (``--logits``). ``--rows`` requests drawn from the cell's
 traffic are prefilled into one pool at the cell's sizes and decoded together
@@ -120,13 +121,39 @@ def planted(control):
             setattr(mod, name, fn)
 
 
+#: the deviation ``perfbench/weights.py`` draws every bias at
+HARNESS_BIAS_STD = 0.02
+
+
+def trial_init(init):
+    """``K=V,K=V`` -> (overrides of the preset, those of them its ``moe``
+    takes). ``expert_bias_std`` is no key of either: ``trial_params``."""
+    over = {k: float(v) for k, v in
+            (kv.split("=") for kv in init.split(",") if kv)}
+    over.pop("expert_bias_std", None)
+    moe = {k: over.pop(k) for k in ("router_init_scale",) if k in over}
+    return over, moe
+
+
+def trial_params(init, params):
+    """``expert_bias_std=S``: the sigmoid router's selection bias (leaves
+    ``b_select``, which the harness draws at 0.02) scaled to deviation S."""
+    import jax
+    std = dict(kv.split("=") for kv in init.split(",") if kv).get(
+        "expert_bias_std")
+    if std is None:
+        return params
+    k = float(std) / HARNESS_BIAS_STD
+    return jax.tree_util.tree_map_with_path(
+        lambda path, a: (a * k).astype(a.dtype)
+        if getattr(path[-1], "key", None) == "b_select" else a, params)
+
+
 def build(config, init, control):
     """(the sound model under the trial's init, the model with ``control``
     planted)."""
     from build_model import build_model
-    over = {k: float(v) for k, v in
-            (kv.split("=") for kv in init.split(",") if kv)}
-    moe = {k: over.pop(k) for k in ("router_init_scale",) if k in over}
+    over, moe = trial_init(init)
     sound = build_model(config["preset"], **over, **({"moe": moe} if moe else {}))
     if control == "zero_experts_nothing":
         # the router keeps every output; those past the real ones are no
@@ -145,7 +172,14 @@ def build(config, init, control):
     return sound, model
 
 
-def controls(args, config, name_map, name):
+def controls(args, config, name_map, name, traffic=TRAFFIC, build=None,
+             planted=None, sound_extra=None):
+    """The loop of every trial and control. Another configuration's tool
+    (``lfm2_check_controls.py``) hands its own ``traffic``, ``build`` and
+    ``planted``, and ``sound_extra(ref, cfg, weights, prompts, served)``:
+    more keys for the sound control's line."""
+    build = build or globals()["build"]
+    planted = planted or globals()["planted"]
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -159,7 +193,7 @@ def controls(args, config, name_map, name):
     cfg = correctness.reference_config(config, name_map)
     ref = correctness.load_reference(name_map)
     serve = config["assumed"]["serve"]
-    spec = traffic_mod.load(TRAFFIC)
+    spec = traffic_mod.load(traffic)
     want = int(spec["check"]["tokens"])
     for trial in args.init:
         sound, _ = build(config, trial, "sound")
@@ -171,15 +205,18 @@ def controls(args, config, name_map, name):
         prompts = [np.random.default_rng([args.seed, 11, i]).integers(
             0, mcfg.vocab_size, size=n).astype(np.int32)
             for i in range(args.pairs) for n in lens]
-        true_params = make_params(sound, args.seed, jnp.bfloat16,
-                                  jax.devices()[:1])
+        true_params = trial_params(trial, make_params(
+            sound, args.seed, jnp.bfloat16, jax.devices()[:1]))
         weights = ref.Weights(true_params, name_map)
         for control in args.controls:
             t0 = time.perf_counter()
             _, model = build(config, trial, control)
+            # a control may serve a stack without one of the tree's groups
+            own = set(jax.eval_shape(model.init_params, jax.random.key(0)))
             with planted(control):
                 engine = deepspeed_tpu.init_inference(
-                    model, params=true_params, dtype="bf16",
+                    model, dtype="bf16",
+                    params={k: v for k, v in true_params.items() if k in own},
                     serving={"block_size": int(serve["block_size"]),
                              "max_running": int(serve["max_running"]),
                              "max_num_blocks": int(serve["max_num_blocks"])})
@@ -198,8 +235,10 @@ def controls(args, config, name_map, name):
                    for p, s in zip(prompts, served)]
             gaps = np.array([g["worst_gap_bf16_steps"] for g in got])
             refused = int(sum(not g["ok"] for g in got))
+            extra = (sound_extra(ref, cfg, weights, prompts, served)
+                     if control == "sound" and sound_extra else {})
             print(json.dumps({
-                "config": name, "init": trial or "preset",
+                **extra, "config": name, "init": trial or "preset",
                 "init_std": mcfg.init_std, "embed_init_std": mcfg.embed_init_std,
                 "router_init_scale": sound.moe.router_init_scale,
                 "control": control, "prompts": len(prompts), "lengths": lens,
@@ -298,34 +337,41 @@ def logits(args, config, name_map, name):
     }), flush=True)
 
 
-def main():
+def main(tool="longcat_check_controls", configs=("longcat-flash-omni",
+                                                 "rehearsal-longcat-flash-tiny"),
+         names=CONTROLS, seed=4800000101, run_controls=controls,
+         run_logits=logits, more_args=None):
+    """``configs``: (the cell's configuration, its rehearsal's).
+    ``more_args(ap)``: another tool's own options."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--pairs", type=int, default=4)
-    ap.add_argument("--seed", type=int, default=4800000101)
-    ap.add_argument("--controls", nargs="+", default=list(CONTROLS),
-                    choices=CONTROLS)
+    ap.add_argument("--seed", type=int, default=seed)
+    ap.add_argument("--controls", nargs="+", default=list(names), choices=names)
     ap.add_argument("--toy", action="store_true")
     ap.add_argument("--lengths", type=int, nargs=2, default=None)
     ap.add_argument("--init", nargs="+", default=[""],
                     help="K=V,K=V over the preset: init_std, embed_init_std, "
-                         "router_init_scale")
+                         "router_init_scale, expert_bias_std")
     ap.add_argument("--logits", action="store_true")
     ap.add_argument("--rows", type=int, default=4)
-    ap.add_argument("--steps", type=int, default=16)
+    if more_args:
+        more_args(ap)
+    else:
+        ap.add_argument("--steps", type=int, default=16)
     args = ap.parse_args()
 
     import correctness
-    name = "rehearsal-longcat-flash-tiny" if args.toy else "longcat-flash-omni"
+    name = configs[1] if args.toy else configs[0]
     if not args.toy:
         from deepspeed_tpu.accelerator import require_tpu
         try:
             require_tpu()
         except Exception as e:  # noqa: BLE001
-            sys.exit(f"longcat_check_controls: {e}")
+            sys.exit(f"{tool}: {e}")
     with open(os.path.join(ROOT, "perfbench", "configs", name + ".json")) as f:
         config = json.load(f)
     name_map = correctness.load_map(name)
-    (logits if args.logits else controls)(args, config, name_map, name)
+    (run_logits if args.logits else run_controls)(args, config, name_map, name)
 
 
 if __name__ == "__main__":

@@ -874,16 +874,25 @@ class ContinuousBatchingScheduler:
         # pos). Shared blocks count ONCE (dedup by block id); a mid-prefill
         # request counts its whole target as cached — its blocks are spoken
         # for, not wasted, and the gauge would otherwise spike at admission
-        fills = {}
         bs = a.block_size
-        for r in self.running:
-            c = r.prefill_target if r.prefilling else r.pos
-            for j, b in enumerate(r.blocks):
-                f = min(bs, max(0, c - j * bs))
-                if f > fills.get(b, 0):
-                    fills[b] = f
+        if a.prefix_cache:
+            fills = {}
+            for r in self.running:
+                c = r.prefill_target if r.prefilling else r.pos
+                for j, b in enumerate(r.blocks):
+                    f = min(bs, max(0, c - j * bs))
+                    if f > fills.get(b, 0):
+                        fills[b] = f
+            cached = sum(fills.values())
+        else:
+            # without the content-addressed cache no block is shared, and a
+            # request's blocks fill in order: the same sum a request and
+            # not a block (256 rows of 10-20 blocks were 1 ms of this
+            # loop at every action, PERF.md section 6, PR 52)
+            cached = sum(
+                min(max(r.prefill_target if r.prefilling else r.pos, 0),
+                    len(r.blocks) * bs) for r in self.running)
         cap = used * bs
-        cached = sum(fills.values())
         t.kv_fragmentation.set(1.0 - cached / cap if cap > 0 else 0.0)
 
     # ------------------------------------------------------------------ #

@@ -104,7 +104,11 @@ class RequestHandle:
         self.retry_after: Optional[float] = None   # backpressure hint (s),
         # set on admission-control rejections (HTTP 429 Retry-After)
         self._tokens: List[int] = []
-        self._q: "queue.Queue" = queue.Queue()
+        # the C queue: a burst a row a step is 256 puts and as many
+        # wake-ups a step at 256 rows, and ``queue.Queue``'s are Python
+        # under the lock the loop shares with every reader (put / get /
+        # get_nowait and ``queue.Empty`` are all a handle asks of it)
+        self._q: "queue.SimpleQueue" = queue.SimpleQueue()
         self._done = threading.Event()
         self._submit_perf = time.perf_counter()
         self._submit_ns = time.monotonic_ns()

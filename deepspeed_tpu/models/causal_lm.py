@@ -97,16 +97,15 @@ class CausalLM:
     def num_parameters(self) -> int:
         cfg = self.config
         embed = cfg.vocab_size * cfg.d_model + (cfg.max_seq * cfg.d_model if cfg.pos_embedding == "learned" else 0)
-        if cfg.activation == "swiglu":
-            mlp = 3 * cfg.d_model * cfg.ff_dim
-        else:
-            mlp = 2 * cfg.d_model * cfg.ff_dim + cfg.ff_dim + cfg.d_model
+        n_lead = len(cfg.lead_kinds)
+        mlps = (cfg.n_layer - n_lead) * T.dense_mlp_params(cfg) \
+            + n_lead * T.dense_mlp_params(cfg, cfg.lead_d_ff)
         norms = (4 if cfg.norm == "layernorm" else 2) * cfg.d_model
         final_norm = (2 if cfg.norm == "layernorm" else 1) * cfg.d_model
         if cfg.embed_layernorm:
             final_norm += (2 if cfg.norm == "layernorm" else 1) * cfg.d_model
         head = 0 if cfg.tie_embeddings else cfg.d_model * cfg.vocab_size
-        return embed + cfg.n_layer * (mlp + norms) + T.mixer_params(cfg) \
+        return embed + mlps + cfg.n_layer * norms + T.mixer_params(cfg) \
             + final_norm + head
 
     def flops_per_token(self, seq_len: Optional[int] = None) -> float:

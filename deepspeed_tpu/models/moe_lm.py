@@ -106,6 +106,9 @@ class MoEConfig:
     # sorted_dispatch / dense_dispatch)
     dispatch: str = "capacity"
     norm_topk_prob: bool = False         # nodrop: divide the k weights by their sum
+    # what that division adds to the sum (LFM2: 1e-6); None: the router's
+    # own (``topk_routing``: 1e-20 under sigmoid scoring, else nothing)
+    norm_topk_eps: Optional[float] = None
     # nodrop: how a token scores the experts before its k largest are taken:
     # "softmax" over them, or "sigmoid" of each logit with a learned bias
     # ``b_select`` added for the choice alone (DeepSeek-V3 / GLM-4-MoE)
@@ -162,7 +165,9 @@ class MoEConfig:
 
 
 class MoECausalLM:
-    """Causal LM where every block's MLP is an MoE layer."""
+    """Causal LM where every block's MLP is an MoE layer, but for the
+    LEADING layers of its stack (``config.lead_kinds``), which keep the
+    dense MLPs ``transformer.init_params`` gives them."""
 
     def __init__(self, config: T.TransformerConfig, moe_config: MoEConfig = MoEConfig(),
                  param_dtype=jnp.float32, mesh=None):
@@ -238,7 +243,8 @@ class MoECausalLM:
     @property
     def n_moe_layers(self) -> int:
         cfg = self.config
-        return cfg.n_periods if self.moe.shortcut else cfg.n_layer
+        return cfg.n_periods if self.moe.shortcut \
+            else cfg.n_layer - len(cfg.lead_kinds)
 
     # -------------------- params -------------------- #
 
@@ -252,7 +258,7 @@ class MoECausalLM:
             base["layers"][0]["moe"] = self._mlp_params(
                 jax.random.fold_in(rng, 2000), cfg.n_periods)
         elif cfg.layer_kinds is None:
-            base["layers"]["mlp"] = self._mlp_params(rng, cfg.n_layer)
+            base["layers"]["mlp"] = self._mlp_params(rng, cfg.n_periods)
         else:
             for j, group in enumerate(base["layers"]):
                 group["mlp"] = self._mlp_params(
@@ -379,14 +385,18 @@ class MoECausalLM:
         logits = jnp.dot(tokens.astype(jnp.float32),
                          lp["gate_w"].astype(jnp.float32),
                          precision=jax.lax.Precision.HIGHEST)
+        # the epsilon only where the configuration states one: the calls of
+        # every other preset stay what stand-ins written for them take
+        eps = {} if moe.norm_topk_eps is None \
+            else {"norm_eps": moe.norm_topk_eps}
         if not self._select_bias:
             weights, experts, probs = topk_routing(logits, moe.k,
-                                                   moe.norm_topk_prob)
+                                                   moe.norm_topk_prob, **eps)
         else:
             weights, experts, probs = topk_routing(
                 logits, moe.k, moe.norm_topk_prob, scoring=moe.scoring,
                 select_bias=lp["b_select" if moe.scoring == "sigmoid"
-                               else "select_bias"])
+                               else "select_bias"], **eps)
         if moe.routed_scaling_factor != 1.0:
             weights = weights * moe.routed_scaling_factor
         zero = experts >= self.real_router_width if moe.zero_experts else None
@@ -774,7 +784,11 @@ class MoECausalLM:
         norms = (4 if cfg.norm == "layernorm" else 2) * D
         final_norm = (2 if cfg.norm == "layernorm" else 1) * D
         head = 0 if cfg.tie_embeddings else D * cfg.vocab_size
-        # a shortcut MoE: one a period, and a dense MLP in every sub-block
-        dense = cfg.n_layer * 3 * D * cfg.ff_dim if moe.shortcut else 0
+        # a shortcut MoE: one a period, and a dense MLP in every sub-block;
+        # a leading layer's MLP is dense (gated or not, as the stack's are)
+        n_lead = len(cfg.lead_kinds)
+        dense = (cfg.n_layer - n_lead) * 3 * D * cfg.ff_dim \
+            if moe.shortcut else 0
+        dense += n_lead * T.dense_mlp_params(cfg, cfg.lead_d_ff)
         return embed + self.n_moe_layers * moe_mlp + cfg.n_layer * norms \
             + dense + T.mixer_params(cfg) + final_norm + head

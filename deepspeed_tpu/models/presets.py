@@ -446,6 +446,86 @@ def longcat_flash(size: str = "omni-4l-ep32", **over):
         param_dtype=param_dtype)
 
 
+def lfm2_moe(size: str = "24b-a2b-9l", **over):
+    """LFM2-24B-A2B (``LiquidAI/LFM2-24B-A2B`` config.json, ``model_type``
+    ``lfm2_moe``): 40 pre-RMSNorm layers (eps 1e-5, no bias anywhere) of d
+    2,048 whose mixer is by ``layer_types`` a GATED SHORT CONVOLUTION (30
+    layers: ``[B, C, x~] = x W_in``, a causal depthwise conv of 3 taps over
+    ``B * x~``, ``(C * conv) W_out``; it keeps the conv's last 2 inputs a
+    request and nothing else) or softmax GQA (10 layers: 32 query and 8
+    key/value heads of 64, RMSNorm of each head's q and k before RoPE of
+    theta 1e6 over the whole head); the first ``num_dense_layers`` 2 layers
+    with a dense gated-SiLU MLP of 11,776, the other 38 with 64 gated-SiLU
+    experts of 1,536, 4 a token: a float32 sigmoid router with a selection
+    bias (``expert_bias``, for the choice alone), the four weights divided
+    by their sum + 1e-6, times ``routed_scaling_factor`` 1; a final RMSNorm
+    and a head tied to the embedding over 65,536 rows. 23.84 B parameters.
+    ``24b-a2b-9l`` is ONE PIPELINE STAGE on one chip (perfbench's
+    ``lfm2_24b_serve_rollout``): published layers 1-9, ONE leading dense
+    conv layer (``lead_kinds``; leading dense layers count once) and two
+    whole periods of (attention, conv, conv, conv) with every expert and the
+    whole vocabulary, each layer whole on its chip: 5,177,950,976
+    parameters, 10.36 GB in bf16. ``max_seq`` is what the deployment serves
+    (the block tables' width). Its seeded init, chosen on the chip with
+    ``benchmarks/lfm2_check_controls.py`` (PERF.md section 6, PR 52): THE
+    MATRICES AT 0.4, THE EMBEDDING AT 5,400, the router at the library's 1
+    (logits of deviation 1: sigmoid scores of 0.1-0.9, all 64 experts
+    chosen in a step). Why numbers so far from a trained model's: NO seeded
+    init lets the served check (tokens, 4 bf16 steps) both pass the sound
+    program and see this model's layers. The head is TIED, so an embedding
+    that leads the residual stream serves the input token back whatever
+    nine layers do (1.0 or 0.08 over matrices at 0.02: no planted fault
+    refused), and one that does not leaves the logits to a sum the bf16
+    program cannot repeat: the 4th and 5th of 64 router logits lie 0.12
+    deviations apart and bf16 rounds the stream by ~0.5% of one, at ANY
+    router scale and ``expert_bias`` deviation (tried: 0.5, 1, 3; 0.02,
+    0.1), so ~5% of (token, layer) pairs take another fourth expert, a
+    QUARTER of an MoE layer's output under normalised sigmoid weights: at
+    matrices 0.02 sound prompts read 7-34 of the check's 4 steps. A gated
+    conv's output goes with the matrices' deviation to the 4th power and an
+    expert's to the 3rd: at 0.4 the conv layers carry the stream (14,570 a
+    value against an expert's 866) and a changed choice is 2% of a layer;
+    what is left is bf16 rounding through seven cubic mixers, and where the
+    layers decide every position (embedding 0.02) 3 sound prompts of 64
+    still read over 4 (5.2 the worst). So the embedding sits at THE EDGE OF
+    THE ECHO, as Granite's does (PR 43): at 5,400 the layers decide 3.9% of
+    served positions (5.5% at 5,000, 0.8% at 5,800; all measured), a lost
+    conv state shows on 3 and a skipped lead on 6 of 16 prompts, sound
+    prompts on none of 32 (2.8 the worst): a check's 28 prompts refuse
+    either fault with probability over 0.99 and a sound program with
+    ~0.05. A router or conv taps in bf16 and a missing ``expert_bias``
+    are of the size of the stream's own rounding and are seen by no init:
+    the float32 logits tests hold those.
+    ``tiny``: one leading dense conv layer and one period at toy widths
+    (embedding 1.0 over matrices at 0.1, router logits of deviation 3:
+    float32 tests, no flips)."""
+    from deepspeed_tpu.models.moe_lm import MoECausalLM, MoEConfig
+    dims, moe = {
+        "tiny": (dict(n_layer=5, n_head=4, n_kv_head=2, head_size=16,
+                      d_model=64, d_ff=32, lead_d_ff=96, vocab_size=512,
+                      max_seq=1024, init_std=0.1, embed_init_std=1.0),
+                 dict(num_experts=8, k=2, expert_d_ff=32,
+                      router_init_scale=3.0)),
+        "24b-a2b-9l": (dict(n_layer=9, n_head=32, n_kv_head=8, head_size=64,
+                            d_model=2048, d_ff=1536, lead_d_ff=11776,
+                            vocab_size=65536, max_seq=2560, init_std=0.4,
+                            embed_init_std=5400.0),
+                       dict(num_experts=64, k=4, expert_d_ff=1536)),
+    }[size]
+    param_dtype = over.pop("param_dtype", jnp.float32)
+    moe = {**moe, **over.pop("moe", {})}
+    cfg = TransformerConfig(**{**dict(
+        pos_embedding="rope", rope_theta=1e6, norm="rmsnorm", norm_eps=1e-5,
+        activation="swiglu", tie_embeddings=True, attn_bias=False,
+        qk_norm="head", conv_kernel=3, lead_kinds=("short_conv",),
+        layer_kinds=("attention",) + ("short_conv",) * 3), **dims, **over})
+    return MoECausalLM(cfg, MoEConfig(**{**dict(
+        dispatch="nodrop", expert_activation="swiglu", scoring="sigmoid",
+        norm_topk_prob=True, norm_topk_eps=1e-6, routed_scaling_factor=1.0,
+        aux_loss_coef=0.0), **moe}),
+        param_dtype=param_dtype)
+
+
 MODEL_PRESETS: Dict[str, Callable] = {
     "gpt2": gpt2,
     "llama": llama,
@@ -458,6 +538,7 @@ MODEL_PRESETS: Dict[str, Callable] = {
     "smallthinker": smallthinker,
     "granite_hybrid": granite_hybrid,
     "longcat_flash": longcat_flash,
+    "lfm2_moe": lfm2_moe,
 }
 
 

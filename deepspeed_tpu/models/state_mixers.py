@@ -1,5 +1,6 @@
-"""The recurrent mixers: the layer kinds that keep a float32 state and a conv
-state in a slot a request where an attention layer keeps KV blocks. A kind is
+"""The recurrent mixers: the layer kinds that keep a conv state, and all but
+the short conv a float32 state, in a slot a request where an attention layer
+keeps KV blocks. A kind is
 ONE record of ``STATE_MIXERS`` (at the end) and the functions it names.
 ``models/transformer.py`` owns the kinds' NAMES, so that a config is built and
 checked without this module, and reaches the records through its
@@ -14,8 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.transformer import (LINEAR_ATTENTION, MAMBA2,
-                                              TransformerConfig, _use_flash,
-                                              _w)
+                                              SHORT_CONV, TransformerConfig,
+                                              _use_flash, _w)
 from deepspeed_tpu.ops import dispatch
 
 
@@ -549,6 +550,105 @@ def _mamba2_decode(cfg: TransformerConfig, x, lp, state, conv, base, slots):
 
 
 # --------------------------------------------------------------------- #
+# The gated short convolution (LFM2's ``conv`` layers)
+#
+#   [B, C, x~] = split3(x W_in) ;  u = B * x~
+#   c_t = sum_j w[j] u_(t-K+1+j)     (depthwise, causal, K = cfg.conv_kernel
+#   y = (C * c) W_out                 taps, no bias and no activation)
+#
+# ALL a request keeps is its conv state, the last K - 1 values of u in
+# the pool's type ([K-1, d_model] a layer, in its SLOT of ``conv``): no
+# recurrent state (``_short_conv_shapes``'s None, for which
+# ``init_paged_kv_cache`` allocates nothing; the functions below take and
+# return None in the state's place). u is rounded to the activations' type
+# before the conv in a prompt too, so that a token's conv reads the same
+# values whether its neighbours came by the prompt or by the slot; the
+# taps and the gate are float32. A decode step gathers the rows' K - 1
+# values, computes the K taps and writes the newer K - 1 back: plain XLA at
+# 8 KB a row and layer (scope ``state``, under the layer's ``short_conv``).
+
+
+def _init_short_conv(cfg: TransformerConfig, n: int, key, dtype, out_std):
+    D, K = cfg.d_model, cfg.conv_kernel
+    ks = jax.random.split(key, 3)
+
+    def dense(k, shape, scale=cfg.init_std):
+        return (jax.random.normal(k, shape) * scale).astype(dtype)
+
+    return {
+        # B | C | x~, one projection
+        "w_in": dense(ks[0], (n, D, 3 * D)),
+        # depthwise, causal: tap K-1 multiplies the current input; torch's
+        # default draw of a conv, U(-K^-1/2, K^-1/2)
+        "conv_w": jax.random.uniform(ks[1], (n, K, D), minval=-K ** -0.5,
+                                     maxval=K ** -0.5).astype(dtype),
+        "w_out": dense(ks[2], (n, D, D), out_std),
+    }
+
+
+def _short_conv_project(x, lp):
+    """x [R, T, D] -> (u = B * x~ in x's type, the gate C), [R, T, D]."""
+    with jax.named_scope("in_proj"):
+        b, c, xs = jnp.split(x @ _w(lp["w_in"], x), 3, axis=-1)
+    return b * xs, c
+
+
+def _short_conv_taps(win, lp, T: int):
+    """win [R, T+K-1, D], the conv's inputs with the K - 1 before them ->
+    the conv's T outputs, float32."""
+    w = lp["conv_w"].astype(jnp.float32)
+    return sum(win[:, j:j + T].astype(jnp.float32) * w[j]
+               for j in range(w.shape[0]))
+
+
+def _short_conv_output(c, y, lp):
+    """The gate c [R, T, D] times the conv's float32 output y, through W_out."""
+    g = (c.astype(jnp.float32) * y).astype(c.dtype)
+    with jax.named_scope("out_proj"):
+        return g @ _w(lp["w_out"], g)
+
+
+def _short_conv_prefill(cfg: TransformerConfig, x, lp, state, conv, slot,
+                        n_valid, fresh):
+    """The mixer over a prompt, or a chunk of one, of ONE request: x [1, T,
+    D] (T a compile bucket, the first ``n_valid`` positions real), ``slot``
+    the request's row of the layer in ``conv`` [rows, K-1, D]; ``state`` is
+    None. ``fresh`` (a bool, traced or not): the request's first piece
+    starts from zeros (the conv's left padding), whatever the slot's last
+    holder left there. The slot takes the last K - 1 inputs of the VALID
+    positions: a prompt of one token leaves a zero beside it."""
+    T, K = x.shape[1], cfg.conv_kernel
+    dispatch.record("mixer", "short_conv", f"T={T} D={x.shape[2]} K={K}")
+    u, c = _short_conv_project(x, lp)
+    with jax.named_scope("state"):
+        win = jnp.concatenate(
+            [_slot_start(conv, slot, fresh).astype(u.dtype), u], axis=1)
+        y = _short_conv_taps(win, lp, T)
+        # rows by a gather, as the Mamba-2 prefill takes them (a dynamic
+        # slice along time turns the pool time-minor on the TPU)
+        tail = win[:, n_valid + jnp.arange(K - 1)]
+        conv = jax.lax.dynamic_update_slice_in_dim(
+            conv, tail.astype(conv.dtype), slot, axis=0)
+    return _short_conv_output(c, y, lp), state, conv
+
+
+def _short_conv_decode(cfg: TransformerConfig, x, lp, state, conv, base,
+                       slots):
+    """One token a row: x [B, 1, D], ``slots`` [B] each row's slot (0, the
+    dummy, for an inactive row), the layer's slots from row ``base`` of
+    ``conv`` [rows, K-1, D]; ``state`` is None."""
+    dispatch.record("mixer", "short_conv",
+                    f"B={x.shape[0]} D={x.shape[2]} K={cfg.conv_kernel}")
+    rows = base + slots
+    u, c = _short_conv_project(x, lp)
+    with jax.named_scope("state"):
+        win = jnp.concatenate([conv[rows].astype(u.dtype), u], axis=1)
+        y = _short_conv_taps(win, lp, 1)
+        conv = conv.at[rows].set(win[:, 1:].astype(conv.dtype))
+    return _short_conv_output(c, y, lp), state, conv
+
+
+# --------------------------------------------------------------------- #
 # One record a kind: all that ``models/transformer.py`` knows of it
 
 def _kda_check(cfg: TransformerConfig):
@@ -575,11 +675,23 @@ def _mamba2_shapes(cfg: TransformerConfig):
     return (N, H * Pd), (cfg.ssm_conv_kernel - 1, H * Pd + 2 * N)
 
 
+def _short_conv_check(cfg: TransformerConfig):
+    if cfg.conv_kernel < 2:
+        raise ValueError("a short_conv layer needs conv_kernel >= 2 taps")
+
+
+def _short_conv_shapes(cfg: TransformerConfig):
+    # no recurrent state: the conv's last K - 1 inputs are all it keeps
+    return None, (cfg.conv_kernel - 1, cfg.d_model)
+
+
 class StateMixer(NamedTuple):
     key: str            # of the mixer's parameters in a layer group
     check: Callable     # (cfg): ValueError where cfg lacks the kind's sizes
     init: Callable      # (cfg, n, key, dtype, out_std) -> n stacked layers'
-    shapes: Callable    # (cfg) -> a request's state's and conv state's
+    shapes: Callable    # (cfg) -> a request's state's (None: the kind keeps
+    #                     none, and its functions are handed None for
+    #                     ``state``) and conv state's
     prefill: Callable   # (cfg, xn, lp, state, conv, row, n_valid, fresh)
     decode: Callable    # (cfg, xn, lp, state, conv, row0, state_slots);
     #                     both -> (the mixer's output, state, conv)
@@ -590,4 +702,7 @@ STATE_MIXERS = {
                                  _kda_shapes, _kda_prefill, _kda_decode),
     MAMBA2: StateMixer("ssm", _mamba2_check, _init_mamba2, _mamba2_shapes,
                        _mamba2_prefill, _mamba2_decode),
+    SHORT_CONV: StateMixer("conv", _short_conv_check, _init_short_conv,
+                           _short_conv_shapes, _short_conv_prefill,
+                           _short_conv_decode),
 }

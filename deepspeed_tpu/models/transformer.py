@@ -17,6 +17,7 @@ Llama (RoPE + RMSNorm + SwiGLU), BLOOM (alibi), OPT, GPT-NeoX, BERT
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 from typing import Any, Dict, Optional, Tuple
@@ -37,9 +38,14 @@ MAMBA2 = "mamba2"
 #: token keeps one row of ``kv_lora_rank + qk_rope_head_dim`` values a layer,
 #: no k/v pair and no head axis; ``models/latent_attention.py``
 LATENT_ATTENTION = "latent_attention"
-#: the kinds whose layers keep a recurrent state and a conv state in a slot
-#: a request; what each is, is its record in ``models/state_mixers.py``
-STATE_KINDS = (LINEAR_ATTENTION, MAMBA2)
+#: the gated short convolution (LFM2): y = (C * conv(B * x~)) W_out, a
+#: causal depthwise conv of ``conv_kernel`` taps; it keeps the conv's last
+#: inputs a request and NO recurrent state
+SHORT_CONV = "short_conv"
+#: the kinds whose layers keep a conv state, and all but ``SHORT_CONV`` a
+#: recurrent state, in a slot a request; what each is, is its record in
+#: ``models/state_mixers.py``
+STATE_KINDS = (LINEAR_ATTENTION, MAMBA2, SHORT_CONV)
 
 
 def _mixer(kind: str):
@@ -142,6 +148,13 @@ class TransformerConfig:
     # of KV blocks a request that holds the window and no more, or blocks
     # of latent rows.
     layer_kinds: Optional[Tuple[str, ...]] = None
+    # LEADING layers, ahead of the first period: the kinds of their mixers
+    # in order, each with a DENSE MLP of ``lead_d_ff`` (None: ``ff_dim``)
+    # whatever MLP the periods' layers have (an MoE model's leading dense
+    # layers). ``n_layer`` counts them; their parameters are the group
+    # ``lead`` of the tree and their cache entries the first of each pool
+    lead_kinds: Tuple[str, ...] = ()
+    lead_d_ff: Optional[int] = None
     # keys a window_attention layer's query sees, its own among them
     attn_window: int = 0
     # the kinds whose q and k take ``pos_embedding`` where not every
@@ -162,6 +175,10 @@ class TransformerConfig:
     ssm_state: int = 0
     ssm_conv_kernel: int = 4
     ssm_chunk: int = 256
+    # the gated short conv: taps of its causal depthwise conv over d_model
+    # channels (LFM2's ``conv_L_cache``); a request keeps the last
+    # ``conv_kernel - 1`` of the conv's inputs
+    conv_kernel: int = 3
     # the latent (MLA) mixer: queries through a bottleneck of
     # ``q_lora_rank`` (RMSNorm in it), ``n_head`` heads of ``qk_nope_head_dim
     # + qk_rope_head_dim``; keys and values from ONE latent of
@@ -280,17 +297,19 @@ class TransformerConfig:
 
     @property
     def n_periods(self) -> int:
-        return self.n_layer // len(self.period)
+        return (self.n_layer - len(self.lead_kinds)) // len(self.period)
 
     def layers_of(self, kind: str) -> int:
-        return self.n_periods * self.period.count(kind)
+        """The stack's layers of ``kind``, the leading ones among them."""
+        return self.n_periods * self.period.count(kind) \
+            + tuple(self.lead_kinds).count(kind)
 
     @property
     def cache_spec(self) -> Dict[str, int]:
         """What the stack keeps between steps, by cache: ``kv`` the layers
-        that hold KV blocks, ``state`` those that hold a recurrent state
-        and a conv state in a slot a request (KDA and Mamba-2 layers
-        alike: ``STATE_KINDS``), ``window`` those that hold
+        that hold KV blocks, ``state`` those that hold a conv state, and
+        all but the short conv's a recurrent state, in a slot a request
+        (``STATE_KINDS``), ``window`` those that hold
         the last ``attn_window`` positions' KV in a ring of blocks a
         request, ``latent`` those that hold blocks of latent rows
         (``latent_row`` values a token, addressed by the same block tables
@@ -320,8 +339,8 @@ class TransformerConfig:
 
     def state_shapes(self, kind: str):
         """What one layer of a recurrent ``kind`` keeps a request: the
-        shape of its float32 state and of its conv state (the last K - 1
-        inputs of its causal conv)."""
+        shape of its float32 state (None: the kind keeps none) and of its
+        conv state (the last K - 1 inputs of its causal conv)."""
         return _mixer(kind).shapes(self)
 
     def ring_blocks(self, block_size: int) -> int:
@@ -352,7 +371,8 @@ class TransformerConfig:
 # parameter init
 
 def _check_pattern(cfg: TransformerConfig):
-    kinds = cfg.period
+    period, lead = cfg.period, tuple(cfg.lead_kinds)
+    kinds = lead + period
     known = (ATTENTION, WINDOW_ATTENTION, LATENT_ATTENTION, *STATE_KINDS)
     bad = [k for k in kinds if k not in known]
     if bad:
@@ -360,9 +380,14 @@ def _check_pattern(cfg: TransformerConfig):
                          f"(expected {'|'.join(known)})")
     if WINDOW_ATTENTION in kinds and cfg.attn_window < 1:
         raise ValueError("a window_attention layer needs attn_window")
-    if cfg.n_layer % len(kinds):
-        raise ValueError(f"n_layer {cfg.n_layer} is not whole periods of "
-                         f"the layer pattern {kinds}")
+    if (cfg.n_layer - len(lead)) % len(period) or cfg.n_layer <= len(lead):
+        raise ValueError(f"n_layer {cfg.n_layer} is not {len(lead)} leading "
+                         f"layers and whole periods of the layer pattern "
+                         f"{period}")
+    if LINEAR_ATTENTION in lead:
+        raise NotImplementedError(
+            "a linear_attention layer ahead of the periods is not built: its "
+            "decode form finds its slots in a pool of n_periods layers")
     for kind in STATE_KINDS:
         if kind in kinds:
             _mixer(kind).check(cfg)
@@ -371,13 +396,15 @@ def _check_pattern(cfg: TransformerConfig):
         latent_attention.check(cfg)
 
 
-def _layer_group(cfg: TransformerConfig, kind: str, n: int, ks, dtype):
+def _layer_group(cfg: TransformerConfig, kind: str, n: int, ks, dtype,
+                 d_ff: Optional[int] = None):
     """The stacked parameters of ``n`` like layers of ``kind`` (leading dim
     ``n``, what ``lax.scan`` runs one compiled block over) from the keys
-    ``ks``: the mixer under ``attn``, ``lin`` or ``ssm``, its norm, the
-    dense MLP and its norm (a window layer's are an attention layer's)."""
+    ``ks``: the mixer under ``attn`` or its record's key, its norm, the
+    dense MLP (of width ``d_ff``; None: ``cfg.ff_dim``) and its norm (a
+    window layer's are an attention layer's)."""
     std = cfg.init_std
-    D, F = cfg.d_model, cfg.ff_dim
+    D, F = cfg.d_model, d_ff or cfg.ff_dim
     H, KV, Hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
     # attention out & mlp down get depth-scaled init (gpt-2 style)
     out_std = std / math.sqrt(2 * cfg.n_layer)
@@ -451,14 +478,25 @@ def mixer_params(cfg: TransformerConfig) -> int:
         return sum(a.size for a in jax.tree.leaves(jax.eval_shape(
             lambda: init(cfg, 1, jax.random.key(0), jnp.float32, 1.0))))
 
-    return cfg.n_periods * sum(layer(k) for k in cfg.period)
+    return cfg.n_periods * sum(layer(k) for k in cfg.period) \
+        + sum(layer(k) for k in cfg.lead_kinds)
+
+
+def dense_mlp_params(cfg: TransformerConfig, d_ff: Optional[int] = None) -> int:
+    """The parameters of one layer's dense MLP as ``_layer_group`` makes it,
+    ``d_ff`` wide (None: ``cfg.ff_dim``)."""
+    D, F = cfg.d_model, d_ff or cfg.ff_dim
+    return 3 * D * F if cfg.activation == "swiglu" else 2 * D * F + F + D
 
 
 def init_params(cfg: TransformerConfig, rng, dtype=jnp.float32) -> Dict[str, Any]:
     """Stacked-layer parameter pytree. Layer weights carry a leading
     ``n_layer`` dim so ``lax.scan`` runs one compiled block for all layers.
     Under a layer pattern (``cfg.layer_kinds``) ``layers`` is a tuple, one
-    group a position of the period, each stacked over the periods."""
+    group a position of the period, each stacked over the periods. Leading
+    layers (``cfg.lead_kinds``) are the tuple ``lead``, one group of ONE
+    layer each, their MLPs ``cfg.lead_d_ff`` wide; a stack without has no
+    such key."""
     _check_pattern(cfg)
     k_emb, k_pos, k_layers, k_head = jax.random.split(rng, 4)
     std = cfg.init_std
@@ -468,7 +506,7 @@ def init_params(cfg: TransformerConfig, rng, dtype=jnp.float32) -> Dict[str, Any
         return (jax.random.normal(key, shape) * scale).astype(dtype)
 
     if cfg.layer_kinds is None:
-        layers = _layer_group(cfg, ATTENTION, cfg.n_layer,
+        layers = _layer_group(cfg, ATTENTION, cfg.n_periods,
                               jax.random.split(k_layers, 8), dtype)
     else:
         layers = tuple(
@@ -483,6 +521,12 @@ def init_params(cfg: TransformerConfig, rng, dtype=jnp.float32) -> Dict[str, Any
         "ln_f": ({"scale": jnp.ones((D,), dtype), "bias": jnp.zeros((D,), dtype)}
                  if cfg.norm == "layernorm" else {"scale": jnp.ones((D,), dtype)}),
     }
+    if cfg.lead_kinds:
+        params["lead"] = tuple(
+            _layer_group(cfg, kind, 1, jax.random.split(
+                jax.random.fold_in(k_layers, 1000 + j), 9), dtype,
+                d_ff=cfg.lead_d_ff)
+            for j, kind in enumerate(cfg.lead_kinds))
     if cfg.pos_embedding == "learned":
         params["embed"]["positions"] = dense(k_pos, (cfg.max_seq, D))
     if cfg.embed_layernorm:
@@ -505,8 +549,9 @@ def tp_specs(cfg: TransformerConfig) -> Dict[str, Any]:
     """Tensor-parallel PartitionSpecs: column-shard qkv/up, row-shard out/down
     (Megatron layout over the ``tp`` mesh axis); vocab-shard embeddings.
     ZeRO sharding composes on the remaining free dims. A stack with a layer
-    pattern is replicated: no tensor-parallel form of its mixers is built."""
-    if cfg.layer_kinds is not None:
+    pattern or leading layers is replicated: no tensor-parallel form of its
+    mixers is built."""
+    if cfg.layer_kinds is not None or cfg.lead_kinds:
         return replicated_specs(lambda: init_params(cfg, jax.random.key(0)))
     ln = {"scale": P(None, None), "bias": P(None, None)} if cfg.norm == "layernorm" else {"scale": P(None, None)}
     specs = {
@@ -1536,7 +1581,8 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
     recurrent POSITION of the period, [periods, state_slots, *state shape]
     float32 and [periods, state_slots, *conv shape] with the kind's own
     shapes (``cfg.state_shapes``): a slot a running request and slot 0 the
-    dummy. (One array a position, not one for all:
+    dummy; ``state`` holds None at the position of a kind that keeps a conv
+    state alone (the short conv). (One array a position, not one for all:
     the period's layers are unrolled, so their offsets into a shared array
     would be constants, XLA would fold a later layer's read through the
     earlier layer's update back to the buffer as it came in, and a buffer
@@ -1553,8 +1599,11 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
     cfg.latent_pool_row], ONE row a token (no k/v pair, no head axis; its
     ``cfg.latent_row`` values, then zeros to whole lane tiles), its blocks those of the
     same tables and block 0 the same dummy; a stack whose every attention
-    is latent has no ``k`` / ``v`` at all (``pool_geometry``). This is the
-    one home of the shapes."""
+    is latent has no ``k`` / ``v`` at all (``pool_geometry``). LEADING
+    layers (``cfg.lead_kinds``) hold the first entries of each: layers
+    0.. of ``k`` / ``v`` / ``wk`` / ``c``, and the first arrays of ``state``
+    and ``conv``, [1, state_slots, ...] each. This is the one home of the
+    shapes."""
     spec = cfg.cache_spec
     row = cfg.kv_heads * cfg.head_dim
     shape = (spec["kv"], num_blocks, block_size, row)
@@ -1575,11 +1624,18 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
         pools["wk"] = jnp.zeros(wshape, dtype)
         pools["wv"] = jnp.zeros(wshape, dtype)
     if spec["state"]:
-        shapes = [cfg.state_shapes(k) for k in cfg.period if k in STATE_KINDS]
-        lead = (cfg.n_periods, state_slots)
-        pools["state"] = tuple(jnp.zeros(lead + st, jnp.float32)
-                               for st, _ in shapes)
-        pools["conv"] = tuple(jnp.zeros(lead + cv, dtype) for _, cv in shapes)
+        # a leading layer's arrays first, [1, slots, ...] each; a kind that
+        # keeps no recurrent state (its state shape None) allocates none,
+        # and its None keeps the two tuples aligned by position
+        shapes = [((n, state_slots), cfg.state_shapes(k))
+                  for n, kinds in ((1, cfg.lead_kinds),
+                                   (cfg.n_periods, cfg.period))
+                  for k in kinds if k in STATE_KINDS]
+        pools["state"] = tuple(
+            None if st is None else jnp.zeros(lead + st, jnp.float32)
+            for lead, (st, _) in shapes)
+        pools["conv"] = tuple(jnp.zeros(lead + cv, dtype)
+                              for lead, (_, cv) in shapes)
     return pools
 
 
@@ -1675,12 +1731,18 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
     last)`` and returns ``(out, held)``: ``held`` is what the sub-block
     before it returned (None to the period's first), ``last`` whether this
     is the period's last. Returns (x, new pools, aux)."""
-    period = cfg.period
+    period, lead = cfg.period, tuple(cfg.lead_kinds)
     n_att, n_win = period.count(ATTENTION), period.count(WINDOW_ATTENTION)
     n_lat = period.count(LATENT_ATTENTION)
+    # layers a period and leading layers, by block pool: the leading
+    # layers' entries come first in every pool
+    per = {ATTENTION: (n_att, lead.count(ATTENTION)),
+           WINDOW_ATTENTION: (n_win, lead.count(WINDOW_ATTENTION)),
+           LATENT_ATTENTION: (n_lat, lead.count(LATENT_ATTENTION))}
+    l_lin = sum(k in STATE_KINDS for k in lead)
     Nb, bs, _ = pool_geometry(pools)
-    n_slots = pools["state"][0].shape[1] if "state" in pools else 0
-    Nwb = pools["wk"].shape[1] if n_win else 0
+    n_slots = pools["conv"][0].shape[1] if "conv" in pools else 0
+    Nwb = pools["wk"].shape[1] if "wk" in pools else 0
     groups = params["layers"] if cfg.layer_kinds is not None \
         else (params["layers"],)
     stack_keys = getattr(mlp_fn, "stack_keys", ())
@@ -1694,10 +1756,60 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
                          if k not in stack_keys}} if root in g else g
             for g in groups)
 
+    def run_layer(h, flat, kind, lp, mfn, l, i, p):
+        """One layer of ``kind`` on the flat pools (tuples as lists):
+        ``l`` its index among the layers that share its block pool, or
+        ``i`` its position in the state tuples and ``p`` its index in that
+        array's layers."""
+        if kind == ATTENTION:
+            h, flat["k"], flat["v"] = _decode_block(
+                cfg, h, lp,
+                lambda xn: attn_fn(xn, lp["attn"], flat["k"], flat["v"],
+                                   l * Nb, l * (Nb * bs)),
+                mfn)
+        elif kind == WINDOW_ATTENTION:
+            h, flat["wk"], flat["wv"] = _decode_block(
+                cfg, h, lp,
+                lambda xn: win_fn(xn, lp["attn"], flat["wk"], flat["wv"],
+                                  l * Nwb),
+                mfn, scope=WINDOW_ATTENTION)
+        elif kind == LATENT_ATTENTION:
+            h, flat["c"] = _decode_block(
+                cfg, h, lp,
+                lambda xn: lat_fn(xn, lp["attn"], flat["c"], l * Nb,
+                                  l * (Nb * bs)),
+                mfn, scope=LATENT_ATTENTION)
+        else:
+            m = _mixer(kind)
+            h, flat["state"][i], flat["conv"][i] = _decode_block(
+                cfg, h, lp,
+                lambda xn: lin_fn(m, xn, lp[m.key], flat["state"][i],
+                                  flat["conv"][i], p * n_slots),
+                mfn, scope=kind)
+        return h
+
+    def numbered(kinds):
+        """Each of ``kinds`` with its index among those of them that share
+        its pool (the state tuples are one pool for every recurrent kind)."""
+        seen = collections.Counter()
+        out = []
+        for kind in kinds:
+            pool = "state" if kind in STATE_KINDS else kind
+            out.append((kind, seen[pool]))
+            seen[pool] += 1
+        return out
+
+    def as_lists(flat):
+        return {n: list(a) if isinstance(a, tuple) else a
+                for n, a in flat.items()}
+
+    def as_tuples(flat):
+        return {n: tuple(a) if isinstance(a, list) else a
+                for n, a in flat.items()}
+
     def run_period(carry, xs):
         h, flat = carry
-        flat = {n: list(a) if isinstance(a, tuple) else a
-                for n, a in flat.items()}
+        flat = as_lists(flat)
         lps, p = xs
         aux = []
         held = None
@@ -1716,47 +1828,27 @@ def _scan_paged_layers(cfg: TransformerConfig, params, pools, x, attn_fn,
                     out, a = out
                     aux.append(a)
                 return out
-        i_att = i_lin = i_win = i_lat = 0
-        for j, (kind, lp) in enumerate(zip(period, lps)):
-            if kind == ATTENTION:
-                l = p * n_att + i_att
-                i_att += 1
-                h, flat["k"], flat["v"] = _decode_block(
-                    cfg, h, lp,
-                    lambda xn: attn_fn(xn, lp["attn"], flat["k"], flat["v"],
-                                       l * Nb, l * (Nb * bs)),
-                    mfn)
-            elif kind == WINDOW_ATTENTION:
-                l = p * n_win + i_win
-                i_win += 1
-                h, flat["wk"], flat["wv"] = _decode_block(
-                    cfg, h, lp,
-                    lambda xn: win_fn(xn, lp["attn"], flat["wk"], flat["wv"],
-                                      l * Nwb),
-                    mfn, scope=WINDOW_ATTENTION)
-            elif kind == LATENT_ATTENTION:
-                l = p * n_lat + i_lat
-                i_lat += 1
-                h, flat["c"] = _decode_block(
-                    cfg, h, lp,
-                    lambda xn: lat_fn(xn, lp["attn"], flat["c"], l * Nb,
-                                      l * (Nb * bs)),
-                    mfn, scope=LATENT_ATTENTION)
-            else:
-                i, m = i_lin, _mixer(kind)
-                i_lin += 1
-                h, flat["state"][i], flat["conv"][i] = _decode_block(
-                    cfg, h, lp,
-                    lambda xn: lin_fn(m, xn, lp[m.key], flat["state"][i],
-                                      flat["conv"][i], p * n_slots),
-                    mfn, scope=kind)
-        flat = {n: tuple(a) if isinstance(a, list) else a
-                for n, a in flat.items()}
+        for j, ((kind, i), lp) in enumerate(zip(numbered(period), lps)):
+            l = None
+            if kind in per:
+                n, first = per[kind]
+                l = p * n + (first + i)
+            h = run_layer(h, flat, kind, lp, mfn, l, l_lin + i, p)
         out = None if not aux else aux[0] if len(aux) == 1 else jnp.stack(aux)
-        return (h, flat), out
+        return (h, as_tuples(flat)), out
 
     flat = jax.tree.map(
         lambda a: a.reshape(a.shape[0] * a.shape[1], *a.shape[2:]), pools)
+    if lead:
+        # the leading layers, unrolled, each with its own dense MLP
+        # whatever ``mlp_fn`` the periods' layers take
+        flat = as_lists(flat)
+        with jax.named_scope("lead"):
+            for (kind, i), group in zip(numbered(lead), params["lead"]):
+                x = run_layer(x, flat, kind,
+                              jax.tree.map(lambda a: a[0], group), None,
+                              i, i, 0)
+        flat = as_tuples(flat)
     (x, flat), aux = jax.lax.scan(
         run_period, (x, flat),
         (groups, jnp.arange(cfg.n_periods, dtype=jnp.int32)))
@@ -1916,12 +2008,13 @@ def _paged_chunk_attention(cfg: TransformerConfig, x, lp, positions,
 
 
 def _paged_path_only(cfg: TransformerConfig, what: str):
-    """What only the paged serving path builds: a layer pattern, and the
-    embedding, residual and logit multipliers."""
-    if cfg.layer_kinds is not None:
+    """What only the paged serving path builds: a layer pattern, leading
+    layers, and the embedding, residual and logit multipliers."""
+    if cfg.layer_kinds is not None or cfg.lead_kinds:
         raise NotImplementedError(
             f"{what} is not built for a stack with a layer pattern "
-            f"({cfg.period}): it runs on the paged serving path only")
+            f"({tuple(cfg.lead_kinds)} + {cfg.period}): it runs on the "
+            "paged serving path only")
     if (cfg.embedding_multiplier, cfg.residual_multiplier,
             cfg.logits_scaling) != (1.0, 1.0, 1.0):
         raise NotImplementedError(

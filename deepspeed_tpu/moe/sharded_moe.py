@@ -182,7 +182,8 @@ def top2gating(logits: jnp.ndarray,
 
 
 def topk_routing(logits: jnp.ndarray, k: int, norm_topk_prob: bool = False,
-                 scoring: str = "softmax", select_bias=None
+                 scoring: str = "softmax", select_bias=None,
+                 norm_eps: Optional[float] = None
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """A score for every expert first (``scoring``: ``softmax`` over the
     experts, or ``sigmoid`` of each logit), then the ``k`` largest as they
@@ -190,7 +191,9 @@ def topk_routing(logits: jnp.ndarray, k: int, norm_topk_prob: bool = False,
     (the sigmoid router's load-balancing bias) is added to the scores for
     the CHOICE only: the weights are the scores without it. logits [T, E] ->
     (weights [T, k] float32, experts [T, k] int32, scores [T, E] float32).
-    All in float32 whatever the logits came in."""
+    ``norm_eps``: what the normalisation adds to the sum (None: 1e-20 under
+    sigmoid scoring, whose k scores can all underflow, and nothing under
+    softmax). All in float32 whatever the logits came in."""
     logits = logits.astype(jnp.float32)
     if scoring == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
@@ -204,8 +207,10 @@ def topk_routing(logits: jnp.ndarray, k: int, norm_topk_prob: bool = False,
         _, experts = jax.lax.top_k(probs + select_bias.astype(jnp.float32), k)
         weights = jnp.take_along_axis(probs, experts, axis=-1)
     if norm_topk_prob:
+        if norm_eps is None:
+            norm_eps = 1e-20 if scoring == "sigmoid" else 0.0
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
-                             + (1e-20 if scoring == "sigmoid" else 0.0))
+                             + norm_eps)
     return weights, experts.astype(jnp.int32), probs
 
 

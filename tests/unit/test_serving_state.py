@@ -10,7 +10,9 @@ What is held: many requests through continuous batching with FEWER slots
 than requests, so that slots are reused, rows go idle and a chunked prefill
 interleaves with decode, give each request the tokens it gets alone, and
 those are the reference's (``perfbench/reference/solar_open2_decoder.py``,
-``granite_hybrid_decoder.py``);
+``granite_hybrid_decoder.py``, ``lfm2_moe_decoder.py``: the ``lfm2_moe``
+``tiny`` preset, a leading dense conv layer and a period of a GQA layer and
+three gated short-conv layers that keep a conv state and no recurrent one);
 a recompute-preemption, an engine restart with a step in flight and a
 launched-ahead step whose row turned out to be past its EOS (the overshoot)
 change nothing; and what cannot hold beside a state yet is refused, each
@@ -43,7 +45,8 @@ sys.path.insert(0, BENCH)
 import correctness  # noqa: E402
 from weights import make_params  # noqa: E402
 
-TOYS = ("rehearsal-solar-open2-tiny", "rehearsal-granite-hybrid-tiny")
+TOYS = ("rehearsal-solar-open2-tiny", "rehearsal-granite-hybrid-tiny",
+        "rehearsal-lfm2-moe-tiny")
 #: what is the engine's own whatever the state's kind (a restart, a step
 #: fault, an overshoot) runs on the first toy alone: the suite's time
 one_kind = pytest.mark.parametrize("toy", TOYS[:1], indirect=True,
@@ -132,14 +135,19 @@ def test_more_requests_than_slots(toy):
     assert counters["serving/state_slot_resets"] == len(prompts)
     assert counters["serving/decode_state_rows"] == stats["emitted_tokens"]
     assert stats["decode_steps_ahead"] / stats["decode_steps"] >= 0.7
-    if hasattr(toy[0], "num_experts"):              # the Solar toy's experts
+    if hasattr(toy[0], "num_experts"):              # the MoE toys' experts
+        moe = toy[0]
         assert counters["serving/moe_dropped_assignments"] == 0
-        # a share's counters are of the experts held here: 2 of 16 take
-        # about an eighth of rows x 4 assignments
-        assert 0 < counters["serving/moe_assignments"] \
-            < 0.5 * 4 * counters["serving/decode_state_rows"] * 4
+        routed = moe.n_moe_layers * moe.moe.k * counters["serving/decode_state_rows"]
+        if moe.router_width == moe.num_experts:
+            # every expert is here: all that the rows' routers chose
+            assert counters["serving/moe_assignments"] == routed
+        else:
+            # a share's counters are of the experts held here: 2 of 16 take
+            # about an eighth of rows x 4 assignments
+            assert 0 < counters["serving/moe_assignments"] < 0.5 * routed
     assert engine._active_session is None
-    assert engine._paged_workspace[2]["state"][0].shape[1] == 4
+    assert engine._paged_workspace[2]["conv"][0].shape[1] == 4
 
 
 def test_recompute_preemption_gives_the_undisturbed_tokens(toy):
