@@ -4,6 +4,8 @@
     python benchmarks/moe_dispatch_bench.py --preset sdar 30b-a3b-ep8 --rows 64 256 1024
     python benchmarks/moe_dispatch_bench.py --preset smallthinker 21b-a3b-12l \\
         --rows 16 --forms dense kernel --touched 16 32 50 64
+    python benchmarks/moe_dispatch_bench.py --preset lfm2_moe 24b-a2b-9l \\
+        --rows 256 384 512 --forms dense kernel gmm --row-tile 32 64 128
 
 Times ``MoECausalLM._nodrop_mlp`` scanned over 8 layers of a preset (default
 ``olmoe`` ``1b-7b-8l``: 64 experts of 2,048 x 1,024, top-8; 805 MB of expert
@@ -15,7 +17,9 @@ programs run it, in its forms:
   slices;
 * ``kernel`` (``ops/pallas/grouped_expert_mlp.py``: the touched experts, the
   stacks closed over whole and read in place; calls of at most its
-  ``MAX_ROWS`` rows), ``--f-tile`` lanes of F a grid step;
+  ``MAX_ROWS`` rows), ``--f-tile`` lanes of F a grid step, and for a call
+  past its ``RIDE_ROWS`` (each expert over its own rows) ``--row-tile`` rows
+  of an expert's own at a time;
 * ``gmm`` (the zero-line baseline of PR 40: three calls of jax's own
   ``megablox.gmm`` over the whole stacks, the layer's group sizes written
   into a zero ``[layers x E]`` vector, behind ``sorted_dispatch``).
@@ -25,13 +29,15 @@ programs run it, in its forms:
 form's time can be read against the bytes it has to move. The time is the
 device's: the median duration of the program's executions in a profiler
 trace, over the layers. The numbers behind
-``moe_lm._SORTED_DISPATCH_MIN_ROWS`` (PERF.md section 6, PR 26) and
-``moe_lm._GROUPED_KERNEL_MAX_ROWS`` (PR 40). TPU only: a time from another
+``moe_lm._SORTED_DISPATCH_MIN_ROWS`` (PERF.md section 6, PR 26),
+``moe_lm._GROUPED_KERNEL_MAX_ROWS`` (PR 40, PR 53) and the kernel's
+``ROW_TILE`` (PR 53). TPU only: a time from another
 backend says nothing about either, so the script refuses to print one.
 ``--preset FAMILY SIZE`` is any MoE preset, at 8 layers of depth (PR 33:
 ``sdar 30b-a3b-ep8``, 16 held experts of 2,048 x 768 of a router's 128,
 top-8; PR 40: ``smallthinker 21b-a3b-12l``, 64 of 2,560 x 768, top-6, and
-``solar_open2 250b-4l-ep8``, 40 held of 320 of 4,096 x 1,280, top-8).
+``solar_open2 250b-4l-ep8``, 40 held of 320 of 4,096 x 1,280, top-8; PR 53:
+``lfm2_moe 24b-a2b-9l``, 64 of 2,048 x 1,536, top-4).
 """
 
 import argparse
@@ -51,11 +57,13 @@ import trace_reduce
 from deepspeed_tpu.models import moe_lm
 from deepspeed_tpu.models.presets import get_model
 from deepspeed_tpu.moe.sharded_moe import sorted_dispatch
-from deepspeed_tpu.ops.pallas import grouped_expert_mlp as kernel_module
+# the module, which the package's function of the same name hides
+kernel_module = sys.modules[moe_lm.grouped_expert_mlp.__module__]
 
 FORMS = ("sorted", "dense", "kernel", "gmm")
 N_LAYER = 8
 _f_tile = kernel_module._f_tile         # the kernel's own rule (--f-tile 0)
+ROW_TILE = kernel_module.ROW_TILE       # and its own row tile (--row-tile 0)
 
 
 def _gmm_mlp(model, lp, whole, layer, h):
@@ -64,7 +72,7 @@ def _gmm_mlp(model, lp, whole, layer, h):
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     E = model.moe.num_experts
     tokens = h.reshape(-1, h.shape[-1])
-    weights, experts, _ = model._route(lp, tokens)
+    weights, experts, *_ = model._route(lp, tokens)
     rows = tokens.shape[0] * model.moe.k
     tm = next(t for t in (128, 64, 32, 16, 8) if rows % t == 0)
 
@@ -97,6 +105,10 @@ def main():
                          "(0: as the router has it)")
     ap.add_argument("--f-tile", type=int, nargs="+", default=[0],
                     help="the kernel form's F tile (0: its own)")
+    ap.add_argument("--row-tile", type=int, nargs="+", default=[0],
+                    help="rows of an expert's own a visit of the kernel form "
+                         "computes at a time in a call past its RIDE_ROWS "
+                         "(0: its own ROW_TILE)")
     args = ap.parse_args()
     platform = jax.devices()[0].platform
     if platform != "tpu":
@@ -116,10 +128,10 @@ def main():
 
     def folded(lp, tokens):
         """The router's choices folded onto the first ``touched[0]`` experts."""
-        weights, experts, probs = route(lp, tokens)
+        weights, experts, *rest = route(lp, tokens)
         if touched[0]:
             experts = jnp.where(experts < E, experts * touched[0] // E, E)
-        return weights, experts, probs
+        return (weights, experts, *rest)
     model._route = folded
 
     variants = []
@@ -128,11 +140,13 @@ def main():
             for form in args.forms:
                 if form == "kernel" and rows > kernel_module.MAX_ROWS:
                     continue
+                tiled = form == "kernel" and rows > kernel_module.RIDE_ROWS
                 for tf in (args.f_tile if form == "kernel" else [0]):
-                    variants.append((rows, n, form, tf))
+                    for tm in (args.row_tile if tiled else [0]):
+                        variants.append((rows, n, form, tf, tm))
 
     runs, outs = {}, {}
-    for rows, n, form, tf in variants:
+    for rows, n, form, tf, tm in variants:
         x = jax.random.normal(jax.random.key(rows), (1, rows, cfg.d_model),
                               jnp.bfloat16)
 
@@ -156,21 +170,23 @@ def main():
                 h = h * jax.lax.rsqrt(jnp.mean(h * h, axis=-1, keepdims=True))
                 return h.astype(x.dtype), counts
             return jax.lax.scan(body, x, (sliced, jnp.arange(N_LAYER)))
-        name = f"moe_{form}_r{rows}_t{n}_f{tf}"
+        name = f"moe_{form}_r{rows}_t{n}_f{tf}_m{tm}"
         # the name is what the trace files the program's executions under
         stack.__name__ = name
         # read while tracing
         moe_lm._SORTED_DISPATCH_MIN_ROWS = 0 if form == "sorted" else 1 << 30
         kernel_module._f_tile = (lambda *_, tf=tf: tf) if tf else _f_tile
+        kernel_module.ROW_TILE = tm or ROW_TILE
         touched[0] = n
         run = jax.jit(stack)
         try:
             outs[name] = jax.block_until_ready(run(mlp, x))
         except Exception as e:      # a form the compiler refuses: say so, go on
             print(json.dumps({"rows": rows, "touched": n, "form": form,
-                              "f_tile": tf, "error": str(e)[:400]}), flush=True)
+                              "f_tile": tf, "row_tile": tm,
+                              "error": str(e)[:400]}), flush=True)
             continue
-        runs[name] = (run, x, rows, n, form, tf)
+        runs[name] = (run, x, rows, n, form, tf, tm)
 
     trace_dir = tempfile.mkdtemp(prefix="moe_dispatch_bench_")
     opts = jax.profiler.ProfileOptions()
@@ -185,7 +201,7 @@ def main():
     programs = trace["devices"][trace_reduce.busiest_device(trace)]["programs"]
 
     first = {}
-    for name, (_, _, rows, n, form, tf) in runs.items():
+    for name, (_, _, rows, n, form, tf, tm) in runs.items():
         took = [d for prog, _, d in programs if name + "(" in prog + "("]
         if len(took) != args.reps:
             sys.exit(f"{name}: {len(took)} executions in the trace, "
@@ -201,6 +217,8 @@ def main():
                     round(layer_bytes * n_touched / E / ms / 1e6, 1)}
         if form == "kernel":
             line["f_tile"] = tf or _f_tile(cfg.d_model, model.expert_ff, 2)
+            if rows > kernel_module.RIDE_ROWS:
+                line["row_tile"] = tm or ROW_TILE
         ref = first.setdefault((rows, n), out.astype(jnp.float32))
         line["max_abs_diff_from_first_form"] = \
             float(jnp.abs(out.astype(jnp.float32) - ref).max())

@@ -497,8 +497,9 @@ def blockgen_leg(name, dry_run, n_dev):
     paged kernel (a row's 4 positions on its position axis) and the
     prefill the flash kernel's staircase, and the tokens are those of the
     same weights on the plain-XLA forms. The experts of a pass (a call of
-    16 positions) must have been read by the grouped expert kernel
-    (``experts=grouped_kernel``)."""
+    16 positions) and of both prefill buckets (128 tokens: all rows ride
+    every visit; 256: each expert over its own rows) must have been read by
+    the grouped expert kernel (``experts=grouped_kernel``, never ``dense``)."""
     import jax
     import numpy as np
 
@@ -535,8 +536,9 @@ def blockgen_leg(name, dry_run, n_dev):
     assert dry_run or not any(k.endswith("=interpret") for k in forms), forms
     if n_dev == 1:
         how = "interpret" if dry_run else "compiled"
-        # a pass's 16 positions and the 128-token bucket take the grouped
-        # expert kernel, the 256-token bucket the dense form
+        # a pass's 16 positions and both buckets take the grouped expert
+        # kernel (the 256-token one past one row tile), none the dense form
+        assert "experts=dense" not in forms, forms
         for need in ("paged_block=paged_kernel", "paged_prefill=flash",
                      "experts=grouped_kernel",
                      f"kernel/paged_decode_attention={how}",

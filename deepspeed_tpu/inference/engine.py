@@ -1700,6 +1700,12 @@ class _ServeSession:
         # a model with zero-compute experts (``count_moe``)
         self.zero_experts = bool(getattr(getattr(
             engine.module, "moe", None), "zero_experts", 0))
+        # the row tile of the decode program's grouped expert kernel; 0: its
+        # call is of one row tile, or takes another form (``count_moe``)
+        tile_of = getattr(engine.module, "expert_row_tile", None)
+        self.moe_row_tile = tile_of(
+            engine.params, W * (sched.gen.block if sched.gen else 1)) \
+            if tile_of else 0
         self._programs = dict(zip(_DISPATCH_SITES, jits))
         # fault containment (serving.fault): the action a fault can be
         # attributed to, the finer-grained dispatch site for the
@@ -2223,7 +2229,8 @@ class _ServeSession:
         rows = [(r, t) for r, t, live in zip(
             reqs, step.kind.tokens(got, reqs), step.live) if live]
         if step.aux and tel is not None:
-            tel.count_moe(np.asarray(step.aux[0]), self.zero_experts)
+            tel.count_moe(np.asarray(step.aux[0]), self.zero_experts,
+                          self.moe_row_tile)
         if self.ev is not None:
             # AFTER the tokens' fetch (it is the sync: emitting first
             # would clock async dispatch; a step that fetched none

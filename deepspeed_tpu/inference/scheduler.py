@@ -437,13 +437,17 @@ class ServingTelemetry:
             "its first block is not one): over block_row_passes, the tokens "
             "a row's pass yields").inc(n)
 
-    def count_moe(self, counts, zero_experts: bool = False) -> None:
+    def count_moe(self, counts, zero_experts: bool = False,
+                  row_tile: int = 0) -> None:
         """One fused decode step of an MoE model. ``counts`` [L, E + 1], the
         program's own: the assignments each expert of each layer computed
         (padding rows excluded) and, in the last column, those the layer
         owed (real rows x k). ``zero_experts`` (a model with zero-compute
         experts): two more columns, the assignments those took and all the
-        router made. The ``serving/moe_*`` counters exist only once
+        router made. ``row_tile``: the rows of an expert's own a visit of
+        the grouped expert kernel computes at a time in this step's call (0:
+        all rows ride every visit, or another form ran). The
+        ``serving/moe_*`` counters exist only once
         an MoE model has decoded (they are not pre-created: a reader that
         requires them finds nothing under a dense model); resolved per
         access like every family here (0.4 us each), so a registry reset
@@ -471,6 +475,13 @@ class ServingTelemetry:
           "experts with at least one row, summed over layers and decode "
           "steps: over moe_layer_steps, the expert weights a layer reads"
           ).inc(int((computed > 0).sum()))
+        if row_tile:
+            c("serving/moe_expert_row_tiles",
+              "row tiles the grouped expert kernel computed (an expert's "
+              "own rows, a tile at a time), summed over layers and decode "
+              "steps whose call is past one row tile: over "
+              "moe_experts_touched, 1.0 when no expert needed a second"
+              ).inc(int((-(-computed // row_tile)).sum()))
         c("serving/moe_max_expert_load",
           "rows of the busiest expert, summed over layers and decode steps"
           ).inc(int(computed.max(axis=1).sum()))

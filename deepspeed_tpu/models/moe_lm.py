@@ -26,6 +26,7 @@ from deepspeed_tpu.ops.pallas.grouped_expert_mlp import (MAX_ROWS,
                                                          dense_expert_mlp,
                                                          envelope_ok,
                                                          grouped_expert_mlp,
+                                                         own_rows, row_tile,
                                                          touched_visits)
 from deepspeed_tpu.utils.init_on_device import honors_on_device
 from deepspeed_tpu.moe.sharded_moe import (dense_dispatch, dispatch_combine,
@@ -37,8 +38,8 @@ from deepspeed_tpu.moe.sharded_moe import (dense_dispatch, dispatch_combine,
 # site ``experts``), chosen by the rows of a call and by nothing else.
 #
 # ``grouped_kernel``: a PAGED program's call of at most
-# ``_GROUPED_KERNEL_MAX_ROWS`` rows (a decode step; a short prefill bucket)
-# where a bare Pallas call is legal (one device; ``T._use_flash``) reads the
+# ``_GROUPED_KERNEL_MAX_ROWS`` rows (a decode step; a prefill bucket) where
+# a bare Pallas call is legal (one device; ``T._use_flash``) reads the
 # experts its rows chose from the layer stack in place
 # (ops/pallas/grouped_expert_mlp.py): time goes with the TOUCHED experts'
 # bytes. Measured on a v5e (benchmarks/moe_dispatch_bench.py --forms dense
@@ -53,15 +54,22 @@ from deepspeed_tpu.moe.sharded_moe import (dense_dispatch, dispatch_combine,
 # x 1,280: 1.811 | 1.712 (38.5); SDAR's 16 held of 128 of 2,048 x 768: 64
 # rows 0.210 | 0.208, 128 rows 0.216 | 0.215 (16). jax's own megablox.gmm
 # over the same whole stacks (three calls behind a sort): 0.898 at
-# SmallThinker's 16 rows, 0.333 / 0.567 / 0.754 at 16 / 32 / 50. The kernel
-# carries every row on every visit, one MXU row tile: past 128 rows a
-# visit's products outlast its weights' copy (SDAR's 256 positions: dense
-# 0.244 at the chip's ridge) and rows would have to be sorted to their
-# experts first, which is not built (ROADMAP S13).
+# SmallThinker's 16 rows, 0.333 / 0.567 / 0.754 at 16 / 32 / 50. Up to 128
+# rows every row rides every visit, one MXU row tile; past it a visit's
+# products would outlast its weights' copy, so each expert computes its OWN
+# rows, 32 at a time, gathered from and added back to the resident rows
+# inside the kernel (PR 53). The same bench, PERF.md section 6, PR 53, call
+# 1, ms a layer, dense | kernel, all experts touched: LFM2's 64 of 2,048 x
+# 1,536, top-4: 256 rows 1.935 | 1.623, 384 rows 2.623 | 1.632, 512 rows
+# 3.288 | 1.640; SDAR's 256 positions 0.2443 | 0.2154, 512 rows 0.4293 |
+# 0.2237; Solar's 256 rows 1.962 | 1.800, 512 rows 3.631 | 1.888; OLMoE's
+# 256 rows 1.243 | 1.082, 512 rows 2.219 | 1.133: ahead at every measured
+# shape, so the number is the kernel's largest call, 512. Calls of 513 rows
+# to the ragged side keep ``dense`` (Solar's 1,024 bucket: ROADMAP S13 (b)).
 #
 # ``dense`` (sharded_moe.dense_dispatch: every held expert over every row):
 # every other call of fewer than ``_SORTED_DISPATCH_MIN_ROWS`` rows (more
-# than 128 rows; a mesh; int8 experts; ungated experts; training and the
+# than 512 rows; a mesh; int8 experts; ungated experts; training and the
 # dense-workspace cache), the kernel's plain-XLA twin and the CPU's form.
 #
 # ``ragged`` (sorted_dispatch over jax.lax.ragged_dot) from
@@ -424,6 +432,13 @@ class MoECausalLM:
                             for g in groups if root in g
                             for k in self._expert_keys()))
 
+    def expert_row_tile(self, params, rows: int) -> int:
+        """The rows of an expert's own a visit of the grouped kernel computes
+        at a time in a paged program's call of ``rows`` rows; 0 where all
+        rows ride every visit or the call takes another form (what the
+        engine's ``serving/moe_expert_row_tiles`` counts by)."""
+        return row_tile(rows) if self._grouped_kernel(params, rows) else 0
+
     def _nodrop_mlp(self, lp, x, valid=None, route_x=None, stack=None):
         """A score an expert in float32 (softmax, or sigmoid with a
         selection bias), the k largest as they are, every assignment to an
@@ -449,8 +464,12 @@ class MoECausalLM:
         rows = tokens.shape[0]
         form = "grouped_kernel" if stack is not None else \
             "dense" if rows < _SORTED_DISPATCH_MIN_ROWS else "ragged"
+        # a kernel call past one row tile: the rows of an expert's own a
+        # visit computes at a time (``row_tile``), else 0
+        tm = row_tile(rows) if stack is not None else 0
         dispatch.record("experts", form,
-                        f"rows={rows} k={moe.k} E={E} D={D} F={self.expert_ff}")
+                        f"rows={rows} k={moe.k} E={E} D={D} F={self.expert_ff}"
+                        + (f" tm={tm}" if tm else ""))
         with jax.named_scope("router"):
             weights, experts, probs, zero = self._route(
                 lp, tokens if route_x is None else route_x.reshape(-1, D))
@@ -493,15 +512,17 @@ class MoECausalLM:
                 return out + combine @ p["b_down"]
 
         def kernel(xs, combine):
-            """The touched experts over every row, the stacks in place."""
+            """The touched experts over every row (past one row tile: each
+            over its own rows), the stacks in place."""
             whole, layer = stack
             with jax.named_scope("moe_dispatch"):
                 visits = touched_visits(combine)
+                own = own_rows(combine, tm) if tm else None
             with jax.named_scope("experts"):
                 w = {k: a.reshape(-1, *a.shape[2:]) for k, a in whole.items()}
                 return grouped_expert_mlp(
                     xs, combine, w["w_gate"], w["w_up"], w["w_down"],
-                    layer * E, relu=relu, visits=visits)
+                    layer * E, relu=relu, visits=visits, rows=own)
 
         if form == "ragged":
             # a share computes ~E / width of its rows' assignments: four

@@ -42,8 +42,10 @@ Sites and their forms:
                       MoE layer's expert matmuls, from the rows of a call:
                       the Pallas kernel over the touched experts of the
                       layer stack in place, a paged program's calls of at
-                      most 128 rows on one device | every held expert over
-                      every row | ``jax.lax.ragged_dot`` over sorted rows,
+                      most 512 rows on one device (past 128 each expert
+                      over its own rows: ``tm=`` in the detail) | every
+                      held expert over every row |
+                      ``jax.lax.ragged_dot`` over sorted rows,
                       from 1,536 rows on)
 ``flash_bwd_diag``    ``chunks`` | ``whole`` (how the flash backward kernels
                       take a diagonal block, from the static shapes of a

@@ -419,7 +419,8 @@ def test_block_step_keeps_the_pools_in_place_for_v5e(one_v5e, monkeypatch):
     one a position), the pools are aliased, the temporaries
     stay under a quarter of them and no ``copy``, ``dynamic-slice`` or
     ``dynamic-update-slice`` of a layer's pool size exists. So does the
-    block-causal prefill of 256 tokens through the flash kernel."""
+    block-causal prefill of 256 tokens through the flash kernel. Both
+    programs' experts (256 rows) are the grouped expert kernel's."""
     import deepspeed_tpu.comm as dist
     from deepspeed_tpu.inference import blockgen
     from deepspeed_tpu.models.presets import get_model
@@ -469,8 +470,11 @@ def test_block_step_keeps_the_pools_in_place_for_v5e(one_v5e, monkeypatch):
         assert mem.temp_size_in_bytes < pool_bytes / 4, (
             name, mem.temp_size_in_bytes, pool_bytes)
         text = compiled.as_text()
-        # one kernel call in the layer scan's body
-        assert text.count('custom_call_target="tpu_custom_call"') == 1, name
+        # two kernel calls in the layer scan's body: the attention's and,
+        # since PR 53, the experts' (a call of 256 rows: each expert over
+        # its own rows)
+        assert text.count('custom_call_target="tpu_custom_call"') == 2, name
+        assert text.count("grouped_expert_mlp_own_rows") >= 1, name
         moved = []
         for m in re.finditer(
                 r"= \w+\[([\d,]+)\]\S* "

@@ -121,8 +121,9 @@ def test_the_grouped_expert_kernel_is_the_reference_too(toy, n_prompt):
     """The paged programs on their Pallas forms, interpreted: a period of
     four layers, one stack of experts a position of the period read in place
     by ``ops/pallas/grouped_expert_mlp.py`` through the period's offset (the
-    decode step's 3 rows; the 64-token bucket too, the 512-token one keeps
-    the dense form), the router fed the mixer's input, the ReLU gate."""
+    decode step's 3 rows; the 64-token bucket too, and since PR 53 the
+    384-token one, past one row tile: each expert over its own rows), the
+    router fed the mixer's input, the ReLU gate."""
     from deepspeed_tpu.ops import dispatch
     with open(os.path.join(BENCH, "configs", TOY + ".json")) as f:
         preset = json.load(f)["preset"]
@@ -133,8 +134,8 @@ def test_the_grouped_expert_kernel_is_the_reference_too(toy, n_prompt):
     got = paged_logits(model, toy[1], tokens, n_prompt, bs=128)
     chosen = dispatch.selected()
     n_layer = len(model.config.period)
-    assert chosen["experts=grouped_kernel"] == n_layer * (2 if n_prompt == 60 else 1)
-    assert chosen.get("experts=dense", 0) == n_layer * (n_prompt != 60)
+    assert chosen["experts=grouped_kernel"] == n_layer * 2
+    assert "experts=dense" not in chosen
     want = reference_logits(toy, tokens)[n_prompt - 1:]
     assert np.abs(got - want).max() < LOGIT_TOL, np.abs(got - want).max(axis=-1)
 

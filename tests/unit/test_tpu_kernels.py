@@ -892,6 +892,13 @@ EXPERT_CELLS = {
     "olmoe": (64, 8, 64, 64, 2048, 1024),
     "sdar_bucket": (128, 8, 16, 128, 2048, 768),
     "solar": (128, 8, 40, 320, 4096, 1280),
+    # calls past one row tile (PR 53: a visit computes its expert's own
+    # rows): LFM2's decode step, SDAR's 256-position pass, Solar's largest
+    # taken prefill bucket, and every row of a step on ONE expert
+    "lfm2_step": (256, 4, 64, 64, 2048, 1536),
+    "sdar_pass": (256, 8, 16, 128, 2048, 768),
+    "solar_bucket_512": (512, 8, 40, 320, 4096, 1280),
+    "lfm2_step_every_row_on_one_expert": (256, 1, 64, 64, 2048, 1536),
 }
 
 
@@ -902,7 +909,9 @@ def test_grouped_expert_mlp_compiles_and_matches(tpu, cell):
     of a two-layer stack read through the layer offset, against its
     plain-XLA twin over that layer's slice: bf16 operands, float32 sums.
     Both round the hidden values to bf16 (the twin after the routing
-    weight, the kernel before it), so they differ by that rounding."""
+    weight, the kernel before it), so they differ by that rounding. A call
+    past one row tile is held to the twin in float32 instead (the same bf16
+    weights and rows, every product and sum in float32)."""
     import jax
     import jax.numpy as jnp
 
@@ -919,7 +928,10 @@ def test_grouped_expert_mlp_compiles_and_matches(tpu, cell):
     x = draw(ks[3], (T, D), 1.0)
     weights, experts, _ = topk_routing(jax.random.normal(ks[4], (T, R)), k, True)
     experts = jnp.where(experts < E, experts, E)       # held elsewhere
+    if cell.endswith("one_expert"):
+        experts = jnp.full_like(experts, 3)
     valid = (jnp.arange(T) % 5 != 4).astype(jnp.int32)  # padding rows
+    f32 = (lambda a: a.astype(jnp.float32)) if T > 128 else (lambda a: a)
 
     @jax.jit
     def both(x, w_gate, w_up, w_down):
@@ -928,10 +940,12 @@ def test_grouped_expert_mlp_compiles_and_matches(tpu, cell):
             lambda xs, c: grouped_expert_mlp(xs, c, w_gate, w_up, w_down,
                                              jnp.int32(E), interpret=False),
             valid)
-        want, _ = dense_dispatch(
-            x, weights, experts, E,
-            lambda xs, c: dense_expert_mlp(xs, c, w_gate[E:], w_up[E:],
-                                           w_down[E:]), valid)
+        with jax.default_matmul_precision("highest"):
+            want, _ = dense_dispatch(
+                f32(x), weights, experts, E,
+                lambda xs, c: dense_expert_mlp(xs, c, f32(w_gate[E:]),
+                                               f32(w_up[E:]), f32(w_down[E:])),
+                valid)
         return got, want, n
 
     got, want, n = both(x, w_gate, w_up, w_down)
