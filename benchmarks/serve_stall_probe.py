@@ -10,8 +10,12 @@ takes ``perfbench/run.py``'s own arguments (``--stacks`` and
 and prints, beside its lines,
 
 * ``stall counters over the window``: the growth of ``host/gc_*``, of
-  ``serving/decode_steps{,_ahead,_late}`` and of ``serving/prefill_{steps,
-  tokens,padded_tokens}`` between the window's marks, and the
+  ``serving/decode_steps{,_ahead,_late}``, of ``serving/prefill_{steps,
+  tokens,padded_tokens}`` and of the loop's own time (PR 54:
+  ``serving/late_ms`` and ``late_slack_ms``, the TIME the late steps' device
+  had nothing queued, as a bracket; ``serving/loop_*`` and
+  ``serving/commit_*``, of which the manifest enters only some for the
+  mixed cell) between the window's marks, and the
   mean full (generation 2) pause, ``host/gc_full_pause_ms`` over
   ``host/gc_full_collections``: the per-layer metrics hold the count and the
   share, no line of the harness holds the pause;
@@ -66,13 +70,18 @@ COUNTERS = ("host/gc_pause_ms", "host/gc_full_pause_ms",
             # closed loop's tokens/s (PERF.md section 6, PR 43)
             "serving/prefill_steps", "serving/prefill_tokens",
             "serving/prefill_padded_tokens")
+#: the loop's own time (``monitor.trace.LoopTime``), by family
+LOOP_TIME = ("serving/late_", "serving/loop_", "serving/commit_")
 
 
 def stall_counters(marks):
-    """Growth of :data:`COUNTERS` between a window's marks (a counter the
-    program does not have is left out) and the mean full pause in ms."""
+    """Growth of :data:`COUNTERS` and of the :data:`LOOP_TIME` families
+    between a window's marks (a counter the program does not have is left
+    out) and the mean full pause in ms."""
     start, end = marks["start"]["counters"], marks["end"]["counters"]
     grown = {k: end[k] - start.get(k, 0.0) for k in COUNTERS if k in end}
+    grown.update({k: end[k] - start.get(k, 0.0) for k in sorted(end)
+                  if k.startswith(LOOP_TIME)})
     full = grown.get("host/gc_full_collections")
     if full:
         grown["mean_full_pause_ms"] = grown["host/gc_full_pause_ms"] / full

@@ -32,7 +32,7 @@ import numpy as np
 import deepspeed_tpu.comm as dist
 from deepspeed_tpu.inference import blockgen
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
-from deepspeed_tpu.monitor.trace import span
+from deepspeed_tpu.monitor.trace import LoopTime, span
 from deepspeed_tpu.utils.fault_injection import step_fault as _step_fault
 from deepspeed_tpu.utils.logging import log_dist, logger, warn_once
 
@@ -1667,7 +1667,10 @@ class _ServeSession:
     no second thread. The queue is one deep, so a decode step launched when
     the step in flight has ALREADY finished found the device dry: the launch
     counts those (``serving/decode_steps_late``, one non-blocking
-    ``is_ready()``), the stalls of the host that outlasted a device step.
+    ``is_ready()``), the stalls of the host that outlasted a device step,
+    and with a telemetry how LONG the device was dry, as a bracket
+    (``serving/late_ms``, ``late_slack_ms``: ``self.loop``, which also
+    books the loop's phases, :meth:`phase`).
     Nobody but :meth:`step` and :meth:`land` may touch a step in flight, and
     everything that needs its tokens on the host or
     would undo its rows lands it first — :meth:`cancel`,
@@ -1760,6 +1763,23 @@ class _ServeSession:
         # the rows' open blocks as the newest pass left them, on the device
         self._gen = sched.gen
         self._blk_dev = None
+        #: the loop's own time (``monitor.trace.LoopTime``), where there is
+        #: a telemetry to publish it; without one no phase reads a clock
+        self.loop = LoopTime() if sched.telemetry is not None else None
+
+    def phase(self, name, **args):
+        """``with self.phase("commit"):`` — the loop's phase ``name``:
+        ``span("serve.<name>", **args)`` and, with a telemetry, its time in
+        ``self.loop``."""
+        if self.loop is None:
+            return span("serve." + name, **args)
+        return self.loop.phase(name, **args)
+
+    def _booking(self):
+        """A step's bookkeeping between the spans (the launch's positional
+        half, the landing's rows, MoE counts and recorder events): a
+        counter of the loop's time with a telemetry, and never a span."""
+        return nullcontext() if self.loop is None else self.loop.phase("book")
 
     # ---- request front-end ---- #
 
@@ -1811,16 +1831,19 @@ class _ServeSession:
         self.last_action = None      # a fault in next_action itself must
         self.fault_site = None       # not be attributed to the PREVIOUS
         # step's action or dispatch site
-        if self._flight is not None \
-                and not self.sched.plans_ahead(self._flight.reqs):
+        # whatever else it does, a step lands the step it finds in flight
+        worked = self._flight is not None
+        if worked and not self.sched.plans_ahead(self._flight.reqs):
             self.land()
         try:
-            with span("serve.schedule"):
+            with self.phase("schedule"):
                 action = self.sched.next_action()
         except BaseException:
             self.land()              # its tokens are sound
             raise
         if action is None:
+            if worked:
+                self._count_step()
             if self._flight is not None:
                 self.land()          # an EOS may yet free what a queued
                 return True          # request waits for: choose again
@@ -1849,7 +1872,8 @@ class _ServeSession:
                 raise
             self.land()              # the step before, under this one
             if launched is not None:
-                self._advance(launched)
+                with self._booking():
+                    self._advance(launched)
                 self._flight = launched
                 if (launched.tok is None and self._gen is None) \
                         or not kind.ahead or not self._run_ahead:
@@ -1860,9 +1884,15 @@ class _ServeSession:
                     self.land()
             if self._finished_seen < len(self.sched.finished):
                 # retirements the launch itself made (an admission's error)
-                with span("serve.commit"):
+                with self.phase("commit"):
                     self._flush_finished()
+        if worked or launched is not None:
+            self._count_step()
         return True
+
+    def _count_step(self) -> None:
+        if self.loop is not None:
+            self.loop.steps += 1
 
     def _chunk_len(self, req) -> int:
         """Tokens the next prefill chunk of ``req`` computes."""
@@ -2059,7 +2089,7 @@ class _ServeSession:
         req.fetch_pending = []
         if not fetches:
             return pools
-        with span("serve.kv_fetch", blocks=len(fetches)):
+        with self.phase("kv_fetch", blocks=len(fetches)):
             return self._land_fetches(req, pools, fetches)
 
     def _land_fetches(self, req, pools, fetches):
@@ -2128,7 +2158,7 @@ class _ServeSession:
         self.fault_site = site
         if pre:
             _step_fault(site, "pre")
-        with span("serve.dispatch") if spanned else nullcontext():
+        with self.phase("dispatch") if spanned else nullcontext():
             t0 = time.monotonic_ns() if self.ev is not None else 0
             out = self._programs[site](*map(_on_device, operands))
             if post:
@@ -2174,29 +2204,40 @@ class _ServeSession:
             # each adopted as it lands: a fault at a later site leaves
             # the pools the earlier ones handed back
             self.pools = sub(self, reqs[0], self.pools)
-        with span("serve.inputs"):
+        loop = self.loop
+        with self.phase("inputs"):
             (toks, *rest), part = kind.inputs(self, reqs)
         if kind.fed and self._flight is not None:
             self.sched.stats["decode_steps_ahead"] += 1
             if tel is not None:
                 tel.decode_steps_ahead.inc()
             tok = self._flight.tok
-            if tok is not None and tok.is_ready():
+            # one query at the launch: with a telemetry, the one the inputs'
+            # exit has just made for the late time's bracket
+            if tok is not None and (tok.is_ready() if loop is None
+                                    else loop.finished):
                 # the queue is one deep: the step in flight has finished,
                 # so the device has nothing to run until this dispatch
                 self.sched.stats["decode_steps_late"] += 1
                 if tel is not None:
                     tel.decode_steps_late.inc()
+        before_ns = 0 if loop is None else loop.t
         spent = self.pools
         (logits, pools, *aux), t0 = self._dispatch(
             name, self.engine.params, toks, spent, *rest, pre=False)
         self.pools = pools
+        if loop is not None:
+            # how long the device had been dry when this dispatch returned
+            loop.launched(self._flight is not None, before_ns)
         if aux and tel is not None:
             # an MoE model's assignment counts, on their way beside
             # the tokens: no wait of their own
             aux[0].copy_to_host_async()
         tok = kind.sample(self, logits, reqs, part)
-        return _Launched(name, kind, reqs, part, tok, aux, t0, logits, spent)
+        step = _Launched(name, kind, reqs, part, tok, aux, t0, logits, spent)
+        if loop is not None:
+            loop.watch(step.tok)
+        return step
 
     def _advance(self, step: _Launched) -> None:
         """The positional half of a launched step, once the step before it
@@ -2220,40 +2261,82 @@ class _ServeSession:
         tel = self.sched.telemetry
         reqs, part = step.reqs, step.part
         try:
-            with span("serve.fetch"):
+            with self.phase("fetch"):
                 got = None if step.tok is None else np.asarray(step.tok)
         except BaseException:
             self.sched.abandon(reqs)     # recomputed, like a preemption
             raise
-        # a row whose EOS landed meanwhile is left out: its token is dropped
-        rows = [(r, t) for r, t, live in zip(
-            reqs, step.kind.tokens(got, reqs), step.live) if live]
-        if step.aux and tel is not None:
-            tel.count_moe(np.asarray(step.aux[0]), self.zero_experts,
-                          self.moe_row_tile)
-        if self.ev is not None:
-            # AFTER the tokens' fetch (it is the sync: emitting first
-            # would clock async dispatch; a step that fetched none
-            # waits in _book) and BEFORE the commit, so a retirement
-            # this step triggers lands after its last slice
-            rid, events = step.kind.events(
-                [r for r, _ in rows], part, [t for _, t in rows])
-            self._book(step.name, step.t0, events, rid=rid,
-                       sync=None if any(t for _, t in rows)
-                       else step.logits)
-        with span("serve.commit"):
-            for r, tokens in rows:
-                out = step.kind.record(self.sched, r, part, tokens)
-                if out is not None:
-                    tokens = out     # what the row streams of what landed
-                if tokens and self.on_tokens is not None:
-                    self.on_tokens(r, tokens)
+        with self._booking():
+            # a row whose EOS landed meanwhile is left out: its token is
+            # dropped
+            rows = [(r, t) for r, t, live in zip(
+                reqs, step.kind.tokens(got, reqs), step.live) if live]
+            if step.aux and tel is not None:
+                tel.count_moe(np.asarray(step.aux[0]), self.zero_experts,
+                              self.moe_row_tile)
+            if self.ev is not None:
+                # AFTER the tokens' fetch (it is the sync: emitting first
+                # would clock async dispatch; a step that fetched none
+                # waits in _book) and BEFORE the commit, so a retirement
+                # this step triggers lands after its last slice
+                rid, events = step.kind.events(
+                    [r for r, _ in rows], part, [t for _, t in rows])
+                self._book(step.name, step.t0, events, rid=rid,
+                           sync=None if any(t for _, t in rows)
+                           else step.logits)
+        with self.phase("commit"):
+            self._commit(step, rows)
             self._flush_finished()
-        with span("serve.release"):
+        with self.phase("release"):
             # the span is there because dropping the consumed pool handles
             # costs the host a millisecond (PERF.md §5); the step's logits
             # die under it too, and not at the return
             step.spent = step.logits = step.tok = None
+
+    def _commit(self, step: _Launched, rows) -> None:
+        """``record`` and ``on_tokens``, row by row. With a telemetry, the
+        newest launched step is asked whether it has finished every
+        ``COMMIT_POLL_ROWS`` rows (the late time's bracket stays that
+        narrow across the loop's longest phase), and on one landed step in
+        ``COMMIT_SAMPLE`` the two halves of each row are timed apart."""
+        loop = self.loop
+        if loop is None:
+            return self._commit_rows(step, rows)
+        if not rows:
+            return
+        timed = loop.landed % loop.COMMIT_SAMPLE == 0
+        loop.landed += 1
+        commit = self._commit_rows_timed if timed else self._commit_rows
+        for lo in range(0, len(rows), loop.COMMIT_POLL_ROWS):
+            commit(step, rows[lo:lo + loop.COMMIT_POLL_ROWS])
+            loop.poll_now()
+
+    def _commit_rows(self, step, rows) -> None:
+        record, sched, part = step.kind.record, self.sched, step.part
+        on_tokens = self.on_tokens
+        for r, tokens in rows:
+            out = record(sched, r, part, tokens)
+            if out is not None:
+                tokens = out         # what the row streams of what landed
+            if tokens and on_tokens is not None:
+                on_tokens(r, tokens)
+
+    def _commit_rows_timed(self, step, rows) -> None:
+        """:meth:`_commit_rows` with a clock read between a row's halves."""
+        record, sched, part = step.kind.record, self.sched, step.part
+        on_tokens, loop = self.on_tokens, self.loop
+        now_ns = loop.now
+        for r, tokens in rows:
+            t0 = now_ns()
+            out = record(sched, r, part, tokens)
+            t1 = now_ns()
+            if out is not None:
+                tokens = out
+            if tokens and on_tokens is not None:
+                on_tokens(r, tokens)
+            loop.record_ns += t1 - t0
+            loop.wake_ns += now_ns() - t1
+        loop.sampled_rows += len(rows)
 
     # ---- what differs by kind: the hooks _ACTION_KINDS names ---- #
 
@@ -2431,7 +2514,7 @@ class _ServeSession:
                 or (part is not None and sum(part) < reqs[0].prefill_target):
             # a model that generates by blocks samples nothing at a prefill
             return None
-        with span("serve.sample"):
+        with self.phase("sample"):
             key = temperature = None     # greedy draws nothing
             if self.temperature > 0.0:
                 self.rng, key = jax.random.split(self.rng)
@@ -2453,7 +2536,7 @@ class _ServeSession:
     def _greedy(self, logits, reqs, part):
         """Launch side of a verify step: the same argmax the decode path's
         sampler runs, at every window position."""
-        with span("serve.sample"):
+        with self.phase("sample"):
             return jnp.argmax(logits.astype(jnp.float32), axis=-1)
 
     @staticmethod

@@ -213,6 +213,8 @@ class ServingTelemetry:
         scheduler per serve call — re-creates after a registry reset."""
         for name in self._SERIES:
             getattr(self, name)
+        for name, text in self._LOOP.items():
+            self.registry.counter("serving/" + name, text)
 
     # families resolved per access (get-or-create under the registry
     # lock; serving events are host-side per engine step, not a jit hot
@@ -363,6 +365,77 @@ class ServingTelemetry:
           "gc_full_collections, the mean full pause").inc(full_pause_ms)
         c("host/gc_full_collections",
           "full (generation 2) garbage collections").inc(full)
+
+    #: the serving loop's own time (``monitor.trace.LoopTime``), by counter
+    #: less ``serving/``: "is my loop host-bound, and is it Python or the
+    #: lock". All on the host's one clock, over the loop's whole life.
+    _LOOP = {
+        "loop_steps":
+            "session steps of the serving loop that launched or landed "
+            "something: what the loop_*_ms counters are a step of",
+        "loop_busy_ms":
+            "wall clock of the loop's thread inside serve.step and outside "
+            "serve.fetch (where it waits for the device): over loop_steps, "
+            "the host's step, which bounds the loop where it is longer than "
+            "the device's",
+        "loop_cpu_busy_ms":
+            "of loop_busy_ms, the turns of the loop on which its thread's "
+            "CPU clock was read too (one in 8: that clock is a system call)",
+        "loop_cpu_ms":
+            "CPU time of the loop's thread over the stretches of "
+            "loop_cpu_busy_ms: busy less CPU is time it wanted to run and "
+            "did not (the interpreter lock held by the clients it woke, a "
+            "collection on another thread, a freeze of the machine)",
+        **{f"loop_{name}_ms":
+           f"of the loop's thread's wall clock, inside serve.{name} ({what}) "
+           "and outside any phase opened within it"
+           for name, what in (
+               ("schedule", "the scheduler's choice of the next action"),
+               ("inputs", "a launch's operands built in numpy"),
+               ("dispatch", "the call of a step's program"),
+               ("sample", "the sampler's dispatch"),
+               ("fetch", "the wait for a launched step's tokens: NOT part "
+                         "of loop_busy_ms"),
+               ("commit", "record and on_tokens row by row, the "
+                          "retirements"),
+               ("release", "the drop of what a landed step held"),
+               ("intake", "the front-end's commands"))},
+        "loop_book_ms":
+            "of the loop's thread's wall clock, a step's bookkeeping between "
+            "the spans (a phase with no span of its own): the launch's "
+            "positional half (advance_* a row), the landing's rows, MoE "
+            "counts and recorder events",
+        "late_ms":
+            "time the device had nothing queued, at least: at each launch "
+            "behind an unfetched step, from when that step was first seen "
+            "finished (is_ready() at a phase's exit) to the dispatch's "
+            "return; the lower end of a bracket late_slack_ms wide",
+        "late_slack_ms":
+            "at those launches, from when the step was last seen unfinished "
+            "to when it was first seen finished: the device was dry for "
+            "late_ms at least and late_ms + late_slack_ms at most",
+        "commit_sampled_rows":
+            "rows of the landed steps whose commit loop was timed row by "
+            "row (one landed step in 64)",
+        "commit_record_ms":
+            "over commit_sampled_rows, inside the scheduler's record (the "
+            "token's commit, a retirement)",
+        "commit_wake_ms":
+            "over commit_sampled_rows, inside on_tokens (the row's client "
+            "handed its token and woken)",
+    }
+
+    def count_loop(self, grown) -> None:
+        """What the serving loop's own time grew by since it last said
+        (``LoopTime.take``: ``*_ms`` in ns, the others counts), published
+        where ``count_gc`` is. ``serving/loop_kv_fetch_ms`` (the host
+        tier's blocks landing ahead of a prefill) exists only once that
+        has happened; every other family is pre-created."""
+        c = self.registry.counter
+        for name, n in grown.items():
+            if n:
+                c("serving/" + name, self._LOOP.get(name, "")).inc(
+                    n / 1e6 if name.endswith("_ms") else n)
 
     @property
     def decode_live_kv_tokens(self):

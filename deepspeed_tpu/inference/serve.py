@@ -64,6 +64,11 @@ import numpy as np
 
 from deepspeed_tpu.monitor.trace import gc_totals, span, watch_gc
 
+#: the loop's own time reaches the registry every so many steps (and when
+#: the loop goes idle): one publication is ~20 counters under the registry's
+#: lock, more than the clocks of a step cost together
+_LOOP_PUBLISH_STEPS = 16
+
 #: terminal handle statuses
 FINISHED, CANCELLED, ERROR, REJECTED, TIMEOUT = (
     "finished", "cancelled", "error", "rejected", "timeout")
@@ -491,8 +496,11 @@ class AsyncServingEngine:
     def _step_once(self) -> bool:
         """One loop iteration (``serve.step``), then what the garbage
         collector took meanwhile, on any thread, into the ``host/gc_*``
-        counters: one comparison where nothing was collected. Returns False
-        when the loop should exit."""
+        counters: one comparison where nothing was collected; and the
+        loop's own time (``LoopTime``) into the ``serving/loop_*``,
+        ``late_*`` and ``commit_*`` counters, every
+        ``_LOOP_PUBLISH_STEPS`` steps and when the loop goes idle. Returns
+        False when the loop should exit."""
         alive = self._commands_and_step()
         totals = gc_totals()
         if totals != self._gc_seen:
@@ -502,17 +510,23 @@ class AsyncServingEngine:
                     a - b for a, b in zip(totals, self._gc_seen))
                 tel.count_gc(pause / 1e6, full_pause / 1e6, full)
             self._gc_seen = totals
+        loop = self._session.loop
+        if loop is not None and loop.due(
+                _LOOP_PUBLISH_STEPS, idle=not alive
+                or self._session.sched.all_done()):
+            self._session.sched.telemetry.count_loop(loop.take())
         return alive
 
     def _commands_and_step(self) -> bool:
         """Commands, load shedding, exit checks, one engine step with fault
         containment."""
-        with span("serve.step"):
+        loop = self._session.loop
+        with span("serve.step") if loop is None else loop.step():
             with self._cv:
                 cmds = list(self._intake)
                 self._intake.clear()
             # an empty intake is no span: a trace holds what happened
-            with span("serve.intake", n=len(cmds)) if cmds \
+            with self._session.phase("intake", n=len(cmds)) if cmds \
                     else nullcontext():
                 for kind, h in cmds:
                     if kind == "submit":

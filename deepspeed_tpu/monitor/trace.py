@@ -68,6 +68,260 @@ def step_span(name: str, step: int):
 
 
 # ------------------------------------------------------------------ #
+# the serving loop's own time: the same spans, and counters beside them
+
+#: the host's one clock and the calling thread's CPU clock, as module
+#: attributes so a test can plant its own
+now_ns = time.perf_counter_ns
+cpu_ns = time.thread_time_ns
+
+
+class LoopTime:
+    """Where the serving loop's thread spends its step, as plain integers
+    (ns) that ``ServingTelemetry.count_loop`` publishes as the
+    ``serving/loop_*``, ``serving/late_*`` and ``serving/commit_*``
+    counters. A session has one where its scheduler has a telemetry, and
+    reads no clock where it has none. Single-threaded like the session: the
+    loop's thread alone calls it, so nothing here takes a lock.
+
+    **Phases** (:meth:`phase`): ``with loop.phase("commit"):`` enters
+    ``span("serve.commit")`` as the bare span did and adds the time to
+    ``ns["commit"]``: span and counter come from one ``with`` and cannot
+    drift apart. A phase opened inside another (a cancel lands the step in
+    flight under ``serve.intake``) stops the outer one's count while it is
+    open, so the phases never sum past the step. ``PHASES`` names them;
+    ``kv_fetch`` (the host tier's blocks landing ahead of a prefill, the
+    neighbour of ``inputs``) has a slot that stays 0, and is published
+    nowhere, unless a host tier is on. ``book`` is a phase with a counter
+    and NO span (``UNSPANNED``; a traced run pays for every span it
+    holds): a step's bookkeeping between the spans, which is a tenth of
+    the host's step where a step carries MoE counts or block plans.
+
+    **Busy and CPU** (:meth:`step`): around ``serve.step``, the wall
+    clock, less what ``fetch`` took inside it (there the thread waits for
+    the device): ``busy_ns``. On one turn of the loop in ``CPU_SAMPLE`` the
+    thread's CPU clock is read around the same stretches (it is a system
+    call, where the wall clock is not): ``cpu_ns`` of those turns'
+    ``cpu_busy_ns``. Busy less CPU is time the loop's thread wanted to run
+    and did not: the interpreter lock held by the clients it woke, a
+    collection on another thread, a freeze of the machine.
+
+    **Late time** (:meth:`poll_now`, :meth:`launched`): the device's queue is
+    one deep, so once the newest launched step has finished the device is
+    dry until the next dispatch returns. At every phase's exit (and every
+    ``COMMIT_POLL_ROWS`` rows of the commit loop) the step's tokens are
+    asked ``is_ready()`` until they say yes; ``launched`` adds, for a
+    launch behind an unfetched step, dispatch's return less the first yes
+    to ``late_ns`` (the device was dry AT LEAST that long) and the first
+    yes less the last no to ``slack_ns`` (AT MOST that much more). One
+    clock, the host's: a freeze of 120 ms reads 120 ms. A phase's exit
+    reads the clock just before it asks, so either end is off by the
+    query's own length at most.
+
+    **The commit loop's halves**: on one landed step in ``COMMIT_SAMPLE``
+    the session times each row's ``record`` and ``on_tokens`` apart
+    (``record_ns``, ``wake_ns`` over ``sampled_rows``)."""
+
+    PHASES = ("schedule", "inputs", "dispatch", "sample", "fetch", "commit",
+              "release", "intake", "kv_fetch", "book")
+    UNSPANNED = ("book",)
+    COMMIT_SAMPLE = 64
+    COMMIT_POLL_ROWS = 32
+    CPU_SAMPLE = 8
+
+    __slots__ = ("ns", "steps", "busy_ns", "cpu_ns", "cpu_busy_ns", "turns",
+                 "cpu_on", "late_ns", "slack_ns",
+                 "sampled_rows", "record_ns", "wake_ns", "landed",
+                 "_open", "t", "_fetch_cpu_ns", "_tok", "_no_ns", "_yes_ns",
+                 "_published", "_phases", "_step")
+
+    def __init__(self):
+        self.ns = dict.fromkeys(self.PHASES, 0)
+        #: session steps that launched or landed something
+        self.steps = 0
+        self.busy_ns = self.cpu_ns = self.cpu_busy_ns = 0
+        #: entries of :meth:`step`; every CPU_SAMPLE-th reads the CPU clock
+        self.turns = 0
+        self.cpu_on = False          # inside such a turn
+        self.late_ns = self.slack_ns = 0
+        self.sampled_rows = self.record_ns = self.wake_ns = 0
+        #: landed steps that committed rows (every COMMIT_SAMPLE-th is timed)
+        self.landed = 0
+        self._open = []              # the open phases, innermost last
+        #: the clock at the newest entry or exit of a phase
+        self.t = 0
+        self._fetch_cpu_ns = 0       # the thread's CPU time inside fetch
+        self._tok = None             # the newest launched step's tokens
+        self._no_ns = self._yes_ns = 0
+        self._published = {}
+        self._phases = {name: (_Fetch if name == "fetch" else _Phase)(
+            self, name) for name in self.PHASES}
+        self._step = _Step(self)
+
+    def phase(self, name: str, **args):
+        """The phase ``name`` as a context manager: ``span("serve.<name>",
+        **args)`` (none for a name in ``UNSPANNED``) and its time into
+        ``ns[name]``."""
+        return _Phase(self, name, args) if args else self._phases[name]
+
+    def step(self):
+        """``span("serve.step")`` with the busy time of the calling thread
+        taken around it, and on a sampled turn its CPU time."""
+        return self._step
+
+    @property
+    def finished(self) -> bool:
+        """Whether the newest launched step has been seen finished."""
+        return self._yes_ns != 0
+
+    @property
+    def polling(self) -> bool:
+        """Whether :meth:`poll_now` still has something to ask."""
+        return self._tok is not None and not self._yes_ns
+
+    def poll_now(self) -> None:
+        """Ask the newest launched step's tokens whether they are there
+        (one non-blocking query), if they have not said so: for a caller
+        inside a long phase (the commit loop); a phase's exit does the
+        same at the clock it has just read."""
+        if self._tok is not None and not self._yes_ns:
+            t = now_ns()
+            if self._tok.is_ready():
+                self._yes_ns = t
+            else:
+                self._no_ns = t
+
+    @staticmethod
+    def now() -> int:
+        """The host's clock, for a caller that times inside a phase."""
+        return now_ns()
+
+    def launched(self, behind: bool, before_ns: int) -> None:
+        """A step's dispatch has returned (at ``t``). ``behind``: the step
+        before it was unfetched at the launch (the first launch after an
+        idle wait is behind nothing and counts nothing); ``before_ns``: a
+        clock from before the dispatch, when the new step cannot have
+        finished. :meth:`watch` then names what to ask of it."""
+        if behind and self._yes_ns:
+            self.late_ns += self.t - self._yes_ns
+            self.slack_ns += self._yes_ns - self._no_ns
+        self._tok, self._no_ns, self._yes_ns = None, before_ns, 0
+
+    def watch(self, tok) -> None:
+        """The launched step's tokens on the device (None: a step that
+        samples nothing, of which nothing can be asked)."""
+        self._tok = tok
+
+    def due(self, every: int, idle: bool) -> bool:
+        """Whether :meth:`take` has ``every`` steps to say, or, going
+        ``idle``, any."""
+        unsaid = self.steps - self._published.get("loop_steps", 0)
+        return unsaid >= every or (idle and unsaid > 0)
+
+    def take(self) -> Dict[str, int]:
+        """What grew since the last call, by the name of its counter less
+        ``serving/`` (``*_ms`` in ns; the others are counts)."""
+        totals = {"loop_steps": self.steps, "loop_busy_ms": self.busy_ns,
+                  "loop_cpu_ms": self.cpu_ns,
+                  "loop_cpu_busy_ms": self.cpu_busy_ns,
+                  "late_ms": self.late_ns,
+                  "late_slack_ms": self.slack_ns,
+                  "commit_sampled_rows": self.sampled_rows,
+                  "commit_record_ms": self.record_ns,
+                  "commit_wake_ms": self.wake_ns}
+        for name, ns in self.ns.items():
+            totals[f"loop_{name}_ms"] = ns
+        seen, self._published = self._published, totals
+        return {k: v - seen.get(k, 0) for k, v in totals.items()}
+
+
+class _Phase:
+    """One phase of one :class:`LoopTime`, entered again and again (no
+    phase opens inside itself): the span is made anew at every entry."""
+    __slots__ = ("loop", "name", "full", "args", "span")
+
+    def __init__(self, loop, name, args=None):
+        self.loop, self.name, self.args = loop, name, args
+        self.full = None if name in loop.UNSPANNED \
+            else SPAN_PREFIX + "serve." + name
+        self.span = None
+
+    def __enter__(self):
+        loop = self.loop
+        t = now_ns()
+        if loop._open:
+            loop.ns[loop._open[-1]] += t - loop.t
+        loop._open.append(self.name)
+        loop.t = t
+        if self.full is not None:
+            # inside the phase's time: the span is part of what it costs
+            self.span = span_factory(self.full, **self.args) if self.args \
+                else span_factory(self.full)
+            self.span.__enter__()
+
+    def __exit__(self, *exc):
+        kept = None if self.span is None else self.span.__exit__(*exc)
+        loop = self.loop
+        t = now_ns()
+        loop.ns[loop._open.pop()] += t - loop.t
+        loop.t = t
+        tok = loop._tok
+        if tok is not None and not loop._yes_ns:   # ``poll_now`` at ``t``
+            if tok.is_ready():
+                loop._yes_ns = t
+            else:
+                loop._no_ns = t
+        return kept
+
+
+class _Fetch(_Phase):
+    """The wait for the device: on a turn that reads the thread's CPU
+    clock, that clock around it too."""
+    __slots__ = ("cpu",)
+
+    def __enter__(self):
+        if self.loop.cpu_on:
+            self.cpu = cpu_ns()
+        _Phase.__enter__(self)
+
+    def __exit__(self, *exc):
+        kept = _Phase.__exit__(self, *exc)
+        if self.loop.cpu_on:
+            self.loop._fetch_cpu_ns += cpu_ns() - self.cpu
+        return kept
+
+
+class _Step:
+    __slots__ = ("loop", "span", "t", "cpu", "fetch", "fetch_cpu")
+
+    def __init__(self, loop):
+        self.loop = loop
+
+    def __enter__(self):
+        loop = self.loop
+        self.fetch = loop.ns["fetch"]
+        loop.turns += 1
+        if loop.turns % loop.CPU_SAMPLE == 0:
+            loop.cpu_on = True
+            self.fetch_cpu, self.cpu = loop._fetch_cpu_ns, cpu_ns()
+        self.t = now_ns()
+        self.span = span("serve.step")
+        self.span.__enter__()
+
+    def __exit__(self, *exc):
+        kept = self.span.__exit__(*exc)
+        loop = self.loop
+        busy = now_ns() - self.t - (loop.ns["fetch"] - self.fetch)
+        loop.busy_ns += busy
+        if loop.cpu_on:
+            loop.cpu_on = False
+            loop.cpu_busy_ns += busy
+            loop.cpu_ns += cpu_ns() - self.cpu \
+                - (loop._fetch_cpu_ns - self.fetch_cpu)
+        return kept
+
+
+# ------------------------------------------------------------------ #
 # garbage collections: spans on the profiler's clock, totals for counters
 
 # One collection runs at a time, under the interpreter lock, so plain

@@ -5,7 +5,12 @@ kept trace is followed by; the spans change no token; the counters that
 ride along count what they say; ``range_push``/``range_pop`` keep to their
 thread; the serving programs carry names; garbage collections are ``gc``
 spans on whichever thread collects and counters the loop publishes; a
-decode step launched behind a finished one is counted late."""
+decode step launched behind a finished one is counted late; and the loop's
+own time (``LoopTime``) rides the same ``with`` statements: the same spans
+with a telemetry as without, each phase's counter the time planted under its
+span, busy and CPU time around ``serve.step`` less ``serve.fetch``, the late
+time a bracket around a planted finish, the commit loop timed on one landed
+step in 64, no clock read without a telemetry."""
 
 import gc
 import threading
@@ -405,6 +410,332 @@ def test_late_steps_count_launches_behind_a_finished_step(ready, monkeypatch):
         prompts, max_new_tokens=6)
     for a, b in zip(out, plain):                # the query lands nothing
         np.testing.assert_array_equal(a, b)
+
+
+# ---- the loop's own time: counters out of the spans' ``with`` ---- #
+
+LOOP_PHASES = ("schedule", "inputs", "dispatch", "sample", "fetch", "commit",
+               "release", "intake")
+#: ns planted under each span (even, so that half of it is whole)
+PLANTED = {"serve.step": 20_000, "serve.exec": 10_000, "serve.fetch":
+           5_000_000, **{"serve." + p: 1_000_000 + 2_000 * i
+                         for i, p in enumerate(LOOP_PHASES) if p != "fetch"}}
+
+
+class PlantedClock:
+    """The host's clock and the thread's CPU clock, planted: time passes
+    only where a span opens, by ``PLANTED`` of its name, and the thread is
+    on a CPU for half of it."""
+
+    def __init__(self, monkeypatch, recorder):
+        self.ns = 1_000_000_000
+        self.cpu_reads = 0
+        monkeypatch.setattr(trace_mod, "now_ns", lambda: self.ns)
+        monkeypatch.setattr(trace_mod, "cpu_ns", self.cpu)
+        monkeypatch.setattr(trace_mod, "span_factory", self.span)
+        self.recorder = recorder
+
+    def cpu(self):
+        self.cpu_reads += 1
+        return self.ns // 2
+
+    def span(self, name, **args):
+        clock, inner = self, self.recorder(name, **args)
+
+        class Planted:
+            def __enter__(self):
+                clock.ns += PLANTED.get(name[len(trace_mod.SPAN_PREFIX):], 0)
+                return inner.__enter__()
+
+            def __exit__(self, *exc):
+                return inner.__exit__(*exc)
+        return Planted()
+
+
+def toy_engine(model, telemetry=True):
+    from deepspeed_tpu.models.presets import get_model
+    return deepspeed_tpu.init_inference(
+        tiny_model() if model == "dense" else get_model("solar_open2", "tiny"),
+        dtype="fp32", telemetry=telemetry,
+        serving={"block_size": 8, "max_running": 3})
+
+
+def drive(engine, max_new=5):
+    """Three requests through the synchronous driver, one of them cancelled
+    while a step is in flight (its landing then lies under serve.intake);
+    returns the session's ``LoopTime`` (None without a telemetry)."""
+    serving = AsyncServingEngine(engine, max_new_tokens=max_new, start=False)
+    handles = [serving.add_request(np.arange(3, 14 + i, dtype=np.int32))
+               for i in range(3)]
+    for _ in range(4):
+        assert serving.step()
+    assert serving._session._flight is not None
+    handles[2].cancel()
+    while serving.step():
+        pass
+    assert [h.status for h in handles] == ["finished", "finished",
+                                           "cancelled"]
+    loop = serving._session.loop
+    serving.shutdown()
+    return loop
+
+
+@pytest.fixture(scope="module")
+def planted_runs():
+    """One planted run a model, for the cases below: the session's
+    ``LoopTime``, the spans it recorded and the counters it published."""
+    runs = {}
+
+    def run(model):
+        if model not in runs:
+            with pytest.MonkeyPatch.context() as mp:
+                rec = Recorder()
+                clock = PlantedClock(mp, rec)
+                get_registry().reset()
+                loop = drive(toy_engine(model))
+                counters = dict(get_registry().snapshot()["counters"])
+            runs[model] = (loop, rec, counters, clock)
+        return runs[model]
+    return run
+
+
+@pytest.mark.parametrize("phase", LOOP_PHASES)
+@pytest.mark.parametrize("model", ["dense", "stateful"])
+def test_a_phases_counter_is_the_time_under_its_span(model, phase,
+                                                     planted_runs):
+    loop, rec, counters, _ = planted_runs(model)
+    spans = rec.named(pb("serve." + phase))
+    assert spans, f"no serve.{phase} in the run"
+    # exclusive of whatever opened inside it: the cancel landed a step
+    # (fetch, commit, release) under serve.intake
+    assert loop.ns[phase] == len(spans) * PLANTED["serve." + phase]
+    assert counters[f"serving/loop_{phase}_ms"] \
+        == pytest.approx(loop.ns[phase] / 1e6)
+    if phase == "intake":
+        assert any(s["parent"] == pb("serve.intake")
+                   for s in rec.named(pb("serve.fetch")))
+
+
+@pytest.mark.parametrize("model", ["dense", "stateful"])
+def test_the_phases_cover_the_busy_time_and_never_pass_it(model,
+                                                          planted_runs):
+    loop, rec, counters, clock = planted_runs(model)
+    # ``book`` is a phase with a counter and no span: no time passes under
+    # it here, and nothing of its name is in the trace
+    assert loop.ns["book"] == 0 == counters["serving/loop_book_ms"]
+    assert not rec.named(pb("serve.book"))
+    in_step = sum(ns for p, ns in loop.ns.items() if p != "fetch")
+    outside = sum(len(rec.named(pb(n))) * PLANTED[n]
+                  for n in ("serve.step", "serve.exec"))
+    # every phase opened inside a serve.step here, so busy is the phases
+    # (less the wait for the device) and what the two bare spans took
+    assert loop.busy_ns == in_step + outside
+    assert 0.9 * loop.busy_ns <= in_step <= loop.busy_ns
+    # the thread's CPU clock (a system call) is read on one turn in 8,
+    # around serve.step and a serve.fetch inside it: the planted half
+    sampled = loop.turns // loop.CPU_SAMPLE
+    assert sampled >= 1 and loop.turns == len(rec.named(pb("serve.step")))
+    assert 0 < loop.cpu_ns * 2 == loop.cpu_busy_ns < loop.busy_ns
+    assert 2 * sampled <= clock.cpu_reads <= 6 * sampled
+    assert loop.ns["kv_fetch"] == 0 and \
+        "serving/loop_kv_fetch_ms" not in counters
+    # published with the steps they are a step of: the driver's last turn
+    # found nothing to do, counted no step, and its serve.step waits for
+    # the next publication
+    said = loop.busy_ns - PLANTED["serve.step"]
+    assert counters["serving/loop_busy_ms"] == pytest.approx(said / 1e6)
+    assert counters["serving/loop_cpu_ms"] * 2 \
+        == pytest.approx(counters["serving/loop_cpu_busy_ms"])
+    assert 0 < counters["serving/loop_cpu_busy_ms"] \
+        < counters["serving/loop_busy_ms"]
+    # steps that launched or landed something: every serve.exec launched
+    # (no ``wait`` action here), and the step that only landed the last
+    assert counters["serving/loop_steps"] == loop.steps \
+        >= len(rec.named(pb("serve.exec")))
+
+
+@pytest.mark.parametrize("model", ["dense", "stateful"])
+def test_the_helper_changes_no_span(model, planted_runs):
+    """Names, arguments, nesting and number of the spans of the same
+    requests, with the counters and without."""
+    _, counted, _, _ = planted_runs(model)
+    with pytest.MonkeyPatch.context() as mp:
+        bare = Recorder()
+        mp.setattr(trace_mod, "span_factory", bare)
+        assert drive(toy_engine(model, telemetry=False)) is None
+
+    def told(rec):
+        return [(s["name"], s["parent"], sorted(s["args"])) for s in rec.spans
+                if s["name"] != pb("gc")]
+    assert told(counted) == told(bare)
+
+
+def test_no_clock_is_read_without_a_telemetry(monkeypatch):
+    def no_clock():
+        raise AssertionError("a clock was read")
+    monkeypatch.setattr(trace_mod, "now_ns", no_clock)
+    monkeypatch.setattr(trace_mod, "cpu_ns", no_clock)
+    assert drive(toy_engine("dense", telemetry=False)) is None
+
+
+@pytest.mark.parametrize("name", sorted(
+    "serving/" + n for n in engine_mod.LoopTime().take()
+    if n != "loop_kv_fetch_ms"))
+def test_every_loop_family_exists_at_zero(name):
+    from deepspeed_tpu.inference.scheduler import ServingTelemetry
+    from deepspeed_tpu.monitor.metrics import MetricsRegistry
+    reg = MetricsRegistry()
+    tel = ServingTelemetry(reg)
+    assert reg.snapshot()["counters"][name] == 0
+    assert name[len("serving/"):] in tel._LOOP
+    reg.reset()
+    tel.ensure()
+    assert reg.snapshot()["counters"][name] == 0
+
+
+class _PlantedTok:
+    """Tokens that are there from ``finish`` on, by the planted clock."""
+
+    def __init__(self, clock, finish):
+        self.clock, self.finish, self.asked = clock, finish, 0
+
+    def is_ready(self):
+        self.asked += 1
+        return self.clock[0] >= self.finish
+
+
+@pytest.fixture
+def hand_clock(monkeypatch):
+    clock = [1_000]
+    monkeypatch.setattr(trace_mod, "now_ns", lambda: clock[0])
+    monkeypatch.setattr(trace_mod, "cpu_ns", lambda: clock[0])
+    return clock
+
+
+def launch(loop, clock, tok, behind, took=10):
+    """What ``_launch`` does around its dispatch."""
+    with loop.phase("inputs"):
+        clock[0] += took
+    late = loop.finished
+    before = loop.t
+    with loop.phase("dispatch"):
+        clock[0] += took
+    loop.launched(behind, before)
+    with loop.phase("sample"):
+        clock[0] += took
+    loop.watch(tok)
+    return late
+
+
+@pytest.mark.parametrize("finish", [1_045, 1_075, 1_100, 1_300])
+def test_late_time_brackets_a_planted_finish(finish, hand_clock):
+    clock = hand_clock
+    loop = trace_mod.LoopTime()
+    first = _PlantedTok(clock, finish)
+    # the first launch (after an idle wait: behind nothing) counts nothing,
+    # whatever the clock
+    assert not launch(loop, clock, first, behind=False)      # 1,000-1,030
+    assert (loop.late_ns, loop.slack_ns) == (0, 0)
+    for phase in ("fetch", "commit", "release", "schedule"):
+        with loop.phase(phase):                              # ...-1,110
+            clock[0] += 20
+    late = launch(loop, clock, _PlantedTok(clock, 10**9), behind=True)
+    returned = 1_130                                         # its dispatch
+    true_late = max(returned - finish, 0)
+    assert late == (finish <= 1_120)        # as seen at the inputs' exit
+    assert loop.late_ns <= true_late <= loop.late_ns + loop.slack_ns
+    if true_late:
+        # asked at every phase's exit (20 apart; the first 40 after the
+        # launch's own last no), and never after the first yes
+        assert loop.slack_ns <= 40
+        assert first.asked <= 5
+    else:
+        assert (loop.late_ns, loop.slack_ns) == (0, 0)
+
+
+def test_a_step_that_samples_nothing_is_asked_nothing(hand_clock):
+    clock = hand_clock
+    loop = trace_mod.LoopTime()
+    launch(loop, clock, None, behind=True)          # a chunk: ``tok is None``
+    assert not loop.polling
+    with loop.phase("commit"):
+        clock[0] += 500
+    assert not launch(loop, clock, _PlantedTok(clock, 0), behind=True)
+    assert (loop.late_ns, loop.slack_ns) == (0, 0)
+    # and a launch behind NOTHING drops a bracket that had closed
+    with loop.phase("commit"):
+        clock[0] += 500
+    assert loop.finished
+    launch(loop, clock, None, behind=False)
+    assert (loop.late_ns, loop.slack_ns) == (0, 0)
+
+
+def test_the_first_launch_after_an_idle_wait_counts_no_late_time(monkeypatch):
+    """Through the session: every step's tokens are there at once, so every
+    launch behind an unfetched step is late, and the launches behind
+    nothing (the first of all, the first after the loop had gone idle)
+    add nothing."""
+    class Launched(engine_mod._Launched):
+        __slots__ = ()
+
+        def __init__(self, *args):
+            super().__init__(*args)
+            if self.tok is not None:
+                self.tok = _Tok(self.tok, True)
+
+    monkeypatch.setattr(engine_mod, "_Launched", Launched)
+    get_registry().reset()
+    serving = AsyncServingEngine(tiny_engine(), max_new_tokens=4,
+                                 start=False)
+    session = serving._session
+    grew = []                       # (launched behind a step, late grew)
+    for _ in range(2):
+        serving.add_request(np.arange(3, 14, dtype=np.int32))
+        while True:
+            behind, was = session._flight is not None, session.loop.late_ns
+            alive = serving.step()
+            if session.last_action is not None:
+                grew.append((behind, session.loop.late_ns > was))
+            if not alive:
+                break
+        assert session._flight is None          # idle: nothing in flight
+    assert [b for b, _ in grew].count(False) == 2
+    assert all(late == behind for behind, late in grew), grew
+    c = get_registry().snapshot()["counters"]
+    assert c["serving/late_ms"] == pytest.approx(session.loop.late_ns / 1e6)
+    assert c["serving/late_ms"] > 0 and c["serving/late_slack_ms"] >= 0
+    assert c["serving/decode_steps_late"] \
+        == session.sched.stats["decode_steps_late"] > 0
+    serving.shutdown()
+
+
+def test_the_commit_loop_is_timed_on_one_landed_step_in_64(monkeypatch):
+    landed = []
+    plain = engine_mod._ServeSession._commit
+
+    def commit(self, step, rows):
+        if rows:
+            landed.append(len(rows))
+        return plain(self, step, rows)
+
+    monkeypatch.setattr(engine_mod._ServeSession, "_commit", commit)
+    get_registry().reset()
+    engine = tiny_engine(max_running=2)
+    serving = AsyncServingEngine(engine, max_new_tokens=70, start=False)
+    for i in range(2):
+        serving.add_request(np.arange(3, 14 + i, dtype=np.int32))
+    while serving.step():
+        pass
+    loop = serving._session.loop
+    assert len(landed) == loop.landed > 64
+    # a sampled step's rows once, an unsampled step's not at all
+    assert loop.sampled_rows == sum(landed[::64]) == landed[0] + landed[64]
+    assert loop.record_ns > 0 and loop.wake_ns > 0
+    c = get_registry().snapshot()["counters"]
+    assert c["serving/commit_sampled_rows"] == loop.sampled_rows
+    assert c["serving/commit_record_ms"] \
+        == pytest.approx(loop.record_ns / 1e6)
+    serving.shutdown()
 
 
 def test_range_push_pop_keep_to_their_thread(monkeypatch):
