@@ -12,9 +12,10 @@ refused; each fault's count says whether the cell's check would catch it
 (what it cannot see is held by ``tests/unit/test_sdar.py``'s logits on the
 CPU). The faults, each planted in the program and taken out again:
 
-* ``no_commit``: a commit row's pass writes the dummy block, so the block's
-  KV stays what its last denoise pass wrote;
-* ``denoise_kv``: the commit pass is fed the last denoise pass's tokens
+* ``no_commit``: every committing entry of a pass (a rider, a lone
+  commit's) writes the dummy block, so the block's KV stays what its last
+  denoise pass wrote;
+* ``denoise_kv``: a committing entry is fed the last denoise pass's tokens
   (``[MASK]`` at the block's last position) in place of the final ones:
   by construction the same pool state as ``no_commit``, reached another way;
 * ``causal_prefill``: the prefill's mask is the plain triangle;
@@ -65,21 +66,26 @@ def plant(fault, engine, models):
         real = eng._ServeSession._block_inputs
 
         def inputs(self, reqs):
+            # a lone commit's entry and every rider (the entries past the
+            # rows' own) write to the dummy block
             (feed, bt, pos, n, commit, *rest), plan = real(self, reqs)
             bt = bt.copy()
-            bt[commit] = 0
+            bt[:commit.size][commit] = 0
+            bt[commit.size:] = 0
             return (feed, bt, pos, n, commit, *rest), plan
         kinds(inputs)
     elif fault == "denoise_kv":
         real = eng._ServeSession._block_inputs
 
         def inputs(self, reqs):
-            # the host feeds every row here (depth zero below), so a commit
-            # row's last position can be handed over undecided
+            # the host feeds every entry here (depth zero below), so a
+            # committing entry's last position (a lone commit's, a
+            # rider's) can be handed over undecided
             (feed, bt, pos, n, commit, *rest), plan = real(self, reqs)
             prev, idx, host = feed
             host = host.copy()
             host[commit[:len(reqs)].nonzero()[0], gen.block - 1] = -1
+            host[commit.size:, gen.block - 1] = -1
             assert (idx < 0).all()
             return ((prev, idx, host), bt, pos, n, commit, *rest), plan
         kinds(inputs)

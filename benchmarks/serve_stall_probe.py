@@ -15,7 +15,11 @@ and prints, beside its lines,
   ``serving/late_ms`` and ``late_slack_ms``, the TIME the late steps' device
   had nothing queued, as a bracket; ``serving/loop_*`` and
   ``serving/commit_*``, of which the manifest enters only some for the
-  mixed cell) between the window's marks, and the
+  mixed cell; PR 55: ``serving/block_*`` of a model that generates by
+  blocks, the rows' passes, lone commits, riders and decided tokens, from
+  which the positions a token the model really computed are 4 x
+  (``block_row_passes`` + ``block_commit_rides``) /
+  ``block_decided_tokens``) between the window's marks, and the
   mean full (generation 2) pause, ``host/gc_full_pause_ms`` over
   ``host/gc_full_collections``: the per-layer metrics hold the count and the
   share, no line of the harness holds the pause;
@@ -70,8 +74,11 @@ COUNTERS = ("host/gc_pause_ms", "host/gc_full_pause_ms",
             # closed loop's tokens/s (PERF.md section 6, PR 43)
             "serving/prefill_steps", "serving/prefill_tokens",
             "serving/prefill_padded_tokens")
-#: the loop's own time (``monitor.trace.LoopTime``), by family
-LOOP_TIME = ("serving/late_", "serving/loop_", "serving/commit_")
+#: the loop's own time (``monitor.trace.LoopTime``), by family, and the
+#: block passes of a model that generates by blocks (rows' passes, lone
+#: commits, riders, decided tokens: what the manifest's ratios are made of)
+LOOP_TIME = ("serving/late_", "serving/loop_", "serving/commit_",
+             "serving/block_")
 
 
 def stall_counters(marks):

@@ -2,17 +2,31 @@
 
 A model whose config carries a ``BlockGeneration`` record
 (``models/transformer.py``) generates a block of ``Bg`` positions by
-denoise passes and one commit pass (``transformer.forward_paged_block``
-is both). A row's open block is ONE int32 vector of ``Bg``: a decided
-position holds its token, an undecided one -1. **Masked-ness is this
-state, not a token id**: a prompt token or an argmax that equals the
-model's mask id is a token like any other; the mask id only stands in
-for an undecided position at the embedding (``feed``).
+denoise passes and one commit of its final tokens' k/v
+(``transformer.forward_paged_block`` is both: what it runs over is
+ENTRIES, each a block of some row). A row's open block is ONE int32
+vector of ``Bg``: a decided position holds its token, an undecided one
+-1. **Masked-ness is this state, not a token id**: a prompt token or an
+argmax that equals the model's mask id is a token like any other; the
+mask id only stands in for an undecided position at the embedding
+(``tokens_of``).
+
+A fused step has one MAIN entry a row (the block the row's pass decides
+in) and ``ride_slots`` RIDER entries behind them: a row whose block is
+whole and whose request goes on takes its NEXT block's first denoise pass
+in its main entry, at ``pos + Bg``, and the whole block rides the same
+launch as a rider at ``pos`` over the same block table (the commit: its
+k/v stays, it decides nothing and has no logits). Every entry's k/v is in
+the pools before any entry's queries read, and an entry reads up to its
+own block's end, so that is, layer by layer, the commit pass followed by
+the denoise pass. A whole block that finds no rider slot is committed by
+its row's main entry alone (``commit``), as every block was before.
 
 Device side (ops of the serving step's one program): ``feed`` resolves
-each row's state from the step before, still on the device, or from the
-host's; ``unmask`` takes the pass's logits to the next state. Host side:
-``open_block`` is the state a row enters a block with.
+each entry's state from the step before, still on the device, or from the
+host's; ``unmask`` takes the main entries' logits to their next state.
+Host side: ``open_block`` is the state a row enters a block with,
+``ride_slots`` the rider entries of the one program width.
 """
 
 from __future__ import annotations
@@ -24,10 +38,20 @@ import numpy as np
 UNDECIDED = -1
 
 
+def ride_slots(gen, rows: int) -> int:
+    """Rider entries of a fused step over ``rows`` rows: a row's block is
+    whole after one of its ``gen.steps`` denoise passes, so a step finds
+    about ``rows / gen.steps`` whole blocks; up to whole sublane tiles of
+    entries (8), so the entries' axis stays tiled."""
+    return -(-(-(-rows // gen.steps)) // 8) * 8
+
+
 def feed(prev, idx, host):
-    """Row i's block state: ``prev[idx[i]]`` (what the pass in flight left
-    for it, on the device) where ``idx[i] >= 0``, else the host's
-    ``host[i]``. prev, host [W, Bg] int32; idx [W] int32."""
+    """Entry i's block state: ``prev[idx[i]]`` (what the pass in flight
+    left at its main entry ``idx[i]``, on the device) where ``idx[i] >=
+    0``, else the host's ``host[i]``. prev [W, Bg] int32 (the main entries
+    alone keep a state); idx [N] int32, host [N, Bg] int32 over all ``N =
+    W + ride_slots`` entries."""
     return jnp.where(idx[:, None] >= 0, prev[jnp.maximum(idx, 0)], host)
 
 
@@ -38,13 +62,16 @@ def tokens_of(gen, state):
 
 
 def unmask(gen, logits, state, n_decide, commit, draw=None):
-    """One pass's decision, on the device. logits [W, Bg, V] at every
-    position of the rows' blocks (each position's OWN: there is no
-    next-token shift); state [W, Bg] int32 as fed; n_decide [W] the
-    positions this pass decides at least (``gen.transfers`` of the row's
-    pass, 0 for a commit row); commit [W] bool, the rows whose block was
-    whole before this pass: their output is dropped and they enter the next
-    block, all undecided. ``draw``: ``logits [N, V] -> tokens [N]`` under a
+    """One pass's decision, on the device, over the W main entries (a
+    rider decides nothing: it has no logits and no next state). logits [W,
+    Bg, V] at every position of the rows' blocks (each position's OWN:
+    there is no next-token shift); state [W, Bg] int32 as fed; n_decide
+    [W] the positions this pass decides at least (``gen.transfers`` of the
+    row's pass, 0 for a lone commit); commit [W] bool, the rows that commit
+    ALONE in this step (a whole block that found no rider slot; an idle
+    row): their output is dropped and they enter the next block, all
+    undecided. A row whose commit rides is a denoise row here: its main
+    entry is its next block. ``draw``: ``logits [N, V] -> tokens [N]`` under a
     temperature (the logits it draws from are then what the confidence is
     taken over), None = greedy. Returns the next state [W, Bg]."""
     with jax.named_scope("unmask"):

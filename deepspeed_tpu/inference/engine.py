@@ -1217,17 +1217,24 @@ class InferenceEngine:
                             key=None, temperature=None, top_k=0):
                 # one pass of generation by blocks over all running rows,
                 # the decision included: ``t`` is the block feed ``(prev,
-                # idx, host)`` (row i's open block as the pass in flight
-                # left it on the device at row idx[i], or the host's), the
-                # first output the rows' blocks as this pass leaves them,
-                # which stay on the device for the next pass's feed
+                # idx, host)`` (entry i's block as the pass in flight left
+                # it on the device at main entry idx[i], or the host's): W
+                # main entries, a row's open block each (``n_decide``,
+                # ``commit`` [W]), then the rider entries (``bt``, ``pos``
+                # [N]), whole blocks that commit beside their rows' next
+                # (``blockgen``). The head and the decision run over the
+                # main entries alone; the first output is their blocks as
+                # this pass leaves them, which stay on the device for the
+                # next pass's feed
+                W = n_decide.shape[0]
                 state = blockgen.feed(*t)
                 logits, pools, *aux = _pinned(mod.forward_paged_block)(
-                    p, blockgen.tokens_of(gen, state), pools, bt, pos)
+                    p, blockgen.tokens_of(gen, state), pools, bt, pos,
+                    n_logits=W)
                 draw = None if key is None else (
                     lambda lg: self._draw(lg, temperature, top_k, key))
-                return (blockgen.unmask(gen, logits, state, n_decide, commit,
-                                        draw), pools, *aux)
+                return (blockgen.unmask(gen, logits, state[:W], n_decide,
+                                        commit, draw), pools, *aux)
 
             def paged_cow(pools, src, dst):
                 return _pin(copy_paged_block(pools, src, dst))
@@ -1707,8 +1714,8 @@ class _ServeSession:
         # call is of one row tile, or takes another form (``count_moe``)
         tile_of = getattr(engine.module, "expert_row_tile", None)
         self.moe_row_tile = tile_of(
-            engine.params, W * (sched.gen.block if sched.gen else 1)) \
-            if tile_of else 0
+            engine.params, (W + sched.ride_slots) * sched.gen.block
+            if sched.gen else W) if tile_of else 0
         self._programs = dict(zip(_DISPATCH_SITES, jits))
         # fault containment (serving.fault): the action a fault can be
         # attributed to, the finer-grained dispatch site for the
@@ -2426,39 +2433,52 @@ class _ServeSession:
         return ((self._tok_dev, idx, toks), bt, pos, *state), None
 
     def _block_inputs(self, reqs):
-        """A fused pass of generation by blocks: each row's table and
-        committed depth, its block feed (from the pass in flight on the
-        device where the row rode it, else the host's state), and the
-        scheduler's plan for it (commit or denoise, how many to decide),
-        which is also the step's ``part``, by rid."""
+        """A fused pass of generation by blocks: W main entries, each row's
+        table, the depth of the block its pass decides in, that block's
+        feed (from the pass in flight on the device where the row rode it,
+        else the host's state) and the scheduler's plan for it (denoise or
+        lone commit, how many to decide), then the rider entries: the whole
+        block of each row whose commit rides, under the row's own table at
+        its committed depth, while the row's main entry is its NEXT block,
+        all undecided. The plan is also the step's ``part``, by rid."""
         tel, sched, Bg = self.sched.telemetry, self.sched, self._gen.block
-        W = self.W
-        bt = np.zeros((W, self.n_max), np.int32)            # zeros → dummy
-        pos = np.zeros((W,), np.int32)
-        host = np.full((W, Bg), -1, np.int32)
-        idx = np.full((W,), -1, np.int32)
+        W, N = self.W, self.W + self.sched.ride_slots
+        bt = np.zeros((N, self.n_max), np.int32)            # zeros → dummy
+        pos = np.zeros((N,), np.int32)
+        host = np.full((N, Bg), -1, np.int32)
+        idx = np.full((N,), -1, np.int32)
         n_decide = np.zeros((W,), np.int32)
         commit = np.ones((W,), bool)     # an idle row's block stays undecided
         ahead = self._flight
         src = {} if ahead is None or ahead.name != "block" else {
             id(r): j for j, r in enumerate(ahead.reqs)}
         plan = {}
+        rides = 0
         for i, r in enumerate(reqs):
+            step = plan[r.rid] = sched.plan_block(r)
             bt[i, :len(r.blocks)] = r.blocks
             pos[i] = r.pos
-            plan[r.rid] = sched.plan_block(r)
-            commit[i], n_decide[i], _ = plan[r.rid]
+            n_decide[i] = step.n
+            at = i                       # the entry of the row's block as
+            if step.ride:                # it stands: its own, or a rider
+                at = W + rides
+                rides += 1
+                bt[at], pos[at] = bt[i], r.pos
+                pos[i] += Bg
+            commit[i] = step.alone
             j = src.get(id(r))
             if j is None:
-                host[i] = sched.block_state(r)
+                host[at] = sched.block_state(r)
             else:
-                idx[i] = j
+                idx[at] = j
         if tel is not None:
-            # a row's pass reads its committed tokens and its open block
-            tel.decode_live_kv_tokens.inc(int(pos.sum()) + Bg * len(reqs))
+            # an entry's pass reads its row's committed tokens and its own
+            # block: a row with a rider is read twice
+            live = np.concatenate([pos[:len(reqs)], pos[W:W + rides]])
+            tel.decode_live_kv_tokens.inc(int(live.sum()) + Bg * live.size)
             tel.decode_live_kv_blocks.inc(
-                int(((pos[:len(reqs)] + Bg - 1) // self.bs + 1).sum()))
-            tel.count_block(len(reqs), int(commit[:len(reqs)].sum()))
+                int(((live + Bg - 1) // self.bs + 1).sum()))
+            tel.count_block(len(reqs), int(commit[:len(reqs)].sum()), rides)
         prev = self._blk_dev if self._blk_dev is not None \
             else np.full((W, Bg), -1, np.int32)
         draw = ()
@@ -2651,6 +2671,13 @@ class _ActionKind(NamedTuple):
     record: Optional[Callable] = None
 
 
+def _commits_of(steps, **says):
+    """``says`` and how a block step's rows commit: alone, or as riders."""
+    steps = list(steps)
+    return dict(says, commits=sum(s.alone for s in steps),
+                rides=sum(s.ride for s in steps))
+
+
 def _commit_one(sched, r, part, t):
     """A prefill's token, if this piece of it sampled one."""
     if t:
@@ -2699,21 +2726,22 @@ _ACTION_KINDS: Dict[str, _ActionKind] = {
         record=lambda sched, r, part, t:
             sched.commit_token(r, t[0], fused=True)),
     # generation by diffusion over blocks: a fused pass over every running
-    # row's open block, denoise and commit rows together (``part``: the
-    # scheduler's plan by rid). Ahead like decode: under the static rules
-    # the plan reads no token (``plans_ahead`` lands the data-dependent one)
+    # row's open block, denoise rows, lone commits and riders together
+    # (``part``: the scheduler's plan by rid). Ahead like decode: under the
+    # static rules the plan reads no token (``plans_ahead`` lands the
+    # data-dependent one)
     "block": _ActionKind(
         rows=lambda reqs: reqs,
-        says=lambda s, reqs: {
-            "rows": len(reqs),
-            "commits": sum(s.sched.plan_block(r)[0] for r in reqs)},
+        says=lambda s, reqs: _commits_of(map(s.sched.plan_block, reqs),
+                                         rows=len(reqs)),
         inputs=_ServeSession._block_inputs, fed=True, ahead=True,
         sample=_ServeSession._block_kept, tokens=_ServeSession._block_states,
         # the fused step's event, as a decode step's: what the recorder's
         # request tracks and the latency anatomy read a row's step from
         events=lambda reqs, part, out: (None, [
-            ("decode.tick", dict(rids=[r.rid for r in reqs], n=len(reqs),
-                                 commits=sum(part[r.rid][0] for r in reqs)))]),
+            ("decode.tick", _commits_of(
+                (part[r.rid] for r in reqs),
+                rids=[r.rid for r in reqs], n=len(reqs)))]),
         advance=lambda sched, r, part: sched.advance_block(r, *part[r.rid]),
         record=lambda sched, r, part, t:
             sched.record_block(r, *part[r.rid], t)),

@@ -107,10 +107,18 @@ left of the prefix (``len % Bg`` tokens) is decided from the start in the
 request's first open block, ``[pos, pos + Bg)``. Each ``block`` step is,
 for a row, a DENOISE pass (``plan_block``: it decides ``n`` of the block's
 undecided positions; their k/v, written to the block's slots, is
-overwritten by the next pass) or, once the block is whole, a COMMIT pass
-(its k/v stays; ``pos`` moves on by ``Bg``). A pool block holds whole
-generation blocks, so a row's next pass needs the same one block ahead a
-decode step needs (``_ensure_decode_capacity`` as it is). A token is
+overwritten by the next pass) or, once the block is whole, its COMMIT (its
+k/v stays; ``pos`` moves on by ``Bg``). A commit RIDES the launch that
+opens the next block: the whole block is a second entry of the same row
+(one of the step's ``ride_slots`` rider entries) and the row's pass is
+denoise pass 0 of the block at ``pos + Bg``, so a block of ``steps``
+passes costs a row ``steps`` steps. When more rows have a whole block
+than the step has rider slots, the first ``ride_slots`` of them in
+admission order ride (``_mark_rides``) and the others commit ALONE, a pass
+of their own that decides nothing, as every block did before. A pool
+block holds whole generation blocks, so a row's next pass needs the same
+one block ahead a decode step needs, from the deepest position it writes:
+``pos``, or ``pos + Bg`` for a ride (``_ensure_decode_capacity``). A token is
 streamed (``generated``) once it and every token before it is decided;
 ``max_new`` cuts inside a block. Under the two static rules the host
 knows every row's phase without reading a token (``blk_decided``,
@@ -140,12 +148,12 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import numpy as np
 
 from deepspeed_tpu.inference.block_allocator import ROOT_KEY, BlockAllocator
-from deepspeed_tpu.inference.blockgen import open_block
+from deepspeed_tpu.inference.blockgen import open_block, ride_slots
 from deepspeed_tpu.utils.logging import logger
 
 QUEUED, RUNNING, FINISHED = "queued", "running", "finished"
@@ -487,21 +495,28 @@ class ServingTelemetry:
             "request's first, and its first again after each recompute"
         ).inc()
 
-    def count_block(self, rows: int, commits: int) -> None:
+    def count_block(self, rows: int, commits: int, rides: int) -> None:
         """One fused block step (generation by diffusion over blocks) over
-        ``rows`` live rows, ``commits`` of them on their commit pass. Not
-        pre-created: a model that generates a token a step has none of the
-        ``serving/block_*`` counters."""
+        ``rows`` live rows: ``commits`` of them commit alone (their pass
+        decides nothing), ``rides`` more commit as rider entries beside
+        their next block's first pass. Not pre-created: a model that
+        generates a token a step has none of the ``serving/block_*``
+        counters."""
         c = self.registry.counter
         c("serving/block_passes",
           "fused block steps: one pass over every running row's open block"
           ).inc()
         c("serving/block_row_passes",
-          "live rows summed over the block steps: the passes rows took"
-          ).inc(rows)
+          "live rows summed over the block steps: the passes rows took (a "
+          "row whose commit rides took one)").inc(rows)
         c("serving/block_commit_row_passes",
-          "of block_row_passes, the commit passes (a whole block's final "
-          "KV written, the row moved on, nothing decided)").inc(commits)
+          "of block_row_passes, the lone commits: a pass that wrote a whole "
+          "block's final KV, moved the row on and decided nothing, for want "
+          "of a rider slot").inc(commits)
+        c("serving/block_commit_rides",
+          "commits that rode the pass opening the next block, as a second "
+          "entry of the same row: with block_commit_row_passes, the blocks "
+          "committed").inc(rides)
 
     def count_block_decided(self, n: int) -> None:
         self.registry.counter(
@@ -815,6 +830,8 @@ class Request:
     blk_decided: int = 0            # its decided positions, launched passes
     # counted in (under the data-dependent rule: as of the last landing)
     blk_pass: int = 0               # denoise passes launched on it
+    blk_ride: bool = False          # its next step commits it as a rider
+    # and opens the next block (``_mark_rides``, anew before every block step)
     blk_landed: Optional[Tuple] = None  # (start, state [Bg] int32, passes):
     # the open block as the newest landed denoise pass left it, what a
     # re-admission re-enters it with
@@ -839,6 +856,28 @@ class Request:
     def output(self) -> np.ndarray:
         return np.concatenate([self.prompt,
                                np.asarray(self.generated, np.int32)])
+
+
+class BlockStep(NamedTuple):
+    """What one fused block step is for a row (generation by blocks):
+    ``plan_block`` makes it, the session lays its entries out by it, and
+    ``advance_block`` / ``record_block`` take its fields in this order."""
+    #: the row's open block is whole: this launch writes its final k/v
+    commit: bool
+    #: positions the row's denoise pass decides at least (a lone commit: 0)
+    n: int
+    #: that pass's index in its block
+    i: int
+    #: the commit rides: the whole block is a rider entry and the row's own
+    #: entry is pass 0 of the NEXT block; False with ``commit``: the commit
+    #: takes the row's pass alone
+    ride: bool = False
+
+    @property
+    def alone(self) -> bool:
+        """A lone commit: the row's pass writes the whole block's final k/v
+        and decides nothing."""
+        return self.commit and not self.ride
 
 
 class ContinuousBatchingScheduler:
@@ -870,6 +909,10 @@ class ContinuousBatchingScheduler:
         self.max_blocks_per_seq = max_blocks_per_seq
         #: the model's BlockGeneration record; None: a token a step
         self.gen = generation
+        #: rider entries of a fused block step, behind its ``max_running``
+        #: main entries: the one program width, from the rows and the record
+        self.ride_slots = 0 if generation is None else ride_slots(
+            generation, max_running)
         self.prefix_caching = prefix_caching and allocator.prefix_cache
         # chunk_tokens and spec_k are runtime-mutable by contract: the
         # adaptive controller (monitor/controller.py) lowers them under
@@ -1535,8 +1578,9 @@ class ContinuousBatchingScheduler:
         n-gram proposer would read the tokens, when a deadline sweep would
         retire one of ``rows``, or when growing the decode rows' blocks
         would have to preempt (a victim re-queues prompt + generated).
-        Reads only; conservative about the last (an admission that takes
-        the turn grows nothing)."""
+        Reads only (the rows' ride marks are derived, and made anew by
+        :meth:`next_action`); conservative about the last (an admission
+        that takes the turn grows nothing)."""
         if self.spec_k > 0 or (self.gen is not None
                                and self.gen.data_dependent):
             return False
@@ -1544,8 +1588,10 @@ class ContinuousBatchingScheduler:
                 any(req is r for r in rows) for req, _ in self._expired()):
             return False
         bs = self.allocator.block_size
-        grow = sum(1 for r in self.running
-                   if not r.prefilling and r.pos >= len(r.blocks) * bs)
+        ride = self._mark_rides()
+        grow = sum(1 for r in self.running if not r.prefilling
+                   and r.pos + (ride if r.blk_ride else 0)
+                   >= len(r.blocks) * bs)
         return grow <= self.allocator.num_free
 
     def abandon(self, rows: List[Request]) -> None:
@@ -1608,16 +1654,35 @@ class ContinuousBatchingScheduler:
                                "nothing runnable")
         return None
 
+    def _mark_rides(self) -> int:
+        """Generation by blocks: which rows' next block step is a ride (the
+        whole block a rider entry, the row's pass the next block's first),
+        ``blk_ride``: the first ``ride_slots`` rows in admission order whose
+        block is whole. A row still running has a token left to generate
+        past a whole block (one that ends with it was handed on). Returns
+        how much deeper a ride writes than ``pos`` (0: a token a step)."""
+        if self.gen is None:
+            return 0
+        left, whole = self.ride_slots, self.gen.block
+        for r in self.running:
+            r.blk_ride = left > 0 and not r.prefilling \
+                and r.blk_decided == whole
+            left -= r.blk_ride
+        return whole
+
     def _ensure_decode_capacity(self) -> None:
         """Every decode-ready request writes its next token at slot
-        ``pos``; grow its block list when that slot crosses a block
+        ``pos`` (a row whose commit rides: its next block, from ``pos +
+        Bg``); grow its block list when that slot crosses a block
         boundary, evicting the policy's victim (FIFO: latest admitted,
         SLA: most TTFT slack) when the pool — free list AND reclaimable
         cold blocks — is dry."""
+        ride = self._mark_rides()
         for req in list(self.running):
             if req.state != RUNNING or req.prefilling:
                 continue  # evicted by an earlier iteration, or mid-prefill
-            while req.pos >= len(req.blocks) * self.allocator.block_size:
+            while req.pos + (ride if req.blk_ride else 0) \
+                    >= len(req.blocks) * self.allocator.block_size:
                 got = self.allocator.allocate(1)
                 if got is not None:
                     req.blocks.extend(got)
@@ -1852,28 +1917,34 @@ class ContinuousBatchingScheduler:
         req.blk_pass = req.blk_landed[2] if again else 0
         req.blk_decided = int((self.block_state(req) >= 0).sum())
 
-    def plan_block(self, req: Request) -> Tuple[bool, int, int]:
-        """What the next block step is for ``req``: (commit, the positions
-        it decides at least, the index of the pass in its block)."""
-        left = self.gen.block - req.blk_decided
-        if left == 0:
-            return True, 0, req.blk_pass
-        return (False, min(self.gen.transfers(req.blk_pass), left),
-                req.blk_pass)
+    def plan_block(self, req: Request) -> BlockStep:
+        """What the next block step is for ``req``: a denoise pass, a lone
+        commit, or a ride (:class:`BlockStep`)."""
+        g = self.gen
+        left = g.block - req.blk_decided
+        if left:
+            return BlockStep(False, min(g.transfers(req.blk_pass), left),
+                             req.blk_pass)
+        if req.blk_ride:
+            return BlockStep(True, g.transfers(0), 0, ride=True)
+        return BlockStep(True, 0, req.blk_pass)
 
-    def advance_block(self, req: Request, commit: bool, n: int,
-                      i: int) -> None:
-        """A block step over ``req`` was launched, for it a commit pass
-        (the block's final k/v is in the pools: ``pos`` moves on and the
-        next block opens, all undecided) or denoise pass ``i`` deciding
-        ``n`` positions (the data-dependent rule: at least ``n``; the
-        count comes with the landing)."""
+    def advance_block(self, req: Request, commit: bool, n: int, i: int,
+                      ride: bool = False) -> None:
+        """A block step over ``req`` was launched, for it a commit (the
+        block's final k/v is in the pools: ``pos`` moves on and the next
+        block opens, all undecided), denoise pass ``i`` deciding ``n``
+        positions (the data-dependent rule: at least ``n``; the count comes
+        with the landing), or (``ride``) both in that order, the pass over
+        the block the commit opened."""
         g = self.gen
         if commit:
             req.pos += g.block
             req.blk_start, req.blk_decided, req.blk_pass = req.pos, 0, 0
+            req.blk_ride = False
             self._register_full_blocks(req)
-            return
+            if not ride:
+                return
         req.blk_pass = i + 1
         if g.data_dependent:
             return
@@ -1887,12 +1958,13 @@ class ContinuousBatchingScheduler:
             self._hand_on(req)
 
     def record_block(self, req: Request, commit: bool, n: int, i: int,
-                     state) -> List[int]:
+                     ride: bool, state) -> List[int]:
         """``req``'s launched block step landed with ``state`` [Bg] (-1:
-        undecided), its block as the pass left it (a commit pass: the next
-        block, all undecided). Streams what is newly decided with every
-        token before it: returns those tokens."""
-        if commit:
+        undecided), its open block as the pass left it (a lone commit: the
+        next block, all undecided; a ride: the next block after its first
+        pass). Streams what is newly decided with every token before it:
+        returns those tokens."""
+        if commit and not ride:
             return []
         state = np.asarray(state, np.int32)
         start = req.blk_start

@@ -89,9 +89,10 @@ class CausalLM:
         return T.forward_paged_verify(self.config, params, tokens, pools,
                                       block_tables, slots, pos)
 
-    def forward_paged_block(self, params, tokens, pools, block_tables, pos):
+    def forward_paged_block(self, params, tokens, pools, block_tables, pos,
+                            n_logits=None):
         return T.forward_paged_block(self.config, params, tokens, pools,
-                                     block_tables, pos)
+                                     block_tables, pos, n_logits=n_logits)
 
     @property
     def num_parameters(self) -> int:

@@ -765,16 +765,19 @@ class MoECausalLM:
                                       block_tables, pos, pad_bias, mlp_fn=mlp_fn,
                                       state_slots=state_slots)
 
-    def forward_paged_block(self, params, tokens, pools, block_tables, pos):
-        """(logits [W, Bg, vocab], new pools, counts [L, E + 1]) of one
-        pass of block generation: as ``forward_paged_decode``, with the
-        rows' Bg positions each a row of the experts' work."""
+    def forward_paged_block(self, params, tokens, pools, block_tables, pos,
+                            n_logits=None):
+        """(logits [n_logits or N, Bg, vocab], new pools, counts [L, E +
+        1]) of one pass of block generation: as ``forward_paged_decode``,
+        with every entry's Bg positions each a row of the experts' work
+        (a rider's too: its positions are computed like any other's)."""
         bs = pools["k"].shape[2]
         slots = (block_tables[jnp.arange(pos.shape[0]), pos // bs] * bs
                  + pos % bs)[:, None] + jnp.zeros_like(tokens)
         mlp_fn = self._paged(params, pools, slots, counts=True)
         return T.forward_paged_block(self.config, params, tokens, pools,
-                                     block_tables, pos, mlp_fn=mlp_fn)
+                                     block_tables, pos, mlp_fn=mlp_fn,
+                                     n_logits=n_logits)
 
     def loss(self, params, batch, rng=None):
         logits, aux = self.forward(params, batch["input_ids"], batch.get("attention_mask"),

@@ -196,7 +196,7 @@ def sdar(size: str = "30b-a3b-ep8", share: int = 0, rule: str = "sequential",
     untied head over 151,936 rows. It GENERATES BY DIFFUSION OVER BLOCKS:
     its config carries a ``BlockGeneration`` record (blocks of 4 positions,
     bidirectional inside a block and causal between blocks; ``steps``
-    denoise passes and a commit pass a block), which the scheduler and the
+    denoise passes a block and its commit), which the scheduler and the
     serving session read. ``30b-a3b-ep8`` is ONE CHIP OF THE EIGHT of a
     v5e-8 that share each layer, AT FULL DEPTH: the router keeps its 128
     outputs, the 16 experts of ``share`` (0-7) are held here, attention,

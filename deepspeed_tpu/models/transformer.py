@@ -2317,16 +2317,21 @@ def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
 
 def _paged_block_attention(cfg: TransformerConfig, x, lp, positions, pos,
                            kp, vp, block_tables, dummy_block=0):
-    """Attention of one block-generation pass over all running rows: x
-    [W, Bg, D], the ``Bg = cfg.causal_block`` positions ``pos .. pos + Bg -
-    1`` of each row's open block (``pos`` a multiple of Bg, so a block never
-    straddles a pool block). The block's k/v go to the row's pool slots
-    FIRST (a later pass over the same block, or its commit pass, overwrites
-    them: a denoise pass's KV is not kept by being written over), then all
+    """Attention of one block-generation pass over all its entries: x [W,
+    Bg, D], the ``Bg = cfg.causal_block`` positions ``pos .. pos + Bg - 1``
+    of each ENTRY's block (``pos`` a multiple of Bg, so a block never
+    straddles a pool block). An entry is a block of some row: its open
+    block, or (a rider) the whole block before it, under the same block
+    table at ``pos - Bg``. EVERY entry's k/v go to its pool slots FIRST (a
+    later pass over the same block, or its commit, overwrites them: a
+    denoise pass's KV is not kept by being written over), then an entry's
     Bg queries read positions ``<= pos + Bg - 1``: everything before the
-    block and the block itself, both directions. The Bg queries of a row
-    see the SAME keys, so they ride the paged kernel on its position axis
-    (Bg x H / KV query rows a kv head), in ONE read of the row's KV.
+    block and the block itself, both directions. So a rider's final k/v is
+    in the pools before its row's open block reads it, and the rider never
+    sees the open block: one launch is the commit pass followed by the
+    denoise pass. The Bg queries of an entry see the SAME keys, so they
+    ride the paged kernel on its position axis (Bg x H / KV query rows a kv
+    head), in ONE read of the entry's KV.
     Returns (out [W, Bg, D], kp, vp)."""
     W, Bg, D = x.shape
     H, KV, Hd = cfg.n_head, cfg.kv_heads, cfg.head_dim
@@ -2365,16 +2370,21 @@ def _paged_block_attention(cfg: TransformerConfig, x, lp, positions, pos,
 
 
 def forward_paged_block(cfg: TransformerConfig, params, tokens, pools,
-                        block_tables, pos, mlp_fn=None):
-    """One pass of block generation over ALL running rows (a model with a
-    ``generation`` record): tokens [W, Bg], each row's open block with its
-    undecided positions as the mask id; block_tables [W, max_blocks]; pos
-    [W] the rows' committed depths (multiples of Bg; an inactive row has a
-    zeroed table and writes the dummy block). Returns (logits [W, Bg,
-    vocab] at EVERY position of the block, each position's own; new pools)
-    and third what an ``mlp_fn`` that returns ``(out, aux)`` gave. Denoise
-    and commit passes are this same program: what differs is what the
-    caller does with the logits and whether ``pos`` moves on."""
+                        block_tables, pos, mlp_fn=None, n_logits=None):
+    """One pass of block generation over ALL its entries (a model with a
+    ``generation`` record): tokens [N, Bg], each entry's block with its
+    undecided positions as the mask id; block_tables [N, max_blocks]; pos
+    [N] the depths the entries' blocks start at (multiples of Bg; an idle
+    entry has a zeroed table and writes the dummy block). An entry is a
+    row's open block or, behind those, a rider: the whole block of a row
+    whose open block is the next one, under the same table (the serving
+    session's layout: ``inference/blockgen.py``). ``n_logits``: the head
+    runs over the first ``n_logits`` entries alone (riders decide nothing;
+    None: all). Returns (logits [n_logits, Bg, vocab] at EVERY position of
+    those blocks, each position's own; new pools) and third what an
+    ``mlp_fn`` that returns ``(out, aux)`` gave. A denoise pass, a commit
+    and a rider are this same program: what differs is what the caller
+    does with the logits and whether ``pos`` moves on."""
     _check_paged_config(cfg)
     if cfg.generation is None or cfg.cache_spec["state"] \
             or cfg.cache_spec["window"]:
@@ -2391,7 +2401,7 @@ def forward_paged_block(cfg: TransformerConfig, params, tokens, pools,
             cfg, xn, lp, positions, pos, kp, vp, block_tables + block0,
             dummy_block=block0),
         mlp_fn)
-    logits = cached_head(cfg, params, x)
+    logits = cached_head(cfg, params, x if n_logits is None else x[:n_logits])
     return (logits, pools) if aux is None else (logits, pools, aux)
 
 

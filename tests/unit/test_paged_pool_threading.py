@@ -411,16 +411,18 @@ def test_mamba2_kernel_compiles_for_v5e_at_real_widths(one_v5e, monkeypatch):
 
 def test_block_step_keeps_the_pools_in_place_for_v5e(one_v5e, monkeypatch):
     """The fused step of generation by blocks at ``sdar30b_serve_blockgen``'s
-    real widths (64 rows of 4 positions, 32 query and 4 key/value heads of
-    128, d 2,048, 16 held experts of 768 of a router's 128; two layers of
-    the 48), the decision included, compiled for a described v5e: Mosaic
+    real widths (64 rows' main entries and the 16 rider entries the
+    session derives beside them, 4 positions each, 32 query and 4 key/value
+    heads of 128, d 2,048, 16 held experts of 768 of a router's 128; two
+    layers of the 48), the decision included over the main entries alone,
+    compiled for a described v5e: Mosaic
     takes the paged kernel with the block's 4 positions on its own axis, 32
     query rows a kv head and the products a kv head (one call a layer, not
     one a position), the pools are aliased, the temporaries
     stay under a quarter of them and no ``copy``, ``dynamic-slice`` or
     ``dynamic-update-slice`` of a layer's pool size exists. So does the
     block-causal prefill of 256 tokens through the flash kernel. Both
-    programs' experts (256 rows) are the grouped expert kernel's."""
+    programs' experts (320 and 256 rows) are the grouped expert kernel's."""
     import deepspeed_tpu.comm as dist
     from deepspeed_tpu.inference import blockgen
     from deepspeed_tpu.models.presets import get_model
@@ -439,19 +441,24 @@ def test_block_step_keeps_the_pools_in_place_for_v5e(one_v5e, monkeypatch):
     pools = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
         lambda: model.init_paged_cache(num_blocks, BS, dtype=jnp.bfloat16)))
     n_max = -(-cfg.max_seq // BS)
+    entries = rows + blockgen.ride_slots(gen, rows)
+    assert entries == 80
 
     def step(p, po, prev, idx, host, bt, pos, n_decide, commit):
+        # the engine's ``paged_block``: every entry fed and computed, the
+        # head and the decision over the rows' own entries
         state = blockgen.feed(prev, idx, host)
         logits, po, aux = model.forward_paged_block(
-            p, blockgen.tokens_of(gen, state), po, bt, pos)
-        return blockgen.unmask(gen, logits, state, n_decide, commit), po, aux
+            p, blockgen.tokens_of(gen, state), po, bt, pos, n_logits=rows)
+        return blockgen.unmask(gen, logits, state[:rows], n_decide,
+                               commit), po, aux
 
-    blk = sds((rows, gen.block), I32)
     dispatch.reset()
     programs = {
         "block": jax.jit(step, donate_argnums=(1,)).lower(
-            params, pools, blk, sds((rows,), I32), blk,
-            sds((rows, n_max), I32), sds((rows,), I32), sds((rows,), I32),
+            params, pools, sds((rows, gen.block), I32), sds((entries,), I32),
+            sds((entries, gen.block), I32), sds((entries, n_max), I32),
+            sds((entries,), I32), sds((rows,), I32),
             sds((rows,), jnp.bool_)).compile(),
         "prefill": jax.jit(
             lambda p, po, t, s, li: model.forward_paged_prefill(p, t, po, s, li),
@@ -471,8 +478,8 @@ def test_block_step_keeps_the_pools_in_place_for_v5e(one_v5e, monkeypatch):
             name, mem.temp_size_in_bytes, pool_bytes)
         text = compiled.as_text()
         # two kernel calls in the layer scan's body: the attention's and,
-        # since PR 53, the experts' (a call of 256 rows: each expert over
-        # its own rows)
+        # since PR 53, the experts' (a call of 320 or 256 rows: each expert
+        # over its own rows)
         assert text.count('custom_call_target="tpu_custom_call"') == 2, name
         assert text.count("grouped_expert_mlp_own_rows") >= 1, name
         moved = []

@@ -7,8 +7,9 @@ What is held here and by nothing on the chip's served check: logits (not
 only tokens) of every deciding pass, every unmasking rule and step count,
 every prompt remainder, a request cut inside a block, chunked prefill, a
 recompute inside an open block, a cancel, a restart, rows out of phase, the
-share of the experts, a token equal to the mask id, and six controls that
-MUST fail the tolerance. The toy is drawn in a regime where that is a test:
+share of the experts, a token equal to the mask id, a block's commit as a
+rider entry of the pass that opens the next block (against the two passes
+it stands for, and served), and six controls that MUST fail the tolerance. The toy is drawn in a regime where that is a test:
 matrices at 0.3 and the embedding at 1.0 give ten distinct tokens in ten
 (at the program's default 0.02 a toy of d 64 answers the same three tokens
 whatever it is asked), so a wrong mask or a lost commit moves the logits by
@@ -644,9 +645,10 @@ def test_the_block_program_carries_its_name(toy):
 
 
 def test_block_counters_and_what_they_give(toy):
-    """Passes, commits and decided tokens: 4 denoise passes and a commit a
-    block of 4 give 0.8 tokens a row pass and 20% commit passes (a cut last
-    block and a prompt's remainder move both a little)."""
+    """Passes, commits and decided tokens: 4 denoise passes a block of 4,
+    the block's commit a rider of the next block's first, give 1.0 token a
+    row pass and no commit pass (a cut last block and a prompt's remainder
+    move the first a little)."""
     get_registry().reset()
     engine = engine_of(toy, telemetry={"enabled": True})
     prompts = prompts_of((8, 12, 16), seed=1)
@@ -654,15 +656,22 @@ def test_block_counters_and_what_they_give(toy):
     c = engine.telemetry_snapshot()["counters"]
     assert c["serving/block_decided_tokens"] == 3 * 24
     assert c["serving/generated_tokens"] == 3 * 24
-    # 6 blocks a request: 24 denoise passes and 5 commits (the last block's
-    # is not needed)
-    assert c["serving/block_row_passes"] == 3 * 29
-    assert c["serving/block_commit_row_passes"] == 3 * 5
+    # 6 blocks a request: 24 denoise passes, and 5 commits that ride the
+    # next block's first (the last block's is not needed)
+    assert c["serving/block_row_passes"] == 3 * 24
+    assert c["serving/block_commit_row_passes"] == 0
+    assert c["serving/block_commit_rides"] == 3 * 5
     assert c["serving/block_passes"] == c["serving/decode_steps"]
     assert c["serving/moe_layer_steps"] == 2 * c["serving/block_passes"]
     assert c["serving/moe_dropped_assignments"] == 0
-    # a row's pass reads its committed tokens and its open block
-    assert c["serving/decode_live_kv_tokens"] >= 4 * c["serving/block_row_passes"]
+    # an entry's pass reads its row's committed tokens and its own block,
+    # a rider's too
+    entries = c["serving/block_row_passes"] + c["serving/block_commit_rides"]
+    assert c["serving/decode_live_kv_tokens"] >= 4 * entries
+    # every entry's 4 positions are rows of the experts' work: top-2 of 8
+    # routed, 4 held
+    assert c["serving/moe_assignments"] <= 2 * 2 * 4 * entries
+    assert c["serving/moe_assignments"] >= 2 * 4 * entries // 2
 
 
 def test_a_model_that_decodes_has_no_block_counter():
@@ -676,6 +685,226 @@ def test_a_model_that_decodes_has_no_block_counter():
     counters = engine.telemetry_snapshot()["counters"]
     assert not [k for k in counters if k.startswith("serving/block_")]
     assert len(engine._paged_jits) == 8          # no block program is made
+
+
+# --------------------------------------------------------------------- #
+# (8) a block's commit rides the pass that opens the next block
+
+def ride_case(model, rows, W, E, bs, seed=0):
+    """The entries of ONE fused pass with riders, and of the TWO passes it
+    stands for. ``rows``: (depth, rides) a live row; a row that rides has a
+    whole block at ``depth`` and its next block, all undecided, at ``depth
+    + 4``; one that does not, an open block at ``depth``. Tables are random
+    and distinct. Returns {"first", "second", "fused"}: (tokens, tables,
+    depths) each, ``first`` and ``second`` W entries (a commit or denoise
+    pass over each row's block as it stands; then the riding rows' next
+    blocks), ``fused`` W + E (the main entries, then the riders)."""
+    gen = model.config.generation
+    Bg, n_max = gen.block, 4
+    r = np.random.default_rng(seed)
+    ids = iter(r.permutation(np.arange(1, 1 + len(rows) * n_max)))
+    table = np.zeros((len(rows), n_max), np.int32)
+    for i, (p, _) in enumerate(rows):
+        for j in range((p + 2 * Bg - 1) // bs + 1):
+            table[i, j] = next(ids)
+
+    def entries(n):
+        return (np.full((n, Bg), gen.mask_id, np.int32),
+                np.zeros((n, n_max), np.int32), np.zeros((n,), np.int32))
+
+    first, second, fused = entries(W), entries(W), entries(W + E)
+    riders = 0
+    for i, (p, rides) in enumerate(rows):
+        block = r.integers(0, gen.mask_id, Bg).astype(np.int32)
+        if not rides:
+            block[r.integers(1, Bg):] = gen.mask_id      # still open
+        for toks, bt, pos in (first, fused):
+            toks[i], bt[i], pos[i] = block, table[i], p
+        if rides:
+            at = W + riders
+            riders += 1
+            fused[0][at], fused[1][at], fused[2][at] = block, table[i], p
+            for toks, bt, pos in (second, fused):
+                toks[i], bt[i], pos[i] = gen.mask_id, table[i], p + Bg
+    return {"first": first, "second": second, "fused": fused}
+
+
+def ride_passes(model, params, pools, case, W):
+    """(logits, pools, counts) of the fused pass (the head over the W main
+    entries) and of the two passes one after the other."""
+    step = jax.jit(model.forward_paged_block, static_argnames=("n_logits",))
+
+    def run(po, name, **kw):
+        toks, bt, pos = map(jnp.asarray, case[name])
+        return step(params, toks, po, bt, pos, **kw)
+
+    fused = run(pools, "fused", n_logits=W)
+    first = run(pools, "first")
+    second = run(first[1], "second")
+    return fused, first, second
+
+
+#: (depth, rides): an A/B pair inside one pool block and one across two
+#: (the whole block ends a pool block of 128), a row that does not ride
+RIDE_ROWS = [(8, True), (124, True), (40, False), (252, True)]
+
+
+@pytest.mark.parametrize("backend", ["xla", "flash"])
+def test_a_rider_and_the_next_block_are_a_commit_pass_then_a_denoise_pass(
+        backend):
+    """One launch, two entries of the same row over the same table (the
+    whole block at ``p``, the next block at ``p + 4``), through gather +
+    einsum and through the interpreted paged kernel: the next block's
+    logits and every pool slot are those of a commit pass followed by a
+    denoise pass, a row without a rider keeps its own pass's logits, the
+    experts count both entries' positions; and with the rider left out
+    the next block's logits move."""
+    from deepspeed_tpu.ops import dispatch
+    bs, W, E = 128, 5, 4                 # an idle main entry, an idle rider
+    model = get_model("sdar", "tiny", head_size=64, attention_backend=backend,
+                      **REGIME)
+    params = model.init_params(jax.random.key(1))
+    r = np.random.default_rng(7)
+    pools = jax.tree.map(
+        lambda a: jnp.asarray(r.standard_normal(a.shape), a.dtype),
+        model.init_paged_cache(17, bs, dtype=jnp.float32))
+    case = ride_case(model, RIDE_ROWS, W, E, bs)
+    dispatch.reset()
+    fused, first, second = ride_passes(model, params, pools, case, W)
+    form = "paged_kernel" if backend == "flash" else "gather_einsum"
+    # traced once a width: the fused pass, and the two passes' W entries
+    assert dispatch.selected()[f"paged_block={form}"] == 2
+    assert fused[0].shape == (W, 4, 512)
+    rides = np.array([ride for _, ride in RIDE_ROWS])
+    live = len(RIDE_ROWS)
+    want = np.where(rides[:, None, None], np.asarray(second[0])[:live],
+                    np.asarray(first[0])[:live])
+    np.testing.assert_allclose(np.asarray(fused[0])[:live], want,
+                               atol=LOGIT_TOL)
+    for a, b in zip(jax.tree.leaves(fused[1]), jax.tree.leaves(second[1])):
+        np.testing.assert_allclose(np.asarray(a[:, 1:]), np.asarray(b[:, 1:]),
+                                   atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(fused[2]),
+                                  np.asarray(first[2]) + np.asarray(second[2]))
+    # the control: no rider, so the next block reads its row's stale block
+    toks, bt, pos = case["fused"]
+    bare = {"fused": (toks, np.concatenate([bt[:W], 0 * bt[W:]]), pos),
+            "first": case["first"], "second": case["second"]}
+    lost = ride_passes(model, params, pools, bare, W)[0][0]
+    assert float(np.abs(np.asarray(lost)[:live][rides]
+                        - want[rides]).max()) > 50 * LOGIT_TOL
+
+
+def ride_counters(engine):
+    c = engine.telemetry_snapshot()["counters"]
+    return (c["serving/block_row_passes"],
+            c["serving/block_commit_row_passes"],
+            c["serving/block_commit_rides"], c["serving/block_decided_tokens"])
+
+
+@pytest.mark.parametrize("rule,threshold", [
+    ("sequential", 0.9), ("low_confidence_static", 0.9),
+    ("low_confidence_dynamic", FIRING)])
+def test_served_with_riders_the_tokens_are_the_references(rule, threshold):
+    """Three rows out of phase, answers of 13 tokens (not a multiple of 4:
+    the last block is cut and ends without a commit), planned a pass ahead
+    (the rider fed from the pass in flight) and landed every pass (the
+    dynamic rule: from the host): every commit rides, every row pass but a
+    prompt's remainder's decides, and the tokens are the reference's."""
+    get_registry().reset()
+    toy = load_toy(rule, 4, threshold)
+    engine = engine_of(toy, telemetry={"enabled": True})
+    prompts = prompts_of((9, 30, 18, 7), seed=21)
+    outs = engine.generate_batch(prompts, max_new_tokens=13)
+    for p, o in zip(prompts, outs):
+        assert list(np.asarray(o)[p.size:]) == reference_tokens(toy, p, 13)
+    passes, alone_, rides, decided = ride_counters(engine)
+    # blocks a request opens less one: the last ends with the request
+    assert alone_ == 0 and rides == sum(
+        -(-(p.size + 13) // 4) - p.size // 4 - 1 for p in prompts)
+    stats = engine._last_serve_stats
+    if rule == "sequential":
+        # one position a pass, and none past the request's last token
+        assert passes == decided == 4 * 13
+    else:
+        # a confidence order decides the cut block whole
+        assert decided >= 4 * 13
+    if rule != "low_confidence_dynamic":
+        assert stats["decode_steps_ahead"] >= 0.8 * stats["decode_steps"]
+    else:
+        assert stats["decode_steps_ahead"] == 0
+
+
+@pytest.mark.parametrize("rule,threshold", [
+    ("sequential", 0.9), ("low_confidence_dynamic", FIRING)])
+def test_more_whole_blocks_than_rider_slots(rule, threshold, monkeypatch):
+    """One rider slot under three rows in phase (the derived width is 8 at
+    any toy's rows, so the rule is planted): the first whole row in
+    admission order rides, the others commit alone exactly as before, a
+    step later and a phase apart; each gets the tokens it gets alone, and
+    rides and lone commits are the blocks committed."""
+    from deepspeed_tpu.inference import scheduler as sched_mod
+    toy = load_toy(rule, 4, threshold)
+    prompts = prompts_of((8, 8, 8), seed=23)
+    want = alone(toy, prompts, 18)
+    monkeypatch.setattr(sched_mod, "ride_slots", lambda gen, rows: 1)
+    get_registry().reset()
+    engine = engine_of(toy, telemetry={"enabled": True})
+    outs = engine.generate_batch(prompts, max_new_tokens=18)
+    for o, w in zip(outs, want):
+        np.testing.assert_array_equal(np.asarray(o), w)
+    passes, alone_, rides, decided = ride_counters(engine)
+    assert alone_ > 0 and rides > 0
+    assert alone_ + rides == 3 * 4        # 5 blocks a request, the last cut
+    if rule == "sequential":
+        # a lone commit is a row pass that decides nothing
+        assert decided == 3 * 18 and passes == decided + alone_
+
+
+def test_a_preemption_with_a_block_open_under_riders():
+    """A pool too small for three rows' growth, a ride needing its block a
+    step sooner than a lone commit did: the victim re-prefills whole blocks
+    and re-enters its open block, riders before and after it, and the
+    counters say every committed block once."""
+    get_registry().reset()
+    toy = load_toy("sequential", 4)
+    prompts = prompts_of((30, 25, 28, 20), seed=2)
+    want = alone(toy, prompts, 40)
+    engine = engine_of(toy, max_num_blocks=9, telemetry={"enabled": True})
+    outs = engine.generate_batch(prompts, max_new_tokens=40)
+    assert engine._last_serve_stats["preemptions"] > 0
+    for o, w in zip(outs, want):
+        np.testing.assert_array_equal(np.asarray(o), w)
+    passes, alone_, rides, decided = ride_counters(engine)
+    assert alone_ == 0 and rides > 0
+    # a victim's decided tokens are decided once: what it streamed comes
+    # back as prefix, the rest of its open block as it landed
+    assert decided == 4 * 40
+
+
+def test_an_eos_inside_a_block_under_riders(toy):
+    """A request's EOS lands while its next step (a ride among them) is
+    queued: that step's row is dropped, the answer ends with the EOS, and
+    the rows beside it get the reference's tokens."""
+    prompts = prompts_of((9, 30, 18), seed=21)
+    refs = [reference_tokens(toy, p, 17) for p in prompts]
+    for cut in (3, 6, 8):             # inside a block, and a block's last
+        eos = refs[0][cut]
+        outs = engine_of(toy).generate_batch(prompts, max_new_tokens=17,
+                                             eos_token_id=int(eos))
+        for p, o, r in zip(prompts, outs, refs):
+            want = r[:r.index(eos) + 1] if eos in r else r
+            assert list(np.asarray(o)[p.size:]) == want, cut
+
+
+def test_the_rider_width_comes_from_the_rows_and_the_record():
+    """``ceil(rows / steps)`` up to whole tiles of 8: 16 beside the cell's
+    64 rows at 4 steps; the session's program is that wide and no wider."""
+    g = T.BlockGeneration(block=4, steps=4)
+    assert [blockgen.ride_slots(g, w) for w in (1, 3, 32, 33, 64, 256)] \
+        == [8, 8, 8, 16, 16, 64]
+    assert blockgen.ride_slots(T.BlockGeneration(block=4, steps=1), 64) == 64
+    assert blockgen.ride_slots(T.BlockGeneration(block=8, steps=3), 64) == 24
 
 
 # (7) an autoregressive model's programs trace to the same jaxprs as before

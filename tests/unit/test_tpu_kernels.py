@@ -886,6 +886,79 @@ def test_sdar_toy_logits_through_the_compiled_kernels(tpu):
     assert err["auto"] <= max(2 * err["xla"], 1e-4), err
 
 
+@tpu_tier
+def test_sdar_riders_through_the_compiled_kernels_at_the_cells_shape(tpu):
+    """A block's commit as a rider entry, COMPILED at the cell's shape: 64
+    main and 16 rider entries of 4 positions, GQA 32/4 at 128 (32 query
+    rows a kv head, the products a kv head), pool blocks of 128, d 2,048
+    and 16 held experts of a router's 128 (two layers of the 48; float32
+    weights, matmuls in full float32). 16 rows ride, their A/B pairs inside
+    one pool block and across two (the whole block ends a pool block), 47
+    do not, one main entry idles. The next blocks' logits and every pool
+    slot are those of a commit pass followed by a denoise pass through the
+    plain-XLA forms; the same fused pass on the XLA forms bounds what the
+    chip's float32 arithmetic leaves between two batchings; with the riders
+    left out the next blocks' logits move by far more."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.models.presets import get_model
+    from deepspeed_tpu.ops import dispatch
+    from tests.unit import test_sdar as sdar
+
+    W, E, bs = 64, 16, 128
+    r = np.random.default_rng(55)
+    depths = (r.integers(0, (4 * bs - 8) // 4, W - 1) * 4).tolist()
+    depths[:6] = [0, 8, bs - 8, bs - 4, 2 * bs - 4, 3 * bs - 4]
+    rows = [(int(p), i < E) for i, p in enumerate(depths)]
+    rides = np.array([ride for _, ride in rows])
+    err = {}
+    with jax.default_matmul_precision("highest"):
+        models = {b: get_model("sdar", "30b-a3b-ep8", n_layer=2,
+                               attention_backend=b, **sdar.REGIME)
+                  for b in ("auto", "xla")}
+        params = models["xla"].init_params(jax.random.key(2))
+        pools = jax.tree.map(
+            lambda a: jnp.asarray(r.standard_normal(a.shape), a.dtype),
+            models["xla"].init_paged_cache(1 + 4 * len(rows), bs,
+                                           dtype=jnp.float32))
+        case = sdar.ride_case(models["xla"], rows, W, E, bs, seed=56)
+        _, first, second = sdar.ride_passes(models["xla"], params, pools,
+                                            case, W)
+        want = np.where(rides[:, None, None], np.asarray(second[0])[:W - 1],
+                        np.asarray(first[0])[:W - 1])
+        scale = float(np.abs(want).max())
+        for backend in ("auto", "xla"):
+            dispatch.reset()
+            fused = sdar.ride_passes(models[backend], params, pools, case,
+                                     W)[0]
+            forms = dispatch.selected()
+            kernels = {"paged_block=paged_kernel", "experts=grouped_kernel",
+                       "paged_decode_attention=per_kv_head",
+                       "kernel/paged_decode_attention=compiled"}
+            assert (kernels <= set(forms)) == (backend == "auto"), forms
+            err[backend] = float(np.abs(
+                np.asarray(fused[0])[:W - 1] - want).max()) / scale
+            err[backend + ".pools"] = max(
+                float(np.abs(np.asarray(a[:, 1:]) - np.asarray(b[:, 1:])).max())
+                for a, b in zip(jax.tree.leaves(fused[1]),
+                                jax.tree.leaves(second[1])))
+            np.testing.assert_array_equal(
+                np.asarray(fused[2]),
+                np.asarray(first[2]) + np.asarray(second[2]))
+        toks, bt, pos = case["fused"]
+        bare = dict(case, fused=(toks, np.concatenate([bt[:W], 0 * bt[W:]]),
+                                 pos))
+        lost = sdar.ride_passes(models["auto"], params, pools, bare, W)[0][0]
+        err["riders_left_out"] = float(np.abs(
+            np.asarray(lost)[:W - 1][rides] - want[rides]).max()) / scale
+    print("riders on the chip, logit error over the largest logit:", err)
+    assert err["xla"] <= 1e-4 and err["xla.pools"] <= 1e-3, err
+    assert err["auto"] <= max(2 * err["xla"], 1e-4), err
+    assert err["auto.pools"] <= max(2 * err["xla.pools"], 1e-3), err
+    assert err["riders_left_out"] > 100 * max(err["auto"], 1e-5), err
+
+
 # the four routed cells' calls: rows, top-k, held experts, of a router's, D, F
 EXPERT_CELLS = {
     "smallthinker": (16, 6, 64, 64, 2560, 768),
