@@ -68,10 +68,24 @@ class BlockAllocator:
     one handed to a request at its admission and back at its release. A
     slot holds no content worth keeping once freed, so there is nothing to
     reference-count: the next holder's first prefill piece starts it from
-    zero."""
+    zero.
+
+    The third (a model with window layers): a second free list, the WINDOW
+    POOL's, of ``window_blocks`` blocks (its block 0 a dummy too) of which a
+    request holds ``min(its blocks of the full pool, ring_blocks)``: one
+    more each time it takes a block of the full pool while it holds fewer
+    than a ring (``grow_window``), all of them back when it finishes or is
+    preempted (``free_window``). The list a request holds IS its window
+    layers' table from the host: logical block ``j`` at entry ``j %
+    ring_blocks``, and what is not handed out yet names the dummy. A
+    request never holds more window blocks than full blocks, so a window
+    pool of ``window_pool_blocks(num_blocks, rows, ring_blocks)`` cannot
+    run dry before the full pool does: admission and preemption read the
+    full pool alone, and ``grow_window`` asserts the invariant."""
 
     def __init__(self, num_blocks: int, block_size: int,
-                 prefix_cache: bool = False, state_slots: int = 0):
+                 prefix_cache: bool = False, state_slots: int = 0,
+                 window_blocks: int = 0, ring_blocks: int = 0):
         if num_blocks < 2:
             raise ValueError(f"num_blocks={num_blocks}: need at least one "
                              "allocatable block besides the reserved dummy")
@@ -104,6 +118,14 @@ class BlockAllocator:
                 "boundaries, which are not built")
         self.state_slots = int(state_slots)
         self._free_slots = list(range(self.state_slots - 1, 0, -1))
+        if window_blocks and (prefix_cache or ring_blocks < 1):
+            raise ValueError(
+                "a window pool needs ring_blocks, and no prefix cache: a "
+                "cached block says nothing of the window before it")
+        self.window_blocks = int(window_blocks)
+        self.ring_blocks = int(ring_blocks)
+        self._free_window = deque(range(1, self.window_blocks))
+        self._window_held = set()
 
     # ------------------------------------------------------------------ #
     # capacity accounting
@@ -146,8 +168,52 @@ class BlockAllocator:
 
     def leak_report(self) -> Dict[int, int]:
         """Blocks still referenced — empty once every request retired
-        (the test-suite teardown assertion; cold blocks are NOT leaks)."""
-        return {b: r for b, r in self._ref.items() if r > 0}
+        (the test-suite teardown assertion; cold blocks are NOT leaks). A
+        window pool's blocks still held come under ``"window"``, counted."""
+        leaks = {b: r for b, r in self._ref.items() if r > 0}
+        if self.window_used:
+            leaks["window"] = self.window_used
+        return leaks
+
+    @staticmethod
+    def window_pool_blocks(num_blocks: int, rows: int, ring_blocks: int) -> int:
+        """The window pool that cannot run dry beside a full pool of
+        ``num_blocks`` and ``rows`` running requests: a ring a row, or a
+        block a block of the full pool, whichever is less (dummies
+        included)."""
+        return min(int(num_blocks), int(rows) * int(ring_blocks) + 1)
+
+    @property
+    def window_used(self) -> int:
+        """Window-pool blocks requests hold right now."""
+        return len(self._window_held)
+
+    def grow_window(self, held: List[int], blocks: int) -> None:
+        """Top ``held``, a request's window blocks in ring order, up to
+        ``min(blocks, ring_blocks)``, ``blocks`` being what it holds of the
+        full pool. Nothing for a model without window layers."""
+        if not self.window_blocks:
+            return
+        for _ in range(min(blocks, self.ring_blocks) - len(held)):
+            # never dry: window held <= full held <= num_blocks - 1, and
+            # <= rows x ring_blocks by the min above
+            held.append(self._free_window.popleft())
+            self._window_held.add(held[-1])
+        if self.window_used > self._num_used:
+            raise AssertionError(
+                f"requests hold {self.window_used} window blocks and "
+                f"{self._num_used} of the full pool: a request took a "
+                "window block without a block of the full pool")
+
+    def free_window(self, held: List[int]) -> None:
+        """Give a request's window blocks back (it finished or was
+        preempted); ``held`` is emptied."""
+        for b in held:
+            if b not in self._window_held:
+                raise ValueError(f"window block {b} is not held")
+            self._window_held.remove(b)
+        self._free_window.extend(held)
+        del held[:]
 
     @property
     def slots_held(self) -> int:

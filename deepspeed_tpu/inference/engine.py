@@ -844,27 +844,47 @@ class InferenceEngine:
             return NamedSharding(self.mesh, P(None, None, "tp"))
         return NamedSharding(self.mesh, P())
 
-    def _state_slots(self) -> int:
-        """Slots a model needs whose layers keep something a REQUEST beside
-        the shared KV pool, a recurrent state or a window layer's ring of
-        blocks (one a running request and the dummy), 0 for a model without:
-        read from the model's cache spec, the one place what it keeps is
-        declared."""
-        spec = getattr(getattr(self.module, "config", None), "cache_spec",
+    def _cache_spec(self) -> dict:
+        """The model's cache spec, the one place what it keeps is declared
+        ({} for a model without one)."""
+        return getattr(getattr(self.module, "config", None), "cache_spec",
                        None) or {}
+
+    def _state_slots(self) -> int:
+        """Slots a model needs whose layers keep a recurrent or conv STATE
+        a request beside the shared KV pool (one a running request and the
+        dummy), 0 for a model without. A window layer takes none: its
+        blocks are the allocator's second free list."""
         return int(self._config.serving.max_running) + 1 \
-            if spec.get("state") or spec.get("window") else 0
+            if self._cache_spec().get("state") else 0
+
+    def _window_blocks(self, num_blocks: int, block_size: int) -> int:
+        """Blocks of the window layers' pool beside a full pool of
+        ``num_blocks`` (``BlockAllocator.window_pool_blocks``), 0 for a
+        model without window layers."""
+        from deepspeed_tpu.inference.block_allocator import BlockAllocator
+        if not self._cache_spec().get("window"):
+            return 0
+        return BlockAllocator.window_pool_blocks(
+            num_blocks, self._config.serving.max_running,
+            self.module.config.ring_blocks(block_size))
+
+    def _keeps_beside_kv(self) -> bool:
+        """Whether the model keeps something a request that no KV block
+        holds (a state slot, a window layer's blocks): what a prefix hit,
+        a verify window's rewind, the host tier and a head-sharded pool
+        are refused beside."""
+        spec = self._cache_spec()
+        return bool(spec.get("state") or spec.get("window"))
 
     def _latent_rows(self) -> int:
         """The model's layers that keep latent rows (its cache spec)."""
-        spec = getattr(getattr(self.module, "config", None), "cache_spec",
-                       None) or {}
-        return int(spec.get("latent", 0))
+        return int(self._cache_spec().get("latent", 0))
 
     def _slot_kept(self) -> str:
-        """What the model keeps a slot, for a refusal's message."""
-        spec = self.module.config.cache_spec
-        return "a recurrent state" if spec.get("state") \
+        """What the model keeps a request beside its KV blocks, for a
+        refusal's message."""
+        return "a recurrent state" if self._cache_spec().get("state") \
             else "a window layer's ring of blocks"
 
     def _kv_host_pool_for(self, num_blocks: int, block_size: int,
@@ -881,7 +901,7 @@ class InferenceEngine:
                 "(cache_spec['latent']): the host tier's block slice is "
                 "[layers, block_size, kv_heads * head_dim] of a k and a v "
                 "pool, and a latent block is neither")
-        if kh is not None and kh.enabled and self._state_slots():
+        if kh is not None and kh.enabled and self._keeps_beside_kv():
             raise ValueError(
                 f"serving.kv_host is on but the model keeps {self._slot_kept()} "
                 "beside its KV: a block on the host says nothing of the "
@@ -1106,18 +1126,22 @@ class InferenceEngine:
         workspace has no valid cached content, so the caller must drop any
         persisted prefix-cache state alongside it."""
         pw = getattr(self, "_paged_workspace", None)
-        slots = self._state_slots()
+        # what sizes the pools beside the KV blocks' geometry, the
+        # workspace's key with it
+        slots, wblocks = kept = (self._state_slots(),
+                                 self._window_blocks(num_blocks, block_size))
         if pw is not None and pw[0] == num_blocks and pw[1] == block_size \
-                and pw[3] == slots:
+                and pw[3] == kept:
             leaves = jax.tree.leaves(pw[2])
             if not any(getattr(a, "is_deleted", lambda: False)() for a in leaves):
                 return pw[2], True
         pools = self.module.init_paged_cache(
             num_blocks, block_size, dtype=self.dtype,
-            **({"state_slots": slots} if slots else {}))
+            **({"state_slots": slots} if slots else {}),
+            **({"window_blocks": wblocks} if wblocks else {}))
         kv_sh = self._kv_head_sharding()
         pools = jax.tree.map(lambda a: jax.device_put(a, kv_sh), pools)
-        self._paged_workspace = (num_blocks, block_size, pools, slots)
+        self._paged_workspace = (num_blocks, block_size, pools, kept)
         return pools, False
 
     def _paged_allocator(self, num_blocks: int, block_size: int,
@@ -1134,8 +1158,12 @@ class InferenceEngine:
 
         if not caching:
             self._paged_alloc = None
-            return BlockAllocator(num_blocks, block_size,
-                                  state_slots=self._state_slots())
+            wblocks = self._window_blocks(num_blocks, block_size)
+            return BlockAllocator(
+                num_blocks, block_size, state_slots=self._state_slots(),
+                window_blocks=wblocks,
+                ring_blocks=self.module.config.ring_blocks(block_size)
+                if wblocks else 0)
         pa = self._paged_alloc
         if (pools_reused and pa is not None
                 and pa.num_blocks == num_blocks
@@ -1177,16 +1205,24 @@ class InferenceEngine:
             # named functions, not lambdas: the name is what a device trace
             # calls the program (``jit_paged_decode``) and what the
             # persistent compile cache keys it by
-            # ``ss``: the state slot(s) of a model that keeps a recurrent
-            # state (its cache spec), the last operand; None for the others
-            def _state(ss, name):
-                return {} if ss is None else {name: ss}
+            # ``kept``: what the model keeps a request beside its KV blocks,
+            # by its cache spec and in this order, the last operands: the
+            # state slot(s) of a model with a recurrent state, the window
+            # table(s) from the host of one with window layers
+            spec = self._cache_spec()
 
-            def paged_prefill(p, t, pools, slots, li, ss=None):
+            def _kept(kept, slot, table=None):
+                names = [n for n, on in ((slot, spec.get("state")),
+                                         (table, spec.get("window")))
+                         if n and on]
+                return dict(zip(names, kept, strict=True))
+
+            def paged_prefill(p, t, pools, slots, li, *kept):
                 return _pinned(mod.forward_paged_prefill)(
-                    p, t, pools, slots, li, **_state(ss, "state_slot"))
+                    p, t, pools, slots, li,
+                    **_kept(kept, "state_slot", "window_table"))
 
-            def paged_decode(p, t, pools, bt, pos, ss=None):
+            def paged_decode(p, t, pools, bt, pos, *kept):
                 # the session hands ``t`` over as the step's token feed
                 # ``(prev, idx, toks)``: row i takes the token at
                 # ``prev[idx[i]]``, still on the device as the sampler left
@@ -1201,11 +1237,13 @@ class InferenceEngine:
                                   prev[jnp.maximum(idx, 0)][:, None]
                                   .astype(toks.dtype), toks)
                 return _pinned(mod.forward_paged_decode)(
-                    p, t, pools, bt, pos, **_state(ss, "state_slots"))
+                    p, t, pools, bt, pos,
+                    **_kept(kept, "state_slots", "window_tables"))
 
-            def paged_prefill_chunk(p, t, pools, bt, slots, sp, li, ss=None):
+            def paged_prefill_chunk(p, t, pools, bt, slots, sp, li, *kept):
                 return _pinned(mod.forward_paged_prefill_chunk)(
-                    p, t, pools, bt, slots, sp, li, **_state(ss, "state_slot"))
+                    p, t, pools, bt, slots, sp, li,
+                    **_kept(kept, "state_slot"))
 
             def paged_verify(p, t, pools, bt, slots, pos):
                 return _pinned(mod.forward_paged_verify)(
@@ -1473,7 +1511,7 @@ class InferenceEngine:
         chunk_ok = hasattr(self.module, "forward_paged_prefill_chunk")
         # what cannot hold beside a recurrent state or a window layer's
         # ring yet, each refused from the model's cache spec
-        stateful = bool(self._state_slots())
+        stateful = self._keeps_beside_kv()
         if stateful and pc_mode == "on":
             raise ValueError(
                 f"serving.prefix_caching='on' but the model keeps "
@@ -1507,7 +1545,7 @@ class InferenceEngine:
                 "(no form reads the ring beside the chunk's own keys)")
         if stateful and self.mesh.shape.get("tp", 1) > 1:
             raise ValueError(f"serving.tp > 1 but the model keeps "
-                             f"{self._slot_kept()}: the pools of its slots "
+                             f"{self._slot_kept()}: the pools that hold it "
                              "are not sharded")
         # what no program reads a latent row for yet (a hit's tail and a
         # chunk ride the chunk forward, speculation the verify window)
@@ -1755,13 +1793,15 @@ class _ServeSession:
         self.retain_finished = retain_finished
         self._finished_seen = 0
         self._closed = False
-        # a model that keeps a recurrent state or a window layer's ring
-        # takes the requests' slots as each program's last operand
+        # a model that keeps a recurrent state takes the requests' slots,
+        # one with window layers their window tables from the host, as
+        # each program's last operands
         self._stateful = "state" in pools
-        self._slotted = self._stateful or "wk" in pools
-        # a window layer's reach (positions); 0: none
+        # a window layer's reach (positions) and its ring's blocks; 0: none
         self._window = int(engine.module.config.attn_window) \
             if "wk" in pools else 0
+        self._ring = engine.module.config.ring_blocks(bs) \
+            if self._window else 0
         self._flight: Optional[_Launched] = None
         # the newest sampled tokens at the decode width, on the device:
         # what a decode step's feed gathers from (the step in flight's, if any)
@@ -2072,7 +2112,7 @@ class _ServeSession:
         the session has no spill hook / host tier)."""
         if self._closed:
             raise RuntimeError("serving session is closed")
-        if self._slotted:
+        if self._stateful or self._window:
             raise NotImplementedError(
                 "a prefill->decode handoff moves KV blocks through the host "
                 "tier, and this model keeps a recurrent state or a window "
@@ -2363,9 +2403,16 @@ class _ServeSession:
                 self.sched.telemetry.count_state_reset()
         return toks, table, slots.astype(np.int32), np.int32(n - 1)
 
-    def _state_of(self, req):
-        """The trailing operand of a stateful model's prefill programs."""
-        return (np.int32(req.state_slot),) if self._slotted else ()
+    def _kept_of(self, req, window: bool = True):
+        """The trailing operands of a prefill program: the request's slot
+        (a model with recurrent state), then its window table (one with
+        window layers; a chunk program takes none)."""
+        kept = (np.int32(req.state_slot),) if self._stateful else ()
+        if self._window and window:
+            wt = np.zeros((self._ring,), np.int32)          # zeros → dummy
+            wt[:len(req.window_blocks)] = req.window_blocks
+            kept += (wt,)
+        return kept
 
     def _prefill_inputs(self, reqs):
         # generation by blocks prefills the prefix's whole generation
@@ -2376,7 +2423,7 @@ class _ServeSession:
         prefix = whole[:reqs[0].prefill_target]
         toks, _, slots, last = self._piece_inputs(
             reqs[0], prefix, 0, prefix.size, bucket_of=whole.size)
-        return (toks, slots, last, *self._state_of(reqs[0])), (0, prefix.size)
+        return (toks, slots, last, *self._kept_of(reqs[0])), (0, prefix.size)
 
     def _chunk_inputs(self, reqs):
         req = reqs[0]
@@ -2392,7 +2439,7 @@ class _ServeSession:
         bt = np.zeros((1, nb), np.int32)
         bt[0, :table.size] = table
         return (toks, bt, slots, np.int32(start), last,
-                *self._state_of(req)), (start, n)
+                *self._kept_of(req, window=False)), (start, n)
 
     def _decode_inputs(self, reqs):
         tel = self.sched.telemetry
@@ -2406,8 +2453,17 @@ class _ServeSession:
         ahead = self._flight
         src = {} if ahead is None else {
             id(r): j for j, r in enumerate(ahead.reqs)}
+        # the window layers' tables beside ``bt``, built in the same pass:
+        # a row's changes only on the steps in which it takes a block
+        # below a whole ring
+        wt = np.zeros((self.W, self._ring), np.int32) \
+            if self._window else None                       # zeros → dummy
+        held = 0
         for i, r in enumerate(reqs):
             bt[i, :len(r.blocks)] = r.blocks
+            if wt is not None:
+                wt[i, :len(r.window_blocks)] = r.window_blocks
+                held += len(r.window_blocks)
             pos[i] = r.pos
             j = src.get(id(r))
             if j is None:
@@ -2421,16 +2477,19 @@ class _ServeSession:
                 int((pos[:len(reqs)] // self.bs + 1).sum()))
             if self._stateful:
                 tel.count_state(len(reqs))
-            if self._window:
-                tel.count_window(pos, len(reqs), self._window, self.bs)
-        state = ()
-        if self._slotted:
+        kept = ()
+        if self._stateful:
             slots = np.zeros((self.W,), np.int32)           # zeros → dummy
             slots[:len(reqs)] = [r.state_slot for r in reqs]
-            state = (slots,)
+            kept = (slots,)
+        if wt is not None:
+            kept += (wt,)
+            if tel is not None:
+                tel.count_window(pos, len(reqs), self._window, self.bs,
+                                 held, self._ring)
         # _tok_dev: a request decodes after its own prefill, so the sampler
         # has left tokens there by the first decode step
-        return ((self._tok_dev, idx, toks), bt, pos, *state), None
+        return ((self._tok_dev, idx, toks), bt, pos, *kept), None
 
     def _block_inputs(self, reqs):
         """A fused pass of generation by blocks: W main entries, each row's
@@ -2622,8 +2681,10 @@ class _ServeSession:
             # decode workspace are the serving memory story)
             from deepspeed_tpu.monitor.health import sample_memory_gauges
             sample_memory_gauges(engine._tel_reg)
-        engine._paged_workspace = (self.num_blocks, self.bs, self.pools,
-                                   engine._state_slots())
+        engine._paged_workspace = (
+            self.num_blocks, self.bs, self.pools,
+            (engine._state_slots(),
+             engine._window_blocks(self.num_blocks, self.bs)))
 
 
 class _ActionKind(NamedTuple):

@@ -187,12 +187,13 @@ class ServingTelemetry:
     and ``serving/cold_blocks`` gauges the freed-but-cached pool blocks."""
 
     _SERIES = ("ttft", "tpot", "queue_wait", "queue_depth", "running",
-               "kv_blocks_used",
+               "kv_blocks_used", "window_blocks_used",
                "kv_blocks_free", "kv_block_utilization", "kv_fragmentation",
                "cold_blocks", "prefill_steps", "prefill_chunks",
                "prefill_tokens", "prefill_padded_tokens",
                "decode_steps", "decode_steps_ahead", "decode_steps_late",
                "decode_live_kv_tokens", "decode_live_kv_blocks",
+               "decode_window_blocks_held", "decode_window_ring_blocks",
                "prefix_cache_lookups", "prefix_cache_hits",
                "prefix_cache_hit_tokens",
                "kv_host_blocks", "kv_host_bytes", "kv_spills",
@@ -267,6 +268,28 @@ class ServingTelemetry:
         return self.registry.gauge(
             "serving/kv_blocks_used",
             "pool blocks referenced by live requests (excl. dummy)")
+
+    @property
+    def window_blocks_used(self):
+        return self.registry.gauge(
+            "serving/window_blocks_used",
+            "blocks of the window layers' pool that live requests hold "
+            "(excl. dummy; 0 for a model without window layers)")
+
+    @property
+    def decode_window_blocks_held(self):
+        return self.registry.counter(
+            "serving/decode_window_blocks_held",
+            "per fused decode step, the window-pool blocks its live rows "
+            "HOLD (min(blocks of the full pool, a ring) each): over "
+            "decode_window_ring_blocks, how full the rings are")
+
+    @property
+    def decode_window_ring_blocks(self):
+        return self.registry.counter(
+            "serving/decode_window_ring_blocks",
+            "per fused decode step, its live rows x the blocks of a whole "
+            "ring: what the rows would hold with a ring each")
 
     @property
     def kv_blocks_free(self):
@@ -470,10 +493,15 @@ class ServingTelemetry:
             "updates: over decode_steps, the states a step reads and writes"
         ).inc(rows)
 
-    def count_window(self, pos, rows: int, window: int, bs: int) -> None:
+    def count_window(self, pos, rows: int, window: int, bs: int,
+                     held: int, ring: int) -> None:
         """One fused decode step of a model with window layers: ``pos``
-        [W] the step's rows' depths (idle rows 0), the first ``rows`` live.
-        Not pre-created: a model without a window has none of these."""
+        [W] the step's rows' depths (idle rows 0), the first ``rows`` live,
+        which hold ``held`` blocks of the window pool where a ring has
+        ``ring``. The live counters are not pre-created: a model without a
+        window has none of them."""
+        self.decode_window_blocks_held.inc(held)
+        self.decode_window_ring_blocks.inc(rows * ring)
         c = self.registry.counter
         first = np.maximum(pos - (window - 1), 0) // bs
         c("serving/decode_live_window_kv_tokens",
@@ -776,6 +804,10 @@ class Request:
     blocks: List[int] = dataclasses.field(default_factory=list)
     state_slot: int = 0             # its row of the state pools, held like
     # its blocks from admission to release (0: none, or a model without)
+    # its blocks of the window layers' pool in ring order, the window
+    # layers' table: one more with each block of ``blocks`` up to a ring
+    # (``BlockAllocator.grow_window``); empty for a model without a window
+    window_blocks: List[int] = dataclasses.field(default_factory=list)
     pos: int = 0                    # tokens currently cached in the pools
     generated: List[int] = dataclasses.field(default_factory=list)
     admit_seq: int = -1             # admission stamp (eviction order)
@@ -989,6 +1021,7 @@ class ContinuousBatchingScheduler:
         t.running.set(len(self.running))
         used = a.num_used
         t.kv_blocks_used.set(used)
+        t.window_blocks_used.set(a.window_used)
         t.kv_blocks_free.set(a.num_free)
         t.cold_blocks.set(a.num_cold)
         hp = a.host_pool
@@ -1457,6 +1490,7 @@ class ContinuousBatchingScheduler:
             self.telemetry.phase(
                 "queue", max(now - req.t_arrival, 0.0) * 1e3, rid=req.rid)
         req.blocks = blocks
+        self.allocator.grow_window(req.window_blocks, len(blocks))
         # max_running + 1 slots and at most max_running rows: one is free
         req.state_slot = self.allocator.allocate_slot()
         if req.state_slot is None:
@@ -1686,6 +1720,8 @@ class ContinuousBatchingScheduler:
                 got = self.allocator.allocate(1)
                 if got is not None:
                     req.blocks.extend(got)
+                    self.allocator.grow_window(req.window_blocks,
+                                               len(req.blocks))
                     break
                 victim = self.policy.select_victim(self, req)
                 # identity scan: Request's dataclass __eq__ compares numpy
@@ -1798,6 +1834,7 @@ class ContinuousBatchingScheduler:
         if self.prefix_caching:
             blocks = list(reversed(blocks))
         self.allocator.free(blocks)
+        self.allocator.free_window(req.window_blocks)
         self.allocator.free_slot(req.state_slot)
         req.state_slot = 0
         req.blocks = []

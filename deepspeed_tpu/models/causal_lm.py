@@ -61,14 +61,17 @@ class CausalLM:
     # ---- paged KV serving (see transformer.forward_paged_*) ----
 
     def init_paged_cache(self, num_blocks: int, block_size: int,
-                         dtype=jnp.bfloat16, state_slots: int = 0) -> Dict[str, Any]:
+                         dtype=jnp.bfloat16, state_slots: int = 0,
+                         window_blocks: Optional[int] = None) -> Dict[str, Any]:
         return T.init_paged_kv_cache(self.config, num_blocks, block_size, dtype,
-                                     state_slots=state_slots)
+                                     state_slots=state_slots,
+                                     window_blocks=window_blocks)
 
     def forward_paged_prefill(self, params, tokens, pools, slots, last_idx,
-                              state_slot=None):
+                              state_slot=None, window_table=None):
         return T.forward_paged_prefill(self.config, params, tokens, pools,
-                                       slots, last_idx, state_slot=state_slot)
+                                       slots, last_idx, state_slot=state_slot,
+                                       window_table=window_table)
 
     def forward_paged_prefill_chunk(self, params, tokens, pools,
                                     block_tables, slots, start_pos, last_idx,
@@ -79,10 +82,12 @@ class CausalLM:
                                              state_slot=state_slot)
 
     def forward_paged_decode(self, params, tokens, pools, block_tables, pos,
-                             pad_bias=None, state_slots=None):
+                             pad_bias=None, state_slots=None,
+                             window_tables=None):
         return T.forward_paged_decode(self.config, params, tokens, pools,
                                       block_tables, pos, pad_bias,
-                                      state_slots=state_slots)
+                                      state_slots=state_slots,
+                                      window_tables=window_tables)
 
     def forward_paged_verify(self, params, tokens, pools, block_tables,
                              slots, pos):
@@ -101,7 +106,8 @@ class CausalLM:
         n_lead = len(cfg.lead_kinds)
         mlps = (cfg.n_layer - n_lead) * T.dense_mlp_params(cfg) \
             + n_lead * T.dense_mlp_params(cfg, cfg.lead_d_ff)
-        norms = (4 if cfg.norm == "layernorm" else 2) * cfg.d_model
+        norms = (4 if cfg.norm == "layernorm" else 2) * cfg.d_model \
+            * (2 if cfg.norm_position == "sandwich" else 1)
         final_norm = (2 if cfg.norm == "layernorm" else 1) * cfg.d_model
         if cfg.embed_layernorm:
             final_norm += (2 if cfg.norm == "layernorm" else 1) * cfg.d_model

@@ -683,9 +683,11 @@ class MoECausalLM:
     # counted nowhere (T.paged_real_rows).
 
     def init_paged_cache(self, num_blocks: int, block_size: int,
-                         dtype=jnp.bfloat16, state_slots: int = 0) -> Dict[str, Any]:
+                         dtype=jnp.bfloat16, state_slots: int = 0,
+                         window_blocks: Optional[int] = None) -> Dict[str, Any]:
         return T.init_paged_kv_cache(self.config, num_blocks, block_size, dtype,
-                                     state_slots=state_slots)
+                                     state_slots=state_slots,
+                                     window_blocks=window_blocks)
 
     def _paged(self, params, pools, slots, counts: bool = False):
         """The ``mlp_fn`` of a ``transformer.forward_paged_*`` call whose
@@ -730,11 +732,12 @@ class MoECausalLM:
         return mlp_fn
 
     def forward_paged_prefill(self, params, tokens, pools, slots, last_idx,
-                              state_slot=None):
+                              state_slot=None, window_table=None):
         mlp_fn = self._paged(params, pools, slots)
         return T.forward_paged_prefill(self.config, params, tokens, pools,
                                        slots, last_idx, mlp_fn=mlp_fn,
-                                       state_slot=state_slot)
+                                       state_slot=state_slot,
+                                       window_table=window_table)
 
     def forward_paged_prefill_chunk(self, params, tokens, pools,
                                     block_tables, slots, start_pos, last_idx,
@@ -751,7 +754,8 @@ class MoECausalLM:
                                       block_tables, slots, pos, mlp_fn=mlp_fn)
 
     def forward_paged_decode(self, params, tokens, pools, block_tables, pos,
-                             pad_bias=None, state_slots=None):
+                             pad_bias=None, state_slots=None,
+                             window_tables=None):
         """(logits [B, vocab], new pools, counts [L, E + 1]): third, the
         assignments each expert of each MoE layer computed in this step and,
         in column E, those the layer owed (real rows x k; a share: those to
@@ -763,7 +767,8 @@ class MoECausalLM:
         mlp_fn = self._paged(params, pools, slots, counts=True)
         return T.forward_paged_decode(self.config, params, tokens, pools,
                                       block_tables, pos, pad_bias, mlp_fn=mlp_fn,
-                                      state_slots=state_slots)
+                                      state_slots=state_slots,
+                                      window_tables=window_tables)
 
     def forward_paged_block(self, params, tokens, pools, block_tables, pos,
                             n_logits=None):
@@ -805,7 +810,8 @@ class MoECausalLM:
         if self._select_bias:
             moe_mlp += self.router_width                   # the bias
         moe_mlp += 3 * D * moe.shared_expert_d_ff
-        norms = (4 if cfg.norm == "layernorm" else 2) * D
+        norms = (4 if cfg.norm == "layernorm" else 2) * D \
+            * (2 if cfg.norm_position == "sandwich" else 1)
         final_norm = (2 if cfg.norm == "layernorm" else 1) * D
         head = 0 if cfg.tie_embeddings else D * cfg.vocab_size
         # a shortcut MoE: one a period, and a dense MLP in every sub-block;

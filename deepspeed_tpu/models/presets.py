@@ -8,6 +8,7 @@ config). Sizes follow the published architecture tables.
 
 from __future__ import annotations
 
+import math
 from typing import Callable, Dict
 
 import jax.numpy as jnp
@@ -526,6 +527,86 @@ def lfm2_moe(size: str = "24b-a2b-9l", **over):
         param_dtype=param_dtype)
 
 
+def trinity(size: str = "large-preview-5l-ep8", share: int = 0, **over):
+    """Trinity-Large-Preview (``arcee-ai/Trinity-Large-Preview``
+    config.json, ``model_type`` ``afmoe``, 400B-A13B): 60 SANDWICH-NORMED
+    layers (RMSNorm, eps 1e-5, on each branch's way in and on its way out:
+    ``h + norm_post(branch(norm_pre(h)))``, no bias anywhere) of d 3,072.
+    Attention is GQA of 48 query and 8 key/value heads of 128 with a
+    sigmoid output gate and an RMSNorm of each head's q and k; by
+    ``layer_types`` three layers of four have a WINDOW of 4,096 and rope
+    (theta 10,000, the half-split pairing, the whole head) and the fourth
+    is FULL attention with NO positions. Layers 0-5 have a dense gated-SiLU
+    MLP of 12,288; the other 54 have 256 gated-SiLU experts of 3,072, 4 a
+    token by a float32 sigmoid score plus a selection bias (``expert_bias``,
+    for the choice alone), the four scores divided by their sum + 1e-20,
+    times ``route_scale`` 2.448, beside one shared expert of 3,072. The
+    embedding is multiplied by sqrt(3,072) (``mup_enabled``); a final
+    RMSNorm and an untied head over 200,192 rows. 398.6 B parameters.
+    ``large-preview-5l-ep8`` is ONE CHIP OF THE EIGHT that share each layer
+    (perfbench's ``trinitylarge_serve_shortlong``): one leading dense WINDOW
+    layer (``lead_kinds``; leading dense layers count once) and ONE whole
+    period of the pattern (window, window, window, full: published layers
+    8-11), the router's 256 outputs with the 32 experts of ``share`` (0-7)
+    held here, attention and the shared expert whole, the vocabulary this
+    chip's eighth: 4,321,903,872 parameters, 8.64 GB in bf16. ``max_seq``
+    is what the deployment serves (6,144 + 1,024; the block tables'
+    width). Its seeded init: matrices at the library's 0.02 (output
+    projections depth-scaled), every norm's scale 1, and the EMBEDDING at
+    ``embed_init_std`` 0.16, 8.9 a value after the sqrt(d) multiplier. A
+    sandwich norm hands every branch to the stream at a deviation of 1
+    whatever its weights are, so the embedding's scale alone sets how much
+    of the stream one branch is (a ninth here), and with it both what a
+    served token can show of a fault and what a flipped boundary choice of
+    the router costs (one held expert beside the shared one is half an
+    MoE branch, and a flip feeds the next layers' routers). Chosen on the
+    chip with ``benchmarks/trinity_check_controls.py`` (PERF.md section 6,
+    PR 56): at 0.08 a sound prompt of 16 read 17 of the served check's 4
+    bf16 steps; at 0.16, 0.3 and 0.5 none of 96 sound prompts each
+    was refused (worst 1.68, 0.85, 1.17: a bf16 logit's own step), while
+    the check's reach falls with the scale (a missing post-norm is refused
+    on 16, 13 of 16 prompts at 0.16, 0.3; a missing shared expert on 16,
+    8; a missing gate on 5, 2; float8 on 2, 0; a window left out on 1, 0):
+    0.16 is the least scale whose sound readings leave room under the
+    limit, and the check reaches twice as far under it as under 0.3; what
+    it still does not see is held by the float32 logits tests and the
+    tool's ``--logits``. What the check sees under it is in the
+    configuration file (``assumed.seeded_init``).
+    ``tiny``: the lead and TWO periods at toy widths, a window of 256 (two
+    blocks of 128), 4 of 16 experts held, top-4."""
+    from deepspeed_tpu.models.moe_lm import MoECausalLM, MoEConfig
+    dims, moe = {
+        "tiny": (dict(n_layer=9, n_head=4, n_kv_head=2, head_size=32,
+                      d_model=64, d_ff=32, lead_d_ff=96, vocab_size=512,
+                      max_seq=1024, attn_window=256, embed_init_std=0.16),
+                 dict(num_experts=4, router_experts=16, k=4, expert_d_ff=32,
+                      shared_expert_d_ff=32)),
+        "large-preview-5l-ep8": (
+            dict(n_layer=5, n_head=48, n_kv_head=8, head_size=128,
+                 d_model=3072, d_ff=3072, lead_d_ff=12288, vocab_size=25024,
+                 max_seq=7168, attn_window=4096, embed_init_std=0.16),
+            dict(num_experts=32, router_experts=256, k=4, expert_d_ff=3072,
+                 shared_expert_d_ff=3072)),
+    }[size]
+    param_dtype = over.pop("param_dtype", jnp.float32)
+    moe = {**moe, **over.pop("moe", {})}
+    dims = {**dims, **over}
+    cfg = TransformerConfig(**{**dict(
+        pos_embedding="rope", rope_theta=10000.0, norm="rmsnorm",
+        norm_eps=1e-5, norm_position="sandwich", activation="swiglu",
+        tie_embeddings=False, attn_bias=False, qk_norm="head",
+        attn_out_gate=True, lead_kinds=("window_attention",),
+        layer_kinds=("window_attention",) * 3 + ("attention",),
+        rope_kinds=("window_attention",),
+        embedding_multiplier=math.sqrt(dims["d_model"])), **dims})
+    return MoECausalLM(cfg, MoEConfig(**{**dict(
+        dispatch="nodrop", expert_activation="swiglu", scoring="sigmoid",
+        norm_topk_prob=True, norm_topk_eps=1e-20,
+        routed_scaling_factor=2.448, aux_loss_coef=0.0,
+        expert_offset=share * moe["num_experts"]), **moe}),
+        param_dtype=param_dtype)
+
+
 MODEL_PRESETS: Dict[str, Callable] = {
     "gpt2": gpt2,
     "llama": llama,
@@ -539,6 +620,7 @@ MODEL_PRESETS: Dict[str, Callable] = {
     "granite_hybrid": granite_hybrid,
     "longcat_flash": longcat_flash,
     "lfm2_moe": lfm2_moe,
+    "trinity": trinity,
 }
 
 

@@ -117,7 +117,11 @@ class TransformerConfig:
     norm: str = "layernorm"              # layernorm | rmsnorm
     activation: str = "gelu"             # gelu (tanh) | gelu_exact | quick_gelu | swiglu | relu
     parallel_residual: bool = False      # gpt-neox style
-    norm_position: str = "pre"           # pre (GPT) | post (BERT add&norm)
+    # pre (GPT) | post (BERT add&norm) | sandwich: each branch normed on the
+    # way in AND on the way out, h + norm_post(branch(norm_pre(h))), a layer's
+    # two more norms ``ln_attn_post`` / ``ln_mlp_post`` (the paged serving
+    # path alone builds it)
+    norm_position: str = "pre"
     causal: bool = True
     tie_embeddings: bool = True
     embed_layernorm: bool = False        # BLOOM word_embeddings_layernorm
@@ -310,8 +314,11 @@ class TransformerConfig:
         that hold KV blocks, ``state`` those that hold a conv state, and
         all but the short conv's a recurrent state, in a slot a request
         (``STATE_KINDS``), ``window`` those that hold
-        the last ``attn_window`` positions' KV in a ring of blocks a
-        request, ``latent`` those that hold blocks of latent rows
+        the last ``attn_window`` positions' KV in a ring of at most
+        ``ring_blocks`` blocks of their own pool, handed out as the request
+        grows and named by a table from the host (logical block ``j`` at
+        entry ``j % ring_blocks``), ``latent`` those that hold blocks of
+        latent rows
         (``latent_row`` values a token, addressed by the same block tables
         as KV blocks). What cannot hold with a state, a ring or a latent
         row yet (a prefix hit, a verify window's rewind, a block moved to
@@ -380,6 +387,9 @@ def _check_pattern(cfg: TransformerConfig):
                          f"(expected {'|'.join(known)})")
     if WINDOW_ATTENTION in kinds and cfg.attn_window < 1:
         raise ValueError("a window_attention layer needs attn_window")
+    if cfg.norm_position not in ("pre", "post", "sandwich"):
+        raise ValueError(f"norm_position {cfg.norm_position!r} (expected "
+                         "pre|post|sandwich)")
     if (cfg.n_layer - len(lead)) % len(period) or cfg.n_layer <= len(lead):
         raise ValueError(f"n_layer {cfg.n_layer} is not {len(lead)} leading "
                          f"layers and whole periods of the layer pattern "
@@ -445,6 +455,9 @@ def _layer_group(cfg: TransformerConfig, kind: str, n: int, ks, dtype,
                if cfg.attn_out_gate else {}),
         }
     group["ln_mlp"] = norm_params()
+    if cfg.norm_position == "sandwich":
+        group["ln_attn_post"] = norm_params()
+        group["ln_mlp_post"] = norm_params()
     group["mlp"] = ({
         "w_gate": dense(ks[4], (n, D, F)),
         "w_up": dense(ks[5], (n, D, F)),
@@ -1481,22 +1494,34 @@ def _decode_block(cfg: TransformerConfig, h, lp, mix_fn, mlp_fn=None,
     Mamba-2), under ``scope``; ``mlp_fn(cfg, x_normed, lp, mixer_in)``
     overrides the dense MLP (MoE; ``mixer_in`` is what the mixer read, for a
     router placed before attention). A branch joins the residual stream
-    times ``cfg.residual_multiplier`` where the model has one. Returns (h,
-    *new caches)."""
+    times ``cfg.residual_multiplier`` where the model has one, and under
+    ``norm_position="sandwich"`` through its own norm (``ln_attn_post`` /
+    ``ln_mlp_post``, scope ``branch_norm`` inside the mixer's scope and
+    inside ``mlp``). Returns (h, *new caches)."""
     mfn = mlp_fn if mlp_fn is not None else (
         lambda c, xx, lpp, _: mlp(c, xx, lpp["mlp"]))
     # 1.0 puts no multiply into a model's step that has no multiplier
     rm = cfg.residual_multiplier
     scaled = (lambda b: b) if rm == 1.0 else (lambda b: b * rm)
+
+    def joins(b, post, where):
+        """Branch ``b`` as it joins the stream: through the layer's norm
+        ``post`` under a sandwich norm, as it is otherwise."""
+        if cfg.norm_position != "sandwich":
+            return scaled(b)
+        with jax.named_scope(where), jax.named_scope("branch_norm"):
+            return scaled(_norm(cfg, b, lp[post]))
+
     xa = _norm(cfg, h, lp["ln_attn"])
     with jax.named_scope(scope):
         a, *caches = mix_fn(xa)
+    a = joins(a, "ln_attn_post", scope)
     if cfg.parallel_residual:
         m = mfn(cfg, _norm(cfg, h, lp["ln_mlp"]), lp, xa)
-        return (h + scaled(a) + scaled(m), *caches)
-    h = h + scaled(a)
+        return (h + a + joins(m, "ln_mlp_post", "mlp"), *caches)
+    h = h + a
     m = mfn(cfg, _norm(cfg, h, lp["ln_mlp"]), lp, xa)
-    return (h + scaled(m), *caches)
+    return (h + joins(m, "ln_mlp_post", "mlp"), *caches)
 
 
 def cached_block(cfg: TransformerConfig, h, lp, ck, cv, positions, pos,
@@ -1571,7 +1596,8 @@ def forward_cached(cfg: TransformerConfig, params, tokens, cache, pos, pad_bias=
 
 def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
                         block_size: int, dtype=jnp.bfloat16,
-                        state_slots: int = 0) -> Dict[str, Any]:
+                        state_slots: int = 0,
+                        window_blocks: Optional[int] = None) -> Dict[str, Any]:
     """Paged pools, a dict that each kind of layer adds to. Attention
     layers: k/v [kv layers, num_blocks, block_size, kv_heads * Hd] (kv head
     ``g`` of a token at ``[g*Hd, (g+1)*Hd)`` of its row). Block 0 is
@@ -1588,13 +1614,17 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
     earlier layer's update back to the buffer as it came in, and a buffer
     read after it is written cannot be updated in place: the whole pool
     was copied in and out, twice its size a step.) Window layers: ``wk``
-    and ``wv`` [window layers, (state_slots - 1) * R + 1, block_size,
-    kv_heads * Hd], R = ``cfg.ring_blocks(block_size)``: the request in
-    slot ``s`` holds blocks ``1 + (s - 1) R .. s R`` of every window layer
-    as a RING (position ``p`` in its block ``(p // block_size) % R``), and
-    block 0 is their dummy. The ring comes and goes with the slot: no
-    table changes while a request runs, and the window pools cannot run
-    dry (admission reads the full layers' pool alone). Latent-attention
+    and ``wv`` [window layers, ``window_blocks``, block_size, kv_heads *
+    Hd]: a request holds at most R = ``cfg.ring_blocks(block_size)`` of
+    those blocks in every window layer as a RING (position ``p`` in the
+    block at entry ``(p // block_size) % R`` of its TABLE FROM THE HOST,
+    which ``BlockAllocator`` fills as the request grows), and block 0 is
+    their dummy. A request never holds more window blocks than blocks of
+    the full pool, so ``min(num_blocks, rows * R + 1)`` of them cannot run
+    dry (admission reads the full layers' pool alone). ``window_blocks``
+    None: ``(state_slots - 1) * R + 1``, a whole ring a slot, what a
+    caller sizes that addresses rings by slot (``ring_tables``).
+    Latent-attention
     layers: ``c`` [latent layers, num_blocks, block_size,
     cfg.latent_pool_row], ONE row a token (no k/v pair, no head axis; its
     ``cfg.latent_row`` values, then zeros to whole lane tiles), its blocks those of the
@@ -1613,14 +1643,16 @@ def init_paged_kv_cache(cfg: TransformerConfig, num_blocks: int,
     if spec["latent"]:
         pools["c"] = jnp.zeros((spec["latent"], num_blocks, block_size,
                                 cfg.latent_pool_row), dtype)
-    if (spec["state"] or spec["window"]) and state_slots < 2:
-        raise ValueError("a stack with recurrent state or window layers "
-                         "needs state_slots >= 2 (the dummy and one a "
-                         "running request)")
+    if (spec["state"] or spec["window"] and window_blocks is None) \
+            and state_slots < 2:
+        raise ValueError("a stack with recurrent state, or window layers "
+                         "whose pool is sized a ring a slot, needs "
+                         "state_slots >= 2 (the dummy and one a running "
+                         "request)")
     if spec["window"]:
-        wshape = (spec["window"],
-                  (state_slots - 1) * cfg.ring_blocks(block_size) + 1,
-                  block_size, row)
+        if window_blocks is None:
+            window_blocks = (state_slots - 1) * cfg.ring_blocks(block_size) + 1
+        wshape = (spec["window"], window_blocks, block_size, row)
         pools["wk"] = jnp.zeros(wshape, dtype)
         pools["wv"] = jnp.zeros(wshape, dtype)
     if spec["state"]:
@@ -1665,9 +1697,12 @@ def _paged_gather(pool, block_tables, kv_heads: int):
 
 
 def ring_tables(slots, R: int):
-    """The window layers' block tables [B, R] of the requests in ``slots``
-    [B]: slot ``s`` holds blocks ``1 + (s - 1) R .. s R``, slot 0 (an idle
-    row) the dummy block in every entry."""
+    """WHOLE rings addressed by slot, for a caller that hands the paged
+    programs slots and no table from the host (a sizing tool, a kernel's
+    test; the engine's tables are the allocator's): the window layers'
+    block tables [B, R] of the requests in ``slots`` [B], slot ``s``
+    holding blocks ``1 + (s - 1) R .. s R``, slot 0 (an idle row) the dummy
+    block in every entry."""
     slots = jnp.asarray(slots, jnp.int32).reshape(-1, 1)
     own = 1 + (slots - 1) * R + jnp.arange(R, dtype=jnp.int32)[None, :]
     return jnp.where(slots > 0, own, 0)
@@ -1874,9 +1909,10 @@ def _paged_decode_attention(cfg: TransformerConfig, x, lp, positions, pos,
     cache depths, kp/vp [blocks, bs, KV*Hd], block_tables [B, max_blocks]
     (``dummy_block``: what an inactive row's entries name, the layer's first
     block of the stacked pools; the kernel copies nothing for such a row).
-    ``window`` > 0 (a window layer): the tables are the requests' RINGS
-    (logical block ``j`` at entry ``j % width``) and a row reads positions
-    ``pos - window < j <= pos`` only.
+    ``window`` > 0 (a window layer): the tables are the requests' RINGS, as
+    the host handed them (logical block ``j`` at entry ``j % width``; an
+    entry not handed out yet names the dummy block and lies past ``pos``),
+    and a row reads positions ``pos - window < j <= pos`` only.
     Returns (out [B, 1, D], new kp, vp)."""
     B, T, D = x.shape
     H = cfg.n_head
@@ -2008,8 +2044,14 @@ def _paged_chunk_attention(cfg: TransformerConfig, x, lp, positions,
 
 
 def _paged_path_only(cfg: TransformerConfig, what: str):
-    """What only the paged serving path builds: a layer pattern, leading
-    layers, and the embedding, residual and logit multipliers."""
+    """What only the paged serving path builds: a sandwich norm, a layer
+    pattern, leading layers, and the embedding, residual and logit
+    multipliers."""
+    if cfg.norm_position == "sandwich":
+        raise NotImplementedError(
+            f"{what} is not built for norm_position='sandwich' (a norm on "
+            "each branch's way out, ln_attn_post / ln_mlp_post): it runs on "
+            "the paged serving path only")
     if cfg.layer_kinds is not None or cfg.lead_kinds:
         raise NotImplementedError(
             f"{what} is not built for a stack with a layer pattern "
@@ -2025,7 +2067,8 @@ def _paged_path_only(cfg: TransformerConfig, what: str):
 
 def _check_paged_config(cfg: TransformerConfig):
     if cfg.norm_position == "post" or not cfg.causal:
-        raise ValueError("the paged KV path serves pre-LN causal LMs only")
+        raise ValueError("the paged KV path serves pre-LN (or sandwich-"
+                         "normed) causal LMs only")
     if cfg.sparse_attention is not None:
         raise NotImplementedError(
             "sparse_attention is not supported by the paged KV decode path")
@@ -2063,17 +2106,22 @@ def _lin_prefill_fn(cfg: TransformerConfig, pools, state_slot, n_valid, fresh):
 
 
 def _win_prefill_fn(cfg: TransformerConfig, pools, state_slot, positions,
-                    last_idx):
+                    last_idx, window_table=None):
     """The ``win_fn`` of a prefill program (None for a stack without window
-    layers): the request's ring, and the slots of it that the prompt's
-    positions keep."""
+    layers): the request's ring ``window_table`` [R] from the host (an
+    entry not handed out names the dummy block; absent: the whole ring of
+    ``state_slot``), and the slots of it that the prompt's positions
+    keep."""
     if "wk" not in pools:
         return None
-    if state_slot is None:
-        raise ValueError("a stack with window layers needs the request's "
-                         "slot (its ring)")
     bs = pools["wk"].shape[2]
-    table = ring_tables(state_slot, cfg.ring_blocks(bs))[0]
+    if window_table is not None:
+        table = jnp.asarray(window_table, jnp.int32).reshape(-1)
+    elif state_slot is None:
+        raise ValueError("a stack with window layers needs the request's "
+                         "window table (or its slot, for a whole ring)")
+    else:
+        table = ring_tables(state_slot, cfg.ring_blocks(bs))[0]
     slots = _ring_slots(table, positions[0], positions[0, last_idx], bs)
     return lambda xn, lp, kp, vp, block0: _paged_prefill_attention(
         cfg, xn, lp, positions, kp, vp, slots + block0 * bs,
@@ -2081,7 +2129,8 @@ def _win_prefill_fn(cfg: TransformerConfig, pools, state_slot, positions,
 
 
 def forward_paged_prefill(cfg: TransformerConfig, params, tokens, pools,
-                          slots, last_idx, mlp_fn=None, state_slot=None):
+                          slots, last_idx, mlp_fn=None, state_slot=None,
+                          window_table=None):
     """Prefill ONE admitted request into its allocated blocks.
 
     tokens [1, T] right-padded prompt (T the compile bucket); slots [T]
@@ -2089,9 +2138,11 @@ def forward_paged_prefill(cfg: TransformerConfig, params, tokens, pools,
     pads routed to the dummy block); last_idx [] int32 index of the last
     real prompt token. Returns (logits [1, vocab] at last_idx, new pools) —
     junk pad positions are causally invisible to the sampled position.
-    ``state_slot`` [] int32 (a stack with recurrent state or window
-    layers): the request's slot, whose state a whole prefill starts from
-    zero and whose ring it fills with the prompt's last blocks."""
+    ``state_slot`` [] int32 (a stack with recurrent state): the request's
+    slot, whose state a whole prefill starts from zero. ``window_table``
+    [R] int32 (a stack with window layers): the request's blocks of the
+    window pool, which the prefill fills with the prompt's last blocks
+    (absent: the whole ring of ``state_slot``)."""
     _check_paged_config(cfg)
     x, positions = cached_embed(cfg, params, tokens, jnp.int32(0),
                                 pool_geometry(pools)[2])
@@ -2101,7 +2152,8 @@ def forward_paged_prefill(cfg: TransformerConfig, params, tokens, pools,
         lambda xn, lp, kp, vp, block0, slot0: _paged_prefill_attention(
             cfg, xn, lp, positions, kp, vp, slots + slot0),
         mlp_fn, _lin_prefill_fn(cfg, pools, state_slot, last_idx + 1, True),
-        _win_prefill_fn(cfg, pools, state_slot, positions, last_idx),
+        _win_prefill_fn(cfg, pools, state_slot, positions, last_idx,
+                        window_table),
         _lat_fn(pools, lambda la, xn, lp, cp, block0, slot0: la.prefill(
             cfg, xn, lp, positions, cp, slots + slot0)))
     # head on the sampled position only: the [1, vocab] projection, not
@@ -2275,29 +2327,36 @@ def forward_paged_verify(cfg: TransformerConfig, params, tokens, pools,
 
 def forward_paged_decode(cfg: TransformerConfig, params, tokens, pools,
                          block_tables, pos, pad_bias=None, mlp_fn=None,
-                         state_slots=None):
+                         state_slots=None, window_tables=None):
     """One fused decode step over ALL running requests: tokens [B, 1] (each
     request's last sampled token), block_tables [B, max_blocks], pos [B]
     per-request cache depths. Returns (logits [B, vocab], new pools), and
     third what an ``mlp_fn`` that returns ``(out, aux)`` gave, stacked over
     the layers (an MoE model's [L, E + 1] assignment counts).
-    ``state_slots`` [B] int32 (a stack with recurrent state or window
-    layers): each row's slot, 0 (the dummy) for an inactive row."""
+    ``state_slots`` [B] int32 (a stack with recurrent state): each row's
+    slot, 0 (the dummy) for an inactive row. ``window_tables`` [B, R] int32
+    (a stack with window layers): each row's ring of window-pool blocks
+    from the host, logical block ``j`` at entry ``j % R``, the dummy block
+    where none is handed out yet (absent: the whole ring of each row's
+    slot)."""
     _check_paged_config(cfg)
     x, positions = cached_embed(cfg, params, tokens, pos,
                                 pool_geometry(pools)[2])
     if "c" in pools and pad_bias is not None:
         _no_latent_form(pools, "a key-side pad bias")
     lin_fn = win_fn = None
-    if ("state" in pools or "wk" in pools) and state_slots is None:
-        raise ValueError("a stack with recurrent state or window layers "
-                         "needs the rows' slots")
+    if state_slots is None and (
+            "state" in pools or "wk" in pools and window_tables is None):
+        raise ValueError("a stack with recurrent state needs the rows' "
+                         "slots, one with window layers their window "
+                         "tables (or their slots, for whole rings)")
     if "state" in pools:
         lin_fn = lambda mixer, xn, lp, st, cv, row0: mixer.decode(  # noqa: E731
             cfg, xn, lp, st, cv, row0, state_slots)
     if "wk" in pools:
-        rings = ring_tables(state_slots,
-                            cfg.ring_blocks(pools["wk"].shape[2]))
+        rings = jnp.asarray(window_tables, jnp.int32) \
+            if window_tables is not None else ring_tables(
+                state_slots, cfg.ring_blocks(pools["wk"].shape[2]))
         win_fn = lambda xn, lp, kp, vp, block0: _paged_decode_attention(  # noqa: E731
             cfg, xn, lp, positions, pos, kp, vp, rings + block0, None,
             window=cfg.attn_window, dummy_block=block0)

@@ -275,11 +275,14 @@ def sorted_dispatch(tokens: jnp.ndarray, weights: jnp.ndarray,
         ys = grouped_fn(xs, counts)
         with jax.named_scope("moe_dispatch"):
             # rows past the last group belong to no expert: whatever the
-            # grouped matmul left there must not reach a token
-            w = jnp.where(flat[rows] < num_experts,
-                          weights.reshape(-1)[rows].astype(jnp.float32), 0.0)
+            # grouped matmul left there must not reach a token, and a
+            # weight of zero would not stop it (the chip leaves those rows
+            # as it found them: a NaN or an infinity times 0 is a NaN)
+            w = weights.reshape(-1)[rows].astype(jnp.float32)
+            ys = jnp.where((flat[rows] < num_experts)[:, None],
+                           ys.astype(jnp.float32) * w[:, None], 0.0)
             return jnp.zeros((T, tokens.shape[1]), jnp.float32).at[
-                rows // k].add(ys.astype(jnp.float32) * w[:, None])
+                rows // k].add(ys)
 
     if cap and cap < T * k:
         out = jax.lax.cond(jnp.sum(counts) <= cap, lambda: over(cap),

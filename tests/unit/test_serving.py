@@ -161,6 +161,92 @@ class TestScheduler:
 
 
 # --------------------------------------------------------------------- #
+# the window layers' pool: a second free list, handed out by need
+
+class TestWindowPool:
+
+    @pytest.mark.parametrize("num_blocks,rows,R,want", [
+        (1441, 16, 33, 529),        # long rows: a ring a row
+        (1825, 64, 33, 1825),       # mostly short rows: the full pool's size
+        (24, 4, 3, 13), (10, 4, 3, 10)])
+    def test_pool_size_is_the_lesser_of_a_ring_a_row_and_the_full_pool(
+            self, num_blocks, rows, R, want):
+        assert BlockAllocator.window_pool_blocks(num_blocks, rows, R) == want
+
+    def test_a_request_takes_a_window_block_with_each_block_below_a_ring(self):
+        a = BlockAllocator(12, 8, window_blocks=7, ring_blocks=3)
+        held, full = [], a.allocate(2)
+        a.grow_window(held, len(full))
+        assert held == [1, 2] and a.window_used == 2
+        full += a.allocate(2)
+        a.grow_window(held, len(full))          # 4 blocks, a ring of 3
+        assert held == [1, 2, 3] and a.window_used == 3
+        a.grow_window(held, len(full) + 1)      # a whole ring: nothing more
+        assert held == [1, 2, 3]
+        assert a.leak_report() == {1: 1, 2: 1, 3: 1, 4: 1, "window": 3}
+        a.free(full)
+        a.free_window(held)
+        assert held == [] and a.window_used == 0 and not a.leak_report()
+        with pytest.raises(ValueError, match="not held"):
+            a.free_window([2])
+
+    def test_a_window_block_without_a_full_block_is_refused(self):
+        a = BlockAllocator(12, 8, window_blocks=7, ring_blocks=3)
+        with pytest.raises(AssertionError, match="without a block"):
+            a.grow_window([], 2)                # nothing of the full pool held
+        with pytest.raises(ValueError, match="ring_blocks"):
+            BlockAllocator(12, 8, window_blocks=7)
+        with pytest.raises(ValueError, match="prefix cache"):
+            BlockAllocator(12, 8, prefix_cache=True, window_blocks=7,
+                           ring_blocks=3)
+        # a model without a window: the calls are there and do nothing
+        plain, held = BlockAllocator(4, 8), []
+        plain.grow_window(held, 3)
+        plain.free_window(held)
+        assert held == [] and plain.window_used == 0
+
+    @pytest.mark.parametrize("seed", [0, 1, 2, 3])
+    def test_window_held_never_passes_full_held_on_a_random_schedule(self, seed):
+        """Requests of random lengths through a pool too small for them all
+        (so some are preempted and prefilled again): after every action a
+        request holds min(blocks, ring) window blocks, the pool of
+        ``window_pool_blocks`` never runs dry, and everything is back at the
+        end."""
+        rng = np.random.default_rng(seed)
+        bs, R, rows, nb = 4, 3, 3, 14
+        alloc = BlockAllocator(
+            nb, bs, window_blocks=BlockAllocator.window_pool_blocks(nb, rows, R),
+            ring_blocks=R)
+        assert alloc.window_blocks == 10
+        s = ContinuousBatchingScheduler(alloc, rows, 12)
+        reqs = [s.add_request([1] * int(rng.integers(1, 24)),
+                              max_new=int(rng.integers(2, 20)))
+                for _ in range(12)]
+        steps = 0
+        while (action := s.next_action()) is not None:
+            kind, what = action
+            if kind == "prefill":
+                s.record_prefill(what, 5)
+            else:
+                for r in list(what):
+                    s.record_decode(r, 5)
+            for r in s.running:
+                assert len(r.window_blocks) == min(len(r.blocks), R)
+                assert len(set(r.window_blocks)) == len(r.window_blocks)
+            assert alloc.window_used == sum(len(r.window_blocks)
+                                            for r in s.running)
+            assert alloc.window_used <= alloc.num_used
+            for r in reqs:
+                if r.state != "running":
+                    assert not r.window_blocks and not r.blocks
+            steps += 1
+            assert steps < 5000
+        assert all(r.state == FINISHED for r in reqs)
+        assert sum(r.preemptions for r in reqs) > 0
+        assert alloc.window_used == 0 and not alloc.leak_report()
+
+
+# --------------------------------------------------------------------- #
 # engine generate_batch
 
 class TestGenerateBatch:

@@ -1,7 +1,8 @@
 """SmallThinker's stack on the normal path: full attention layers without
 positions and rope layers with a WINDOW in one stack, their KV kept by kind
-(the shared pool for the full layers, a ring of blocks a running request
-for the window layers), a router that reads the attention's input, ReLU
+(the shared pool for the full layers, for the window layers a ring of
+blocks of a second pool, handed out as a request grows and named by a table
+from the host), a router that reads the attention's input, ReLU
 gated experts. The ``smallthinker`` ``tiny`` preset (window 256) with seeded
 weights, float32 on the CPU, against the plain reference
 (``perfbench/reference/smallthinker_decoder.py``): prefill, then decode
@@ -291,11 +292,19 @@ def test_a_cancel_gives_the_ring_back(toy):
     hs = [serving.add_request(p) for p in prompts]
     for _ in range(4):
         assert serving.step()
-    alloc = serving._session.sched.allocator
-    assert alloc.slots_held == 2
+    sched = serving._session.sched
+    alloc = sched.allocator
+    # two rows run: the 280-token one holds a whole ring of the window
+    # pool, the 40-token one a block for each of its blocks of the full pool
+    R = W // 16 + 1
+    held = sorted(len(r.window_blocks) for r in sched.running)
+    assert held == [3, R] and alloc.window_used == 3 + R
+    assert all(len(r.window_blocks) == min(len(r.blocks), R)
+               for r in sched.running)
+    assert alloc.slots_held == 0          # a window takes no state slot
     hs[0].cancel()
     drive(serving)
-    assert alloc.slots_held == 0 and not alloc.leak_report()
+    assert alloc.window_used == 0 and not alloc.leak_report()
     serving.shutdown(drain=True)
     for h, w in zip(hs[1:], want[1:]):
         np.testing.assert_array_equal(np.asarray(h.result(1)), w)
@@ -318,7 +327,13 @@ def test_auto_resolves_to_no_prefix_cache_and_no_verify_program(toy):
     session = engine.open_serve_session(max_new=2)
     try:
         assert not session.sched.prefix_caching
-        assert session.sched.allocator.state_slots == 4
+        alloc = session.sched.allocator
+        # no slot for a window; its pool is a ring a row or a block a block
+        # of the full pool, whichever is less
+        assert alloc.state_slots == 0 and alloc.ring_blocks == W // 16 + 1
+        assert alloc.window_blocks == min(alloc.num_blocks,
+                                          3 * (W // 16 + 1) + 1)
+        assert session.pools["wk"].shape[1] == alloc.window_blocks
         with pytest.raises(NotImplementedError, match="handoff"):
             session.demote_prompt(prompts_of((40,))[0])
     finally:
