@@ -37,8 +37,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.transformer import (TransformerConfig, _rope,
-                                              _use_flash, _w)
+from deepspeed_tpu.models.transformer import (TransformerConfig, _flat,
+                                              _rope, _use_flash, _w)
 from deepspeed_tpu.ops import dispatch
 
 #: the eps of the two bottleneck norms (the published ``q_a_layernorm`` /
@@ -95,7 +95,7 @@ def project(cfg: TransformerConfig, x, lp, positions):
     rope = lambda a: _rope(a, positions, cfg.rope_theta, 0,  # noqa: E731
                            cfg.rope_interleaved)
     cq = _rms(x @ _w(lp["wq_a"], x), lp["q_norm"], LORA_EPS)
-    q = (cq @ _w(lp["wq_b"], x)).reshape(B, T, H, dn + dr)
+    q = _flat(cq @ _w(lp["wq_b"], x)).reshape(B, T, H, dn + dr)
     kv = x @ _w(lp["wkv_a"], x)
     ckv = _rms(kv[..., :R], lp["kv_norm"], LORA_EPS)
     if cfg.mla_lora_scale:

@@ -1344,6 +1344,22 @@ def _attn_out(cfg: TransformerConfig, out, x, lp):
     return out @ _w(lp["wo"], out) + (lp["bo"] if cfg.attn_bias else 0)
 
 
+def _flat(y):
+    """A projection's flat output [rows, H*Hd], held as it is ahead of the
+    split into heads. Without it XLA:TPU sinks the ``reshape(.., H, Hd)``
+    into the product, batches it over heads and wants the weight as
+    [H, Hd, D]: the layer's matrix is then sliced out of its [L, ...] stack
+    and transposed, a copy of the whole weight twice a layer a step, to
+    multiply a few dozen rows by it. With it the product is the plain
+    [rows, D] x [D, H*Hd] its siblings (``wv``, ``wo``) are, reads the
+    stack where it lies, and what is relaid for the kernel is the small
+    output (PERF.md section 6, PR 57). The identity: same product, same
+    precision. For the projections whose output is split right after the
+    product (or inside a per-head norm): not the training ``attention()``,
+    whose thousands of rows make the compiler's layout the better trade."""
+    return jax.lax.optimization_barrier(y)
+
+
 def _qkv_project(cfg: TransformerConfig, x, lp, positions,
                  kind: str = ATTENTION):
     """Shared decode-side q/k/v projection: act-quant (QAT parity with the
@@ -1358,7 +1374,11 @@ def _qkv_project(cfg: TransformerConfig, x, lp, positions,
     bq = lp["bq"] if cfg.attn_bias else 0
     bk = lp["bk"] if cfg.attn_bias else 0
     bv = lp["bv"] if cfg.attn_bias else 0
-    q, k = _qk_norm(cfg, x @ _w(lp["wq"], x) + bq, x @ _w(lp["wk"], x) + bk, lp)
+    # a norm over the whole vector reads the flat output itself, and the
+    # compiler leaves that product plain unasked
+    held = (lambda y: y) if cfg.qk_norm is True else _flat
+    q, k = _qk_norm(cfg, held(x @ _w(lp["wq"], x) + bq),
+                    held(x @ _w(lp["wk"], x) + bk), lp)
     q = q.reshape(B, T, H, Hd)
     k = k.reshape(B, T, KV, Hd)
     v = (x @ _w(lp["wv"], x) + bv).reshape(B, T, KV, Hd)

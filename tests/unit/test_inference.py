@@ -121,6 +121,16 @@ def test_client_optax_optimizer_descends():
     assert losses[-1] < losses[0] * 0.5, f"{losses[0]} -> {losses[-1]}"
 
 
+def _drawn_biases(params):
+    """``params`` with its attention biases (zeros as initialised), where
+    it has them, drawn: a bias that is dropped or added twice shows."""
+    attn = params["layers"]["attn"]
+    for i, b in enumerate(n for n in ("bq", "bk", "bv", "bo") if n in attn):
+        attn[b] = 0.5 * jax.random.normal(jax.random.key(10 + i),
+                                          attn[b].shape)
+    return params
+
+
 class TestKVCacheDecode:
     """KV-cache decode path (reference: inference_context.h:49 workspace,
     softmax_context KV append pt_binding.cpp:1668-1793)."""
@@ -132,7 +142,7 @@ class TestKVCacheDecode:
         return CausalLM(TransformerConfig(**base))
 
     @pytest.mark.parametrize("style", [
-        "gpt2", "gqa",
+        "gpt2", "gqa", "opt",
         pytest.param("llama", marks=pytest.mark.nightly),
         pytest.param("alibi", marks=pytest.mark.nightly),
         pytest.param("gptj", marks=pytest.mark.nightly),
@@ -140,6 +150,8 @@ class TestKVCacheDecode:
     def test_decode_logits_match_full_forward(self, style):
         over = {
             "gpt2": {},
+            # OPT's projections: a bias on q, k, v and o (drawn below), ReLU
+            "opt": dict(activation="relu", attn_bias=True),
             "llama": dict(pos_embedding="rope", norm="rmsnorm", activation="swiglu",
                           tie_embeddings=False),
             "alibi": dict(pos_embedding="alibi"),
@@ -153,7 +165,7 @@ class TestKVCacheDecode:
                                  parallel_residual=True, attn_bias=True),
         }[style]
         model = self._model(**over)
-        params = model.init_params(jax.random.key(0))
+        params = _drawn_biases(model.init_params(jax.random.key(0)))
         toks = jax.random.randint(jax.random.key(1), (2, 10), 0, 64)
 
         full = model.forward(params, toks).astype(jnp.float32)
@@ -256,6 +268,84 @@ class TestKVCacheDecode:
         out = engine.generate(prompt, max_new_tokens=5, temperature=0.8, top_k=10, seed=3)
         assert out.shape == (2, 8)
         assert int(out.min()) >= 0 and int(out.max()) < 64
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk", "verify"])
+def test_biased_projections_give_the_full_forwards_logits(program):
+    """OPT's form of the q/k/v projections (a bias on each, drawn and not
+    zero, learned positions, ReLU) through ``_qkv_project``: a paged prefill
+    followed by decode steps, prefill chunks or a verify window gives the
+    logits of the training ``forward()``, whose ``attention()`` does not
+    share that function (the workspace path: ``TestKVCacheDecode``'s
+    ``opt`` style). The other three forms have theirs: a norm of each head
+    in ``test_sdar.py::test_pass_logits_are_the_references`` and
+    ``test_lfm2_moe.py::test_logits_of_a_whole_prompt_and_four_decode_steps``,
+    a gated output in ``test_trinity.py::test_prefill_then_decode_is_the_reference``,
+    the latent ``wq_b`` in ``test_longcat_flash.py::
+    test_prefill_then_absorbed_decode_gives_the_references_logits``."""
+    model = CausalLM(TransformerConfig(
+        vocab_size=64, n_layer=2, n_head=4, d_model=32, d_ff=64, max_seq=32,
+        activation="relu", attn_bias=True, remat=False))
+    params = _drawn_biases(model.init_params(jax.random.key(0)))
+    S, n0, bs, nb = 14, 6, 8, 4
+    toks = np.asarray(jax.random.randint(jax.random.key(1), (1, S), 0, 64))
+    full = np.asarray(model.forward(params, toks).astype(jnp.float32))[0]
+
+    def close(got, want, what):
+        np.testing.assert_allclose(np.asarray(got), want, rtol=2e-4,
+                                   atol=2e-4, err_msg=what)
+
+    # the request holds blocks 1..4 (0 is the dummy) and row 1 of 3
+    pools = model.init_paged_cache(nb + 1, bs, dtype=jnp.float32)
+    table = np.arange(1, nb + 1, dtype=np.int32)
+    slot = lambda t: (table[t // bs] * bs + t % bs).astype(np.int32)  # noqa: E731
+    t = np.arange(bs, dtype=np.int32)
+    first = np.zeros((1, bs), np.int32)
+    first[0, :n0] = toks[0, :n0]
+    lg, pools = model.forward_paged_prefill(
+        params, first, pools, np.where(t < n0, slot(t), t), np.int32(n0 - 1))
+    close(lg[0], full[n0 - 1], "prefill")
+    bt = np.zeros((3, nb), np.int32)
+    bt[1] = table
+    pos = np.zeros((3,), np.int32)
+    if program == "decode":
+        for i in range(n0, S):
+            pos[1] = i
+            nt = np.zeros((3, 1), np.int32)
+            nt[1, 0] = toks[0, i]
+            lg, pools = model.forward_paged_decode(params, nt, pools, bt, pos)
+            close(lg[1], full[i], f"step {i}")
+    elif program == "prefill_chunk":
+        for start in (n0, n0 + 4):
+            lg, pools = model.forward_paged_prefill_chunk(
+                params, toks[:, start:start + 4], pools, table[None],
+                slot(start + np.arange(4)), np.int32(start), np.int32(3))
+            close(lg[0], full[start + 3], f"chunk at {start}")
+    else:
+        W = 4
+        pos[1] = n0
+        win = np.zeros((3, W), np.int32)
+        win[1] = toks[0, n0:n0 + W]
+        slots = np.tile(np.arange(W, dtype=np.int32), (3, 1))   # the dummy's
+        slots[1] = slot(n0 + np.arange(W))
+        lg, pools = model.forward_paged_verify(params, win, pools, bt, slots,
+                                               pos)
+        close(lg[1], full[n0:n0 + W], "window")
+
+
+def test_the_training_forward_holds_no_projection_flat():
+    """``_flat`` is the serving projections' (``_qkv_project``: a few dozen
+    rows against a whole matrix); the training ``attention()`` multiplies
+    thousands of rows, where the compiler's own layout is the better trade,
+    and traces to what it did (PERF.md section 6, PR 57)."""
+    model = tiny_model()
+    params = jax.eval_shape(model.init_params, jax.random.key(0))
+    toks = jax.ShapeDtypeStruct((2, 8), jnp.int32)
+    assert "optimization_barrier" not in str(
+        jax.make_jaxpr(model.forward)(params, toks))
+    cache = jax.eval_shape(lambda: model.init_cache(2, 16, dtype=jnp.float32))
+    assert "optimization_barrier" in str(jax.make_jaxpr(model.forward_cached)(
+        params, toks, cache, jnp.int32(0)))
 
 
 def test_generate_rejects_encoder_modules():

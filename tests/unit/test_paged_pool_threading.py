@@ -13,6 +13,10 @@ their temporaries under a quarter of the pool and hold no ``copy``,
 ``dynamic-slice`` or ``dynamic-update-slice`` of a layer's pool size. With
 the pool stored ``[.., KV, Hd=64]`` and scanned as inputs/outputs (before PR
 24) the same programs had six such copies and two pool-sized temporaries.
+
+(c) The same compile at the cells' published widths: no decode program
+yields a buffer of an attention projection's shape, i.e. the products read
+their weights where they lie in the layers' stack (PR 57).
 """
 
 import os
@@ -171,6 +175,106 @@ def test_no_pool_sized_copy_compiled_for_v5e(name, one_v5e, monkeypatch):
         if int(np.prod([int(d) for d in m.group(1).split(",")])) >= layer_pool:
             moved.append(m.group(0))
     assert not moved, moved
+
+
+def _weight_sized_moves(text, shapes):
+    """The instructions of a compiled program's text that YIELD a buffer of
+    one of ``shapes`` (each a matrix's dims; a leading 1, the scan's slice,
+    and either order of the two count as the same buffer): copies,
+    transposes, dynamic slices and fusions outside any fused computation. A
+    product that reads its weight where it lies has the slice INSIDE its
+    fusion and yields [rows, width], so it is not one of them."""
+    want = {tuple(sorted(s)) for s in shapes}
+    moved, fused = [], False
+    for line in text.splitlines():
+        if line and not line[0].isspace():
+            fused = line.startswith("%fused_computation")
+        m = re.match(r"\s+(?:ROOT )?(%\S+) = \w+\[([\d,]+)\]\S* "
+                     r"(copy|transpose|dynamic-slice|fusion)\(", line)
+        if not m or fused:
+            continue
+        dims = tuple(sorted(int(d) for d in m.group(2).split(",")
+                            if d != "1"))
+        if dims in want:
+            moved.append(f"{m.group(1)} {m.group(3)} [{m.group(2)}]")
+    return moved
+
+
+@pytest.mark.parametrize("family,size,rows", [
+    # attn_bias, head size 64: opt1b3_serve_decode / _mixed
+    pytest.param("opt", "1.3b", 40, id="opt"),
+    # RMSNorm of each head of q and k; sdar30b_serve_blockgen's fused pass too
+    pytest.param("sdar", "30b-a3b-ep8", 64, id="sdar"),
+    # window and full layers: smallthinker21b_serve_longctx
+    pytest.param("smallthinker", "21b-a3b-12l", 16, id="smallthinker"),
+    # one period unrolled, gated outputs: trinitylarge_serve_shortlong
+    pytest.param("trinity", "large-preview-5l-ep8", 64, id="trinity"),
+    # latent attention: longcatflashomni_serve_ctx3k
+    pytest.param("longcat_flash", "omni-4l-ep32", 64, id="longcat"),
+])
+def test_no_stacked_projection_weight_is_copied_for_v5e(
+        family, size, rows, one_v5e, monkeypatch):
+    """A decode step multiplies a few dozen rows by each attention matrix of
+    a layer: the product reads the matrix where it lies in the [L, ...]
+    stack. Compiled for a described v5e at the cells' published widths and
+    rows, no program yields a buffer of an attention projection's shape.
+    Before PR 57 each of these cases failed: a query (or per-head-normed
+    key) split into heads right after its product was computed as a product
+    batched over heads, for which the layer's ``wq`` (``wk``; LongCat's
+    ``wq_b``) was sliced out of the stack and transposed, the whole matrix
+    twice a layer a step (``models/transformer.py`` ``_flat``). What is left
+    and allowed: LongCat's ``wkv_b`` [512, 16384], sliced and transposed for
+    the absorbed form's ``bhd,rhd->bhr`` (ROADMAP S11)."""
+    import deepspeed_tpu.comm as dist
+    from deepspeed_tpu.inference import blockgen
+    from deepspeed_tpu.inference.block_allocator import BlockAllocator
+    from deepspeed_tpu.models.presets import get_model
+    from deepspeed_tpu.ops import dispatch
+    dist.set_mesh(None)
+    monkeypatch.setattr(dispatch, "on_tpu", lambda: True)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_v5e)
+
+    model = get_model(family, size)
+    cfg, num_blocks = model.config, 8 * rows + 1
+    shapes = jax.eval_shape(model.init_params, jax.random.key(0))
+    params = jax.tree.map(lambda a: sds(a.shape, jnp.bfloat16), shapes)
+    weights = {path[-1].key: leaf.shape[1:]
+               for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]
+               if "['attn']" in jax.tree_util.keystr(path) and leaf.ndim == 3}
+    assert {"wq", "wk"} <= set(weights) or "wq_b" in weights
+    weights.pop("wkv_b", None)                         # S11's, see above
+    # what a row keeps beside its KV blocks: a window layer's ring of blocks
+    pool_kw, kept = {}, {}
+    if cfg.cache_spec["window"]:
+        ring = cfg.ring_blocks(BS)
+        pool_kw["window_blocks"] = BlockAllocator.window_pool_blocks(
+            num_blocks, rows, ring)
+        kept["window_tables"] = sds((rows, ring), I32)
+    pools = jax.tree.map(lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+        lambda: model.init_paged_cache(num_blocks, BS, dtype=jnp.bfloat16,
+                                       **pool_kw)))
+    n_max = -(-cfg.max_seq // BS)
+    for name in ("decode", "block") if cfg.generation else ("decode",):
+        if name == "decode":
+            compiled = jax.jit(
+                lambda p, po, t, bt, pos, **kw: model.forward_paged_decode(
+                    p, t, po, bt, pos, **kw), donate_argnums=(1,)).lower(
+                params, pools, sds((rows, 1), I32), sds((rows, n_max), I32),
+                sds((rows,), I32), **kept).compile()
+        else:
+            # the fused pass of generation by blocks: main and rider entries
+            gen = cfg.generation
+            entries = rows + blockgen.ride_slots(gen, rows)
+            compiled = jax.jit(
+                lambda p, po, t, bt, pos: model.forward_paged_block(
+                    p, t, po, bt, pos, n_logits=rows),
+                donate_argnums=(1,)).lower(
+                params, pools, sds((entries, gen.block), I32),
+                sds((entries, n_max), I32), sds((entries,), I32)).compile()
+        moved = _weight_sized_moves(compiled.as_text(), weights.values())
+        assert not moved, (name, moved, weights)
 
 
 @pytest.mark.parametrize("B,Q,H,KV,Hd,width,terms,form", [
