@@ -60,6 +60,14 @@ class ServeRunner:
 
     # ------------------------------------------------------------------ #
 
+    def weights_seed(self):
+        """``--seed``, unless the mix names a ``weights_seed``: where a chip
+        holds a share of a router's experts, how many of them a step touches
+        (and so its time) follows the seeded router's skew, and the mix gives
+        every ``--seed`` the same weights (``traffic.py``). Prompts, and the
+        check's, are the seed's in either case."""
+        return int(self.spec.get("weights_seed", self.seed))
+
     def setup(self):
         import jax
         import jax.numpy as jnp
@@ -81,10 +89,11 @@ class ServeRunner:
         if self.traffic.longest_request() > mcfg.max_seq:
             raise ValueError("the mix's longest request exceeds max_seq")
         t0 = time.perf_counter()
-        params = make_params(self.model, self.seed, jnp.bfloat16,
+        params = make_params(self.model, self.weights_seed(), jnp.bfloat16,
                              jax.devices()[:1])
         jax.block_until_ready(params)
-        self.say(f"weights: {time.perf_counter() - t0:.1f}s, bf16, seeded")
+        self.say(f"weights: {time.perf_counter() - t0:.1f}s, bf16, seeded "
+                 f"({self.weights_seed()})")
         self.engine = deepspeed_tpu.init_inference(
             self.model, params=params, dtype="bf16",
             telemetry={"enabled": True},

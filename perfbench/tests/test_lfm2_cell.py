@@ -161,7 +161,8 @@ def test_the_manifest_enters_the_cell_and_only_adds():
     assert conf["file"] == f"perfbench/configs/{CONFIG}.json"
     per = {p["name"]: p for p in man["per_layer"]}
     for n in NEW:
-        assert per["decode." + n]["workloads"] == [CELL]
+        # a later cell of these layer kinds may enter itself beside this one
+        assert CELL in per["decode." + n]["workloads"]
         assert per["decode." + n]["moves"] == "serve_out_tokens_per_s"
         assert per["decode." + n]["source"] == "device_trace"
     assert per["decode.moe_layers_expert_matmul_roofline"]["layer"] == "kernels"
@@ -181,7 +182,7 @@ def test_the_manifest_enters_the_cell_and_only_adds():
                          "decode.mamba2_time_share",
                          "decode.latent_decode_roofline"}
     e2e = {e["name"]: e for e in man["end_to_end"]}
-    assert e2e["serve_out_tokens_per_s"]["workloads"][-1] == CELL
+    assert CELL in e2e["serve_out_tokens_per_s"]["workloads"]
     assert e2e["serve_out_tokens_per_s"]["bound"] == 0.03
     sys.path.insert(0, BENCH)
     import run
@@ -246,8 +247,8 @@ def test_the_cell_rehearses_correct_with_its_counters():
     last = json.loads(lines[-1])
     assert last["rehearsal"] and last["platform"] == "cpu" and last["correct"]
     assert last["attempted"] >= 20 and last["failed"] == 0
-    for name in ("decode.live_kv_blocks_per_step", "decode.batch_occupancy",
-                 "decode.preemptions", "decode.ahead_step_share",
+    for name in ("decode.live_kv_tokens_per_step", "decode.batch_occupancy",
+                 "decode.preemptions", "decode.late_time_share",
                  "decode.experts_touched_per_layer_step",
                  "decode.moe_dropped_assignments", "decode.expert_load_imbalance",
                  "decode.compiles_in_window", "compile_cache_misses"):

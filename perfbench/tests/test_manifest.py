@@ -11,6 +11,12 @@ import pytest
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+#: metric files that no entry names and that stay all the same: tier-1's
+#: ``tests/unit/test_serving_ahead.py`` opens this one, and the PR that
+#: retired its two entries (PR 59, a ``benchmark`` PR) may not edit a file
+#: under ``tests/``. The next PR that may drops that reading; the next
+#: ``benchmark`` PR after it deletes the file and this exception.
+HELD_FOR_TIER1 = {"ahead_step_share.json"}
 
 
 @pytest.fixture(scope="module")
@@ -36,7 +42,9 @@ def test_every_name_leads_to_a_file(manifest):
         with open(os.path.join(BENCH, "layer_metrics", base + ".json")) as f:
             spec = json.load(f)
         assert hasattr(importlib.import_module("readers." + spec["reader"]), "read")
-    assert used == set(os.listdir(os.path.join(BENCH, "layer_metrics")))
+    assert not used & HELD_FOR_TIER1
+    assert used | HELD_FOR_TIER1 \
+        == set(os.listdir(os.path.join(BENCH, "layer_metrics")))
 
 
 def test_names_units_and_bounds(manifest):

@@ -113,13 +113,14 @@ def test_the_manifest_enters_them_for_this_cell_alone():
             if m["name"].rpartition(".")[2] in NEW}
     assert len(mine) == 3
     for m in mine.values():
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_out_tokens_per_s"
+        # a later cell with a window may enter itself beside this one
+        assert CELL in m["workloads"] and m["moves"] == "serve_out_tokens_per_s"
     # the 3 s of trace often hold no prefill (the traffic's `why`), and a
     # traced run has to report every metric the cell is listed under: what
     # reads a prefill program is no entry of this cell
-    names = {m["name"] for m in manifest["per_layer"]}
-    assert "decode.window_flash_roofline" not in names
-    assert "decode.prefill_ms_per_ktoken" not in names
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
+    assert "decode.window_flash_roofline" not in by_name
+    assert CELL not in by_name["decode.prefill_ms_per_ktoken"]["workloads"]
     # the cell stays out of what takes every KV layer to read every token,
     # and of what another stack's parts count
     listed = {m["name"] for m in manifest["per_layer"]
@@ -129,7 +130,7 @@ def test_the_manifest_enters_them_for_this_cell_alone():
                  "decode.held_expert_load_imbalance", "decode.tokens_per_row_pass"):
         assert name not in listed
     for name in ("decode.expert_load_imbalance", "decode.expert_matmul_roofline",
-                 "decode.live_kv_blocks_per_step", "decode.batch_occupancy",
+                 "decode.live_kv_tokens_per_step", "decode.batch_occupancy",
                  "decode.paged_decode_time_share", "compile_cache_misses"):
         assert name in listed
     cell = next(w for w in manifest["workloads"] if w["name"] == CELL)
@@ -137,7 +138,7 @@ def test_the_manifest_enters_them_for_this_cell_alone():
     assert cell["config"] == CONFIG and len(cell["why"]) <= 200
     e2e = next(m for m in manifest["end_to_end"]
                if m["name"] == "serve_out_tokens_per_s")
-    assert e2e["workloads"][-1] == CELL and e2e["bound"] == 0.03
+    assert CELL in e2e["workloads"] and e2e["bound"] == 0.03
 
 
 def test_the_configuration_is_the_published_one_cut_in_depth_alone():
@@ -191,9 +192,9 @@ def test_the_cell_rehearses_correct_with_its_new_metrics():
     assert last["rehearsal"] and last["platform"] == "cpu" and last["correct"]
     assert last["attempted"] > 0 and last["failed"] == 0
     for name in ("decode.live_window_kv_blocks_per_step",
-                 "decode.live_kv_blocks_per_step", "decode.batch_occupancy",
+                 "decode.live_kv_tokens_per_step", "decode.batch_occupancy",
                  "decode.expert_load_imbalance", "decode.preemptions",
-                 "decode.moe_dropped_assignments", "decode.ahead_step_share"):
+                 "decode.moe_dropped_assignments", "decode.late_time_share"):
         assert name in last["per_layer_names"], name
     assert "decode.paged_decode_roofline" not in last["per_layer_names"]
     per_layer = json.loads(next(
@@ -204,8 +205,7 @@ def test_the_cell_rehearses_correct_with_its_new_metrics():
     # 4 rows, a window of two blocks: at most 3 ring blocks a row, and the
     # rows (307-576 tokens: 3-5 blocks of a full layer) are past it
     assert 4.0 <= per_layer["decode.live_window_kv_blocks_per_step"]["value"] <= 12.0
-    assert per_layer["decode.live_kv_blocks_per_step"]["value"] \
-        > per_layer["decode.live_window_kv_blocks_per_step"]["value"]
+    assert per_layer["decode.live_kv_tokens_per_step"]["value"] > 4 * 256
     # both warmed buckets are those `_bucket` names for the toy's prompts
     assert sum("warm-up: prompt bucket" in ln for ln in lines) == 2
 

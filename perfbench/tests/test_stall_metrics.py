@@ -1,7 +1,10 @@
-"""The metrics that name what stalls the serving loop (PR 35): the reader of
-a counter's rate on hand-made facts, the eight entries the manifest gained
-against their files, and a ``gc`` span of another thread taking the idle
-stretch from the phase of the loop it fell into."""
+"""The metrics that name what stalls the serving loop (PR 35; PR 59 retired
+``late_steps``, ``gc_full_collections`` and ``gap_gc_share``, which
+``late_time_share``, ``gc_pause_share`` and the ledger's
+``breakdown.idle_gaps`` repeat): the reader of a counter's rate on hand-made
+facts, the entry that stays against its file, found by name, and a ``gc``
+span of another thread taking the idle stretch from the phase of the loop it
+fell into."""
 
 import json
 import os
@@ -9,16 +12,11 @@ import os
 import pytest
 
 import trace_reduce as tr
-from readers import counter_delta, counter_rate, trace_gap_by_annotation
+from readers import counter_rate, trace_gap_by_annotation
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
-DECODE = ["opt1b3_serve_decode", "olmoe1b7b_serve_decode",
-          "solaropen2_serve_decode", "sdar30b_serve_blockgen"]
-NEW = {"late_steps": ("count", "program_counter"),
-       "gc_pause_share": ("%", "program_counter"),
-       "gc_full_collections": ("count", "program_counter"),
-       "gap_gc_share": ("%", "device_trace")}
+RETIRED = ("late_steps", "gc_full_collections", "gap_gc_share")
 
 
 def _spec(name):
@@ -49,35 +47,23 @@ def test_counter_rate_arithmetic():
         == pytest.approx(2.0)
 
 
-@pytest.mark.parametrize("name", ["late_steps", "gc_full_collections"])
-def test_the_counted_metrics_read_growth_or_nothing(name):
-    spec = _spec(name)
-    assert spec["reader"] == "counter_delta"
-    counter = spec["params"]["counter"]
-    assert spec["params"]["require"] == [counter]
-    facts = _facts({counter: 3.0}, {counter: 11.0})
-    assert counter_delta.read(spec["params"], facts) == 8.0
-    assert counter_delta.read(spec["params"], _facts({}, {"x": 1.0})) is None
-
-
-@pytest.mark.parametrize("group,moves,cells", [
-    ("decode", "serve_out_tokens_per_s", DECODE),
-    ("mixed", "itl_p90_ms", ["opt1b3_serve_mixed"])])
-@pytest.mark.parametrize("name", sorted(NEW))
-def test_the_manifests_new_entries(name, group, moves, cells):
+@pytest.mark.parametrize("group,moves", [
+    ("decode", "serve_out_tokens_per_s"), ("mixed", "itl_p90_ms")])
+def test_the_manifests_entry_by_name(group, moves):
+    """By name, wherever it stands in the list: a later cell enters itself
+    on its ``workloads`` and a later entry follows it."""
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         manifest = json.load(f)
-    unit, source = NEW[name]
     (entry,) = [m for m in manifest["per_layer"]
-                if m["name"] == f"{group}.{name}"]
-    assert entry == {"name": f"{group}.{name}", "unit": unit,
-                     "better": "lower", "source": source,
+                if m["name"] == f"{group}.gc_pause_share"]
+    (e2e,) = [m for m in manifest["end_to_end"] if m["name"] == moves]
+    assert entry == {"name": f"{group}.gc_pause_share", "unit": "%",
+                     "better": "lower", "source": "program_counter",
                      "layer": "serving engine", "moves": moves,
-                     "workloads": cells}
-    # at the end of the list: an entry put in the middle reads as a change
-    tail = [m["name"].rpartition(".")[2] for m in manifest["per_layer"][-8:]]
-    assert sorted(set(tail)) == sorted(NEW)
-    assert _spec(name)["what"]
+                     "workloads": e2e["workloads"]}
+    assert _spec("gc_pause_share")["what"]
+    names = {m["name"] for m in manifest["per_layer"]}
+    assert not names & {f"{group}.{gone}" for gone in RETIRED}
 
 
 def test_a_gc_span_of_another_thread_takes_the_gap():
@@ -103,5 +89,4 @@ def test_a_gc_span_of_another_thread_takes_the_gap():
     assert sum(by.values()) == pytest.approx(100 * (1 - busy / span))
     # a program from before PR 35 emits no gc span: 0, and no raise
     facts["trace"]["host"] = host[:3]
-    assert trace_gap_by_annotation.read(_spec("gap_gc_share")["params"],
-                                        facts) == 0.0
+    assert trace_gap_by_annotation.read({"annotation": "gc"}, facts) == 0.0

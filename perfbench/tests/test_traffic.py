@@ -1,6 +1,9 @@
 """The generator: the same seed gives the same inputs, lengths stay inside
 their bounds, and the open loop's schedule is absolute."""
 
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -86,6 +89,106 @@ def test_plan_seed_fixes_times_and_lengths_and_leaves_tokens_to_the_seed():
     c, d = (traffic.ServeTraffic(spec, 50272, s).open_plan(3.0, 51.0)
             for s in (1, 2))
     assert shape(c) == shape(d) == shape(own)
+
+
+def closed_mixes_of_the_cells():
+    """Every ``"loop": "closed"`` file that a cell of BENCHMARK.json names."""
+    with open(os.path.join(os.path.dirname(traffic.HERE), "BENCHMARK.json")) as f:
+        names = sorted({w["traffic"] for w in json.load(f)["workloads"]})
+    return [n for n in names if traffic.load(n).get("loop") == "closed"]
+
+
+PLANNED = closed_mixes_of_the_cells()
+
+
+@pytest.mark.parametrize("name", PLANNED)
+def test_every_closed_loop_a_cell_names_carries_a_plan(name):
+    assert isinstance(traffic.load(name)["plan_seed"], int)
+
+
+@pytest.mark.parametrize("name", PLANNED)
+def test_a_closed_loops_plan_seed_fixes_classes_and_lengths_in_their_order(name):
+    """Every seed offers request i with the same class and lengths, and its
+    own tokens (PERF.md section 6, PR 59)."""
+    spec = traffic.load(name)
+    plan = spec["plan_seed"]
+    shape = lambda t, n=1000: [(r["cls"], len(r["prompt"]), r["max_new"])
+                               for r in (t.request(i) for i in range(n))]
+    a, b = (traffic.ServeTraffic(spec, 50272, s) for s in (7, 2147483777))
+    assert shape(a) == shape(b)
+    # the plan is that seed's own draw of lengths, as a mix with no plan
+    # gives them
+    free = {k: v for k, v in spec.items() if k != "plan_seed"}
+    assert shape(a, 200) == shape(traffic.ServeTraffic(free, 50272, plan), 200)
+    assert shape(a, 200) != shape(traffic.ServeTraffic(free, 50272, 7), 200)
+    # the seed draws the tokens, the same seed the same tokens
+    assert not any(np.array_equal(a.request(i)["prompt"][:16],
+                                  b.request(i)["prompt"][:16])
+                   for i in range(0, 1000, 50))
+    assert np.array_equal(a.request(3)["prompt"],
+                          traffic.ServeTraffic(spec, 50272, 7).request(3)["prompt"])
+
+
+@pytest.mark.parametrize("name", PLANNED)
+def test_a_plan_is_a_sample_of_the_mix_and_not_another_mix(name):
+    """Over the plan's first 500 requests the classes' shares lie within 5
+    points of the file's and each length's quartiles within 5% of the
+    distribution's own."""
+    spec = traffic.load(name)
+    t = traffic.ServeTraffic(spec, 50272, 1)
+    reqs = [t.request(i) for i in range(500)]
+    for ci, c in enumerate(spec["classes"]):
+        mine = [r for r in reqs if r["cls"] == ci]
+        assert abs(len(mine) / 500 - t.shares[ci]) < 0.05
+        for key, got in (("prompt", [len(r["prompt"]) for r in mine]),
+                         ("answer", [r["max_new"] for r in mine])):
+            want = traffic.lengths_at(c[key], [0.25, 0.5, 0.75])
+            have = np.quantile(got, [0.25, 0.5, 0.75])
+            assert np.all(np.abs(have - want) < 0.05 * want), (name, key, have, want)
+            lo, hi = traffic.length_bounds(c[key])
+            assert lo <= min(got) and max(got) <= hi
+
+
+def serve_mixes_of_the_cells():
+    """Every serve mix that a cell of BENCHMARK.json names."""
+    with open(os.path.join(os.path.dirname(traffic.HERE), "BENCHMARK.json")) as f:
+        names = sorted({w["traffic"] for w in json.load(f)["workloads"]})
+    return [n for n in names if traffic.load(n).get("kind") == "serve"]
+
+
+@pytest.mark.parametrize("name", serve_mixes_of_the_cells())
+def test_a_mix_may_name_the_seed_its_weights_are_made_from(name):
+    """A rule on the data, so that a later cell brings only its own files: a
+    mix that names a ``weights_seed`` gets those weights for every ``--seed``
+    and says in its ``why`` what the key gives up (the served check then sees
+    one router's skew: PERF.md section 6, PR 59); any other mix's weights are
+    the seed's. The prompts are the seed's in either case."""
+    from runners.serve import ServeRunner
+    spec = traffic.load(name)
+    got = [ServeRunner({"chips": 1}, {}, spec, s, print).weights_seed()
+           for s in (7, 2147483777)]
+    if "weights_seed" in spec:
+        assert isinstance(spec["weights_seed"], int)
+        assert got == [spec["weights_seed"]] * 2
+        assert "weights_seed" in spec["why"] and "gives up" in spec["why"]
+    else:
+        assert got == [7, 2147483777]
+
+
+def test_a_closed_loop_without_a_plan_draws_by_the_seed_as_before():
+    """A file without the key (a trial mix, a test's own): request i is what
+    it was before PR 59, value for value."""
+    for name, want in (("closed_decode", [(185, 367, 27252), (143, 150, 28229),
+                                          (222, 346, 2488)]),
+                       ("closed_shortlong_6k", [(598, 991, 27252), (345, 557, 28229),
+                                                (816, 948, 2488)])):
+        free = {k: v for k, v in traffic.load(name).items() if k != "plan_seed"}
+        a, b = (traffic.ServeTraffic(free, 50272, s) for s in (7, 8))
+        got = [a.request(i) for i in (0, 1, 999)]
+        assert [(len(r["prompt"]), r["max_new"], int(r["prompt"][0]))
+                for r in got] == want
+        assert [len(a.request(i)["prompt"]) for i in range(50)] != \
+            [len(b.request(i)["prompt"]) for i in range(50)]
 
 
 def test_arrivals_are_a_poisson_process_given_its_count():

@@ -9,7 +9,11 @@ Train files (``"kind": "train"``): ``seq``, ``micro_batch_per_chip``,
 Serve files (``"kind": "serve"``): ``loop`` (``open`` | ``closed``),
 ``arrivals`` (open: ``{"dist": "poisson", "rate_per_s"}`` and optionally
 ``"plan_seed"``: see ``ServeTraffic.open_plan``), ``clients_per_row``
-(closed: clients = that x ``max_running``), ``ramp_s`` (load offered before
+(closed: clients = that x ``max_running``), ``plan_seed`` (closed, optional:
+see ``ServeTraffic.request``), ``weights_seed`` (optional: the seed the
+runner makes the weights from, for every ``--seed``; a rehearsal obeys it
+too, so it narrows what the cell's check and its kept control see, and only
+``closed_shortlong_6k`` names one), ``ramp_s`` (load offered before
 the window opens, not measured), ``classes`` (each ``share``, ``prompt`` and
 ``answer`` length distributions), ``trace_seconds``, ``drain_s`` (the most the
 runner waits, after the close, for the requests it cuts), ``check``.
@@ -19,8 +23,12 @@ Length distributions, each through its quantile function (``lengths_at``):
 (inclusive), ``{"dist": "lognormal", "median", "sigma", "lo", "hi"}``
 (clipped). A closed loop consumes as many requests as the system completes,
 so request ``i`` takes its class and lengths at quantiles drawn freely from
-``(seed, i)``. An open loop's window holds a known number of requests, so its
-plan takes them at evenly spread quantiles (``ServeTraffic.open_plan``).
+``(plan_seed, i)``: one sample path of the mix for every ``--seed`` (every
+closed loop a cell names has one since PR 59; a file without the key, a trial
+mix or a test's own, draws them from ``(seed, i)``; a rehearsal loads its
+cell's own mix and so keeps the plan). An open loop's window
+holds a known number of requests, so its plan takes them at evenly spread
+quantiles (``ServeTraffic.open_plan``).
 ``length_scale`` (set only by a rehearsal configuration) multiplies every
 length.
 """
@@ -126,12 +134,24 @@ class ServeTraffic:
 
     def request(self, i: int) -> dict:
         """Request ``i`` of a closed loop: ``{"index", "cls", "prompt" (int32
-        ids), "max_new"}``, whatever was consumed before it."""
+        ids), "max_new"}``, whatever was consumed before it.
+
+        A closed loop's tokens/s follows how many prefills its window holds,
+        and a window holds some percent more or fewer by where a seed's draw
+        of lengths puts the completions. So a mix names a ``plan_seed`` at
+        its top level: request ``i`` then takes its class and its lengths
+        from ``(plan_seed, i)``, the same for every ``--seed``, and the seed
+        draws only the prompt's tokens (and the weights): every seed offers
+        the same work in the same order. Without the key both come from
+        ``(seed, i)``."""
         rng = np.random.default_rng([self.seed, 2, i])
-        ci = min(int(np.searchsorted(np.cumsum(self.shares), rng.random())),
+        plan_seed = self.spec.get("plan_seed")
+        shape = rng if plan_seed is None else \
+            np.random.default_rng([int(plan_seed), 2, i])
+        ci = min(int(np.searchsorted(np.cumsum(self.shares), shape.random())),
                  len(self.classes) - 1)
         cls = self.classes[ci]
-        n_prompt, n_new = (lengths_at(cls[k], rng.random(), self.scale)[0]
+        n_prompt, n_new = (lengths_at(cls[k], shape.random(), self.scale)[0]
                            for k in ("prompt", "answer"))
         return self._request(i, ci, n_prompt, n_new, rng)
 
