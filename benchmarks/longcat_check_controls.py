@@ -173,13 +173,16 @@ def build(config, init, control):
 
 
 def controls(args, config, name_map, name, traffic=TRAFFIC, build=None,
-             planted=None, sound_extra=None):
+             planted=None, sound_extra=None, cfg_of=None):
     """The loop of every trial and control. Another configuration's tool
     (``lfm2_check_controls.py``) hands its own ``traffic``, ``build`` and
-    ``planted``, and ``sound_extra(ref, cfg, weights, prompts, served)``:
-    more keys for the sound control's line."""
+    ``planted``, ``sound_extra(ref, cfg, weights, prompts, served)``: more
+    keys for the sound control's line, and ``cfg_of(control, cfg)``: the
+    reference's configuration the served tokens are CHECKED under (a control
+    planted in the reference and not in the program)."""
     build = build or globals()["build"]
     planted = planted or globals()["planted"]
+    cfg_of = cfg_of or (lambda control, cfg: cfg)
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -231,7 +234,7 @@ def controls(args, config, name_map, name, traffic=TRAFFIC, build=None,
             del engine, serving, handles
             gc.collect()
             jax.clear_caches()
-            got = [correctness.check_served(cfg, weights, p, s)
+            got = [correctness.check_served(cfg_of(control, cfg), weights, p, s)
                    for p, s in zip(prompts, served)]
             gaps = np.array([g["worst_gap_bf16_steps"] for g in got])
             refused = int(sum(not g["ok"] for g in got))
@@ -342,7 +345,8 @@ def main(tool="longcat_check_controls", configs=("longcat-flash-omni",
          names=CONTROLS, seed=4800000101, run_controls=controls,
          run_logits=logits, more_args=None):
     """``configs``: (the cell's configuration, its rehearsal's).
-    ``more_args(ap)``: another tool's own options."""
+    ``more_args(ap)``: another tool's own options. Returns what the chosen
+    run returned (a tool's exit code, or None)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--pairs", type=int, default=4)
     ap.add_argument("--seed", type=int, default=seed)
@@ -371,7 +375,8 @@ def main(tool="longcat_check_controls", configs=("longcat-flash-omni",
     with open(os.path.join(ROOT, "perfbench", "configs", name + ".json")) as f:
         config = json.load(f)
     name_map = correctness.load_map(name)
-    (run_logits if args.logits else run_controls)(args, config, name_map, name)
+    return (run_logits if args.logits else run_controls)(
+        args, config, name_map, name)
 
 
 if __name__ == "__main__":

@@ -2398,7 +2398,7 @@ class _ServeSession:
         table = np.asarray(req.blocks, np.int32)
         slots = engine._flat_slots(table, start, n, Tb, self.bs)
         if self.sched.telemetry is not None:
-            self.sched.telemetry.count_prefill(n, Tb)
+            self.sched.telemetry.count_prefill(n, Tb, start)
             if self._stateful and start == 0:
                 self.sched.telemetry.count_state_reset()
         return toks, table, slots.astype(np.int32), np.int32(n - 1)

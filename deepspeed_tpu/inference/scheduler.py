@@ -191,6 +191,7 @@ class ServingTelemetry:
                "kv_blocks_free", "kv_block_utilization", "kv_fragmentation",
                "cold_blocks", "prefill_steps", "prefill_chunks",
                "prefill_tokens", "prefill_padded_tokens",
+               "prefill_tokens_squared",
                "decode_steps", "decode_steps_ahead", "decode_steps_late",
                "decode_live_kv_tokens", "decode_live_kv_blocks",
                "decode_window_blocks_held", "decode_window_ring_blocks",
@@ -353,10 +354,21 @@ class ServingTelemetry:
             "the same steps' compile-bucket widths: what the device "
             "computed, padding included")
 
-    def count_prefill(self, tokens: int, padded: int) -> None:
-        """One prefill or chunk step, where its padding was decided."""
+    @property
+    def prefill_tokens_squared(self):
+        return self.registry.counter(
+            "serving/prefill_tokens_squared",
+            "what a causal prefill's attention is counted from: the true "
+            "prompt length squared of a whole-prompt prefill; of a chunk "
+            "of n tokens behind ``start`` cached ones, (start + n)^2 - "
+            "start^2, so that a prompt's chunks add up to its square")
+
+    def count_prefill(self, tokens: int, padded: int, start: int) -> None:
+        """One prefill or chunk step of ``tokens`` real tokens behind
+        ``start`` cached ones, where its padding was decided."""
         self.prefill_tokens.inc(tokens)
         self.prefill_padded_tokens.inc(padded)
+        self.prefill_tokens_squared.inc(tokens * (2 * start + tokens))
 
     @property
     def decode_steps(self):

@@ -1,6 +1,9 @@
 """Multi-head latent attention (MLA): the ``latent_attention`` layer kind.
 
     cq = N(x Wqa);  q = cq Wqb -> H heads of (dn + dr)          [x s_q]
+    (``q_lora_rank`` 0, a DIRECT query: q = x Wq, one matrix [D, H (dn +
+    dr)], no bottleneck, no norm and no s_q: DeepSeek-V3's ``q_lora_rank``
+    null, Kanana-2)
     [ckv | kr] = x Wkva;  ckv = N(ckv) [x s_kv];  kr ONE head for all H
     rope on q's last dr and on kr;  [k_nope | v] = ckv Wkvb -> H x (dn + dv)
     scores (q . [k_nope | kr]) / sqrt(dn + dr), causal softmax in float32
@@ -47,13 +50,13 @@ LORA_EPS = 1e-6
 
 
 def check(cfg: TransformerConfig):
-    sizes = (cfg.q_lora_rank, cfg.kv_lora_rank, cfg.qk_nope_head_dim,
-             cfg.qk_rope_head_dim, cfg.v_head_dim)
-    if min(sizes) < 1 or cfg.qk_rope_head_dim % 2:
+    sizes = (cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+             cfg.v_head_dim)
+    if min(sizes) < 1 or cfg.qk_rope_head_dim % 2 or cfg.q_lora_rank < 0:
         raise ValueError(
-            "a latent_attention layer needs q_lora_rank, kv_lora_rank, "
-            "qk_nope_head_dim, v_head_dim and an even qk_rope_head_dim "
-            f"(got {sizes})")
+            "a latent_attention layer needs kv_lora_rank, qk_nope_head_dim, "
+            "v_head_dim, an even qk_rope_head_dim and a q_lora_rank of 0 (a "
+            f"direct query) or more (got {sizes}, {cfg.q_lora_rank})")
     if cfg.pos_embedding != "rope":
         raise ValueError("a latent_attention layer's shared key part is "
                          "roped: pos_embedding='rope'")
@@ -68,10 +71,12 @@ def init(cfg: TransformerConfig, n: int, key, dtype, out_std):
     def dense(k, shape, scale=cfg.init_std):
         return (jax.random.normal(k, shape) * scale).astype(dtype)
 
+    query = {"wq_a": dense(ks[0], (n, D, Q)),
+             "q_norm": {"scale": jnp.ones((n, Q), dtype)},
+             "wq_b": dense(ks[1], (n, Q, H * (dn + dr)))} if Q else \
+        {"wq": dense(ks[0], (n, D, H * (dn + dr)))}
     return {
-        "wq_a": dense(ks[0], (n, D, Q)),
-        "q_norm": {"scale": jnp.ones((n, Q), dtype)},
-        "wq_b": dense(ks[1], (n, Q, H * (dn + dr))),
+        **query,
         "wkv_a": dense(ks[2], (n, D, R + dr)),
         "kv_norm": {"scale": jnp.ones((n, R), dtype)},
         "wkv_b": dense(ks[3], (n, R, H * (dn + dv))),
@@ -94,12 +99,17 @@ def project(cfg: TransformerConfig, x, lp, positions):
     dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     rope = lambda a: _rope(a, positions, cfg.rope_theta, 0,  # noqa: E731
                            cfg.rope_interleaved)
-    cq = _rms(x @ _w(lp["wq_a"], x), lp["q_norm"], LORA_EPS)
-    q = _flat(cq @ _w(lp["wq_b"], x)).reshape(B, T, H, dn + dr)
+    if cfg.q_lora_rank:
+        cq = _rms(x @ _w(lp["wq_a"], x), lp["q_norm"], LORA_EPS)
+        q = _flat(cq @ _w(lp["wq_b"], x))
+    else:
+        q = _flat(x @ _w(lp["wq"], x))
+    q = q.reshape(B, T, H, dn + dr)
     kv = x @ _w(lp["wkv_a"], x)
     ckv = _rms(kv[..., :R], lp["kv_norm"], LORA_EPS)
     if cfg.mla_lora_scale:
-        q = q * math.sqrt(D / cfg.q_lora_rank)
+        if cfg.q_lora_rank:
+            q = q * math.sqrt(D / cfg.q_lora_rank)
         ckv = ckv * math.sqrt(D / R)
     kr = rope(kv[..., None, R:])[:, :, 0]
     return q[..., :dn], rope(q[..., dn:]), jnp.concatenate([ckv, kr], -1)
@@ -167,7 +177,8 @@ def prefill(cfg: TransformerConfig, x, lp, positions, cp, slots):
     (out [1, T, D], cp)."""
     q_nope, q_rope, rows = project(cfg, x, lp, positions)
     cp = _scatter(cp, rows, slots)
-    out = expanded_attention(cfg, q_nope, q_rope, rows, lp)
+    with jax.named_scope("latent_prefill"):
+        out = expanded_attention(cfg, q_nope, q_rope, rows, lp)
     return out @ _w(lp["wo"], out), cp
 
 

@@ -607,6 +607,69 @@ def trinity(size: str = "large-preview-5l-ep8", share: int = 0, **over):
         param_dtype=param_dtype)
 
 
+def deepseek_v3(size: str = "kanana-2-30b-8l", **over):
+    """The ``deepseek_v3`` stack as Kanana-2-30B-A3B publishes it
+    (``kakaocorp/kanana-2-30b-a3b-instruct-2601`` config.json): 48
+    pre-RMSNorm layers (eps 1e-6, no bias anywhere) of d 2,048, every mixer
+    multi-head LATENT attention with a DIRECT query (``q_lora_rank`` null:
+    one matrix to 32 heads of 128 + 64, no bottleneck and no norm), keys
+    and values from one RMSNormed latent of 512 a token beside a roped key
+    part of 64 shared by the heads (values 128; rope theta 1e6 on
+    interleaved pairs, scale 1/sqrt(192)); layer 0 with a dense gated-SiLU
+    MLP of 6,144 (``first_k_dense_replace`` 1), the other 47 with 128
+    gated-SiLU experts of 768, 6 a token by a float32 sigmoid score plus a
+    selection bias (for the choice alone; ``n_group`` = ``topk_group`` = 1:
+    no group limit), the six scores divided by their sum + 1e-20 and times
+    2.448, beside two shared experts (one gated MLP of 1,536); a final
+    RMSNorm and an untied head over 128,256 rows. 30.67 B parameters.
+    ``kanana-2-30b-8l`` is STAGE 1 OF A SIX-STAGE PIPELINE (perfbench's
+    ``kanana2_30b_serve_longdoc``): the leading dense layer (a LATENT lead:
+    ``lead_kinds``, pool ``c``'s first entry) and 7 MoE layers with ALL 128
+    experts and the whole vocabulary. ``max_seq`` is what the deployment
+    serves (20,480 + 768; the block tables' width, 166 blocks of 128). Its
+    seeded init: matrices at ``init_std`` 0.045 (output projections
+    depth-scaled), the embedding at ``embed_init_std`` 24.0, the router's
+    scale 1.0; the selection bias ``b_select`` is zero here and drawn N(0,
+    0.02) by the harness, as Solar's and Trinity's. The init was chosen on
+    the chip (``benchmarks/kanana_check_controls.py``; the sweep's readings
+    are in PERF.md section 6, PR 61): matrices sharp enough that attention
+    over 12 k keys is not flat, the stream held by the embedding firmly
+    enough that ONE expert taken for another by a router under bf16
+    activations (a quarter of the positions) does not cost a sound prompt
+    the served check.
+    ``tiny``: the lead and TWO MoE layers at toy widths, 8 experts top-3,
+    keys 32 + 64 and values 32 over a latent of 128."""
+    from deepspeed_tpu.models.moe_lm import MoECausalLM, MoEConfig
+    dims, moe = {
+        "tiny": (dict(n_layer=3, n_head=8, d_model=64, d_ff=32, lead_d_ff=96,
+                      vocab_size=512, max_seq=768, kv_lora_rank=128,
+                      qk_nope_head_dim=32, qk_rope_head_dim=64,
+                      v_head_dim=32, init_std=0.1, embed_init_std=1.0),
+                 dict(num_experts=8, k=3, expert_d_ff=32,
+                      shared_expert_d_ff=64)),
+        "kanana-2-30b-8l": (
+            dict(n_layer=8, n_head=32, d_model=2048, d_ff=768,
+                 lead_d_ff=6144, vocab_size=128256, max_seq=21248,
+                 kv_lora_rank=512, qk_nope_head_dim=128, qk_rope_head_dim=64,
+                 v_head_dim=128, init_std=0.045, embed_init_std=24.0),
+            dict(num_experts=128, k=6, expert_d_ff=768,
+                 shared_expert_d_ff=1536)),
+    }[size]
+    param_dtype = over.pop("param_dtype", jnp.float32)
+    moe = {**moe, **over.pop("moe", {})}
+    cfg = TransformerConfig(**{**dict(
+        pos_embedding="rope", rope_theta=1e6, rope_interleaved=True,
+        norm="rmsnorm", norm_eps=1e-6, activation="swiglu",
+        tie_embeddings=False, attn_bias=False, q_lora_rank=0,
+        lead_kinds=("latent_attention",),
+        layer_kinds=("latent_attention",)), **dims, **over})
+    return MoECausalLM(cfg, MoEConfig(**{**dict(
+        dispatch="nodrop", expert_activation="swiglu", scoring="sigmoid",
+        norm_topk_prob=True, norm_topk_eps=1e-20,
+        routed_scaling_factor=2.448, aux_loss_coef=0.0), **moe}),
+        param_dtype=param_dtype)
+
+
 MODEL_PRESETS: Dict[str, Callable] = {
     "gpt2": gpt2,
     "llama": llama,
@@ -621,6 +684,7 @@ MODEL_PRESETS: Dict[str, Callable] = {
     "longcat_flash": longcat_flash,
     "lfm2_moe": lfm2_moe,
     "trinity": trinity,
+    "deepseek_v3": deepseek_v3,
 }
 
 

@@ -183,9 +183,9 @@ class TransformerConfig:
     # channels (LFM2's ``conv_L_cache``); a request keeps the last
     # ``conv_kernel - 1`` of the conv's inputs
     conv_kernel: int = 3
-    # the latent (MLA) mixer: queries through a bottleneck of
-    # ``q_lora_rank`` (RMSNorm in it), ``n_head`` heads of ``qk_nope_head_dim
-    # + qk_rope_head_dim``; keys and values from ONE latent of
+    # the latent (MLA) mixer: queries through a bottleneck of ``q_lora_rank``
+    # (RMSNorm in it; 0: a DIRECT query, one matrix), ``n_head`` heads of
+    # ``qk_nope_head_dim + qk_rope_head_dim``; keys, values from ONE latent of
     # ``kv_lora_rank`` (RMSNorm) a token, expanded a head to
     # ``qk_nope_head_dim + v_head_dim``, beside one roped key part of
     # ``qk_rope_head_dim`` shared by all heads. ``mla_lora_scale``: the

@@ -211,6 +211,9 @@ def _weight_sized_moves(text, shapes):
     pytest.param("trinity", "large-preview-5l-ep8", 64, id="trinity"),
     # latent attention: longcatflashomni_serve_ctx3k
     pytest.param("longcat_flash", "omni-4l-ep32", 64, id="longcat"),
+    # latent attention with a direct query behind a latent lead:
+    # kanana2_30b_serve_longdoc
+    pytest.param("deepseek_v3", "kanana-2-30b-8l", 12, id="kanana"),
 ])
 def test_no_stacked_projection_weight_is_copied_for_v5e(
         family, size, rows, one_v5e, monkeypatch):
@@ -243,7 +246,8 @@ def test_no_stacked_projection_weight_is_copied_for_v5e(
     weights = {path[-1].key: leaf.shape[1:]
                for path, leaf in jax.tree_util.tree_flatten_with_path(shapes)[0]
                if "['attn']" in jax.tree_util.keystr(path) and leaf.ndim == 3}
-    assert {"wq", "wk"} <= set(weights) or "wq_b" in weights
+    # q and k projections, or a latent stack's (either form of its query)
+    assert {"wq", "wk"} <= set(weights) or "wkv_a" in weights
     weights.pop("wkv_b", None)                         # S11's, see above
     # what a row keeps beside its KV blocks: a window layer's ring of blocks
     pool_kw, kept = {}, {}
